@@ -58,7 +58,6 @@ _PEAK_HBM_BYTES_S = {
     "TPU v6 lite": 1640e9,
     "TPU v5 lite": 819e9,
     "TPU v5p": 2765e9,
-    "TPU v5": 819e9,  # bare "v5" reported by some stacks is v5e
     "TPU v4": 1228e9,
     "TPU v3": 900e9,
 }

@@ -35,9 +35,9 @@ scrapes it for free) and on the event bus (so the journal, the
 
 The wrapper itself never emits from inside a traced region: when a
 tracked function is being traced INTO an enclosing computation (e.g.
-``ops.kde.propose`` vmapped inside the fused sweep), the wrapper detects
-the live trace and passes straight through — the outer tracked boundary
-owns that compile.
+``ops.kde.propose`` vmapped inside the fused sweep), its arguments are
+tracers; the wrapper sees them and passes straight through — the outer
+tracked boundary owns that compile.
 """
 
 from __future__ import annotations
@@ -226,19 +226,24 @@ def _value_key(leaf: Any) -> Any:
         return repr(leaf)
 
 
-#: jax.tree_util.tree_flatten, bound once on first use — the wrapper sits
-#: on the hot dispatch path, so per-call `import jax` + attribute chains
-#: are real money (measured ~14µs of a ~18µs signature)
-_TREE_FLATTEN: Optional[Callable] = None
+#: (jax.tree_util.tree_flatten, jax.core.Tracer), bound once on first use —
+#: the wrapper sits on the hot dispatch path, so per-call `import jax` +
+#: attribute chains are real money (measured ~14µs of a ~18µs signature)
+_JAX_BINDINGS: Optional[Tuple[Callable, type]] = None
 
 
 def _flatten(x: Any):
-    global _TREE_FLATTEN
-    if _TREE_FLATTEN is None:
+    """``(leaves, treedef, traced)``: ``traced`` is True when any leaf is a
+    ``jax.core.Tracer`` — the call is being traced INTO an enclosing
+    computation and must pass through untracked."""
+    global _JAX_BINDINGS
+    if _JAX_BINDINGS is None:
         import jax
 
-        _TREE_FLATTEN = jax.tree_util.tree_flatten
-    return _TREE_FLATTEN(x)
+        _JAX_BINDINGS = (jax.tree_util.tree_flatten, jax.core.Tracer)
+    tree_flatten, tracer_type = _JAX_BINDINGS
+    leaves, treedef = tree_flatten(x)
+    return leaves, treedef, any(isinstance(l, tracer_type) for l in leaves)
 
 
 def _abstract_signature(
@@ -246,15 +251,18 @@ def _abstract_signature(
     kwargs: Dict,
     static_nums: frozenset = frozenset(),
     static_names: frozenset = frozenset(),
-) -> Tuple:
+) -> Optional[Tuple]:
     """Hashable abstract signature of a call, in the same terms jax's own
     dispatch cache keys on: tree structure + per-leaf shape/dtype for
     traced leaves (python scalars by type only — weak-typed), static args
     by value. Weak-type-vs-strong-type distinctions inside arrays are
     deliberately ignored — a documented trade for a wrapper cheap enough
-    to sit on the hot dispatch path."""
+    to sit on the hot dispatch path. ``None`` when an argument leaf is a
+    tracer: the call belongs to an enclosing trace, not to a dispatch."""
     if not static_nums and not static_names:
-        leaves, treedef = _flatten((args, kwargs))
+        leaves, treedef, traced = _flatten((args, kwargs))
+        if traced:
+            return None
         return (treedef, tuple(map(_leaf_key, leaves)), (), ())
     t_args = tuple(a for i, a in enumerate(args) if i not in static_nums)
     s_args = tuple(
@@ -264,7 +272,9 @@ def _abstract_signature(
     s_kwargs = tuple(sorted(
         (k, _value_key(v)) for k, v in kwargs.items() if k in static_names
     ))
-    leaves, treedef = _flatten((t_args, t_kwargs))
+    leaves, treedef, traced = _flatten((t_args, t_kwargs))
+    if traced:
+        return None
     return (treedef, tuple(map(_leaf_key, leaves)), s_args, s_kwargs)
 
 
@@ -312,9 +322,10 @@ def tracked_jit(
 
     Signature tracking is per wrapper (each wrapper owns its own jit
     cache) while compile counts aggregate per label in the process-wide
-    :class:`CompileTracker`. Calls made while an enclosing trace is live
-    pass straight through untracked — the wrapper must never emit from
-    inside a traced region (the ``obs-emit-in-jit`` contract).
+    :class:`CompileTracker`. Calls whose arguments hold a
+    ``jax.core.Tracer`` (an enclosing trace is live) pass straight through
+    untracked — the wrapper must never emit from inside a traced region
+    (the ``obs-emit-in-jit`` contract).
     """
     if fn is None:
         return partial(
@@ -347,16 +358,10 @@ def tracked_jit(
         pass  # builtins/exotic callables: keyword statics still resolve
     static_nums = frozenset(static_nums)
     static_names = frozenset(names)
-    # bound once: jax.core's module __getattr__ costs ~1µs per access
-    trace_state_clean = jax.core.trace_state_clean
 
     def wrapper(*args: Any, **kwargs: Any):
-        if not E._ENABLED or not trace_state_clean():
-            # disabled, or being traced into an enclosing computation:
-            # the outer tracked boundary owns any compile that results
+        if not E._ENABLED:
             return jitted(*args, **kwargs)
-        reg = registry if registry is not None else get_metrics()
-        reg.counter("runtime.tracked_calls").inc()
         try:
             sig = _abstract_signature(args, kwargs, static_nums, static_names)
         except Exception:
@@ -364,6 +369,12 @@ def tracked_jit(
             # block the dispatch it was only supposed to observe
             logger.exception("tracked_jit signature for %r failed", label)
             return jitted(*args, **kwargs)
+        if sig is None:
+            # a tracer among the arguments: being traced into an enclosing
+            # computation, whose tracked boundary owns the compile
+            return jitted(*args, **kwargs)
+        reg = registry if registry is not None else get_metrics()
+        reg.counter("runtime.tracked_calls").inc()
         if sig in seen:
             return jitted(*args, **kwargs)
         t0 = time.perf_counter()
@@ -439,8 +450,6 @@ def _extract_cost(compiled: Any) -> Optional[Dict[str, float]]:
         ca = compiled.cost_analysis()
     except Exception:  # graftlint: disable=swallowed-exception — backends without cost analysis are expected; absence of a roofline row is the answer, the compile is still ledgered
         return None
-    if isinstance(ca, (list, tuple)):  # older jax returns [dict] per device
-        ca = ca[0] if ca else None
     if not isinstance(ca, dict):
         return None
     out: Dict[str, float] = {}

@@ -283,7 +283,7 @@ def propose_batch_seeded_scored(
 ) -> Tuple[jax.Array, jax.Array]:
     """Like :func:`propose_batch` but derives the key batch on-device from
     a single uint32 seed — one scalar transfer instead of an [n, 2] key
-    upload (matters when the host link is a high-latency tunnel) — and
+    upload — and
     also returns each proposal's winning acquisition score:
     ``(f32[n, d], f32[n])`` where the score is the selected candidate's
     ``log l(x) - log g(x)`` (the max over the same score vector the

@@ -44,8 +44,6 @@ Public surface:
   INSIDE an existing ``shard_map`` (composes with other parallelism).
 * :func:`make_ring_attention` — wraps it in ``shard_map`` over a named
   mesh axis: ``fn(q, k, v)`` on global ``[T, H, dh]`` arrays.
-* ``shard_map`` — the version-resolved transform, re-exported so callers
-  don't repeat the pre-0.8 fallback.
 
 Parity with dense attention — values AND gradients — is pinned in
 ``tests/test_ring_attention.py`` on the virtual 8-device mesh.
@@ -60,14 +58,11 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec
 
-try:  # jax >= 0.8
-    from jax import shard_map
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 __all__ = [
     "ring_attention_block", "make_ring_attention", "seq_mesh",
-    "stripe_indices", "shard_map",
+    "stripe_indices",
 ]
 
 #: additive mask value: large-negative (not -inf) so fully-masked tiles

@@ -261,8 +261,8 @@ class ContinuousRunner:
 
         # the carry is the device-resident state thread: donate it so the
         # update aliases in place on accelerator backends; gated OFF on
-        # CPU by the shared probe (ops/sweep.py sweep_donation_safe — the
-        # jax-0.4.37 CPU PJRT aliasing hazard). The vectors/counts/reset
+        # CPU by the shared gate (ops/sweep.py sweep_donation_safe). The
+        # vectors/counts/reset
         # inputs are fresh uploads each chunk and their shapes never match
         # an output: donation declined for them explicitly
         # (docs/perf_notes.md "Buffer donation contract").

@@ -1,66 +1,65 @@
-"""Persistent XLA compile cache — one knob, every process tier.
+"""Persistent XLA compile cache — one switch, every process tier.
 
 The fused tiers' first-run cost is dominated by XLA compiles; jax can
 persist compiled executables to disk so the SECOND process on a machine
-pays none of it. ``bench.py`` has enabled this since the fused tiers
-landed, but workers and executors spawned outside the bench (the RPC
-worker pool, ``TPUBatchedWorker``, a user's own ``BatchedExecutor``)
-compiled cold every time. This module is the one shared switch, called
-from every startup path that is about to build device programs.
+pays none of it. Every startup path that is about to build device
+programs (``FusedBOHB``, ``BatchedExecutor``, ``Worker``,
+``TPUBatchedWorker``, ``ServePool``, ``bench.py``, the test suite) calls
+the one function here.
 
-Knobs (documented in docs/perf_notes.md):
+Where the cache lives:
 
-* ``HPB_XLA_CACHE=0`` disables entirely (e.g. hermetic CI);
-* ``HPB_XLA_CACHE_DIR`` overrides the cache directory (default
-  ``~/.cache/hpbandster_tpu_xla``).
+* ``JAX_COMPILATION_CACHE_DIR`` set: jax reads the directory from its own
+  environment variable and this module sets none in code — whoever runs
+  the program places the cache.
+* unset: ``<checkout>/.jax_compilation_cache``, resolved from this
+  package's own location. The directory is part of the cache key's
+  surroundings, so it is a fixed path: never a temp name, a pid or a time.
 
-Idempotent and exception-free: a jax too old for the config names, an
-unwritable directory, or a disabled env all degrade to "no persistent
-cache" — in-process caches still apply and callers never need a guard.
+Either way the min-compile-time threshold is set in code, to zero: every
+program persists. With jax's default of one second, a program that
+compiles in about a second is written by whichever run happens to take
+1.01 s, so what a second run finds would depend on timing noise (seen on
+the chip: a warm ``chip_smoke.py`` run added two entries). ``HPB_XLA_CACHE=0``
+disables the cache entirely (e.g. hermetic CI).
 """
 
 from __future__ import annotations
 
 import os
 
-__all__ = ["enable_persistent_compile_cache"]
+__all__ = ["enable_persistent_compile_cache", "DEFAULT_CACHE_DIR"]
 
-#: min compile seconds worth persisting — tiny kernels churn the disk for
-#: nothing; the fused programs this exists for compile in 10s of seconds
-_MIN_COMPILE_TIME_S = 1.0
+#: min compile seconds worth persisting: none — see the module docstring
+_MIN_COMPILE_TIME_S = 0.0
+
+#: the in-checkout default (gitignored), used when the environment names
+#: no directory
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_compilation_cache",
+)
 
 _enabled_dir: str = ""
 
 
-def enable_persistent_compile_cache(cache_dir: str = "") -> str:
-    """Point jax's persistent compilation cache at a shared directory.
-
-    Returns the directory in use ('' when disabled). Safe to call from
-    any tier, any number of times; only the first effective call touches
-    jax config (re-pointing at a different directory works too, but the
-    common path is a no-op lookup).
-    """
+def enable_persistent_compile_cache() -> str:
+    """Turn on jax's persistent compilation cache; returns the directory
+    in use ('' when disabled). Safe to call from any tier, any number of
+    times; only the first effective call touches jax config."""
     global _enabled_dir
     if os.environ.get("HPB_XLA_CACHE", "") == "0":
         return ""
-    cache_dir = (
-        cache_dir
-        or os.environ.get("HPB_XLA_CACHE_DIR", "")
-        or os.path.expanduser("~/.cache/hpbandster_tpu_xla")
-    )
-    if _enabled_dir == cache_dir:
+    if _enabled_dir:
         return _enabled_dir
-    try:
-        os.makedirs(cache_dir, exist_ok=True)
-        import jax
+    import jax
 
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR", "")
+    if not cache_dir:
+        cache_dir = DEFAULT_CACHE_DIR
         jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update(
-            "jax_persistent_cache_min_compile_time_secs", _MIN_COMPILE_TIME_S
-        )
-    # degrade to in-process caches only: older jax spells the flags
-    # differently, and an unwritable HOME must not take down a worker
-    except Exception:  # graftlint: disable=swallowed-exception — cache is an optimization; absence is a valid state
-        return ""
+    jax.config.update(
+        "jax_persistent_cache_min_compile_time_secs", _MIN_COMPILE_TIME_S
+    )
     _enabled_dir = cache_dir
     return _enabled_dir

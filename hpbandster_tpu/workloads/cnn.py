@@ -1,4 +1,4 @@
-"""CNN hyperparameter-search workload — BASELINE.md rung 4 (CNN/CIFAR-10).
+"""CNN hyperparameter-search workload — BASELINE.json config 4 (CNN/CIFAR-10).
 
 Every config is a full conv-net training run on CIFAR-shaped images, and the
 whole config batch trains simultaneously: parameters for all configs are

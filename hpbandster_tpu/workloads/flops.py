@@ -45,12 +45,12 @@ __all__ = [
 #: per-chip peak dense bf16 FLOP/s by ``device.device_kind`` prefix.
 #: v5e ("TPU v5 lite"): 394 TOPS int8 / 197 TFLOP/s bf16; v4: 275; v5p: 459;
 #: v6e ("TPU v6 lite", Trillium): 918. Unknown kinds return None — the
-#: bench then reports achieved FLOP/s without an MFU percentage.
+#: bench then reports achieved FLOP/s without an MFU percentage, and
+#: ``chip_smoke.py`` fails on a chip that has no row here.
 _PEAK_BF16 = {
     "TPU v6 lite": 918e12,
     "TPU v5 lite": 197e12,
     "TPU v5p": 459e12,
-    "TPU v5": 197e12,  # bare "v5" reported by some stacks is v5e
     "TPU v4": 275e12,
     "TPU v3": 123e12,
 }

@@ -1,6 +1,6 @@
 """MLP hyperparameter-search workload — the flagship batched-training path.
 
-BASELINE.md rung 3 ("MLP with JAX-trainable worker"): every config is a full
+BASELINE.json config 3 ("MLP with JAX-trainable worker"): every config is a full
 MLP training run (SGD with momentum + weight decay on a classification set),
 and the *whole config batch trains simultaneously* — parameters for all
 configs are stacked on a leading config axis and the training loop is one

@@ -1,4 +1,4 @@
-"""ResNet-18 sweep workload — BASELINE.md rung 5 (ResNet-18, eta=4 sweep).
+"""ResNet-18 sweep workload — BASELINE.json config 5 (ResNet-18, eta=4 sweep).
 
 A ResNet-18-shaped network (stem + 4 stages x 2 basic blocks + GAP head)
 whose training run is fully jittable and vmappable over a config batch, so a

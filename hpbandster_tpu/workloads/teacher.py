@@ -1,6 +1,6 @@
 """Teacher-student classification workload — the deterministic "real-ish" rung.
 
-BASELINE.md's ladder calls for dataset workloads (MLP/MNIST, CNN/CIFAR-10),
+BASELINE.json's ladder calls for dataset workloads (MLP/MNIST, CNN/CIFAR-10),
 but this sandbox is offline (SURVEY.md provenance block), so real downloads
 are out. This module provides the next-best thing (VERDICT r1 #8): a FIXED
 procedurally generated classification problem whose labels come from a

@@ -12,9 +12,11 @@ setup(
     long_description=open("README.md").read(),
     long_description_content_type="text/markdown",
     packages=find_packages(include=["hpbandster_tpu", "hpbandster_tpu.*"]),
-    python_requires=">=3.10",
+    python_requires=">=3.11",
     install_requires=[
-        "jax",
+        # written for the one installed stack (jax 0.9.0 / libtpu 0.0.34):
+        # jax.shard_map(check_vma=), jax.core.Tracer, pltpu.CompilerParams
+        "jax>=0.9.0",
         "numpy",
     ],
     extras_require={

@@ -390,9 +390,8 @@ class TestDonationContract:
 
     def test_forced_donation_matches_and_consumes(self, monkeypatch):
         """With donation forced on (the accelerator default;
-        HPB_SWEEP_DONATE gates it off on CPU where jax 0.4.37's PJRT
-        intermittently corrupts the heap on aliased dict pytrees —
-        docs/perf_notes.md), results stay bit-identical and the donated
+        ops/sweep.py sweep_donation_safe keeps it off on CPU), results
+        stay bit-identical and the donated
         inputs are CONSUMED (aliased in place, not copied)."""
         plans, plain, state_fn, mkargs = self._sweep_pair(
             caps_n=32, donate_env="1", monkeypatch=monkeypatch

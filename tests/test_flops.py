@@ -30,8 +30,6 @@ RATIO_LO, RATIO_HI = 0.80, 1.45
 
 def _xla_flops(fn, *args) -> float:
     cost = jax.jit(fn).lower(*args).compile().cost_analysis()
-    if isinstance(cost, list):  # older jax returns one dict per computation
-        cost = cost[0]
     return float(cost["flops"])
 
 

@@ -8,14 +8,13 @@ from hpbandster_tpu.ops import KDE, LOG_PDF_FLOOR, kde_logpdf, normal_reference_
 from hpbandster_tpu.ops.pallas_kde import pallas_score_candidates
 
 
-def make_kde(rng, n, d, cards):
+def make_kde(rng, n, d, cards, cap=64):
     data = np.zeros((n, d), np.float32)
     for j in range(d):
         if cards[j] > 0:
             data[:, j] = rng.integers(cards[j], size=n)
         else:
             data[:, j] = rng.uniform(size=n)
-    cap = 64
     padded = np.zeros((cap, d), np.float32)
     padded[:n] = data
     mask = np.zeros(cap, np.float32)
@@ -82,6 +81,40 @@ def test_empty_mask_rows_ignored():
     )
     want = xla_scores(jnp.asarray(cands), good, bad, jnp.asarray(vt), jnp.asarray(cards, dtype=jnp.int32))
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
+
+
+def test_observation_tiled_scorer_matches_xla_at_8192():
+    """The capacity the 10k-config sweeps hand the kernel: 8192 slots is
+    16 observation tiles, so every candidate's score crosses the running
+    max/sum fold many times — and good/bad hold different live counts.
+    On a mesh each device scores its own candidate rows: same numbers."""
+    import jax
+
+    from hpbandster_tpu.parallel.mesh import config_mesh
+
+    rng = np.random.default_rng(2)
+    cards = [0, 4, 0]
+    vt = jnp.asarray([0, 1, 0], jnp.int32)
+    cards_arr = jnp.asarray(cards, jnp.int32)
+    good = make_kde(rng, 5000, 3, cards, cap=8192)
+    bad = make_kde(rng, 7000, 3, cards, cap=8192)
+    cands = np.zeros((300, 3), np.float32)
+    cands[:, 0] = rng.uniform(size=300)
+    cands[:, 1] = rng.integers(4, size=300)
+    cands[:, 2] = rng.uniform(size=300)
+
+    got = np.asarray(pallas_score_candidates(
+        cands, good, bad, vt, cards_arr, interpret=True))
+    want = xla_scores(jnp.asarray(cands), good, bad, vt, cards_arr)
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
+
+    mesh = config_mesh(jax.devices())
+    assert mesh.size == 8  # the conftest-forced CPU mesh
+    sharded = np.asarray(jax.jit(
+        lambda c: pallas_score_candidates(
+            c, good, bad, vt, cards_arr, interpret=True, mesh=mesh)
+    )(cands))
+    np.testing.assert_allclose(sharded, got, rtol=1e-5, atol=1e-5)
 
 
 def test_bohb_generator_pallas_path_end_to_end():

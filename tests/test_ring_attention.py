@@ -165,7 +165,7 @@ class TestRingAttentionParity:
         # the composition seam for mixing seq parallelism with other axes
         from jax.sharding import PartitionSpec
 
-        from hpbandster_tpu.ops.ring_attention import shard_map
+        from jax import shard_map
 
         q, k, v = _qkv(jax.random.key(4))
         mesh = seq_mesh()

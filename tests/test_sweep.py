@@ -487,6 +487,34 @@ class TestFusedSweep:
             c["config_info"].get("model_based_pick") for c in id2conf.values()
         ), "pallas-scored sweep produced no model-based picks"
 
+    def test_pallas_scorer_on_mesh_matches_single_device(self):
+        """On a mesh the scorer runs under a shard_map (each device its
+        own candidate rows); without shard_sampling the sweep draws the
+        same random stream either way, so mesh and single-device sweeps
+        make the same proposals."""
+        import jax
+
+        from hpbandster_tpu.parallel import config_mesh
+
+        def sweep(mesh):
+            opt = FusedBOHB(
+                configspace=branin_space(seed=0), eval_fn=branin_from_vector,
+                run_id="pl-mesh", min_budget=1, max_budget=9, eta=3, seed=23,
+                use_pallas=True, mesh=mesh,
+            )
+            res = opt.run(n_iterations=3)
+            id2conf = res.get_id2config_mapping()
+            return {
+                cid: (c["config"]["x"], c["config"]["y"])
+                for cid, c in id2conf.items()
+            }
+
+        single = sweep(None)
+        meshed = sweep(config_mesh(jax.devices()))
+        assert single.keys() == meshed.keys()
+        for cid, xy in single.items():
+            np.testing.assert_allclose(meshed[cid], xy, rtol=1e-4, atol=1e-4)
+
     @pytest.mark.slow
     def test_hartmann6_fused_sweep_converges(self):
         """BASELINE rung 2: 6-D Hartmann on the fused path."""

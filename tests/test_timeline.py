@@ -428,16 +428,11 @@ class TestCli:
 
 
 class TestEndToEnd:
-    def test_journaled_fused_sweep_timeline_and_critical_path(
-        self, tmp_path, capsys
-    ):
-        """ISSUE 19 acceptance: run a fused sweep (device metrics on, the
-        8-device CPU mesh) with a journal attached; ``obs timeline``
-        yields a Perfetto-loadable trace whose device rung slices are
-        correctly ordered, and ``obs critical-path`` attributes >= 96%
-        of the sweep's wall-clock to named phases (tightened from 95%
-        once the batched journal sink took fsync stalls off the span
-        path — ISSUE 20 satellite)."""
+    @staticmethod
+    def _journaled_sweep(seed, path, warm_seed=None):
+        """Run a fused sweep (device metrics on) with a journal attached
+        at ``path``; ``warm_seed`` first runs one unjournaled sweep so
+        the journaled one is the steady state. Returns the journal."""
         from hpbandster_tpu.optimizers import FusedBOHB
         from hpbandster_tpu.workloads.toys import (
             branin_from_vector,
@@ -453,22 +448,30 @@ class TestEndToEnd:
             opt.run(n_iterations=6, device_metrics=True)
             opt.shutdown()
 
-        def journaled_run(s, path):
-            journal = obs.JsonlJournal(
-                path, max_bytes=50_000_000, max_files=3
-            )
-            detach = obs.get_bus().subscribe(journal)
-            try:
-                run_once(s)
-            finally:
-                detach()
-                journal.close()
-            return journal
+        if warm_seed is not None:
+            # first-in-process jax/XLA backend init is one-time, not sweep
+            run_once(warm_seed)
+        journal = obs.JsonlJournal(path, max_bytes=50_000_000, max_files=3)
+        detach = obs.get_bus().subscribe(journal)
+        try:
+            run_once(seed)
+        finally:
+            detach()
+            journal.close()
+        return journal
 
-        run_once(5)  # warm: the acceptance bar is the steady state —
-        # first-in-process jax/XLA backend init is one-time, not sweep
+    def test_journaled_fused_sweep_timeline_and_critical_path(
+        self, tmp_path, capsys
+    ):
+        """ISSUE 19 acceptance, structural half: run a fused sweep (device
+        metrics on, the 8-device CPU mesh) with a journal attached;
+        ``obs timeline`` yields a Perfetto-loadable trace whose device
+        rung slices are correctly ordered, and ``obs critical-path``
+        attributes the sweep's wall-clock to named phases. HOW MUCH of
+        the wall it attributes is a timing, checked in the slow lane
+        (``test_critical_path_attributes_96pct_of_wall``)."""
         path = str(tmp_path / "journal.jsonl")
-        journal = journaled_run(6, path)
+        journal = self._journaled_sweep(6, path, warm_seed=5)
 
         # ISSUE 20 satellite: the sink batches micro-span writes behind
         # chunk-close barriers — physical flushes stay far below the
@@ -507,23 +510,33 @@ class TestEndToEnd:
         # flows stitched the sweep's trace_id across rows
         assert doc["otherData"]["flows"] >= 1
 
-        # critical path: >= 96% of the journaled wall attributed (the
-        # batched sink bought the extra point: per-record write+fsync
-        # used to ride between spans as unattributed gap). One retry
-        # with a fresh journal damps shared-host scheduling noise
-        # (a ms-scale toy sweep; a single descheduling blip between two
-        # spans can cost a percent) — the claim is about steady state.
+        assert obs_main(["critical-path", path, "--json"]) == 0
+        cp = json.loads(capsys.readouterr().out)
+        assert cp["end_to_end_s"] > 0
+        assert 0.0 < cp["attributed_share"] <= 1.0
+        assert cp["phases"]["rung_compute"]["s"] > 0
+
+    @pytest.mark.slow
+    def test_critical_path_attributes_96pct_of_wall(self, tmp_path, capsys):
+        """>= 96% of the journaled wall attributed to named phases
+        (tightened from 95% once the batched journal sink took fsync
+        stalls off the span path — ISSUE 20 satellite). A wall-clock
+        share of a millisecond-scale CPU sweep: a timing, so it lives
+        in the slow lane, not tier-1. One retry with a fresh journal
+        damps shared-host scheduling noise (a single descheduling blip
+        between two spans can cost a percent) — the claim is about
+        steady state."""
+        path = str(tmp_path / "journal.jsonl")
+        self._journaled_sweep(6, path, warm_seed=5)
         assert obs_main(["critical-path", path, "--json"]) == 0
         cp = json.loads(capsys.readouterr().out)
         if cp["attributed_share"] < 0.96:
             path2 = str(tmp_path / "journal2.jsonl")
-            journaled_run(7, path2)
+            self._journaled_sweep(7, path2)
             assert obs_main(["critical-path", path2, "--json"]) == 0
             cp = json.loads(capsys.readouterr().out)
-        assert cp["end_to_end_s"] > 0
         assert cp["attributed_share"] >= 0.96, format_critical_path(cp)
         assert cp["verdict"]["ok"] is True
-        assert cp["phases"]["rung_compute"]["s"] > 0
 
 
 if __name__ == "__main__":

@@ -1,0 +1,102 @@
+"""Chipless compile checks: the TPU compiler on the programs the chip runs.
+
+Interpret mode (every other Pallas test here) is plain XLA ops: it has no
+VMEM limit and partitions over a mesh on its own, so it cannot see the two
+ways these kernels failed on hardware — a VMEM footprint that grew with the
+observation count, and a Mosaic call inside an SPMD-partitioned program.
+The installed libtpu compiles for a ``v5e:2x2`` topology DESCRIPTION with
+no chip attached, which can: these tests AOT-compile the kernels at the
+capacities ``pow2_capacities`` produces and the 4-chip fused sweep.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec, SingleDeviceSharding
+
+from hpbandster_tpu.ops import pallas_kde
+from hpbandster_tpu.ops.bracket import hyperband_bracket
+from hpbandster_tpu.ops.kde import KDE
+from hpbandster_tpu.ops.sweep import build_space_codec, make_fused_sweep_fn
+from hpbandster_tpu.workloads.toys import branin_from_vector, branin_space
+
+
+@pytest.fixture(scope="module")
+def v5e_devices():
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 — any failure to DESCRIBE the topology is the one allowed skip
+        pytest.skip(
+            "no v5e:2x2 topology description from the installed libtpu "
+            f"({type(e).__name__}: {e})"
+        )
+    assert len(topo.devices) == 4
+    # a TPU executable cannot be read back without a TPU client: keep
+    # these compiles out of the suite's (CPU) persistent cache. jax
+    # decides once per process whether the cache is in use, so the
+    # decision is reset on both sides of the switch.
+    from jax.experimental.compilation_cache import compilation_cache
+
+    cache_was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield topo.devices
+    jax.config.update("jax_enable_compilation_cache", cache_was_on)
+    compilation_cache.reset_cache()
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+@pytest.mark.parametrize("n_obs", [256, 4096, 8192, 16384])
+def test_scorer_compiles_for_v5e_at_sweep_capacities(v5e_devices, n_obs):
+    one = SingleDeviceSharding(v5e_devices[0])
+    d, n_cands = 2, 8192
+
+    def score(cands, gd, gm, gb, bd, bm, bb, vartypes, cards):
+        return pallas_kde.pallas_score_candidates(
+            cands, KDE(gd, gm, gb), KDE(bd, bm, bb), vartypes, cards
+        )
+
+    f32, i32 = jnp.float32, jnp.int32
+    kde = (_sds((n_obs, d), f32, one), _sds((n_obs,), f32, one),
+           _sds((d,), f32, one))
+    compiled = jax.jit(score).lower(
+        _sds((n_cands, d), f32, one), *kde, *kde,
+        _sds((d,), i32, one), _sds((d,), i32, one),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("rows", [512, 4096, 8192, 16384, 131072])
+def test_moments_kernel_compiles_for_v5e(v5e_devices, rows):
+    one = SingleDeviceSharding(v5e_devices[0])
+    block = _sds((rows, 128), jnp.float32, one)
+    compiled = jax.jit(
+        lambda data, mask: pallas_kde._masked_moments_padded(
+            data, mask, interpret=False
+        )
+    ).lower(block, block).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_four_chip_mesh_sweep_compiles_with_pallas_scorer(v5e_devices):
+    """The README's "shard over all chips" program: a Mosaic call inside
+    the mesh-sharded sweep must sit under a shard_map or the SPMD
+    partitioner refuses the whole program at lowering."""
+    mesh = Mesh(np.asarray(v5e_devices), ("config",))
+    plans = [hyperband_bracket(i, 1, 9, 3) for i in range(3)]
+    fn = make_fused_sweep_fn(
+        branin_from_vector, plans, build_space_codec(branin_space(seed=0)),
+        mesh=mesh, use_pallas=True, pallas_interpret=False,
+    )
+    seed = _sds((), jnp.uint32, NamedSharding(mesh, PartitionSpec()))
+    text = fn.lower(seed).compile().as_text()
+    assert "tpu_custom_call" in text
+    assert "all-reduce" in text or "all-gather" in text

@@ -115,7 +115,9 @@ class TestSeqParallelForward:
         # the single-device forward within bf16 matmul rounding
         from jax.sharding import PartitionSpec
 
-        from hpbandster_tpu.ops.ring_attention import seq_mesh, shard_map
+        from jax import shard_map
+
+        from hpbandster_tpu.ops.ring_attention import seq_mesh
         from hpbandster_tpu.workloads.transformer import (
             transformer_forward_seq_parallel,
         )
@@ -152,7 +154,9 @@ class TestSeqParallelForward:
         # silently wrong while the forward parity test stayed green)
         from jax.sharding import PartitionSpec
 
-        from hpbandster_tpu.ops.ring_attention import seq_mesh, shard_map
+        from jax import shard_map
+
+        from hpbandster_tpu.ops.ring_attention import seq_mesh
         from hpbandster_tpu.workloads.transformer import (
             transformer_forward_seq_parallel,
         )
