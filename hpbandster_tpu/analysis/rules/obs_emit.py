@@ -15,8 +15,10 @@ traced body it flags:
 
 * calls resolving through the import map into ``hpbandster_tpu.obs``
   (``emit(...)``, ``span(...)``, ``obs.emit(...)``, the timeline span
-  API ``phase_span(...)``/``mark(...)``, aliased imports);
-* ``.emit(...)``, ``.phase_span(...)`` and ``.mark(...)`` method calls —
+  API ``phase_span(...)``/``sweep_span(...)``/``mark(...)``, aliased
+  imports);
+* ``.emit(...)``, ``.phase_span(...)``, ``.sweep_span(...)`` and
+  ``.mark(...)`` method calls —
   including on the result of ``get_bus()`` — but only in modules that
   import ``hpbandster_tpu.obs`` at all, so unrelated APIs elsewhere
   stay unflagged.
@@ -35,9 +37,9 @@ _OBS_PREFIX = "hpbandster_tpu.obs"
 
 #: emission-shaped attribute calls flagged in obs-importing modules:
 #: the bus API (``.emit``) and the timeline span API
-#: (``obs/timeline.py`` ``phase_span``/``mark``) — both are host clock
-#: reads + sink dispatch, equally wrong inside a traced body
-_EMIT_ATTRS = frozenset({"emit", "phase_span", "mark"})
+#: (``obs/timeline.py`` ``phase_span``/``sweep_span``/``mark``) — both
+#: are host clock reads + sink dispatch, equally wrong inside a traced body
+_EMIT_ATTRS = frozenset({"emit", "phase_span", "sweep_span", "mark"})
 
 
 def _module_imports_obs(imports: ImportMap) -> bool:

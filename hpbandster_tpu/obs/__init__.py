@@ -7,8 +7,8 @@ emit into (see docs/observability.md):
   fixed-bucket histograms with an atomic :meth:`MetricsRegistry.snapshot`;
 * :mod:`~hpbandster_tpu.obs.events` — the typed event bus
   (``job_submitted`` ... ``unknown_result``) plus monotonic-clock
-  :func:`span` regions, with ``utils/profiling.py`` as the optional
-  ``jax.profiler`` span backend;
+  :func:`span` regions (host-only; the fused driver's
+  :func:`sweep_span` in ``obs/timeline.py`` adds the profiler's clock);
 * :mod:`~hpbandster_tpu.obs.journal` — rotating JSONL run journal +
   in-memory ring buffer for post-mortems, identity-stamped via
   ``static_fields`` / ``configure(identity=...)``;
@@ -152,7 +152,6 @@ from hpbandster_tpu.obs.events import (  # noqa: F401
     get_bus,
     make_event,
     span,
-    use_jax_annotations,
 )
 from hpbandster_tpu.obs.export import (  # noqa: F401
     parse_prometheus_text,
@@ -179,6 +178,7 @@ from hpbandster_tpu.obs.metrics import (  # noqa: F401
 from hpbandster_tpu.obs.profile import (  # noqa: F401
     ProfileSession,
     device_peaks,
+    device_phase_map,
     format_roofline,
     get_profile_session,
     roofline_report,
@@ -199,6 +199,7 @@ from hpbandster_tpu.obs.runtime import (  # noqa: F401
 from hpbandster_tpu.obs.timeline import (  # noqa: F401
     ADMISSION,
     COMPILE,
+    DEVICE_SCOPES,
     PHASES,
     PROMOTION,
     RPC,
@@ -211,6 +212,7 @@ from hpbandster_tpu.obs.timeline import (  # noqa: F401
     format_critical_path,
     mark,
     phase_span,
+    sweep_span,
     to_chrome_trace,
 )
 from hpbandster_tpu.obs.trace import (  # noqa: F401
@@ -230,7 +232,6 @@ from hpbandster_tpu.obs.trace import (  # noqa: F401
 
 __all__ = [
     "Event", "EventBus", "emit", "make_event", "get_bus", "span",
-    "use_jax_annotations",
     "JsonlJournal", "RingBuffer", "read_journal", "process_identity",
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "get_metrics",
     "TraceContext", "new_trace", "current_trace", "use_trace",
@@ -257,6 +258,7 @@ __all__ = [
     "FleetCollector", "derive_fleet", "format_fleet_table", "read_series",
     "ProfileSession", "get_profile_session", "device_peaks",
     "roofline_report", "format_roofline",
+    "device_phase_map",
     "render_snapshot", "render_registry", "parse_prometheus_text",
     "configure", "set_enabled", "enabled",
     "EVENT_TYPES", "JOB_SUBMITTED", "JOB_STARTED", "JOB_FINISHED",
@@ -270,6 +272,7 @@ __all__ = [
     "DEVICE_TELEMETRY", "RPC_CLIENT_CALL",
     "PHASES", "ADMISSION", "COMPILE", "TRANSFER", "RUNG_COMPUTE",
     "PROMOTION", "RPC",
+    "DEVICE_SCOPES", "sweep_span",
     "phase_span", "mark", "TimelineRecorder", "align_clocks",
     "build_timeline", "to_chrome_trace", "critical_path",
     "format_critical_path",

@@ -14,11 +14,11 @@ Design constraints (the acceptance bar in docs/observability.md):
   for human-readable journal ordering (the same wall/mono split
   ``core.job.Job`` records).
 
-The span backend folds in ``utils/profiling.py``: after
-:func:`use_jax_annotations` every span additionally opens a
-``jax.profiler.TraceAnnotation`` so the named region shows up in
-TensorBoard/Perfetto device traces. Never emit events from INSIDE jitted
-code — that is host work in a traced body; the ``obs-emit-in-jit``
+Spans here are host-only and import no jax: the host-pool tiers
+(``parallel/``, ``serve/``) use them as they are. The fused driver's spans
+(``obs/timeline.py`` :func:`~hpbandster_tpu.obs.timeline.sweep_span`)
+additionally sit on the profiler's clock. Never emit events from INSIDE
+jitted code — that is host work in a traced body; the ``obs-emit-in-jit``
 graftlint rule gates the repo on it.
 """
 
@@ -40,7 +40,6 @@ __all__ = [
     "emit",
     "make_event",
     "span",
-    "use_jax_annotations",
     "EVENT_TYPES",
     "JOB_SUBMITTED",
     "JOB_STARTED",
@@ -275,43 +274,21 @@ def emit(name: str, **fields: Any) -> Optional[Event]:
 
 
 # ---------------------------------------------------------------- spans
-#: optional jax.profiler annotation factory (utils.profiling.annotate),
-#: installed by use_jax_annotations(); None = spans are host-only
-_ANNOTATE: Optional[Callable[[str], Any]] = None
-
-
-def use_jax_annotations(enable: bool = True) -> None:
-    """Fold ``utils/profiling.py`` in as the span backend: every span
-    additionally opens a ``jax.profiler.TraceAnnotation`` so named host
-    regions line up with device traces. Off by default (importing jax
-    from the obs layer must stay opt-in)."""
-    global _ANNOTATE
-    if enable:
-        from hpbandster_tpu.utils.profiling import annotate
-
-        _ANNOTATE = annotate
-    else:
-        _ANNOTATE = None
-
-
 @contextlib.contextmanager
 def span(name: str, bus: Optional[EventBus] = None, **fields: Any) -> Iterator[None]:
     """Monotonic-clock duration region: on exit, emits ``name`` with a
     ``duration_s`` field (plus ``error=<type>`` if the body raised).
 
-    Near-zero when inactive: with no sinks and no jax annotation backend
-    the body runs with no clock reads at all."""
+    Near-zero when inactive: with no sinks the body runs with no clock
+    reads at all."""
     target = bus if bus is not None else _DEFAULT_BUS
-    annotate = _ANNOTATE
-    if not target.active and annotate is None:
+    if not target.active:
         yield
         return
-    ctx = annotate(name) if annotate is not None else contextlib.nullcontext()
     t0 = time.monotonic()
     error: Optional[str] = None
     try:
-        with ctx:
-            yield
+        yield
     except BaseException as e:
         error = type(e).__name__
         raise

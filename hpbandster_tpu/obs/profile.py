@@ -34,6 +34,7 @@ rule); importing this module costs nothing.
 from __future__ import annotations
 
 import os
+import re
 import tempfile
 import threading
 import time
@@ -45,6 +46,8 @@ __all__ = [
     "ProfileSession",
     "get_profile_session",
     "device_peaks",
+    "device_phase_map",
+    "hlo_module_name",
     "roofline_report",
     "format_roofline",
     "transfer_summary",
@@ -393,3 +396,93 @@ def _fmtnum(v: Any) -> str:
     if not isinstance(v, (int, float)) or isinstance(v, bool):
         return "-"
     return f"{float(v):.3g}"
+
+
+# ---------------------------------------------------- device phase map
+_HLO_MODULE = re.compile(r"^HloModule ([^\s,]+)")
+_HLO_COMPUTATION = re.compile(r"^(ENTRY )?%?([\w.\-]+) \(.*\{$")
+_HLO_INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%?([\w.\-]+) = ")
+_HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_HLO_CALLEES = re.compile(
+    r"(?:calls|to_apply|body|condition|true_computation|false_computation)"
+    r"=%?([\w.\-]+)"
+)
+_HLO_BRANCHES = re.compile(r"branch_computations=\{([^}]*)\}")
+_SCOPE_NAME = re.compile(r"hpb\.[a-z_]+")
+
+
+def _hlo_text(compiled: Any) -> str:
+    """A compiled executable's optimized HLO, or the text itself."""
+    return compiled if isinstance(compiled, str) else compiled.as_text()
+
+
+def hlo_module_name(compiled: Any) -> str:
+    """``jit_hpb_sweep`` of ``HloModule jit_hpb_sweep, ...``: the name a profiler
+    trace's ``XLA Modules`` events carry (there followed by ``(<id>)``)."""
+    match = _HLO_MODULE.match(_hlo_text(compiled))
+    if match is None:
+        raise ValueError("not the text of an HLO module")
+    return match.group(1)
+
+
+def device_phase_map(compiled: Any) -> Dict[str, str]:
+    """``{instruction name: phase}`` of one compiled program, read off its
+    optimized HLO text (``compiled.as_text()``; the text itself is taken
+    too).
+
+    A profiler trace prints device operations by the compiler's
+    instruction names (``multiply_subtract_fusion.546``), which change with
+    every edit and carry no metadata there. The same names are in the
+    compiled text, each with the ``op_name`` its ``jax.named_scope`` left:
+    an instruction's phase is the scope of
+    :data:`~hpbandster_tpu.obs.timeline.DEVICE_SCOPES` found in it
+    (``jit(sweep)/vmap(hpb.train)/while/body/...`` is ``hpb.train``; the
+    scopes are flat, so there is at most one, and the last would win). An
+    instruction without one inside a nested computation — a loop's body,
+    a fusion, a reducer — inherits its caller's. Instructions that stay
+    without a phase are left out. Names lose their ``%``.
+
+    Parsing a large program's text takes seconds: call this on demand,
+    never on a sweep's path."""
+    from hpbandster_tpu.obs.timeline import DEVICE_SCOPES
+
+    computations: Dict[str, List[Any]] = {}
+    entry, current = None, None
+    for line in _hlo_text(compiled).splitlines():
+        header = _HLO_COMPUTATION.match(line)
+        if header is not None:
+            current = computations.setdefault(header.group(2), [])
+            if header.group(1):
+                entry = header.group(2)
+            continue
+        instruction = _HLO_INSTRUCTION.match(line)
+        if instruction is None or current is None:
+            continue
+        op_name = _HLO_OP_NAME.search(line)
+        scopes = [
+            s for s in _SCOPE_NAME.findall(op_name.group(1))
+            if s in DEVICE_SCOPES
+        ] if op_name else []
+        callees = _HLO_CALLEES.findall(line)
+        for group in _HLO_BRANCHES.findall(line):
+            callees += [c.strip().lstrip("%") for c in group.split(",")]
+        current.append(
+            (instruction.group(1), scopes[-1] if scopes else None, callees)
+        )
+    if entry is None:
+        raise ValueError("the compiled text has no ENTRY computation")
+
+    phases: Dict[str, str] = {}
+    inherited: Dict[str, Optional[str]] = {entry: None}
+    queue = [entry]
+    while queue:
+        name = queue.pop()
+        for instruction, own, callees in computations.get(name, ()):
+            phase = own or inherited[name]
+            if phase is not None:
+                phases[instruction] = phase
+            for callee in callees:
+                if callee not in inherited:
+                    inherited[callee] = phase
+                    queue.append(callee)
+    return phases

@@ -40,17 +40,22 @@ Recording discipline: the span API below (:func:`phase_span`,
 attached, and NEVER legal inside a jitted function (the
 ``obs-emit-in-jit`` graftlint rule covers these names too). With the
 recorder off, behavior is byte-identical to not having it: no clock
-reads, no event construction.
+reads, no event construction. :func:`sweep_span` is the fused driver's
+variant: always on the profiler's clock and always measured, because the
+fused tier has jax loaded by definition and runs a few dozen spans a
+sweep, never one per evaluation.
 """
 
 from __future__ import annotations
 
 import statistics
+import time
 import zlib
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 from hpbandster_tpu.obs import events as E
 from hpbandster_tpu.obs.journal import event_to_record, process_identity
+from hpbandster_tpu.obs.trace import TraceContext, use_trace
 
 __all__ = [
     "ADMISSION",
@@ -61,7 +66,10 @@ __all__ = [
     "KDE_REFIT",
     "RPC",
     "PHASES",
+    "SPAN_PREFIX",
+    "DEVICE_SCOPES",
     "phase_span",
+    "sweep_span",
     "mark",
     "TimelineRecorder",
     "clock_offsets",
@@ -94,6 +102,28 @@ RPC = "rpc"
 #: critical-path table cannot silently grow unaggregatable rows
 PHASES = (ADMISSION, COMPILE, TRANSFER, RUNG_COMPUTE, PROMOTION,
           KDE_REFIT, RPC)
+
+#: prefix of the fused driver's spans in a profiler trace (``hpb:run``,
+#: ``hpb:fetch``, ...): what a trace reader selects the program's own
+#: host spans by
+SPAN_PREFIX = "hpb:"
+
+#: the closed list of ``jax.named_scope`` names inside the fused sweep's
+#: device program, flat (never nested in each other). A compiled
+#: instruction's phase is the scope found in its ``op_name``
+#: (``obs.profile.device_phase_map``); a metric follows "the trainer" or
+#: "the promotion" by these names from PR to PR, whatever the compiler
+#: calls its fusions
+DEVICE_SCOPES = (
+    "hpb.sample",      # random and model-based proposals, quantisation, masks
+    "hpb.kde_fit",     # good/bad split, imputation, bandwidths
+    "hpb.kde_score",   # acquisition scoring, Pallas and XLA alike
+    "hpb.train",       # the evaluation: the ensemble's SGD steps
+    "hpb.validate",    # the loss pass that ends a rung
+    "hpb.promote",     # rank key, masked top-k, gather of survivors' state
+    "hpb.obs_update",  # folding results into observation/output buffers
+    "hpb.incumbent",   # the cross-bracket incumbent fold
+)
 
 #: attribution priority when concurrent spans overlap (lower = wins):
 #: device/eval work is the sweep's purpose, so overhead phases only
@@ -128,33 +158,88 @@ _STAGE_PHASE = (
 
 
 # --------------------------------------------------------- timeline span API
+def _check_phase(phase: str) -> None:
+    """Unknown phases raise: the critical-path table cannot silently grow
+    rows nothing aggregates."""
+    if phase not in _PHASE_PRIORITY:
+        raise ValueError(
+            f"unknown phase {phase!r}; expected one of {PHASES}"
+        )
+
+
 def phase_span(name: str, phase: str, **fields: Any):
     """A named duration region pre-attributed to one of :data:`PHASES`.
 
     Thin wrapper over :func:`obs.events.span` that stamps the ``phase``
     field the critical-path analyzer attributes by — same near-zero
-    inactive path (no sinks + no jax annotation backend = no clock
-    reads), same monotonic measurement, same ban on use inside jitted
+    inactive path (no sinks = no clock reads), same monotonic
+    measurement, same ban on use inside jitted
     code (``obs-emit-in-jit``). Returns the span context manager
     directly rather than wrapping it in a second generator frame: the
     validation happens once at call time, so the inactive ``with`` costs
     ONE context frame, not two (bench_timeline_overhead measures this
     path)."""
-    if phase not in _PHASE_PRIORITY:
-        raise ValueError(
-            f"unknown phase {phase!r}; expected one of {PHASES}"
-        )
+    _check_phase(phase)
     return E.span(name, phase=phase, **fields)
+
+
+class sweep_span:
+    """One phase of a fused sweep, on three clocks at once.
+
+    Always: a ``jax.profiler.TraceAnnotation`` named ``hpb:<name>`` (a
+    flag test when no profiler session is active), so the region lies on
+    the device trace's clock and every idle gap of the device has the
+    program's own name; and the monotonic duration added to
+    ``totals[name]`` — the chunk's ``run_stats`` row ``phase_s`` — so a
+    sweep's breakdown exists with no profiler and no sink. With a sink:
+    the same journal event :func:`phase_span` emits (``duration_s``,
+    ``phase``, ``error`` if the body raised), stamped with the current
+    trace — ``trace`` if given, so that a sweep's spans share its
+    ``trace_id`` while the per-evaluation records replayed inside them
+    keep the ambient one. ``totals`` may be re-pointed before exit
+    (``with ... as s: s.totals = row``): the seconds land where it points
+    then.
+
+    For the fused tier only — it imports jax, which the host-pool tiers'
+    :func:`phase_span` must not. Never inside jitted code."""
+
+    __slots__ = ("name", "phase", "totals", "trace", "fields",
+                 "_annotation", "_t0")
+
+    def __init__(self, name: str, phase: str,
+                 totals: Optional[Dict[str, float]] = None,
+                 trace: Optional[TraceContext] = None, **fields: Any):
+        _check_phase(phase)
+        self.name, self.phase = name, phase
+        self.totals, self.trace, self.fields = totals, trace, fields
+
+    def __enter__(self) -> "sweep_span":
+        from jax.profiler import TraceAnnotation
+
+        self._annotation = TraceAnnotation(SPAN_PREFIX + self.name)
+        self._annotation.__enter__()
+        self._t0 = time.monotonic()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        duration = time.monotonic() - self._t0
+        self._annotation.__exit__(exc_type, exc, tb)
+        if self.totals is not None:
+            self.totals[self.name] = self.totals.get(self.name, 0.0) + duration
+        if E.get_bus().active:
+            fields = self.fields
+            if exc_type is not None:
+                fields = dict(fields, error=exc_type.__name__)
+            with use_trace(self.trace):
+                E.emit(self.name, duration_s=round(duration, 6),
+                       phase=self.phase, **fields)
 
 
 def mark(name: str, phase: str, **fields: Any) -> Optional[E.Event]:
     """Emit one instant timeline event attributed to ``phase`` — the
     point-in-time sibling of :func:`phase_span` (no-op without a sink,
     like every emit; never legal inside jitted code)."""
-    if phase not in _PHASE_PRIORITY:
-        raise ValueError(
-            f"unknown phase {phase!r}; expected one of {PHASES}"
-        )
+    _check_phase(phase)
     return E.emit(name, phase=phase, **fields)
 
 

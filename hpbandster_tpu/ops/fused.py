@@ -205,8 +205,14 @@ def fused_sh_bracket(
     if n_rows < n0:
         raise ValueError(f"need >= {n0} stage-0 vectors, got {n_rows}")
 
+    # device phase names (obs.timeline.DEVICE_SCOPES): a stateless
+    # evaluation is "the trainer" whole; a StatefulEval names its own
+    # training and validation (workloads/ensemble.py)
     def eval_stage(vecs: jax.Array, budget: float) -> jax.Array:
-        return jax.vmap(lambda v: eval_fn(v, budget))(vecs).astype(jnp.float32)
+        with jax.named_scope("hpb.train"):
+            return jax.vmap(lambda v: eval_fn(v, budget))(vecs).astype(
+                jnp.float32
+            )
 
     def rank_key(scores: jax.Array, is_pad: jax.Array) -> jax.Array:
         key = jnp.where(jnp.isnan(scores), _CRASH_RANK, scores)
@@ -243,36 +249,41 @@ def fused_sh_bracket(
         losses0 = losses0.astype(jnp.float32)
     else:
         losses0 = eval_stage(vectors, float(budgets[0]))
-    cur_idx = jnp.arange(n_rows, dtype=jnp.int32)
-    history = [losses0]  # per-stage losses of the CURRENT survivor set
-    cur_key = rank_key(scores_for(history, 0), cur_idx >= n0)
-    out = [(jnp.arange(n0, dtype=jnp.int32), losses0[:n0])]
+    with jax.named_scope("hpb.promote"):
+        cur_idx = jnp.arange(n_rows, dtype=jnp.int32)
+        history = [losses0]  # per-stage losses of the CURRENT survivor set
+        cur_key = rank_key(scores_for(history, 0), cur_idx >= n0)
+        out = [(jnp.arange(n0, dtype=jnp.int32), losses0[:n0])]
 
     for s in range(1, len(num_configs)):
         k = int(num_configs[s])
-        _, top = jax.lax.top_k(-cur_key, k)
-        top = jnp.sort(top)  # preserve original ordering among survivors
-        sel_idx = cur_idx[top]
-        sel_vecs = shard_rows(vectors[sel_idx], mesh, axis)
+        with jax.named_scope("hpb.promote"):
+            _, top = jax.lax.top_k(-cur_key, k)
+            top = jnp.sort(top)  # preserve original ordering among survivors
+            sel_idx = cur_idx[top]
+            sel_vecs = shard_rows(vectors[sel_idx], mesh, axis)
+            if stateful is not None:
+                # warm continuation: gather the SURVIVING lanes' live state
+                # by the same local top-k indices the rank just promoted;
+                # evicted lanes simply drop out of the gather
+                state = _shard_state(
+                    jax.tree.map(lambda leaf: leaf[top], state), mesh, axis
+                )
         if stateful is not None:
-            # warm continuation: gather the SURVIVING lanes' live state by
-            # the same local top-k indices the rank just promoted, then
-            # train only the incremental budget from where they left off —
-            # evicted lanes simply drop out of the gather
-            state = _shard_state(
-                jax.tree.map(lambda leaf: leaf[top], state), mesh, axis
-            )
+            # ... then train only the incremental budget from where the
+            # survivors left off
             state, losses_s = stateful.step_fn(
                 state, sel_vecs, float(budgets[s]), float(budgets[s - 1])
             )
             losses_s = losses_s.astype(jnp.float32)
         else:
             losses_s = eval_stage(sel_vecs, float(budgets[s]))
-        cur_idx = sel_idx
-        history = [col[top] for col in history] + [losses_s]
-        cur_key = rank_key(
-            scores_for(history, s), jnp.zeros_like(sel_idx, dtype=bool)
-        )
+        with jax.named_scope("hpb.promote"):
+            cur_idx = sel_idx
+            history = [col[top] for col in history] + [losses_s]
+            cur_key = rank_key(
+                scores_for(history, s), jnp.zeros_like(sel_idx, dtype=bool)
+            )
         out.append((cur_idx, losses_s))
     if return_final_state:
         return out, state

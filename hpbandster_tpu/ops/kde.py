@@ -205,23 +205,29 @@ def propose(
     ``log l(x) - log g(x)`` with both log-densities floored at
     ``LOG_PDF_FLOOR`` exactly like the reference's ``max(1e-32, pdf)`` clamp.
     """
-    k_idx, k_samp = jax.random.split(key)
-    logits = jnp.where(good.mask > 0, 0.0, -jnp.inf)
-    idx = jax.random.categorical(k_idx, logits, shape=(num_samples,))
-    data = good.data[idx]  # [S, d]
+    # device phase names (obs.timeline.DEVICE_SCOPES): generating the
+    # candidates is sampling, ranking them is the scorer
+    with jax.named_scope("hpb.sample"):
+        k_idx, k_samp = jax.random.split(key)
+        logits = jnp.where(good.mask > 0, 0.0, -jnp.inf)
+        idx = jax.random.categorical(k_idx, logits, shape=(num_samples,))
+        data = good.data[idx]  # [S, d]
 
-    keys = jax.random.split(k_samp, num_samples)
-    cands = jax.vmap(
-        lambda k, x: sample_around(
-            k, x, good.bw, vartypes, cards, bandwidth_factor, min_bandwidth
+        keys = jax.random.split(k_samp, num_samples)
+        cands = jax.vmap(
+            lambda k, x: sample_around(
+                k, x, good.bw, vartypes, cards, bandwidth_factor,
+                min_bandwidth,
+            )
+        )(keys, data)
+
+    with jax.named_scope("hpb.kde_score"):
+        lg = jax.vmap(lambda c: kde_logpdf(c, good, vartypes, cards))(cands)
+        lb = jax.vmap(lambda c: kde_logpdf(c, bad, vartypes, cards))(cands)
+        scores = (
+            jnp.maximum(lg, LOG_PDF_FLOOR) - jnp.maximum(lb, LOG_PDF_FLOOR)
         )
-    )(keys, data)
-
-    lg = jax.vmap(lambda c: kde_logpdf(c, good, vartypes, cards))(cands)
-    lb = jax.vmap(lambda c: kde_logpdf(c, bad, vartypes, cards))(cands)
-    scores = jnp.maximum(lg, LOG_PDF_FLOOR) - jnp.maximum(lb, LOG_PDF_FLOOR)
-
-    best = cands[jnp.argmax(scores)]
+        best = cands[jnp.argmax(scores)]
     return best, cands, scores
 
 
@@ -237,15 +243,17 @@ def generate_candidates(
     """``total`` perturbed-good-point candidates, ``f32[total, d]`` — the
     generation half of the BOHB proposal, shared by the seeded host entry
     point and the fused-sweep tracer so the sampling scheme has one home."""
-    k_idx, k_samp = jax.random.split(key)
-    logits = jnp.where(good.mask > 0, 0.0, -jnp.inf)
-    idx = jax.random.categorical(k_idx, logits, shape=(total,))
-    keys = jax.random.split(k_samp, total)
-    return jax.vmap(
-        lambda k, x: sample_around(
-            k, x, good.bw, vartypes, cards, bandwidth_factor, min_bandwidth
-        )
-    )(keys, good.data[idx])
+    with jax.named_scope("hpb.sample"):
+        k_idx, k_samp = jax.random.split(key)
+        logits = jnp.where(good.mask > 0, 0.0, -jnp.inf)
+        idx = jax.random.categorical(k_idx, logits, shape=(total,))
+        keys = jax.random.split(k_samp, total)
+        return jax.vmap(
+            lambda k, x: sample_around(
+                k, x, good.bw, vartypes, cards, bandwidth_factor,
+                min_bandwidth,
+            )
+        )(keys, good.data[idx])
 
 
 @partial(tracked_jit, static_argnames=("n", "num_samples"))
@@ -404,26 +412,6 @@ def fit_kde_pair_masked(
     numeric consumer (one-pass variance), so it is opt-in behind the
     flag; the split/sort half is unchanged either way.
     """
-    cap = vecs.shape[0]
-    order = jnp.argsort(losses, stable=True)  # +inf pads sort last
-    sorted_v = vecs[order]
-    rank = jnp.arange(cap, dtype=jnp.int32)
-    good_mask = rank < n_good
-    bad_mask = (rank >= count - n_bad) & (rank < count)
-    if impute_key is not None:
-        # conditional spaces: donor-impute each split side exactly like the
-        # static path, with non-members NaN'd out so they neither donate
-        # nor constrain (their filled values are then masked from the fit)
-        kg, kb = jax.random.split(impute_key)
-        good_data = impute_conditional_masked(
-            kg, jnp.where(good_mask[:, None], sorted_v, jnp.nan), cards
-        )
-        bad_data = impute_conditional_masked(
-            kb, jnp.where(bad_mask[:, None], sorted_v, jnp.nan), cards
-        )
-    else:
-        good_data = bad_data = sorted_v
-
     env = _pallas_fit_requested()
     pallas_fit = bool(use_pallas_fit) if env is None else env
 
@@ -445,7 +433,28 @@ def fit_kde_pair_masked(
             )
         return KDE(data, mask, bw)
 
-    return mk(good_data, good_mask), mk(bad_data, bad_mask)
+    with jax.named_scope("hpb.kde_fit"):
+        cap = vecs.shape[0]
+        order = jnp.argsort(losses, stable=True)  # +inf pads sort last
+        sorted_v = vecs[order]
+        rank = jnp.arange(cap, dtype=jnp.int32)
+        good_mask = rank < n_good
+        bad_mask = (rank >= count - n_bad) & (rank < count)
+        if impute_key is not None:
+            # conditional spaces: donor-impute each split side exactly like
+            # the static path, with non-members NaN'd out so they neither
+            # donate nor constrain (their filled values are then masked
+            # from the fit)
+            kg, kb = jax.random.split(impute_key)
+            good_data = impute_conditional_masked(
+                kg, jnp.where(good_mask[:, None], sorted_v, jnp.nan), cards
+            )
+            bad_data = impute_conditional_masked(
+                kb, jnp.where(bad_mask[:, None], sorted_v, jnp.nan), cards
+            )
+        else:
+            good_data = bad_data = sorted_v
+        return mk(good_data, good_mask), mk(bad_data, bad_mask)
 
 
 # the observation buffers are rebuilt host-side per refit and never reread

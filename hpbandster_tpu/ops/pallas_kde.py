@@ -210,26 +210,27 @@ def pallas_score_candidates(
     """
     from hpbandster_tpu.parallel.mesh import shard_count
 
-    cands = jnp.asarray(cands, jnp.float32)
-    s, d = cands.shape
-    n_shards = shard_count(mesh, axis)
-    s_pad = _round_up(s, _TILE_S * n_shards)
-    cpad = jnp.pad(cands, ((0, s_pad - s), (0, _round_up(d, _LANE) - d)))
-    gpar, goodT, gmask = _prep_kde(good, vartypes, cards)
-    bpar, badT, bmask = _prep_kde(bad, vartypes, cards)
-
     def score(cpad, gpar, goodT, gmask, bpar, badT, bmask):
         lg = _logpdf_padded(gpar, cpad, goodT, gmask, interpret=interpret)
         lb = _logpdf_padded(bpar, cpad, badT, bmask, interpret=interpret)
         return jnp.maximum(lg, LOG_PDF_FLOOR) - jnp.maximum(lb, LOG_PDF_FLOOR)
 
-    if mesh is not None:
-        rows = P(axis) if n_shards > 1 else P()
-        score = jax.shard_map(
-            score, mesh=mesh, in_specs=(rows,) + (P(),) * 6,
-            out_specs=rows, check_vma=False,
-        )
-    return score(cpad, gpar, goodT, gmask, bpar, badT, bmask)[:s, 0]
+    # the same device phase name as the XLA scorer in ops.kde.propose
+    with jax.named_scope("hpb.kde_score"):
+        cands = jnp.asarray(cands, jnp.float32)
+        s, d = cands.shape
+        n_shards = shard_count(mesh, axis)
+        s_pad = _round_up(s, _TILE_S * n_shards)
+        cpad = jnp.pad(cands, ((0, s_pad - s), (0, _round_up(d, _LANE) - d)))
+        gpar, goodT, gmask = _prep_kde(good, vartypes, cards)
+        bpar, badT, bmask = _prep_kde(bad, vartypes, cards)
+        if mesh is not None:
+            rows = P(axis) if n_shards > 1 else P()
+            score = jax.shard_map(
+                score, mesh=mesh, in_specs=(rows,) + (P(),) * 6,
+                out_specs=rows, check_vma=False,
+            )
+        return score(cpad, gpar, goodT, gmask, bpar, badT, bmask)[:s, 0]
 
 
 def pallas_propose_batch(
@@ -266,9 +267,10 @@ def pallas_propose_batch(
     scores = pallas_score_candidates(
         cands, good, bad, vartypes, cards, interpret=interpret,
         mesh=mesh, axis=axis,
-    ).reshape(n, num_samples)
-    best = jnp.argmax(scores, axis=1)
-    return cands.reshape(n, num_samples, -1)[jnp.arange(n), best]
+    )
+    with jax.named_scope("hpb.kde_score"):
+        best = jnp.argmax(scores.reshape(n, num_samples), axis=1)
+        return cands.reshape(n, num_samples, -1)[jnp.arange(n), best]
 
 
 @functools.partial(

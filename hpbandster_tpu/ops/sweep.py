@@ -783,21 +783,21 @@ def _fit_kde_pair_device(
     top ``n_good`` / bottom ``n_bad`` rows, normal-reference bandwidths.
     Pass ``impute_key`` for conditional spaces — NaN (inactive) dims are
     then donor-imputed per split side, like the host model."""
-    n = vecs.shape[0]
-    order = jnp.argsort(losses, stable=True)
-    good = vecs[order[:n_good]]
-    bad = vecs[order[n - n_bad:]]
-    if impute_key is not None:
-        kg, kb = jax.random.split(impute_key)
-        good = _impute_conditional_device(kg, good, cards)
-        bad = _impute_conditional_device(kb, bad, cards)
-
     def mk(data: jax.Array) -> KDE:
         mask = jnp.ones(data.shape[0], jnp.float32)
         bw = normal_reference_bandwidths(data, mask, cards, min_bandwidth)
         return KDE(data, mask, bw)
 
-    return mk(good), mk(bad)
+    with jax.named_scope("hpb.kde_fit"):
+        n = vecs.shape[0]
+        order = jnp.argsort(losses, stable=True)
+        good = vecs[order[:n_good]]
+        bad = vecs[order[n - n_bad:]]
+        if impute_key is not None:
+            kg, kb = jax.random.split(impute_key)
+            good = _impute_conditional_device(kg, good, cards)
+            bad = _impute_conditional_device(kb, bad, cards)
+        return mk(good), mk(bad)
 
 
 #: the traced-count fit moved to ops/kde.py (fit_kde_pair_masked) so the
@@ -1069,7 +1069,8 @@ def make_fused_sweep_fn(
                 num_samples, bandwidth_factor, min_bandwidth,
                 pallas_interpret, mesh=mesh, axis=axis,
             )
-        keys = jax.random.split(k_prop, n0)
+        with jax.named_scope("hpb.sample"):
+            keys = jax.random.split(k_prop, n0)
         return jax.vmap(
             lambda k: propose(
                 k, good, bad, vartypes_dev, cards_dev,
@@ -1100,30 +1101,32 @@ def make_fused_sweep_fn(
         on empty buffers (harmless, NaN-free) and ``mb_mask`` discards
         every model pick — matching the static path's all-random bracket.
         """
-        sel_v = jnp.zeros((capmax, d), jnp.float32)
-        sel_l = jnp.full((capmax,), jnp.inf, jnp.float32)
-        sel_n = jnp.zeros((), jnp.int32)
-        any_model = jnp.zeros((), bool)
-        for b in sorted(caps, reverse=True):
-            has, _, _ = dynamic_gate(counts[b])
-            take = has & ~any_model
-            pad = capmax - caps[b]
-            pv = jnp.pad(obs_v[b], ((0, pad), (0, 0)))
-            pl = jnp.pad(obs_l[b], (0, pad), constant_values=jnp.inf)
-            sel_v = jnp.where(take, pv, sel_v)
-            sel_l = jnp.where(take, pl, sel_l)
-            sel_n = jnp.where(take, counts[b], sel_n)
-            any_model = any_model | has
-        _, n_good, n_bad = dynamic_gate(sel_n)
+        with jax.named_scope("hpb.kde_fit"):
+            sel_v = jnp.zeros((capmax, d), jnp.float32)
+            sel_l = jnp.full((capmax,), jnp.inf, jnp.float32)
+            sel_n = jnp.zeros((), jnp.int32)
+            any_model = jnp.zeros((), bool)
+            for b in sorted(caps, reverse=True):
+                has, _, _ = dynamic_gate(counts[b])
+                take = has & ~any_model
+                pad = capmax - caps[b]
+                pv = jnp.pad(obs_v[b], ((0, pad), (0, 0)))
+                pl = jnp.pad(obs_l[b], (0, pad), constant_values=jnp.inf)
+                sel_v = jnp.where(take, pv, sel_v)
+                sel_l = jnp.where(take, pl, sel_l)
+                sel_n = jnp.where(take, counts[b], sel_n)
+                any_model = any_model | has
+            _, n_good, n_bad = dynamic_gate(sel_n)
         good, bad = _fit_kde_pair_dynamic(
             sel_v, sel_l, sel_n, n_good, n_bad, cards_dev, min_bandwidth,
             impute_key=k_fit if active_mask_fn is not None else None,
         )
         model_vecs = _propose_model_vecs(good, bad, k_prop, n0)
-        mb_mask = any_model & (
-            jax.random.uniform(k_frac, (n0,)) >= random_fraction
-        )
-        proposals = jnp.where(mb_mask[:, None], model_vecs, rand_vecs)
+        with jax.named_scope("hpb.sample"):
+            mb_mask = any_model & (
+                jax.random.uniform(k_frac, (n0,)) >= random_fraction
+            )
+            proposals = jnp.where(mb_mask[:, None], model_vecs, rand_vecs)
         # any_model rides along for the metrics plane: it is the traced
         # twin of "a KDE refit ran with an open gate this bracket"
         return proposals, mb_mask, any_model
@@ -1233,16 +1236,19 @@ def make_fused_sweep_fn(
         """
         obs_v, obs_l, counts = dict(obs_v), dict(obs_l), dict(counts)
         n0 = plan.num_configs[0]
-        k_rand, k_prop, k_frac, k_fit = jax.random.split(
-            jax.random.fold_in(key, b_i), 4
-        )
-        # per-shard derivation under shard_sampling: each shard's rows
-        # come from its own folded key, so generation stays local to
-        # the owning device (n_shards == 1 falls through to the
-        # unfolded base key — the 1-device-mesh bit-parity contract)
-        rand_vecs = random_unit_sharded(codec, k_rand, n0, n_shards)
-        if n_shards > 1:
-            rand_vecs = shard_rows(rand_vecs, mesh, axis)
+        # the scopes below (obs.timeline.DEVICE_SCOPES) are flat, never
+        # nested: an instruction's op_name holds at most one of them
+        with jax.named_scope("hpb.sample"):
+            k_rand, k_prop, k_frac, k_fit = jax.random.split(
+                jax.random.fold_in(key, b_i), 4
+            )
+            # per-shard derivation under shard_sampling: each shard's rows
+            # come from its own folded key, so generation stays local to
+            # the owning device (n_shards == 1 falls through to the
+            # unfolded base key — the 1-device-mesh bit-parity contract)
+            rand_vecs = random_unit_sharded(codec, k_rand, n0, n_shards)
+            if n_shards > 1:
+                rand_vecs = shard_rows(rand_vecs, mesh, axis)
 
         #: metrics-plane KDE gate flag for this bracket: traced under the
         #: dynamic tier (the gate is count-arithmetic), concrete 0/1 on
@@ -1281,13 +1287,67 @@ def make_fused_sweep_fn(
                     impute_key=k_fit if active_mask_fn is not None else None,
                 )
                 model_vecs = _propose_model_vecs(good, bad, k_prop, n0)
-                mb_mask = (
-                    jax.random.uniform(k_frac, (n0,)) >= random_fraction
-                )
-                proposals = jnp.where(
-                    mb_mask[:, None], model_vecs, rand_vecs
-                )
+                with jax.named_scope("hpb.sample"):
+                    mb_mask = (
+                        jax.random.uniform(k_frac, (n0,)) >= random_fraction
+                    )
+                    proposals = jnp.where(
+                        mb_mask[:, None], model_vecs, rand_vecs
+                    )
 
+        with jax.named_scope("hpb.sample"):
+            mb_mask, eval_vectors, out_vectors = finish_sample(
+                proposals, mb_mask, k_rand, n0
+            )
+
+        stages = fused_sh_bracket(
+            eval_fn, eval_vectors, plan.num_configs, plan.budgets,
+            rank_fn=rank_fn,
+            # per-stage sharding constraints: the rung ladder's
+            # survivor batches stay distributed over the config axis
+            # (promotion masks reduce across shards on-device)
+            mesh=mesh if shard_sampling else None, axis=axis,
+            # warm-continuation seam: the bracket's live training states
+            # stay device-internal (bracket-local scratch, never carried)
+            stateful=stateful_eval,
+        )
+
+        with jax.named_scope("hpb.obs_update"):
+            for (idx_s, losses_s), k_s, budget in zip(
+                stages, plan.num_configs, plan.budgets
+            ):
+                b = float(budget)
+                c = counts[b]
+                upd_l = jnp.where(jnp.isnan(losses_s), jnp.inf, losses_s)
+                if dynamic_counts:
+                    obs_v[b] = jax.lax.dynamic_update_slice_in_dim(
+                        obs_v[b], out_vectors[idx_s], c, 0
+                    )
+                    obs_l[b] = jax.lax.dynamic_update_slice_in_dim(
+                        obs_l[b], upd_l, c, 0
+                    )
+                else:
+                    obs_v[b] = obs_v[b].at[c:c + k_s].set(out_vectors[idx_s])
+                    obs_l[b] = obs_l[b].at[c:c + k_s].set(upd_l)
+                counts[b] = c + k_s
+            if metrics is not None:
+                metrics = fold_metrics(metrics, b_i, plan, stages, fit_flag)
+
+        out = None
+        if incumbent_only:
+            with jax.named_scope("hpb.incumbent"):
+                inc = fold_incumbent(inc, b_i, stages, out_vectors)
+        else:
+            with jax.named_scope("hpb.obs_update"):
+                idx_packed, loss_packed = _pack_stages(stages)
+                out = SweepBracketOutput(
+                    out_vectors[:n0], mb_mask, idx_packed, loss_packed
+                )
+        return obs_v, obs_l, counts, inc, metrics, out
+
+    def finish_sample(proposals, mb_mask, k_rand, n0):
+        """Quantisation, forbidden resampling and the activity mask: from
+        raw proposals to ``(mb_mask, eval_vectors, out_vectors)``."""
         vectors = quantize_unit(codec, proposals)
 
         if forbidden_fn is not None:
@@ -1344,108 +1404,78 @@ def make_fused_sweep_fn(
         # (config, model) mesh with a 9-row bracket), and shard_rows is
         # the one place that divisibility policy lives
         eval_vectors = shard_rows(eval_vectors, mesh, axis)
+        return mb_mask, eval_vectors, out_vectors
 
-        stages = fused_sh_bracket(
-            eval_fn, eval_vectors, plan.num_configs, plan.budgets,
-            rank_fn=rank_fn,
-            # per-stage sharding constraints: the rung ladder's
-            # survivor batches stay distributed over the config axis
-            # (promotion masks reduce across shards on-device)
-            mesh=mesh if shard_sampling else None, axis=axis,
-            # warm-continuation seam: the bracket's live training states
-            # stay device-internal (bracket-local scratch, never carried)
-            stateful=stateful_eval,
+    def fold_metrics(metrics, b_i, plan, stages, fit_flag):
+        """One bracket's rungs into the :class:`DeviceMetrics` carry."""
+        # metrics plane: per-rung histograms / crash counts plus the
+        # per-bracket refit flag and best final loss, all written at
+        # row b_i (concrete OR traced — the resident/unrolled parity
+        # contract extends to telemetry). O(n) binning per stage is
+        # trivial next to the stage evaluation it accompanies; the
+        # carried arrays are O(schedule), never O(configs).
+        m_hist, m_ev, m_cr, m_pr, m_sq = (
+            metrics.loss_hist, metrics.evals, metrics.crashes,
+            metrics.promotions, metrics.rung_seq,
+        )
+        depth = len(plan.num_configs)
+        for s, ((_idx_s, losses_s), k_s) in enumerate(
+            zip(stages, plan.num_configs)
+        ):
+            h_s, c_s = stage_telemetry(losses_s, dm_edges)
+            m_hist = m_hist.at[b_i, s].set(h_s)
+            m_ev = m_ev.at[b_i, s].set(k_s)
+            m_cr = m_cr.at[b_i, s].set(c_s)
+            m_pr = m_pr.at[b_i, s].set(
+                plan.num_configs[s + 1] if s + 1 < depth else 0
+            )
+            # global execution-order stamp: static per-bracket base
+            # (gathered at the concrete-or-traced b_i) + the stage
+            # offset — monotonically increasing over the whole
+            # schedule, resident rounds included
+            m_sq = m_sq.at[b_i, s].set(dm_seq_base[b_i] + s)
+        _, loss_fin = stages[-1]
+        key_fin = jnp.where(jnp.isnan(loss_fin), _CRASH_RANK, loss_fin)
+        return DeviceMetrics(
+            loss_hist=m_hist, evals=m_ev, crashes=m_cr,
+            promotions=m_pr,
+            model_fits=metrics.model_fits.at[b_i].set(fit_flag),
+            best_final=metrics.best_final.at[b_i].set(
+                loss_fin[jnp.argmin(key_fin)]
+            ),
+            rung_seq=m_sq,
         )
 
-        for (idx_s, losses_s), k_s, budget in zip(
-            stages, plan.num_configs, plan.budgets
-        ):
-            b = float(budget)
-            c = counts[b]
-            upd_l = jnp.where(jnp.isnan(losses_s), jnp.inf, losses_s)
-            if dynamic_counts:
-                obs_v[b] = jax.lax.dynamic_update_slice_in_dim(
-                    obs_v[b], out_vectors[idx_s], c, 0
-                )
-                obs_l[b] = jax.lax.dynamic_update_slice_in_dim(
-                    obs_l[b], upd_l, c, 0
-                )
-            else:
-                obs_v[b] = obs_v[b].at[c:c + k_s].set(out_vectors[idx_s])
-                obs_l[b] = obs_l[b].at[c:c + k_s].set(upd_l)
-            counts[b] = c + k_s
+    def fold_incumbent(inc, b_i, stages, out_vectors):
+        """Only the winner leaves the device loop: reduce the final
+        (largest-budget) stage to its best row and fold it into the
+        running cross-bracket incumbent — crashed (NaN) rows rank behind
+        every real loss via the shared crash rank."""
+        best_key, best_loss, best_vec, best_bracket, per_bracket = inc
+        idx_f, loss_f = stages[-1]
+        key_f = jnp.where(jnp.isnan(loss_f), _CRASH_RANK, loss_f)
+        a = jnp.argmin(key_f)
+        cand_key = key_f[a]
+        take = cand_key < best_key
+        best_key = jnp.where(take, cand_key, best_key)
+        best_loss = jnp.where(take, loss_f[a], best_loss)
+        best_vec = jnp.where(take, out_vectors[idx_f[a]], best_vec)
+        best_bracket = jnp.where(
+            take, jnp.asarray(b_i, jnp.int32), best_bracket
+        )
+        per_bracket = per_bracket.at[b_i].set(loss_f[a])
+        return best_key, best_loss, best_vec, best_bracket, per_bracket
 
-        if metrics is not None:
-            # metrics plane: per-rung histograms / crash counts plus the
-            # per-bracket refit flag and best final loss, all written at
-            # row b_i (concrete OR traced — the resident/unrolled parity
-            # contract extends to telemetry). O(n) binning per stage is
-            # trivial next to the stage evaluation it accompanies; the
-            # carried arrays are O(schedule), never O(configs).
-            m_hist, m_ev, m_cr, m_pr, m_sq = (
-                metrics.loss_hist, metrics.evals, metrics.crashes,
-                metrics.promotions, metrics.rung_seq,
-            )
-            depth = len(plan.num_configs)
-            for s, ((_idx_s, losses_s), k_s) in enumerate(
-                zip(stages, plan.num_configs)
-            ):
-                h_s, c_s = stage_telemetry(losses_s, dm_edges)
-                m_hist = m_hist.at[b_i, s].set(h_s)
-                m_ev = m_ev.at[b_i, s].set(k_s)
-                m_cr = m_cr.at[b_i, s].set(c_s)
-                m_pr = m_pr.at[b_i, s].set(
-                    plan.num_configs[s + 1] if s + 1 < depth else 0
-                )
-                # global execution-order stamp: static per-bracket base
-                # (gathered at the concrete-or-traced b_i) + the stage
-                # offset — monotonically increasing over the whole
-                # schedule, resident rounds included
-                m_sq = m_sq.at[b_i, s].set(dm_seq_base[b_i] + s)
-            _, loss_fin = stages[-1]
-            key_fin = jnp.where(jnp.isnan(loss_fin), _CRASH_RANK, loss_fin)
-            metrics = DeviceMetrics(
-                loss_hist=m_hist, evals=m_ev, crashes=m_cr,
-                promotions=m_pr,
-                model_fits=metrics.model_fits.at[b_i].set(fit_flag),
-                best_final=metrics.best_final.at[b_i].set(
-                    loss_fin[jnp.argmin(key_fin)]
-                ),
-                rung_seq=m_sq,
-            )
-
-        out = None
-        if incumbent_only:
-            # only the winner leaves the device loop: reduce the final
-            # (largest-budget) stage to its best row and fold it into
-            # the running cross-bracket incumbent — crashed (NaN) rows
-            # rank behind every real loss via the shared crash rank
-            best_key, best_loss, best_vec, best_bracket, per_bracket = inc
-            idx_f, loss_f = stages[-1]
-            key_f = jnp.where(jnp.isnan(loss_f), _CRASH_RANK, loss_f)
-            a = jnp.argmin(key_f)
-            cand_key = key_f[a]
-            take = cand_key < best_key
-            best_key = jnp.where(take, cand_key, best_key)
-            best_loss = jnp.where(take, loss_f[a], best_loss)
-            best_vec = jnp.where(take, out_vectors[idx_f[a]], best_vec)
-            best_bracket = jnp.where(
-                take, jnp.asarray(b_i, jnp.int32), best_bracket
-            )
-            per_bracket = per_bracket.at[b_i].set(loss_f[a])
-            inc = (best_key, best_loss, best_vec, best_bracket, per_bracket)
-        else:
-            idx_packed, loss_packed = _pack_stages(stages)
-            out = SweepBracketOutput(
-                out_vectors[:n0], mb_mask, idx_packed, loss_packed
-            )
-        return obs_v, obs_l, counts, inc, metrics, out
-
-    def sweep(
+    # the function's name is the compiled module's (``jit_hpb_sweep``): what
+    # a profiler trace's ``XLA Modules`` events carry, and what a reader
+    # joins the program's phase map by, so it is the program's own and not
+    # a name any other jitted ``sweep`` of the process would share
+    def hpb_sweep(
         seed: jax.Array, warm_v=None, warm_l=None, warm_n=None
     ) -> List[SweepBracketOutput]:
         key = jax.random.key(seed)
-        obs_v, obs_l, counts = init_obs_state(warm_v, warm_l, warm_n)
+        with jax.named_scope("hpb.obs_update"):
+            obs_v, obs_l, counts = init_obs_state(warm_v, warm_l, warm_n)
         inc = init_incumbent() if incumbent_only else None
         # the metrics carry rides the same functional thread as the
         # incumbent (None = metrics plane off: a registered-empty pytree
@@ -1554,12 +1584,12 @@ def make_fused_sweep_fn(
 
         rep = NamedSharding(mesh, PartitionSpec())
         return tracked_jit(
-            sweep,
+            hpb_sweep,
             name=base_name + ("_resident_spmd" if resident else "_spmd"),
             in_shardings=rep, out_shardings=rep, donate_argnums=donate,
         )
     return tracked_jit(
-        sweep,
+        hpb_sweep,
         name=base_name + ("_resident" if resident else ""),
         donate_argnums=donate,
     )

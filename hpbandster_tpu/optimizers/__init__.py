@@ -9,4 +9,5 @@ from hpbandster_tpu.optimizers.fused_bohb import (  # noqa: F401
     FusedH2BO,
     FusedHyperBand,
     FusedRandomSearch,
+    sweep_phase_maps,
 )

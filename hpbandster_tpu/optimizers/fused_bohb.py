@@ -45,13 +45,46 @@ from hpbandster_tpu.ops.sweep import (
 from hpbandster_tpu.space import ConfigurationSpace
 from hpbandster_tpu.utils.lru import LRUCache
 
-__all__ = ["FusedBOHB", "FusedHyperBand", "FusedRandomSearch", "FusedH2BO"]
+__all__ = ["FusedBOHB", "FusedHyperBand", "FusedRandomSearch", "FusedH2BO",
+           "sweep_phase_maps"]
 
 #: process-wide compiled-sweep cache (same policy as the fused-bracket and
 #: batch caches: one compile per (objective, schedule, space, knobs, mesh)).
 #: Values are AOT-compiled executables — cache hits skip retracing AND
 #: recompiling on repeated runs of the same schedule.
 _SWEEP_EXE_CACHE: LRUCache = LRUCache(maxsize=16)
+
+
+def sweep_phase_maps() -> Dict[str, Dict[str, str]]:
+    """``{module name: {instruction name: phase}}`` over every sweep
+    executable this process holds: the program's own map from what a
+    profiler trace prints (``XLA Modules`` events named
+    ``<module name>(<id>)`` enclosing ``XLA Ops`` named by instruction)
+    to the phases of ``obs.timeline.DEVICE_SCOPES``
+    (``obs.profile.device_phase_map``). Two executables of one module name
+    (a chunked run that crossed a capacity bucket) share an entry; an
+    instruction name they give different phases is left out, and so is an
+    executable whose text names no scope at all. Parses each executable's
+    text: seconds for a large program, so call it after the sweeps, never
+    between them."""
+    from hpbandster_tpu.obs.profile import device_phase_map, hlo_module_name
+
+    maps: Dict[str, Dict[str, str]] = {}
+    clashed = set()
+    for compiled in _SWEEP_EXE_CACHE.values():
+        text = compiled.as_text()
+        phases = device_phase_map(text)
+        if not phases:
+            # loaded from a persistent cache that a commit without scopes
+            # filled (the cache's key leaves metadata out): nothing to tell
+            continue
+        module = hlo_module_name(text)
+        for name, phase in phases.items():
+            if maps.setdefault(module, {}).setdefault(name, phase) != phase:
+                clashed.add((module, name))
+    for module, name in clashed:
+        del maps[module][name]
+    return maps
 
 
 def _note_device_refits(decoded: Dict[str, Any]) -> None:
@@ -125,183 +158,192 @@ class FusedBOHB:
                 "eval_fn and stateful_eval are exclusive: one evaluation "
                 "seam per optimizer"
             )
-        self.configspace = configspace
-        self.codec = build_space_codec(configspace)
-        # conditional spaces: the condition DAG compiles to an on-device
-        # activity mask (ops.sweep.compile_active_mask); raises for
-        # condition forms without a device representation
-        if configspace.get_conditions():
-            from hpbandster_tpu.ops.sweep import compile_active_mask
+        from hpbandster_tpu.obs.timeline import ADMISSION, sweep_span
 
-            self.active_mask_fn = compile_active_mask(configspace, self.codec)
-            self._conditions_sig = tuple(
-                repr(c) for c in configspace.get_conditions()
-            )
-        else:
-            self.active_mask_fn = None
-            self._conditions_sig = ()
-        # forbidden clauses: compiled predicate + in-trace rejection
-        # resampling; the clamp fallback is a host-verified valid config
-        if configspace.get_forbiddens():
-            from hpbandster_tpu.ops.sweep import compile_forbidden_mask
+        #: seconds of the spans that ran before a ``run_stats`` row existed
+        #: to hold them (construction, a run's planning and set-up): the
+        #: next row appended takes them as its ``phase_s``
+        self._phase_carry: Dict[str, float] = {}
+        with sweep_span("construct", ADMISSION, self._phase_carry):
+            self.configspace = configspace
+            self.codec = build_space_codec(configspace)
+            # conditional spaces: the condition DAG compiles to an on-device
+            # activity mask (ops.sweep.compile_active_mask); raises for
+            # condition forms without a device representation
+            if configspace.get_conditions():
+                from hpbandster_tpu.ops.sweep import compile_active_mask
 
-            self.forbidden_fn = compile_forbidden_mask(configspace, self.codec)
-            # deterministic in the optimizer seed (not the space's shared
-            # RNG), so the clamp result is reproducible run to run
-            fb_rng = np.random.default_rng(
-                0xFB if seed is None else (int(seed) ^ 0xFB)
-            )
-            fb = configspace.to_vector(
-                configspace.sample_configuration(rng=fb_rng)
-            )
-            self._fallback_vector = np.nan_to_num(
-                np.asarray(fb, np.float32), nan=0.0
-            )
-            self._forbiddens_sig = tuple(
-                repr(c) for c in configspace.get_forbiddens()
-            ) + (self._fallback_vector.tobytes(),)
-        else:
-            self.forbidden_fn = None
-            self._fallback_vector = None
-            self._forbiddens_sig = ()
-        # fail fast on a non-scalar objective: without this check the first
-        # run() dies with an opaque XLA broadcasting error from deep inside
-        # the sweep trace. jax.eval_shape is abstract (no backend or device
-        # work); the budget is passed CONCRETE exactly as the sweep does,
-        # so Python-level loops over epochs inside eval_fn stay legal —
-        # min_budget keeps any such unrolling as small as possible.
-        import jax as _jax
-        import jax.numpy as _jnp
-
-        d = int(self.codec.kind.shape[0])
-        if stateful_eval is not None:
-            # same fail-fast contract for the stateful seam: a 2-lane
-            # abstract init->step round-trip surfaces protocol bugs
-            # (wrong arity, non-batched losses) before the sweep trace
-            # buries them in an opaque XLA error
-            try:
-                _, losses_sds = _jax.eval_shape(
-                    lambda v: stateful_eval.step_fn(
-                        stateful_eval.init_fn(v), v, float(min_budget), 0.0
-                    ),
-                    _jax.ShapeDtypeStruct((2, d), _jnp.float32),
+                self.active_mask_fn = compile_active_mask(configspace, self.codec)
+                self._conditions_sig = tuple(
+                    repr(c) for c in configspace.get_conditions()
                 )
-            except Exception as e:
-                raise ValueError(
-                    f"stateful_eval failed under abstract evaluation "
-                    f"(init_fn + step_fn over f32[2, {d}] vectors): "
-                    f"{type(e).__name__}: {e}"
-                ) from e
-            if tuple(getattr(losses_sds, "shape", ())) != (2,):
-                raise ValueError(
-                    "stateful_eval.step_fn must return per-lane losses "
-                    f"f32[n], got shape {getattr(losses_sds, 'shape', None)}"
+            else:
+                self.active_mask_fn = None
+                self._conditions_sig = ()
+            # forbidden clauses: compiled predicate + in-trace rejection
+            # resampling; the clamp fallback is a host-verified valid config
+            if configspace.get_forbiddens():
+                from hpbandster_tpu.ops.sweep import compile_forbidden_mask
+
+                self.forbidden_fn = compile_forbidden_mask(configspace, self.codec)
+                # deterministic in the optimizer seed (not the space's shared
+                # RNG), so the clamp result is reproducible run to run
+                fb_rng = np.random.default_rng(
+                    0xFB if seed is None else (int(seed) ^ 0xFB)
                 )
-        else:
-            try:
-                out_sds = _jax.eval_shape(
-                    lambda v: eval_fn(v, float(min_budget)),
-                    _jax.ShapeDtypeStruct((d,), _jnp.float32),
+                fb = configspace.to_vector(
+                    configspace.sample_configuration(rng=fb_rng)
                 )
-            except Exception as e:
-                # deliberately broad: eval_shape surfaces plain bugs inside
-                # eval_fn (wrong arity, NameError) as well as tracing errors,
-                # so the banner says what was ATTEMPTED, not what went wrong —
-                # the chained original exception carries the real diagnosis
-                # (ADVICE r4)
-                raise ValueError(
-                    f"eval_fn(config_vector f32[{d}], budget) failed under "
-                    f"abstract evaluation (jax.eval_shape) for this {d}-dim "
-                    f"space: {type(e).__name__}: {e}"
-                ) from e
-            leaves = _jax.tree_util.tree_leaves(out_sds)
-            shapes = [tuple(getattr(l, "shape", ())) for l in leaves]
-            if len(leaves) != 1 or shapes[0] != ():
-                raise ValueError(
-                    "eval_fn must return a single SCALAR loss, got "
-                    f"{len(leaves)} output leaves with shapes {shapes} — "
-                    "reduce per-example losses (e.g. .mean()) and drop aux "
-                    "outputs before returning"
+                self._fallback_vector = np.nan_to_num(
+                    np.asarray(fb, np.float32), nan=0.0
                 )
-        self.eval_fn = eval_fn
-        self.stateful_eval = stateful_eval
-        self.run_id = run_id
-        self.eta = float(eta)
-        self.min_budget = float(min_budget)
-        self.max_budget = float(max_budget)
-        self.min_points_in_model = min_points_in_model
-        self.top_n_percent = int(top_n_percent)
-        self.num_samples = int(num_samples)
-        self.random_fraction = float(random_fraction)
-        self.bandwidth_factor = float(bandwidth_factor)
-        self.min_bandwidth = float(min_bandwidth)
-        self.mesh = mesh
-        self.axis = axis
-        # Pallas acquisition scorer inside the sweep trace. Default (None):
-        # ON whenever the backend is a TPU (its speed against the XLA
-        # scorer is not measured on current code; HPB_USE_PALLAS=0 is the
-        # explicit opt-out until a chip A/B settles it). =1 forces it even
-        # off-TPU, where the kernel runs in the Pallas interpreter, like
-        # explicitly passing use_pallas=True on a CPU/GPU backend. On a
-        # TPU the kernel is always Mosaic-compiled, never interpreted.
-        from hpbandster_tpu.ops.pallas_kde import pallas_available
+                self._forbiddens_sig = tuple(
+                    repr(c) for c in configspace.get_forbiddens()
+                ) + (self._fallback_vector.tobytes(),)
+            else:
+                self.forbidden_fn = None
+                self._fallback_vector = None
+                self._forbiddens_sig = ()
+            # fail fast on a non-scalar objective: without this check the first
+            # run() dies with an opaque XLA broadcasting error from deep inside
+            # the sweep trace. jax.eval_shape is abstract (no backend or device
+            # work); the budget is passed CONCRETE exactly as the sweep does,
+            # so Python-level loops over epochs inside eval_fn stay legal —
+            # min_budget keeps any such unrolling as small as possible.
+            import jax as _jax
+            import jax.numpy as _jnp
 
-        if use_pallas is None:
-            import os
+            with sweep_span("construct.eval_shape", ADMISSION,
+                            self._phase_carry):
+                d = int(self.codec.kind.shape[0])
+                if stateful_eval is not None:
+                    # same fail-fast contract for the stateful seam: a 2-lane
+                    # abstract init->step round-trip surfaces protocol bugs
+                    # (wrong arity, non-batched losses) before the sweep trace
+                    # buries them in an opaque XLA error
+                    try:
+                        _, losses_sds = _jax.eval_shape(
+                            lambda v: stateful_eval.step_fn(
+                                stateful_eval.init_fn(v), v, float(min_budget), 0.0
+                            ),
+                            _jax.ShapeDtypeStruct((2, d), _jnp.float32),
+                        )
+                    except Exception as e:
+                        raise ValueError(
+                            f"stateful_eval failed under abstract evaluation "
+                            f"(init_fn + step_fn over f32[2, {d}] vectors): "
+                            f"{type(e).__name__}: {e}"
+                        ) from e
+                    if tuple(getattr(losses_sds, "shape", ())) != (2,):
+                        raise ValueError(
+                            "stateful_eval.step_fn must return per-lane losses "
+                            f"f32[n], got shape {getattr(losses_sds, 'shape', None)}"
+                        )
+                else:
+                    try:
+                        out_sds = _jax.eval_shape(
+                            lambda v: eval_fn(v, float(min_budget)),
+                            _jax.ShapeDtypeStruct((d,), _jnp.float32),
+                        )
+                    except Exception as e:
+                        # deliberately broad: eval_shape surfaces plain bugs inside
+                        # eval_fn (wrong arity, NameError) as well as tracing errors,
+                        # so the banner says what was ATTEMPTED, not what went wrong —
+                        # the chained original exception carries the real diagnosis
+                        # (ADVICE r4)
+                        raise ValueError(
+                            f"eval_fn(config_vector f32[{d}], budget) failed under "
+                            f"abstract evaluation (jax.eval_shape) for this {d}-dim "
+                            f"space: {type(e).__name__}: {e}"
+                        ) from e
+                    leaves = _jax.tree_util.tree_leaves(out_sds)
+                    shapes = [tuple(getattr(l, "shape", ())) for l in leaves]
+                    if len(leaves) != 1 or shapes[0] != ():
+                        raise ValueError(
+                            "eval_fn must return a single SCALAR loss, got "
+                            f"{len(leaves)} output leaves with shapes {shapes} — "
+                            "reduce per-example losses (e.g. .mean()) and drop aux "
+                            "outputs before returning"
+                        )
+            self.eval_fn = eval_fn
+            self.stateful_eval = stateful_eval
+            self.run_id = run_id
+            self.eta = float(eta)
+            self.min_budget = float(min_budget)
+            self.max_budget = float(max_budget)
+            self.min_points_in_model = min_points_in_model
+            self.top_n_percent = int(top_n_percent)
+            self.num_samples = int(num_samples)
+            self.random_fraction = float(random_fraction)
+            self.bandwidth_factor = float(bandwidth_factor)
+            self.min_bandwidth = float(min_bandwidth)
+            self.mesh = mesh
+            self.axis = axis
+            # Pallas acquisition scorer inside the sweep trace. Default (None):
+            # ON whenever the backend is a TPU (its speed against the XLA
+            # scorer is not measured on current code; HPB_USE_PALLAS=0 is the
+            # explicit opt-out until a chip A/B settles it). =1 forces it even
+            # off-TPU, where the kernel runs in the Pallas interpreter, like
+            # explicitly passing use_pallas=True on a CPU/GPU backend. On a
+            # TPU the kernel is always Mosaic-compiled, never interpreted.
+            from hpbandster_tpu.ops.pallas_kde import pallas_available
 
-            env = os.environ.get("HPB_USE_PALLAS", "")
-            use_pallas = True if env == "1" else (
-                False if env == "0" else pallas_available()
-            )
-        self.use_pallas = bool(use_pallas)
-        self.pallas_interpret = self.use_pallas and not pallas_available()
-        self.result_logger = result_logger
-        self.working_directory = working_directory
-        self.logger = logger or logging.getLogger("hpbandster_tpu.fused_bohb")
-        self.rng = np.random.default_rng(seed)
+            if use_pallas is None:
+                import os
 
-        self.max_SH_iter = max_sh_iterations(min_budget, max_budget, eta)
-        self.budgets = budget_ladder(min_budget, max_budget, eta)
-        self.iterations: List[SuccessiveHalving] = []
-        self.config: Dict[str, Any] = {
-            "time_ref": None,
-            "eta": self.eta,
-            "min_budget": self.min_budget,
-            "max_budget": self.max_budget,
-            "budgets": list(self.budgets),
-            "max_SH_iter": self.max_SH_iter,
-            "min_points_in_model": min_points_in_model,
-            "top_n_percent": top_n_percent,
-            "num_samples": num_samples,
-            "random_fraction": random_fraction,
-            "bandwidth_factor": bandwidth_factor,
-            "min_bandwidth": min_bandwidth,
-        }
-        #: stats for tests/benchmarks
-        self.total_evaluated = 0
-        #: per-chunk device timings (compile vs execute seconds), appended by
-        #: every ``run()``
-        self.run_stats: List[Dict[str, Any]] = []
-        #: the AOT-compiled executable of the last chunk dispatched
-        #: (``.as_text()``, ``.cost_analysis()``, shardings): what
-        #: ``chip_smoke.py`` inspects to prove which program ran
-        self.last_executable = None
-        #: optional on-device promotion scorer (see FusedH2BO); None = the
-        #: plain successive-halving raw-loss top-k
-        self.promotion_rank_fn = None
-        #: last run's decoded device-telemetry record (None until a run
-        #: with the metrics plane on completes — obs/device_metrics.py)
-        self.last_device_telemetry: Optional[Dict[str, Any]] = None
+                env = os.environ.get("HPB_USE_PALLAS", "")
+                use_pallas = True if env == "1" else (
+                    False if env == "0" else pallas_available()
+                )
+            self.use_pallas = bool(use_pallas)
+            self.pallas_interpret = self.use_pallas and not pallas_available()
+            self.result_logger = result_logger
+            self.working_directory = working_directory
+            self.logger = logger or logging.getLogger("hpbandster_tpu.fused_bohb")
+            self.rng = np.random.default_rng(seed)
 
-        # warm start (reference: previous_result= replays old data into the
-        # model, SURVEY.md §5): old (config, budget, loss) observations seed
-        # the device observation buffers; the old data rides into the final
-        # Result as a finished pseudo-iteration under negative ids
-        self._warm_v: Dict[float, np.ndarray] = {}
-        self._warm_l: Dict[float, np.ndarray] = {}
-        self.warmstart_iteration: List[Any] = []
-        if previous_result is not None:
-            self._ingest_previous_result(previous_result)
+            self.max_SH_iter = max_sh_iterations(min_budget, max_budget, eta)
+            self.budgets = budget_ladder(min_budget, max_budget, eta)
+            self.iterations: List[SuccessiveHalving] = []
+            self.config: Dict[str, Any] = {
+                "time_ref": None,
+                "eta": self.eta,
+                "min_budget": self.min_budget,
+                "max_budget": self.max_budget,
+                "budgets": list(self.budgets),
+                "max_SH_iter": self.max_SH_iter,
+                "min_points_in_model": min_points_in_model,
+                "top_n_percent": top_n_percent,
+                "num_samples": num_samples,
+                "random_fraction": random_fraction,
+                "bandwidth_factor": bandwidth_factor,
+                "min_bandwidth": min_bandwidth,
+            }
+            #: stats for tests/benchmarks
+            self.total_evaluated = 0
+            #: per-chunk device timings (compile vs execute seconds), appended by
+            #: every ``run()``
+            self.run_stats: List[Dict[str, Any]] = []
+            #: the AOT-compiled executable of the last chunk dispatched
+            #: (``.as_text()``, ``.cost_analysis()``, shardings): what
+            #: ``chip_smoke.py`` inspects to prove which program ran
+            self.last_executable = None
+            #: optional on-device promotion scorer (see FusedH2BO); None = the
+            #: plain successive-halving raw-loss top-k
+            self.promotion_rank_fn = None
+            #: last run's decoded device-telemetry record (None until a run
+            #: with the metrics plane on completes — obs/device_metrics.py)
+            self.last_device_telemetry: Optional[Dict[str, Any]] = None
+
+            # warm start (reference: previous_result= replays old data into the
+            # model, SURVEY.md §5): old (config, budget, loss) observations seed
+            # the device observation buffers; the old data rides into the final
+            # Result as a finished pseudo-iteration under negative ids
+            self._warm_v: Dict[float, np.ndarray] = {}
+            self._warm_l: Dict[float, np.ndarray] = {}
+            self.warmstart_iteration: List[Any] = []
+            if previous_result is not None:
+                self._ingest_previous_result(previous_result)
 
     def _ingest_previous_result(self, previous_result: Result) -> None:
         from hpbandster_tpu.core.warmstart import WarmStartIteration
@@ -425,14 +467,17 @@ class FusedBOHB:
 
     def _sweep_compiled(self, plans, example_args, dynamic=False, caps=None,
                         resident=False, incumbent_only=False,
-                        device_metrics=False):
+                        device_metrics=False, phase_s=None):
         """AOT-compiled sweep executable + honest timing attribution:
         returns ``(compiled, build_compile_seconds, cache_hit)``. Ahead-of-
         time ``lower().compile()`` separates compile from execute time (the
         jit dispatch path can't), and the cached executable skips retracing
         on repeated runs of the same schedule. ``build_compile_seconds`` is
         the time THIS call paid — 0.0 on a cache hit, so summing it across
-        artifacts never double-counts a compile."""
+        artifacts never double-counts a compile. On a miss the two halves
+        are spans of their own (``compile.trace_lower``: Python trace and
+        lowering; ``compile.compile``: XLA, or the persistent cache's
+        load), their seconds added to ``phase_s``."""
         key = self._sweep_key(plans, dynamic=dynamic, caps=caps,
                               resident=resident,
                               incumbent_only=incumbent_only,
@@ -448,12 +493,17 @@ class FusedBOHB:
         # before the first compile: a second process (or the next chip
         # call, where the machine keeps the directory) loads the program
         enable_persistent_compile_cache()
+        from hpbandster_tpu.obs.timeline import COMPILE, sweep_span
+
         t0 = time.perf_counter()
-        fn = self._build_sweep_fn(plans, dynamic=dynamic, caps=caps,
-                                  resident=resident,
-                                  incumbent_only=incumbent_only,
-                                  device_metrics=device_metrics)
-        compiled = fn.lower(*example_args).compile()
+        with sweep_span("compile.trace_lower", COMPILE, phase_s):
+            fn = self._build_sweep_fn(plans, dynamic=dynamic, caps=caps,
+                                      resident=resident,
+                                      incumbent_only=incumbent_only,
+                                      device_metrics=device_metrics)
+            lowered = fn.lower(*example_args)
+        with sweep_span("compile.compile", COMPILE, phase_s):
+            compiled = lowered.compile()
         dt = time.perf_counter() - t0
         _SWEEP_EXE_CACHE[key] = compiled
         self.last_executable = compiled
@@ -542,29 +592,73 @@ class FusedBOHB:
         ``HPB_DEVICE_METRICS=1``; off otherwise — telemetry changes the
         compiled program, so the default is explicit, never inferred
         from the ambient bus.
+
+        Every phase of the call is one ``obs.timeline.sweep_span``: a
+        ``hpb:<name>`` region in any live profiler trace, a journal event
+        when a sink listens, and always its seconds under ``phase_s`` of
+        the chunk's ``run_stats`` row (docs/observability.md lists the
+        names). ``run`` encloses the rest; a dotted name lies inside the
+        span it is named after (``replay.configs`` in ``bracket_replay``,
+        ``compile.*`` in ``compile_lookup``).
         """
         del min_n_workers  # API symmetry with Master.run; no worker pool here
+        from hpbandster_tpu.obs.timeline import ADMISSION, sweep_span
+        from hpbandster_tpu.obs.trace import current_trace, new_trace
+
+        #: one trace identity for this run() call's whole sweep: every
+        #: span, chunk record, compile event and the decoded device-
+        #: telemetry record share it, so the flight recorder
+        #: (obs/timeline.py) and summarize's trace_timelines can stitch the
+        #: fused sweep — host phases AND the device loop — into one
+        #: per-trace timeline whose phases sum to the sweep's wall. The
+        #: per-evaluation records the replay emits stay outside it (they
+        #: are jobs, counted one by one). An already-active trace (a
+        #: serving layer driving this run) wins.
+        sweep_trace = current_trace() or new_trace(self.run_id)
+        with sweep_span(
+            "run", ADMISSION, self._phase_carry, trace=sweep_trace
+        ) as run_span:
+            result = self._run_sweep(
+                run_span, n_iterations, profile_dir, chunk_brackets,
+                checkpoint_path, dynamic_counts, resident, device_metrics,
+            )
+        # after the last span has closed: the file holds finished rows
+        self._write_timings_sidecar()
+        return result
+
+    def _run_sweep(self, run_span, n_iterations, profile_dir, chunk_brackets,
+                   checkpoint_path, dynamic_counts, resident, device_metrics):
+        """The body of :meth:`run`, inside its ``run`` span."""
+        import functools
+
         import jax
 
-        from hpbandster_tpu.utils.profiling import trace
-
+        from hpbandster_tpu.obs import timeline
         from hpbandster_tpu.obs.timeline import (
             ADMISSION,
             COMPILE,
             PROMOTION,
+            RUNG_COMPUTE,
             TRANSFER,
-            phase_span,
         )
+        from hpbandster_tpu.obs.trace import use_trace
+        from hpbandster_tpu.utils.profiling import trace
+
+        sweep_trace = run_span.trace
+        sweep_span = functools.partial(timeline.sweep_span, trace=sweep_trace)
 
         first = len(self.iterations)
+        #: where this call's spans add their seconds: until the first chunk
+        #: adopts it, the dict that also holds construction's
+        phase_s = self._phase_carry
         # planning is the sweep's admission work: schedule geometry +
         # bracket_created records, before anything boards the device
-        with phase_span("sweep_planning", ADMISSION):
+        with sweep_span("sweep_planning", ADMISSION, phase_s):
             plans = [self._plan(i) for i in range(first, int(n_iterations))]
         # everything between planning and the first dispatch — mesh
-        # probing, tier policy, trace mint, transfer baselines — is
-        # still admission work on the timeline
-        with phase_span("sweep_setup", ADMISSION):
+        # probing, tier policy, transfer baselines — is still admission
+        # work on the timeline
+        with sweep_span("sweep_setup", ADMISSION, phase_s):
             if self.config["time_ref"] is None:
                 self.config["time_ref"] = time.time()
 
@@ -602,15 +696,6 @@ class FusedBOHB:
             #: decoded once at the end of the run into ONE telemetry record
             dm_parts: List[Any] = []
             dm_execute_s = 0.0
-            #: one trace identity for this run() call's whole sweep: every
-            #: chunk span, compile event and the decoded device-telemetry
-            #: record share it, so the flight recorder (obs/timeline.py) and
-            #: summarize's trace_timelines can stitch the fused sweep — host
-            #: phases AND the device loop — into one per-trace timeline. An
-            #: already-active trace (a serving layer driving this run) wins.
-            from hpbandster_tpu.obs.trace import current_trace, new_trace, use_trace
-
-            sweep_trace = current_trace() or new_trace(self.run_id)
             link0 = None
             if plans:
                 from hpbandster_tpu.obs.runtime import transfer_counters
@@ -651,6 +736,9 @@ class FusedBOHB:
 
         while plans:
             chunk_plans, plans = plans[:chunk], plans[chunk:]
+            # this chunk's phase seconds, its row's ``phase_s``: the first
+            # row takes what construction and set-up carried
+            phase_s, self._phase_carry = self._phase_carry, {}
             seed = np.uint32(self.rng.integers(2**32, dtype=np.uint32))
             overlap_s = None
             #: host bytes materialized by the per-shard streamed warm
@@ -663,7 +751,7 @@ class FusedBOHB:
                 # -- the host cost of putting this chunk's inputs on the
                 # device link (the flight recorder's h2d counterpart of
                 # telemetry_fetch)
-                with phase_span("chunk_staging", TRANSFER):
+                with sweep_span("chunk_staging", TRANSFER, phase_s):
                     run_caps = None
                     if dynamic:
                         # PAST-ONLY capacities, pow2-bucketed with a generous
@@ -759,14 +847,17 @@ class FusedBOHB:
                     # on a ledger miss this window is the real trace+build
                     # wall (also reported as compile_s on the chunk
                     # record); on a hit, the lookup itself
-                    with phase_span("compile_lookup", COMPILE):
+                    with sweep_span("compile_lookup", COMPILE, phase_s):
                         compiled, compile_s, cache_hit = self._sweep_compiled(
                             tuple(chunk_plans), args, dynamic=dynamic,
                             caps=run_caps, resident=resident,
-                            device_metrics=use_dm,
+                            device_metrics=use_dm, phase_s=phase_s,
                         )
                     t_exec = time.perf_counter()
-                    raw = compiled(*args)  # async dispatch
+                    # arguments up and the program enqueued: returns
+                    # before the device has finished (async dispatch)
+                    with sweep_span("dispatch", TRANSFER, phase_s):
+                        raw = compiled(*args)
                     dm_dev = None
                     if dynamic:
                         # keep the updated observation state ON DEVICE for
@@ -781,18 +872,16 @@ class FusedBOHB:
                     # pipelining: the previous chunk's bookkeeping replays
                     # HERE, concurrent with this chunk's device execution
                     _flush_replay()
-                    outputs = jax.device_get(raw)
+                    # the host blocked on the device: what is left of the
+                    # program's run, then the outputs' d2h
+                    with sweep_span("fetch", RUNG_COMPUTE, phase_s):
+                        outputs = jax.device_get(raw)
                     if dm_dev is not None:
                         # outputs already synced above, so this fetch is
                         # pure d2h of the O(schedule) telemetry pytree —
                         # the one transfer-phase slice the fused journal
                         # can measure honestly
-                        from hpbandster_tpu.obs.timeline import (
-                            TRANSFER,
-                            phase_span,
-                        )
-
-                        with phase_span("telemetry_fetch", TRANSFER):
+                        with sweep_span("telemetry_fetch", TRANSFER, phase_s):
                             dm_parts.append((
                                 jax.device_get(dm_dev),
                                 [(p.num_configs, p.budgets)
@@ -826,8 +915,9 @@ class FusedBOHB:
                         unstack_resident_outputs,
                     )
 
-                    _, n_rounds, _ = resident_rotation(chunk_plans)
-                    outputs = unstack_resident_outputs(outputs, n_rounds)
+                    with sweep_span("unstack", TRANSFER, phase_s):
+                        _, n_rounds, _ = resident_rotation(chunk_plans)
+                        outputs = unstack_resident_outputs(outputs, n_rounds)
             finally:
                 # any failure above (arg building, a bucket-doubling
                 # recompile, dispatch, fetch) must still land the COMPLETED
@@ -852,7 +942,7 @@ class FusedBOHB:
             # chunk accounting — run_stats row, the sweep_chunk journal
             # record (and its sink write), per-job attribution info —
             # is host bookkeeping the timeline charges to promotion
-            with phase_span("chunk_accounting", PROMOTION):
+            with sweep_span("chunk_accounting", PROMOTION, phase_s):
                 stat = {
                     "chunk_index": len(self.run_stats),
                     "brackets": list(range(done, done + len(chunk_plans))),
@@ -866,6 +956,12 @@ class FusedBOHB:
                     # where this chunk's warm observations came from: 0 bytes
                     # uploaded = the donated device thread carried them
                     "warm_upload_bytes": int(upload_bytes),
+                    # seconds per span name, this chunk's share of the
+                    # sweep's wall; the spans that close after this point
+                    # (this one, obs_fold, the chunk's bracket_replay
+                    # whenever it runs, the call's result and run on its
+                    # last row) add to the same dict
+                    "phase_s": phase_s,
                 }
                 if overlap_s is not None:
                     # host replay of the PRIOR chunk that ran inside this
@@ -886,6 +982,9 @@ class FusedBOHB:
                         seq=stat["chunk_index"],
                         h2d_bytes=int(upload_bytes),
                         d2h_bytes=int(d2h_bytes),
+                        # the phases that have closed by now; the later
+                        # ones are journal events of their own
+                        phase_s=dict(phase_s),
                     )
                 # per-job device-timing attribution (VERDICT r1 #10): every run
                 # of this chunk carries the chunk's compile/execute seconds into
@@ -902,7 +1001,7 @@ class FusedBOHB:
             staged = []
             # the eager observation fold is successive-halving bookkeeping
             # on the host path — a promotion-phase slice on the timeline
-            with phase_span("obs_fold", PROMOTION):
+            with sweep_span("obs_fold", PROMOTION, phase_s):
                 for b_i, (plan, out) in enumerate(
                     zip(chunk_plans, outputs), start=done
                 ):
@@ -915,11 +1014,16 @@ class FusedBOHB:
                     # the Master's, sees all past results
                     self._accumulate_obs(plan, out, stages)
 
-            def replay_now(staged=staged, job_info=job_info):
-                for b_i, plan, out, stages in staged:
-                    self._replay_bracket(
-                        b_i, plan, out, stages, job_info=job_info
-                    )
+            def replay_now(staged=staged, job_info=job_info, phase_s=phase_s):
+                # the replay is promotion bookkeeping wherever it runs —
+                # here, or inside the next chunk's device window — and its
+                # seconds go to the row of the chunk it replays
+                span = functools.partial(sweep_span, totals=phase_s)
+                with span("bracket_replay", PROMOTION):
+                    for b_i, plan, out, stages in staged:
+                        self._replay_bracket(
+                            b_i, plan, out, stages, job_info, span
+                        )
 
             done += len(chunk_plans)
             if checkpoint_path is not None:
@@ -927,16 +1031,23 @@ class FusedBOHB:
                 # boundary, so checkpointed runs replay sequentially —
                 # resume-equals-uninterrupted stays bitwise either way
                 # (replay content never depends on when it runs)
-                with phase_span("bracket_replay", PROMOTION):
-                    replay_now()
+                replay_now()
                 self.save_checkpoint(checkpoint_path)
             else:
                 pending_replay = replay_now
         if pending_replay is not None:
-            # last chunk has no successor to hide behind; the replay is
-            # promotion bookkeeping, so the timeline charges it there
-            with phase_span("bracket_replay", PROMOTION):
-                pending_replay()
+            # last chunk has no successor to hide behind
+            pending_replay()
+        # the call's result and run spans land on its last row
+        run_span.totals = phase_s
+        with sweep_span("result", PROMOTION, phase_s):
+            return self._sweep_result(
+                link0, dm_parts, dm_execute_s, sweep_trace
+            )
+
+    def _sweep_result(self, link0, dm_parts, dm_execute_s, sweep_trace) -> Result:
+        """Transfer gauges, the decoded device telemetry and the
+        ``Result``: what :meth:`run` does after its last replay."""
         if link0 is not None:
             # per-sweep host-link gauges (sweep.transfer_bytes.{h2d,d2h},
             # sweep.host_syncs): this run() call's whole transfer bill
@@ -961,11 +1072,12 @@ class FusedBOHB:
             # journaled under the sweep's trace: the device loop's rung
             # sections join the same per-trace timeline as the host-side
             # chunk spans (summarize trace_timelines / obs timeline)
+            from hpbandster_tpu.obs.trace import use_trace
+
             with use_trace(sweep_trace):
                 emit_device_telemetry(decoded)
                 _note_device_refits(decoded)
             self.last_device_telemetry = decoded
-        self._write_timings_sidecar()
         return Result(
             list(self.iterations) + self.warmstart_iteration, self.config
         )
@@ -1000,9 +1112,35 @@ class FusedBOHB:
         promotion counts for a sweep whose per-rung decisions otherwise
         never leave the device — decoded into the gauges + one
         ``device_telemetry`` record, and returned under
-        ``"device_telemetry"``.
+        ``"device_telemetry"``. ``"phase_s"`` holds the call's seconds by
+        span name, the names :meth:`run` uses for the phases this entry
+        point has.
         """
+        from hpbandster_tpu.obs.timeline import ADMISSION, sweep_span
+        from hpbandster_tpu.obs.trace import current_trace, new_trace, use_trace
+
+        phase_s: Dict[str, float] = {}
+        inc_trace = current_trace() or new_trace(self.run_id)
+        with use_trace(inc_trace), sweep_span("run", ADMISSION, phase_s):
+            out = self._run_incumbent(
+                phase_s, n_iterations, profile_dir, resident, device_metrics
+            )
+        out["phase_s"] = phase_s
+        return out
+
+    def _run_incumbent(self, phase_s, n_iterations, profile_dir, resident,
+                       device_metrics) -> Dict[str, Any]:
+        """The body of :meth:`run_incumbent`, inside its ``run`` span."""
         import jax
+
+        from hpbandster_tpu.obs.timeline import (
+            ADMISSION,
+            COMPILE,
+            PROMOTION,
+            RUNG_COMPUTE,
+            TRANSFER,
+            sweep_span,
+        )
 
         from hpbandster_tpu.obs.runtime import (
             note_transfer,
@@ -1018,78 +1156,77 @@ class FusedBOHB:
                 "parallel.multihost.run_sharded_fused_sweep(resident=True) "
                 "for the SPMD pod tier"
             )
-        plans = [self._plan(i) for i in range(int(n_iterations))]
+        with sweep_span("sweep_planning", ADMISSION, phase_s):
+            plans = [self._plan(i) for i in range(int(n_iterations))]
         if not plans:
             raise ValueError("run_incumbent needs n_iterations >= 1")
-        d = int(self.codec.kind.shape[0])
-        # same capacity policy as the chunked tier (pow2, floor 256) so a
-        # warm-started incumbent query shares executables with runs that
-        # agree on history
-        run_caps = {float(b): len(l) for b, l in self._warm_l.items()}
-        for b, k in plan_additions(plans).items():
-            run_caps[b] = run_caps.get(b, 0) + k
-        run_caps = pow2_capacities(run_caps)
-        seed = np.uint32(self.rng.integers(2**32, dtype=np.uint32))
-        warm_v_pad, warm_l_pad, warm_n = {}, {}, {}
-        for b, cap in run_caps.items():
-            v = self._warm_v.get(b)
-            n = 0 if v is None else len(v)
-            buf_v = np.zeros((cap, d), np.float32)
-            buf_l = np.full(cap, np.inf, np.float32)
-            if n:
-                buf_v[:n] = v
-                buf_l[:n] = self._warm_l[b]
-            warm_v_pad[b] = buf_v
-            warm_l_pad[b] = buf_l
-            warm_n[b] = np.int32(n)
-        args = (seed, warm_v_pad, warm_l_pad, warm_n)
-        from hpbandster_tpu.obs.device_metrics import device_metrics_default
+        with sweep_span("chunk_staging", TRANSFER, phase_s):
+            d = int(self.codec.kind.shape[0])
+            # same capacity policy as the chunked tier (pow2, floor 256) so a
+            # warm-started incumbent query shares executables with runs that
+            # agree on history
+            run_caps = {float(b): len(l) for b, l in self._warm_l.items()}
+            for b, k in plan_additions(plans).items():
+                run_caps[b] = run_caps.get(b, 0) + k
+            run_caps = pow2_capacities(run_caps)
+            seed = np.uint32(self.rng.integers(2**32, dtype=np.uint32))
+            warm_v_pad, warm_l_pad, warm_n = {}, {}, {}
+            for b, cap in run_caps.items():
+                v = self._warm_v.get(b)
+                n = 0 if v is None else len(v)
+                buf_v = np.zeros((cap, d), np.float32)
+                buf_l = np.full(cap, np.inf, np.float32)
+                if n:
+                    buf_v[:n] = v
+                    buf_l[:n] = self._warm_l[b]
+                warm_v_pad[b] = buf_v
+                warm_l_pad[b] = buf_l
+                warm_n[b] = np.int32(n)
+            args = (seed, warm_v_pad, warm_l_pad, warm_n)
+            from hpbandster_tpu.obs.device_metrics import device_metrics_default
 
-        use_dm = (
-            device_metrics_default()
-            if device_metrics is None else bool(device_metrics)
-        )
-        link0 = transfer_counters()
-        upload_bytes = sum(
-            int(getattr(l, "nbytes", 0))
-            for l in jax.tree_util.tree_leaves(args)
-        )
-        note_transfer("h2d", upload_bytes)
-        with trace(profile_dir):
-            compiled, compile_s, cache_hit = self._sweep_compiled(
-                tuple(plans), args, dynamic=True, caps=run_caps,
-                resident=resident, incumbent_only=True,
-                device_metrics=use_dm,
+            use_dm = (
+                device_metrics_default()
+                if device_metrics is None else bool(device_metrics)
             )
+            link0 = transfer_counters()
+            upload_bytes = sum(
+                int(getattr(l, "nbytes", 0))
+                for l in jax.tree_util.tree_leaves(args)
+            )
+            note_transfer("h2d", upload_bytes)
+        with trace(profile_dir):
+            with sweep_span("compile_lookup", COMPILE, phase_s):
+                compiled, compile_s, cache_hit = self._sweep_compiled(
+                    tuple(plans), args, dynamic=True, caps=run_caps,
+                    resident=resident, incumbent_only=True,
+                    device_metrics=use_dm, phase_s=phase_s,
+                )
             t0 = time.perf_counter()
-            raw = compiled(*args)
-            dm_host = None
-            if use_dm:
-                inc, dm_dev = raw
-                inc, dm_host = jax.device_get((inc, dm_dev))
-            else:
-                inc = jax.device_get(raw)
+            with sweep_span("dispatch", TRANSFER, phase_s):
+                raw = compiled(*args)
+            with sweep_span("fetch", RUNG_COMPUTE, phase_s):
+                inc, dm_host = jax.device_get(raw) if use_dm else (
+                    jax.device_get(raw), None
+                )
             execute_s = time.perf_counter() - t0
-        dm_leaves = (
-            list(jax.tree_util.tree_leaves(dm_host))
-            if dm_host is not None else []
-        )
-        note_transfer(
-            "d2h",
-            sum(int(np.asarray(l).nbytes) for l in inc)
-            + sum(int(np.asarray(l).nbytes) for l in dm_leaves),
-            buffers=len(inc) + len(dm_leaves),
-        )
-        link = publish_sweep_transfers(link0)
-        evaluations = int(sum(sum(p.num_configs) for p in plans))
-        vector = [float(x) for x in np.asarray(inc.vector)]
-        loss = float(np.asarray(inc.loss))
-        bracket = int(np.asarray(inc.bracket))
-        per_bracket = [float(x) for x in np.asarray(inc.per_bracket_loss)]
-        from hpbandster_tpu.obs.trace import current_trace, new_trace, use_trace
-
-        inc_trace = current_trace() or new_trace(self.run_id)
-        with use_trace(inc_trace):
+        with sweep_span("result", PROMOTION, phase_s):
+            dm_leaves = (
+                list(jax.tree_util.tree_leaves(dm_host))
+                if dm_host is not None else []
+            )
+            note_transfer(
+                "d2h",
+                sum(int(np.asarray(l).nbytes) for l in inc)
+                + sum(int(np.asarray(l).nbytes) for l in dm_leaves),
+                buffers=len(inc) + len(dm_leaves),
+            )
+            link = publish_sweep_transfers(link0)
+            evaluations = int(sum(sum(p.num_configs) for p in plans))
+            vector = [float(x) for x in np.asarray(inc.vector)]
+            loss = float(np.asarray(inc.loss))
+            bracket = int(np.asarray(inc.bracket))
+            per_bracket = [float(x) for x in np.asarray(inc.per_bracket_loss)]
             # span-shaped device slice: the resident sweep is one chunk,
             # so the flight recorder gets a rung_compute interval to lay
             # the decoded per-rung sections onto
@@ -1112,35 +1249,34 @@ class FusedBOHB:
                 h2d_bytes=link["transfer_bytes_h2d"],
                 host_syncs=link["transfers_h2d"] + link["transfers_d2h"],
             )
-        out = {
-            "incumbent": {
-                "vector": vector,
-                "loss": loss,
-                "bracket": bracket,
-                "per_bracket_loss": per_bracket,
-            },
-            "evaluations": evaluations,
-            "build_compile_s": round(compile_s, 4),
-            "compile_cache_hit": cache_hit,
-            "execute_fetch_s": round(execute_s, 4),
-            "transfers": link,
-        }
-        if dm_host is not None:
-            from hpbandster_tpu.obs.device_metrics import (
-                decode_device_metrics,
-                emit_device_telemetry,
-                publish_device_metrics,
-            )
+            out = {
+                "incumbent": {
+                    "vector": vector,
+                    "loss": loss,
+                    "bracket": bracket,
+                    "per_bracket_loss": per_bracket,
+                },
+                "evaluations": evaluations,
+                "build_compile_s": round(compile_s, 4),
+                "compile_cache_hit": cache_hit,
+                "execute_fetch_s": round(execute_s, 4),
+                "transfers": link,
+            }
+            if dm_host is not None:
+                from hpbandster_tpu.obs.device_metrics import (
+                    decode_device_metrics,
+                    emit_device_telemetry,
+                    publish_device_metrics,
+                )
 
-            decoded = decode_device_metrics(
-                dm_host, plans=plans, execute_s=execute_s
-            )
-            publish_device_metrics(decoded)
-            with use_trace(inc_trace):
+                decoded = decode_device_metrics(
+                    dm_host, plans=plans, execute_s=execute_s
+                )
+                publish_device_metrics(decoded)
                 emit_device_telemetry(decoded)
                 _note_device_refits(decoded)
-            self.last_device_telemetry = decoded
-            out["device_telemetry"] = decoded
+                self.last_device_telemetry = decoded
+                out["device_telemetry"] = decoded
         return out
 
     def _can_stream_warm(self, multiprocess: bool, run_caps) -> bool:
@@ -1234,7 +1370,9 @@ class FusedBOHB:
         with whatever is already on disk — a second optimizer sharing the
         logger (warm-start flow) or a checkpoint-resumed run appends rather
         than clobbering the earlier timing trail; entries already present
-        verbatim (restored-from-checkpoint stats) are not duplicated."""
+        (restored-from-checkpoint stats) are not duplicated. Each row's
+        ``phase_s`` is the sweep's breakdown by span name: what a stalled
+        sweep names its phase with, no profiler needed."""
         results_fn = getattr(self.result_logger, "results_fn", None)
         if not results_fn:
             return
@@ -1249,7 +1387,16 @@ class FusedBOHB:
                     existing = json.load(fh)
             except (OSError, ValueError):
                 existing = []
-        merged = existing + [s for s in self.run_stats if s not in existing]
+        # a row restored from a checkpoint lacks the spans that closed
+        # after the checkpoint was written: the same row all the same, and
+        # the file's copy is the finished one
+        def identity(row):
+            return {k: v for k, v in row.items() if k != "phase_s"}
+
+        on_disk = [identity(s) for s in existing]
+        merged = existing + [
+            s for s in self.run_stats if identity(s) not in on_disk
+        ]
         with open(path, "w") as fh:
             json.dump(merged, fh, indent=1)
 
@@ -1294,8 +1441,13 @@ class FusedBOHB:
 
     # --------------------------------------------------------------- replay
     def _replay_bracket(
-        self, b_i: int, plan, out, stages, job_info: Optional[Dict] = None
+        self, b_i: int, plan, out, stages, job_info: Optional[Dict], span
     ) -> None:
+        """One bracket's device outputs into the reference's bookkeeping.
+        ``span(name, phase)`` opens a ``sweep_span`` of the caller's: its
+        row's ``phase_s``, its sweep's trace."""
+        from hpbandster_tpu.obs.timeline import PROMOTION
+
         vectors = np.asarray(out.vectors)
         mb_mask = np.asarray(out.model_based)
         promotion_sets = [set(int(i) for i in idx) for idx, _ in stages[1:]]
@@ -1321,19 +1473,27 @@ class FusedBOHB:
         )
         self.iterations.append(it)
 
-        for i in range(plan.num_configs[0]):
-            cfg = dict(self.configspace.from_vector(vectors[i]))
-            it.add_configuration(
-                cfg,
-                {
-                    "model_based_pick": bool(mb_mask[i]),
-                    # decision detail (KDE budget, l/g score) stayed on
-                    # device; the audit record still attributes the arm
-                    "sample_reason": "fused_sweep",
-                    "fused_sweep": True,
-                },
-            )
+        # two spans a bracket, never one an evaluation: decoding the
+        # bracket's configurations, then replaying its runs
+        with span("replay.configs", PROMOTION):
+            for i in range(plan.num_configs[0]):
+                cfg = dict(self.configspace.from_vector(vectors[i]))
+                it.add_configuration(
+                    cfg,
+                    {
+                        "model_based_pick": bool(mb_mask[i]),
+                        # decision detail (KDE budget, l/g score) stayed on
+                        # device; the audit record still attributes the arm
+                        "sample_reason": "fused_sweep",
+                        "fused_sweep": True,
+                    },
+                )
+        with span("replay.runs", PROMOTION):
+            self._replay_runs(it, stages, job_info)
 
+    def _replay_runs(self, it, stages, job_info) -> None:
+        """Every run of one replayed bracket: ``Job``, journal record,
+        result logger, ``register_result``."""
         loss_of = [dict(zip(map(int, idx), map(float, losses))) for idx, losses in stages]
         stage_no = 0
         while True:

@@ -50,6 +50,12 @@ class LRUCache:
             while len(self._data) > self.maxsize:
                 self._data.popitem(last=False)
 
+    def values(self) -> list:
+        """A snapshot of the cached values, oldest first; recency is not
+        refreshed."""
+        with self._lock:
+            return list(self._data.values())
+
     def __len__(self) -> int:
         with self._lock:
             return len(self._data)

@@ -129,12 +129,17 @@ def make_mlp_ensemble(
         params = init_mlp_params(init_key, cfg, hp[3])
         return EnsembleState(params, jax.tree.map(jnp.zeros_like, params))
 
+    # device phase names (obs.timeline.DEVICE_SCOPES): lane set-up and the
+    # SGD steps are "hpb.train", the loss pass that ends a rung
+    # "hpb.validate", so a trace tells the trainer's seconds apart
     def init_fn(vectors: jax.Array) -> EnsembleState:
-        return jax.vmap(init_one)(vectors)
+        with jax.named_scope("hpb.train"):
+            return jax.vmap(init_one)(vectors)
 
     def train_one(state: EnsembleState, vec: jax.Array, n_steps: int,
                   step0: int):
-        lr, momentum, wd, _ = decode_mlp_hparams(vec)
+        with jax.named_scope("hpb.train"):
+            lr, momentum, wd, _ = decode_mlp_hparams(vec)
 
         def body(carry, t):
             p, v = carry
@@ -151,11 +156,14 @@ def make_mlp_ensemble(
         # scan, not while_loop: the trip count is static (concrete rung
         # budgets), which XLA unrolls/pipelines better and keeps the
         # minibatch offset arithmetic pure index math
-        (p, v), _ = jax.lax.scan(
-            body, (state.params, state.velocity),
-            jnp.arange(n_steps, dtype=jnp.int32),
-        )
-        return EnsembleState(p, v), _xent(mlp_forward(p, x_val), y_val)
+        with jax.named_scope("hpb.train"):
+            (p, v), _ = jax.lax.scan(
+                body, (state.params, state.velocity),
+                jnp.arange(n_steps, dtype=jnp.int32),
+            )
+        with jax.named_scope("hpb.validate"):
+            loss = _xent(mlp_forward(p, x_val), y_val)
+        return EnsembleState(p, v), loss
 
     def step_fn(state: EnsembleState, vectors: jax.Array, budget,
                 prev_budget):

@@ -44,6 +44,7 @@ from hpbandster_tpu.obs.timeline import (
     mark,
     normalized_time,
     phase_span,
+    sweep_span,
     to_chrome_trace,
 )
 
@@ -384,6 +385,65 @@ class TestSpanApi:
         Event exists to observe."""
         assert not obs.get_bus().active
         assert mark("probe", RUNG_COMPUTE) is None
+
+    def test_inactive_phase_span_reads_no_clock(self, monkeypatch):
+        """The host-pool tiers' spans (``obs.span``, ``phase_span``) keep
+        the inactive path they had: no sink, no clock read. The fused
+        driver's ``sweep_span`` is the one that always measures."""
+        from hpbandster_tpu.obs import events
+
+        def no_clock():
+            raise AssertionError("an inactive span read the clock")
+
+        assert not obs.get_bus().active
+        monkeypatch.setattr(events.time, "monotonic", no_clock)
+        with phase_span("probe", ADMISSION):
+            pass
+        with obs.span("probe"):
+            pass
+
+    def test_obs_imports_no_jax(self):
+        """``sweep_span`` imports jax when it is entered, never before:
+        the host-pool tiers import ``obs`` and stay jax-free."""
+        import subprocess
+        import sys
+
+        code = ("import sys; import hpbandster_tpu.obs; "
+                "from hpbandster_tpu.obs.timeline import sweep_span; "
+                "sys.exit('jax' in sys.modules)")
+        repo = str(Path(__file__).parent.parent)
+        assert subprocess.run([sys.executable, "-c", code], cwd=repo).returncode == 0
+
+    def test_sweep_span_always_measures_and_journals_with_a_sink(self):
+        with pytest.raises(ValueError, match="unknown phase"):
+            sweep_span("x", "not_a_phase")
+        # no sink: the seconds are kept all the same, and accumulate
+        totals = {}
+        assert not obs.get_bus().active
+        for _ in range(2):
+            with sweep_span("fetch", RUNG_COMPUTE, totals):
+                pass
+        assert set(totals) == {"fetch"} and totals["fetch"] >= 0.0
+        # re-pointed before exit: the seconds land where it points then
+        row = {}
+        with sweep_span("run", ADMISSION, totals) as run_span:
+            run_span.totals = row
+        assert set(row) == {"run"} and set(totals) == {"fetch"}
+        # with a sink: phase_span's event, under the trace it was given
+        trace = obs.new_trace("sweep")
+        rec = TimelineRecorder(static_fields={"host": "h0", "pid": 7})
+        with rec:
+            with sweep_span("dispatch", PROMOTION, totals, trace=trace, seq=3):
+                mark("inside", PROMOTION)
+            with pytest.raises(KeyError):
+                with sweep_span("result", PROMOTION, totals):
+                    raise KeyError("boom")
+        inside, dispatch, result = rec.records
+        assert dispatch["event"] == "dispatch" and dispatch["seq"] == 3
+        assert dispatch["phase"] == PROMOTION and dispatch["duration_s"] >= 0
+        assert dispatch["trace_id"] == trace.trace_id
+        assert "trace_id" not in inside and "trace_id" not in result
+        assert result["error"] == "KeyError" and "result" in totals
 
 
 class TestCli:
