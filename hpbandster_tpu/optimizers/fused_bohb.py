@@ -20,6 +20,7 @@ representation (construction raises ``ValueError`` for those).
 from __future__ import annotations
 
 import logging
+import math
 import sys
 import time
 from typing import Any, Dict, List, Optional
@@ -27,6 +28,7 @@ from typing import Any, Dict, List, Optional
 import numpy as np
 
 from hpbandster_tpu import obs
+from hpbandster_tpu.core.iteration import Status
 from hpbandster_tpu.core.job import Job
 from hpbandster_tpu.core.result import Result
 from hpbandster_tpu.core.successive_halving import SuccessiveHalving
@@ -956,6 +958,9 @@ class FusedBOHB:
                     # where this chunk's warm observations came from: 0 bytes
                     # uploaded = the donated device thread carried them
                     "warm_upload_bytes": int(upload_bytes),
+                    # evaluations whose replay built a Job: all of them
+                    # under a result logger or a journal sink, else none
+                    "replay_jobs_built": 0,
                     # seconds per span name, this chunk's share of the
                     # sweep's wall; the spans that close after this point
                     # (this one, obs_fold, the chunk's bracket_replay
@@ -1014,14 +1019,14 @@ class FusedBOHB:
                     # the Master's, sees all past results
                     self._accumulate_obs(plan, out, stages)
 
-            def replay_now(staged=staged, job_info=job_info, phase_s=phase_s):
+            def replay_now(staged=staged, job_info=job_info, stat=stat):
                 # the replay is promotion bookkeeping wherever it runs —
                 # here, or inside the next chunk's device window — and its
-                # seconds go to the row of the chunk it replays
-                span = functools.partial(sweep_span, totals=phase_s)
+                # seconds and its count go to the row of the chunk it replays
+                span = functools.partial(sweep_span, totals=stat["phase_s"])
                 with span("bracket_replay", PROMOTION):
                     for b_i, plan, out, stages in staged:
-                        self._replay_bracket(
+                        stat["replay_jobs_built"] += self._replay_bracket(
                             b_i, plan, out, stages, job_info, span
                         )
 
@@ -1442,10 +1447,11 @@ class FusedBOHB:
     # --------------------------------------------------------------- replay
     def _replay_bracket(
         self, b_i: int, plan, out, stages, job_info: Optional[Dict], span
-    ) -> None:
+    ) -> int:
         """One bracket's device outputs into the reference's bookkeeping.
         ``span(name, phase)`` opens a ``sweep_span`` of the caller's: its
-        row's ``phase_s``, its sweep's trace."""
+        row's ``phase_s``, its sweep's trace. Returns the ``Job`` objects
+        the runs' replay built (:meth:`_replay_runs`)."""
         from hpbandster_tpu.obs.timeline import PROMOTION
 
         vectors = np.asarray(out.vectors)
@@ -1489,53 +1495,96 @@ class FusedBOHB:
                     },
                 )
         with span("replay.runs", PROMOTION):
-            self._replay_runs(it, stages, job_info)
+            return self._replay_runs(it, stages, job_info)
 
-    def _replay_runs(self, it, stages, job_info) -> None:
-        """Every run of one replayed bracket: ``Job``, journal record,
-        result logger, ``register_result``."""
-        loss_of = [dict(zip(map(int, idx), map(float, losses))) for idx, losses in stages]
-        stage_no = 0
-        while True:
-            nr = it.get_next_run()
-            if nr is None:
-                if not it.process_results():
-                    break
-                stage_no += 1
-                continue
-            config_id, cfg, budget = nr
-            job = Job(
-                config_id,
-                config=cfg,
-                budget=budget,
-                working_directory=self.working_directory,
-            )
-            job.time_it("submitted")
-            job.time_it("started")
-            loss = loss_of[stage_no][config_id[2]]
-            # mirror register_result: only NaN means crashed; a genuine
-            # +/-inf loss (diverged run) is a valid maximally-bad result
-            if not np.isnan(loss):
-                job.result = {"loss": loss, "info": dict(job_info or {})}
-            else:
-                job.result = None
-                job.exception = f"non-finite loss {loss!r} at budget {budget}"
-            job.time_it("finished")
-            # the fused tier's loss-carrying result record — journal
-            # parity with Master.job_callback (no run_s: the evaluation
-            # executed inside a fused device chunk, per-job host timing
-            # would be fiction; sweep_chunk carries the real durations)
-            obs.emit(
-                obs.JOB_FAILED if job.exception is not None else obs.JOB_FINISHED,
-                config_id=list(config_id), budget=budget,
-                # non-finite (NaN-crashed or inf-diverged) -> null: bare
-                # NaN/Infinity is not strict JSON (same rule as the master)
-                loss=float(loss) if np.isfinite(loss) else None,
-            )
-            if self.result_logger is not None:
-                self.result_logger(job)
-            it.register_result(job)
-            self.total_evaluated += 1
+    def _replay_runs(self, it, stages, job_info) -> int:
+        """Every run of one replayed bracket, rung by rung from the
+        device's arrays: each lane's ``Datum`` takes what
+        ``register_result`` would write, then one ``process_results()``
+        advances the bracket and makes the rung's promotion records.
+        Nothing is ever pending here, so ``get_next_run`` is not polled:
+        it rescans the bracket from its first entry on every call.
+
+        A ``Job`` is an object for someone to look at: it is built,
+        journalled and handed to the result logger only while a logger or
+        a sink is attached. Returns how many were built."""
+        now = time.time
+        jobs_built = 0
+        for stage_no, (idx, losses) in enumerate(stages):
+            budget = it.budgets[stage_no]
+            observed = self.result_logger is not None or obs.get_bus().active
+            # ascending lane index is the bracket's insertion order, the
+            # order get_next_run handed runs out in: the journal and the
+            # incumbent trajectory (sorted by 'finished') keep it
+            order = np.argsort(idx, kind="stable")
+            for lane, loss in zip(
+                idx[order].tolist(), losses[order].tolist()
+            ):
+                config_id = (it.HPB_iter, 0, lane)
+                datum = it.data[config_id]
+                if datum.status != Status.QUEUED or datum.budget != budget:
+                    raise RuntimeError(
+                        f"device ran {config_id} at budget {budget}, the "
+                        f"bracket holds it {datum.status.name} at "
+                        f"{datum.budget}"
+                    )
+                # only NaN means crashed; a genuine +/-inf loss (diverged
+                # run) is a valid maximally-bad result
+                crashed = loss != loss
+                exception = (
+                    f"non-finite loss {loss!r} at budget {budget}"
+                    if crashed else None
+                )
+                info = None if crashed else dict(job_info or {})
+                if observed:
+                    job = Job(
+                        config_id,
+                        config=datum.config,
+                        budget=budget,
+                        working_directory=self.working_directory,
+                    )
+                    job.time_it("submitted")
+                    job.time_it("started")
+                    if not crashed:
+                        job.result = {"loss": loss, "info": info}
+                    job.exception = exception
+                    job.time_it("finished")
+                    stamps = dict(job.timestamps)
+                    # the fused tier's loss-carrying result record — journal
+                    # parity with Master.job_callback (no run_s: the
+                    # evaluation executed inside a fused device chunk,
+                    # per-job host timing would be fiction; sweep_chunk
+                    # carries the real durations)
+                    obs.emit(
+                        obs.JOB_FAILED if crashed else obs.JOB_FINISHED,
+                        config_id=list(config_id), budget=budget,
+                        # non-finite (NaN-crashed or inf-diverged) -> null:
+                        # bare NaN/Infinity is not strict JSON (same rule
+                        # as the master)
+                        loss=loss if math.isfinite(loss) else None,
+                    )
+                    if self.result_logger is not None:
+                        self.result_logger(job)
+                    jobs_built += 1
+                else:
+                    stamps = {
+                        "submitted": now(), "started": now(), "finished": now(),
+                    }
+                datum.results[budget] = None if crashed else loss
+                datum.exceptions[budget] = exception
+                datum.time_stamps[budget] = stamps
+                if not crashed:
+                    datum.infos[budget] = info
+                # crashed evaluations stay in the bracket as REVIEW with a
+                # None loss, as register_result leaves them: never promoted
+                datum.status = Status.REVIEW
+                self.total_evaluated += 1
+            if not it.process_results():
+                raise RuntimeError(
+                    f"bracket {it.HPB_iter} did not advance past rung "
+                    f"{stage_no}: the device's lanes do not fill it"
+                )
+        return jobs_built
 
     def shutdown(self, shutdown_workers: bool = False) -> None:
         """API symmetry with Master; nothing to tear down."""
