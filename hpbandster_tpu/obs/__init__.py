@@ -200,6 +200,7 @@ from hpbandster_tpu.obs.timeline import (  # noqa: F401
     ADMISSION,
     COMPILE,
     DEVICE_SCOPES,
+    LANE_SCOPES,
     PHASES,
     PROMOTION,
     RPC,
@@ -272,7 +273,7 @@ __all__ = [
     "DEVICE_TELEMETRY", "RPC_CLIENT_CALL",
     "PHASES", "ADMISSION", "COMPILE", "TRANSFER", "RUNG_COMPUTE",
     "PROMOTION", "RPC",
-    "DEVICE_SCOPES", "sweep_span",
+    "DEVICE_SCOPES", "LANE_SCOPES", "sweep_span",
     "phase_span", "mark", "TimelineRecorder", "align_clocks",
     "build_timeline", "to_chrome_trace", "critical_path",
     "format_critical_path",

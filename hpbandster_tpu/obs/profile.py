@@ -38,7 +38,7 @@ import re
 import tempfile
 import threading
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Sequence
 
 from hpbandster_tpu.obs.metrics import get_metrics
 
@@ -408,7 +408,7 @@ _HLO_CALLEES = re.compile(
     r"=%?([\w.\-]+)"
 )
 _HLO_BRANCHES = re.compile(r"branch_computations=\{([^}]*)\}")
-_SCOPE_NAME = re.compile(r"hpb\.[a-z_]+")
+_SCOPE_NAME = re.compile(r"[a-z]+\.[a-z_]+")
 
 
 def _hlo_text(compiled: Any) -> str:
@@ -425,7 +425,9 @@ def hlo_module_name(compiled: Any) -> str:
     return match.group(1)
 
 
-def device_phase_map(compiled: Any) -> Dict[str, str]:
+def device_phase_map(
+    compiled: Any, scopes: Optional[Sequence[str]] = None
+) -> Dict[str, str]:
     """``{instruction name: phase}`` of one compiled program, read off its
     optimized HLO text (``compiled.as_text()``; the text itself is taken
     too).
@@ -437,14 +439,18 @@ def device_phase_map(compiled: Any) -> Dict[str, str]:
     an instruction's phase is the scope of
     :data:`~hpbandster_tpu.obs.timeline.DEVICE_SCOPES` found in it
     (``jit(sweep)/vmap(hpb.train)/while/body/...`` is ``hpb.train``; the
-    scopes are flat, so there is at most one, and the last would win). An
+    scopes are flat, so there is at most one, and the last would win).
+    ``scopes`` names another closed list to read by
+    (:data:`~hpbandster_tpu.obs.timeline.LANE_SCOPES`, the parts of a
+    lane); names of one list are not seen when reading by another. An
     instruction without one inside a nested computation — a loop's body,
     a fusion, a reducer — inherits its caller's. Instructions that stay
     without a phase are left out. Names lose their ``%``.
 
     Parsing a large program's text takes seconds: call this on demand,
     never on a sweep's path."""
-    from hpbandster_tpu.obs.timeline import DEVICE_SCOPES
+    if scopes is None:
+        from hpbandster_tpu.obs.timeline import DEVICE_SCOPES as scopes
 
     computations: Dict[str, List[Any]] = {}
     entry, current = None, None
@@ -459,15 +465,14 @@ def device_phase_map(compiled: Any) -> Dict[str, str]:
         if instruction is None or current is None:
             continue
         op_name = _HLO_OP_NAME.search(line)
-        scopes = [
-            s for s in _SCOPE_NAME.findall(op_name.group(1))
-            if s in DEVICE_SCOPES
+        found = [
+            s for s in _SCOPE_NAME.findall(op_name.group(1)) if s in scopes
         ] if op_name else []
         callees = _HLO_CALLEES.findall(line)
         for group in _HLO_BRANCHES.findall(line):
             callees += [c.strip().lstrip("%") for c in group.split(",")]
         current.append(
-            (instruction.group(1), scopes[-1] if scopes else None, callees)
+            (instruction.group(1), found[-1] if found else None, callees)
         )
     if entry is None:
         raise ValueError("the compiled text has no ENTRY computation")

@@ -68,6 +68,7 @@ __all__ = [
     "PHASES",
     "SPAN_PREFIX",
     "DEVICE_SCOPES",
+    "LANE_SCOPES",
     "phase_span",
     "sweep_span",
     "mark",
@@ -123,6 +124,21 @@ DEVICE_SCOPES = (
     "hpb.promote",     # rank key, masked top-k, gather of survivors' state
     "hpb.obs_update",  # folding results into observation/output buffers
     "hpb.incumbent",   # the cross-bracket incumbent fold
+)
+
+#: a second closed list, of the parts of one lane: ``jax.named_scope``
+#: names that a workload whose lane has layers of several kinds
+#: (``workloads/kimi_linear.py``) sets *inside* ``hpb.train`` and
+#: ``hpb.validate``. The two families do not see each other:
+#: ``device_phase_map(compiled)`` reads the phases above,
+#: ``device_phase_map(compiled, LANE_SCOPES)`` these
+LANE_SCOPES = (
+    "lane.kda",        # gated delta-rule linear attention, its projections
+    "lane.mla",        # latent attention, its projections
+    "lane.moe",        # router, held experts, shared expert
+    "lane.dense_ffn",  # a dense feed-forward layer
+    "lane.head",       # embedding, final norm, head, loss
+    "lane.update",     # the optimizer's step
 )
 
 #: attribution priority when concurrent spans overlap (lower = wins):

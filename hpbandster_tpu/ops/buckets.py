@@ -248,21 +248,18 @@ def fused_sh_bracket_bucketed(
     import jax
     import jax.numpy as jnp
 
-    from hpbandster_tpu.ops.fused import shard_rows
+    from hpbandster_tpu.ops.fused import eval_lanes, shard_rows
 
     widths = bucket.widths
     budgets = bucket.budgets
     depth = len(widths)
     counts = jnp.asarray(counts, jnp.int32)
 
-    def eval_stage(vecs, budget: float):
-        return jax.vmap(lambda v: eval_fn(v, budget))(vecs).astype(jnp.float32)
-
     cur_vecs = shard_rows(vectors, mesh, axis)
     cur_idx = jnp.arange(widths[0], dtype=jnp.int32)
     out = []
     for t in range(depth):
-        losses_t = eval_stage(cur_vecs, float(budgets[t]))
+        losses_t = eval_lanes(eval_fn, cur_vecs, float(budgets[t]), mesh)
         out.append((cur_idx, losses_t))
         if t + 1 == depth:
             break

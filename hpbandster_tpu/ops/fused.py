@@ -16,7 +16,7 @@ mesh-padding rows), index-stably — matching ``sh_promotion_mask``.
 
 from __future__ import annotations
 
-from typing import Any, Callable, List, NamedTuple, Sequence, Tuple
+from typing import Any, Callable, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -24,8 +24,9 @@ import numpy as np
 
 from hpbandster_tpu.obs.runtime import note_transfer, tracked_jit
 
-__all__ = ["StatefulEval", "fused_sh_bracket", "make_fused_bracket_fn",
-           "shard_rows", "stage_telemetry"]
+__all__ = ["LaneFacts", "StatefulEval", "eval_lanes", "fused_sh_bracket",
+           "lanes_at_once", "make_fused_bracket_fn", "shard_rows",
+           "stage_telemetry"]
 
 #: crashed (NaN) losses map here for ranking: behind any real loss, ahead of
 #: the +inf padding rows, ties broken index-stably by top_k — the same
@@ -140,6 +141,156 @@ class StatefulEval(NamedTuple):
     step_fn: Callable[[Any, jax.Array, float, float], Tuple[Any, jax.Array]]
 
 
+class LaneFacts(NamedTuple):
+    """What a workload's maker states about one lane of its stateless
+    ``eval_fn``, attached as ``eval_fn.lane_facts``: facts the sweep cannot
+    see from outside the function and acts on (:func:`eval_lanes`) or
+    accounts with (``FusedBOHB.run_stats``). An ``eval_fn`` without the
+    attribute is evaluated and accounted exactly as before there was one."""
+
+    #: device bytes one lane needs while it is evaluated: parameters,
+    #: optimizer state, gradients and the peak of its activations
+    bytes: int
+    #: tokens one unit of budget trains on (0: budget is not in tokens)
+    tokens_per_step: int = 0
+    #: names of the numbers an evaluation counts on the device beside its
+    #: loss, and ``with_counters(vec, budget) -> (loss, f32[len(counters)])``
+    counters: Tuple[str, ...] = ()
+    with_counters: Any = None
+    #: the evaluation takes its budget as a traced value too (no Python
+    #: loop over it): lanes in turn can then share one trace of the lane
+    #: over all the rungs of a bracket (:func:`_sh_bracket_in_turn`)
+    traced_budget: bool = False
+
+
+def _device_memory_bytes():
+    """What one device of the default backend can hold, or ``None`` where
+    the backend does not say (the CPU)."""
+    stats = jax.devices()[0].memory_stats()
+    return stats.get("bytes_limit") if stats else None
+
+
+def lanes_at_once(eval_fn, n_lanes: int) -> int:
+    """How many of a rung's ``n_lanes`` lanes are evaluated side by side:
+    all of them, unless the maker stated a footprint
+    (``eval_fn.lane_facts.bytes``) under which they do not fit the
+    device's memory together; then as many as do, and at least one."""
+    facts = getattr(eval_fn, "lane_facts", None)
+    memory = _device_memory_bytes() if facts is not None else None
+    if memory is None or n_lanes * facts.bytes <= memory:
+        return n_lanes
+    return max(int(memory // facts.bytes), 1)
+
+
+def eval_lanes(eval_fn, vecs: jax.Array, budget: float, mesh=None,
+               counters: Optional[list] = None) -> jax.Array:
+    """A rung's losses ``f32[n]`` from its vectors ``f32[n, d]``: the one
+    definition of how a rung evaluates the lanes of a stateless
+    ``eval_fn``. Lanes that fit the device side by side are one ``vmap``;
+    lanes that do not (:func:`lanes_at_once`) are taken in turn by
+    ``jax.lax.map``, as many at once as fit; where that is one, and in a
+    rung of one lane, the lane is traced unbatched. With ``counters`` a
+    list and an ``eval_fn`` that counts on the device
+    (``lane_facts.counters``), the rung's ``f32[n, len(counters)]`` is
+    appended to it."""
+    facts = getattr(eval_fn, "lane_facts", None)
+    counted = counters is not None and facts is not None and bool(facts.counters)
+    one = ((lambda v: facts.with_counters(v, budget)) if counted
+           else (lambda v: eval_fn(v, budget)))
+    n = vecs.shape[0]
+    at_once = lanes_at_once(eval_fn, n)
+    if facts is None or 1 < n <= at_once:
+        out = jax.vmap(one)(vecs)
+    elif mesh is not None and at_once < n:
+        raise NotImplementedError(
+            f"{n} lanes of {facts.bytes} bytes do not fit one device side by "
+            "side, and lanes in turn are not sharded over a mesh yet")
+    elif at_once == 1 or n == 1:
+        # a lane that states its footprint is traced unbatched where it
+        # runs alone, the single lane of a last rung too
+        out = jax.lax.map(one, vecs)
+    else:
+        out = jax.lax.map(one, vecs, batch_size=at_once)
+    if counted:
+        out, counts = out
+        counters.append(counts.astype(jnp.float32))
+    return out.astype(jnp.float32)
+
+
+def _sh_bracket_in_turn(eval_fn, vectors, num_configs, budgets, rank_key,
+                        scores_for, lane_counters):
+    """:func:`fused_sh_bracket` for lanes that run one at a time and take a
+    traced budget: ONE loop over the bracket's evaluations, every rung's,
+    so the program holds the lane once and not once a rung (a lane of a
+    published block takes a minute to compile). Slot ``i`` evaluates row
+    ``i`` of a queue of vectors at its rung's budget; after a rung's last
+    slot the promotion of :func:`fused_sh_bracket`, the same arithmetic on
+    the same arrays, fills the next rung's rows of the queue."""
+    n0, n_rows = int(num_configs[0]), vectors.shape[0]
+    widths = [n_rows] + [int(k) for k in num_configs[1:]]
+    starts = np.concatenate([[0], np.cumsum(widths)])
+    total, depth = int(starts[-1]), len(widths)
+    rows = [slice(int(starts[s]), int(starts[s + 1])) for s in range(depth)]
+    facts = eval_fn.lane_facts
+    counted = lane_counters is not None and bool(facts.counters)
+    stage_of = np.repeat(np.arange(depth), widths)
+    # after slot i: nothing (0), or the promotion that follows rung s (s + 1)
+    then = np.zeros(total, np.int32)
+    then[starts[1:-1] - 1] = np.arange(1, depth)
+
+    def promote(s, carry):
+        """What follows rung ``s``: rank its survivors by their history
+        and write rung ``s + 1``'s vectors, indices and local top-k."""
+        queue, losses, counts, idx, tops = carry
+        with jax.named_scope("hpb.promote"):
+            history = []
+            for r in range(s + 1):
+                col = losses[rows[r]]
+                for later in range(r + 1, s + 1):
+                    col = col[tops[later - 1]]
+                history.append(col)
+            cur_idx = (jnp.arange(n_rows, dtype=jnp.int32) if s == 0
+                       else idx[s - 1])
+            is_pad = (cur_idx >= n0 if s == 0
+                      else jnp.zeros_like(cur_idx, dtype=bool))
+            _, top = jax.lax.top_k(
+                -rank_key(scores_for(history, s), is_pad), widths[s + 1])
+            top = jnp.sort(top)
+            sel_idx = cur_idx[top]
+            queue = jax.lax.dynamic_update_slice_in_dim(
+                queue, vectors[sel_idx], rows[s + 1].start, axis=0)
+            idx = idx[:s] + (sel_idx,) + idx[s + 1:]
+            tops = tops[:s] + (top,) + tops[s + 1:]
+        return queue, losses, counts, idx, tops
+
+    def slot(i, carry):
+        queue, losses, counts, idx, tops = carry
+        budget = jnp.asarray(budgets, jnp.float32)[jnp.asarray(stage_of)[i]]
+        with jax.named_scope("hpb.train"):
+            if counted:
+                loss, count = facts.with_counters(queue[i], budget)
+                counts = counts.at[i].set(count.astype(jnp.float32))
+            else:
+                loss = eval_fn(queue[i], budget)
+        losses = losses.at[i].set(loss.astype(jnp.float32))
+        carry = (queue, losses, counts, idx, tops)
+        return jax.lax.switch(
+            jnp.asarray(then)[i],
+            [lambda c: c] + [lambda c, s=s: promote(s, c) for s in range(depth - 1)],
+            carry)
+
+    later = tuple(jnp.zeros((k,), jnp.int32) for k in widths[1:])
+    queue = jnp.zeros((total, vectors.shape[1]), vectors.dtype).at[:n_rows].set(vectors)
+    _, losses, counts, idx, _ = jax.lax.fori_loop(0, total, slot, (
+        queue, jnp.zeros((total,), jnp.float32),
+        jnp.zeros((total, len(facts.counters) if counted else 0), jnp.float32),
+        later, later))
+    if counted:
+        lane_counters.extend(counts[r] for r in rows)
+    return ([(jnp.arange(n0, dtype=jnp.int32), losses[:n0])]
+            + [(idx[s - 1], losses[rows[s]]) for s in range(1, depth)])
+
+
 def _shard_state(state, mesh, axis: str):
     """Naive per-leaf sharding of an ensemble state: every leaf's leading
     config axis stays distributed over ``axis`` (the SNIPPETS
@@ -162,6 +313,7 @@ def fused_sh_bracket(
     axis: str = "config",
     stateful: "StatefulEval" = None,
     return_final_state: bool = False,
+    lane_counters: Optional[list] = None,
 ) -> List[Tuple[jax.Array, jax.Array]]:
     """Trace one whole bracket. Returns per-stage ``(indices, losses)``
     where ``indices`` index the original (unpadded) stage-0 rows.
@@ -192,6 +344,11 @@ def fused_sh_bracket(
     get. ``return_final_state=True`` additionally returns the last stage's
     surviving state (``(stages, state)``) for callers that extract trained
     weights — the fused sweep itself leaves it device-internal.
+
+    ``lane_counters`` (a list) receives, per stage and in stage order, the
+    ``f32[n_s, k]`` that an ``eval_fn`` with device counters
+    (:class:`LaneFacts`) counted beside its losses; it stays empty for
+    every other evaluation.
     """
     if (eval_fn is None) == (stateful is None):
         raise ValueError(
@@ -210,9 +367,7 @@ def fused_sh_bracket(
     # training and validation (workloads/ensemble.py)
     def eval_stage(vecs: jax.Array, budget: float) -> jax.Array:
         with jax.named_scope("hpb.train"):
-            return jax.vmap(lambda v: eval_fn(v, budget))(vecs).astype(
-                jnp.float32
-            )
+            return eval_lanes(eval_fn, vecs, budget, mesh, lane_counters)
 
     def rank_key(scores: jax.Array, is_pad: jax.Array) -> jax.Array:
         key = jnp.where(jnp.isnan(scores), _CRASH_RANK, scores)
@@ -238,6 +393,11 @@ def fused_sh_bracket(
         return scores
 
     vectors = shard_rows(vectors, mesh, axis)
+    facts = getattr(eval_fn, "lane_facts", None)
+    if (facts is not None and facts.traced_budget and mesh is None
+            and len(num_configs) > 1 and lanes_at_once(eval_fn, n_rows) == 1):
+        return _sh_bracket_in_turn(eval_fn, vectors, num_configs, budgets,
+                                   rank_key, scores_for, lane_counters)
     state = None
     if stateful is not None:
         # one lane per row (padding rows train too — they can never be

@@ -31,7 +31,7 @@ construction — the per-bracket path remains the fallback for those.
 
 from __future__ import annotations
 
-from typing import Callable, List, NamedTuple, Optional, Sequence, Tuple
+from typing import Any, Callable, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -592,6 +592,10 @@ class SweepBracketOutput(NamedTuple):
     idx_packed: jax.Array
     #: matching losses (NaN = crashed), f32[sum(ns)]
     loss_packed: jax.Array
+    #: what an ``eval_fn`` with device counters (``ops.fused.LaneFacts``)
+    #: counted beside each loss, f32[sum(ns), k]; ``None`` (no leaf, so the
+    #: program's outputs are what they were) for every other evaluation
+    lane_counters: Any = None
 
 
 class SweepIncumbent(NamedTuple):
@@ -760,7 +764,7 @@ def unstack_resident_outputs(
     outs: List[SweepBracketOutput] = []
     for r in range(int(n_rounds)):
         for pos_out in raw.stacked:
-            outs.append(SweepBracketOutput(*(leaf[r] for leaf in pos_out)))
+            outs.append(jax.tree.map(lambda leaf: leaf[r], pos_out))
     outs.extend(SweepBracketOutput(*o) for o in raw.tail)
     return outs
 
@@ -1300,9 +1304,10 @@ def make_fused_sweep_fn(
                 proposals, mb_mask, k_rand, n0
             )
 
+        lane_counters: List[jax.Array] = []
         stages = fused_sh_bracket(
             eval_fn, eval_vectors, plan.num_configs, plan.budgets,
-            rank_fn=rank_fn,
+            rank_fn=rank_fn, lane_counters=lane_counters,
             # per-stage sharding constraints: the rung ladder's
             # survivor batches stay distributed over the config axis
             # (promotion masks reduce across shards on-device)
@@ -1341,7 +1346,11 @@ def make_fused_sweep_fn(
             with jax.named_scope("hpb.obs_update"):
                 idx_packed, loss_packed = _pack_stages(stages)
                 out = SweepBracketOutput(
-                    out_vectors[:n0], mb_mask, idx_packed, loss_packed
+                    out_vectors[:n0], mb_mask, idx_packed, loss_packed,
+                    # stage 0 counted its padding rows too
+                    jnp.concatenate([c[:k] for c, k in zip(
+                        lane_counters, plan.num_configs)])
+                    if lane_counters else None,
                 )
         return obs_v, obs_l, counts, inc, metrics, out
 

@@ -57,13 +57,15 @@ __all__ = ["FusedBOHB", "FusedHyperBand", "FusedRandomSearch", "FusedH2BO",
 _SWEEP_EXE_CACHE: LRUCache = LRUCache(maxsize=16)
 
 
-def sweep_phase_maps() -> Dict[str, Dict[str, str]]:
+def sweep_phase_maps(scopes=None) -> Dict[str, Dict[str, str]]:
     """``{module name: {instruction name: phase}}`` over every sweep
     executable this process holds: the program's own map from what a
     profiler trace prints (``XLA Modules`` events named
     ``<module name>(<id>)`` enclosing ``XLA Ops`` named by instruction)
     to the phases of ``obs.timeline.DEVICE_SCOPES``
-    (``obs.profile.device_phase_map``). Two executables of one module name
+    (``obs.profile.device_phase_map``), or to those of another closed list
+    of scope names given as ``scopes`` (``obs.timeline.LANE_SCOPES``: the
+    parts of a lane, inside the trainer). Two executables of one module name
     (a chunked run that crossed a capacity bucket) share an entry; an
     instruction name they give different phases is left out, and so is an
     executable whose text names no scope at all. Parses each executable's
@@ -75,7 +77,7 @@ def sweep_phase_maps() -> Dict[str, Dict[str, str]]:
     clashed = set()
     for compiled in _SWEEP_EXE_CACHE.values():
         text = compiled.as_text()
-        phases = device_phase_map(text)
+        phases = device_phase_map(text, scopes)
         if not phases:
             # loaded from a persistent cache that a commit without scopes
             # filled (the cache's key leaves metadata out): nothing to tell
@@ -87,6 +89,34 @@ def sweep_phase_maps() -> Dict[str, Dict[str, str]]:
     for module, name in clashed:
         del maps[module][name]
     return maps
+
+
+def _lane_accounting(eval_fn, plans, outputs) -> Dict[str, Any]:
+    """What a chunk's ``run_stats`` row says of the lanes of an ``eval_fn``
+    whose maker stated its facts (``ops.fused.LaneFacts``): the steps and
+    tokens its evaluations trained (a stateless evaluation trains its whole
+    budget), how many lanes the widest rung evaluated side by side, and the
+    mean over the evaluations of each device counter. Published as gauges
+    ``sweep.lane.<name>`` too. Empty for every other evaluation."""
+    facts = getattr(eval_fn, "lane_facts", None)
+    if facts is None:
+        return {}
+    from hpbandster_tpu.ops.fused import lanes_at_once
+
+    steps = sum(n * int(round(b)) for plan in plans
+                for n, b in zip(plan.num_configs, plan.budgets))
+    row = {
+        "lane_steps": steps,
+        "lane_tokens": steps * facts.tokens_per_step,
+        "lanes_at_once": lanes_at_once(
+            eval_fn, max(n for plan in plans for n in plan.num_configs)),
+    }
+    if facts.counters:
+        counted = np.concatenate([out.lane_counters for out in outputs])
+        row.update(zip(facts.counters, np.nanmean(counted, axis=0).tolist()))
+    for name, value in row.items():
+        obs.get_metrics().gauge("sweep.lane." + name).set(value)
+    return row
 
 
 def _note_device_refits(decoded: Dict[str, Any]) -> None:
@@ -241,6 +271,11 @@ class FusedBOHB:
                             "stateful_eval.step_fn must return per-lane losses "
                             f"f32[n], got shape {getattr(losses_sds, 'shape', None)}"
                         )
+                elif getattr(eval_fn, "lane_facts", None) is not None:
+                    # a maker that states its lane's facts (ops.fused.LaneFacts)
+                    # has stated a scalar loss with them: a lane that large
+                    # takes seconds of host time to trace, every construction
+                    pass
                 else:
                     try:
                         out_sds = _jax.eval_shape(
@@ -972,6 +1007,7 @@ class FusedBOHB:
                     # host replay of the PRIOR chunk that ran inside this
                     # chunk's device window
                     stat["replay_overlap_s"] = round(overlap_s, 4)
+                stat.update(_lane_accounting(self.eval_fn, chunk_plans, outputs))
                 self.run_stats.append(stat)
                 # one span-shaped event per device chunk: the journal's view of
                 # the fused tier (duration = dispatch -> fetch; compile split

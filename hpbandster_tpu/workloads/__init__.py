@@ -66,3 +66,8 @@ from hpbandster_tpu.workloads.teacher import (  # noqa: F401
     make_teacher_eval_fn,
     teacher_space,
 )
+from hpbandster_tpu.workloads.kimi_linear import (  # noqa: F401
+    KimiLinearConfig,
+    kimi_linear_space,
+    make_kimi_linear_eval_fn,
+)

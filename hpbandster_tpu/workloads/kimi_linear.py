@@ -1,0 +1,702 @@
+"""A block of Kimi-Linear-48B-A3B-Instruct as a rung's lane.
+
+The published hybrid (``model_type`` ``kimi_linear``; Kimi Linear,
+arXiv:2510.26692; widths from the model's ``config.json``): pre-norm
+residual layers ``h += Mixer(RMSNorm(h)); h += FFN(RMSNorm(h))`` whose
+mixers are Kimi Delta Attention (KDA, a gated delta-rule linear attention
+with per-channel decay) three times out of four and latent attention
+without positions (NoPE MLA) the fourth, and whose feed-forward is one
+dense SwiGLU layer first and then 256 sigmoid-routed experts, 8 a token,
+beside one shared expert. A final RMSNorm and an untied head close it.
+
+What trains here is **one chip's share** of that model
+(:class:`KimiLinearConfig`'s last fields): ``layer_kinds`` (the layers
+held), ``experts_held`` (which of the 256 routed experts live here: the
+router keeps its 256 outputs and its 8 a token, this chip adds
+``w_e * E_e(x)`` only for chosen experts it holds, and the shared expert
+once) and ``vocab_rows`` (a slice of the vocabulary: ids, logits and loss
+are over the slice). What the absent experts would add is left out and
+nothing stands in for them or for their exchange.
+
+An evaluation (:func:`make_kimi_linear_eval_fn`) is the stateless seam's
+``eval_fn(vec, budget)``: initialise the lane from the configuration's
+key, train ``budget`` momentum-SGD steps of one sequence each, return the
+next-token cross-entropy of the held-out sequences (infinity where the
+training diverged and the loss is no number). A promoted lane
+restarts from the key at the next budget. Parameters, momentum and
+gradients are float32; matmul operands bfloat16 with float32 accumulation
+(``workloads/transformer.py``'s rule); the KDA state and gates, softmax,
+router scores, norms and the loss float32. Each layer's activations are
+recomputed in the backward pass (``jax.checkpoint``).
+
+Departures from the published description, all ``assumed`` in
+``benchmark/configs/kimi-linear-sgd.json`` too:
+
+* the low-rank width of KDA's decay and output-gate projections (the
+  head dim, 128) and the initial ``A_log`` (``log`` of 1..16 over the
+  heads) and ``dt_bias`` (``softplus^-1`` of 0.001..0.1 over the
+  channels) are not in ``config.json``;
+* the router's balancing bias is a buffer held at zero: its update rate
+  is not in ``config.json``, and SGD leaves it at zero because top-k
+  passes it no gradient;
+* KDA is computed chunkwise (chunks of 64, the WY form) with every decay
+  applied as ``exp`` of a difference that is never positive, and JAX
+  differentiates it; no fused kernel;
+* the router's 2304 x 256 product keeps float32 operands (three
+  bfloat16 passes): its top-8 is a discrete choice that bfloat16 operands
+  would flip;
+* tokens are synthetic: Zipf-distributed ids over the slice, the second
+  half of a sequence repeating its first.
+"""
+
+from __future__ import annotations
+
+import zlib
+from typing import NamedTuple, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from hpbandster_tpu.ops.fused import LaneFacts
+from hpbandster_tpu.space import ConfigurationSpace, UniformFloatHyperparameter
+
+__all__ = [
+    "KimiLinearConfig",
+    "LANE_COUNTERS",
+    "kimi_linear_space",
+    "decode_kimi_linear_hparams",
+    "init_kimi_linear_params",
+    "kimi_linear_loss",
+    "kimi_linear_forward",
+    "kda_chunked",
+    "moe_held_experts",
+    "make_token_dataset",
+    "kimi_linear_lane_bytes",
+    "make_kimi_linear_eval_fn",
+]
+
+#: what an evaluation counts on the device beside its loss, over the
+#: expert layers of its validation pass: the share of token-choices that
+#: fell on held experts (8/256 if routing is even) and the fullest held
+#: expert's load over the mean held load
+LANE_COUNTERS = ("moe_held_choice_share", "moe_load_max_over_mean")
+
+
+class KimiLinearConfig(NamedTuple):
+    """Published widths as defaults, then the cut, then the data."""
+
+    hidden_size: int = 2304
+    num_heads: int = 32               # of KDA and of MLA alike
+    kda_head_dim: int = 128           # linear_attn_config.head_dim: d_k = d_v
+    short_conv_kernel_size: int = 4
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 128
+    qk_rope_head_dim: int = 64        # kept, not rotated (mla_use_nope)
+    v_head_dim: int = 128
+    intermediate_size: int = 9216     # the leading dense layer's FFN
+    moe_intermediate_size: int = 1024
+    num_experts: int = 256            # the router's outputs
+    num_experts_per_token: int = 8
+    routed_scaling_factor: float = 2.446
+    rms_norm_eps: float = 1e-5
+    #: the cut: (mixer, ffn) of each layer held, layers 1-5 of 27
+    layer_kinds: Tuple[Tuple[str, str], ...] = (
+        ("kda", "dense"), ("kda", "moe"), ("kda", "moe"), ("mla", "moe"),
+        ("kda", "moe"),
+    )
+    #: which of the routed experts this chip holds
+    experts_held: Tuple[int, ...] = (0, 1, 2, 3, 4, 5, 6, 7)
+    vocab_rows: int = 20480
+    #: data: tokens a step, sequences to cycle through and held out
+    seq_len: int = 4096
+    n_train: int = 64
+    n_val: int = 2
+    #: how the program computes it, not what: KDA's chunk and the blocks of
+    #: positions inside it, the heads
+    #: whose attention scores are alive at once, the blocks of queries
+    kda_chunk: int = 64
+    kda_block: int = 16
+    mla_heads_at_once: int = 8
+    mla_query_blocks: int = 4
+
+
+def kimi_linear_space(seed=None) -> ConfigurationSpace:
+    """lr (log), momentum, weight decay (log), init scale (log): the
+    ``mlp_space`` axes and ranges."""
+    cs = ConfigurationSpace(seed=seed)
+    cs.add_hyperparameter(UniformFloatHyperparameter("lr", 1e-4, 1.0, log=True))
+    cs.add_hyperparameter(UniformFloatHyperparameter("momentum", 0.0, 0.99))
+    cs.add_hyperparameter(
+        UniformFloatHyperparameter("weight_decay", 1e-7, 1e-2, log=True)
+    )
+    cs.add_hyperparameter(
+        UniformFloatHyperparameter("init_scale", 0.1, 10.0, log=True)
+    )
+    return cs
+
+
+def decode_kimi_linear_hparams(vec: jax.Array):
+    """Unit-cube vector -> (lr, momentum, weight_decay, init_scale)."""
+    lr = 10.0 ** (-4.0 + 4.0 * vec[0])
+    momentum = 0.99 * vec[1]
+    wd = 10.0 ** (-7.0 + 5.0 * vec[2])
+    init_scale = 10.0 ** (-1.0 + 2.0 * vec[3])
+    return lr, momentum, wd, init_scale
+
+
+# ------------------------------------------------------------- parameters
+def _layer_shapes(cfg: KimiLinearConfig, mixer: str, ffn: str) -> dict:
+    d, h = cfg.hidden_size, cfg.num_heads
+    dk = cfg.kda_head_dim
+    shapes = {"norm1": (d,), "norm2": (d,)}
+    if mixer == "kda":
+        shapes.update(
+            wq=(d, h * dk), wk=(d, h * dk), wv=(d, h * dk),
+            conv_q=(cfg.short_conv_kernel_size, h * dk),
+            conv_k=(cfg.short_conv_kernel_size, h * dk),
+            conv_v=(cfg.short_conv_kernel_size, h * dk),
+            wa1=(d, dk), wa2=(dk, h * dk), A_log=(h,), dt_bias=(h * dk,),
+            wb=(d, h), wg1=(d, dk), wg2=(dk, h * dk), o_norm=(dk,),
+            wo=(h * dk, d),
+        )
+    else:
+        dq = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+        shapes.update(
+            wq=(d, h * dq),
+            wkva=(d, cfg.kv_lora_rank + cfg.qk_rope_head_dim),
+            kv_norm=(cfg.kv_lora_rank,),
+            wkvb=(cfg.kv_lora_rank, h * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
+            wo=(h * cfg.v_head_dim, d),
+        )
+    if ffn == "dense":
+        f = cfg.intermediate_size
+        shapes.update(w_gate=(d, f), w_up=(d, f), w_down=(f, d))
+    else:
+        f, e = cfg.moe_intermediate_size, len(cfg.experts_held)
+        shapes.update(
+            router=(d, cfg.num_experts), router_bias=(cfg.num_experts,),
+            shared_gate=(d, f), shared_up=(d, f), shared_down=(f, d),
+            e_gate=(e, d, f), e_up=(e, d, f), e_down=(e, f, d),
+        )
+    return shapes
+
+
+def _init_leaf(key, name: str, shape, init_scale):
+    """One leaf from the key and its own name, so that the draw does not
+    depend on which other leaves exist. Matrices (and the depthwise
+    convolutions) are ``init_scale / sqrt(fan_in) * N(0, 1)``, the
+    embedding ``init_scale * N(0, 1)`` (a lookup's fan-in is one: a
+    smaller embedding only has the first norm multiply its gradient up),
+    norm weights one, the balancing bias zero."""
+    leaf = name.rsplit("/", 1)[-1]
+    if leaf.startswith("norm") or leaf in ("kv_norm", "o_norm"):
+        return jnp.ones(shape, jnp.float32)
+    if leaf == "router_bias":
+        return jnp.zeros(shape, jnp.float32)
+    if leaf == "A_log":
+        return jnp.log(jnp.linspace(1.0, 16.0, shape[0], dtype=jnp.float32))
+    if leaf == "dt_bias":
+        dt = jnp.exp(jnp.linspace(
+            np.log(0.001), np.log(0.1), shape[0], dtype=jnp.float32))
+        return dt + jnp.log(-jnp.expm1(-dt))  # softplus^-1
+    # drawn as a matrix and folded: the same numbers in the same order (the
+    # chip's compiler takes fourteen seconds over a three-dimensional draw)
+    draw = jax.random.normal(
+        jax.random.fold_in(key, zlib.crc32(name.encode()) & 0x7FFFFFFF),
+        (int(np.prod(shape[:-1])), shape[-1]), jnp.float32).reshape(shape)
+    fan_in = 1 if leaf == "embed" else shape[-2]
+    return (init_scale * fan_in ** -0.5) * draw
+
+
+def init_kimi_linear_params(key: jax.Array, cfg: KimiLinearConfig,
+                            init_scale) -> dict:
+    shapes = {
+        "embed": (cfg.vocab_rows, cfg.hidden_size),
+        "norm_f": (cfg.hidden_size,),
+        "head": (cfg.hidden_size, cfg.vocab_rows),
+    }
+    params = {n: _init_leaf(key, n, s, init_scale) for n, s in shapes.items()}
+    for i, (mixer, ffn) in enumerate(cfg.layer_kinds):
+        params[f"l{i}"] = {
+            n: _init_leaf(key, f"l{i}/{n}", s, init_scale)
+            for n, s in _layer_shapes(cfg, mixer, ffn).items()
+        }
+    return params
+
+
+# ----------------------------------------------------------------- layers
+#: what every matrix product's operands are cast to; the accumulation is
+#: float32 (``workloads/transformer.py``'s ``_mm``). The tests set float32
+#: here to hold the equations to the reference without rounding in the way.
+_OPERAND = jnp.bfloat16
+
+
+#: the few products whose operands stay float32 (the router's, whose top 8
+#: is a discrete choice, and KDA's blocks under the diagonal, which feed a
+#: triangular solve): three bfloat16 passes, 2^-16 of a product. The six
+#: passes of ``HIGHEST`` take the chip's compiler four seconds a product
+#: and there are some sixty of them in a lane.
+_FLOAT32 = jax.lax.Precision.HIGH
+
+
+def _mm(a, b):
+    return jnp.matmul(
+        a.astype(_OPERAND), b.astype(_OPERAND), preferred_element_type=jnp.float32)
+
+
+def _einsum(spec, a, b):
+    return jnp.einsum(
+        spec, a.astype(_OPERAND), b.astype(_OPERAND),
+        preferred_element_type=jnp.float32)
+
+
+def _rms(x, w, eps):
+    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * w
+
+
+def _l2norm(x):
+    return x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + 1e-6)
+
+
+def _causal_conv(x, w):
+    """Depthwise causal convolution: ``y_t = sum_i w[i] x[t - K + 1 + i]``
+    with zeros before the sequence; ``x`` f32[T, C], ``w`` f32[K, C]."""
+    k, t = w.shape[0], x.shape[0]
+    padded = jnp.pad(x, ((k - 1, 0), (0, 0)))
+    return sum(w[i] * padded[i:i + t] for i in range(k))
+
+
+def _chunk_products(q, k, g, sub: int):
+    """``(A, P)`` f32[..., C, C] of a chunk: ``A_ij = sum_c k_ic k_jc
+    exp(g_ic - g_jc)`` and ``P`` with ``q`` on the left, for ``j <= i``
+    (zero above the diagonal); ``q, k, g`` f32[..., C, d], ``g`` the running
+    sum of ``log a`` (never rising). In blocks of ``sub`` positions: a block
+    on the diagonal sums its ``sub x sub x d`` decays one by one; a block
+    under it splits the decay at ``g`` of the last position before the row
+    block, ``exp(g_i - g*) exp(g* - g_j)``, both exponents never positive,
+    and becomes a matrix product."""
+    c, d = q.shape[-2:]
+    r = c // sub
+    blocks = lambda x: x.reshape(x.shape[:-2] + (r, sub, d))
+    qb, kb, gb = blocks(q), blocks(k), blocks(g)
+    # on the diagonal: [..., r, i, j, d], reduced over d at once
+    tri = jnp.tril(jnp.ones((sub, sub), bool))[:, :, None]
+    decay = jnp.exp(jnp.where(
+        tri, gb[..., :, None, :] - gb[..., None, :, :], -jnp.inf))
+    kd = kb[..., None, :, :] * decay
+    a_diag = jnp.sum(kb[..., :, None, :] * kd, -1)          # [..., r, i, j]
+    p_diag = jnp.sum(qb[..., :, None, :] * kd, -1)
+    if r == 1:
+        return a_diag[..., 0, :, :], p_diag[..., 0, :, :]
+    # under it: g* of row block b is g at the end of block b - 1
+    g_star = jnp.concatenate(
+        [jnp.zeros_like(gb[..., :1, -1, :]), gb[..., :-1, -1, :]], -2)  # [..., r, d]
+    left = jnp.exp(gb - g_star[..., :, None, :])                        # [..., r, i, d]
+    below = jnp.tril(jnp.ones((r, r), bool), -1)[:, :, None, None]
+    right = kb[..., None, :, :, :] * jnp.exp(jnp.where(                 # [..., b, b', j, d]
+        below, g_star[..., :, None, None, :] - gb[..., None, :, :, :], -jnp.inf))
+    # the rows of k and of q in one product: [..., b, 2 sub, b', j]
+    off = jnp.einsum(
+        "...bic,...bdjc->...bidj", jnp.concatenate([kb * left, qb * left], -2), right,
+        precision=_FLOAT32)
+    eye = jnp.eye(r, dtype=jnp.float32)[:, None, :, None]               # [b, 1, b', 1]
+    whole = lambda diag, off: (
+        diag[..., :, :, None, :] * eye + off).reshape(q.shape[:-2] + (c, c))
+    return (whole(a_diag, off[..., :sub, :, :]), whole(p_diag, off[..., sub:, :, :]))
+
+
+def kda_chunked(q, k, v, log_a, beta, chunk: int, sub: int = None):
+    """The gated delta rule, chunk by chunk.
+
+    Per head, with ``S`` f32[d_k, d_v] zero at the start::
+
+        S_t = (I - beta_t k_t k_t^T) diag(a_t) S_{t-1} + beta_t k_t v_t^T
+        o_t = S_t^T q_t
+
+    ``q, k`` f32[T, H, d_k], ``v`` f32[T, H, d_v], ``log_a`` f32[T, H, d_k]
+    (``log a_t <= 0``), ``beta`` f32[T, H]; returns f32[T, H, d_v]. Inside
+    a chunk, with ``G_i`` the running sum of ``log_a`` and ``u_i`` the
+    delta rule's corrected values, ``(I + diag(beta) tril(A, -1)) U =
+    diag(beta) (V - (K exp G) S_0)`` where ``A_ij = sum_c k_ic k_jc
+    exp(G_ic - G_jc)``: one triangular solve gives ``U`` from the state the
+    chunk starts with (the WY form), then ``o_i = (q_i exp G_i) S_0 +
+    sum_{j<=i} P_ij u_j`` with ``P`` as ``A`` with ``q`` on the left, and
+    ``S_C = diag(exp G_C) S_0 + (K exp(G_C - G))^T U``. Every exponent is
+    a difference that is never positive, so no decay however strong
+    overflows. What does not need the state (``A``, ``P``, the solve
+    against ``[V, K exp G]``) is computed for all chunks at once
+    (:func:`_chunk_products`, in blocks of ``sub``, a quarter of the chunk
+    unless given); only the state's own recurrence, three small products a
+    chunk, is a scan. A length that is no multiple of ``chunk`` is padded
+    with steps that leave the state alone (``a = 1, beta = 0``)."""
+    t, h, dk = q.shape
+    dv = v.shape[-1]
+    sub = sub or max(chunk // 4, 1)
+    pad = -t % chunk
+    if pad:
+        q, k, v, log_a = (jnp.pad(x, ((0, pad), (0, 0), (0, 0)))
+                          for x in (q, k, v, log_a))
+        beta = jnp.pad(beta, ((0, pad), (0, 0)))
+    n = (t + pad) // chunk
+    # chunk-major, head before position: [n, H, C, d]
+    split = lambda x: x.reshape((n, chunk) + x.shape[1:]).swapaxes(1, 2)
+    q, k, v, log_a, beta = (split(x) for x in (q, k, v, log_a, beta))
+    g = jnp.cumsum(log_a, axis=2)
+    # recomputed in the backward pass: the blocks' decays are not kept
+    a, p = jax.checkpoint(_chunk_products, static_argnums=(3,))(q, k, g, sub)
+    strictly = jnp.tril(jnp.ones((chunk, chunk), bool), -1)
+    system = (jnp.eye(chunk, dtype=jnp.float32)
+              + beta[..., None] * jnp.where(strictly, a, 0.0))
+    from_start = jnp.exp(g)
+    rhs = beta[..., None] * jnp.concatenate([v, k * from_start], -1)
+    solved = jax.scipy.linalg.solve_triangular(system, rhs, lower=True)
+    w_v, w_k = solved[..., :dv], solved[..., dv:]
+    q_start = q * from_start
+    k_end = k * jnp.exp(g[:, :, -1:, :] - g)
+    keep = from_start[:, :, -1, :, None]                 # [n, H, dk, 1]
+
+    def one_chunk(state, xs):
+        w_v, rows, p, k_end, keep = xs           # rows: w_k above q_start
+        from_state = _einsum("hic,hcv->hiv", rows, state)
+        u = w_v - from_state[:, :chunk]
+        out = from_state[:, chunk:] + _einsum("hij,hjv->hiv", p, u)
+        return keep * state + _einsum("hic,hiv->hcv", k_end, u), out
+
+    _, out = jax.lax.scan(
+        one_chunk, jnp.zeros((h, dk, dv), jnp.float32),
+        (w_v, jnp.concatenate([w_k, q_start], 2), p, k_end, keep))
+    return out.swapaxes(1, 2).reshape((t + pad, h, dv))[:t]
+
+
+def _mm_beside(x, *weights):
+    """``x @ w`` for several ``w`` as ONE product, the weights side by
+    side, and the columns handed back apart: the same sums, and one
+    product for the compiler (half a second each on the chip's) and for
+    the chip in place of several."""
+    out = _mm(x, jnp.concatenate([w.astype(_OPERAND) for w in weights], axis=1))
+    return jnp.split(out, np.cumsum([w.shape[1] for w in weights])[:-1], axis=1)
+
+
+def _kda(x, p, cfg: KimiLinearConfig):
+    t = x.shape[0]
+    h, dk = cfg.num_heads, cfg.kda_head_dim
+    heads = lambda y: y.reshape(t, h, dk)
+    q, k, v, a1, g1, b = _mm_beside(
+        x, p["wq"], p["wk"], p["wv"], p["wa1"], p["wg1"], p["wb"])
+    q, k, v = (heads(jax.nn.silu(_causal_conv(y, p[c])))
+               for y, c in ((q, "conv_q"), (k, "conv_k"), (v, "conv_v")))
+    q, k = _l2norm(q), _l2norm(k)
+    # log a_t = -exp(A_log) * softplus(W_a2 W_a1 x_t + dt_bias), per channel
+    log_a = -jnp.exp(p["A_log"])[None, :, None] * heads(
+        jax.nn.softplus(_mm(a1, p["wa2"]) + p["dt_bias"]))
+    beta = jax.nn.sigmoid(b)
+    o = kda_chunked(q, k, v, log_a, beta, cfg.kda_chunk,
+                    min(cfg.kda_block, cfg.kda_chunk)) * dk ** -0.5
+    gate = jax.nn.sigmoid(heads(_mm(g1, p["wg2"])))
+    o = _rms(o, p["o_norm"], cfg.rms_norm_eps) * gate
+    return _mm(o.reshape(t, h * dk), p["wo"])
+
+
+def _mla(x, p, cfg: KimiLinearConfig):
+    t, h = x.shape[0], cfg.num_heads
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    q = _mm(x, p["wq"]).reshape(t, h, dn + dr)
+    kva = _mm(x, p["wkva"])
+    c = _rms(kva[:, :cfg.kv_lora_rank], p["kv_norm"], cfg.rms_norm_eps)
+    k_pe = kva[:, cfg.kv_lora_rank:]            # shared by the heads, not rotated
+    kvb = _mm(c, p["wkvb"]).reshape(t, h, dn + dv)
+    scale = (dn + dr) ** -0.5
+    # queries in blocks, each against the keys up to its own end: what lies
+    # wholly above the diagonal is never computed
+    block = -(-t // cfg.mla_query_blocks)
+    spans = [(lo, min(lo + block, t)) for lo in range(0, t, block)]
+
+    @jax.checkpoint
+    def some_heads(qg, kvg):
+        kg = jnp.concatenate([
+            kvg[..., :dn],
+            jnp.broadcast_to(k_pe[:, None, :], kvg.shape[:2] + (dr,))], -1)
+        out = []
+        for lo, hi in spans:
+            s = _einsum("qhd,khd->hqk", qg[lo:hi], kg[:hi]) * scale
+            causal = jnp.arange(lo, hi)[:, None] >= jnp.arange(hi)[None, :]
+            att = jax.nn.softmax(jnp.where(causal[None], s, -1e30), axis=-1)
+            out.append(_einsum("hqk,khd->qhd", att, kvg[:hi, :, dn:]))
+        return jnp.concatenate(out, 0)
+
+    g = min(cfg.mla_heads_at_once, h)
+    groups = lambda y: y.reshape(t, h // g, g, y.shape[-1]).swapaxes(0, 1)
+    out = jax.lax.map(lambda qk: some_heads(*qk), (groups(q), groups(kvb)))
+    return _mm(out.swapaxes(0, 1).reshape(t, h * dv), p["wo"])
+
+
+def _swiglu(x, w_gate, w_up, w_down):
+    gate, up = _mm_beside(x, w_gate, w_up)
+    return _mm(jax.nn.silu(gate) * up, w_down)
+
+
+def moe_held_experts(x, p, cfg: KimiLinearConfig):
+    """This chip's part of the expert layer: the shared expert once, plus
+    ``w_e * E_e(x)`` for each chosen expert it holds. Returns ``(y f32[T,
+    D], counters f32[2])``, the counters being (token-choices on held
+    experts, fullest held expert's load over the mean held load).
+
+    The router scores all ``num_experts``, chooses the top 8 of ``s + b``
+    and weighs them ``s_e / sum(chosen s) * routed_scaling_factor``.
+    Token-choices are sorted by held expert (the others last) and the
+    held ones go through ``jax.lax.ragged_dot``, one group an expert, in
+    tiles of four times the even load, the rows that are not for this chip
+    in a last group of zero weights: a tile that no held choice reaches is
+    skipped (``lax.cond``), so the work follows the load and no token is
+    dropped whatever the load."""
+    t, d = x.shape
+    top_k, held = cfg.num_experts_per_token, len(cfg.experts_held)
+    s = jax.nn.sigmoid(jnp.matmul(x, p["router"], precision=_FLOAT32))
+    _, chosen = jax.lax.top_k(s + p["router_bias"], top_k)       # [T, 8]
+    s_chosen = jnp.take_along_axis(s, chosen, axis=1)
+    weight = s_chosen / s_chosen.sum(-1, keepdims=True) * cfg.routed_scaling_factor
+    slot_of = np.full((cfg.num_experts,), held, np.int32)        # held = "not here"
+    slot_of[list(cfg.experts_held)] = np.arange(held)
+    slot = jnp.asarray(slot_of)[chosen].reshape(-1)              # [T * 8]
+    # a counting sort, stable: a choice's place is its slot's start plus the
+    # earlier choices of its slot (the chip's compiler takes ten seconds
+    # over an ``argsort`` of this length, and there is one a layer and pass)
+    in_slot = (slot[:, None] == jnp.arange(held + 1)[None, :]).astype(jnp.int32)
+    all_loads = in_slot.sum(0)
+    place = (in_slot * (jnp.cumsum(in_slot, 0) - in_slot
+                        + (jnp.cumsum(all_loads) - all_loads)[None, :])).sum(1)
+    loads = all_loads[:held]
+    ends = jnp.cumsum(loads)
+    n_held = ends[-1]
+    rows = min(t * top_k, max(4 * t * top_k * held // cfg.num_experts, 8))
+    n_tiles = -(-t * top_k // rows)
+    order = jnp.zeros((n_tiles * rows,), jnp.int32).at[place].set(
+        jnp.arange(t * top_k, dtype=jnp.int32))
+    weight = weight.reshape(-1)
+
+    # every row of a tile belongs to a group: after the held experts comes
+    # one whose weights are zero and takes the rows that are not for this
+    # chip. On the chip ``ragged_dot`` leaves the rows that no group holds
+    # as it finds them, in the backward pass too, where a mask on its
+    # output cannot reach.
+    with_rest = lambda w: jnp.concatenate([w, jnp.zeros_like(w[:1])]).astype(_OPERAND)
+    xb = x.astype(_OPERAND)
+    # gate and up side by side: one grouped product for the two
+    e_in = with_rest(jnp.concatenate([p["e_gate"], p["e_up"]], -1))
+    e_down = with_rest(p["e_down"])
+    f = cfg.moe_intermediate_size
+
+    def tile(lo):
+        take = jax.lax.dynamic_slice(order, (lo,), (rows,))
+        token = take // top_k
+        sizes = jnp.clip(ends - lo, 0, rows) - jnp.clip(ends - loads - lo, 0, rows)
+        sizes = jnp.concatenate([sizes, rows - sizes.sum(keepdims=True)])
+        dot = lambda a, w: jax.lax.ragged_dot(
+            a.astype(_OPERAND), w, sizes, preferred_element_type=jnp.float32)
+        gate_up = dot(xb[token], e_in)
+        y = dot(jax.nn.silu(gate_up[:, :f]) * gate_up[:, f:], e_down)
+        return jnp.zeros((t, d), jnp.float32).at[token].add(y * weight[take][:, None])
+
+    # recomputed in the backward pass from the scan's own constants: what
+    # a ``cond`` keeps for its branches would be kept once per tile
+    @jax.checkpoint
+    def tile_if_reached(lo):
+        return jax.lax.cond(
+            lo < n_held, tile, lambda lo: jnp.zeros((t, d), jnp.float32), lo)
+
+    def add_tile(routed, lo):
+        return routed + tile_if_reached(lo), None
+
+    routed, _ = jax.lax.scan(
+        add_tile, jnp.zeros((t, d), jnp.float32), jnp.arange(n_tiles) * rows)
+    y = routed + _swiglu(x, p["shared_gate"], p["shared_up"], p["shared_down"])
+    load = loads.astype(jnp.float32)
+    counters = jnp.stack([load.sum(), load.max() / jnp.maximum(load.mean(), 1e-9)])
+    return y, counters
+
+
+def _layer(h, p, kind, cfg: KimiLinearConfig):
+    mixer, ffn = kind
+    x = _rms(h, p["norm1"], cfg.rms_norm_eps)
+    with jax.named_scope("lane." + mixer):
+        h = h + (_kda if mixer == "kda" else _mla)(x, p, cfg)
+    x = _rms(h, p["norm2"], cfg.rms_norm_eps)
+    if ffn == "dense":
+        with jax.named_scope("lane.dense_ffn"):
+            return h + _swiglu(x, p["w_gate"], p["w_up"], p["w_down"]), jnp.zeros((2,))
+    with jax.named_scope("lane.moe"):
+        y, counters = moe_held_experts(x, p, cfg)
+    return h + y, counters
+
+
+def _embed(params, tokens):
+    with jax.named_scope("lane.head"):
+        return params["embed"][tokens[:-1]]
+
+
+def _head_loss(h, norm_f, head, tokens, cfg: KimiLinearConfig):
+    with jax.named_scope("lane.head"):
+        logits = _mm(_rms(h, norm_f, cfg.rms_norm_eps), head)
+        logp = jax.nn.log_softmax(logits)
+        return -jnp.take_along_axis(logp, tokens[1:, None], axis=-1)[:, 0].mean()
+
+
+def kimi_linear_loss(params: dict, tokens: jax.Array, cfg: KimiLinearConfig):
+    """``tokens`` i32[T + 1] -> ``(mean next-token cross-entropy over the
+    vocabulary slice, counters f32[n_layers, 2])``."""
+    h = _embed(params, tokens)
+    counters = []
+    for i, kind in enumerate(cfg.layer_kinds):
+        layer = jax.checkpoint(lambda h, p, kind=kind: _layer(h, p, kind, cfg))
+        h, c = layer(h, params[f"l{i}"])
+        counters.append(c)
+    loss = _head_loss(h, params["norm_f"], params["head"], tokens, cfg)
+    return loss, jnp.stack(counters)
+
+
+def kimi_linear_forward(params: dict, tokens: jax.Array, cfg: KimiLinearConfig):
+    """:func:`kimi_linear_loss` with nothing kept for a gradient but the
+    input of every layer: ``(loss, counters, [h_0 .. h_L])``. An evaluation
+    takes the gradient from these by the chain rule, a layer at a time, each
+    layer's inside computed again (what ``jax.grad`` does with
+    ``jax.checkpoint`` around every layer), so that a pass that needs no
+    gradient (a held-out sequence) is the same trace as one that does."""
+    hs, counters = [_embed(params, tokens)], []
+    for i, kind in enumerate(cfg.layer_kinds):
+        h, c = _layer(hs[-1], params[f"l{i}"], kind, cfg)
+        hs.append(h)
+        counters.append(c)
+    loss = _head_loss(hs[-1], params["norm_f"], params["head"], tokens, cfg)
+    return loss, jnp.stack(counters), hs
+
+
+# ------------------------------------------------------------------- data
+def make_token_dataset(key: jax.Array, cfg: KimiLinearConfig):
+    """``(train i32[n_train, T + 1], val i32[n_val, T + 1])``: ids over
+    the vocabulary slice, Zipf-distributed (``p(rank r) ~ 1 / r``, by
+    inverse CDF from uniform draws), the second half of each sequence
+    repeating its first, so that a lane predicts it only through state
+    and attention."""
+    cdf = np.cumsum(1.0 / np.arange(1, cfg.vocab_rows + 1, dtype=np.float64))
+    cdf = jnp.asarray((cdf / cdf[-1]).astype(np.float32))
+    half = cfg.seq_len // 2 + 1
+
+    def draw(k, n):
+        ids = jnp.searchsorted(cdf, jax.random.uniform(k, (n, half)))
+        ids = jnp.minimum(ids, cfg.vocab_rows - 1).astype(jnp.int32)
+        return jnp.concatenate([ids, ids[:, :cfg.seq_len + 1 - half]], axis=1)
+
+    kt, kv = jax.random.split(key)
+    return draw(kt, cfg.n_train), draw(kv, cfg.n_val)
+
+
+# ------------------------------------------------------------- evaluation
+def kimi_linear_lane_bytes(cfg: KimiLinearConfig) -> int:
+    """Device bytes one lane needs while it trains: float32 parameters,
+    momentum and gradients (12 bytes a parameter) and the peak of its
+    activations: the logits and their gradient, one layer's recomputed
+    activations (about 40 hidden-sized rows a token, the attention scores
+    of ``mla_heads_at_once`` heads) and a layer's input per layer. At the
+    published widths it gives 11.5 GB where the chip's compiler counts
+    12.1 GB for the bracket: one lane fits a 16.9 GB chip, two do not."""
+    shapes = jax.eval_shape(
+        lambda: init_kimi_linear_params(jax.random.key(0), cfg, 1.0))
+    n_params = sum(int(np.prod(s.shape)) for s in jax.tree.leaves(shapes))
+    t = cfg.seq_len
+    activations = 4 * t * (
+        3 * cfg.vocab_rows
+        + (40 + len(cfg.layer_kinds)) * cfg.hidden_size
+        + 3 * min(cfg.mla_heads_at_once, cfg.num_heads) * t
+    )
+    return 12 * n_params + activations
+
+
+def make_kimi_linear_eval_fn(cfg: KimiLinearConfig = KimiLinearConfig(),
+                             data_seed: int = 0):
+    """``eval_fn(config_vec, budget) -> held-out cross-entropy``, handed to
+    ``FusedBOHB(eval_fn=...)`` as ``make_transformer_eval_fn``'s is. Budget
+    is momentum-SGD steps of one ``seq_len``-token sequence; step ``t``
+    trains on sequence ``t mod n_train``; ``v <- m v + g + wd p; p <- p -
+    lr v``. ``eval_fn.lane_facts`` states the lane's footprint, its tokens
+    a step and its device counters (:data:`LANE_COUNTERS`), which the
+    rung's evaluation (``ops.fused.eval_lanes``) reads."""
+    train, val = make_token_dataset(jax.random.key(data_seed), cfg)
+    init_key = jax.random.key(data_seed + 1)
+    choices = cfg.seq_len * cfg.num_experts_per_token
+
+    def with_counters(vec: jax.Array, budget):
+        lr, momentum, wd, init_scale = decode_kimi_linear_hparams(vec)
+        params = init_kimi_linear_params(init_key, cfg, init_scale)
+        steps = jnp.asarray(budget, jnp.float32).round().astype(jnp.int32)
+
+        # ONE loop over the training sequences and then the held-out ones:
+        # each pass runs the forward trace, and a training pass the backward
+        # one and the update besides, so the program holds the forward pass
+        # once for both (a quarter of its compilation). The backward pass
+        # and the update are a ``lax.cond`` a layer (the head, each layer
+        # from the last, the embedding): parameters and momentum through
+        # ONE ``cond`` would be held twice over, and all the gradient's
+        # leaves would be alive at once.
+        def update(p, v, g):
+            with jax.named_scope("lane.update"):
+                v = jax.tree.map(lambda vi, gi, pi: momentum * vi + gi + wd * pi, v, g, p)
+                return jax.tree.map(lambda pi, vi: pi - lr * vi, p, v), v
+
+        def one_pass(t, carry):
+            p, v, held_loss, held_counters = carry
+            training = t < steps
+            seq = jnp.where(training, train[t % cfg.n_train],
+                            val[jnp.clip(t - steps, 0, cfg.n_val - 1)])
+            loss, counters, hs = kimi_linear_forward(p, seq, cfg)
+            p, v = dict(p), dict(v)
+
+            def if_training(step, *state):
+                return jax.lax.cond(training, step, lambda *same: same, *state)
+
+            def head_step(dh, pn, vn, ph, vh):
+                dh, g_norm, g_head = jax.grad(_head_loss, argnums=(0, 1, 2))(
+                    hs[-1], pn, ph, seq, cfg)
+                return (dh,) + update(pn, vn, g_norm) + update(ph, vh, g_head)
+
+            dh, p["norm_f"], v["norm_f"], p["head"], v["head"] = if_training(
+                head_step, jnp.zeros_like(hs[-1]), p["norm_f"], v["norm_f"],
+                p["head"], v["head"])
+            for i in reversed(range(len(cfg.layer_kinds))):
+                def layer_step(dh, pl, vl, i=i):
+                    _, pull = jax.vjp(
+                        lambda h, q: _layer(h, q, cfg.layer_kinds[i], cfg)[0], hs[i], pl)
+                    dh, g = pull(dh)
+                    return (dh,) + update(pl, vl, g)
+
+                dh, p[f"l{i}"], v[f"l{i}"] = if_training(
+                    layer_step, dh, p[f"l{i}"], v[f"l{i}"])
+
+            def embed_step(pe, ve):
+                with jax.named_scope("lane.head"):
+                    g = jnp.zeros_like(pe).at[seq[:-1]].add(dh)
+                return update(pe, ve, g)
+
+            p["embed"], v["embed"] = if_training(embed_step, p["embed"], v["embed"])
+            held = jnp.where(training, 0.0, 1.0)
+            return p, v, held_loss + held * loss, held_counters + held * counters
+
+        _, _, loss, counters = jax.lax.fori_loop(0, steps + cfg.n_val, one_pass, (
+            params, jax.tree.map(jnp.zeros_like, params), jnp.float32(0.0),
+            jnp.zeros((len(cfg.layer_kinds), 2), jnp.float32)))
+        loss = loss / cfg.n_val
+        moe = counters[np.asarray([f == "moe" for _, f in cfg.layer_kinds])]
+        # a lane whose training diverged has no number for a loss: it
+        # reports the worst one, infinity; NaN is the sweep's mask for a crash
+        return jnp.where(jnp.isnan(loss), jnp.inf, loss), jnp.stack([
+            moe[:, 0].sum() / max(moe.shape[0] * cfg.n_val * choices, 1),
+            moe[:, 1].mean() / cfg.n_val if moe.shape[0] else jnp.float32(0.0),
+        ])
+
+    def eval_fn(vec: jax.Array, budget) -> jax.Array:
+        return with_counters(vec, budget)[0]
+
+    eval_fn.lane_facts = LaneFacts(
+        bytes=kimi_linear_lane_bytes(cfg), tokens_per_step=cfg.seq_len,
+        counters=LANE_COUNTERS, with_counters=with_counters, traced_budget=True)
+    return eval_fn

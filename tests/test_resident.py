@@ -55,6 +55,10 @@ def _assert_outputs_bitwise(a, b):
     assert len(a) == len(b)
     for i, (oa, ob) in enumerate(zip(a, b)):
         for name, la, lb in zip(oa._fields, oa, ob):
+            if la is None or lb is None:
+                # an eval_fn without device counters leaves that field empty
+                assert la is lb, f"bracket {i} leaf {name} diverged"
+                continue
             assert np.array_equal(
                 np.asarray(la), np.asarray(lb), equal_nan=True
             ), f"bracket {i} leaf {name} diverged"
