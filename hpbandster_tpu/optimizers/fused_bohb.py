@@ -28,7 +28,7 @@ from typing import Any, Dict, List, Optional
 import numpy as np
 
 from hpbandster_tpu import obs
-from hpbandster_tpu.core.iteration import Status
+from hpbandster_tpu.core.iteration import Datum, Status
 from hpbandster_tpu.core.job import Job
 from hpbandster_tpu.core.result import Result
 from hpbandster_tpu.core.successive_halving import SuccessiveHalving
@@ -150,6 +150,35 @@ class _ReplayIteration(SuccessiveHalving):
     def _advance_to_next_stage(self, config_ids, losses) -> np.ndarray:
         promoted = self._promotion_sets[self.stage]
         return np.array([cid[2] in promoted for cid in config_ids], bool)
+
+    def add_configurations(self, configs: List[Dict], infos: List[Dict]) -> None:
+        """A rung's fresh configurations at once: what one
+        ``add_configuration(config, info)`` a configuration leaves, with
+        its checks made once for all of them. The result logger's
+        ``new_config`` and the journal's ``config_sampled`` are reached,
+        once a configuration and in that order, only while a logger or a
+        sink is attached."""
+        stage = self.stage
+        if self.is_finished:
+            raise RuntimeError("iteration is finished, cannot add configurations")
+        first = self.actual_num_configs[stage]
+        if configs and first + len(configs) > self.num_configs[stage]:
+            raise RuntimeError(
+                f"stage {stage} of iteration {self.HPB_iter} is already full"
+            )
+        budget = self.budgets[stage]
+        logger = self.result_logger
+        observed = logger is not None or obs.get_bus().active
+        for i, (config, info) in enumerate(zip(configs, infos), start=first):
+            config_id = (self.HPB_iter, stage, i)
+            self.data[config_id] = Datum(
+                config=config, config_info=info, budget=budget
+            )
+            self.actual_num_configs[stage] = i + 1
+            if observed:
+                if logger is not None:
+                    logger.new_config(config_id, config, info)
+                obs.emit_config_sampled(config_id, budget, info)
 
 
 class FusedBOHB:
@@ -996,6 +1025,9 @@ class FusedBOHB:
                     # evaluations whose replay built a Job: all of them
                     # under a result logger or a journal sink, else none
                     "replay_jobs_built": 0,
+                    # first-rung configurations that from_vectors decoded a
+                    # column at a time; the rest went through from_vector
+                    "replay_configs_by_column": 0,
                     # seconds per span name, this chunk's share of the
                     # sweep's wall; the spans that close after this point
                     # (this one, obs_fold, the chunk's bracket_replay
@@ -1062,8 +1094,8 @@ class FusedBOHB:
                 span = functools.partial(sweep_span, totals=stat["phase_s"])
                 with span("bracket_replay", PROMOTION):
                     for b_i, plan, out, stages in staged:
-                        stat["replay_jobs_built"] += self._replay_bracket(
-                            b_i, plan, out, stages, job_info, span
+                        self._replay_bracket(
+                            b_i, plan, out, stages, job_info, span, stat
                         )
 
             done += len(chunk_plans)
@@ -1482,16 +1514,19 @@ class FusedBOHB:
 
     # --------------------------------------------------------------- replay
     def _replay_bracket(
-        self, b_i: int, plan, out, stages, job_info: Optional[Dict], span
-    ) -> int:
+        self, b_i: int, plan, out, stages, job_info: Optional[Dict], span,
+        stat: Dict,
+    ) -> None:
         """One bracket's device outputs into the reference's bookkeeping.
         ``span(name, phase)`` opens a ``sweep_span`` of the caller's: its
-        row's ``phase_s``, its sweep's trace. Returns the ``Job`` objects
-        the runs' replay built (:meth:`_replay_runs`)."""
+        row's ``phase_s``, its sweep's trace. ``stat`` is that row: its
+        ``replay_configs_by_column`` takes the configurations decoded a
+        column at a time, its ``replay_jobs_built`` the ``Job`` objects the
+        runs' replay built (:meth:`_replay_runs`)."""
         from hpbandster_tpu.obs.timeline import PROMOTION
 
         vectors = np.asarray(out.vectors)
-        mb_mask = np.asarray(out.model_based)
+        mb_mask = np.asarray(out.model_based, dtype=bool)
         promotion_sets = [set(int(i) for i in idx) for idx, _ in stages[1:]]
         promotion_sets.append(set())
 
@@ -1518,20 +1553,24 @@ class FusedBOHB:
         # two spans a bracket, never one an evaluation: decoding the
         # bracket's configurations, then replaying its runs
         with span("replay.configs", PROMOTION):
-            for i in range(plan.num_configs[0]):
-                cfg = dict(self.configspace.from_vector(vectors[i]))
-                it.add_configuration(
-                    cfg,
+            first_rung = vectors[: plan.num_configs[0]]
+            if self.configspace.decodes_by_column(first_rung):
+                stat["replay_configs_by_column"] += len(first_rung)
+            it.add_configurations(
+                self.configspace.from_vectors(first_rung),
+                [
                     {
-                        "model_based_pick": bool(mb_mask[i]),
+                        "model_based_pick": picked,
                         # decision detail (KDE budget, l/g score) stayed on
                         # device; the audit record still attributes the arm
                         "sample_reason": "fused_sweep",
                         "fused_sweep": True,
-                    },
-                )
+                    }
+                    for picked in mb_mask[: len(first_rung)].tolist()
+                ],
+            )
         with span("replay.runs", PROMOTION):
-            return self._replay_runs(it, stages, job_info)
+            stat["replay_jobs_built"] += self._replay_runs(it, stages, job_info)
 
     def _replay_runs(self, it, stages, job_info) -> int:
         """Every run of one replayed bracket, rung by rung from the

@@ -197,6 +197,41 @@ class ConfigurationSpace:
                 raw[name] = self._hps[name].from_unit(float(vector[i]))
         return Configuration(self._active_set(raw))
 
+    def decodes_by_column(self, matrix: np.ndarray) -> bool:
+        """Whether :meth:`from_vectors` decodes ``matrix`` a column at a
+        time: the space has a dimension and no condition (activity is a
+        walk over the conditions' DAG per configuration) and every entry is
+        finite (a NaN marks an inactive dimension, which a row leaves out)."""
+        return (
+            bool(self._order)
+            and not self._conditions
+            and bool(np.isfinite(matrix).all())
+        )
+
+    def from_vectors(self, matrix: Sequence[Sequence[float]]) -> List[Dict[str, Any]]:
+        """``[n, dim]`` vectors -> ``n`` plain dicts, row for row equal to
+        ``dict(from_vector(row))``: the same keys in the same order, values
+        equal under ``==`` and of the same Python types.
+
+        What the input shows decides how, and no caller chooses: where
+        :meth:`decodes_by_column` holds, each hyperparameter decodes its
+        whole column at once (``Hyperparameter.from_unit_many``); otherwise
+        every row goes through :meth:`from_vector`.
+        """
+        matrix = np.asarray(matrix, dtype=np.float64)
+        if matrix.ndim != 2 or matrix.shape[1] != self.dim:
+            raise ValueError(
+                f"expected shape (n, {self.dim}), got {matrix.shape}"
+            )
+        if not self.decodes_by_column(matrix):
+            return [dict(self.from_vector(row)) for row in matrix]
+        columns = [
+            self._hps[name].from_unit_many(matrix[:, i])
+            for i, name in enumerate(self._order)
+        ]
+        names = self._order
+        return [dict(zip(names, values)) for values in zip(*columns)]
+
     def vartypes(self) -> np.ndarray:
         """``int32[dim]`` of VARTYPE_CODES ('c'=0, 'u'=1, 'o'=2)."""
         return np.asarray(

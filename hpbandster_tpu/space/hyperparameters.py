@@ -17,7 +17,7 @@ generator"), so the KDE semantics carry over unchanged.
 from __future__ import annotations
 
 import math
-from typing import Any, Hashable, Optional, Sequence
+from typing import Any, Hashable, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -37,6 +37,29 @@ def _clamp(value, lower, upper):
     fused-replay profiles. NaN propagates (value is max's first arg),
     matching np.clip."""
     return min(max(value, lower), upper)
+
+
+def _unit_column(column) -> np.ndarray:
+    """One vector dimension as float64, clamped to [0, 1]."""
+    return np.clip(np.asarray(column, dtype=np.float64), 0.0, 1.0)
+
+
+def _log_scale_many(u: np.ndarray, lower: float, upper: float) -> np.ndarray:
+    """``exp(log(lower) + u * (log(upper) - log(lower)))`` over a column.
+    ``np.exp`` and ``math.exp`` differ in the last bit for some arguments,
+    and the scalar ``from_unit``s use ``math.exp``: the exponential stays
+    one call an element, only its argument is made as an array."""
+    log_lower = math.log(lower)
+    args = log_lower + u * (math.log(upper) - log_lower)
+    return np.fromiter(map(math.exp, args.tolist()), np.float64, len(args))
+
+
+def _choose_many(column, choices: list) -> list:
+    """The choice every entry indexes: rounded half to even as ``round``
+    does, then clamped."""
+    idx = np.rint(np.asarray(column, dtype=np.float64))
+    idx = np.clip(idx, 0, len(choices) - 1).astype(np.int64)
+    return [choices[i] for i in idx.tolist()]
 
 
 class Hyperparameter:
@@ -61,6 +84,17 @@ class Hyperparameter:
     def from_unit(self, u: float) -> Any:
         """Inverse of :meth:`to_unit` (after rounding/clipping)."""
         raise NotImplementedError
+
+    def from_unit_many(self, column: Sequence[float]) -> list:
+        """:meth:`from_unit` of every entry of one vector dimension.
+
+        The contract every override keeps, element for element: equal under
+        ``==`` to ``from_unit(float(u))`` and of the same Python type (never
+        a numpy scalar: the values go into ``Result`` and the JSON logs).
+        Entries are finite (``ConfigurationSpace.from_vectors`` sends a
+        matrix with a NaN down its row path). A kind that does no better
+        inherits the per-element call."""
+        return [self.from_unit(float(u)) for u in column]
 
     # -- sampling ---------------------------------------------------------
     def sample_unit(self, rng: np.random.Generator) -> float:
@@ -145,6 +179,17 @@ class UniformFloatHyperparameter(Hyperparameter):
             v = self.lower + u * (self.upper - self.lower)
         return self._quantize(float(_clamp(v, self.lower, self.upper)))
 
+    def from_unit_many(self, column: Sequence[float]) -> list:
+        u = _unit_column(column)
+        if self.log:
+            v = _log_scale_many(u, self.lower, self.upper)
+        else:
+            v = self.lower + u * (self.upper - self.lower)
+        v = np.clip(v, self.lower, self.upper)
+        if self.q is not None:
+            v = np.clip(np.rint(v / self.q) * self.q, self.lower, self.upper)
+        return v.tolist()
+
     def sample_unit(self, rng: np.random.Generator) -> float:
         return float(rng.uniform())
 
@@ -209,15 +254,31 @@ class UniformIntegerHyperparameter(Hyperparameter):
             return float(_clamp(u, 0.0, 1.0))
         return float(_clamp((v - self.lower + 0.5) / self._n, 0.0, 1.0))
 
+    def _log_bounds(self) -> Tuple[float, float]:
+        """The interval whose log-uniform draw rounds to uniform-in-log
+        integers over ``[lower, upper]``."""
+        lo = (self.lower - 0.4999) if self.lower > 1 else max(self.lower, 1) * 0.5001
+        return lo, self.upper + 0.4999
+
     def from_unit(self, u: float) -> int:
         u = float(_clamp(u, 0.0, 1.0))
         if self.log:
-            lo = (self.lower - 0.4999) if self.lower > 1 else max(self.lower, 1) * 0.5001
-            hi = self.upper + 0.4999
+            lo, hi = self._log_bounds()
             v = math.exp(math.log(lo) + u * (math.log(hi) - math.log(lo)))
         else:
             v = self.lower - 0.5 + u * self._n
         return int(_clamp(int(round(v)), self.lower, self.upper))
+
+    def from_unit_many(self, column: Sequence[float]) -> list:
+        if max(abs(self.lower), abs(self.upper)) >= 2**53:
+            # bounds a float64 cannot hold: Python's integers can
+            return super().from_unit_many(column)
+        u = _unit_column(column)
+        if self.log:
+            v = _log_scale_many(u, *self._log_bounds())
+        else:
+            v = self.lower - 0.5 + u * self._n
+        return np.clip(np.rint(v), self.lower, self.upper).astype(np.int64).tolist()
 
     def sample_unit(self, rng: np.random.Generator) -> float:
         return float(rng.uniform())
@@ -275,6 +336,9 @@ class CategoricalHyperparameter(Hyperparameter):
         idx = int(_clamp(int(round(float(u))), 0, self.num_choices - 1))
         return self.choices[idx]
 
+    def from_unit_many(self, column: Sequence[float]) -> list:
+        return _choose_many(column, self.choices)
+
     def sample_unit(self, rng: np.random.Generator) -> float:
         return float(rng.choice(self.num_choices, p=self.probabilities))
 
@@ -312,6 +376,9 @@ class OrdinalHyperparameter(Hyperparameter):
         idx = int(_clamp(int(round(float(u))), 0, self.num_choices - 1))
         return self.sequence[idx]
 
+    def from_unit_many(self, column: Sequence[float]) -> list:
+        return _choose_many(column, self.sequence)
+
     def sample_unit(self, rng: np.random.Generator) -> float:
         return float(rng.integers(self.num_choices))
 
@@ -336,6 +403,9 @@ class Constant(Hyperparameter):
 
     def from_unit(self, u: float) -> Any:
         return self.value
+
+    def from_unit_many(self, column: Sequence[float]) -> list:
+        return [self.value] * len(column)
 
     def sample_unit(self, rng: np.random.Generator) -> float:
         return 0.0
