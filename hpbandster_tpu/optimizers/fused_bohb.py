@@ -38,23 +38,12 @@ from hpbandster_tpu.ops.bracket import (
     hyperband_bracket,
     max_sh_iterations,
 )
-from hpbandster_tpu.ops.sweep import (
-    build_space_codec,
-    make_fused_sweep_fn,
-    plan_additions,
-    pow2_capacities,
-)
+from hpbandster_tpu.ops.sweep import build_space_codec
+from hpbandster_tpu.ops.sweep_driver import _SWEEP_EXE_CACHE, SweepDriver
 from hpbandster_tpu.space import ConfigurationSpace
-from hpbandster_tpu.utils.lru import LRUCache
 
 __all__ = ["FusedBOHB", "FusedHyperBand", "FusedRandomSearch", "FusedH2BO",
            "sweep_phase_maps"]
-
-#: process-wide compiled-sweep cache (same policy as the fused-bracket and
-#: batch caches: one compile per (objective, schedule, space, knobs, mesh)).
-#: Values are AOT-compiled executables — cache hits skip retracing AND
-#: recompiling on repeated runs of the same schedule.
-_SWEEP_EXE_CACHE: LRUCache = LRUCache(maxsize=16)
 
 
 def sweep_phase_maps(scopes=None) -> Dict[str, Dict[str, str]]:
@@ -117,21 +106,6 @@ def _lane_accounting(eval_fn, plans, outputs) -> Dict[str, Any]:
     for name, value in row.items():
         obs.get_metrics().gauge("sweep.lane." + name).set(value)
     return row
-
-
-def _note_device_refits(decoded: Dict[str, Any]) -> None:
-    """Surface device-side TPE fits to the event plane: a fused sweep
-    fits its models in-trace, so the host-side ``kde_refit`` emit in
-    models/bohb_kde.py never fires and the model-freshness consumers
-    (the kde_refit_stall anomaly rule, the kde_refit_staleness SLO in
-    obs/slo.py) would read a healthy fused run as permanently stale.
-    One event per telemetry fold that recorded any fits."""
-    fits = decoded.get("model_fits")
-    if (
-        isinstance(fits, (int, float)) and fits > 0
-        and obs.get_bus().active
-    ):
-        obs.emit(obs.KDE_REFIT, source="device", fits=int(fits))
 
 
 class _ReplayIteration(SuccessiveHalving):
@@ -450,130 +424,45 @@ class FusedBOHB:
             iteration, self.min_budget, self.max_budget, self.eta
         )
 
-    def _sweep_key(self, plans, dynamic=False, caps=None, resident=False,
-                   incumbent_only=False, device_metrics=False):
-        if dynamic:
-            from hpbandster_tpu.ops.kde import _pallas_fit_requested
-
-            # the whole point of the dynamic tier: observation counts are
-            # traced inputs, so they must NOT key the executable — only the
-            # buffer capacities (shapes) do. "state" marks the
-            # return_state/donated executable this driver always builds
-            # (a plain dynamic sweep built elsewhere must not collide).
-            # The resolved HPB_PALLAS_KDE_FIT flag keys too: it is read
-            # at trace time inside fit_kde_pair_masked, so flipping it
-            # mid-process must MISS the cache, not silently serve an
-            # executable compiled under the other fit path.
-            obs_term = ("dynamic", "state", tuple(sorted(caps.items())),
-                        bool(resident), bool(incumbent_only),
-                        _pallas_fit_requested())
-        else:
-            warm_counts = {b: len(l) for b, l in self._warm_l.items()}
-            obs_term = tuple(sorted(warm_counts.items()))
-        return (
-            # exactly one of these is non-None (ctor contract), so the
-            # pair keys stateless and stateful executables apart
-            (self.eval_fn, self.stateful_eval),
-            tuple((p.num_configs, p.budgets) for p in plans),
-            self.codec.signature,
-            self.num_samples,
-            self.random_fraction,
-            self.top_n_percent,
-            self.min_points_in_model,
-            self.bandwidth_factor,
-            self.min_bandwidth,
-            self.mesh,
-            self.axis,
-            obs_term,
-            self.use_pallas,
-            self.pallas_interpret,
-            self.promotion_rank_fn,
-            self._conditions_sig,
-            self._forbiddens_sig,
-            # telemetry changes the traced program (extra outputs), so
-            # metrics-on and metrics-off executables must never collide
-            bool(device_metrics),
-        )
-
-    def _build_sweep_fn(self, plans, dynamic=False, caps=None,
-                        resident=False, incumbent_only=False,
-                        device_metrics=False):
-        warm_counts = {b: len(l) for b, l in self._warm_l.items()}
-        return make_fused_sweep_fn(
-            self.eval_fn,
-            plans,
-            self.codec,
-            num_samples=self.num_samples,
-            random_fraction=self.random_fraction,
-            top_n_percent=self.top_n_percent,
-            min_points_in_model=self.min_points_in_model,
-            bandwidth_factor=self.bandwidth_factor,
-            min_bandwidth=self.min_bandwidth,
-            mesh=self.mesh,
-            axis=self.axis,
-            warm_counts=warm_counts,
-            use_pallas=self.use_pallas,
-            pallas_interpret=self.pallas_interpret,
-            rank_fn=self.promotion_rank_fn,
-            active_mask_fn=self.active_mask_fn,
-            forbidden_fn=self.forbidden_fn,
-            fallback_vector=self._fallback_vector,
-            dynamic_counts=dynamic,
-            capacities=caps,
+    def _sweep_driver(self, dynamic, incumbent_only=False,
+                      **mode) -> SweepDriver:
+        """This optimizer's sweep program behind the one driver of the
+        device sweep (``ops/sweep_driver.py``); ``mode`` is the rest of
+        ``SweepDriver``'s (``resident``, ``device_metrics``, ``cold``, ...)."""
+        return SweepDriver(
+            self.eval_fn, self.codec,
+            dict(
+                # exactly one of eval_fn and stateful_eval is non-None (ctor
+                # contract), so the pair keys stateless and stateful
+                # executables apart
+                stateful_eval=self.stateful_eval,
+                num_samples=self.num_samples,
+                random_fraction=self.random_fraction,
+                top_n_percent=self.top_n_percent,
+                min_points_in_model=self.min_points_in_model,
+                bandwidth_factor=self.bandwidth_factor,
+                min_bandwidth=self.min_bandwidth,
+                mesh=self.mesh,
+                axis=self.axis,
+                use_pallas=self.use_pallas,
+                pallas_interpret=self.pallas_interpret,
+                rank_fn=self.promotion_rank_fn,
+                active_mask_fn=self.active_mask_fn,
+                forbidden_fn=self.forbidden_fn,
+                fallback_vector=self._fallback_vector,
+            ),
+            space_sig=(self._conditions_sig, self._forbiddens_sig),
+            dynamic=dynamic,
+            incumbent_only=incumbent_only,
             # the dynamic tier returns (and the warm inputs donate into)
             # the updated observation state, so consecutive chunks thread
             # it device-to-device across chunk boundaries — the ensemble
             # state itself is bracket-local scratch and never part of it
-            return_state=dynamic and not incumbent_only,
-            resident=resident,
-            incumbent_only=incumbent_only,
-            device_metrics=device_metrics,
-            stateful_eval=self.stateful_eval,
+            thread_state=dynamic and not incumbent_only,
+            warm_v=self._warm_v,
+            warm_l=self._warm_l,
+            **mode,
         )
-
-    def _sweep_compiled(self, plans, example_args, dynamic=False, caps=None,
-                        resident=False, incumbent_only=False,
-                        device_metrics=False, phase_s=None):
-        """AOT-compiled sweep executable + honest timing attribution:
-        returns ``(compiled, build_compile_seconds, cache_hit)``. Ahead-of-
-        time ``lower().compile()`` separates compile from execute time (the
-        jit dispatch path can't), and the cached executable skips retracing
-        on repeated runs of the same schedule. ``build_compile_seconds`` is
-        the time THIS call paid — 0.0 on a cache hit, so summing it across
-        artifacts never double-counts a compile. On a miss the two halves
-        are spans of their own (``compile.trace_lower``: Python trace and
-        lowering; ``compile.compile``: XLA, or the persistent cache's
-        load), their seconds added to ``phase_s``."""
-        key = self._sweep_key(plans, dynamic=dynamic, caps=caps,
-                              resident=resident,
-                              incumbent_only=incumbent_only,
-                              device_metrics=device_metrics)
-        hit = _SWEEP_EXE_CACHE.get(key)
-        if hit is not None:
-            self.last_executable = hit
-            return hit, 0.0, True
-        from hpbandster_tpu.utils.compile_cache import (
-            enable_persistent_compile_cache,
-        )
-
-        # before the first compile: a second process (or the next chip
-        # call, where the machine keeps the directory) loads the program
-        enable_persistent_compile_cache()
-        from hpbandster_tpu.obs.timeline import COMPILE, sweep_span
-
-        t0 = time.perf_counter()
-        with sweep_span("compile.trace_lower", COMPILE, phase_s):
-            fn = self._build_sweep_fn(plans, dynamic=dynamic, caps=caps,
-                                      resident=resident,
-                                      incumbent_only=incumbent_only,
-                                      device_metrics=device_metrics)
-            lowered = fn.lower(*example_args)
-        with sweep_span("compile.compile", COMPILE, phase_s):
-            compiled = lowered.compile()
-        dt = time.perf_counter() - t0
-        _SWEEP_EXE_CACHE[key] = compiled
-        self.last_executable = compiled
-        return compiled, dt, False
 
     def run(
         self,
@@ -697,18 +586,8 @@ class FusedBOHB:
         """The body of :meth:`run`, inside its ``run`` span."""
         import functools
 
-        import jax
-
         from hpbandster_tpu.obs import timeline
-        from hpbandster_tpu.obs.timeline import (
-            ADMISSION,
-            COMPILE,
-            PROMOTION,
-            RUNG_COMPUTE,
-            TRANSFER,
-        )
-        from hpbandster_tpu.obs.trace import use_trace
-        from hpbandster_tpu.utils.profiling import trace
+        from hpbandster_tpu.obs.timeline import ADMISSION, PROMOTION, TRANSFER
 
         sweep_trace = run_span.trace
         sweep_span = functools.partial(timeline.sweep_span, trace=sweep_trace)
@@ -727,10 +606,6 @@ class FusedBOHB:
         with sweep_span("sweep_setup", ADMISSION, phase_s):
             if self.config["time_ref"] is None:
                 self.config["time_ref"] = time.time()
-
-            from hpbandster_tpu.parallel.mesh import is_multiprocess_mesh
-
-            multiprocess = is_multiprocess_mesh(self.mesh)
             if resident and chunk_brackets is not None:
                 raise ValueError(
                     "resident=True replaces chunking (the whole schedule is one "
@@ -752,22 +627,12 @@ class FusedBOHB:
                 (chunk_brackets is not None)
                 if dynamic_counts is None else bool(dynamic_counts)
             )
-            from hpbandster_tpu.obs.device_metrics import device_metrics_default
-
-            use_dm = (
-                device_metrics_default()
-                if device_metrics is None else bool(device_metrics)
+            # (a cold resident sweep uploads zero-filled buffers here, the
+            # bare seed in run_sharded_fused_sweep: ROADMAP, named debt)
+            driver = self._sweep_driver(
+                dynamic, resident=resident, device_metrics=device_metrics,
+                cold="stream_all", trace=sweep_trace, profile_dir=profile_dir,
             )
-            #: fetched per-chunk metrics pytrees + their bracket schedules —
-            #: decoded once at the end of the run into ONE telemetry record
-            dm_parts: List[Any] = []
-            dm_execute_s = 0.0
-            link0 = None
-            if plans:
-                from hpbandster_tpu.obs.runtime import transfer_counters
-
-                link0 = transfer_counters()
-            d = int(self.codec.kind.shape[0])
             done = first
             #: deferred host bookkeeping of the PREVIOUS chunk: replaying the
             #: reference-shaped Datum/SuccessiveHalving state machine is the
@@ -777,15 +642,6 @@ class FusedBOHB:
             #: executes the next chunk instead of serializing with it
             pending_replay = None
             overlap_s = None
-            #: device-resident observation state threaded between dynamic
-            #: chunks (the return_state/donation contract, ops/sweep.py): the
-            #: previous chunk's returned (obs_v, obs_l, counts) pytrees feed
-            #: the next call directly — donated, so XLA updates the buffers in
-            #: place and the warm state never round-trips through the host.
-            #: Invalidated when a capacity bucket doubles (shapes changed);
-            #: the host fold (_accumulate_obs) then rebuilds identical values.
-            dev_state = None
-            dev_caps = None
 
         def _flush_replay():
             """Idempotent: runs the deferred replay exactly once. Clears
@@ -807,172 +663,14 @@ class FusedBOHB:
             phase_s, self._phase_carry = self._phase_carry, {}
             seed = np.uint32(self.rng.integers(2**32, dtype=np.uint32))
             overlap_s = None
-            #: host bytes materialized by the per-shard streamed warm
-            #: upload (jax Arrays, so the generic non-jax-leaf sum below
-            #: cannot see them)
-            streamed_bytes = 0
             try:
-                # the staging window: warm-buffer padding / streaming,
-                # transfer-ledger accounting, replicated-array wrapping
-                # -- the host cost of putting this chunk's inputs on the
-                # device link (the flight recorder's h2d counterpart of
-                # telemetry_fetch)
-                with sweep_span("chunk_staging", TRANSFER, phase_s):
-                    run_caps = None
-                    if dynamic:
-                        # PAST-ONLY capacities, pow2-bucketed with a generous
-                        # floor: warm counts at this chunk boundary + this chunk's
-                        # additions, rounded up. Two runs that agree on history
-                        # agree on every chunk's buffer shapes regardless of how
-                        # much schedule lies ahead (the resume guarantee), and
-                        # consecutive chunks reuse one executable until a bucket
-                        # doubles. The 256 floor makes doublings RARE: any run
-                        # under 256 observations per budget is one compile total,
-                        # and a 10k-config sweep crosses ~6 boundaries — where a
-                        # floor-of-8 bucket spent the whole small-run regime in
-                        # doubling-dense territory and recompiled almost every
-                        # chunk (measured: 8 compiles/9 chunks). Masked model math
-                        # over >=256 rows is trivial device work next to that.
-                        run_caps = {
-                            float(b): len(l) for b, l in self._warm_l.items()
-                        }
-                        for b, k in plan_additions(chunk_plans).items():
-                            run_caps[b] = run_caps.get(b, 0) + k
-                        run_caps = pow2_capacities(run_caps)
-                        if dev_state is not None and run_caps == dev_caps:
-                            # same buffer shapes: hand the previous chunk's
-                            # device state straight back — zero warm-state
-                            # bytes cross the host link
-                            args = (seed,) + dev_state
-                        elif self._can_stream_warm(multiprocess, run_caps):
-                            # sharded mesh: warm buffers stream up PER SHARD
-                            # SLICE — the full-capacity array (1M+ rows at the
-                            # fused_1M scale) never materializes on host in
-                            # one piece (ISSUE 10: bounded peak host RSS,
-                            # probed by the bench tier)
-                            args, streamed_bytes = self._stream_warm_args(
-                                seed, run_caps, d
-                            )
-                            dev_state = None  # stale shapes: never reuse
-                        else:
-                            warm_v_pad, warm_l_pad, warm_n = {}, {}, {}
-                            for b, cap in run_caps.items():
-                                v = self._warm_v.get(b)
-                                n = 0 if v is None else len(v)
-                                buf_v = np.zeros((cap, d), np.float32)
-                                buf_l = np.full(cap, np.inf, np.float32)
-                                if n:
-                                    buf_v[:n] = v
-                                    buf_l[:n] = self._warm_l[b]
-                                warm_v_pad[b] = buf_v
-                                warm_l_pad[b] = buf_l
-                                warm_n[b] = np.int32(n)
-                            args = (seed, warm_v_pad, warm_l_pad, warm_n)
-                            dev_state = None  # stale shapes: never reuse
-                    else:
-                        args = (
-                            (seed, self._warm_v, self._warm_l)
-                            if self._warm_l else (seed,)
-                        )
-                    # the budget gate's transfer ledger: bytes the host link
-                    # actually carries this chunk — measured BEFORE any
-                    # to_global conversion below wraps the numpy leaves in jax
-                    # Arrays (measuring after would read 0 on the DCN tier).
-                    # Device-resident state leaves cost nothing: that is the
-                    # state-threading win.
-                    upload_bytes = streamed_bytes + sum(
-                        int(getattr(l, "nbytes", 0))
-                        for l in jax.tree_util.tree_leaves(args)
-                        if not isinstance(l, jax.Array)
-                    )
-                    if multiprocess:
-                        # DCN tier: host-local numpy args become GLOBAL replicated
-                        # arrays (every rank holds identical values — the SPMD
-                        # drivers run the same deterministic control flow), matching
-                        # the sweep executable's replicated in_shardings. Leaves
-                        # that are already jax Arrays (the threaded device state)
-                        # pass through untouched — they carry the right sharding
-                        # from the previous call's out_shardings.
-                        from jax.sharding import NamedSharding, PartitionSpec
-
-                        rep = NamedSharding(self.mesh, PartitionSpec())
-
-                        def to_global(x):
-                            if isinstance(x, jax.Array):
-                                return x
-                            arr = np.asarray(x)
-                            return jax.make_array_from_callback(
-                                arr.shape, rep, lambda idx: arr[idx]
-                            )
-
-                        args = jax.tree.map(to_global, args)
-                    from hpbandster_tpu.obs.runtime import note_transfer
-
-                    note_transfer("h2d", upload_bytes)
-                with trace(profile_dir), use_trace(sweep_trace):
-                    # on a ledger miss this window is the real trace+build
-                    # wall (also reported as compile_s on the chunk
-                    # record); on a hit, the lookup itself
-                    with sweep_span("compile_lookup", COMPILE, phase_s):
-                        compiled, compile_s, cache_hit = self._sweep_compiled(
-                            tuple(chunk_plans), args, dynamic=dynamic,
-                            caps=run_caps, resident=resident,
-                            device_metrics=use_dm, phase_s=phase_s,
-                        )
-                    t_exec = time.perf_counter()
-                    # arguments up and the program enqueued: returns
-                    # before the device has finished (async dispatch)
-                    with sweep_span("dispatch", TRANSFER, phase_s):
-                        raw = compiled(*args)
-                    dm_dev = None
-                    if dynamic:
-                        # keep the updated observation state ON DEVICE for
-                        # the next chunk; only bracket outputs (and the
-                        # O(schedule) metrics pytree) are fetched
-                        if use_dm:
-                            raw, dm_dev, new_state = raw
-                        else:
-                            raw, new_state = raw
-                    elif use_dm:
-                        raw, dm_dev = raw
-                    # pipelining: the previous chunk's bookkeeping replays
-                    # HERE, concurrent with this chunk's device execution
-                    _flush_replay()
-                    # the host blocked on the device: what is left of the
-                    # program's run, then the outputs' d2h
-                    with sweep_span("fetch", RUNG_COMPUTE, phase_s):
-                        outputs = jax.device_get(raw)
-                    if dm_dev is not None:
-                        # outputs already synced above, so this fetch is
-                        # pure d2h of the O(schedule) telemetry pytree —
-                        # the one transfer-phase slice the fused journal
-                        # can measure honestly
-                        with sweep_span("telemetry_fetch", TRANSFER, phase_s):
-                            dm_parts.append((
-                                jax.device_get(dm_dev),
-                                [(p.num_configs, p.budgets)
-                                 for p in chunk_plans],
-                            ))
-                    # span of the device phase (dispatch -> fetch complete).
-                    # When the overlapped replay outlasts the device work this
-                    # OVERSTATES device-busy seconds, so derived MFU reads
-                    # conservative; replay_overlap_s makes it attributable.
-                    execute_s = time.perf_counter() - t_exec
-                    if dynamic:
-                        dev_state, dev_caps = new_state, run_caps
-                d2h_bytes = sum(
-                    int(l.nbytes)
-                    for l in jax.tree_util.tree_leaves(outputs)
+                # pipelining: the previous chunk's bookkeeping replays
+                # inside this chunk's device window
+                outputs, stat = driver.run_chunk(
+                    chunk_plans, seed, phase_s, first_bracket=done,
+                    while_device_runs=_flush_replay,
                 )
-                if dm_parts and dm_dev is not None:
-                    # the telemetry rides the same final d2h; its bill is
-                    # O(schedule), measured here rather than asserted
-                    d2h_bytes += sum(
-                        int(np.asarray(l).nbytes)
-                        for l in jax.tree_util.tree_leaves(dm_parts[-1][0])
-                    )
-                    dm_execute_s += execute_s
-                note_transfer("d2h", d2h_bytes)
+                self.last_executable = driver.last_executable
                 if resident:
                     # scan-stacked per-rotation-position outputs -> the
                     # flat per-bracket list the replay below consumes
@@ -1009,19 +707,10 @@ class FusedBOHB:
             # record (and its sink write), per-job attribution info —
             # is host bookkeeping the timeline charges to promotion
             with sweep_span("chunk_accounting", PROMOTION, phase_s):
-                stat = {
+                driver.journal(stat, len(self.run_stats), phase_s)
+                stat.update({
                     "chunk_index": len(self.run_stats),
-                    "brackets": list(range(done, done + len(chunk_plans))),
-                    "evaluations": int(
-                        sum(sum(p.num_configs) for p in chunk_plans)
-                    ),
-                    "build_compile_s": round(compile_s, 4),
-                    "compile_cache_hit": cache_hit,
-                    "execute_fetch_s": round(execute_s, 4),
                     "dynamic_counts": bool(dynamic),
-                    # where this chunk's warm observations came from: 0 bytes
-                    # uploaded = the donated device thread carried them
-                    "warm_upload_bytes": int(upload_bytes),
                     # evaluations whose replay built a Job: all of them
                     # under a result logger or a journal sink, else none
                     "replay_jobs_built": 0,
@@ -1034,31 +723,13 @@ class FusedBOHB:
                     # whenever it runs, the call's result and run on its
                     # last row) add to the same dict
                     "phase_s": phase_s,
-                }
+                })
                 if overlap_s is not None:
                     # host replay of the PRIOR chunk that ran inside this
                     # chunk's device window
                     stat["replay_overlap_s"] = round(overlap_s, 4)
                 stat.update(_lane_accounting(self.eval_fn, chunk_plans, outputs))
                 self.run_stats.append(stat)
-                # one span-shaped event per device chunk: the journal's view of
-                # the fused tier (duration = dispatch -> fetch; compile split
-                # out; h2d/d2h byte fields feed the summarize host-link section)
-                with use_trace(sweep_trace):
-                    obs.emit(
-                        "sweep_chunk",
-                        duration_s=stat["execute_fetch_s"],
-                        compile_s=stat["build_compile_s"],
-                        compile_cache_hit=cache_hit,
-                        evaluations=stat["evaluations"],
-                        brackets=stat["brackets"],
-                        seq=stat["chunk_index"],
-                        h2d_bytes=int(upload_bytes),
-                        d2h_bytes=int(d2h_bytes),
-                        # the phases that have closed by now; the later
-                        # ones are journal events of their own
-                        phase_s=dict(phase_s),
-                    )
                 # per-job device-timing attribution (VERDICT r1 #10): every run
                 # of this chunk carries the chunk's compile/execute seconds into
                 # Result.info / results.json, so timing claims reproduce from
@@ -1066,7 +737,7 @@ class FusedBOHB:
                 job_info = {
                     "fused_chunk": stat["chunk_index"],
                     "chunk_compile_s": stat["build_compile_s"],
-                    "chunk_compile_cache_hit": cache_hit,
+                    "chunk_compile_cache_hit": stat["compile_cache_hit"],
                     "chunk_execute_s": stat["execute_fetch_s"],
                     "chunk_evaluations": stat["evaluations"],
                 }
@@ -1114,46 +785,14 @@ class FusedBOHB:
         # the call's result and run spans land on its last row
         run_span.totals = phase_s
         with sweep_span("result", PROMOTION, phase_s):
-            return self._sweep_result(
-                link0, dm_parts, dm_execute_s, sweep_trace
+            # this call's whole transfer bill as gauges, and the decoded
+            # device telemetry (None with the metrics plane off)
+            _, decoded = driver.finish()
+            if decoded is not None:
+                self.last_device_telemetry = decoded
+            return Result(
+                list(self.iterations) + self.warmstart_iteration, self.config
             )
-
-    def _sweep_result(self, link0, dm_parts, dm_execute_s, sweep_trace) -> Result:
-        """Transfer gauges, the decoded device telemetry and the
-        ``Result``: what :meth:`run` does after its last replay."""
-        if link0 is not None:
-            # per-sweep host-link gauges (sweep.transfer_bytes.{h2d,d2h},
-            # sweep.host_syncs): this run() call's whole transfer bill
-            from hpbandster_tpu.obs.runtime import publish_sweep_transfers
-
-            publish_sweep_transfers(link0)
-        if dm_parts:
-            # fold every chunk's device telemetry into ONE decoded record:
-            # gauges for the scraper, a device_telemetry journal record
-            # for summarize/report/anomaly — the obs pipeline's view of
-            # work that never surfaced to host per bracket
-            from hpbandster_tpu.obs.device_metrics import (
-                decode_device_metrics,
-                emit_device_telemetry,
-                publish_device_metrics,
-            )
-
-            decoded = decode_device_metrics(
-                dm_parts, execute_s=dm_execute_s
-            )
-            publish_device_metrics(decoded)
-            # journaled under the sweep's trace: the device loop's rung
-            # sections join the same per-trace timeline as the host-side
-            # chunk spans (summarize trace_timelines / obs timeline)
-            from hpbandster_tpu.obs.trace import use_trace
-
-            with use_trace(sweep_trace):
-                emit_device_telemetry(decoded)
-                _note_device_refits(decoded)
-            self.last_device_telemetry = decoded
-        return Result(
-            list(self.iterations) + self.warmstart_iteration, self.config
-        )
 
     def run_incumbent(
         self,
@@ -1189,39 +828,9 @@ class FusedBOHB:
         span name, the names :meth:`run` uses for the phases this entry
         point has.
         """
-        from hpbandster_tpu.obs.timeline import ADMISSION, sweep_span
+        from hpbandster_tpu.obs.timeline import ADMISSION, PROMOTION, sweep_span
         from hpbandster_tpu.obs.trace import current_trace, new_trace, use_trace
-
-        phase_s: Dict[str, float] = {}
-        inc_trace = current_trace() or new_trace(self.run_id)
-        with use_trace(inc_trace), sweep_span("run", ADMISSION, phase_s):
-            out = self._run_incumbent(
-                phase_s, n_iterations, profile_dir, resident, device_metrics
-            )
-        out["phase_s"] = phase_s
-        return out
-
-    def _run_incumbent(self, phase_s, n_iterations, profile_dir, resident,
-                       device_metrics) -> Dict[str, Any]:
-        """The body of :meth:`run_incumbent`, inside its ``run`` span."""
-        import jax
-
-        from hpbandster_tpu.obs.timeline import (
-            ADMISSION,
-            COMPILE,
-            PROMOTION,
-            RUNG_COMPUTE,
-            TRANSFER,
-            sweep_span,
-        )
-
-        from hpbandster_tpu.obs.runtime import (
-            note_transfer,
-            publish_sweep_transfers,
-            transfer_counters,
-        )
         from hpbandster_tpu.parallel.mesh import is_multiprocess_mesh
-        from hpbandster_tpu.utils.profiling import trace
 
         if is_multiprocess_mesh(self.mesh):
             raise ValueError(
@@ -1229,213 +838,58 @@ class FusedBOHB:
                 "parallel.multihost.run_sharded_fused_sweep(resident=True) "
                 "for the SPMD pod tier"
             )
-        with sweep_span("sweep_planning", ADMISSION, phase_s):
-            plans = [self._plan(i) for i in range(int(n_iterations))]
-        if not plans:
+        if int(n_iterations) < 1:
             raise ValueError("run_incumbent needs n_iterations >= 1")
-        with sweep_span("chunk_staging", TRANSFER, phase_s):
-            d = int(self.codec.kind.shape[0])
-            # same capacity policy as the chunked tier (pow2, floor 256) so a
-            # warm-started incumbent query shares executables with runs that
-            # agree on history
-            run_caps = {float(b): len(l) for b, l in self._warm_l.items()}
-            for b, k in plan_additions(plans).items():
-                run_caps[b] = run_caps.get(b, 0) + k
-            run_caps = pow2_capacities(run_caps)
+        phase_s: Dict[str, float] = {}
+        inc_trace = current_trace() or new_trace(self.run_id)
+        with use_trace(inc_trace), sweep_span("run", ADMISSION, phase_s):
+            with sweep_span("sweep_planning", ADMISSION, phase_s):
+                plans = [self._plan(i) for i in range(int(n_iterations))]
+            # the chunked tier's capacity policy, so a warm-started incumbent
+            # query shares buffer shapes with runs that agree on history; its
+            # buffers are padded on the host whatever the mesh
+            driver = self._sweep_driver(
+                True, resident=resident, incumbent_only=True,
+                device_metrics=device_metrics, trace=inc_trace,
+                profile_dir=profile_dir,
+            )
             seed = np.uint32(self.rng.integers(2**32, dtype=np.uint32))
-            warm_v_pad, warm_l_pad, warm_n = {}, {}, {}
-            for b, cap in run_caps.items():
-                v = self._warm_v.get(b)
-                n = 0 if v is None else len(v)
-                buf_v = np.zeros((cap, d), np.float32)
-                buf_l = np.full(cap, np.inf, np.float32)
-                if n:
-                    buf_v[:n] = v
-                    buf_l[:n] = self._warm_l[b]
-                warm_v_pad[b] = buf_v
-                warm_l_pad[b] = buf_l
-                warm_n[b] = np.int32(n)
-            args = (seed, warm_v_pad, warm_l_pad, warm_n)
-            from hpbandster_tpu.obs.device_metrics import device_metrics_default
-
-            use_dm = (
-                device_metrics_default()
-                if device_metrics is None else bool(device_metrics)
-            )
-            link0 = transfer_counters()
-            upload_bytes = sum(
-                int(getattr(l, "nbytes", 0))
-                for l in jax.tree_util.tree_leaves(args)
-            )
-            note_transfer("h2d", upload_bytes)
-        with trace(profile_dir):
-            with sweep_span("compile_lookup", COMPILE, phase_s):
-                compiled, compile_s, cache_hit = self._sweep_compiled(
-                    tuple(plans), args, dynamic=True, caps=run_caps,
-                    resident=resident, incumbent_only=True,
-                    device_metrics=use_dm, phase_s=phase_s,
+            inc, stat = driver.run_chunk(plans, seed, phase_s)
+            self.last_executable = driver.last_executable
+            with sweep_span("result", PROMOTION, phase_s):
+                # the resident sweep is one chunk: its sweep_chunk record
+                # is the rung_compute interval the flight recorder lays the
+                # decoded per-rung sections onto
+                driver.journal(stat, 0, phase_s)
+                link, decoded = driver.finish()
+                incumbent = {
+                    "vector": [float(x) for x in np.asarray(inc.vector)],
+                    "loss": float(np.asarray(inc.loss)),
+                    "bracket": int(np.asarray(inc.bracket)),
+                    "per_bracket_loss": [
+                        float(x) for x in np.asarray(inc.per_bracket_loss)
+                    ],
+                }
+                obs.emit_sweep_incumbent(
+                    evaluations=stat["evaluations"],
+                    d2h_bytes=link["transfer_bytes_d2h"],
+                    h2d_bytes=link["transfer_bytes_h2d"],
+                    host_syncs=link["transfers_h2d"] + link["transfers_d2h"],
+                    **incumbent,
                 )
-            t0 = time.perf_counter()
-            with sweep_span("dispatch", TRANSFER, phase_s):
-                raw = compiled(*args)
-            with sweep_span("fetch", RUNG_COMPUTE, phase_s):
-                inc, dm_host = jax.device_get(raw) if use_dm else (
-                    jax.device_get(raw), None
-                )
-            execute_s = time.perf_counter() - t0
-        with sweep_span("result", PROMOTION, phase_s):
-            dm_leaves = (
-                list(jax.tree_util.tree_leaves(dm_host))
-                if dm_host is not None else []
-            )
-            note_transfer(
-                "d2h",
-                sum(int(np.asarray(l).nbytes) for l in inc)
-                + sum(int(np.asarray(l).nbytes) for l in dm_leaves),
-                buffers=len(inc) + len(dm_leaves),
-            )
-            link = publish_sweep_transfers(link0)
-            evaluations = int(sum(sum(p.num_configs) for p in plans))
-            vector = [float(x) for x in np.asarray(inc.vector)]
-            loss = float(np.asarray(inc.loss))
-            bracket = int(np.asarray(inc.bracket))
-            per_bracket = [float(x) for x in np.asarray(inc.per_bracket_loss)]
-            # span-shaped device slice: the resident sweep is one chunk,
-            # so the flight recorder gets a rung_compute interval to lay
-            # the decoded per-rung sections onto
-            obs.emit(
-                "sweep_chunk",
-                duration_s=round(execute_s, 4),
-                compile_s=round(compile_s, 4),
-                compile_cache_hit=cache_hit,
-                evaluations=evaluations,
-                brackets=list(range(len(plans))),
-                seq=0,
-            )
-            obs.emit_sweep_incumbent(
-                vector=vector,
-                loss=loss,
-                bracket=bracket,
-                per_bracket_loss=per_bracket,
-                evaluations=evaluations,
-                d2h_bytes=link["transfer_bytes_d2h"],
-                h2d_bytes=link["transfer_bytes_h2d"],
-                host_syncs=link["transfers_h2d"] + link["transfers_d2h"],
-            )
-            out = {
-                "incumbent": {
-                    "vector": vector,
-                    "loss": loss,
-                    "bracket": bracket,
-                    "per_bracket_loss": per_bracket,
-                },
-                "evaluations": evaluations,
-                "build_compile_s": round(compile_s, 4),
-                "compile_cache_hit": cache_hit,
-                "execute_fetch_s": round(execute_s, 4),
-                "transfers": link,
-            }
-            if dm_host is not None:
-                from hpbandster_tpu.obs.device_metrics import (
-                    decode_device_metrics,
-                    emit_device_telemetry,
-                    publish_device_metrics,
-                )
-
-                decoded = decode_device_metrics(
-                    dm_host, plans=plans, execute_s=execute_s
-                )
-                publish_device_metrics(decoded)
-                emit_device_telemetry(decoded)
-                _note_device_refits(decoded)
-                self.last_device_telemetry = decoded
-                out["device_telemetry"] = decoded
+                out = {
+                    "incumbent": incumbent,
+                    "evaluations": stat["evaluations"],
+                    "build_compile_s": stat["build_compile_s"],
+                    "compile_cache_hit": stat["compile_cache_hit"],
+                    "execute_fetch_s": stat["execute_fetch_s"],
+                    "transfers": link,
+                    "phase_s": phase_s,
+                }
+                if decoded is not None:
+                    self.last_device_telemetry = decoded
+                    out["device_telemetry"] = decoded
         return out
-
-    def _can_stream_warm(self, multiprocess: bool, run_caps) -> bool:
-        """Streamed per-shard warm uploads apply on single-process meshes
-        whose capacities shard evenly — exactly the cases where the sweep
-        pins the state's boundary shardings over the config axis
-        (``ops/sweep.py`` ``pin_state_shards`` + ``shard_rows``'s
-        divisible-widths policy), so streamed inputs and threaded device
-        state always agree on sharding. Anything else keeps the plain
-        host-buffer path."""
-        if self.mesh is None or multiprocess:
-            return False
-        from hpbandster_tpu.parallel.mesh import shard_count
-
-        n_shards = shard_count(self.mesh, self.axis)
-        return n_shards > 1 and all(
-            cap % n_shards == 0 for cap in run_caps.values()
-        )
-
-    def _stream_warm_args(self, seed, run_caps, d):
-        """Warm observation buffers for a single-process MESH run, built
-        per shard slice through ``jax.make_array_from_callback``.
-
-        The plain path allocates each budget's full-capacity buffer on
-        host before upload — at the 1M-config scale that is the one place
-        the chunked driver materializes O(total configs) host memory in a
-        single piece. Here the callback only ever holds ONE shard's slice
-        (capacity / shard count rows), so peak host RSS is bounded by a
-        slice regardless of sweep size (the bench ``fused_100k`` /
-        ``fused_1M`` RSS probe). Shardings match the sweep's in-trace
-        state pins (``ops/sweep.py`` ``pin_state_shards``): the AOT
-        executable sees identical input shardings whether the state
-        arrives streamed (chunk 0 / after a capacity doubling) or as the
-        previous chunk's threaded device state. Returns
-        ``(args, host_bytes_materialized)``.
-        """
-        import jax
-
-        from hpbandster_tpu.parallel.mesh import batch_sharding, shard_count
-
-        n_shards = shard_count(self.mesh, self.axis)
-        shard = batch_sharding(self.mesh, self.axis)
-        warm_v, warm_l, warm_n = {}, {}, {}
-        bytes_up = 0
-        for b, cap in run_caps.items():
-            if cap % n_shards:
-                # _can_stream_warm guarantees divisible caps; a
-                # differently-sharded streamed input would violate the
-                # AOT sharding-stability contract above — fail loudly
-                # rather than silently falling back to replication
-                raise ValueError(
-                    f"streamed warm upload needs capacities divisible by "
-                    f"the {n_shards}-way '{self.axis}' axis, got {cap} for "
-                    f"budget {b} (gate with _can_stream_warm)"
-                )
-            src_v = self._warm_v.get(b)
-            src_l = self._warm_l.get(b)
-            n = 0 if src_v is None else len(src_v)
-
-            def fill(idx, shape, fill_value, src, n=n):
-                start, stop, _ = idx[0].indices(shape[0])
-                buf = np.full((stop - start,) + shape[1:], fill_value,
-                              np.float32)
-                if src is not None and start < n:
-                    take = src[start:min(stop, n)]
-                    buf[: len(take)] = take
-                return buf
-
-            # bind per-iteration values as defaults: the callbacks run
-            # inside make_array_from_callback but must not see a later
-            # iteration's closure state
-            warm_v[b] = jax.make_array_from_callback(
-                (cap, d), shard,
-                lambda idx, cap=cap, src=src_v, fill=fill: fill(
-                    idx, (cap, d), 0.0, src
-                ),
-            )
-            warm_l[b] = jax.make_array_from_callback(
-                (cap,), shard,
-                lambda idx, cap=cap, src=src_l, fill=fill: fill(
-                    idx, (cap,), np.inf, src
-                ),
-            )
-            warm_n[b] = np.int32(n)
-            bytes_up += cap * d * 4 + cap * 4 + 4
-        return (seed, warm_v, warm_l, warm_n), bytes_up
 
     def _write_timings_sidecar(self) -> None:
         """Persist ``run_stats`` as ``fused_timings.json`` next to the

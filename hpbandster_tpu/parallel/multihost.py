@@ -22,19 +22,11 @@ membership per run (SURVEY.md §7 "Multi-host elasticity").
 from __future__ import annotations
 
 import logging
-import time
 from typing import Any, Dict, List, Optional
 
 from hpbandster_tpu.parallel.batched_executor import BatchedExecutor
 
 logger = logging.getLogger("hpbandster_tpu.multihost")
-
-#: process-wide sharded-sweep fn cache — one traced program per
-#: (objective, chunk schedule, space, mesh, knobs), same policy as
-#: ops.fused._FUSED_FN_CACHE / FusedBOHB._SWEEP_EXE_CACHE
-from hpbandster_tpu.utils.lru import LRUCache as _LRUCache
-
-_SHARDED_FN_CACHE: _LRUCache = _LRUCache(maxsize=16)
 
 __all__ = [
     "initialize_multihost",
@@ -237,40 +229,26 @@ def run_sharded_fused_sweep(
     host-link bill above is untouched. ``program_name`` labels the
     compiled program in the obs ledger (roofline attribution).
 
-    Returns a stats dict (incumbent, per-device balance, chunk timings,
-    and ``phase_s``: the call's seconds by span name, the
-    ``obs.timeline.sweep_span`` names ``FusedBOHB.run`` uses for the
+    Every chunk's program is compiled ahead of time through the one
+    driver and executable cache ``FusedBOHB`` uses (``ops/sweep_driver.py``).
+    Returns a stats dict (incumbent, per-device balance, ``chunks`` — each
+    with its ``build_compile_s``, 0.0 on a ``compile_cache_hit`` —,
+    ``last_executable``, and ``phase_s``: the call's seconds by span name,
+    the ``obs.timeline.sweep_span`` names ``FusedBOHB.run`` uses for the
     phases this entry point has).
     SPMD multi-host: call on every rank with identical arguments over a
     pod-spanning mesh; the returned incumbent is identical on all ranks.
     """
-    from hpbandster_tpu.obs.timeline import (
-        ADMISSION,
-        COMPILE,
-        PROMOTION,
-        RUNG_COMPUTE,
-        TRANSFER,
-        sweep_span,
-    )
+    from hpbandster_tpu.obs.timeline import ADMISSION, PROMOTION, sweep_span
 
     phase_s: Dict[str, float] = {}
     with sweep_span("run", ADMISSION, phase_s):
-        import jax
         import numpy as np
 
-        from hpbandster_tpu.obs.runtime import note_transfer
         from hpbandster_tpu.ops.bracket import mesh_aligned_plan
-        from hpbandster_tpu.ops.sweep import (
-            build_space_codec,
-            make_fused_sweep_fn,
-            plan_additions,
-            pow2_capacities,
-        )
-        from hpbandster_tpu.parallel.mesh import (
-            batch_sharding,
-            config_mesh,
-            shard_count,
-        )
+        from hpbandster_tpu.ops.sweep import build_space_codec
+        from hpbandster_tpu.ops.sweep_driver import SweepDriver
+        from hpbandster_tpu.parallel.mesh import config_mesh, shard_count
 
         with sweep_span("sweep_planning", ADMISSION, phase_s):
             if mesh is None:
@@ -278,10 +256,9 @@ def run_sharded_fused_sweep(
             n_shards = shard_count(mesh, axis)
             plan = mesh_aligned_plan(n_configs, min_budget, max_budget, eta, n_shards)
             plans = [plan] * max(int(n_brackets), 1)
+            evaluations = int(sum(sum(p.num_configs) for p in plans))
             codec = build_space_codec(configspace)
-            d = int(codec.kind.shape[0])
             rng = np.random.default_rng(seed)
-            codec_sig = codec.signature
 
         with sweep_span("sweep_setup", ADMISSION, phase_s):
             if resident and chunk_brackets is not None:
@@ -295,182 +272,46 @@ def run_sharded_fused_sweep(
                 else max(int(chunk_brackets), 1)
             )
             dynamic = resident or chunk_brackets is not None
-            from hpbandster_tpu.obs.device_metrics import device_metrics_default
-
-            use_dm = (
-                device_metrics_default()
-                if device_metrics is None else bool(device_metrics)
-            )
-            sweep_kwargs: Dict[str, Any] = dict(
-                num_samples=num_samples,
-                mesh=mesh,
-                axis=axis,
-                shard_sampling=True,
+            driver = SweepDriver(
+                eval_fn, codec,
+                dict(
+                    stateful_eval=stateful_eval,
+                    num_samples=num_samples,
+                    mesh=mesh,
+                    axis=axis,
+                    shard_sampling=True,
+                    # HyperBand mode: an unreachable gate keeps the KDE out of the
+                    # trace entirely (any_trainable=False) — pure sample/eval/promote
+                    min_points_in_model=None if model else 2**30,
+                    program_name=program_name,
+                ),
+                dynamic=dynamic,
+                resident=resident,
                 incumbent_only=True,
-                # HyperBand mode: an unreachable gate keeps the KDE out of the
-                # trace entirely (any_trainable=False) — pure sample/eval/promote
-                min_points_in_model=None if model else 2**30,
+                # resident runs the whole schedule in one dispatch: there is
+                # no next chunk to thread state into
+                thread_state=dynamic and not resident,
+                device_metrics=device_metrics,
+                # a cold resident sweep's whole upload is the 4-byte seed; a
+                # chunked one streams its empty buffers per shard slice
+                cold="seed" if resident else "stream_each",
             )
-            caps = None
-            if dynamic:
-                # one capacity map for the WHOLE schedule (pow2, floor 256): every
-                # chunk shares buffer shapes, so the run is one executable and the
-                # threaded state never re-uploads (ops/sweep.py return_state)
-                caps = pow2_capacities(plan_additions(plans))
-
-            def _empty_state_args():
-                """Zero-observation warm buffers, built PER SHARD SLICE via
-                ``make_array_from_callback`` — no host allocation ever holds a
-                full capacity buffer (the bounded-RSS contract the bench tier's
-                RSS probe checks). Returns ``(warm_v, warm_l, warm_n,
-                host_bytes)`` — the bytes the host link actually carries, so the
-                transfer ledger measures the warm upload instead of asserting it
-                (same accounting as ``FusedBOHB._stream_warm_args``)."""
-                from jax.sharding import NamedSharding, PartitionSpec
-
-                shard = batch_sharding(mesh, axis)
-                rep = NamedSharding(mesh, PartitionSpec())
-                warm_v, warm_l, warm_n = {}, {}, {}
-                host_bytes = 0
-                for b, cap in caps.items():
-                    sh = shard if cap % n_shards == 0 else rep
-                    warm_v[b] = jax.make_array_from_callback(
-                        (cap, d), sh,
-                        lambda idx, cap=cap: np.zeros(
-                            _slice_shape(idx, (cap, d)), np.float32
-                        ),
-                    )
-                    warm_l[b] = jax.make_array_from_callback(
-                        (cap,), sh,
-                        lambda idx, cap=cap: np.full(
-                            _slice_shape(idx, (cap,)), np.inf, np.float32
-                        ),
-                    )
-                    warm_n[b] = np.int32(0)
-                    host_bytes += cap * d * 4 + cap * 4 + 4
-                return warm_v, warm_l, warm_n, host_bytes
-
-            from hpbandster_tpu.obs.runtime import (
-                publish_sweep_transfers,
-                transfer_counters,
-            )
-
-            link0 = transfer_counters()
-            fns: Dict[int, Any] = {}
             chunks: List[Dict[str, Any]] = []
             best: Optional[Dict[str, Any]] = None
             per_bracket_all: List[float] = []
-            dm_parts: List[Any] = []
-            dm_execute_s = 0.0
-            state = None
             remaining = list(plans)
             bracket_base = 0
         while remaining:
             chunk_plans, remaining = remaining[:chunk], remaining[chunk:]
-            # the jitted sweep, from the process-wide cache or built; this
-            # entry point jits on first call, so a first dispatch also
-            # traces and compiles
-            with sweep_span("compile_lookup", COMPILE, phase_s):
-                if len(chunk_plans) not in fns:
-                    # process-wide reuse (same policy as the other fused tiers):
-                    # bench repeats of the same (objective, schedule, mesh, knobs)
-                    # must not retrace/recompile — the compile-count acceptance
-                    # (<= one program per chunk shape) is per PROCESS, not per call
-                    from hpbandster_tpu.ops.kde import _pallas_fit_requested
-
-                    cache_key = (
-                        # exactly one is non-None; the pair keys stateless and
-                        # stateful (warm-continuation) executables apart
-                        (eval_fn, stateful_eval),
-                        tuple((p.num_configs, p.budgets) for p in chunk_plans),
-                        codec_sig, mesh, axis, bool(model), int(num_samples),
-                        dynamic, bool(resident),
-                        None if caps is None else tuple(sorted(caps.items())),
-                        # trace-time flag (ops/kde.py): an env flip must miss
-                        # the cache, not serve the other fit path's executable
-                        _pallas_fit_requested(),
-                        # telemetry adds outputs to the traced program — the
-                        # metrics-on executable must never serve a metrics-off
-                        # call (or vice versa)
-                        use_dm,
-                        # the ledger label is part of what the caller asked for:
-                        # a relabeled request must not serve a fn tracked under
-                        # the old name (roofline attribution would lie)
-                        program_name,
-                    )
-                    cached = _SHARDED_FN_CACHE.get(cache_key)
-                    if cached is None:
-                        cached = make_fused_sweep_fn(
-                            eval_fn, chunk_plans, codec,
-                            dynamic_counts=dynamic,
-                            capacities=caps,
-                            # resident runs the whole schedule in one dispatch:
-                            # there is no next chunk to thread state into
-                            return_state=dynamic and not resident,
-                            resident=resident,
-                            device_metrics=use_dm,
-                            stateful_eval=stateful_eval,
-                            program_name=program_name,
-                            **sweep_kwargs,
-                        )
-                        _SHARDED_FN_CACHE[cache_key] = cached
-                    fns[len(chunk_plans)] = cached
-                fn = fns[len(chunk_plans)]
-            with sweep_span("chunk_staging", TRANSFER, phase_s):
-                seed_val = np.uint32(rng.integers(2**32, dtype=np.uint32))
-                upload_bytes = int(seed_val.nbytes)
-                if dynamic:
-                    if state is not None:
-                        # device-resident thread: nothing but the seed goes up
-                        args = (seed_val,) + state
-                    elif resident:
-                        # cold resident sweep: with no warm inputs the dynamic
-                        # init zeroes the observation buffers IN-TRACE
-                        # (ops/sweep.py init_obs_state's absent-budget branch),
-                        # so the whole upload is the 4-byte seed — h2d is flat
-                        # in config count, like the incumbent-only d2h
-                        args = (seed_val,)
-                    else:
-                        warm_v, warm_l, warm_n, host_bytes = _empty_state_args()
-                        args = (seed_val, warm_v, warm_l, warm_n)
-                        upload_bytes += host_bytes
-                else:
-                    args = (seed_val,)
-                note_transfer("h2d", upload_bytes)
-            t0 = time.perf_counter()
-            with sweep_span("dispatch", TRANSFER, phase_s):
-                out = fn(*args)
-            dm_dev = None
-            if dynamic and not resident:
-                if use_dm:
-                    inc, dm_dev, state = out
-                else:
-                    inc, state = out
-            elif use_dm:
-                inc, dm_dev = out
-            else:
-                inc = out
-            with sweep_span("fetch", RUNG_COMPUTE, phase_s):
-                inc = jax.device_get(inc)
-                dm_host = jax.device_get(dm_dev) if dm_dev is not None else None
-            execute_s = time.perf_counter() - t0
+            seed_val = np.uint32(rng.integers(2**32, dtype=np.uint32))
+            # one capacity map for the WHOLE schedule: nothing of this
+            # sweep's observations ever reaches the host
+            inc, stat = driver.run_chunk(
+                chunk_plans, seed_val, phase_s, first_bracket=bracket_base,
+                sized_by=plans,
+            )
             with sweep_span("chunk_accounting", PROMOTION, phase_s):
-                dm_leaves = (
-                    list(jax.tree_util.tree_leaves(dm_host))
-                    if dm_host is not None else []
-                )
-                if dm_host is not None:
-                    dm_parts.append((
-                        dm_host,
-                        [(p.num_configs, p.budgets) for p in chunk_plans],
-                    ))
-                    dm_execute_s += execute_s
-                note_transfer(
-                    "d2h",
-                    sum(int(np.asarray(l).nbytes) for l in inc)
-                    + sum(int(np.asarray(l).nbytes) for l in dm_leaves),
-                    buffers=len(inc) + len(dm_leaves),
-                )
+                driver.journal(stat, len(chunks), phase_s)
                 loss = float(np.asarray(inc.loss))
                 cand = {
                     "vector": np.asarray(inc.vector, np.float32).tolist(),
@@ -488,12 +329,9 @@ def run_sharded_fused_sweep(
                     )
                 ):
                     best = cand
-                chunks.append({
-                    "brackets": len(chunk_plans),
-                    "execute_fetch_s": round(execute_s, 4),
-                    # 4 bytes (the seed) once the state threads device-to-device
-                    "warm_upload_bytes": upload_bytes,
-                })
+                # warm_upload_bytes: 4 bytes (the seed) once the state
+                # threads device-to-device
+                chunks.append(dict(stat, brackets=len(chunk_plans)))
                 bracket_base += len(chunk_plans)
 
         with sweep_span("result", PROMOTION, phase_s):
@@ -503,9 +341,8 @@ def run_sharded_fused_sweep(
             # width — alignment surplus rows are extra exploration, not dead
             # padding), so pad_rows is 0 here and the surplus over the pure
             # eta-decay ladder is reported separately, uncounted in configs.
-            pure = []
-            for j in range(len(plan.num_configs)):
-                pure.append(max(int(n_configs * float(eta) ** (-j)), 1))
+            pure = [max(int(n_configs * float(eta) ** (-j)), 1)
+                    for j in range(len(plan.num_configs))]
             per_shard_rows = sum(plan.num_configs) // n_shards * len(plans)
             surplus_total = (sum(plan.num_configs) - sum(pure)) * len(plans)
             per_shard_configs = [per_shard_rows] * n_shards
@@ -519,44 +356,23 @@ def run_sharded_fused_sweep(
             # stats dict, and — since the incumbent is this sweep's ONLY decision
             # payload — a sweep_incumbent audit record the replay harness can
             # re-score (per-rung decisions never left the device)
-            link = publish_sweep_transfers(link0)
+            link, decoded_dm = driver.finish()
             host_syncs = link["transfers_h2d"] + link["transfers_d2h"]
-            decoded_dm = None
-            if dm_parts:
-                # the metrics plane's host half: one decoded record per sweep —
-                # gauges for the scraper, a device_telemetry journal record for
-                # summarize/report and the anomaly rules (every rank publishes
-                # its own copy, like the incumbent record: SPMD values are
-                # identical on all ranks)
-                from hpbandster_tpu.obs.device_metrics import (
-                    decode_device_metrics,
-                    emit_device_telemetry,
-                    publish_device_metrics,
-                )
+            from hpbandster_tpu.obs.audit import emit_sweep_incumbent
 
-                decoded_dm = decode_device_metrics(
-                    dm_parts, execute_s=dm_execute_s
-                )
-                publish_device_metrics(decoded_dm)
-                emit_device_telemetry(decoded_dm)
-            if best is not None:
-                from hpbandster_tpu.obs.audit import emit_sweep_incumbent
-
-                emit_sweep_incumbent(
-                    vector=best["vector"],
-                    loss=best["loss"],
-                    bracket=best["bracket"],
-                    per_bracket_loss=per_bracket_all,
-                    evaluations=int(sum(sum(p.num_configs) for p in plans)),
-                    n_configs=int(n_configs),
-                    d2h_bytes=link["transfer_bytes_d2h"],
-                    h2d_bytes=link["transfer_bytes_h2d"],
-                    host_syncs=host_syncs,
-                )
+            emit_sweep_incumbent(
+                **best,  # vector, loss, bracket
+                per_bracket_loss=per_bracket_all,
+                evaluations=evaluations,
+                n_configs=int(n_configs),
+                d2h_bytes=link["transfer_bytes_d2h"],
+                h2d_bytes=link["transfer_bytes_h2d"],
+                host_syncs=host_syncs,
+            )
 
         return {
             "incumbent": best,
-            "evaluations": int(sum(sum(p.num_configs) for p in plans)),
+            "evaluations": evaluations,
             "requested_configs": int(n_configs),
             "aligned_stage_counts": list(plan.num_configs),
             "budgets": list(plan.budgets),
@@ -570,6 +386,9 @@ def run_sharded_fused_sweep(
             "alignment_surplus_rows": int(surplus_total),
             "balance_skew": 0.0 if skew is None else round(float(skew), 6),
             "chunks": chunks,
+            # the AOT-compiled program of the last chunk (``.as_text()``,
+            # ``.cost_analysis()``): FusedBOHB.last_executable's twin
+            "last_executable": driver.last_executable,
             "execute_fetch_s": round(
                 sum(c["execute_fetch_s"] for c in chunks), 4
             ),
@@ -584,13 +403,3 @@ def run_sharded_fused_sweep(
             # the call's seconds by span name (FusedBOHB.run's names)
             "phase_s": phase_s,
         }
-
-
-def _slice_shape(idx, shape) -> tuple:
-    """Concrete shape of the shard slice ``make_array_from_callback``
-    asks for — the per-shard allocation unit of the streamed uploads."""
-    out = []
-    for sl, n in zip(idx, shape):
-        start, stop, _ = sl.indices(n)
-        out.append(stop - start)
-    return tuple(out)

@@ -578,20 +578,32 @@ class TestASHAEndToEnd:
         assert not first_higher_before_last_low(rec_sync)
         assert first_higher_before_last_low(rec_asha)
 
-        # measured barrier stall: under sync EVERY rung-0 promotion
-        # waited ~ the straggler delay (the rung could not cut until the
-        # delayed result landed); under asha the first promotion wave
-        # fired the moment its quota opened — near-zero wait. (Later
-        # asha waves can legitimately wait: floor(n_done/eta) grows with
-        # completions, so the k-th promotion needs k*eta results — a
+        # the barrier stall, in results and not in seconds (a wall-clock
+        # bound on the waits read the load of a shared CPU): under sync no
+        # rung-0 promotion is decided while a rung-0 result is outstanding
+        # (the rung could not cut until the delayed result landed); under
+        # asha the first promotion wave fired the moment its quota opened,
+        # with rung-0 results (the straggler's among them) still to come.
+        # (Later asha waves can legitimately wait: floor(n_done/eta) grows
+        # with completions, so the k-th promotion needs k*eta results — a
         # quota, not a barrier.)
+        def rung0_results_after_first_decision(records):
+            first = next(
+                i for i, r in enumerate(records)
+                if r.get("event") == "promotion_decision" and r.get("rung") == 0
+            )
+            return sum(
+                r.get("event") == "job_finished" and "loss" in r
+                and r.get("budget") == 1.0
+                for r in records[first:]
+            )
+
+        assert rung0_results_after_first_decision(rec_sync) == 0
+        assert rung0_results_after_first_decision(rec_asha) >= 1
         waits_sync = promotion_waits(rec_sync)
         waits_asha = promotion_waits(rec_asha)
         assert waits_sync["max_wait_s"] is not None
-        assert waits_sync["max_wait_s"] > 0.25
-        first_asha = waits_asha["per_decision"][0]
-        assert first_asha["rung"] == 0
-        assert first_asha["mean_wait_s"] < 0.2
+        assert waits_asha["per_decision"][0]["rung"] == 0
         # worker utilization must not regress under async promotion
         util_sync = worker_utilization(rec_sync)["busy_fraction"]
         util_asha = worker_utilization(rec_asha)["busy_fraction"]

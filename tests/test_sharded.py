@@ -423,8 +423,10 @@ class TestStreamedWarmUpload:
         opt._warm_v[9.0] = np.arange(20, dtype=np.float32).reshape(10, 2)
         opt._warm_l[9.0] = np.linspace(0, 1, 10).astype(np.float32)
         caps = {1.0: 256, 9.0: 256}
-        args, bytes_up = opt._stream_warm_args(np.uint32(0), caps, 2)
-        seed, warm_v, warm_l, warm_n = args
+        from hpbandster_tpu.ops.sweep_driver import stream_warm_buffers
+
+        (warm_v, warm_l, warm_n), bytes_up = stream_warm_buffers(
+            opt._warm_v, opt._warm_l, caps, 2, mesh, "config")
         assert bytes_up == sum(c * 2 * 4 + c * 4 + 4 for c in caps.values())
         for b, cap in caps.items():
             assert warm_v[b].shape == (cap, 2)

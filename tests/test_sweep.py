@@ -959,13 +959,16 @@ class TestDynamicCountSweep:
         assert len(res.get_all_runs()) == sum(sum(p.num_configs) for p in plans)
         assert res.get_incumbent_id() is not None
 
-    def test_later_chunk_failure_keeps_previous_chunk_replayed(self):
+    def test_later_chunk_failure_keeps_previous_chunk_replayed(
+            self, monkeypatch):
         # the deferred replay must land even when the NEXT chunk dies
         # before dispatch (e.g. a bucket-doubling recompile failing):
         # otherwise a retry would re-execute a chunk whose observations
         # are already folded into the warm data
+        from hpbandster_tpu.ops.sweep_driver import SweepDriver
+
         opt = self._mk(seed=53)
-        orig = opt._sweep_compiled
+        orig = SweepDriver._compiled
         calls = {"n": 0}
 
         def failing(*a, **k):
@@ -974,13 +977,13 @@ class TestDynamicCountSweep:
                 raise RuntimeError("recompile OOM")
             return orig(*a, **k)
 
-        opt._sweep_compiled = failing
+        monkeypatch.setattr(SweepDriver, "_compiled", failing)
         with pytest.raises(RuntimeError, match="recompile OOM"):
             opt.run(n_iterations=9, chunk_brackets=3)
         # chunk 1's brackets were replayed before the error propagated
         assert len(opt.iterations) == 3
         # and a retry continues from bracket 3 with no duplicates
-        opt._sweep_compiled = orig
+        monkeypatch.setattr(SweepDriver, "_compiled", orig)
         res = opt.run(n_iterations=9, chunk_brackets=3)
         opt.shutdown()
         plans = hyperband_schedule(9, 1, 9, 3)

@@ -270,8 +270,10 @@ def test_incumbent_and_sharded_entries_use_the_same_names(mlp_ensemble):
         None, mlp_space(seed=2), stateful_eval=mlp_ensemble, n_configs=16,
         n_brackets=2, min_budget=1, max_budget=9, eta=3, seed=2,
         mesh=config_mesh(jax.devices()[:4]), resident=True)
+    # this ensemble's first sharded sweep: the program is built ahead of time
     assert {"run", "sweep_planning", "sweep_setup", "chunk_staging",
-            "compile_lookup", "dispatch", "fetch", "chunk_accounting",
+            "compile_lookup", "compile.trace_lower", "compile.compile",
+            "dispatch", "fetch", "chunk_accounting",
             "result"} == set(out["phase_s"])
 
 
@@ -376,7 +378,8 @@ def lowered_text(opt, n_iterations, dynamic, resident):
         args += ({b: np.zeros((c, d), np.float32) for b, c in caps.items()},
                  {b: np.full(c, np.inf, np.float32) for b, c in caps.items()},
                  {b: np.int32(0) for b in caps})
-    fn = opt._build_sweep_fn(plans, dynamic=dynamic, caps=caps, resident=resident)
+    fn = opt._sweep_driver(dynamic, resident=resident,
+                           device_metrics=False).build(plans, caps)
     return fn.lower(*args).as_text()
 
 
@@ -401,3 +404,85 @@ def test_scopes_are_metadata_only(monkeypatch, mlp_ensemble, case):
     assert lowered_text(opt, *shape) == with_scopes
     assert set(entered) <= set(DEVICE_SCOPES) and "hpb.sample" in entered
     assert "hpb." not in with_scopes
+
+
+# ------------------------------------------- one driver, three entry points
+def own_objective():
+    """An objective of a new identity: its programs are in no cache."""
+    return lambda v, b: branin_from_vector(v, b)  # noqa: E731
+
+
+def entry_call(entry, eval_fn, mesh):
+    """One bracket of 9, 3, 1 through an entry point, dynamic counts on:
+    ``(chunk rows, phase_s)``."""
+    from hpbandster_tpu.parallel.multihost import run_sharded_fused_sweep
+
+    if entry == "sharded":
+        out = run_sharded_fused_sweep(
+            eval_fn, branin_space(seed=3), n_configs=9, n_brackets=1,
+            chunk_brackets=1, model=True, min_budget=1, max_budget=9, eta=3,
+            mesh=mesh, seed=3)
+        assert out["aligned_stage_counts"] == [9, 3, 1]
+        return out["chunks"], out["phase_s"]
+    opt = FusedBOHB(
+        configspace=branin_space(seed=3), eval_fn=eval_fn, run_id="entries",
+        min_budget=1, max_budget=9, eta=3, seed=3, mesh=mesh)
+    if entry == "incumbent":
+        out = opt.run_incumbent(n_iterations=1, resident=False)
+        return [out], out["phase_s"]
+    opt.run(n_iterations=1, dynamic_counts=True)
+    return opt.run_stats, opt.run_stats[-1]["phase_s"]
+
+
+@pytest.mark.parametrize("entry", ["run", "incumbent", "sharded"])
+def test_every_entry_point_compiles_ahead_once(entry):
+    from hpbandster_tpu.obs.runtime import get_compile_tracker
+    from hpbandster_tpu.parallel import config_mesh
+
+    eval_fn, mesh = own_objective(), config_mesh(jax.devices()[:1])
+    compiles = lambda: get_compile_tracker().snapshot()["total_compiles"]  # noqa: E731
+    before = compiles()
+    rows, phase_s = entry_call(entry, eval_fn, mesh)
+    assert [r["compile_cache_hit"] for r in rows] == [False]
+    assert rows[0]["build_compile_s"] > 0
+    assert {"compile.trace_lower", "compile.compile"} <= set(phase_s)
+    assert phase_s["compile.trace_lower"] + phase_s["compile.compile"] <= (
+        phase_s["compile_lookup"])
+    assert compiles() == before + 1
+    # the same call again: the executable is found, nothing is built
+    rows, phase_s = entry_call(entry, eval_fn, mesh)
+    assert [r["compile_cache_hit"] for r in rows] == [True]
+    assert rows[0]["build_compile_s"] == 0.0
+    assert not {"compile.trace_lower", "compile.compile"} & set(phase_s)
+    assert compiles() == before + 1
+
+
+def test_entry_points_share_one_cache_and_never_an_entry():
+    from hpbandster_tpu.optimizers.fused_bohb import _SWEEP_EXE_CACHE
+    from hpbandster_tpu.parallel import config_mesh, multihost
+
+    assert not hasattr(multihost, "_SHARDED_FN_CACHE")
+    eval_fn, mesh = own_objective(), config_mesh(jax.devices()[:1])
+    _SWEEP_EXE_CACHE.clear()
+    for n, entry in enumerate(["sharded", "incumbent", "run"], start=1):
+        # one objective, one space, one mesh, one bracket, one set of
+        # capacities: what keys them apart is the entry point's own mode
+        rows, _ = entry_call(entry, eval_fn, mesh)
+        assert not rows[0]["compile_cache_hit"]
+        assert len(_SWEEP_EXE_CACHE) == n
+
+
+def test_sharded_entry_is_in_the_phase_maps(compiled_here):
+    from hpbandster_tpu.optimizers.fused_bohb import _SWEEP_EXE_CACHE
+    from hpbandster_tpu.parallel import config_mesh
+    from hpbandster_tpu.parallel.multihost import run_sharded_fused_sweep
+
+    _SWEEP_EXE_CACHE.clear()
+    out = run_sharded_fused_sweep(
+        own_objective(), branin_space(seed=0), n_configs=256,
+        mesh=config_mesh(jax.devices()), seed=3)
+    maps = sweep_phase_maps()
+    assert list(maps) == [hlo_module_name(out["last_executable"])]
+    (phases,) = maps.values()
+    assert phases == device_phase_map(out["last_executable"])
+    assert {"hpb.train", "hpb.promote"} <= set(phases.values())
