@@ -42,6 +42,14 @@ __all__: List[str] = []
 #: compile-count acceptance is per PROCESS, not per call).
 _SWEEP_EXE_CACHE: LRUCache = LRUCache(maxsize=16)
 
+#: objectives that have passed ``FusedBOHB.__init__``'s admission check,
+#: keyed on the three things the check reads: ``(evaluation object, space
+#: dimension, lowest budget)``. The check is a whole Python trace of the
+#: objective, and its verdict on one object cannot change from one
+#: construction to the next. Bounded as the executable cache, which holds
+#: the same objects strongly in its keys.
+_ADMITTED: LRUCache = LRUCache(maxsize=_SWEEP_EXE_CACHE.maxsize)
+
 #: program options the key cannot hash; the caller's ``space_sig`` stands
 #: for them
 _KEYED_BY_SPACE_SIG = ("active_mask_fn", "forbidden_fn", "fallback_vector")
@@ -60,6 +68,22 @@ def _note_device_refits(decoded: Dict[str, Any]) -> None:
         and obs.get_bus().active
     ):
         obs.emit(obs.KDE_REFIT, source="device", fits=int(fits))
+
+
+def check_once(key: Tuple[Any, int, float], check: Callable[[], None]) -> bool:
+    """Run ``check()`` unless ``key`` has passed it before; True where it
+    ran. Only a check that returned is remembered: one that raised raises
+    again at the next call. A key that cannot be hashed is checked every
+    time."""
+    try:
+        if _ADMITTED.get(key):
+            return False
+    except TypeError:
+        check()
+        return True
+    check()
+    _ADMITTED[key] = True
+    return True
 
 
 def stream_warm_buffers(warm_v, warm_l, caps, d, mesh, axis,

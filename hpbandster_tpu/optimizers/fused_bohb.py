@@ -39,7 +39,11 @@ from hpbandster_tpu.ops.bracket import (
     max_sh_iterations,
 )
 from hpbandster_tpu.ops.sweep import build_space_codec
-from hpbandster_tpu.ops.sweep_driver import _SWEEP_EXE_CACHE, SweepDriver
+from hpbandster_tpu.ops.sweep_driver import (
+    _SWEEP_EXE_CACHE,
+    SweepDriver,
+    check_once,
+)
 from hpbandster_tpu.space import ConfigurationSpace
 
 __all__ = ["FusedBOHB", "FusedHyperBand", "FusedRandomSearch", "FusedH2BO",
@@ -155,6 +159,71 @@ class _ReplayIteration(SuccessiveHalving):
                 obs.emit_config_sampled(config_id, budget, info)
 
 
+def _check_objective(eval_fn, stateful_eval, d: int, min_budget: float) -> None:
+    """The constructor's admission check: raise ``ValueError`` for an
+    objective that the sweep cannot run, where the first ``run()`` would
+    die with an opaque XLA broadcasting error from deep inside the sweep
+    trace. ``jax.eval_shape`` is abstract: no backend or device work, but
+    a whole Python trace of the objective on the host (55-61 ms for the
+    ``mlp-sgd`` ensemble's step, seconds for a large lane), which is why
+    ``FusedBOHB.__init__`` pays it once an object and not once a
+    construction. The budget is passed CONCRETE exactly as the sweep does,
+    so Python-level loops over epochs inside eval_fn stay legal;
+    min_budget keeps any such unrolling as small as possible."""
+    import jax as _jax
+    import jax.numpy as _jnp
+
+    if stateful_eval is not None:
+        # same fail-fast contract for the stateful seam: a 2-lane
+        # abstract init->step round-trip surfaces protocol bugs
+        # (wrong arity, non-batched losses) before the sweep trace
+        # buries them in an opaque XLA error
+        try:
+            _, losses_sds = _jax.eval_shape(
+                lambda v: stateful_eval.step_fn(
+                    stateful_eval.init_fn(v), v, min_budget, 0.0
+                ),
+                _jax.ShapeDtypeStruct((2, d), _jnp.float32),
+            )
+        except Exception as e:
+            raise ValueError(
+                f"stateful_eval failed under abstract evaluation "
+                f"(init_fn + step_fn over f32[2, {d}] vectors): "
+                f"{type(e).__name__}: {e}"
+            ) from e
+        if tuple(getattr(losses_sds, "shape", ())) != (2,):
+            raise ValueError(
+                "stateful_eval.step_fn must return per-lane losses "
+                f"f32[n], got shape {getattr(losses_sds, 'shape', None)}"
+            )
+        return
+    try:
+        out_sds = _jax.eval_shape(
+            lambda v: eval_fn(v, min_budget),
+            _jax.ShapeDtypeStruct((d,), _jnp.float32),
+        )
+    except Exception as e:
+        # deliberately broad: eval_shape surfaces plain bugs inside
+        # eval_fn (wrong arity, NameError) as well as tracing errors,
+        # so the banner says what was ATTEMPTED, not what went wrong —
+        # the chained original exception carries the real diagnosis
+        # (ADVICE r4)
+        raise ValueError(
+            f"eval_fn(config_vector f32[{d}], budget) failed under "
+            f"abstract evaluation (jax.eval_shape) for this {d}-dim "
+            f"space: {type(e).__name__}: {e}"
+        ) from e
+    leaves = _jax.tree_util.tree_leaves(out_sds)
+    shapes = [tuple(getattr(l, "shape", ())) for l in leaves]
+    if len(leaves) != 1 or shapes[0] != ():
+        raise ValueError(
+            "eval_fn must return a single SCALAR loss, got "
+            f"{len(leaves)} output leaves with shapes {shapes} — "
+            "reduce per-example losses (e.g. .mean()) and drop aux "
+            "outputs before returning"
+        )
+
+
 class FusedBOHB:
     def __init__(
         self,
@@ -180,6 +249,22 @@ class FusedBOHB:
         use_pallas: Optional[bool] = None,
         stateful_eval=None,
     ):
+        """One evaluation seam: a jittable ``eval_fn(config_vector, budget)
+        -> scalar loss`` or a ``StatefulEval``; the other arguments are
+        :class:`~hpbandster_tpu.optimizers.bohb.BOHB`'s.
+
+        The objective is checked here under ``jax.eval_shape``
+        (``_check_objective``: a ``ValueError`` for a loss of the wrong
+        shape or an objective that cannot be traced), once an evaluation
+        object, space dimension and ``min_budget``, not once a
+        construction: the check is a whole Python trace of the objective
+        on the host. So an object that is MUTATED IN PLACE after it passed
+        (a closure's captured state swapped for one that returns another
+        shape) is not checked again; the executable cache assumes the same
+        of the same object, and the sweep's own trace still fails on it,
+        only less readably. An ``eval_fn`` that states ``lane_facts`` is
+        not traced at all, and one that cannot be hashed every time.
+        """
         if configspace is None:
             raise ValueError("you have to provide a valid ConfigurationSpace object")
         if eval_fn is None and stateful_eval is None:
@@ -239,72 +324,31 @@ class FusedBOHB:
                 self.forbidden_fn = None
                 self._fallback_vector = None
                 self._forbiddens_sig = ()
-            # fail fast on a non-scalar objective: without this check the first
-            # run() dies with an opaque XLA broadcasting error from deep inside
-            # the sweep trace. jax.eval_shape is abstract (no backend or device
-            # work); the budget is passed CONCRETE exactly as the sweep does,
-            # so Python-level loops over epochs inside eval_fn stay legal —
-            # min_budget keeps any such unrolling as small as possible.
-            import jax as _jax
-            import jax.numpy as _jnp
-
+            # fail fast on an objective of the wrong shape (_check_objective),
+            # the first time this process meets the object: the check is a
+            # whole Python trace of it, and its verdict can only be what it
+            # was the construction before
             with sweep_span("construct.eval_shape", ADMISSION,
                             self._phase_carry):
                 d = int(self.codec.kind.shape[0])
-                if stateful_eval is not None:
-                    # same fail-fast contract for the stateful seam: a 2-lane
-                    # abstract init->step round-trip surfaces protocol bugs
-                    # (wrong arity, non-batched losses) before the sweep trace
-                    # buries them in an opaque XLA error
-                    try:
-                        _, losses_sds = _jax.eval_shape(
-                            lambda v: stateful_eval.step_fn(
-                                stateful_eval.init_fn(v), v, float(min_budget), 0.0
-                            ),
-                            _jax.ShapeDtypeStruct((2, d), _jnp.float32),
-                        )
-                    except Exception as e:
-                        raise ValueError(
-                            f"stateful_eval failed under abstract evaluation "
-                            f"(init_fn + step_fn over f32[2, {d}] vectors): "
-                            f"{type(e).__name__}: {e}"
-                        ) from e
-                    if tuple(getattr(losses_sds, "shape", ())) != (2,):
-                        raise ValueError(
-                            "stateful_eval.step_fn must return per-lane losses "
-                            f"f32[n], got shape {getattr(losses_sds, 'shape', None)}"
-                        )
-                elif getattr(eval_fn, "lane_facts", None) is not None:
+                if getattr(eval_fn, "lane_facts", None) is not None:
                     # a maker that states its lane's facts (ops.fused.LaneFacts)
                     # has stated a scalar loss with them: a lane that large
-                    # takes seconds of host time to trace, every construction
-                    pass
+                    # takes seconds of host time to trace, and a process that
+                    # paid them once would still pay them in its set-up
+                    traced = False
                 else:
-                    try:
-                        out_sds = _jax.eval_shape(
-                            lambda v: eval_fn(v, float(min_budget)),
-                            _jax.ShapeDtypeStruct((d,), _jnp.float32),
-                        )
-                    except Exception as e:
-                        # deliberately broad: eval_shape surfaces plain bugs inside
-                        # eval_fn (wrong arity, NameError) as well as tracing errors,
-                        # so the banner says what was ATTEMPTED, not what went wrong —
-                        # the chained original exception carries the real diagnosis
-                        # (ADVICE r4)
-                        raise ValueError(
-                            f"eval_fn(config_vector f32[{d}], budget) failed under "
-                            f"abstract evaluation (jax.eval_shape) for this {d}-dim "
-                            f"space: {type(e).__name__}: {e}"
-                        ) from e
-                    leaves = _jax.tree_util.tree_leaves(out_sds)
-                    shapes = [tuple(getattr(l, "shape", ())) for l in leaves]
-                    if len(leaves) != 1 or shapes[0] != ():
-                        raise ValueError(
-                            "eval_fn must return a single SCALAR loss, got "
-                            f"{len(leaves)} output leaves with shapes {shapes} — "
-                            "reduce per-example losses (e.g. .mean()) and drop aux "
-                            "outputs before returning"
-                        )
+                    lowest = float(min_budget)
+                    traced = check_once(
+                        (stateful_eval if eval_fn is None else eval_fn,
+                         d, lowest),
+                        lambda: _check_objective(
+                            eval_fn, stateful_eval, d, lowest),
+                    )
+                #: 1 where this constructor traced its objective, 0 where
+                #: the memo (or ``lane_facts``) answered: the first
+                #: ``run_stats`` row takes it as its ``construct_traced``
+                self._construct_traced = int(traced)
             self.eval_fn = eval_fn
             self.stateful_eval = stateful_eval
             self.run_id = run_id
@@ -661,6 +705,7 @@ class FusedBOHB:
             # this chunk's phase seconds, its row's ``phase_s``: the first
             # row takes what construction and set-up carried
             phase_s, self._phase_carry = self._phase_carry, {}
+            construct_traced, self._construct_traced = self._construct_traced, 0
             seed = np.uint32(self.rng.integers(2**32, dtype=np.uint32))
             overlap_s = None
             try:
@@ -717,6 +762,9 @@ class FusedBOHB:
                     # first-rung configurations that from_vectors decoded a
                     # column at a time; the rest went through from_vector
                     "replay_configs_by_column": 0,
+                    # 1 on the first row of an optimizer whose constructor
+                    # traced its objective for the admission check
+                    "construct_traced": construct_traced,
                     # seconds per span name, this chunk's share of the
                     # sweep's wall; the spans that close after this point
                     # (this one, obs_fold, the chunk's bracket_replay

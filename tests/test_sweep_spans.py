@@ -156,9 +156,15 @@ def test_spans_cost_no_journal_and_no_profiler_to_measure():
 
 # ---------------------------------------------------------------- phase_s
 @pytest.mark.parametrize("resident", [False, True])
-def test_phase_s_has_every_phase_and_sums_to_the_wall(mlp_ensemble, resident):
-    # the trainer's sweep, not Branin's: tenths of a second, so that the
-    # interpreter's own microseconds between two spans are no share of it
+def test_phase_s_has_every_phase_and_sums_to_the_wall(resident):
+    # the trainer's sweep, not Branin's, and at a width that keeps the CPU
+    # busy for tenths of a second, so that the interpreter's own
+    # microseconds between two spans are no share of it (a second
+    # constructor around one object no longer traces it: the wall of the
+    # module's 8-wide ensemble is 7 ms, 0.4 ms of it between spans)
+    mlp_ensemble = make_mlp_ensemble(
+        MLPConfig(d_in=128, width=256, n_classes=4, n_train=512, n_val=512,
+                  batch_size=128), data_seed=0)
     mlp_opt(mlp_ensemble).run(n_iterations=3, resident=resident)  # warm
     space = mlp_space(seed=5)
     t0 = time.perf_counter()
@@ -486,3 +492,183 @@ def test_sharded_entry_is_in_the_phase_maps(compiled_here):
     (phases,) = maps.values()
     assert phases == device_phase_map(out["last_executable"])
     assert {"hpb.train", "hpb.promote"} <= set(phases.values())
+
+
+# ------------------------------- the admission check, once an object (ISSUE 31)
+def counted_objective(kind, loss="lanes"):
+    """``(constructor keyword, Python calls)`` of a new evaluation object
+    that takes a vector of any dimension: ``calls`` gains an entry whenever
+    Python runs the objective, which after construction means a trace."""
+    from hpbandster_tpu.ops.fused import LaneFacts, StatefulEval
+
+    calls = []
+    if kind == "stateful":
+        def step_fn(state, vectors, budget, prev_budget):
+            calls.append(budget)
+            losses = (vectors ** 2).sum(-1) + state["p"]
+            return state, losses if loss == "lanes" else losses.sum()
+
+        return {"stateful_eval": StatefulEval(
+            init_fn=lambda v: {"p": jax.numpy.zeros(v.shape[0])},
+            step_fn=step_fn)}, calls
+
+    def eval_fn(vector, budget):
+        calls.append(budget)
+        return (vector ** 2).sum() / budget
+
+    if kind == "lane_facts":
+        eval_fn.lane_facts = LaneFacts(bytes=1024)
+    return {"eval_fn": eval_fn}, calls
+
+
+def flat_space(d, seed=3):
+    from hpbandster_tpu.space import (
+        ConfigurationSpace,
+        UniformFloatHyperparameter,
+    )
+
+    cs = ConfigurationSpace(seed=seed)
+    for i in range(d):
+        cs.add_hyperparameter(UniformFloatHyperparameter("x%d" % i, 0.0, 1.0))
+    return cs
+
+
+def construct(objective, d=2, min_budget=1, **kwargs):
+    return FusedBOHB(configspace=flat_space(d), run_id="admit",
+                     min_budget=min_budget, max_budget=9, eta=3, seed=3,
+                     **objective, **kwargs)
+
+
+@pytest.mark.parametrize("kind", ["stateful", "stateless"])
+def test_a_second_constructor_does_not_trace_the_objective(kind):
+    objective, calls = counted_objective(kind)
+    first, second = construct(objective), construct(objective)
+    assert calls == [1.0], "one trace, at the lowest budget, by the first"
+    assert (first._construct_traced, second._construct_traced) == (1, 0)
+    # the span opens at every construction; on a hit it holds a lookup
+    for opt in (first, second):
+        assert {"construct", "construct.eval_shape"} == set(opt._phase_carry)
+
+
+@pytest.mark.parametrize("kind", ["stateful", "stateless"])
+@pytest.mark.parametrize("other", [{"d": 3}, {"min_budget": 3}],
+                         ids=["dimension", "min_budget"])
+def test_another_dimension_or_lowest_budget_traces_again(kind, other):
+    """The verdict is remembered under the three things the check reads."""
+    objective, calls = counted_objective(kind)
+    construct(objective)
+    assert construct(objective, **other)._construct_traced == 1
+    assert calls == [1.0, float(other.get("min_budget", 1))]
+    for _ in range(2):
+        assert construct(objective)._construct_traced == 0
+        assert construct(objective, **other)._construct_traced == 0
+    assert len(calls) == 2
+
+
+def untraceable(vector, budget):
+    return float(vector[0])  # concretizes a tracer
+
+
+FAULTY = {
+    "non-scalar": ({"eval_fn": lambda v, b: v},
+                   "eval_fn must return a single SCALAR loss, got 1 output "
+                   "leaves with shapes [(2,)] — reduce per-example losses "
+                   "(e.g. .mean()) and drop aux outputs before returning"),
+    "pytree": ({"eval_fn": lambda v, b: (v.sum(), {"aux": v})},
+               "eval_fn must return a single SCALAR loss, got 2 output "
+               "leaves with shapes [(), (2,)] — reduce per-example losses "
+               "(e.g. .mean()) and drop aux outputs before returning"),
+    "untraceable": ({"eval_fn": untraceable},
+                    "eval_fn(config_vector f32[2], budget) failed under "
+                    "abstract evaluation (jax.eval_shape) for this 2-dim "
+                    "space: ConcretizationTypeError: "),
+    "stateful-scalar": (counted_objective("stateful", loss="scalar")[0],
+                        "stateful_eval.step_fn must return per-lane losses "
+                        "f32[n], got shape ()"),
+    "stateful-untraceable": (
+        {"stateful_eval": counted_objective("stateful")[0]["stateful_eval"]
+         ._replace(init_fn=lambda v: {"p": float(v[0, 0])})},
+        "stateful_eval failed under abstract evaluation (init_fn + step_fn "
+        "over f32[2, 2] vectors): ConcretizationTypeError: "),
+}
+
+
+@pytest.mark.parametrize("fault", sorted(FAULTY))
+def test_a_faulty_objective_raises_the_same_words_every_time(fault):
+    """A check that raised is never remembered."""
+    from hpbandster_tpu.ops.sweep_driver import _ADMITTED
+
+    objective, words = FAULTY[fault]
+    (obj,) = objective.values()
+    said = []
+    for _ in range(3):
+        with pytest.raises(ValueError) as raised:
+            construct(objective)
+        said.append(str(raised.value))
+    assert said[0].startswith(words) and said[1:] == said[:1] * 2
+    assert (obj, 2, 1.0) not in _ADMITTED
+
+
+@pytest.mark.parametrize("kind", ["stateful", "stateless"])
+def test_construct_traced_reads_one_then_zero(kind, tmp_path):
+    """On the rows of two optimizers in turn around one object, and in the
+    sidecar they share; only the first row of an optimizer can read 1."""
+    from hpbandster_tpu.core.result import json_result_logger
+
+    objective, calls = counted_objective(kind)
+    logger, rows = json_result_logger(str(tmp_path), overwrite=True), []
+    for _ in range(2):
+        opt = construct(objective, result_logger=logger)
+        opt.run(n_iterations=2, chunk_brackets=1)
+        rows += opt.run_stats
+    assert [r["construct_traced"] for r in rows] == [1, 0, 0, 0]
+    assert [r["chunk_index"] for r in rows] == [0, 1, 0, 1]
+    assert calls[0] == 1.0 and calls.count(1.0) == 2, (
+        "the constructor's trace and the sweep program's, of one executable")
+    with open(tmp_path / "fused_timings.json") as fh:
+        assert [r["construct_traced"] for r in json.load(fh)] == [1, 0, 0, 0]
+    # a later call on an optimizer carries nothing from its constructor
+    opt.run(n_iterations=3, chunk_brackets=1)
+    assert opt.run_stats[-1]["construct_traced"] == 0
+
+
+def test_the_memo_is_bounded_as_the_executable_cache():
+    from hpbandster_tpu.ops.sweep_driver import _ADMITTED, _SWEEP_EXE_CACHE
+
+    assert _ADMITTED.maxsize == _SWEEP_EXE_CACHE.maxsize == 16
+    oldest, calls = counted_objective("stateless")
+    construct(oldest)
+    for _ in range(_ADMITTED.maxsize):
+        assert construct(counted_objective("stateless")[0])._construct_traced
+        assert len(_ADMITTED) <= _SWEEP_EXE_CACHE.maxsize
+    # sixteen later objects pushed the first out: it is checked again
+    assert construct(oldest)._construct_traced == 1 and len(calls) == 2
+
+
+def test_an_objective_with_lane_facts_is_never_traced_and_leaves_no_entry():
+    from hpbandster_tpu.ops.sweep_driver import _ADMITTED
+
+    objective, calls = counted_objective("lane_facts")
+    before = len(_ADMITTED)
+    assert [construct(objective)._construct_traced for _ in range(2)] == [0, 0]
+    assert calls == [] and len(_ADMITTED) == before
+    assert (objective["eval_fn"], 2, 1.0) not in _ADMITTED
+
+
+def test_an_objective_that_cannot_be_hashed_is_checked_every_time():
+    from hpbandster_tpu.ops.sweep_driver import _ADMITTED
+
+    class Unhashable:
+        calls = 0
+        __hash__ = None
+
+        def __call__(self, vector, budget):
+            type(self).calls += 1
+            return vector.sum()
+
+    eval_fn, before = Unhashable(), len(_ADMITTED)
+    with pytest.raises(TypeError):
+        hash(eval_fn)
+    assert [construct({"eval_fn": eval_fn})._construct_traced
+            for _ in range(3)] == [1, 1, 1]
+    assert Unhashable.calls == 3 and len(_ADMITTED) == before
