@@ -128,14 +128,16 @@ DEVICE_SCOPES = (
 
 #: a second closed list, of the parts of one lane: ``jax.named_scope``
 #: names that a workload whose lane has layers of several kinds
-#: (``workloads/kimi_linear.py``) sets *inside* ``hpb.train`` and
+#: (``workloads/kimi_linear.py``, ``workloads/mellum2.py``) sets *inside* ``hpb.train`` and
 #: ``hpb.validate``. The two families do not see each other:
 #: ``device_phase_map(compiled)`` reads the phases above,
 #: ``device_phase_map(compiled, LANE_SCOPES)`` these
 LANE_SCOPES = (
     "lane.kda",        # gated delta-rule linear attention, its projections
     "lane.mla",        # latent attention, its projections
-    "lane.moe",        # router, held experts, shared expert
+    "lane.swa",        # sliding-window attention: projections, rotary, the band
+    "lane.gqa",        # full causal grouped-query attention, the same
+    "lane.moe",        # router, held experts, a shared expert where there is one
     "lane.dense_ffn",  # a dense feed-forward layer
     "lane.head",       # embedding, final norm, head, loss
     "lane.update",     # the optimizer's step

@@ -71,3 +71,8 @@ from hpbandster_tpu.workloads.kimi_linear import (  # noqa: F401
     kimi_linear_space,
     make_kimi_linear_eval_fn,
 )
+from hpbandster_tpu.workloads.mellum2 import (  # noqa: F401
+    Mellum2Config,
+    make_mellum2_eval_fn,
+    mellum2_space,
+)

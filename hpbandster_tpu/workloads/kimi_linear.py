@@ -27,7 +27,10 @@ restarts from the key at the next budget. Parameters, momentum and
 gradients are float32; matmul operands bfloat16 with float32 accumulation
 (``workloads/transformer.py``'s rule); the KDA state and gates, softmax,
 router scores, norms and the loss float32. Each layer's activations are
-recomputed in the backward pass (``jax.checkpoint``).
+recomputed in the backward pass (``jax.checkpoint``). The search space, the
+rule for a product's operands, the expert layer, embedding and head, the
+tokens and the trainer are every lane's (``workloads/lane.py``); the mixers,
+the configuration and the footprint are this file's.
 
 Departures from the published description, all ``assumed`` in
 ``benchmark/configs/kimi-linear-sgd.json`` too:
@@ -51,15 +54,23 @@ Departures from the published description, all ``assumed`` in
 
 from __future__ import annotations
 
-import zlib
 from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from hpbandster_tpu.ops.fused import LaneFacts
-from hpbandster_tpu.space import ConfigurationSpace, UniformFloatHyperparameter
+from hpbandster_tpu.workloads import lane
+from hpbandster_tpu.workloads.lane import (  # noqa: F401 - the lane's public names
+    LANE_COUNTERS,
+    _FLOAT32,
+    _einsum,
+    _mm,
+    _mm_beside,
+    _rms,
+    _swiglu,
+    make_token_dataset,
+)
 
 __all__ = [
     "KimiLinearConfig",
@@ -75,12 +86,6 @@ __all__ = [
     "kimi_linear_lane_bytes",
     "make_kimi_linear_eval_fn",
 ]
-
-#: what an evaluation counts on the device beside its loss, over the
-#: expert layers of its validation pass: the share of token-choices that
-#: fell on held experts (8/256 if routing is even) and the fullest held
-#: expert's load over the mean held load
-LANE_COUNTERS = ("moe_held_choice_share", "moe_load_max_over_mean")
 
 
 class KimiLinearConfig(NamedTuple):
@@ -121,28 +126,9 @@ class KimiLinearConfig(NamedTuple):
     mla_query_blocks: int = 4
 
 
-def kimi_linear_space(seed=None) -> ConfigurationSpace:
-    """lr (log), momentum, weight decay (log), init scale (log): the
-    ``mlp_space`` axes and ranges."""
-    cs = ConfigurationSpace(seed=seed)
-    cs.add_hyperparameter(UniformFloatHyperparameter("lr", 1e-4, 1.0, log=True))
-    cs.add_hyperparameter(UniformFloatHyperparameter("momentum", 0.0, 0.99))
-    cs.add_hyperparameter(
-        UniformFloatHyperparameter("weight_decay", 1e-7, 1e-2, log=True)
-    )
-    cs.add_hyperparameter(
-        UniformFloatHyperparameter("init_scale", 0.1, 10.0, log=True)
-    )
-    return cs
-
-
-def decode_kimi_linear_hparams(vec: jax.Array):
-    """Unit-cube vector -> (lr, momentum, weight_decay, init_scale)."""
-    lr = 10.0 ** (-4.0 + 4.0 * vec[0])
-    momentum = 0.99 * vec[1]
-    wd = 10.0 ** (-7.0 + 5.0 * vec[2])
-    init_scale = 10.0 ** (-1.0 + 2.0 * vec[3])
-    return lr, momentum, wd, init_scale
+#: lr (log), momentum, weight decay (log), init scale (log): every lane's
+kimi_linear_space = lane.lane_space
+decode_kimi_linear_hparams = lane.decode_lane_hparams
 
 
 # ------------------------------------------------------------- parameters
@@ -183,78 +169,27 @@ def _layer_shapes(cfg: KimiLinearConfig, mixer: str, ffn: str) -> dict:
 
 
 def _init_leaf(key, name: str, shape, init_scale):
-    """One leaf from the key and its own name, so that the draw does not
-    depend on which other leaves exist. Matrices (and the depthwise
-    convolutions) are ``init_scale / sqrt(fan_in) * N(0, 1)``, the
-    embedding ``init_scale * N(0, 1)`` (a lookup's fan-in is one: a
-    smaller embedding only has the first norm multiply its gradient up),
-    norm weights one, the balancing bias zero."""
+    """The lane's draw of a leaf (``lane._init_leaf``), and KDA's two
+    leaves that are not drawn: ``A_log`` the log of 1..16 over the heads,
+    ``dt_bias`` the inverse softplus of 0.001..0.1 over the channels."""
     leaf = name.rsplit("/", 1)[-1]
-    if leaf.startswith("norm") or leaf in ("kv_norm", "o_norm"):
-        return jnp.ones(shape, jnp.float32)
-    if leaf == "router_bias":
-        return jnp.zeros(shape, jnp.float32)
     if leaf == "A_log":
         return jnp.log(jnp.linspace(1.0, 16.0, shape[0], dtype=jnp.float32))
     if leaf == "dt_bias":
         dt = jnp.exp(jnp.linspace(
             np.log(0.001), np.log(0.1), shape[0], dtype=jnp.float32))
         return dt + jnp.log(-jnp.expm1(-dt))  # softplus^-1
-    # drawn as a matrix and folded: the same numbers in the same order (the
-    # chip's compiler takes fourteen seconds over a three-dimensional draw)
-    draw = jax.random.normal(
-        jax.random.fold_in(key, zlib.crc32(name.encode()) & 0x7FFFFFFF),
-        (int(np.prod(shape[:-1])), shape[-1]), jnp.float32).reshape(shape)
-    fan_in = 1 if leaf == "embed" else shape[-2]
-    return (init_scale * fan_in ** -0.5) * draw
+    return lane._init_leaf(key, name, shape, init_scale)
 
 
 def init_kimi_linear_params(key: jax.Array, cfg: KimiLinearConfig,
                             init_scale) -> dict:
-    shapes = {
-        "embed": (cfg.vocab_rows, cfg.hidden_size),
-        "norm_f": (cfg.hidden_size,),
-        "head": (cfg.hidden_size, cfg.vocab_rows),
-    }
-    params = {n: _init_leaf(key, n, s, init_scale) for n, s in shapes.items()}
-    for i, (mixer, ffn) in enumerate(cfg.layer_kinds):
-        params[f"l{i}"] = {
-            n: _init_leaf(key, f"l{i}/{n}", s, init_scale)
-            for n, s in _layer_shapes(cfg, mixer, ffn).items()
-        }
-    return params
+    return lane._init_params(
+        key, cfg, [_layer_shapes(cfg, *kind) for kind in cfg.layer_kinds],
+        init_scale, _init_leaf)
 
 
 # ----------------------------------------------------------------- layers
-#: what every matrix product's operands are cast to; the accumulation is
-#: float32 (``workloads/transformer.py``'s ``_mm``). The tests set float32
-#: here to hold the equations to the reference without rounding in the way.
-_OPERAND = jnp.bfloat16
-
-
-#: the few products whose operands stay float32 (the router's, whose top 8
-#: is a discrete choice, and KDA's blocks under the diagonal, which feed a
-#: triangular solve): three bfloat16 passes, 2^-16 of a product. The six
-#: passes of ``HIGHEST`` take the chip's compiler four seconds a product
-#: and there are some sixty of them in a lane.
-_FLOAT32 = jax.lax.Precision.HIGH
-
-
-def _mm(a, b):
-    return jnp.matmul(
-        a.astype(_OPERAND), b.astype(_OPERAND), preferred_element_type=jnp.float32)
-
-
-def _einsum(spec, a, b):
-    return jnp.einsum(
-        spec, a.astype(_OPERAND), b.astype(_OPERAND),
-        preferred_element_type=jnp.float32)
-
-
-def _rms(x, w, eps):
-    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * w
-
-
 def _l2norm(x):
     return x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + 1e-6)
 
@@ -369,15 +304,6 @@ def kda_chunked(q, k, v, log_a, beta, chunk: int, sub: int = None):
     return out.swapaxes(1, 2).reshape((t + pad, h, dv))[:t]
 
 
-def _mm_beside(x, *weights):
-    """``x @ w`` for several ``w`` as ONE product, the weights side by
-    side, and the columns handed back apart: the same sums, and one
-    product for the compiler (half a second each on the chip's) and for
-    the chip in place of several."""
-    out = _mm(x, jnp.concatenate([w.astype(_OPERAND) for w in weights], axis=1))
-    return jnp.split(out, np.cumsum([w.shape[1] for w in weights])[:-1], axis=1)
-
-
 def _kda(x, p, cfg: KimiLinearConfig):
     t = x.shape[0]
     h, dk = cfg.num_heads, cfg.kda_head_dim
@@ -431,89 +357,18 @@ def _mla(x, p, cfg: KimiLinearConfig):
     return _mm(out.swapaxes(0, 1).reshape(t, h * dv), p["wo"])
 
 
-def _swiglu(x, w_gate, w_up, w_down):
-    gate, up = _mm_beside(x, w_gate, w_up)
-    return _mm(jax.nn.silu(gate) * up, w_down)
+def _experts(cfg: KimiLinearConfig) -> lane.ExpertLayer:
+    return lane.ExpertLayer(
+        outputs=cfg.num_experts, top_k=cfg.num_experts_per_token,
+        held=cfg.experts_held, width=cfg.moe_intermediate_size,
+        score="sigmoid", scaling=cfg.routed_scaling_factor)
 
 
 def moe_held_experts(x, p, cfg: KimiLinearConfig):
-    """This chip's part of the expert layer: the shared expert once, plus
-    ``w_e * E_e(x)`` for each chosen expert it holds. Returns ``(y f32[T,
-    D], counters f32[2])``, the counters being (token-choices on held
-    experts, fullest held expert's load over the mean held load).
-
-    The router scores all ``num_experts``, chooses the top 8 of ``s + b``
-    and weighs them ``s_e / sum(chosen s) * routed_scaling_factor``.
-    Token-choices are sorted by held expert (the others last) and the
-    held ones go through ``jax.lax.ragged_dot``, one group an expert, in
-    tiles of four times the even load, the rows that are not for this chip
-    in a last group of zero weights: a tile that no held choice reaches is
-    skipped (``lax.cond``), so the work follows the load and no token is
-    dropped whatever the load."""
-    t, d = x.shape
-    top_k, held = cfg.num_experts_per_token, len(cfg.experts_held)
-    s = jax.nn.sigmoid(jnp.matmul(x, p["router"], precision=_FLOAT32))
-    _, chosen = jax.lax.top_k(s + p["router_bias"], top_k)       # [T, 8]
-    s_chosen = jnp.take_along_axis(s, chosen, axis=1)
-    weight = s_chosen / s_chosen.sum(-1, keepdims=True) * cfg.routed_scaling_factor
-    slot_of = np.full((cfg.num_experts,), held, np.int32)        # held = "not here"
-    slot_of[list(cfg.experts_held)] = np.arange(held)
-    slot = jnp.asarray(slot_of)[chosen].reshape(-1)              # [T * 8]
-    # a counting sort, stable: a choice's place is its slot's start plus the
-    # earlier choices of its slot (the chip's compiler takes ten seconds
-    # over an ``argsort`` of this length, and there is one a layer and pass)
-    in_slot = (slot[:, None] == jnp.arange(held + 1)[None, :]).astype(jnp.int32)
-    all_loads = in_slot.sum(0)
-    place = (in_slot * (jnp.cumsum(in_slot, 0) - in_slot
-                        + (jnp.cumsum(all_loads) - all_loads)[None, :])).sum(1)
-    loads = all_loads[:held]
-    ends = jnp.cumsum(loads)
-    n_held = ends[-1]
-    rows = min(t * top_k, max(4 * t * top_k * held // cfg.num_experts, 8))
-    n_tiles = -(-t * top_k // rows)
-    order = jnp.zeros((n_tiles * rows,), jnp.int32).at[place].set(
-        jnp.arange(t * top_k, dtype=jnp.int32))
-    weight = weight.reshape(-1)
-
-    # every row of a tile belongs to a group: after the held experts comes
-    # one whose weights are zero and takes the rows that are not for this
-    # chip. On the chip ``ragged_dot`` leaves the rows that no group holds
-    # as it finds them, in the backward pass too, where a mask on its
-    # output cannot reach.
-    with_rest = lambda w: jnp.concatenate([w, jnp.zeros_like(w[:1])]).astype(_OPERAND)
-    xb = x.astype(_OPERAND)
-    # gate and up side by side: one grouped product for the two
-    e_in = with_rest(jnp.concatenate([p["e_gate"], p["e_up"]], -1))
-    e_down = with_rest(p["e_down"])
-    f = cfg.moe_intermediate_size
-
-    def tile(lo):
-        take = jax.lax.dynamic_slice(order, (lo,), (rows,))
-        token = take // top_k
-        sizes = jnp.clip(ends - lo, 0, rows) - jnp.clip(ends - loads - lo, 0, rows)
-        sizes = jnp.concatenate([sizes, rows - sizes.sum(keepdims=True)])
-        dot = lambda a, w: jax.lax.ragged_dot(
-            a.astype(_OPERAND), w, sizes, preferred_element_type=jnp.float32)
-        gate_up = dot(xb[token], e_in)
-        y = dot(jax.nn.silu(gate_up[:, :f]) * gate_up[:, f:], e_down)
-        return jnp.zeros((t, d), jnp.float32).at[token].add(y * weight[take][:, None])
-
-    # recomputed in the backward pass from the scan's own constants: what
-    # a ``cond`` keeps for its branches would be kept once per tile
-    @jax.checkpoint
-    def tile_if_reached(lo):
-        return jax.lax.cond(
-            lo < n_held, tile, lambda lo: jnp.zeros((t, d), jnp.float32), lo)
-
-    def add_tile(routed, lo):
-        return routed + tile_if_reached(lo), None
-
-    routed, _ = jax.lax.scan(
-        add_tile, jnp.zeros((t, d), jnp.float32), jnp.arange(n_tiles) * rows)
-    y = routed + _swiglu(x, p["shared_gate"], p["shared_up"], p["shared_down"])
-    load = loads.astype(jnp.float32)
-    counters = jnp.stack([load.sum(), load.max() / jnp.maximum(load.mean(), 1e-9)])
-    return y, counters
+    """This chip's part of the expert layer (``lane.moe_held_experts``) as
+    this model's router has it: sigmoid scores, the top 8 of ``s + b``,
+    ``routed_scaling_factor``, and the shared expert once."""
+    return lane.moe_held_experts(x, p, _experts(cfg))
 
 
 def _layer(h, p, kind, cfg: KimiLinearConfig):
@@ -530,65 +385,21 @@ def _layer(h, p, kind, cfg: KimiLinearConfig):
     return h + y, counters
 
 
-def _embed(params, tokens):
-    with jax.named_scope("lane.head"):
-        return params["embed"][tokens[:-1]]
-
-
-def _head_loss(h, norm_f, head, tokens, cfg: KimiLinearConfig):
-    with jax.named_scope("lane.head"):
-        logits = _mm(_rms(h, norm_f, cfg.rms_norm_eps), head)
-        logp = jax.nn.log_softmax(logits)
-        return -jnp.take_along_axis(logp, tokens[1:, None], axis=-1)[:, 0].mean()
+def _layers(cfg: KimiLinearConfig):
+    return [lambda h, p, kind=kind: _layer(h, p, kind, cfg) for kind in cfg.layer_kinds]
 
 
 def kimi_linear_loss(params: dict, tokens: jax.Array, cfg: KimiLinearConfig):
     """``tokens`` i32[T + 1] -> ``(mean next-token cross-entropy over the
     vocabulary slice, counters f32[n_layers, 2])``."""
-    h = _embed(params, tokens)
-    counters = []
-    for i, kind in enumerate(cfg.layer_kinds):
-        layer = jax.checkpoint(lambda h, p, kind=kind: _layer(h, p, kind, cfg))
-        h, c = layer(h, params[f"l{i}"])
-        counters.append(c)
-    loss = _head_loss(h, params["norm_f"], params["head"], tokens, cfg)
-    return loss, jnp.stack(counters)
+    return lane._loss(params, tokens, _layers(cfg), cfg.rms_norm_eps)
 
 
 def kimi_linear_forward(params: dict, tokens: jax.Array, cfg: KimiLinearConfig):
     """:func:`kimi_linear_loss` with nothing kept for a gradient but the
-    input of every layer: ``(loss, counters, [h_0 .. h_L])``. An evaluation
-    takes the gradient from these by the chain rule, a layer at a time, each
-    layer's inside computed again (what ``jax.grad`` does with
-    ``jax.checkpoint`` around every layer), so that a pass that needs no
-    gradient (a held-out sequence) is the same trace as one that does."""
-    hs, counters = [_embed(params, tokens)], []
-    for i, kind in enumerate(cfg.layer_kinds):
-        h, c = _layer(hs[-1], params[f"l{i}"], kind, cfg)
-        hs.append(h)
-        counters.append(c)
-    loss = _head_loss(hs[-1], params["norm_f"], params["head"], tokens, cfg)
-    return loss, jnp.stack(counters), hs
-
-
-# ------------------------------------------------------------------- data
-def make_token_dataset(key: jax.Array, cfg: KimiLinearConfig):
-    """``(train i32[n_train, T + 1], val i32[n_val, T + 1])``: ids over
-    the vocabulary slice, Zipf-distributed (``p(rank r) ~ 1 / r``, by
-    inverse CDF from uniform draws), the second half of each sequence
-    repeating its first, so that a lane predicts it only through state
-    and attention."""
-    cdf = np.cumsum(1.0 / np.arange(1, cfg.vocab_rows + 1, dtype=np.float64))
-    cdf = jnp.asarray((cdf / cdf[-1]).astype(np.float32))
-    half = cfg.seq_len // 2 + 1
-
-    def draw(k, n):
-        ids = jnp.searchsorted(cdf, jax.random.uniform(k, (n, half)))
-        ids = jnp.minimum(ids, cfg.vocab_rows - 1).astype(jnp.int32)
-        return jnp.concatenate([ids, ids[:, :cfg.seq_len + 1 - half]], axis=1)
-
-    kt, kv = jax.random.split(key)
-    return draw(kt, cfg.n_train), draw(kv, cfg.n_val)
+    input of every layer: ``(loss, counters, [h_0 .. h_L])``, what the
+    lanes' trainer takes the gradient from (``lane._forward``)."""
+    return lane._forward(params, tokens, _layers(cfg), cfg.rms_norm_eps)
 
 
 # ------------------------------------------------------------- evaluation
@@ -600,9 +411,8 @@ def kimi_linear_lane_bytes(cfg: KimiLinearConfig) -> int:
     of ``mla_heads_at_once`` heads) and a layer's input per layer. At the
     published widths it gives 11.5 GB where the chip's compiler counts
     12.1 GB for the bracket: one lane fits a 16.9 GB chip, two do not."""
-    shapes = jax.eval_shape(
+    n_params = lane._count_params(
         lambda: init_kimi_linear_params(jax.random.key(0), cfg, 1.0))
-    n_params = sum(int(np.prod(s.shape)) for s in jax.tree.leaves(shapes))
     t = cfg.seq_len
     activations = 4 * t * (
         3 * cfg.vocab_rows
@@ -614,89 +424,17 @@ def kimi_linear_lane_bytes(cfg: KimiLinearConfig) -> int:
 
 def make_kimi_linear_eval_fn(cfg: KimiLinearConfig = KimiLinearConfig(),
                              data_seed: int = 0):
-    """``eval_fn(config_vec, budget) -> held-out cross-entropy``, handed to
-    ``FusedBOHB(eval_fn=...)`` as ``make_transformer_eval_fn``'s is. Budget
-    is momentum-SGD steps of one ``seq_len``-token sequence; step ``t``
-    trains on sequence ``t mod n_train``; ``v <- m v + g + wd p; p <- p -
-    lr v``. ``eval_fn.lane_facts`` states the lane's footprint, its tokens
-    a step and its device counters (:data:`LANE_COUNTERS`), which the
-    rung's evaluation (``ops.fused.eval_lanes``) reads."""
-    train, val = make_token_dataset(jax.random.key(data_seed), cfg)
+    """``eval_fn(config_vec, budget) -> held-out cross-entropy`` of the
+    lane, by the lanes' one trainer (``lane.make_lane_eval_fn``: budget is
+    momentum-SGD steps of one ``seq_len``-token sequence);
+    ``eval_fn.lane_facts`` states its footprint, its tokens a step and its
+    device counters (:data:`LANE_COUNTERS`)."""
     init_key = jax.random.key(data_seed + 1)
-    choices = cfg.seq_len * cfg.num_experts_per_token
-
-    def with_counters(vec: jax.Array, budget):
-        lr, momentum, wd, init_scale = decode_kimi_linear_hparams(vec)
-        params = init_kimi_linear_params(init_key, cfg, init_scale)
-        steps = jnp.asarray(budget, jnp.float32).round().astype(jnp.int32)
-
-        # ONE loop over the training sequences and then the held-out ones:
-        # each pass runs the forward trace, and a training pass the backward
-        # one and the update besides, so the program holds the forward pass
-        # once for both (a quarter of its compilation). The backward pass
-        # and the update are a ``lax.cond`` a layer (the head, each layer
-        # from the last, the embedding): parameters and momentum through
-        # ONE ``cond`` would be held twice over, and all the gradient's
-        # leaves would be alive at once.
-        def update(p, v, g):
-            with jax.named_scope("lane.update"):
-                v = jax.tree.map(lambda vi, gi, pi: momentum * vi + gi + wd * pi, v, g, p)
-                return jax.tree.map(lambda pi, vi: pi - lr * vi, p, v), v
-
-        def one_pass(t, carry):
-            p, v, held_loss, held_counters = carry
-            training = t < steps
-            seq = jnp.where(training, train[t % cfg.n_train],
-                            val[jnp.clip(t - steps, 0, cfg.n_val - 1)])
-            loss, counters, hs = kimi_linear_forward(p, seq, cfg)
-            p, v = dict(p), dict(v)
-
-            def if_training(step, *state):
-                return jax.lax.cond(training, step, lambda *same: same, *state)
-
-            def head_step(dh, pn, vn, ph, vh):
-                dh, g_norm, g_head = jax.grad(_head_loss, argnums=(0, 1, 2))(
-                    hs[-1], pn, ph, seq, cfg)
-                return (dh,) + update(pn, vn, g_norm) + update(ph, vh, g_head)
-
-            dh, p["norm_f"], v["norm_f"], p["head"], v["head"] = if_training(
-                head_step, jnp.zeros_like(hs[-1]), p["norm_f"], v["norm_f"],
-                p["head"], v["head"])
-            for i in reversed(range(len(cfg.layer_kinds))):
-                def layer_step(dh, pl, vl, i=i):
-                    _, pull = jax.vjp(
-                        lambda h, q: _layer(h, q, cfg.layer_kinds[i], cfg)[0], hs[i], pl)
-                    dh, g = pull(dh)
-                    return (dh,) + update(pl, vl, g)
-
-                dh, p[f"l{i}"], v[f"l{i}"] = if_training(
-                    layer_step, dh, p[f"l{i}"], v[f"l{i}"])
-
-            def embed_step(pe, ve):
-                with jax.named_scope("lane.head"):
-                    g = jnp.zeros_like(pe).at[seq[:-1]].add(dh)
-                return update(pe, ve, g)
-
-            p["embed"], v["embed"] = if_training(embed_step, p["embed"], v["embed"])
-            held = jnp.where(training, 0.0, 1.0)
-            return p, v, held_loss + held * loss, held_counters + held * counters
-
-        _, _, loss, counters = jax.lax.fori_loop(0, steps + cfg.n_val, one_pass, (
-            params, jax.tree.map(jnp.zeros_like, params), jnp.float32(0.0),
-            jnp.zeros((len(cfg.layer_kinds), 2), jnp.float32)))
-        loss = loss / cfg.n_val
-        moe = counters[np.asarray([f == "moe" for _, f in cfg.layer_kinds])]
-        # a lane whose training diverged has no number for a loss: it
-        # reports the worst one, infinity; NaN is the sweep's mask for a crash
-        return jnp.where(jnp.isnan(loss), jnp.inf, loss), jnp.stack([
-            moe[:, 0].sum() / max(moe.shape[0] * cfg.n_val * choices, 1),
-            moe[:, 1].mean() / cfg.n_val if moe.shape[0] else jnp.float32(0.0),
-        ])
-
-    def eval_fn(vec: jax.Array, budget) -> jax.Array:
-        return with_counters(vec, budget)[0]
-
-    eval_fn.lane_facts = LaneFacts(
-        bytes=kimi_linear_lane_bytes(cfg), tokens_per_step=cfg.seq_len,
-        counters=LANE_COUNTERS, with_counters=with_counters, traced_budget=True)
-    return eval_fn
+    return lane.make_lane_eval_fn(
+        init=lambda init_scale: init_kimi_linear_params(init_key, cfg, init_scale),
+        layers=_layers(cfg),
+        moe_layers=[ffn == "moe" for _, ffn in cfg.layer_kinds],
+        eps=cfg.rms_norm_eps,
+        data=make_token_dataset(jax.random.key(data_seed), cfg),
+        choices_per_pass=cfg.seq_len * cfg.num_experts_per_token,
+        lane_bytes=kimi_linear_lane_bytes(cfg))

@@ -1,0 +1,363 @@
+"""A block of Mellum2-12B-A2.5B-Instruct as a rung's lane.
+
+The published model (``model_type`` ``mellum``; JetBrains' code model;
+widths from its ``config.json``): pre-norm residual layers ``h +=
+Attention(RMSNorm(h)); h += Experts(RMSNorm(h))`` with grouped-query
+attention (32 query heads on 4 key/value heads of 128) under rotary
+positions, three layers in four seeing a sliding window of 1,024 positions
+(plain RoPE, theta 500,000) and the fourth the whole causal past
+(YaRN-interpolated frequencies, factor 16 over 8,192, and an attention
+factor on cos and sin), and in every layer 64 softmax-routed experts of
+width 896, 8 a token, renormalised, no shared one. A final RMSNorm and an
+untied head close it.
+
+What trains here is **one chip's share** (:class:`Mellum2Config`'s cut):
+``layer_kinds`` (one period: sliding, sliding, sliding, full),
+``experts_held`` (16 of the 64: the router keeps its 64 outputs and its 8
+a token, this chip adds ``w_e * E_e(x)`` only for chosen experts it holds)
+and ``vocab_rows`` (a quarter of the vocabulary). The search space, the
+rule for a product's operands, the expert layer, embedding and head, the
+tokens and the trainer are every lane's (``workloads/lane.py``); this file
+has the attention, the configuration and the footprint.
+
+**One attention function for both kinds of layer**
+(:func:`banded_attention`, ``window`` a number or ``None``): queries in
+blocks, each block against the keys from the block that holds the first
+position it may see to its own end and no others, the 8 query heads of a
+key/value head as rows of one product (no head is repeated), as many
+key/value heads at a time as keep the scores alive under
+``_SCORES_AT_ONCE``, each block's scores recomputed in the backward pass
+(``jax.checkpoint``). A block of scores wholly outside the band or above the
+diagonal is never computed (:func:`attention_key_blocks` counts them), no
+``S x S`` array exists in either kind, and a window layer's products are two
+blocks of keys wide whatever the length. Plain JAX, differentiated by JAX.
+
+Precision as the Kimi-Linear lane states it: float32 parameters, momentum
+and gradients; matrix-product operands bfloat16 with float32 accumulation;
+the router's product, softmax, rotary tables, norms and the loss float32.
+What ``config.json`` does not settle is ``assumed`` in
+``benchmark/configs/mellum2-sgd.json``: no per-head norm on queries and
+keys, rotate-half pairing over the whole head, softmax before the top 8 and
+renormalisation after, a position sees itself and the ``window - 1`` before
+it, no auxiliary loss, no multi-token-prediction head.
+"""
+
+from __future__ import annotations
+
+import math
+from typing import NamedTuple, Optional, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from hpbandster_tpu.workloads import lane
+from hpbandster_tpu.workloads.lane import (  # noqa: F401 - the lane's public names
+    LANE_COUNTERS,
+    _mm,
+    _mm_beside,
+    _rms,
+    make_token_dataset,
+    moe_held_experts,
+)
+
+__all__ = [
+    "ATTENTION_COUNTERS",
+    "Mellum2Config",
+    "attention_key_blocks",
+    "banded_attention",
+    "init_mellum2_params",
+    "make_mellum2_eval_fn",
+    "mellum2_forward",
+    "mellum2_lane_bytes",
+    "mellum2_loss",
+    "mellum2_space",
+    "rotary_inv_freq",
+]
+
+#: static facts of the blocking that ride beside :data:`LANE_COUNTERS`, per
+#: training pass: the key blocks of scores the lane computes, and those of
+#: the full ``S x S`` squares of its layers
+ATTENTION_COUNTERS = ("attn_key_blocks_computed", "attn_key_blocks_square")
+
+#: lr (log), momentum, weight decay (log), init scale (log): every lane's
+mellum2_space = lane.lane_space
+
+
+class Mellum2Config(NamedTuple):
+    """Published widths as defaults, then the cut, then the data."""
+
+    hidden_size: int = 2304
+    num_heads: int = 32
+    num_kv_heads: int = 4
+    head_dim: int = 128
+    moe_intermediate_size: int = 896
+    num_experts_per_token: int = 8
+    sliding_window: int = 1024
+    rope_theta: float = 500000.0
+    #: rope_parameters.full_attention (``rope_type`` ``yarn``)
+    yarn_factor: float = 16.0
+    yarn_original_max_position: int = 8192
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_attention_factor: float = 1.2772588722239782
+    rms_norm_eps: float = 1e-6
+    #: the cut: layers 0-3 of 28, one whole period
+    layer_kinds: Tuple[str, ...] = ("sliding", "sliding", "sliding", "full")
+    #: which of the routed experts this chip holds, of ``router_outputs``
+    experts_held: Tuple[int, ...] = tuple(range(16))
+    router_outputs: int = 64
+    vocab_rows: int = 24576
+    #: data: tokens a step, sequences to cycle through and held out
+    seq_len: int = 8192
+    n_train: int = 32
+    n_val: int = 1
+    #: how the program computes it, not what: the block of queries (the
+    #: tests' lanes of 64 tokens take 16)
+    attn_query_block: int = 1024
+
+
+def _experts(cfg: Mellum2Config) -> lane.ExpertLayer:
+    return lane.ExpertLayer(
+        outputs=cfg.router_outputs, top_k=cfg.num_experts_per_token,
+        held=cfg.experts_held, width=cfg.moe_intermediate_size, score="softmax")
+
+
+# ------------------------------------------------------------- parameters
+def _layer_shapes(cfg: Mellum2Config) -> dict:
+    d, dh = cfg.hidden_size, cfg.head_dim
+    f, e = cfg.moe_intermediate_size, len(cfg.experts_held)
+    return dict(
+        norm1=(d,), norm2=(d,),
+        wq=(d, cfg.num_heads * dh), wk=(d, cfg.num_kv_heads * dh),
+        wv=(d, cfg.num_kv_heads * dh), wo=(cfg.num_heads * dh, d),
+        router=(d, cfg.router_outputs),
+        e_gate=(e, d, f), e_up=(e, d, f), e_down=(e, f, d),
+    )
+
+
+def init_mellum2_params(key: jax.Array, cfg: Mellum2Config, init_scale) -> dict:
+    return lane._init_params(
+        key, cfg, [_layer_shapes(cfg)] * len(cfg.layer_kinds), init_scale)
+
+
+# -------------------------------------------------------------- positions
+def rotary_inv_freq(cfg: Mellum2Config, kind: str):
+    """``(inv_freq f64[head_dim / 2], factor)`` of a layer of ``kind``.
+    A window layer: ``theta^(-2i / d)`` and 1. A full layer (YaRN):
+    channels that turn more than ``beta_fast`` times over the original
+    context keep their frequency, those that turn less than ``beta_slow``
+    times have it divided by ``factor``, a linear ramp between (``low`` and
+    ``high``, the channels where the turns cross the two betas, floor and
+    ceiling); cos and sin both carry the attention factor."""
+    d = cfg.head_dim
+    plain = cfg.rope_theta ** (-np.arange(0, d, 2, dtype=np.float64) / d)
+    if kind == "sliding":
+        return plain, 1.0
+    low, high = yarn_correction_range(cfg)
+    ramp = np.clip((np.arange(d // 2, dtype=np.float64) - low) / (high - low), 0.0, 1.0)
+    return (1.0 - ramp) * plain + ramp * plain / cfg.yarn_factor, cfg.yarn_attention_factor
+
+
+def yarn_correction_range(cfg: Mellum2Config):
+    """``(low, high)``: the channel at which a turn count ``r`` over the
+    original context is reached is ``d ln(L / (2 pi r)) / (2 ln theta)``."""
+    def channel(turns):
+        return (cfg.head_dim * math.log(
+            cfg.yarn_original_max_position / (turns * 2 * math.pi))
+            / (2 * math.log(cfg.rope_theta)))
+
+    low = max(math.floor(channel(cfg.yarn_beta_fast)), 0)
+    high = min(math.ceil(channel(cfg.yarn_beta_slow)), cfg.head_dim - 1)
+    return low, (high if high != low else high + 0.001)
+
+
+def _rotary_tables(cfg: Mellum2Config, kind: str, t: int):
+    """``(cos, sin)`` f32[T, head_dim]: channel ``i`` turns with ``i +
+    d / 2`` (the rotate-half form), angles in float32."""
+    inv_freq, factor = rotary_inv_freq(cfg, kind)
+    angle = (jnp.arange(t, dtype=jnp.float32)[:, None]
+             * jnp.asarray(inv_freq, jnp.float32)[None, :])
+    angle = jnp.concatenate([angle, angle], axis=-1)
+    return jnp.cos(angle) * factor, jnp.sin(angle) * factor
+
+
+def _rotate(x, cos, sin):
+    """``x`` f32[T, ..., d] turned by its position's angles."""
+    half = x.shape[-1] // 2
+    turned = jnp.concatenate([-x[..., half:], x[..., :half]], axis=-1)
+    lift = (slice(None),) + (None,) * (x.ndim - 2)
+    return x * cos[lift] + turned * sin[lift]
+
+
+# --------------------------------------------------------------- attention
+#: how many scores (a block's queries x their heads x its keys, of several
+#: key/value heads together where they fit) are alive at once. Measured on
+#: the chip at the published size (PR 32; a layer's mixer, forward and
+#: backward): a window layer, whose block is 2^24 scores, takes two key/value
+#: heads at a time (0.0400 s and 1,065 device events, against 0.0422 s and
+#: 2,564 one at a time); a full layer, whose widest block alone is 2^26, one
+#: (0.073 s; two at a time 0.601 s: a batch of wide score matrices leaves
+#: the softmax's fast path)
+_SCORES_AT_ONCE = 2 ** 25
+
+
+def _attention_spans(t: int, window: Optional[int], block: int):
+    """``[(lo, hi, klo)]``: queries ``lo:hi`` go against keys ``klo:hi``,
+    ``klo`` the start of the block of keys that holds the first position
+    query ``lo`` may see."""
+    first = lambda lo: 0 if window is None else max(0, lo - window + 1) // block * block
+    return [(lo, min(lo + block, t), first(lo)) for lo in range(0, t, block)]
+
+
+def attention_key_blocks(t: int, windows, block: int):
+    """``(computed, square)``: blocks of ``block x block`` scores that
+    :func:`banded_attention` computes over layers of the given ``windows``
+    (a number or ``None`` each), and those of their full squares."""
+    per_side = -(-t // block)
+    computed = sum(-(-(hi - klo) // block)
+                   for window in windows
+                   for _, hi, klo in _attention_spans(t, window, block))
+    return computed, per_side * per_side * len(windows)
+
+
+def banded_attention(q, k, v, window: Optional[int], block: int,
+                     scores_at_once: int = _SCORES_AT_ONCE):
+    """Causal softmax attention with grouped queries, banded where
+    ``window`` is a number: position ``i`` sees ``j <= i`` and, with a
+    window, ``i - j < window``. ``q`` f32[T, G, R, d] (query head ``g * R +
+    r`` on key/value head ``g``), ``k, v`` f32[T, G, d]; returns f32[T, G,
+    R, d]. Scores are ``q . k / sqrt(d)``, the softmax float32.
+
+    Queries go in blocks of ``block``, each against the keys of
+    :func:`_attention_spans` and no others; a key/value head is not
+    repeated for its ``R`` query heads (one product over them); the groups
+    go as many at a time as keep a block's scores under ``scores_at_once``
+    (one where not even two do) and a block's scores are recomputed in the
+    backward pass, so what is alive at once is one block's scores of those
+    groups."""
+    t, d = q.shape[0], q.shape[-1]
+    scale = d ** -0.5
+    spans = _attention_spans(t, window, block)
+
+    def one_block(qb, kb, vb, lo, klo):
+        # rows are (query, head) pairs: the R query heads of a key/value
+        # head share one product, and everything between the two products
+        # is two-dimensional (a [block, R, keys] array of scores costs the
+        # chip eight times the time: its softmax leaves the fast path)
+        nq, r, nk = qb.shape[0], qb.shape[1], kb.shape[0]
+        s = _mm(qb.reshape(nq * r, d), kb.T) * scale
+        at = lo + jnp.arange(nq * r)[:, None] // r
+        key = klo + jnp.arange(nk)[None, :]
+        seen = key <= at
+        if window is not None:
+            seen = seen & (at - key < window)
+        att = jax.nn.softmax(jnp.where(seen, s, -1e30), axis=-1)
+        return _mm(att, vb).reshape(nq, r, d)
+
+    # a Python loop over the blocks, each traced where it starts: a
+    # ``lax.scan`` over a window layer's seven blocks of one shape built
+    # 12 s sooner on the chip's host and ran a sweep 8 % slower (PR 32)
+    one_block = jax.checkpoint(one_block, static_argnums=(3, 4))
+
+    def one_group(qkv):
+        qg, kg, vg = qkv
+        return jnp.concatenate([
+            one_block(qg[lo:hi], kg[klo:hi], vg[klo:hi], lo, klo)
+            for lo, hi, klo in spans], axis=0)
+
+    groups = (q.swapaxes(0, 1), k.swapaxes(0, 1), v.swapaxes(0, 1))
+    widest = max((hi - lo) * (hi - klo) for lo, hi, klo in spans) * q.shape[2]
+    at_once = scores_at_once // widest
+    out = jax.lax.map(one_group, groups, batch_size=at_once if at_once > 1 else None)
+    return out.swapaxes(0, 1)
+
+
+def _attention(x, p, kind: str, cfg: Mellum2Config):
+    """A layer's mixer, from the norm's output to ``W_o``."""
+    t = x.shape[0]
+    g, r, d = cfg.num_kv_heads, cfg.num_heads // cfg.num_kv_heads, cfg.head_dim
+    q, k, v = _mm_beside(x, p["wq"], p["wk"], p["wv"])
+    cos, sin = _rotary_tables(cfg, kind, t)
+    q = _rotate(q.reshape(t, g, r, d), cos, sin)
+    k = _rotate(k.reshape(t, g, d), cos, sin)
+    out = banded_attention(
+        q, k, v.reshape(t, g, d),
+        cfg.sliding_window if kind == "sliding" else None, cfg.attn_query_block)
+    return _mm(out.reshape(t, g * r * d), p["wo"])
+
+
+#: the scope of a layer's mixer by its kind (``obs.timeline.LANE_SCOPES``)
+_MIXER_SCOPE = {"sliding": "lane.swa", "full": "lane.gqa"}
+
+
+def _layer(h, p, kind: str, cfg: Mellum2Config):
+    x = _rms(h, p["norm1"], cfg.rms_norm_eps)
+    with jax.named_scope(_MIXER_SCOPE[kind]):
+        h = h + _attention(x, p, kind, cfg)
+    x = _rms(h, p["norm2"], cfg.rms_norm_eps)
+    with jax.named_scope("lane.moe"):
+        y, counters = moe_held_experts(x, p, _experts(cfg))
+    return h + y, counters
+
+
+def _layers(cfg: Mellum2Config):
+    return [lambda h, p, kind=kind: _layer(h, p, kind, cfg) for kind in cfg.layer_kinds]
+
+
+def mellum2_loss(params: dict, tokens: jax.Array, cfg: Mellum2Config):
+    """``tokens`` i32[T + 1] -> ``(mean next-token cross-entropy over the
+    vocabulary slice, counters f32[n_layers, 2])``."""
+    return lane._loss(params, tokens, _layers(cfg), cfg.rms_norm_eps)
+
+
+def mellum2_forward(params: dict, tokens: jax.Array, cfg: Mellum2Config):
+    """:func:`mellum2_loss` with nothing kept for a gradient but the input
+    of every layer: ``(loss, counters, [h_0 .. h_L])``, what the lanes'
+    trainer takes the gradient from (``lane._forward``)."""
+    return lane._forward(params, tokens, _layers(cfg), cfg.rms_norm_eps)
+
+
+# ------------------------------------------------------------- evaluation
+def mellum2_lane_bytes(cfg: Mellum2Config) -> int:
+    """Device bytes one lane needs while it trains: float32 parameters,
+    momentum and gradients (12 bytes a parameter) and the peak of its
+    activations: the logits, their softmax and their gradient, a layer's
+    input per layer, one layer's recomputed activations (about 24
+    hidden-sized rows a token: projections, rotated heads, their
+    gradients) and three copies of the widest block of scores. At the
+    published widths it gives 12.5 GB where the chip's compiler counts
+    12.0 GB for the bracket and its allocator peaks at 8.5 GB: one lane
+    fits a 16.9 GB chip, two do not."""
+    n_params = lane._count_params(
+        lambda: init_mellum2_params(jax.random.key(0), cfg, 1.0))
+    t = cfg.seq_len
+    widest = max(hi - klo for window in (cfg.sliding_window, None)
+                 for _, hi, klo in _attention_spans(t, window, cfg.attn_query_block))
+    heads_a_group = cfg.num_heads // cfg.num_kv_heads
+    activations = 4 * (
+        t * (3 * cfg.vocab_rows + (24 + len(cfg.layer_kinds)) * cfg.hidden_size)
+        + 3 * heads_a_group * min(cfg.attn_query_block, t) * widest)
+    return 12 * n_params + activations
+
+
+def make_mellum2_eval_fn(cfg: Mellum2Config = Mellum2Config(), data_seed: int = 0):
+    """``eval_fn(config_vec, budget) -> held-out cross-entropy`` of the
+    lane, by the lanes' one trainer (``lane.make_lane_eval_fn``: budget is
+    momentum-SGD steps of one ``seq_len``-token sequence);
+    ``eval_fn.lane_facts`` states its footprint, its tokens a step and its
+    counters: :data:`LANE_COUNTERS` from the device, then
+    :data:`ATTENTION_COUNTERS`, facts of the blocking."""
+    init_key = jax.random.key(data_seed + 1)
+    windows = [cfg.sliding_window if kind == "sliding" else None
+               for kind in cfg.layer_kinds]
+    blocks = attention_key_blocks(cfg.seq_len, windows, cfg.attn_query_block)
+    return lane.make_lane_eval_fn(
+        init=lambda init_scale: init_mellum2_params(init_key, cfg, init_scale),
+        layers=_layers(cfg),
+        moe_layers=[True] * len(cfg.layer_kinds),
+        eps=cfg.rms_norm_eps,
+        data=make_token_dataset(jax.random.key(data_seed), cfg),
+        choices_per_pass=cfg.seq_len * cfg.num_experts_per_token,
+        lane_bytes=mellum2_lane_bytes(cfg),
+        static_counters=tuple(zip(ATTENTION_COUNTERS, blocks)))

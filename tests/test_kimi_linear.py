@@ -41,7 +41,7 @@ def lane_config():
 
 @pytest.fixture
 def float32_operands(monkeypatch):
-    monkeypatch.setattr(K, "_OPERAND", jnp.float32)
+    monkeypatch.setattr(K.lane, "_OPERAND", jnp.float32)
 
 
 def _cfg(lane_config, config):
@@ -92,7 +92,7 @@ def test_loss_and_every_gradient_leaf_match_the_reference(
 ])
 def test_three_steps_match_the_reference(reference, lane_config, monkeypatch,
                                          operand, limit):
-    monkeypatch.setattr(K, "_OPERAND", operand)
+    monkeypatch.setattr(K.lane, "_OPERAND", operand)
     cfg = _cfg(lane_config, SMALL)
     eval_fn = K.make_kimi_linear_eval_fn(cfg, data_seed=SMALL["data_seed"])
     vec = jnp.asarray([0.75, 0.5, 0.3, 0.5])

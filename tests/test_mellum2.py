@@ -1,0 +1,418 @@
+"""The Mellum2 lane against the benchmark's plain reference, on the CPU at a
+small size (``mellum2_small.py``): the loss and every gradient leaf, three
+steps, the blocked attention against a full masked softmax, the rotary
+tables against numbers worked by hand, the chip's share of the expert layer
+against the uncut layer, and the one expert layer under both routers.
+
+Where a test holds the equations to the reference it sets the lanes'
+matrix-product operands to float32 (``lane._OPERAND``): then only the order
+of float32 sums differs, and the tolerances say so. Where it runs the lane
+as the chip does (bfloat16 operands), the tolerance is bfloat16's.
+"""
+
+import json
+import math
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from hpbandster_tpu.workloads import kimi_linear as K
+from hpbandster_tpu.workloads import lane
+from hpbandster_tpu.workloads import mellum2 as M
+
+import kimi_small
+from mellum2_small import BENCHMARK, SMALL, load, small
+
+ROOT = os.path.dirname(BENCHMARK)
+
+
+@pytest.fixture(scope="module")
+def reference():
+    return load("reference", "mellum2-sgd.py")
+
+
+@pytest.fixture(scope="module")
+def builders():
+    # the builders import the harness's ``program`` by that name
+    sys.modules.setdefault("program", load("program.py"))
+    return {"mellum2": load("configs", "mellum2-sgd.py").lane_config,
+            "kimi": load("configs", "kimi-linear-sgd.py").lane_config}
+
+
+@pytest.fixture
+def float32_operands(monkeypatch):
+    monkeypatch.setattr(lane, "_OPERAND", jnp.float32)
+
+
+def _cfg(builders, config):
+    return builders["mellum2"](config)._replace(attn_query_block=16)
+
+
+def test_weights_and_tokens_come_from_the_seed_alike(reference, builders):
+    cfg, key = _cfg(builders, SMALL), jax.random.key(1)
+    ours = M.init_mellum2_params(key, cfg, 0.7)
+    theirs = reference.init_params(SMALL, key, 0.7)
+    assert jax.tree.structure(ours) == jax.tree.structure(theirs)
+    assert all(jax.tree.leaves(jax.tree.map(
+        lambda a, b: bool((a == b).all()), ours, theirs)))
+    for a, b in zip(M.make_token_dataset(jax.random.key(0), cfg),
+                    reference.dataset(SMALL)):
+        assert a.shape[1] == 65 and bool((a == b).all())
+        half = a.shape[1] // 2 + 1
+        assert bool((a[:, half:] == a[:, :a.shape[1] - half]).all())
+
+
+def test_loss_and_every_gradient_leaf_match_the_reference(
+        reference, builders, float32_operands):
+    cfg = _cfg(builders, SMALL)
+    params = M.init_mellum2_params(jax.random.key(1), cfg, 1.0)
+    tokens = M.make_token_dataset(jax.random.key(0), cfg)[0][0]
+    loss, grads = jax.jit(jax.value_and_grad(
+        lambda p: M.mellum2_loss(p, tokens, cfg)[0]))(params)
+    want, want_grads = jax.jit(jax.value_and_grad(
+        lambda p: reference.loss_fn(p, tokens, SMALL)))(params)
+    # float32 both sides, another order of summation (blocks of keys against
+    # the whole row, grouped against masked products): 1e-5 of the loss
+    assert abs(float(loss) - float(want)) < 1e-5 * float(want)
+    for (path, got), ref in zip(jax.tree_util.tree_leaves_with_path(grads),
+                                jax.tree.leaves(want_grads)):
+        # per leaf, against the leaf's largest entry: 1e-6 measured
+        worst = float(jnp.abs(got - ref).max() / (jnp.abs(ref).max() + 1e-12))
+        assert worst < 2e-5, (jax.tree_util.keystr(path), worst)
+
+
+def test_the_forward_pass_of_the_trainer_is_the_loss(builders, float32_operands):
+    cfg = _cfg(builders, SMALL)
+    params = M.init_mellum2_params(jax.random.key(1), cfg, 1.0)
+    tokens = M.make_token_dataset(jax.random.key(0), cfg)[1][0]
+    loss, counters = M.mellum2_loss(params, tokens, cfg)
+    again, same, hs = M.mellum2_forward(params, tokens, cfg)
+    assert float(loss) == pytest.approx(float(again), rel=1e-6)
+    np.testing.assert_allclose(counters, same)
+    assert len(hs) == 5 and all(h.shape == (64, 64) for h in hs)
+
+
+@pytest.mark.parametrize("operand, limit", [
+    # float32 operands: rounding of sums only, three steps amplify it little
+    (jnp.float32, 1e-4),
+    # as the chip runs it: bfloat16 operands (2^-8 a product) through four
+    # layers and three steps
+    (jnp.bfloat16, 2e-2),
+])
+def test_three_steps_match_the_reference(reference, builders, monkeypatch,
+                                         operand, limit):
+    monkeypatch.setattr(lane, "_OPERAND", operand)
+    cfg = _cfg(builders, SMALL)
+    eval_fn = M.make_mellum2_eval_fn(cfg, data_seed=SMALL["data_seed"])
+    vec = jnp.asarray([0.75, 0.5, 0.3, 0.5])
+    got = float(jax.jit(lambda v: eval_fn(v, 3.0))(vec))
+    hparams = [float(x) for x in lane.decode_lane_hparams(vec)]
+    start, want = reference.reference_losses(SMALL, hparams, [0, 3])
+    assert want < start - 0.01  # the steps moved the loss: it is compared
+    assert abs(got - want) < limit * (1 + abs(want))
+
+
+def test_the_reference_trains_by_the_gradient_of_its_loss(reference):
+    """The reference steps layer by layer (``jax.vjp`` chained by hand, so
+    that layers of a kind share a compiled function): with no momentum and
+    no decay the momentum buffer after one step is ``jax.grad`` of its
+    ``loss_fn``, every leaf; float32 sums in another order."""
+    init, step, _ = reference.lane_functions(SMALL, jnp.float32)
+    p, v = init(jnp.float32(1.0))
+    train, _ = reference.dataset(SMALL)
+    want = jax.grad(reference.loss_fn)(p, train[2], SMALL)
+    new_p, got = step(p, v, 2, jnp.float32(0.5), jnp.float32(0.0), jnp.float32(0.0))
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(
+            g, w, atol=1e-5 * float(jnp.abs(w).max()) + 1e-12, err_msg=str(path))
+    np.testing.assert_allclose(new_p["head"], p["head"] - 0.5 * want["head"], atol=1e-6)
+
+
+# ------------------------------------------------------------- attention
+def _full_masked_softmax(q, k, v, window):
+    """The whole ``T x T`` square, key/value heads repeated outright."""
+    t, g, r, d = q.shape
+    k, v = (jnp.repeat(y, r, axis=1) for y in (k, v))
+    s = jnp.einsum("qhd,khd->hqk", q.reshape(t, g * r, d), k) / math.sqrt(d)
+    at, key = jnp.arange(t)[:, None], jnp.arange(t)[None, :]
+    seen = key <= at
+    if window is not None:
+        seen &= at - key < window
+    att = jax.nn.softmax(jnp.where(seen, s, -jnp.inf), axis=-1)
+    return jnp.einsum("hqk,khd->qhd", att, v).reshape(t, g, r, d)
+
+
+def _qkv(length, seed=0, g=2, r=4, d=16):
+    keys = jax.random.split(jax.random.key(seed), 3)
+    return (jax.random.normal(keys[0], (length, g, r, d)),
+            jax.random.normal(keys[1], (length, g, d)),
+            jax.random.normal(keys[2], (length, g, d)))
+
+
+@pytest.mark.parametrize("length", [64, 70, 9])
+@pytest.mark.parametrize("window", [None, 8, 1, 64, 1000])
+def test_blocked_attention_is_the_full_masked_softmax(float32_operands, window, length):
+    """Windows from one position to longer than the sequence, lengths that
+    are and are not multiples of the block of 16, the gradient too."""
+    q, k, v = _qkv(length, seed=length)
+    got = M.banded_attention(q, k, v, window, 16)
+    want = _full_masked_softmax(q, k, v, window)
+    np.testing.assert_allclose(got, want, atol=2e-6)
+    ours = jax.grad(lambda *x: (M.banded_attention(*x, window, 16) ** 2).sum(), (0, 1, 2))
+    theirs = jax.grad(lambda *x: (_full_masked_softmax(*x, window) ** 2).sum(), (0, 1, 2))
+    for g, w in zip(ours(q, k, v), theirs(q, k, v)):
+        np.testing.assert_allclose(g, w, atol=2e-5 * float(jnp.abs(w).max()))
+
+
+@pytest.mark.parametrize("window", [8, 1, 17])
+def test_a_position_never_sees_past_its_window(float32_operands, window):
+    """Perturb the key and value at ``j``: no output at ``i`` with ``i - j
+    >= window`` moves, nor any before ``j``; those inside the window do."""
+    q, k, v = _qkv(64, seed=3)
+    base = M.banded_attention(q, k, v, window, 16)
+    j = 20
+    moved = M.banded_attention(
+        q, k.at[j].add(5.0), v.at[j].add(-3.0), window, 16)
+    changed = np.asarray(jnp.abs(moved - base).max(axis=(1, 2, 3)) > 0)
+    assert changed[j:j + window].all()
+    assert not changed[:j].any() and not changed[j + window:].any()
+
+
+def test_sharing_a_key_value_head_is_repeating_it(float32_operands):
+    q, k, v = _qkv(40, seed=5)          # 2 key/value heads, 4 query heads each
+    shared = M.banded_attention(q, k, v, 8, 16)
+    each_its_own = M.banded_attention(
+        q.reshape(40, 8, 1, 16), jnp.repeat(k, 4, axis=1), jnp.repeat(v, 4, axis=1), 8, 16)
+    np.testing.assert_allclose(shared.reshape(40, 8, 16), each_its_own[:, :, 0], atol=1e-6)
+
+
+@pytest.mark.parametrize("window", [None, 8])
+def test_key_value_heads_at_once_do_not_change_the_result(float32_operands, window):
+    """One key/value head at a time, two (a remainder of one), all four."""
+    q, k, v = _qkv(40, seed=7, g=4, r=2)
+    one_at_a_time = M.banded_attention(q, k, v, window, 16)
+    a_block = 2 * 16 * (40 if window is None else 24)   # R x queries x widest keys
+    for scores_at_once in (2 * a_block, 3 * a_block, 10 ** 9):
+        np.testing.assert_allclose(
+            M.banded_attention(q, k, v, window, 16, scores_at_once), one_at_a_time, atol=1e-6)
+
+
+def test_blocks_outside_the_band_are_never_computed():
+    """Static facts of the blocking: at the published size three window
+    layers compute 15 blocks of keys each (the first block of queries one,
+    the others two) and the full layer 36, of 64 a square; the lane's
+    facts carry them beside the counted ones."""
+    windows = [1024, 1024, 1024, None]
+    assert M.attention_key_blocks(8192, windows, 1024) == (3 * 15 + 36, 4 * 64)
+    assert M.attention_key_blocks(8192, [1024], 1024) == (15, 64)
+    assert M.attention_key_blocks(8192, [None], 1024) == (36, 64)
+    # whatever the length, a window layer's products are two blocks wide
+    assert max(hi - klo for _, hi, klo in M._attention_spans(32768, 1024, 1024)) == 2048
+    assert max(hi - klo for _, hi, klo in M._attention_spans(8192, None, 1024)) == 8192
+    cfg = M.Mellum2Config(seq_len=64, n_train=2, n_val=1, vocab_rows=96, hidden_size=32,
+                          num_heads=4, num_kv_heads=2, head_dim=8, sliding_window=8,
+                          moe_intermediate_size=16, attn_query_block=16)
+    facts = M.make_mellum2_eval_fn(cfg).lane_facts
+    assert facts.counters == lane.LANE_COUNTERS + M.ATTENTION_COUNTERS
+    assert facts.traced_budget and facts.tokens_per_step == 64
+    # queries in 4 blocks: a window of 8 reaches one block back, 1 + 3 x 2;
+    # the full layer 1 + 2 + 3 + 4
+    assert M.attention_key_blocks(64, [8, 8, 8, None], 16) == (3 * 7 + 10, 4 * 16)
+
+
+# ----------------------------------------------------------------- rotary
+def test_yarn_against_numbers_worked_by_hand(reference, builders):
+    cfg = M.Mellum2Config()
+    # c(r) = 128 ln(8192 / (2 pi r)) / (2 ln 500000): c(32) = 18.08, c(1) = 34.98
+    assert M.yarn_correction_range(cfg) == (18, 35)
+    plain, one = M.rotary_inv_freq(cfg, "sliding")
+    yarn, factor = M.rotary_inv_freq(cfg, "full")
+    assert one == 1.0 and factor == pytest.approx(0.1 * math.log(16) + 1.0, abs=1e-12)
+    theta = 500000.0
+    np.testing.assert_allclose(plain[[0, 1, 63]], [1.0, theta ** (-2 / 128), theta ** (-126 / 128)])
+    # below the ramp the frequency is kept, above it divided by 16, on it mixed
+    np.testing.assert_allclose(yarn[:19], plain[:19])
+    np.testing.assert_allclose(yarn[35:], plain[35:] / 16)
+    ramp = (26 - 18) / (35 - 18)
+    assert yarn[26] == pytest.approx((1 - ramp) * plain[26] + ramp * plain[26] / 16)
+    # the configuration's file gives the same tables as the reference builds
+    published = json.load(open(os.path.join(BENCHMARK, "configs", "mellum2-sgd.json")))
+    built = builders["mellum2"](published)
+    assert built == cfg
+    for kind in ("sliding", "full"):
+        for ours, theirs in zip(M._rotary_tables(built, kind, 40),
+                                reference.rotary(published, kind, 40)):
+            np.testing.assert_allclose(ours, theirs, atol=1e-6)
+    assert reference.yarn_range(published["rope_parameters"]["full_attention"], 128) == (18, 35)
+    # the small configuration has a ramp too: c(4) = 1.6, c(1) = 4.03
+    assert M.yarn_correction_range(_cfg(builders, SMALL)) == (1, 5)
+
+
+def test_rotation_keeps_norms_and_depends_on_distance_alone():
+    cfg = M.Mellum2Config(head_dim=16)
+    cos, sin = M._rotary_tables(cfg, "sliding", 32)
+    x = jax.random.normal(jax.random.key(0), (16,))
+    rows = M._rotate(jnp.broadcast_to(x, (32, 1, 16)), cos, sin)[:, 0]
+    np.testing.assert_allclose(jnp.linalg.norm(rows, axis=-1), jnp.linalg.norm(x), rtol=1e-5)
+    # the same vector at positions i and j: the product depends on i - j
+    np.testing.assert_allclose(rows[3] @ rows[10], rows[20] @ rows[27], rtol=1e-4)
+
+
+# ---------------------------------------------------------- expert layer
+def test_shares_of_the_expert_layer_add_up_to_the_uncut_layer(
+        reference, builders, float32_operands):
+    """Four chips of four experts each against the reference's layer over
+    all sixteen (at the published size four shares of 16 make the 64): the
+    guide's tie of the chip's share to the model. No expert is shared, so
+    the shares' sum is the layer."""
+    config = small(cut={"experts_held": list(range(16))})
+    whole = reference.init_params(config, jax.random.key(2), 1.0)["l1"]
+    x = jax.random.normal(jax.random.key(3), (64, 64))
+    want = reference.experts(x, whole, config)
+    total, choices = 0.0, 0.0
+    for share in range(4):
+        held = list(range(4 * share, 4 * share + 4))
+        cfg = _cfg(builders, small(cut={"experts_held": held}))
+        p = dict(whole, **{k: whole[k][4 * share:4 * share + 4]
+                           for k in ("e_gate", "e_up", "e_down")})
+        y, counters = M.moe_held_experts(x, p, M._experts(cfg))
+        total = total + y
+        choices += float(counters[0])
+    assert choices == 64 * 4  # every token-choice fell on exactly one chip
+    np.testing.assert_allclose(total, want, atol=1e-5 * float(jnp.abs(want).max()))
+
+
+def _router_case(name, reference, builders):
+    """``(layer facts, leaves, the reference's experts(x, p))`` of a layer
+    whose first held expert nearly every token chooses."""
+    held = [5, 9, 40, 41]
+    if name == "softmax":
+        config = small(cut={"router_outputs": 64, "experts_held": held})
+        facts = M._experts(_cfg(builders, config))
+        p = reference.init_params(config, jax.random.key(4), 1.0)["l2"]
+        theirs = lambda x, p: reference.experts(x, p, config)
+    else:
+        config = kimi_small.small(cut={"router_outputs": 64, "experts_held": held})
+        facts = K._experts(builders["kimi"](config))
+        kimi_reference = load("reference", "kimi-linear-sgd.py")
+        p = kimi_reference.init_params(config, jax.random.key(4), 1.0)["l2"]
+        theirs = lambda x, p: kimi_reference.experts(x, p, config)
+    p = {k: v for k, v in p.items()
+         if k.startswith(("router", "shared_", "e_"))}
+    p["router"] = p["router"].at[:, jnp.asarray(held)].mul(0.0).at[
+        :, jnp.asarray(held[:3])].add(1.0)
+    return facts, p, theirs
+
+
+@pytest.mark.parametrize("router", ["softmax", "sigmoid"])
+def test_a_full_chip_drops_no_token_under_either_router(
+        reference, builders, float32_operands, router):
+    """The one expert layer (``lane.moe_held_experts``) as each model states
+    its router: softmax over the outputs, no bias, no scaling, no shared
+    expert (Mellum2); sigmoid, a bias, a scaling factor and a shared expert
+    (Kimi-Linear). Held experts that nearly every token chooses fill more
+    than one tile of the grouped product; the layer and its gradient still
+    are the reference's."""
+    facts, p, theirs = _router_case(router, reference, builders)
+    assert ("shared_gate" in p, "router_bias" in p, facts.scaling != 1.0) == (
+        (router == "sigmoid",) * 3)
+    x = jax.random.normal(jax.random.key(6), (64, 64)) + 0.5
+    rows = max(4 * 64 * 4 * 4 // 64, 8)
+    y, counters = lane.moe_held_experts(x, p, facts)
+    assert float(counters[0]) > 2 * rows  # more than two tiles' worth
+    assert float(counters[1]) > 1.2       # and unevenly
+    np.testing.assert_allclose(y, theirs(x, p), atol=2e-5)
+    ours = jax.grad(lambda p: (lane.moe_held_experts(x, p, facts)[0] ** 2).sum())(p)
+    want = jax.grad(lambda p: (theirs(x, p) ** 2).sum())(p)
+    for name in ("e_gate", "e_down", "router"):
+        np.testing.assert_allclose(
+            ours[name], want[name], atol=2e-4 * float(jnp.abs(want[name]).max()))
+
+
+def test_a_held_expert_that_every_token_chooses_drops_none(
+        reference, builders, float32_operands):
+    config = small(cut={"experts_held": [3, 7, 8, 12]})
+    cfg = _cfg(builders, config)
+    p = reference.init_params(config, jax.random.key(4), 1.0)["l0"]
+    # expert 7's logit is 30 for every token, far above every other's: it
+    # draws four times the even load of a held expert
+    x = jax.random.normal(jax.random.key(6), (64, 64)).at[:, 0].set(30.0)
+    p["router"] = p["router"].at[:, 7].set(0.0).at[0, 7].set(1.0)
+    y, counters = M.moe_held_experts(x, p, M._experts(cfg))
+    chosen = jax.lax.top_k(jax.nn.softmax(x @ p["router"], -1), 4)[1]
+    assert bool((chosen == 7).any(axis=1).all())
+    assert float(counters[0]) == float((jnp.isin(chosen, jnp.asarray([3, 7, 8, 12]))).sum())
+    np.testing.assert_allclose(y, reference.experts(x, p, config), atol=2e-5)
+
+
+def test_the_tile_of_the_grouped_product_is_capped():
+    """Four times the even load, and no more than ``_TILE_ROWS`` rows: the
+    Kimi-Linear lane's tile is what it was (4,096 rows at its published
+    size), the Mellum2 lane's 65,536 choices go in two tiles and not one."""
+    rows = lambda t, k, held, outputs: min(
+        t * k, max(min(4 * t * k * held // outputs, lane._TILE_ROWS), 8))
+    assert rows(4096, 8, 8, 256) == 4096 == 4 * 4096 * 8 * 8 // 256
+    assert rows(8192, 8, 16, 64) == 32768 == 2 * 8192 * 8 * 16 // 64
+    assert rows(64, 4, 4, 16) == 256 and rows(64, 4, 4, 64) == 64
+
+
+# ----------------------------------------------------- the configuration
+def test_configuration_file_keeps_every_published_width(builders):
+    config = json.load(open(os.path.join(BENCHMARK, "configs", "mellum2-sgd.json")))
+    entry = next(c for c in json.load(open(os.path.join(ROOT, "BENCHMARK.json")))["configs"]
+                 if c["name"] == "mellum2-sgd")
+    assert entry["source"] == config["source"] and len(entry["source"]) == 81
+    assert entry["reduced"] == config["reduced"] == [
+        "num_hidden_layers", "num_experts", "vocab_size", "layer_types", "mlp_layer_types"]
+    assert config["published"] == {
+        "num_hidden_layers": 28, "num_experts": 64, "vocab_size": 98304}
+    assert (config["num_hidden_layers"], config["num_experts"], config["vocab_size"]) == (
+        len(config["cut"]["layers"]), len(config["cut"]["experts_held"]), 98304 // 4)
+    assert config["layer_types"] == ["sliding_attention"] * 3 + ["full_attention"]
+    assert config["cut"]["router_outputs"] == 64 and config["cut"]["chips_sharing_a_layer"] == 4
+    # every width as published
+    assert [config[k] for k in (
+        "hidden_size", "num_attention_heads", "num_key_value_heads", "head_dim",
+        "moe_intermediate_size", "num_experts_per_tok", "sliding_window",
+        "intermediate_size", "rms_norm_eps")] == [
+            2304, 32, 4, 128, 896, 8, 1024, 7168, 1e-6]
+    assert config["rope_parameters"] == {
+        "full_attention": {
+            "rope_type": "yarn", "rope_theta": 500000, "factor": 16,
+            "original_max_position_embeddings": 8192, "beta_fast": 32, "beta_slow": 1,
+            "attention_factor": 1.2772588722239782},
+        "sliding_attention": {"rope_type": "default", "rope_theta": 500000}}
+    # the catalog's row, where the sandbox has it: every key but the cuts
+    catalog = "/opt/skills/guides/model-configs/architectures.jsonl"
+    if os.path.exists(catalog):
+        row = next(json.loads(line) for line in open(catalog) if "Mellum2-12B" in line)
+        assert row["source_url"] == config["source"]
+        for key, value in row["config"].items():
+            assert key in config["reduced"] or config[key] == value, key
+    assert set(config["assumed"]) >= {
+        "qk_norm", "rotary_pairing", "router", "window", "aux_loss", "mtp_head",
+        "init", "tokens", "optimizer", "data_seed"}
+    assert builders["mellum2"](config) == M.Mellum2Config()
+
+
+def test_lane_counts_agree_with_the_lane():
+    config = json.load(open(os.path.join(BENCHMARK, "configs", "mellum2-sgd.json")))
+    sys.path.insert(0, BENCHMARK)
+    try:
+        counts = load("lane_counts_mellum2.py")
+    finally:
+        sys.path.remove(BENCHMARK)
+    cfg = M.Mellum2Config()
+    n_params = lane._count_params(
+        lambda: M.init_mellum2_params(jax.random.key(0), cfg, 1.0))
+    assert n_params == counts.lane_params(config) == 595_153_152
+    layers, params = counts.layers_of(config), counts.part_params(config)
+    # all but the four layers' two norms and the final one
+    assert n_params - sum(params[p] * layers[p] for p in params) == 9 * 2304
+    # one lane fits the chip, two do not: a rung's lanes go in turn
+    assert 12 * n_params < M.mellum2_lane_bytes(cfg) < 16.9e9 < 2 * M.mellum2_lane_bytes(cfg)

@@ -1,4 +1,4 @@
-"""A bracket of the small Kimi-Linear lane (``kimi_small.py``) through
+"""A bracket of the small Mellum2 lane (``mellum2_small.py``) through
 ``FusedBOHB``, its lanes taken in turn, every reported loss held to the
 benchmark's plain reference. In a file of its own: the sweep's compilation
 is the suite's cost here, and the workers share out files."""
@@ -13,9 +13,10 @@ from hpbandster_tpu.obs.timeline import DEVICE_SCOPES, LANE_SCOPES
 from hpbandster_tpu.ops import fused
 from hpbandster_tpu.optimizers import FusedBOHB, sweep_phase_maps
 from hpbandster_tpu.optimizers.fused_bohb import _SWEEP_EXE_CACHE
-from hpbandster_tpu.workloads import kimi_linear as K
+from hpbandster_tpu.workloads import lane
+from hpbandster_tpu.workloads import mellum2 as M
 
-from kimi_small import SMALL, load
+from mellum2_small import SMALL, load
 
 
 @pytest.fixture(scope="module")
@@ -23,18 +24,18 @@ def swept():
     """One bracket of 9, 3, 1 lanes at 1, 3, 9 steps, float32 operands so
     that the reference can hold every loss tightly, one lane at a time."""
     sys.modules.setdefault("program", load("program.py"))
-    cfg = load("configs", "kimi-linear-sgd.py").lane_config(SMALL)._replace(
-        kda_chunk=16, kda_block=4, mla_heads_at_once=2)
+    cfg = load("configs", "mellum2-sgd.py").lane_config(SMALL)._replace(
+        attn_query_block=16)
     patch = pytest.MonkeyPatch()
-    patch.setattr(K.lane, "_OPERAND", jnp.float32)
-    eval_fn = K.make_kimi_linear_eval_fn(cfg, data_seed=SMALL["data_seed"])
+    patch.setattr(lane, "_OPERAND", jnp.float32)
+    eval_fn = M.make_mellum2_eval_fn(cfg, data_seed=SMALL["data_seed"])
     patch.setattr(fused, "_device_memory_bytes", lambda: eval_fn.lane_facts.bytes + 1)
     # the phase maps below are over every sweep executable the process
     # holds: this worker's earlier files have left theirs
     _SWEEP_EXE_CACHE.clear()
     try:
-        opt = FusedBOHB(configspace=K.kimi_linear_space(seed=11), eval_fn=eval_fn,
-                        run_id="kimi", min_budget=1, max_budget=9, eta=3, seed=11)
+        opt = FusedBOHB(configspace=M.mellum2_space(seed=11), eval_fn=eval_fn,
+                        run_id="mellum2", min_budget=1, max_budget=9, eta=3, seed=11)
         result = opt.run(n_iterations=1)
         yield opt, result
     finally:
@@ -43,7 +44,7 @@ def swept():
 
 def test_every_reported_loss_is_the_references(swept):
     _, result = swept
-    reference = load("reference", "kimi-linear-sgd.py")
+    reference = load("reference", "mellum2-sgd.py")
     by_lane = collections.defaultdict(dict)
     for run in result.get_all_runs():
         by_lane[run.config_id][int(run.budget)] = run.loss
@@ -60,7 +61,7 @@ def test_every_reported_loss_is_the_references(swept):
             assert reference.gap(reported[mark], w) < 2e-3, (hp, mark, reported[mark], w)
 
 
-def test_the_row_counts_the_lanes(swept):
+def test_the_row_counts_the_lanes_and_the_blocks(swept):
     opt, _ = swept
     row = opt.run_stats[-1]
     assert row["evaluations"] == 13 and row["lane_steps"] == 27
@@ -68,13 +69,16 @@ def test_the_row_counts_the_lanes(swept):
     # 4 of 16 experts held, top 4: a quarter of the choices if routing is even
     assert 0.1 < row["moe_held_choice_share"] < 0.5
     assert 1.0 <= row["moe_load_max_over_mean"] < 4.0
+    # static facts of the blocking: 64 tokens in blocks of 16, a window of 8
+    assert row["attn_key_blocks_computed"] == 3 * 7 + 10
+    assert row["attn_key_blocks_square"] == 4 * 16
 
 
 def test_the_lane_names_its_parts_inside_the_trainer(swept):
     (phases,) = sweep_phase_maps().values()
     (parts,) = sweep_phase_maps(LANE_SCOPES).values()
-    # every part of the list but the two mixers of the Mellum2 lane
-    assert set(parts.values()) == set(LANE_SCOPES) - {"lane.swa", "lane.gqa"}
+    assert set(parts.values()) == {
+        "lane.swa", "lane.gqa", "lane.moe", "lane.head", "lane.update"}
     assert {"hpb.train", "hpb.promote"} <= set(phases.values()) <= set(DEVICE_SCOPES)
     # a lane's part lies inside the evaluation: no instruction has a part
     # and a phase other than the trainer's two

@@ -100,3 +100,30 @@ def test_four_chip_mesh_sweep_compiles_with_pallas_scorer(v5e_devices):
     text = fn.lower(seed).compile().as_text()
     assert "tpu_custom_call" in text
     assert "all-reduce" in text or "all-gather" in text
+
+
+@pytest.mark.parametrize("kind, largest", [
+    # two key/value heads' scores of a block (2 x 8 x 1,024 x 2,048) lie
+    # under the projections' output, 8,192 x 5,120
+    ("sliding", 8192 * 5120),
+    # one key/value head's widest block: 8 x 1,024 x 8,192
+    ("full", 2 ** 26)])
+def test_banded_attention_compiles_for_v5e_at_the_published_size(
+        v5e_devices, kind, largest):
+    """The Mellum2 lane's mixer (``workloads/mellum2.py``) at 8,192 tokens,
+    forward pass only: the chip's compiler takes it, and its largest float32
+    array is a block's scores, not a square's (32 heads x 8,192^2 is 2^31
+    elements, one head's 2^26 in a window layer too)."""
+    import re
+
+    from hpbandster_tpu.workloads import mellum2 as M
+
+    one = SingleDeviceSharding(v5e_devices[0])
+    cfg = M.Mellum2Config()
+    leaves = {name: _sds(shape, jnp.float32, one)
+              for name, shape in M._layer_shapes(cfg).items()}
+    compiled = jax.jit(lambda x, p: M._attention(x, p, kind, cfg)).lower(
+        _sds((cfg.seq_len, cfg.hidden_size), jnp.float32, one), leaves).compile()
+    sizes = [int(np.prod([int(n) for n in dims.split(",")]))
+             for dims in re.findall(r"f32\[([\d,]+)\]", compiled.as_text())]
+    assert max(sizes) == largest
