@@ -360,8 +360,8 @@ def _mla(x, p, cfg: KimiLinearConfig):
 def _experts(cfg: KimiLinearConfig) -> lane.ExpertLayer:
     return lane.ExpertLayer(
         outputs=cfg.num_experts, top_k=cfg.num_experts_per_token,
-        held=cfg.experts_held, width=cfg.moe_intermediate_size,
-        score="sigmoid", scaling=cfg.routed_scaling_factor)
+        held=cfg.experts_held, score="sigmoid",
+        scaling=cfg.routed_scaling_factor)
 
 
 def moe_held_experts(x, p, cfg: KimiLinearConfig):
@@ -428,7 +428,8 @@ def make_kimi_linear_eval_fn(cfg: KimiLinearConfig = KimiLinearConfig(),
     lane, by the lanes' one trainer (``lane.make_lane_eval_fn``: budget is
     momentum-SGD steps of one ``seq_len``-token sequence);
     ``eval_fn.lane_facts`` states its footprint, its tokens a step and its
-    device counters (:data:`LANE_COUNTERS`)."""
+    counters: :data:`LANE_COUNTERS` from the device, then
+    ``lane.MOE_COUNTERS``, how the expert layer moves its rows."""
     init_key = jax.random.key(data_seed + 1)
     return lane.make_lane_eval_fn(
         init=lambda init_scale: init_kimi_linear_params(init_key, cfg, init_scale),
@@ -437,4 +438,5 @@ def make_kimi_linear_eval_fn(cfg: KimiLinearConfig = KimiLinearConfig(),
         eps=cfg.rms_norm_eps,
         data=make_token_dataset(jax.random.key(data_seed), cfg),
         choices_per_pass=cfg.seq_len * cfg.num_experts_per_token,
-        lane_bytes=kimi_linear_lane_bytes(cfg))
+        lane_bytes=kimi_linear_lane_bytes(cfg),
+        static_counters=lane.MOE_COUNTERS)

@@ -14,6 +14,7 @@ keeps its mixers, its configuration and its footprint.
 
 from __future__ import annotations
 
+import functools
 import zlib
 from typing import NamedTuple, Tuple
 
@@ -27,6 +28,7 @@ from hpbandster_tpu.space import ConfigurationSpace, UniformFloatHyperparameter
 __all__ = [
     "ExpertLayer",
     "LANE_COUNTERS",
+    "MOE_COUNTERS",
     "decode_lane_hparams",
     "lane_space",
     "make_lane_eval_fn",
@@ -163,8 +165,6 @@ class ExpertLayer(NamedTuple):
     top_k: int
     #: which of them this chip holds, in the order of the leaves' first axis
     held: Tuple[int, ...]
-    #: an expert's inner width
-    width: int
     #: ``"sigmoid"`` or ``"softmax"`` (over all the outputs, before the top k)
     score: str = "sigmoid"
     #: the chosen scores, renormalised to sum to one, times this
@@ -172,12 +172,136 @@ class ExpertLayer(NamedTuple):
 
 
 #: a tile of the grouped product is four times the even load, and no more
-#: rows than this. Measured on the chip at 65,536 token-choices of which a
-#: quarter are held (PR 32; a layer's forward and backward pass): tiles of
-#: 4,096 rows 97 ms, 8,192: 70, 16,384: 72, 32,768: 67, one of 65,536: 100.
-#: What a tile costs beside its products (a pass over the layer's output
-#: and over the experts' gradient) outweighs the rows of the closing group
-_TILE_ROWS = 32768
+#: rows than this. Measured on the chip at 65,536 token-choices (PR 33,
+#: the rows moved by gathers; a layer's forward and backward pass, with
+#: 16,087 / 17,450 choices held: just under and just over the even load of
+#: 16,384, which the lane's measured share of 25.1 % straddles): tiles of
+#: 4,096 rows 45 / 52 ms, 8,192: 41 / 52, 16,384: 39 / 58, 32,768: 58 / 58,
+#: one of 65,536: 87; a whole sweep of the Mellum2 lane 15.06 s at 4,096,
+#: 14.79 s at 8,192, 16.53 s at 32,768 (all read while the loop's buffers
+#: were still filled with zeros first: 0.2 s a sweep whatever the tile;
+#: 14.55 s at 8,192 without the fill). A reached tile pays for all its
+#: rows, the closing group's too (the last reached tile is half empty on
+#: average), and per tile for a pass over the experts' gradient and a
+#: change of layout of their weights (1.9 ms at these widths). With the
+#: scatter-adds of PR 32 a tile cost a pass over the layer's output besides,
+#: and 32,768 rows read best (67 ms against 70 at 8,192)
+_TILE_ROWS = 8192
+
+#: how the expert layer moves its rows, beside a lane's counted facts
+#: (``make_lane_eval_fn(static_counters=...)``): 1 where dispatch, combine
+#: and their transposes are gathers by the counting sort's two permutations
+MOE_COUNTERS = (("moe_combine_by_gather", 1),)
+
+
+def _tile_sizes(ends, lo, rows: int):
+    """The rows of the tile at ``lo`` by group: each held expert's (the
+    sorted rows up to ``ends[e]`` are experts ``0 .. e``'s), then the
+    closing group's: every row of a tile belongs to a group."""
+    return jnp.diff(jnp.clip(ends - lo, 0, rows), prepend=0, append=rows)
+
+
+def _tile_experts(xs, e_in, e_down, sizes):
+    """A tile's rows through their experts: gate and up side by side as one
+    grouped product, then down."""
+    dot = lambda a, w: jax.lax.ragged_dot(
+        a.astype(_OPERAND), w, sizes, preferred_element_type=jnp.float32)
+    gate_up = dot(xs, e_in)
+    f = e_down.shape[1]
+    return dot(jax.nn.silu(gate_up[:, :f]) * gate_up[:, f:], e_down)
+
+
+def _sum_of_choices(sorted_rows, place, held, top_k: int, weight=None):
+    """``out[t] = sum_j weight[t, j] * sorted_rows[place[t, j]]`` over the
+    held choices of token ``t`` (``weight`` one where there is none),
+    float32: a gather by ``place`` (choice -> sorted row). A ``where`` and
+    not a product by zero: what a row holds that is no held choice's (of
+    the closing group, or of a tile that was not reached) never reaches
+    the sum, whatever a diverged lane left there."""
+    picked = jnp.where(held[:, None], sorted_rows[place].astype(jnp.float32), 0.0)
+    picked = picked.reshape(-1, top_k, picked.shape[-1])
+    return (picked if weight is None else picked * weight[:, :, None]).sum(1)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
+def _routed(x, weight, e_in, e_down, order, place, ends, top_k, rows):
+    """``y[t] = sum_j weight[t, j] * E(x[t])`` over the held choices of
+    token ``t``: the rows move from token order to expert order and back by
+    gathers alone, in both passes. ``order`` (sorted row -> choice) and
+    ``place`` (choice -> sorted row) are the counting sort's permutation
+    and its inverse, so each movement's transpose is a gather by the other
+    one, and is written so here: what autodiff makes of a gather is a
+    scatter-add, which sorts its indices on the device."""
+    return _routed_forward(x, weight, e_in, e_down, order, place, ends, top_k, rows)[0]
+
+
+def _routed_forward(x, weight, e_in, e_down, order, place, ends, top_k, rows):
+    """``(y, what the backward rule keeps: the inputs)``. The rules name
+    their own scope: the backward one is traced where the layer's caller
+    has none."""
+    with jax.named_scope("lane.moe"):
+        d = x.shape[1]
+        xb = x.astype(_OPERAND)
+        n_held = ends[-1]
+
+        def tile(i, ys):
+            # dispatch: a gather by ``order``
+            take = jax.lax.dynamic_slice(order, (i * rows,), (rows,))
+            return jax.lax.dynamic_update_slice(ys, _tile_experts(
+                xb[take // top_k], e_in, e_down, _tile_sizes(ends, i * rows, rows)),
+                (i * rows, 0))
+
+        # what the tiles produce is the sorted rows, and only the tiles
+        # that a held choice reaches are computed. The rows start as the
+        # device finds them (no fill: a pass over 0.6 GB a layer at the
+        # Mellum2 lane's size): a reached tile writes all of its rows, and
+        # the combine reads no row of another
+        ys = jax.lax.fori_loop(
+            0, -(-n_held // rows), tile, jax.lax.empty((order.shape[0], d), jnp.float32))
+        # combine: a gather by ``place``, summed over the top k
+        y = _sum_of_choices(ys, place, place < n_held, top_k, weight)
+    return y, (x, weight, e_in, e_down, order, place, ends)
+
+
+def _routed_backward(top_k, rows, kept, dy):
+    """Each reached tile's rows are computed again from the layer's inputs
+    (what ``jax.checkpoint`` around a tile did) and differentiated; the
+    combine's transpose is a gather by ``order`` and the dispatch's a
+    gather by ``place``."""
+    x, weight, e_in, e_down, order, place, ends = kept
+    with jax.named_scope("lane.moe"):
+        d = x.shape[1]
+        xb = x.astype(_OPERAND)
+        n_held = ends[-1]
+        weight_of = weight.reshape(-1)
+
+        def tile(i, grads):
+            g_in, g_down, dxs, dweights = grads
+            take = jax.lax.dynamic_slice(order, (i * rows,), (rows,))
+            token = take // top_k
+            sizes = _tile_sizes(ends, i * rows, rows)
+            ys, pull = jax.vjp(
+                lambda xs, e_in, e_down: _tile_experts(xs, e_in, e_down, sizes),
+                xb[token], e_in, e_down)
+            dy_rows = dy[token]
+            t_xs, t_in, t_down = pull(dy_rows * weight_of[take][:, None])
+            return (g_in + t_in, g_down + t_down,
+                    jax.lax.dynamic_update_slice(dxs, t_xs, (i * rows, 0)),
+                    jax.lax.dynamic_update_slice(
+                        dweights, (dy_rows * ys).sum(-1), (i * rows,)))
+
+        g_in, g_down, dxs, dweights = jax.lax.fori_loop(
+            0, -(-n_held // rows), tile,
+            (jnp.zeros_like(e_in), jnp.zeros_like(e_down),
+             jax.lax.empty((order.shape[0], d), xb.dtype),
+             jax.lax.empty(order.shape, jnp.float32)))
+        held = place < n_held
+        dx = _sum_of_choices(dxs, place, held, top_k)
+        dweight = jnp.where(held, dweights[place], 0.0).reshape(weight.shape)
+    return dx, dweight, g_in, g_down, None, None, None
+
+
+_routed.defvjp(_routed_forward, _routed_backward)
 
 
 def moe_held_experts(x, p, layer: ExpertLayer):
@@ -193,16 +317,20 @@ def moe_held_experts(x, p, layer: ExpertLayer):
     by held expert (the others last) and the held ones go through
     ``jax.lax.ragged_dot``, one group an expert, in tiles of four times the
     even load (at most ``_TILE_ROWS`` rows), the rows that are not for this
-    chip in a last group of zero weights: a tile that no held choice
-    reaches is skipped (``lax.cond``), so the work follows the load and no
-    token is dropped whatever the load."""
-    t, d = x.shape
+    chip in a last group of zero weights: only the tiles that a held choice
+    reaches are computed, so the work follows the load and no token is
+    dropped whatever the load. Rows move to expert order and back by
+    gathers alone (:func:`_routed`)."""
+    t = x.shape[0]
     top_k, held = layer.top_k, len(layer.held)
     logits = jnp.matmul(x, p["router"], precision=_FLOAT32)
     s = (jax.nn.sigmoid(logits) if layer.score == "sigmoid"
          else jax.nn.softmax(logits, axis=-1))
     _, chosen = jax.lax.top_k(s + p["router_bias"] if "router_bias" in p else s, top_k)
-    s_chosen = jnp.take_along_axis(s, chosen, axis=1)
+    # the chosen scores by comparison and not by index: the transpose of
+    # ``take_along_axis`` is a scatter-add too
+    s_chosen = jnp.where(
+        chosen[:, :, None] == jnp.arange(layer.outputs), s[:, None, :], 0.0).sum(-1)
     weight = s_chosen / s_chosen.sum(-1, keepdims=True)
     if layer.scaling != 1.0:
         weight = weight * layer.scaling
@@ -217,14 +345,11 @@ def moe_held_experts(x, p, layer: ExpertLayer):
     place = (in_slot * (jnp.cumsum(in_slot, 0) - in_slot
                         + (jnp.cumsum(all_loads) - all_loads)[None, :])).sum(1)
     loads = all_loads[:held]
-    ends = jnp.cumsum(loads)
-    n_held = ends[-1]
     rows = min(t * top_k,
                max(min(4 * t * top_k * held // layer.outputs, _TILE_ROWS), 8))
     n_tiles = -(-t * top_k // rows)
     order = jnp.zeros((n_tiles * rows,), jnp.int32).at[place].set(
-        jnp.arange(t * top_k, dtype=jnp.int32))
-    weight = weight.reshape(-1)
+        jnp.arange(t * top_k, dtype=jnp.int32), unique_indices=True)
 
     # every row of a tile belongs to a group: after the held experts comes
     # one whose weights are zero and takes the rows that are not for this
@@ -232,35 +357,9 @@ def moe_held_experts(x, p, layer: ExpertLayer):
     # as it finds them, in the backward pass too, where a mask on its
     # output cannot reach.
     with_rest = lambda w: jnp.concatenate([w, jnp.zeros_like(w[:1])]).astype(_OPERAND)
-    xb = x.astype(_OPERAND)
     # gate and up side by side: one grouped product for the two
-    e_in = with_rest(jnp.concatenate([p["e_gate"], p["e_up"]], -1))
-    e_down = with_rest(p["e_down"])
-    f = layer.width
-
-    def tile(lo):
-        take = jax.lax.dynamic_slice(order, (lo,), (rows,))
-        token = take // top_k
-        sizes = jnp.clip(ends - lo, 0, rows) - jnp.clip(ends - loads - lo, 0, rows)
-        sizes = jnp.concatenate([sizes, rows - sizes.sum(keepdims=True)])
-        dot = lambda a, w: jax.lax.ragged_dot(
-            a.astype(_OPERAND), w, sizes, preferred_element_type=jnp.float32)
-        gate_up = dot(xb[token], e_in)
-        y = dot(jax.nn.silu(gate_up[:, :f]) * gate_up[:, f:], e_down)
-        return jnp.zeros((t, d), jnp.float32).at[token].add(y * weight[take][:, None])
-
-    # recomputed in the backward pass from the scan's own constants: what
-    # a ``cond`` keeps for its branches would be kept once per tile
-    @jax.checkpoint
-    def tile_if_reached(lo):
-        return jax.lax.cond(
-            lo < n_held, tile, lambda lo: jnp.zeros((t, d), jnp.float32), lo)
-
-    def add_tile(routed, lo):
-        return routed + tile_if_reached(lo), None
-
-    y, _ = jax.lax.scan(
-        add_tile, jnp.zeros((t, d), jnp.float32), jnp.arange(n_tiles) * rows)
+    y = _routed(x, weight, with_rest(jnp.concatenate([p["e_gate"], p["e_up"]], -1)),
+                with_rest(p["e_down"]), order, place, jnp.cumsum(loads), top_k, rows)
     if "shared_gate" in p:
         y = y + _swiglu(x, p["shared_gate"], p["shared_up"], p["shared_down"])
     load = loads.astype(jnp.float32)

@@ -120,7 +120,7 @@ class Mellum2Config(NamedTuple):
 def _experts(cfg: Mellum2Config) -> lane.ExpertLayer:
     return lane.ExpertLayer(
         outputs=cfg.router_outputs, top_k=cfg.num_experts_per_token,
-        held=cfg.experts_held, width=cfg.moe_intermediate_size, score="softmax")
+        held=cfg.experts_held, score="softmax")
 
 
 # ------------------------------------------------------------- parameters
@@ -347,7 +347,8 @@ def make_mellum2_eval_fn(cfg: Mellum2Config = Mellum2Config(), data_seed: int = 
     momentum-SGD steps of one ``seq_len``-token sequence);
     ``eval_fn.lane_facts`` states its footprint, its tokens a step and its
     counters: :data:`LANE_COUNTERS` from the device, then
-    :data:`ATTENTION_COUNTERS`, facts of the blocking."""
+    :data:`ATTENTION_COUNTERS`, facts of the blocking, and
+    ``lane.MOE_COUNTERS``, how the expert layer moves its rows."""
     init_key = jax.random.key(data_seed + 1)
     windows = [cfg.sliding_window if kind == "sliding" else None
                for kind in cfg.layer_kinds]
@@ -360,4 +361,4 @@ def make_mellum2_eval_fn(cfg: Mellum2Config = Mellum2Config(), data_seed: int = 
         data=make_token_dataset(jax.random.key(data_seed), cfg),
         choices_per_pass=cfg.seq_len * cfg.num_experts_per_token,
         lane_bytes=mellum2_lane_bytes(cfg),
-        static_counters=tuple(zip(ATTENTION_COUNTERS, blocks)))
+        static_counters=tuple(zip(ATTENTION_COUNTERS, blocks)) + lane.MOE_COUNTERS)

@@ -6,6 +6,7 @@ builder; the tests load both by path, as ``benchmark/run.py`` does."""
 import copy
 import importlib.util
 import os
+import re
 
 BENCHMARK = os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmark")
@@ -45,3 +46,42 @@ def load(*parts):
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
     return module
+
+
+def lower_forward_and_backward(layer, x, p):
+    """``layer(x, p) -> y`` and its pull-back of a cotangent shaped as
+    ``x``, lowered as one program."""
+    import jax
+
+    def both(x, p, dy):
+        y, pull = jax.vjp(layer, x, p)
+        return y, pull(dy)
+
+    return jax.jit(both).lower(x, p, x)
+
+
+def scatters_and_sorts(layer, x, p):
+    """``[(element type, "scatter" | "sort")]`` of the lowered forward and
+    backward pass of ``layer(x, p) -> (y, ...)``, one instruction a line in
+    the HLO dialect (``top_k`` is its own instruction there, no sort)."""
+    text = lower_forward_and_backward(
+        lambda x, p: layer(x, p)[0], x, p).as_text(dialect="hlo")
+    return re.findall(r"= (\w+)\[[^\]]*\]\S* (scatter|sort)\(", text)
+
+
+def check_the_moe_backward_rule_is_named(compiled_text, parts):
+    """The expert layer's backward rule is written by hand
+    (``lane._routed``): in a compiled sweep its instructions are charged to
+    ``lane.moe`` as the layer's others are (``parts``: instruction ->
+    lane part), and no gather of hidden-sized rows (64 wide at the small
+    sizes: the embedding's, the layer's five) is in no part."""
+    backward, gathers = [], []
+    for line in compiled_text.splitlines():
+        name = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = ", line)
+        if name and "transpose(jvp(lane.moe))" in line:
+            backward.append(name.group(1))
+        if name and re.search(r" = (f32|bf16)\[[\d,]+,64\]\S* gather\(", line):
+            gathers.append(name.group(1))
+    assert len(backward) > 20 and {parts.get(name) for name in backward} == {"lane.moe"}
+    assert len([g for g in gathers if parts.get(g) == "lane.moe"]) >= 5
+    assert all(g in parts for g in gathers)

@@ -7,7 +7,8 @@ does."""
 
 import copy
 
-from kimi_small import BENCHMARK, load  # noqa: F401
+from kimi_small import (  # noqa: F401
+    BENCHMARK, check_the_moe_backward_rule_is_named, load, scatters_and_sorts)
 
 SMALL = {
     "head_dim": 16, "hidden_size": 64, "intermediate_size": 128,

@@ -22,7 +22,7 @@ from hpbandster_tpu.optimizers import FusedBOHB
 from hpbandster_tpu.workloads import kimi_linear as K
 from hpbandster_tpu.workloads.toys import branin_from_vector, branin_space
 
-from kimi_small import BENCHMARK, SMALL, load, small
+from kimi_small import BENCHMARK, SMALL, load, scatters_and_sorts, small
 
 ROOT = os.path.dirname(BENCHMARK)
 
@@ -202,6 +202,55 @@ def test_a_full_chip_drops_no_token(reference, lane_config, float32_operands):
             ours[name], theirs[name], atol=2e-4 * float(jnp.abs(theirs[name]).max()))
 
 
+def _uneven_layer(reference):
+    """A layer whose held experts are one that every token chooses, one
+    that nobody chooses and two that few do: most choices are not held, so
+    the last tiles of the grouped product are never reached."""
+    held = [5, 9, 40, 41]
+    config = small(cut={"router_outputs": 64, "experts_held": held})
+    p = reference.init_params(config, jax.random.key(4), 1.0)["l2"]
+    p = {k: v for k, v in p.items() if k.startswith(("router", "shared_", "e_"))}
+    x = jax.random.normal(jax.random.key(6), (64, 64)).at[:, 0].set(3.0)
+    p["router"] = p["router"].at[0, 5].set(4.0).at[0, 9].set(-4.0)
+    return config, p, x
+
+
+def test_the_input_and_every_leaf_get_the_references_gradient_through_the_gathers(
+        reference, lane_config, float32_operands):
+    """Dispatch, combine and their transposes are gathers by the counting
+    sort's two permutations (``lane._routed``); the layer, the cotangent of
+    its input and of every leaf still are the reference's, which loops
+    over the experts under a mask, on a layer with an expert nobody
+    chooses, one every token chooses, choices that are not held and tiles
+    that are skipped."""
+    config, p, x = _uneven_layer(reference)
+    cfg = _cfg(lane_config, config)
+    chosen = jax.lax.top_k(jax.nn.sigmoid(x @ p["router"]) + p["router_bias"], 4)[1]
+    assert bool((chosen == 5).any(1).all()) and not bool((chosen == 9).any())
+    y, counters = K.moe_held_experts(x, p, cfg)
+    rows = max(4 * 64 * 4 * 4 // 64, 8)
+    assert 64 < float(counters[0]) <= 64 * 4 - 2 * rows   # two tiles of four not reached
+    np.testing.assert_allclose(y, reference.experts(x, p, config), atol=2e-5)
+    dy = jax.random.normal(jax.random.key(7), y.shape)
+    ours = jax.grad(lambda x, p: (K.moe_held_experts(x, p, cfg)[0] * dy).sum(), (0, 1))(x, p)
+    theirs = jax.grad(lambda x, p: (reference.experts(x, p, config) * dy).sum(), (0, 1))(x, p)
+    assert not bool(ours[1]["e_gate"][1].any())       # nobody chose expert 9
+    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(ours),
+                            jax.tree.leaves(theirs)):
+        np.testing.assert_allclose(
+            g, w, atol=2e-4 * max(float(jnp.abs(w).max()), 1e-6), err_msg=str(path))
+
+
+def test_the_expert_layer_lowers_to_no_float_scatter_and_no_sort(reference, lane_config):
+    """The sigmoid router with its bias and the shared expert beside the
+    routed ones, bfloat16 operands as the chip runs them: the one integer
+    scatter that writes the sorted order, no other scatter, no sort."""
+    config, p, x = _uneven_layer(reference)
+    cfg = _cfg(lane_config, config)
+    assert scatters_and_sorts(
+        lambda x, p: K.moe_held_experts(x, p, cfg), x, p) == [("s32", "scatter")]
+
+
 # ------------------------------------------------------- lanes in turn
 def _toy_eval(lane_bytes, traced_budget=False):
     def with_counters(vec, budget):
@@ -307,5 +356,6 @@ def test_lane_counts_agree_with_the_lane(reference):
     assert n_params - sum(params[p] * layers[p] for p in params) == 11 * 2304
     facts = K.make_kimi_linear_eval_fn(
         K.KimiLinearConfig(seq_len=64, n_train=2, n_val=1)).lane_facts
-    assert facts.counters == K.LANE_COUNTERS and facts.tokens_per_step == 64
+    assert facts.counters == K.LANE_COUNTERS + ("moe_combine_by_gather",)
+    assert facts.tokens_per_step == 64
     assert 12 * n_params < K.kimi_linear_lane_bytes(K.KimiLinearConfig()) < 16.9e9

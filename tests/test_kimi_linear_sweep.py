@@ -9,13 +9,14 @@ import sys
 import jax.numpy as jnp
 import pytest
 
+from hpbandster_tpu import obs
 from hpbandster_tpu.obs.timeline import DEVICE_SCOPES, LANE_SCOPES
 from hpbandster_tpu.ops import fused
 from hpbandster_tpu.optimizers import FusedBOHB, sweep_phase_maps
 from hpbandster_tpu.optimizers.fused_bohb import _SWEEP_EXE_CACHE
 from hpbandster_tpu.workloads import kimi_linear as K
 
-from kimi_small import SMALL, load
+from kimi_small import SMALL, check_the_moe_backward_rule_is_named, load
 
 
 @pytest.fixture(scope="module")
@@ -68,6 +69,11 @@ def test_the_row_counts_the_lanes(swept):
     # 4 of 16 experts held, top 4: a quarter of the choices if routing is even
     assert 0.1 < row["moe_held_choice_share"] < 0.5
     assert 1.0 <= row["moe_load_max_over_mean"] < 4.0
+    # how the expert layer moves its rows: by gathers, in both passes
+    assert row["moe_combine_by_gather"] == 1
+    gauges = obs.get_metrics().snapshot()["gauges"]
+    assert gauges["sweep.lane.moe_combine_by_gather"] == 1.0
+    assert gauges["sweep.lane.lane_steps"] == 27
 
 
 def test_the_lane_names_its_parts_inside_the_trainer(swept):
@@ -80,3 +86,4 @@ def test_the_lane_names_its_parts_inside_the_trainer(swept):
     # and a phase other than the trainer's two
     inside = {phases.get(name) for name in parts}
     assert inside <= {"hpb.train", "hpb.validate"}
+    check_the_moe_backward_rule_is_named(swept[0].last_executable.as_text(), parts)

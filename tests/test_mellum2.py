@@ -25,7 +25,7 @@ from hpbandster_tpu.workloads import lane
 from hpbandster_tpu.workloads import mellum2 as M
 
 import kimi_small
-from mellum2_small import BENCHMARK, SMALL, load, small
+from mellum2_small import BENCHMARK, SMALL, load, scatters_and_sorts, small
 
 ROOT = os.path.dirname(BENCHMARK)
 
@@ -218,7 +218,8 @@ def test_blocks_outside_the_band_are_never_computed():
                           num_heads=4, num_kv_heads=2, head_dim=8, sliding_window=8,
                           moe_intermediate_size=16, attn_query_block=16)
     facts = M.make_mellum2_eval_fn(cfg).lane_facts
-    assert facts.counters == lane.LANE_COUNTERS + M.ATTENTION_COUNTERS
+    assert facts.counters == (lane.LANE_COUNTERS + M.ATTENTION_COUNTERS
+                              + tuple(name for name, _ in lane.MOE_COUNTERS))
     assert facts.traced_budget and facts.tokens_per_step == 64
     # queries in 4 blocks: a window of 8 reaches one block back, 1 + 3 x 2;
     # the full layer 1 + 2 + 3 + 4
@@ -353,12 +354,91 @@ def test_a_held_expert_that_every_token_chooses_drops_none(
 def test_the_tile_of_the_grouped_product_is_capped():
     """Four times the even load, and no more than ``_TILE_ROWS`` rows: the
     Kimi-Linear lane's tile is what it was (4,096 rows at its published
-    size), the Mellum2 lane's 65,536 choices go in two tiles and not one."""
+    size), the Mellum2 lane's 65,536 choices go in eight tiles of half the
+    even load (PR 33: two of 32,768 rows until the rows moved by gathers)."""
     rows = lambda t, k, held, outputs: min(
         t * k, max(min(4 * t * k * held // outputs, lane._TILE_ROWS), 8))
     assert rows(4096, 8, 8, 256) == 4096 == 4 * 4096 * 8 * 8 // 256
-    assert rows(8192, 8, 16, 64) == 32768 == 2 * 8192 * 8 * 16 // 64
+    assert rows(8192, 8, 16, 64) == 8192 == 8192 * 8 * 16 // 64 // 2
     assert rows(64, 4, 4, 16) == 256 and rows(64, 4, 4, 64) == 64
+
+
+# ------------------------------------------- rows moved by gathers alone
+def _sorted_by_hand(rows):
+    """Sixteen tokens' top 2 over four held experts and "not here" (slot
+    4), sorted here by ``argsort``: expert 0 nobody chooses, expert 1
+    every token chooses, a quarter of the choices are not held. The 24
+    held choices reach three tiles of 8 rows and not the fourth, two of 12
+    and not the third (36 rows for 32 choices), and the one of 32."""
+    t, k, held = 16, 2, 4
+    slot = np.stack([np.ones(t, np.int32), np.asarray([2, 3, 4, 4] * 4, np.int32)],
+                    axis=1).reshape(-1)
+    order = np.argsort(slot, kind="stable").astype(np.int32)
+    place = np.argsort(order, kind="stable").astype(np.int32)
+    loads = np.bincount(slot, minlength=held + 1)[:held].astype(np.int32)
+    assert loads.tolist() == [0, 16, 4, 4]
+    order = np.concatenate([order, np.zeros(-(-t * k // rows) * rows - t * k, np.int32)])
+    return jnp.asarray(order), jnp.asarray(place), jnp.asarray(np.cumsum(loads))
+
+
+@pytest.mark.parametrize("rows", [8, 12, 32])
+def test_dispatch_and_combine_and_their_transposes_are_the_plain_indexing_forms(
+        float32_operands, rows):
+    """``lane._routed`` (dispatch a gather by ``order``, combine a gather
+    by ``place``, each one's transpose a gather by the other permutation)
+    against ``x[token]`` and ``.at[token].add`` over every tile, skipped or
+    not, differentiated by ``jax.vjp``: the layer's rows and the cotangent
+    of the input, of the weights of the choices and of both grouped
+    products' weights."""
+    t, k, d, f = 16, 2, 8, 4
+    order, place, ends = _sorted_by_hand(rows)
+    keys = jax.random.split(jax.random.key(5), 5)
+    with_rest = lambda w: jnp.concatenate([w, jnp.zeros_like(w[:1])])
+    args = (jax.random.normal(keys[0], (t, d)),
+            jax.random.uniform(keys[1], (t, k)),
+            with_rest(jax.random.normal(keys[2], (4, d, 2 * f))),
+            with_rest(jax.random.normal(keys[3], (4, f, d))))
+
+    def plain(x, weight, e_in, e_down):
+        y = jnp.zeros_like(x)
+        for lo in range(0, order.shape[0], rows):
+            take = order[lo:lo + rows]
+            token = take // k
+            rows_out = lane._tile_experts(
+                x[token], e_in, e_down, lane._tile_sizes(ends, lo, rows))
+            y = y.at[token].add(rows_out * weight.reshape(-1)[take][:, None])
+        return y
+
+    ours = lambda *a: lane._routed(*a, order, place, ends, k, rows)
+    y, pull = jax.vjp(ours, *args)
+    want, want_pull = jax.vjp(plain, *args)
+    assert float(jnp.abs(want).max()) > 1.0
+    np.testing.assert_allclose(y, want, atol=1e-5 * float(jnp.abs(want).max()))
+    dy = jax.random.normal(keys[4], (t, d))
+    for name, g, w in zip(("x", "weight", "e_in", "e_down"), pull(dy), want_pull(dy)):
+        assert g.shape == w.shape and g.dtype == w.dtype, name
+        np.testing.assert_allclose(
+            g, w, atol=1e-5 * float(jnp.abs(w).max()), err_msg=name)
+    # the expert nobody chooses and the closing group learn nothing
+    g_in = pull(dy)[2]
+    assert not bool(g_in[0].any()) and not bool(g_in[-1].any()) and bool(g_in[1].any())
+
+
+@pytest.mark.parametrize("router", ["softmax", "sigmoid"])
+def test_the_expert_layer_lowers_to_no_float_scatter_and_no_sort(
+        reference, builders, router):
+    """A scatter-add sorts its indices on the chip, and autodiff makes one
+    of every gather: the lowered forward and backward pass of the layer
+    holds the one integer scatter that writes ``order`` and nothing else
+    that scatters or sorts, under either router (bfloat16 operands, as the
+    chip runs it)."""
+    facts, p, _ = _router_case(router, reference, builders)
+    x = jax.random.normal(jax.random.key(6), (64, 64)) + 0.5
+    assert scatters_and_sorts(
+        lambda x, p: lane.moe_held_experts(x, p, facts), x, p) == [("s32", "scatter")]
+    # what the same reading shows of the plain indexing form
+    plain = lambda x, p: (jnp.zeros_like(x).at[jnp.arange(64) // 2].add(x[::-1]),)
+    assert ("f32", "scatter") in scatters_and_sorts(plain, x, p)
 
 
 # ----------------------------------------------------- the configuration

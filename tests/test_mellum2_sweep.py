@@ -9,6 +9,7 @@ import sys
 import jax.numpy as jnp
 import pytest
 
+from hpbandster_tpu import obs
 from hpbandster_tpu.obs.timeline import DEVICE_SCOPES, LANE_SCOPES
 from hpbandster_tpu.ops import fused
 from hpbandster_tpu.optimizers import FusedBOHB, sweep_phase_maps
@@ -16,7 +17,7 @@ from hpbandster_tpu.optimizers.fused_bohb import _SWEEP_EXE_CACHE
 from hpbandster_tpu.workloads import lane
 from hpbandster_tpu.workloads import mellum2 as M
 
-from mellum2_small import SMALL, load
+from mellum2_small import SMALL, check_the_moe_backward_rule_is_named, load
 
 
 @pytest.fixture(scope="module")
@@ -72,6 +73,11 @@ def test_the_row_counts_the_lanes_and_the_blocks(swept):
     # static facts of the blocking: 64 tokens in blocks of 16, a window of 8
     assert row["attn_key_blocks_computed"] == 3 * 7 + 10
     assert row["attn_key_blocks_square"] == 4 * 16
+    # how the expert layer moves its rows: by gathers, in both passes
+    assert row["moe_combine_by_gather"] == 1
+    gauges = obs.get_metrics().snapshot()["gauges"]
+    assert gauges["sweep.lane.moe_combine_by_gather"] == 1.0
+    assert gauges["sweep.lane.lane_steps"] == 27
 
 
 def test_the_lane_names_its_parts_inside_the_trainer(swept):
@@ -84,3 +90,4 @@ def test_the_lane_names_its_parts_inside_the_trainer(swept):
     # and a phase other than the trainer's two
     inside = {phases.get(name) for name in parts}
     assert inside <= {"hpb.train", "hpb.validate"}
+    check_the_moe_backward_rule_is_named(swept[0].last_executable.as_text(), parts)

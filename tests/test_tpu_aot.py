@@ -127,3 +127,39 @@ def test_banded_attention_compiles_for_v5e_at_the_published_size(
     sizes = [int(np.prod([int(n) for n in dims.split(",")]))
              for dims in re.findall(r"f32\[([\d,]+)\]", compiled.as_text())]
     assert max(sizes) == largest
+
+
+@pytest.mark.parametrize("score", ["softmax", "sigmoid"])
+def test_the_expert_layer_compiles_for_v5e_to_gathers_and_unfilled_buffers(
+        v5e_devices, score):
+    """The lanes' expert layer (``workloads/lane.py`` ``moe_held_experts``),
+    forward and backward pass as the chip's compiler leaves them: the rows
+    move by gathers (no floating-point scatter, whose indices the chip
+    sorts; the one integer scatter writes the sorted order), nothing sorts
+    but the router's top k, and the loops over tiles start from buffers the
+    device hands over unfilled (``AllocateBuffer``: on the CPU
+    ``jax.lax.empty`` is zeros, so only this compile can tell)."""
+    import re
+
+    from hpbandster_tpu.workloads import lane
+    from kimi_small import lower_forward_and_backward
+
+    one = SingleDeviceSharding(v5e_devices[0])
+    t, d, f, outputs, held, k = 512, 256, 128, 16, 4, 2
+    facts = lane.ExpertLayer(
+        outputs=outputs, top_k=k, held=tuple(range(held)), score=score)
+    p = {"router": (d, outputs), "e_gate": (held, d, f), "e_up": (held, d, f),
+         "e_down": (held, f, d)}
+    p = {name: _sds(shape, jnp.float32, one) for name, shape in p.items()}
+    x = _sds((t, d), jnp.float32, one)
+
+    text = lower_forward_and_backward(
+        lambda x, p: lane.moe_held_experts(x, p, facts)[0], x, p).compile().as_text()
+    moved = re.findall(r"= \(?(\w+)\[([\d,]*)\]\S* (scatter|gather)\(", text)
+    assert [m for m in moved if m[2] == "scatter"] == [("s32", str(t * k), "scatter")]
+    rows = "%d,%d" % (t * k, d)
+    assert {("f32", rows, "gather"), ("bf16", rows, "gather")} <= set(moved)
+    assert all("top_k" in line for line in text.splitlines() if " sort(" in line)
+    unfilled = re.findall(
+        r"= (\w+\[[\d,]*\])\S* custom-call\(\), custom_call_target=\"AllocateBuffer\"", text)
+    assert {"f32[%s]" % rows, "bf16[%s]" % rows} <= set(unfilled)
