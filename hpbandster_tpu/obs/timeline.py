@@ -128,7 +128,7 @@ DEVICE_SCOPES = (
 
 #: a second closed list, of the parts of one lane: ``jax.named_scope``
 #: names that a workload whose lane has layers of several kinds
-#: (``workloads/kimi_linear.py``, ``workloads/mellum2.py``) sets *inside* ``hpb.train`` and
+#: (``workloads/kimi_linear.py``, ``workloads/mellum2.py``, ``workloads/ouro.py``) sets *inside* ``hpb.train`` and
 #: ``hpb.validate``. The two families do not see each other:
 #: ``device_phase_map(compiled)`` reads the phases above,
 #: ``device_phase_map(compiled, LANE_SCOPES)`` these
@@ -141,6 +141,8 @@ LANE_SCOPES = (
     "lane.dense_ffn",  # a dense feed-forward layer
     "lane.head",       # embedding, final norm, head, loss
     "lane.update",     # the optimizer's step
+    "lane.exit",       # a looped model's exits: gate, exit distribution, the losses' weighted sum, entropy
+    "lane.accumulate", # adding a visit's gradient into the sum of a leaf that several visits share
 )
 
 #: attribution priority when concurrent spans overlap (lower = wins):

@@ -76,3 +76,10 @@ from hpbandster_tpu.workloads.mellum2 import (  # noqa: F401
     make_mellum2_eval_fn,
     mellum2_space,
 )
+from hpbandster_tpu.workloads.ouro import (  # noqa: F401
+    OuroConfig,
+    init_ouro_params,
+    make_ouro_eval_fn,
+    ouro_lane_bytes,
+    ouro_space,
+)

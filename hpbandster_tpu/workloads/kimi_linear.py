@@ -392,14 +392,22 @@ def _layers(cfg: KimiLinearConfig):
 def kimi_linear_loss(params: dict, tokens: jax.Array, cfg: KimiLinearConfig):
     """``tokens`` i32[T + 1] -> ``(mean next-token cross-entropy over the
     vocabulary slice, counters f32[n_layers, 2])``."""
-    return lane._loss(params, tokens, _layers(cfg), cfg.rms_norm_eps)
+    layers = _layers(cfg)
+    loss, (_, counters) = lane._loss(
+        params, tokens, lane.once_through(layers, counted=len(LANE_COUNTERS)),
+        lane.head_exit(len(layers), cfg.rms_norm_eps))
+    return loss, counters
 
 
 def kimi_linear_forward(params: dict, tokens: jax.Array, cfg: KimiLinearConfig):
     """:func:`kimi_linear_loss` with nothing kept for a gradient but the
     input of every layer: ``(loss, counters, [h_0 .. h_L])``, what the
     lanes' trainer takes the gradient from (``lane._forward``)."""
-    return lane._forward(params, tokens, _layers(cfg), cfg.rms_norm_eps)
+    layers = _layers(cfg)
+    loss, (counters, _), hs, _ = lane._forward(
+        params, tokens, lane.once_through(layers, counted=len(LANE_COUNTERS)),
+        lane.head_exit(len(layers), cfg.rms_norm_eps))
+    return loss, counters, hs
 
 
 # ------------------------------------------------------------- evaluation
@@ -431,12 +439,14 @@ def make_kimi_linear_eval_fn(cfg: KimiLinearConfig = KimiLinearConfig(),
     counters: :data:`LANE_COUNTERS` from the device, then
     ``lane.MOE_COUNTERS``, how the expert layer moves its rows."""
     init_key = jax.random.key(data_seed + 1)
+    layers = _layers(cfg)
     return lane.make_lane_eval_fn(
         init=lambda init_scale: init_kimi_linear_params(init_key, cfg, init_scale),
-        layers=_layers(cfg),
-        moe_layers=[ffn == "moe" for _, ffn in cfg.layer_kinds],
-        eps=cfg.rms_norm_eps,
+        visits=lane.once_through(layers, counted=len(LANE_COUNTERS)),
+        exits=lane.head_exit(len(layers), cfg.rms_norm_eps),
         data=make_token_dataset(jax.random.key(data_seed), cfg),
-        choices_per_pass=cfg.seq_len * cfg.num_experts_per_token,
         lane_bytes=kimi_linear_lane_bytes(cfg),
+        counted=lane.expert_counters(
+            [ffn == "moe" for _, ffn in cfg.layer_kinds],
+            cfg.seq_len * cfg.num_experts_per_token),
         static_counters=lane.MOE_COUNTERS)

@@ -1,22 +1,27 @@
 """What the lanes of published blocks share (``kimi_linear.py``,
-``mellum2.py``): a lane is one chip's share of a model of layers, trained
+``mellum2.py``, ``ouro.py``): a lane is one chip's share of a model of layers, trained
 from the configuration's key by momentum SGD, one sequence a step.
 
 Here live the search space and its decoding, the rule for a matrix
 product's operands, the norm and the SwiGLU, the draw of a leaf, the
-synthetic tokens, embedding and head, **the one expert layer**
-(:func:`moe_held_experts`: what differs between routers is stated as
-:class:`ExpertLayer`, a bias and a shared expert by their leaves) and **the
-one lane trainer** (:func:`make_lane_eval_fn`: a model hands it its init
-and its layers). A model's own file
-keeps its mixers, its configuration and its footprint.
+synthetic tokens, embedding and head, rotary positions and **the one causal
+softmax attention** (:func:`banded_attention`, under :func:`attention_mixer`),
+**the one expert layer** (:func:`moe_held_experts`: what differs between
+routers is stated as :class:`ExpertLayer`, a bias and a shared expert by
+their leaves) and **the one lane trainer** (:func:`make_lane_eval_fn`: a
+model hands it its init, its **visits** (which leaf each step of a pass
+takes through which function: a plain stack visits every layer once, a
+looped model the same layers several times over) and its **exits**
+(:class:`Exits`: where a pass's state is read, the loss that is trained and
+the loss that is reported), and what it counts (:class:`Counted`)). A
+model's own file keeps its mixers, its configuration and its footprint.
 """
 
 from __future__ import annotations
 
 import functools
 import zlib
-from typing import NamedTuple, Tuple
+from typing import Any, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -26,20 +31,30 @@ from hpbandster_tpu.ops.fused import LaneFacts
 from hpbandster_tpu.space import ConfigurationSpace, UniformFloatHyperparameter
 
 __all__ = [
+    "Counted",
+    "Exits",
     "ExpertLayer",
     "LANE_COUNTERS",
     "MOE_COUNTERS",
+    "Visit",
+    "attention_key_blocks",
+    "attention_mixer",
+    "banded_attention",
     "decode_lane_hparams",
+    "expert_counters",
+    "head_exit",
     "lane_space",
     "make_lane_eval_fn",
     "make_token_dataset",
     "moe_held_experts",
+    "once_through",
 ]
 
-#: what an evaluation counts on the device beside its loss, over the
-#: expert layers of its validation pass: the share of token-choices that
-#: fell on held experts (``held / outputs`` if routing is even) and the
-#: fullest held expert's load over the mean held load
+#: what a lane with expert layers counts on the device beside its loss
+#: (:func:`expert_counters`), over the expert layers of its held-out
+#: passes: the share of token-choices that fell on held experts (``held /
+#: outputs`` if routing is even) and the fullest held expert's load over
+#: the mean held load
 LANE_COUNTERS = ("moe_held_choice_share", "moe_load_max_over_mean")
 
 
@@ -111,6 +126,123 @@ def _swiglu(x, w_gate, w_up, w_down):
     return _mm(jax.nn.silu(gate) * up, w_down)
 
 
+# ---------------------------------------------------- positions, attention
+def _rotary_tables(inv_freq, factor, t: int):
+    """``(cos, sin)`` f32[T, head_dim] from a head's ``inv_freq``
+    f64[head_dim / 2]: channel ``i`` turns with ``i + d / 2`` (the
+    rotate-half form), angles in float32, both tables times ``factor``."""
+    angle = (jnp.arange(t, dtype=jnp.float32)[:, None]
+             * jnp.asarray(inv_freq, jnp.float32)[None, :])
+    angle = jnp.concatenate([angle, angle], axis=-1)
+    return jnp.cos(angle) * factor, jnp.sin(angle) * factor
+
+
+def _rotate(x, cos, sin):
+    """``x`` f32[T, ..., d] turned by its position's angles."""
+    half = x.shape[-1] // 2
+    turned = jnp.concatenate([-x[..., half:], x[..., :half]], axis=-1)
+    lift = (slice(None),) + (None,) * (x.ndim - 2)
+    return x * cos[lift] + turned * sin[lift]
+
+
+#: how many scores (a block's queries x their heads x its keys, of several
+#: key/value heads together where they fit) are alive at once. Measured on
+#: the chip at the Mellum2 lane's published size (PR 32; a layer's mixer,
+#: forward and backward): a window layer, whose block is 2^24 scores, takes
+#: two key/value heads at a time (0.0400 s and 1,065 device events, against
+#: 0.0422 s and 2,564 one at a time); a full layer, whose widest block alone
+#: is 2^26, one (0.073 s; two at a time 0.601 s: a batch of wide score
+#: matrices leaves the softmax's fast path)
+_SCORES_AT_ONCE = 2 ** 25
+
+
+def _attention_spans(t: int, window: Optional[int], block: int):
+    """``[(lo, hi, klo)]``: queries ``lo:hi`` go against keys ``klo:hi``,
+    ``klo`` the start of the block of keys that holds the first position
+    query ``lo`` may see."""
+    first = lambda lo: 0 if window is None else max(0, lo - window + 1) // block * block
+    return [(lo, min(lo + block, t), first(lo)) for lo in range(0, t, block)]
+
+
+def attention_key_blocks(t: int, windows, block: int):
+    """``(computed, square)``: blocks of ``block x block`` scores that
+    :func:`banded_attention` computes over layers of the given ``windows``
+    (a number or ``None`` each), and those of their full squares."""
+    per_side = -(-t // block)
+    computed = sum(-(-(hi - klo) // block)
+                   for window in windows
+                   for _, hi, klo in _attention_spans(t, window, block))
+    return computed, per_side * per_side * len(windows)
+
+
+def banded_attention(q, k, v, window: Optional[int], block: int,
+                     scores_at_once: int = _SCORES_AT_ONCE):
+    """Causal softmax attention with grouped queries, banded where
+    ``window`` is a number: position ``i`` sees ``j <= i`` and, with a
+    window, ``i - j < window``. ``q`` f32[T, G, R, d] (query head ``g * R +
+    r`` on key/value head ``g``), ``k, v`` f32[T, G, d]; returns f32[T, G,
+    R, d]. Scores are ``q . k / sqrt(d)``, the softmax float32.
+
+    Queries go in blocks of ``block``, each against the keys of
+    :func:`_attention_spans` and no others; a key/value head is not
+    repeated for its ``R`` query heads (one product over them); the groups
+    go as many at a time as keep a block's scores under ``scores_at_once``
+    (one where not even two do) and a block's scores are recomputed in the
+    backward pass, so what is alive at once is one block's scores of those
+    groups."""
+    t, d = q.shape[0], q.shape[-1]
+    scale = d ** -0.5
+    spans = _attention_spans(t, window, block)
+
+    def one_block(qb, kb, vb, lo, klo):
+        # rows are (query, head) pairs: the R query heads of a key/value
+        # head share one product, and everything between the two products
+        # is two-dimensional (a [block, R, keys] array of scores costs the
+        # chip eight times the time: its softmax leaves the fast path)
+        nq, r, nk = qb.shape[0], qb.shape[1], kb.shape[0]
+        s = _mm(qb.reshape(nq * r, d), kb.T) * scale
+        at = lo + jnp.arange(nq * r)[:, None] // r
+        key = klo + jnp.arange(nk)[None, :]
+        seen = key <= at
+        if window is not None:
+            seen = seen & (at - key < window)
+        att = jax.nn.softmax(jnp.where(seen, s, -1e30), axis=-1)
+        return _mm(att, vb).reshape(nq, r, d)
+
+    # a Python loop over the blocks, each traced where it starts: a
+    # ``lax.scan`` over a window layer's seven blocks of one shape built
+    # 12 s sooner on the chip's host and ran a sweep 8 % slower (PR 32)
+    one_block = jax.checkpoint(one_block, static_argnums=(3, 4))
+
+    def one_group(qkv):
+        qg, kg, vg = qkv
+        return jnp.concatenate([
+            one_block(qg[lo:hi], kg[klo:hi], vg[klo:hi], lo, klo)
+            for lo, hi, klo in spans], axis=0)
+
+    groups = (q.swapaxes(0, 1), k.swapaxes(0, 1), v.swapaxes(0, 1))
+    widest = max((hi - lo) * (hi - klo) for lo, hi, klo in spans) * q.shape[2]
+    at_once = scores_at_once // widest
+    out = jax.lax.map(one_group, groups, batch_size=at_once if at_once > 1 else None)
+    return out.swapaxes(0, 1)
+
+
+def attention_mixer(x, p, *, kv_heads: int, heads_per_kv: int, head_dim: int,
+                    inv_freq, factor: float, window: Optional[int], block: int):
+    """An attention layer's mixer, from the norm's output to ``W_o``: the
+    projections ``wq``, ``wk``, ``wv`` as one product, queries and keys
+    turned by the rotary tables of ``inv_freq`` and ``factor``,
+    :func:`banded_attention`, ``wo``."""
+    t = x.shape[0]
+    g, r, d = kv_heads, heads_per_kv, head_dim
+    q, k, v = _mm_beside(x, p["wq"], p["wk"], p["wv"])
+    cos, sin = _rotary_tables(inv_freq, factor, t)
+    q = _rotate(q.reshape(t, g, r, d), cos, sin)
+    k = _rotate(k.reshape(t, g, d), cos, sin)
+    out = banded_attention(q, k, v.reshape(t, g, d), window, block)
+    return _mm(out.reshape(t, g * r * d), p["wo"])
+
+
 # --------------------------------------------------------------- parameters
 def _init_leaf(key, name: str, shape, init_scale):
     """One leaf from the key and its own name, so that the draw does not
@@ -118,11 +250,11 @@ def _init_leaf(key, name: str, shape, init_scale):
     convolutions) are ``init_scale / sqrt(fan_in) * N(0, 1)``, the
     embedding ``init_scale * N(0, 1)`` (a lookup's fan-in is one: a
     smaller embedding only has the first norm multiply its gradient up),
-    norm weights one, a balancing bias zero."""
+    norm weights one, a bias (``*_bias``) zero."""
     leaf = name.rsplit("/", 1)[-1]
     if leaf.startswith("norm") or leaf.endswith("_norm"):
         return jnp.ones(shape, jnp.float32)
-    if leaf == "router_bias":
+    if leaf.endswith("_bias"):
         return jnp.zeros(shape, jnp.float32)
     # drawn as a matrix and folded: the same numbers in the same order (the
     # chip's compiler takes fourteen seconds over a three-dimensional draw)
@@ -380,33 +512,195 @@ def _head_loss(h, norm_f, head, tokens, eps):
         return -jnp.take_along_axis(logp, tokens[1:, None], axis=-1)[:, 0].mean()
 
 
-def _loss(params: dict, tokens, layers, eps):
-    """``tokens`` i32[T + 1] -> ``(mean next-token cross-entropy over the
-    vocabulary slice, counters f32[n_layers, 2])`` through ``layers``, one
-    ``(h, p_i) -> (h, counters f32[2])`` a layer; for ``jax.grad``: each
-    layer's inside is recomputed in the backward pass."""
-    h = _embed(params, tokens)
-    counters = []
-    for i, layer in enumerate(layers):
-        h, c = jax.checkpoint(layer)(h, params[f"l{i}"])
-        counters.append(c)
-    return _head_loss(h, params["norm_f"], params["head"], tokens, eps), jnp.stack(counters)
+# ------------------------------------------------------- visits and exits
+class Exits(NamedTuple):
+    """Where a model's passes end, and what is read there. A state is
+    ``h`` f32[T, D] after so many visits; ``trained`` and ``reported`` take
+    ``(states, leaves, tokens)``: the states of ``after`` in order, the
+    parameters of ``leaves`` in order, ``tokens`` i32[T + 1]."""
+
+    #: exit ``e`` reads the state after ``after[e]`` visits; the last one
+    #: reads the last visit's
+    after: Tuple[int, ...]
+    #: the top-level leaves the exits read and no visit does: differentiated
+    #: once a step through all the exits together, and stepped then
+    leaves: Tuple[str, ...]
+    #: ``-> the loss a step descends``
+    trained: Any
+    #: ``-> (the loss a lane reports, f32[counted] or None)``
+    reported: Any
+    #: how many numbers ``reported`` counts beside its loss
+    counted: int = 0
 
 
-def _forward(params: dict, tokens, layers, eps):
-    """:func:`_loss` with nothing kept for a gradient but the input of
-    every layer: ``(loss, counters, [h_0 .. h_L])``. An evaluation takes the
-    gradient from these by the chain rule, a layer at a time, each layer's
-    inside computed again (what ``jax.grad`` does with ``jax.checkpoint``
-    around every layer), so that a pass that needs no gradient (a held-out
-    sequence) is the same trace as one that does."""
+def head_exit(n_visits: int, eps) -> Exits:
+    """One exit after the last visit: final norm, head, mean next-token
+    cross-entropy, trained and reported alike."""
+    def loss(states, leaves, tokens):
+        (h,), (norm_f, head) = states, leaves
+        return _head_loss(h, norm_f, head, tokens, eps)
+
+    return Exits(after=(n_visits,), leaves=("norm_f", "head"), trained=loss,
+                 reported=lambda *args: (loss(*args), None))
+
+
+class Visit(NamedTuple):
+    """One step of a pass: ``params[leaf]`` through ``through(h, p) -> (h,
+    what the visit counts, f32[counted], or None)``."""
+
+    leaf: str
+    through: Any
+    #: more than one: the leaves of ``params[leaf]`` are stacked ``[times,
+    #: ...]`` and this is ``times`` visits in a row, slice ``i`` the
+    #: ``i``-th's weights, traced and compiled as ONE loop's body (layers of
+    #: one shape); what it counts is the sum over the slices
+    times: int = 1
+    #: how many numbers ``through`` counts beside the state (an expert
+    #: layer: the two of :func:`moe_held_experts`); 0: it hands back None
+    counted: int = 0
+    #: ``slices(params[leaf]) -> take``, ``take(i) -> the i-th visit's
+    #: parameters``: how a loop's visits get their weights out of the stacked
+    #: leaves where that is more than ``x[i]`` (a looped model casts the
+    #: stacked matrices to the products' operand type once and takes every
+    #: slice out under the scope of the part that reads it: the chip's
+    #: compiler makes such casts of whole stacks out of a loop's casts of
+    #: slices whatever the program says, and what it makes itself carries
+    #: no scope); called once before the loop, forward and backward
+    slices: Any = None
+
+
+def once_through(layers, counted: int = 0):
+    """The visits of a plain stack: layer ``i`` takes ``l<i>``, once."""
+    return tuple(Visit(f"l{i}", layer, counted=counted) for i, layer in enumerate(layers))
+
+
+def _slices(visit: Visit, p):
+    if visit.slices is not None:
+        return visit.slices(p)
+    return lambda i: jax.tree.map(lambda x: x[i], p)
+
+
+def _visit_forward(visit: Visit, h, p, through=None):
+    """``-> (h after the visit, what it counts, what its backward pass
+    reads: the input, of every slice where the visit is a loop)``."""
+    through = through or visit.through
+    if visit.times == 1:
+        return through(h, p) + (h,)
+    take = _slices(visit, p)
+
+    def one(h, i):
+        out, c = through(h, take(i))
+        return out, (c, h)
+
+    h, (counters, inputs) = jax.lax.scan(one, h, jnp.arange(visit.times))
+    return h, jax.tree.map(lambda c: c.sum(0), counters), inputs
+
+
+def _visit_backward(visit: Visit, one, dh, kept, p, written, read=None, *, scope):
+    """The backward pass of a visit through the leaf's parameters ``p``,
+    slice by slice from the last where it is a loop: ``one(pull, dh,
+    written_i, read_i) -> (dh, written_i anew)``, ``pull(dh) -> (dh, the
+    gradient of the slice's parameters)`` the visit's pull-back with its
+    inside computed again from what :func:`_visit_forward` kept. ``written``
+    and ``read`` are trees of the leaf's shape (stacked where the visit is a
+    loop); a slice is rewritten where it lies, in the loop's carry, so that
+    what is alive beside the trees is one slice's gradient; taking a slice
+    out and putting it back is charged to ``scope``, the part that ``one``
+    rewrites them for. ``-> (dh, written anew)``."""
+    def pull(h, q):
+        return lambda dh: jax.vjp(lambda h, q: visit.through(h, q)[0], h, q)[1](dh)
+
+    if visit.times == 1:
+        return one(pull(kept, p), dh, written, read)
+    take = _slices(visit, p)
+
+    def from_the_last(k, carry):
+        dh, written = carry
+        i = visit.times - 1 - k
+        at = lambda tree: jax.tree.map(lambda x: x[i], tree)
+        with jax.named_scope(scope):
+            written_i, read_i = at(written), at(read)
+        dh, anew = one(pull(kept[i], take(i)), dh, written_i, read_i)
+        with jax.named_scope(scope):
+            return dh, jax.tree.map(
+                lambda x, slice_i: x.at[i].set(slice_i), written, anew)
+
+    return jax.lax.fori_loop(0, visit.times, from_the_last, (dh, written))
+
+
+class Counted(NamedTuple):
+    """What a lane counts on the device beside its loss, over its held-out
+    passes: the counters are its model's."""
+
+    names: Tuple[str, ...]
+    #: ``(what the visits count, f32[visits that count, counted] in their
+    #: order or None where none does, what the exits count, f32[k] or None,
+    #: both summed over the held-out passes, n_val) -> one number a name``
+    reduce: Any
+    #: of the visits that count, those ``reduce`` is given (a flag each);
+    #: None: all of them
+    visits: Optional[Tuple[bool, ...]] = None
+
+
+def expert_counters(moe_visits, choices_per_pass: int) -> Counted:
+    """:data:`LANE_COUNTERS` over the visits that have experts
+    (``moe_visits``: a flag a visit), their counters those of
+    :func:`moe_held_experts`; ``choices_per_pass`` the token-choices of one
+    expert layer a pass."""
+    def reduce(moe, _, n_val):
+        return [
+            moe[:, 0].sum() / max(moe.shape[0] * n_val * choices_per_pass, 1),
+            moe[:, 1].mean() / n_val if moe.shape[0] else jnp.float32(0.0),
+        ]
+
+    return Counted(LANE_COUNTERS, reduce, tuple(bool(m) for m in moe_visits))
+
+
+def _stacked(visits, counters):
+    """What the visits of a pass count, those that count anything."""
+    if not any(visit.counted for visit in visits):
+        return None
+    return jnp.stack([c for visit, c in zip(visits, counters) if visit.counted])
+
+
+def _exit_states(hs, exits: Exits):
+    return tuple(hs[n] for n in exits.after)
+
+
+def _loss(params: dict, tokens, visits, exits: Exits):
+    """``tokens`` i32[T + 1] -> ``(the trained loss, ((the reported loss,
+    the exits' counters), the visits' counters, :func:`_stacked`))``
+    through ``visits`` (a :class:`Visit` each); for ``jax.grad``: each
+    visit's inside is recomputed in the backward pass, and a leaf that
+    several visits take gets the sum of their gradients from the
+    differentiation itself."""
     hs, counters = [_embed(params, tokens)], []
-    for i, layer in enumerate(layers):
-        h, c = layer(hs[-1], params[f"l{i}"])
+    for visit in visits:
+        h, c, _ = _visit_forward(
+            visit, hs[-1], params[visit.leaf], jax.checkpoint(visit.through))
         hs.append(h)
         counters.append(c)
-    loss = _head_loss(hs[-1], params["norm_f"], params["head"], tokens, eps)
-    return loss, jnp.stack(counters), hs
+    at = (_exit_states(hs, exits), tuple(params[n] for n in exits.leaves), tokens)
+    return exits.trained(*at), (exits.reported(*at), _stacked(visits, counters))
+
+
+def _forward(params: dict, tokens, visits, exits: Exits):
+    """:func:`_loss` with nothing kept for a gradient but the input of
+    every visit: ``(the reported loss, (the visits' counters, the
+    exits'), [h_0 .. h_V], what each visit's backward pass reads)``. An
+    evaluation takes the gradient from these by the chain rule, a visit at
+    a time, each visit's inside computed again (what ``jax.grad`` does with
+    ``jax.checkpoint`` around every visit), so that a pass that needs no
+    gradient (a held-out sequence) is the same trace as one that does."""
+    hs, counters, kept = [_embed(params, tokens)], [], []
+    for visit in visits:
+        h, c, inputs = _visit_forward(visit, hs[-1], params[visit.leaf])
+        hs.append(h)
+        counters.append(c)
+        kept.append(inputs)
+    loss, counted = exits.reported(
+        _exit_states(hs, exits), tuple(params[n] for n in exits.leaves), tokens)
+    return loss, (_stacked(visits, counters), counted), hs, kept
 
 
 # ------------------------------------------------------------------- data
@@ -430,108 +724,224 @@ def make_token_dataset(key: jax.Array, cfg):
 
 
 # ------------------------------------------------------------- evaluation
-def make_lane_eval_fn(*, init, layers, moe_layers, eps, data,
-                      choices_per_pass: int, lane_bytes: int,
-                      static_counters=()):
-    """``eval_fn(config_vec, budget) -> held-out cross-entropy`` of a lane
-    of layers, handed to ``FusedBOHB(eval_fn=...)`` as
-    ``make_transformer_eval_fn``'s is. Budget is momentum-SGD steps of one
-    sequence; step ``t`` trains on sequence ``t mod n_train``; ``v <- m v +
-    g + wd p; p <- p - lr v``. ``eval_fn.lane_facts`` states the lane's
-    footprint, its tokens a step and its device counters
-    (:data:`LANE_COUNTERS`, then ``static_counters``), which the rung's
-    evaluation (``ops.fused.eval_lanes``) reads.
+def _pass(p: dict, v: dict, seq, training, visits, exits: Exits, update):
+    """One sequence through the lane: ``-> (p, v, the reported loss, the
+    counters of :func:`_forward`)``. Every pass runs the forward trace; where
+    ``training`` (a traced flag) it runs the backward one and ``update(p_leaf,
+    v_leaf, g_leaf) -> (p_leaf, v_leaf)`` besides, else ``p`` and ``v`` come
+    back as they are. The backward pass and the update are a ``lax.cond`` a
+    visit (the exits, each visit from the last, the embedding): parameters
+    and momentum through ONE ``cond`` would be held twice over, and of the
+    leaves that one visit takes all the gradients would be alive at once.
+
+    A visit's gradient is taken from the visit alone, its inside computed
+    again; a leaf that several visits share (layers run several times with
+    one set of weights) is **stepped once, where the backward pass leaves
+    its first visit**, by the sum of its visits' gradients: until then
+    every earlier visit still differentiates through the weights as they
+    were. The exits' leaves are differentiated once, through all the exits
+    together, and each exit's cotangent enters the chain where its state
+    was read."""
+    n_visits = len(visits)
+    first_visit = {}
+    for j, visit in enumerate(visits):
+        first_visit.setdefault(visit.leaf, j)
+    shared = {visit.leaf for j, visit in enumerate(visits) if first_visit[visit.leaf] != j}
+    loss, counters, hs, kept = _forward(p, seq, visits, exits)
+    p, v = dict(p), dict(v)
+
+    def if_training(step, *state, out=None):
+        """``step(*state)`` in a training pass; else ``state`` as it is (of
+        it the places ``out``, where ``step`` hands back fewer)."""
+        same = (lambda *same: same) if out is None else (
+            lambda *same: tuple(same[i] for i in out))
+        return jax.lax.cond(training, step, same, *state)
+
+    n_exits = len(exits.after)
+
+    def exits_step(*state):
+        pv = state[n_exits:]
+        d_states, grads = jax.grad(exits.trained, argnums=(0, 1))(
+            _exit_states(hs, exits), pv[::2], seq)
+        stepped = [update(*pvg) for pvg in zip(pv[::2], pv[1::2], grads)]
+        return tuple(d_states) + tuple(x for pair in stepped for x in pair)
+
+    stepped = if_training(
+        exits_step, *[jnp.zeros_like(h) for h in _exit_states(hs, exits)],
+        *[x for leaf in exits.leaves for x in (p[leaf], v[leaf])])
+    # exit ``e`` read the state after ``exits.after[e]`` visits
+    exit_after = {n: e for e, n in enumerate(exits.after)}
+    for i, leaf in enumerate(exits.leaves):
+        p[leaf], v[leaf] = stepped[n_exits + 2 * i], stepped[n_exits + 2 * i + 1]
+    dh = stepped[exit_after[n_visits]]
+    # the sum of a shared leaf's gradients over its visits so far: the
+    # leaf's last visit, the backward pass's first, writes it whole (a fill
+    # of zeros to add to is a pass over the leaf that lands in no part)
+    sums = {}
+    last_visit = {visit.leaf: j for j, visit in enumerate(visits)}
+
+    def unwritten(tree):
+        return jax.tree.map(lambda x: jax.lax.empty(x.shape, x.dtype), tree)
+
+    def add(total, g):
+        with jax.named_scope("lane.accumulate"):
+            return jax.tree.map(jnp.add, total, g)
+
+    def start_it(pull, dh, total, _):
+        """A shared leaf's last visit: its gradient starts the sum."""
+        dh, g = pull(dh)
+        with jax.named_scope("lane.accumulate"):
+            return dh, jax.tree.map(lambda t, gi: gi.astype(t.dtype), total, g)
+
+    def step_it(pull, dh, pv, total):
+        """A leaf's first visit: its gradient is whole, step it."""
+        dh, g = pull(dh)
+        return dh, update(*pv, g if total is None else add(total, g))
+
+    def sum_it(pull, dh, total, _):
+        """A later visit of a shared leaf: into the sum."""
+        dh, g = pull(dh)
+        return dh, add(total, g)
+
+    for j in reversed(range(n_visits)):
+        visit, leaf = visits[j], visits[j].leaf
+        back = functools.partial(
+            _visit_backward, visit, kept=kept[j],
+            scope="lane.accumulate" if first_visit[leaf] != j else "lane.update")
+        if leaf in shared and last_visit[leaf] == j:
+            dh, sums[leaf] = jax.lax.cond(
+                training,
+                lambda dh, pl, back=back: back(start_it, dh, p=pl, written=unwritten(pl)),
+                lambda dh, pl: (dh, unwritten(pl)), dh, p[leaf])
+        elif first_visit[leaf] != j:
+            dh, sums[leaf] = if_training(
+                lambda dh, total, pl, back=back: back(sum_it, dh, p=pl, written=total),
+                dh, sums[leaf], p[leaf], out=(0, 1))
+        elif leaf in shared:
+            def visit_step(dh, total, pl, vl, back=back):
+                dh, (pl, vl) = back(step_it, dh, p=pl, written=(pl, vl), read=total)
+                return dh, pl, vl
+
+            dh, p[leaf], v[leaf] = if_training(
+                visit_step, dh, sums.pop(leaf), p[leaf], v[leaf], out=(0, 2, 3))
+        else:
+            def visit_step(dh, pl, vl, back=back):
+                dh, (pl, vl) = back(step_it, dh, p=pl, written=(pl, vl))
+                return dh, pl, vl
+
+            dh, p[leaf], v[leaf] = if_training(visit_step, dh, p[leaf], v[leaf])
+        if j in exit_after:
+            # an earlier exit read the state this visit took in
+            dh = dh + stepped[exit_after[j]]
+
+    def embed_step(pe, ve):
+        with jax.named_scope("lane.head"):
+            g = jnp.zeros_like(pe).at[seq[:-1]].add(dh)
+        return update(pe, ve, g)
+
+    p["embed"], v["embed"] = if_training(embed_step, p["embed"], v["embed"])
+    return p, v, loss, counters
+
+
+def make_lane_eval_fn(*, init, visits, exits: Exits, data, lane_bytes: int,
+                      counted: Counted, static_counters=()):
+    """``eval_fn(config_vec, budget) -> held-out loss`` of a lane, handed to
+    ``FusedBOHB(eval_fn=...)`` as ``make_transformer_eval_fn``'s is. Budget
+    is momentum-SGD steps of one sequence; step ``t`` trains on sequence
+    ``t mod n_train``; ``v <- m v + g + wd p; p <- p - lr v``.
+    ``eval_fn.lane_facts`` states the lane's footprint, its tokens a step and
+    its device counters (``counted.names``, then ``static_counters``), which
+    the rung's evaluation (``ops.fused.eval_lanes``) reads;
+    ``eval_fn.change(config_vec, budget)`` is what the steps changed, the
+    parameters after them less the parameters at initialisation.
 
     The model is what it hands over:
 
-    * ``init(init_scale) -> params``: ``embed``, ``norm_f``, ``head`` and
-      one ``l<i>`` a layer, from the configuration's key;
-    * ``layers``: one ``(h, p_i) -> (h, counters f32[2])`` a layer, the
-      counters those of :func:`moe_held_experts` (zeros where it has no
-      experts); a pass is :func:`_forward` through them, and a layer's
-      gradient is taken from the layer alone, its inside computed again;
-    * ``moe_layers``: per layer, whether it has experts; ``eps`` the final
-      norm's; ``data = (train, val)`` of :func:`make_token_dataset`;
-      ``choices_per_pass`` the token-choices of one expert layer a pass;
-      ``lane_bytes`` the device bytes a lane needs while it trains;
+    * ``init(init_scale) -> params``: ``embed``, what the visits and the
+      exits name, from the configuration's key;
+    * ``visits``: a :class:`Visit` each, a pass in order. A plain stack
+      visits every layer once (:func:`once_through`); a leaf may be visited
+      several times a pass (layers run several times with one set of
+      weights): :func:`_pass` sums its gradient over the visits and steps it
+      once;
+    * ``exits`` (:class:`Exits`): where a pass's state is read, the loss
+      that is trained and the loss that is reported (a plain stack:
+      :func:`head_exit`);
+    * ``data = (train, val)`` of :func:`make_token_dataset`; ``lane_bytes``
+      the device bytes a lane needs while it trains;
+    * ``counted`` (:class:`Counted`): what the lane counts on the device
+      over its held-out passes (a lane with experts:
+      :func:`expert_counters`);
     * ``static_counters``: ``((name, value), ...)`` facts of how the lane
       is computed that ride beside the counted ones."""
     train, val = data
     n_train, n_val = train.shape[0], val.shape[0]
-    n_layers = len(layers)
+    if exits.after[-1] != len(visits) or list(exits.after) != sorted(set(exits.after)):
+        raise ValueError("the last exit reads the last visit's state; exits in order")
 
-    def with_counters(vec: jax.Array, budget):
+    counting = [visit.counted for visit in visits if visit.counted]
+    if len(set(counting)) > 1:
+        raise ValueError("the visits that count anything count as many numbers each")
+
+    def passes(vec: jax.Array, budget, held_out: int):
+        """``budget`` training passes, then ``held_out`` held-out ones: ``->
+        (the parameters at initialisation, after the passes, the held-out
+        losses' sum, what the held-out passes counted)``."""
         lr, momentum, wd, init_scale = decode_lane_hparams(vec)
         params = init(init_scale)
         steps = jnp.asarray(budget, jnp.float32).round().astype(jnp.int32)
 
-        # ONE loop over the training sequences and then the held-out ones:
-        # each pass runs the forward trace, and a training pass the backward
-        # one and the update besides, so the program holds the forward pass
-        # once for both (a quarter of its compilation). The backward pass
-        # and the update are a ``lax.cond`` a layer (the head, each layer
-        # from the last, the embedding): parameters and momentum through
-        # ONE ``cond`` would be held twice over, and all the gradient's
-        # leaves would be alive at once.
         def update(p, v, g):
             with jax.named_scope("lane.update"):
                 v = jax.tree.map(lambda vi, gi, pi: momentum * vi + gi + wd * pi, v, g, p)
                 return jax.tree.map(lambda pi, vi: pi - lr * vi, p, v), v
 
+        # ONE loop over the training sequences and then the held-out ones:
+        # each pass runs the forward trace, and a training pass the backward
+        # one and the update besides (:func:`_pass`), so the program holds
+        # the forward pass once for both (a quarter of its compilation)
         def one_pass(t, carry):
             p, v, held_loss, held_counters = carry
             training = t < steps
             seq = jnp.where(training, train[t % n_train],
                             val[jnp.clip(t - steps, 0, n_val - 1)])
-            loss, counters, hs = _forward(p, seq, layers, eps)
-            p, v = dict(p), dict(v)
-
-            def if_training(step, *state):
-                return jax.lax.cond(training, step, lambda *same: same, *state)
-
-            def head_step(dh, pn, vn, ph, vh):
-                dh, g_norm, g_head = jax.grad(_head_loss, argnums=(0, 1, 2))(
-                    hs[-1], pn, ph, seq, eps)
-                return (dh,) + update(pn, vn, g_norm) + update(ph, vh, g_head)
-
-            dh, p["norm_f"], v["norm_f"], p["head"], v["head"] = if_training(
-                head_step, jnp.zeros_like(hs[-1]), p["norm_f"], v["norm_f"],
-                p["head"], v["head"])
-            for i in reversed(range(n_layers)):
-                def layer_step(dh, pl, vl, i=i):
-                    _, pull = jax.vjp(lambda h, q: layers[i](h, q)[0], hs[i], pl)
-                    dh, g = pull(dh)
-                    return (dh,) + update(pl, vl, g)
-
-                dh, p[f"l{i}"], v[f"l{i}"] = if_training(
-                    layer_step, dh, p[f"l{i}"], v[f"l{i}"])
-
-            def embed_step(pe, ve):
-                with jax.named_scope("lane.head"):
-                    g = jnp.zeros_like(pe).at[seq[:-1]].add(dh)
-                return update(pe, ve, g)
-
-            p["embed"], v["embed"] = if_training(embed_step, p["embed"], v["embed"])
+            p, v, loss, counters = _pass(p, v, seq, training, visits, exits, update)
             held = jnp.where(training, 0.0, 1.0)
-            return p, v, held_loss + held * loss, held_counters + held * counters
+            return p, v, held_loss + held * loss, jax.tree.map(
+                lambda total, c: total + held * c, held_counters, counters)
 
-        _, _, loss, counters = jax.lax.fori_loop(0, steps + n_val, one_pass, (
-            params, jax.tree.map(jnp.zeros_like, params), jnp.float32(0.0),
-            jnp.zeros((n_layers, 2), jnp.float32)))
+        after, _, loss, counters = jax.lax.fori_loop(
+            0, steps + held_out, one_pass, (
+                params, jax.tree.map(jnp.zeros_like, params), jnp.float32(0.0),
+                (jnp.zeros((len(counting), counting[0]), jnp.float32) if counting else None,
+                 jnp.zeros((exits.counted,), jnp.float32) if exits.counted else None)))
+        return params, after, loss, counters
+
+    def with_counters(vec: jax.Array, budget):
+        _, _, loss, (visit_counters, exit_counters) = passes(vec, budget, n_val)
         loss = loss / n_val
-        moe = counters[np.asarray(moe_layers, bool)]
+        if counted.visits is not None:
+            visit_counters = visit_counters[np.asarray(counted.visits, bool)]
         # a lane whose training diverged has no number for a loss: it
         # reports the worst one, infinity; NaN is the sweep's mask for a crash
-        return jnp.where(jnp.isnan(loss), jnp.inf, loss), jnp.stack([
-            moe[:, 0].sum() / max(moe.shape[0] * n_val * choices_per_pass, 1),
-            moe[:, 1].mean() / n_val if moe.shape[0] else jnp.float32(0.0),
-        ] + [jnp.float32(value) for _, value in static_counters])
+        return jnp.where(jnp.isnan(loss), jnp.inf, loss), jnp.stack(
+            list(counted.reduce(visit_counters, exit_counters, n_val))
+            + [jnp.float32(value) for _, value in static_counters])
+
+    def change(vec: jax.Array, budget):
+        """What ``budget`` steps change: the parameters after them less the
+        parameters at initialisation, leaf by leaf (a check of the trainer
+        against a reference reads it: a loss hardly tells a step that went
+        wrong from rounding, the step itself does)."""
+        params, after, _, _ = passes(vec, budget, 0)
+        return jax.tree.map(jnp.subtract, after, params)
 
     def eval_fn(vec: jax.Array, budget) -> jax.Array:
         return with_counters(vec, budget)[0]
 
     eval_fn.lane_facts = LaneFacts(
         bytes=lane_bytes, tokens_per_step=train.shape[1] - 1,
-        counters=LANE_COUNTERS + tuple(name for name, _ in static_counters),
+        counters=tuple(counted.names) + tuple(name for name, _ in static_counters),
         with_counters=with_counters, traced_budget=True)
+    eval_fn.change = change
     return eval_fn

@@ -45,7 +45,7 @@ it, no auxiliary loss, no multi-token-prediction head.
 from __future__ import annotations
 
 import math
-from typing import NamedTuple, Optional, Tuple
+from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -54,9 +54,12 @@ import numpy as np
 from hpbandster_tpu.workloads import lane
 from hpbandster_tpu.workloads.lane import (  # noqa: F401 - the lane's public names
     LANE_COUNTERS,
-    _mm,
-    _mm_beside,
+    _SCORES_AT_ONCE,
+    _attention_spans,
     _rms,
+    _rotate,
+    attention_key_blocks,
+    banded_attention,
     make_token_dataset,
     moe_held_experts,
 )
@@ -173,118 +176,21 @@ def yarn_correction_range(cfg: Mellum2Config):
 
 
 def _rotary_tables(cfg: Mellum2Config, kind: str, t: int):
-    """``(cos, sin)`` f32[T, head_dim]: channel ``i`` turns with ``i +
-    d / 2`` (the rotate-half form), angles in float32."""
-    inv_freq, factor = rotary_inv_freq(cfg, kind)
-    angle = (jnp.arange(t, dtype=jnp.float32)[:, None]
-             * jnp.asarray(inv_freq, jnp.float32)[None, :])
-    angle = jnp.concatenate([angle, angle], axis=-1)
-    return jnp.cos(angle) * factor, jnp.sin(angle) * factor
-
-
-def _rotate(x, cos, sin):
-    """``x`` f32[T, ..., d] turned by its position's angles."""
-    half = x.shape[-1] // 2
-    turned = jnp.concatenate([-x[..., half:], x[..., :half]], axis=-1)
-    lift = (slice(None),) + (None,) * (x.ndim - 2)
-    return x * cos[lift] + turned * sin[lift]
+    """``(cos, sin)`` f32[T, head_dim] of a layer of ``kind``."""
+    return lane._rotary_tables(*rotary_inv_freq(cfg, kind), t)
 
 
 # --------------------------------------------------------------- attention
-#: how many scores (a block's queries x their heads x its keys, of several
-#: key/value heads together where they fit) are alive at once. Measured on
-#: the chip at the published size (PR 32; a layer's mixer, forward and
-#: backward): a window layer, whose block is 2^24 scores, takes two key/value
-#: heads at a time (0.0400 s and 1,065 device events, against 0.0422 s and
-#: 2,564 one at a time); a full layer, whose widest block alone is 2^26, one
-#: (0.073 s; two at a time 0.601 s: a batch of wide score matrices leaves
-#: the softmax's fast path)
-_SCORES_AT_ONCE = 2 ** 25
-
-
-def _attention_spans(t: int, window: Optional[int], block: int):
-    """``[(lo, hi, klo)]``: queries ``lo:hi`` go against keys ``klo:hi``,
-    ``klo`` the start of the block of keys that holds the first position
-    query ``lo`` may see."""
-    first = lambda lo: 0 if window is None else max(0, lo - window + 1) // block * block
-    return [(lo, min(lo + block, t), first(lo)) for lo in range(0, t, block)]
-
-
-def attention_key_blocks(t: int, windows, block: int):
-    """``(computed, square)``: blocks of ``block x block`` scores that
-    :func:`banded_attention` computes over layers of the given ``windows``
-    (a number or ``None`` each), and those of their full squares."""
-    per_side = -(-t // block)
-    computed = sum(-(-(hi - klo) // block)
-                   for window in windows
-                   for _, hi, klo in _attention_spans(t, window, block))
-    return computed, per_side * per_side * len(windows)
-
-
-def banded_attention(q, k, v, window: Optional[int], block: int,
-                     scores_at_once: int = _SCORES_AT_ONCE):
-    """Causal softmax attention with grouped queries, banded where
-    ``window`` is a number: position ``i`` sees ``j <= i`` and, with a
-    window, ``i - j < window``. ``q`` f32[T, G, R, d] (query head ``g * R +
-    r`` on key/value head ``g``), ``k, v`` f32[T, G, d]; returns f32[T, G,
-    R, d]. Scores are ``q . k / sqrt(d)``, the softmax float32.
-
-    Queries go in blocks of ``block``, each against the keys of
-    :func:`_attention_spans` and no others; a key/value head is not
-    repeated for its ``R`` query heads (one product over them); the groups
-    go as many at a time as keep a block's scores under ``scores_at_once``
-    (one where not even two do) and a block's scores are recomputed in the
-    backward pass, so what is alive at once is one block's scores of those
-    groups."""
-    t, d = q.shape[0], q.shape[-1]
-    scale = d ** -0.5
-    spans = _attention_spans(t, window, block)
-
-    def one_block(qb, kb, vb, lo, klo):
-        # rows are (query, head) pairs: the R query heads of a key/value
-        # head share one product, and everything between the two products
-        # is two-dimensional (a [block, R, keys] array of scores costs the
-        # chip eight times the time: its softmax leaves the fast path)
-        nq, r, nk = qb.shape[0], qb.shape[1], kb.shape[0]
-        s = _mm(qb.reshape(nq * r, d), kb.T) * scale
-        at = lo + jnp.arange(nq * r)[:, None] // r
-        key = klo + jnp.arange(nk)[None, :]
-        seen = key <= at
-        if window is not None:
-            seen = seen & (at - key < window)
-        att = jax.nn.softmax(jnp.where(seen, s, -1e30), axis=-1)
-        return _mm(att, vb).reshape(nq, r, d)
-
-    # a Python loop over the blocks, each traced where it starts: a
-    # ``lax.scan`` over a window layer's seven blocks of one shape built
-    # 12 s sooner on the chip's host and ran a sweep 8 % slower (PR 32)
-    one_block = jax.checkpoint(one_block, static_argnums=(3, 4))
-
-    def one_group(qkv):
-        qg, kg, vg = qkv
-        return jnp.concatenate([
-            one_block(qg[lo:hi], kg[klo:hi], vg[klo:hi], lo, klo)
-            for lo, hi, klo in spans], axis=0)
-
-    groups = (q.swapaxes(0, 1), k.swapaxes(0, 1), v.swapaxes(0, 1))
-    widest = max((hi - lo) * (hi - klo) for lo, hi, klo in spans) * q.shape[2]
-    at_once = scores_at_once // widest
-    out = jax.lax.map(one_group, groups, batch_size=at_once if at_once > 1 else None)
-    return out.swapaxes(0, 1)
-
-
 def _attention(x, p, kind: str, cfg: Mellum2Config):
-    """A layer's mixer, from the norm's output to ``W_o``."""
-    t = x.shape[0]
-    g, r, d = cfg.num_kv_heads, cfg.num_heads // cfg.num_kv_heads, cfg.head_dim
-    q, k, v = _mm_beside(x, p["wq"], p["wk"], p["wv"])
-    cos, sin = _rotary_tables(cfg, kind, t)
-    q = _rotate(q.reshape(t, g, r, d), cos, sin)
-    k = _rotate(k.reshape(t, g, d), cos, sin)
-    out = banded_attention(
-        q, k, v.reshape(t, g, d),
-        cfg.sliding_window if kind == "sliding" else None, cfg.attn_query_block)
-    return _mm(out.reshape(t, g * r * d), p["wo"])
+    """A layer's mixer, from the norm's output to ``W_o``
+    (``lane.attention_mixer``: every lane's causal softmax attention)."""
+    inv_freq, factor = rotary_inv_freq(cfg, kind)
+    return lane.attention_mixer(
+        x, p, kv_heads=cfg.num_kv_heads,
+        heads_per_kv=cfg.num_heads // cfg.num_kv_heads, head_dim=cfg.head_dim,
+        inv_freq=inv_freq, factor=factor,
+        window=cfg.sliding_window if kind == "sliding" else None,
+        block=cfg.attn_query_block)
 
 
 #: the scope of a layer's mixer by its kind (``obs.timeline.LANE_SCOPES``)
@@ -308,14 +214,22 @@ def _layers(cfg: Mellum2Config):
 def mellum2_loss(params: dict, tokens: jax.Array, cfg: Mellum2Config):
     """``tokens`` i32[T + 1] -> ``(mean next-token cross-entropy over the
     vocabulary slice, counters f32[n_layers, 2])``."""
-    return lane._loss(params, tokens, _layers(cfg), cfg.rms_norm_eps)
+    layers = _layers(cfg)
+    loss, (_, counters) = lane._loss(
+        params, tokens, lane.once_through(layers, counted=len(LANE_COUNTERS)),
+        lane.head_exit(len(layers), cfg.rms_norm_eps))
+    return loss, counters
 
 
 def mellum2_forward(params: dict, tokens: jax.Array, cfg: Mellum2Config):
     """:func:`mellum2_loss` with nothing kept for a gradient but the input
     of every layer: ``(loss, counters, [h_0 .. h_L])``, what the lanes'
     trainer takes the gradient from (``lane._forward``)."""
-    return lane._forward(params, tokens, _layers(cfg), cfg.rms_norm_eps)
+    layers = _layers(cfg)
+    loss, (counters, _), hs, _ = lane._forward(
+        params, tokens, lane.once_through(layers, counted=len(LANE_COUNTERS)),
+        lane.head_exit(len(layers), cfg.rms_norm_eps))
+    return loss, counters, hs
 
 
 # ------------------------------------------------------------- evaluation
@@ -353,12 +267,13 @@ def make_mellum2_eval_fn(cfg: Mellum2Config = Mellum2Config(), data_seed: int = 
     windows = [cfg.sliding_window if kind == "sliding" else None
                for kind in cfg.layer_kinds]
     blocks = attention_key_blocks(cfg.seq_len, windows, cfg.attn_query_block)
+    layers = _layers(cfg)
     return lane.make_lane_eval_fn(
         init=lambda init_scale: init_mellum2_params(init_key, cfg, init_scale),
-        layers=_layers(cfg),
-        moe_layers=[True] * len(cfg.layer_kinds),
-        eps=cfg.rms_norm_eps,
+        visits=lane.once_through(layers, counted=len(LANE_COUNTERS)),
+        exits=lane.head_exit(len(layers), cfg.rms_norm_eps),
         data=make_token_dataset(jax.random.key(data_seed), cfg),
-        choices_per_pass=cfg.seq_len * cfg.num_experts_per_token,
         lane_bytes=mellum2_lane_bytes(cfg),
+        counted=lane.expert_counters(
+            [True] * len(layers), cfg.seq_len * cfg.num_experts_per_token),
         static_counters=tuple(zip(ATTENTION_COUNTERS, blocks)) + lane.MOE_COUNTERS)

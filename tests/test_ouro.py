@@ -1,0 +1,472 @@
+"""The Ouro lane (layers run several times with one set of weights, an exit
+after every pass) against the benchmark's plain reference, on the CPU at a
+small size (``ouro_small.py``): both losses, the trainer's gradient of every
+leaf against ``jax.grad`` of the reference's whole looped loss, one and
+three steps, the exit distribution, and the plain stacks of the other two
+lanes through the same trainer.
+
+Where a test holds the equations to the reference it sets the lanes'
+matrix-product operands to float32 (``lane._OPERAND``): then only the order
+of float32 sums differs, and the tolerances say so. Where it runs the lane
+as the chip does (bfloat16 operands), the tolerance is bfloat16's.
+"""
+
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from hpbandster_tpu.workloads import kimi_linear as K
+from hpbandster_tpu.workloads import lane
+from hpbandster_tpu.workloads import mellum2 as M
+from hpbandster_tpu.workloads import ouro as O
+
+import kimi_small
+import mellum2_small
+from ouro_small import SMALL, load, small
+
+
+@pytest.fixture(scope="module")
+def reference():
+    return load("reference", "ouro-sgd.py")
+
+
+@pytest.fixture(scope="module")
+def builders():
+    # the builders import the harness's ``program`` by that name
+    sys.modules.setdefault("program", load("program.py"))
+    return {name: load("configs", name + "-sgd.py").lane_config
+            for name in ("ouro", "mellum2", "kimi-linear")}
+
+
+@pytest.fixture
+def float32_operands(monkeypatch):
+    monkeypatch.setattr(lane, "_OPERAND", jnp.float32)
+
+
+def _cfg(builders, config=SMALL):
+    return builders["ouro"](config)._replace(attn_query_block=16)
+
+
+def _as_the_reference_names_them(tree, cfg):
+    """The program keeps the layers' leaves stacked under ``layers``; the
+    reference one dictionary of ``l<i>``."""
+    tree = dict(tree)
+    stacked = tree["layers"]
+    tree["layers"] = {"l%d" % i: jax.tree.map(lambda x: x[i], stacked)
+                      for i in range(cfg.num_layers)}
+    return tree
+
+
+def _gradient_steps(p):
+    """``(v, update)``: a momentum of zeros and an update that keeps the
+    parameters and hands the gradient back as the momentum."""
+    return jax.tree.map(jnp.zeros_like, p), lambda pl, vl, g: (pl, g)
+
+
+def _worst(got, want):
+    """Per leaf, the largest difference against the leaf's largest entry."""
+    return {jax.tree_util.keystr(path): float(
+        jnp.abs(g - w).max() / (jnp.abs(w).max() + 1e-12))
+        for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(got),
+                                jax.tree.leaves(want))}
+
+
+def test_weights_and_tokens_come_from_the_seed_alike(reference, builders):
+    cfg, key = _cfg(builders), jax.random.key(1)
+    ours = _as_the_reference_names_them(O.init_ouro_params(key, cfg, 0.7), cfg)
+    theirs = reference.init_params(SMALL, key, 0.7)
+    assert jax.tree.structure(ours) == jax.tree.structure(theirs)
+    assert all(jax.tree.leaves(jax.tree.map(
+        lambda a, b: bool((a == b).all()), ours, theirs)))
+    assert ours["gate"].shape == (64, 1) and not float(ours["gate_bias"][0])
+    for a, b in zip(O.make_token_dataset(jax.random.key(0), cfg),
+                    reference.dataset(SMALL)):
+        assert a.shape[1] == 33 and bool((a == b).all())
+
+
+def test_both_losses_and_the_forward_pass_match_the_reference(
+        reference, builders, float32_operands):
+    cfg = _cfg(builders)
+    params = O.init_ouro_params(jax.random.key(1), cfg, 1.3)
+    tokens = O.make_token_dataset(jax.random.key(0), cfg)[0][0]
+    trained, (reported, counted) = jax.jit(lambda p: O.ouro_losses(p, tokens, cfg))(params)
+    theirs = _as_the_reference_names_them(params, cfg)
+    want_trained, want_reported = jax.jit(
+        lambda p: reference.looped_losses(p, tokens, SMALL))(theirs)
+    # float32 both sides, another order of summation (blocks of keys against
+    # the whole row, the exit distribution by a running sum against a loop)
+    assert float(trained) == pytest.approx(float(want_trained), rel=1e-5)
+    assert float(reported) == pytest.approx(float(want_reported), rel=1e-5)
+    # two functions of one forward pass: with a loss over three exits and an
+    # entropy term they are not the same number
+    assert abs(float(trained) - float(reported)) > 1e-2
+    # the forward pass as the trainer runs it: the same reported loss and
+    # counters, and every exit's state the reference's
+    again, same, hs = O.ouro_forward(params, tokens, cfg)
+    assert float(again) == pytest.approx(float(reported), rel=1e-6)
+    np.testing.assert_allclose(same, counted, rtol=1e-6)
+    assert len(hs) == 1 + 3 * 2     # the embedding, then (the layers' loop, norm_f) a pass
+    for ours, want in zip((hs[2], hs[4], hs[6]),
+                          reference.looped_states(theirs, tokens, SMALL)):
+        np.testing.assert_allclose(ours, want, atol=2e-5)
+
+
+def test_the_trainers_gradient_is_that_of_the_references_whole_looped_loss(
+        reference, builders, float32_operands):
+    """Every leaf: the layers' (each visited three times: the trainer sums
+    a visit's gradient into the leaf's, slice by slice), the final norm's
+    (it closes every pass: three visits too), the head's and the gate's
+    (three exits, differentiated together), the embedding's. Against
+    ``jax.grad`` of the reference's loss written as three passes over one
+    dictionary, which knows nothing of visits."""
+    cfg = _cfg(builders)
+    params = O.init_ouro_params(jax.random.key(1), cfg, 1.3)
+    tokens = O.make_token_dataset(jax.random.key(0), cfg)[0][1]
+    v, keep = _gradient_steps(params)
+    _, got, _, _ = jax.jit(lambda p, v: lane._pass(
+        p, v, tokens, jnp.bool_(True), O._visits(cfg), O._exits(cfg), keep))(params, v)
+    want = jax.jit(jax.grad(lambda p: reference.looped_losses(p, tokens, SMALL)[0]))(
+        _as_the_reference_names_them(params, cfg))
+    worst = _worst(_as_the_reference_names_them(got, cfg), want)
+    assert set(worst) >= {"['gate']", "['gate_bias']", "['head']", "['norm_f']",
+                          "['embed']", "['layers']['l1']['w_down']"}
+    # float32 both sides, sums in another order: 3e-6 measured
+    assert max(worst.values()) < 2e-5, worst
+    # bfloat16 parameters would not pass: rounding them alone moves a leaf's
+    # gradient by more than a hundred times that
+    rounded = jax.tree.map(lambda x: x.astype(jnp.bfloat16).astype(jnp.float32), params)
+    _, coarse, _, _ = jax.jit(lambda p, v: lane._pass(
+        p, v, tokens, jnp.bool_(True), O._visits(cfg), O._exits(cfg), keep))(rounded, v)
+    assert max(_worst(_as_the_reference_names_them(coarse, cfg), want).values()) > 2e-3
+
+
+def test_a_held_out_pass_leaves_the_lane_as_it_is(builders, float32_operands):
+    cfg = _cfg(builders)
+    params = O.init_ouro_params(jax.random.key(1), cfg, 1.0)
+    tokens = O.make_token_dataset(jax.random.key(0), cfg)[1][0]
+    v, keep = _gradient_steps(params)
+    p, same_v, loss, (_, counted) = jax.jit(lambda p, v: lane._pass(
+        p, v, tokens, jnp.bool_(False), O._visits(cfg), O._exits(cfg), keep))(params, v)
+    assert all(jax.tree.leaves(jax.tree.map(lambda a, b: bool((a == b).all()), p, params)))
+    assert not any(float(jnp.abs(x).max()) for x in jax.tree.leaves(same_v))
+    assert float(loss) == pytest.approx(float(O.ouro_forward(params, tokens, cfg)[0]))
+    assert counted.shape == (2,)
+
+
+def test_a_step_planted_before_a_leafs_earlier_visits_changes_its_gradient(
+        reference, builders, float32_operands, monkeypatch):
+    """The trainer steps a shared leaf where the backward pass leaves its
+    FIRST visit. Planted: the leaf is decayed by a twentieth once the
+    backward pass has left its LAST visit, so that the earlier visits
+    differentiate through weights that a step has already moved: the
+    gradient is no longer the reference's, by far more than the tolerance
+    of the sound comparison."""
+    cfg = _cfg(builders)
+    params = O.init_ouro_params(jax.random.key(1), cfg, 1.3)
+    tokens = O.make_token_dataset(jax.random.key(0), cfg)[0][1]
+    v, keep = _gradient_steps(params)
+    want = jax.jit(jax.grad(lambda p: reference.looped_losses(p, tokens, SMALL)[0]))(
+        _as_the_reference_names_them(params, cfg))
+    sound, seen = lane._visit_backward, set()
+
+    def stepped_early(visit, one, dh, kept, p, written, read=None, **scope):
+        if one.__name__ in ("start_it", "sum_it"):
+            if visit.leaf in seen:      # an earlier visit of the leaf: already "stepped"
+                p = jax.tree.map(lambda x: 0.95 * x, p)
+            seen.add(visit.leaf)
+        return sound(visit, one, dh, kept, p, written, read, **scope)
+
+    monkeypatch.setattr(lane, "_visit_backward", stepped_early)
+    _, got, _, _ = jax.jit(lambda p, v: lane._pass(
+        p, v, tokens, jnp.bool_(True), O._visits(cfg), O._exits(cfg), keep))(params, v)
+    assert seen == {"layers", "norm_f"}
+    worst = _worst(_as_the_reference_names_them(got, cfg), want)
+    assert worst["['layers']['l0']['w_up']"] > 1e-2 and worst["['norm_f']"] > 1e-3, worst
+
+
+@pytest.mark.parametrize("operand, steps, limit", [
+    # float32 operands: rounding of sums only, steps amplify it little
+    (jnp.float32, 1, 2e-5), (jnp.float32, 3, 1e-4),
+    # as the chip runs it: bfloat16 operands (2^-8 a product) through six
+    # layer visits and three steps
+    (jnp.bfloat16, 1, 5e-3), (jnp.bfloat16, 3, 2e-2),
+])
+def test_steps_match_the_reference(reference, builders, monkeypatch, operand, steps, limit):
+    monkeypatch.setattr(lane, "_OPERAND", operand)
+    cfg = _cfg(builders)
+    eval_fn = O.make_ouro_eval_fn(cfg, data_seed=SMALL["data_seed"])
+    vec = jnp.asarray([0.75, 0.5, 0.3, 0.5])
+    got = float(jax.jit(lambda v: eval_fn(v, float(steps)))(vec))
+    hparams = [float(x) for x in lane.decode_lane_hparams(vec)]
+    start, want = reference.reference_losses(SMALL, hparams, [0, steps])
+    assert want < start - 0.01  # the steps moved the loss: it is compared
+    assert abs(got - want) < limit * (1 + abs(want))
+    if operand == jnp.float32 and steps == 1:
+        # the control: bfloat16 parameters and momentum fail the same limit
+        coarse = reference.reference_losses(SMALL, hparams, [steps], dtype=jnp.bfloat16)[0]
+        assert abs(coarse - want) > 10 * limit * (1 + abs(want))
+
+
+def _sweep_record(lrs, inits):
+    """A sweep's 13 evaluations (9, 3, 1 lanes at 1, 3, 9 steps; lanes 0, 2,
+    8 promoted, lane 2 twice) as ``benchmark/program.py`` records them."""
+    lanes = np.asarray(list(range(9)) + [0, 2, 8] + [2])
+    return {"bracket": np.zeros(13, int), "lane": lanes,
+            "budget": np.asarray([1.0] * 9 + [3.0] * 3 + [9.0]),
+            "loss": 10.0 + 0.01 * np.arange(13),
+            "config": {"lr": np.asarray(lrs)[lanes], "momentum": np.full(13, 0.5),
+                       "weight_decay": np.full(13, 1e-5),
+                       "init_scale": np.asarray(inits)[lanes]}}
+
+
+def test_the_comparison_picks_the_top_lane_and_the_other_lane_of_the_smallest_step(
+        reference):
+    """The lane that reached the top rung, with its loss at its first two
+    rungs; of the others, the one of the smallest learning rate among those
+    of a regular init scale (at most 1.5), promoted once or not, with its
+    loss at the first rung; where the sweep drew no regular one, the one of
+    the smallest init scale. Every sweep has both: no reading is left out."""
+    lrs = [3e-4, 2e-4, 0.05, 2.5e-3, 1e-3, 0.9, 2.9e-3, 0.02, 0.4]
+    picked = reference.sample_lanes(
+        _sweep_record(lrs, [0.12, 2.5, 0.3, 1.2, 0.4, 0.35, 5.0, 0.6, 3.5]))
+    assert sorted(picked) == ["small_step", "top"]
+    top, top_rungs = picked["top"]
+    assert top[0] == 0.05 and sorted(top_rungs) == [1, 3]
+    # lane 1 (2e-4) is chaos at an init scale of 2.5: lane 0, promoted once
+    other, other_rungs = picked["small_step"]
+    assert (other[0], other[3]) == (3e-4, 0.12) and sorted(other_rungs) == [1]
+    picked = reference.sample_lanes(
+        _sweep_record(lrs, [2.0, 2.5, 0.3, 1.9, 4.0, 1.7, 5.0, 1.6, 3.5]))
+    assert picked["small_step"][0][3] == 1.6 and picked["top"][0][0] == 0.05
+
+
+def _lost_visit(monkeypatch):
+    """Planted: the layers' gradient summed over all their visits but the
+    last pass's."""
+    whole, seen = lane._visit_backward, []
+
+    def three_of_four(visit, one, *args, **kwargs):
+        if one.__name__ == "sum_it" and visit.leaf == "layers" and not seen:
+            seen.append(visit)
+
+            def one(pull, dh, total, _):
+                return pull(dh)[0], total
+        return whole(visit, one, *args, **kwargs)
+
+    monkeypatch.setattr(lane, "_visit_backward", three_of_four)
+
+
+@pytest.mark.parametrize("fault, shows", [
+    (None, {}), ("unchanged", {"all": 0.999, "layers": 0.999, "gate": 0.999}),
+    ("lost_visit", {"layers": 0.05}), ("no_entropy", {"gate": 0.3}), ("control", {"all": 0.9})])
+def test_the_comparison_reads_what_the_first_step_changed(
+        reference, builders, float32_operands, monkeypatch, fault, shows):
+    """``compare`` on a sweep's record whose ``lane_change`` is the lane's
+    trainer (``eval_fn.change``, as the cell's builder hands it): the sound
+    trainer's first step is the reference's, a step that changed nothing
+    reads 1 in every group (the step is read on the small-step lane alone:
+    a top lane may be of an init scale at which no step is told from
+    rounding), a visit lost from the layers' sum shows in the layers' group (the last pass's visits carry an eighth of the layers'
+    gradient at this size), the entropy term dropped in the exit gate's, and
+    the control (the reference with bfloat16 parameters and momentum) loses
+    the step of the lane of the smallest learning rate (2e-4) altogether."""
+    cfg = _cfg(builders)
+    if fault == "lost_visit":
+        _lost_visit(monkeypatch)
+    if fault == "no_entropy":
+        cfg = cfg._replace(exit_entropy_beta=0.0)
+    eval_fn = O.make_ouro_eval_fn(cfg, data_seed=SMALL["data_seed"])
+    change = jax.jit(eval_fn.change)
+
+    def lane_change(hparams, steps):
+        lr, momentum, wd, init = hparams
+        vec = jnp.asarray([(np.log10(lr) + 4) / 4, momentum / 0.99, (np.log10(wd) + 7) / 5,
+                           (np.log10(init) + 1) / 2], jnp.float32)
+        tree = _as_the_reference_names_them(change(vec, jnp.float32(steps)), cfg)
+        return jax.tree.map(jnp.zeros_like, tree) if fault == "unchanged" else tree
+
+    # lanes 2 (top) and 1 (the others' smallest learning rate)
+    rec = _sweep_record([0.3, 2e-4, 0.05, 2.5e-3, 1e-3, 0.9, 2.9e-3, 0.02, 0.4],
+                        [0.2, 0.5, 0.3, 1.2, 0.4, 0.35, 5.0, 0.6, 3.5])
+    rec["lane_change"] = lane_change
+    numbers = {name: (value, limit) for name, value, limit in reference.compare(
+        SMALL, None, [rec], seed=5, control=fault == "control")}
+    assert sorted(numbers) == ["change_gap_all", "change_gap_gate", "change_gap_layers",
+                               "loss_gap_max"]
+    for group in ("all", "layers", "gate"):
+        value, limit = numbers["change_gap_" + group]
+        if group in shows:
+            assert value > shows[group], (group, value)
+        elif fault is None:
+            # float32 on both sides: 3e-6 at lr 0.05; at lr 2e-4 a step is a
+            # few float32 units of the embedding's entries, 1.3e-3
+            assert value < 5e-3 < limit, (group, value)
+    if fault in ("unchanged", "control"):
+        # what the contract asks of the limits: a state left unchanged and the
+        # precision below are not correct
+        assert any(numbers["change_gap_" + group][0] > numbers["change_gap_" + group][1]
+                   for group in ("all", "layers", "gate"))
+    # the record's losses are made up (10.0 ..): the net is not what is tested
+    assert numbers["loss_gap_max"][1] == 0.25
+
+
+def test_a_change_that_is_no_number_reads_infinity(reference):
+    want = {"embed": jnp.ones((3, 2)), "gate": jnp.ones((2, 1)), "gate_bias": jnp.ones((1,)),
+            "layers": {"l0": {"wq": jnp.full((2, 2), 2.0)}}}
+    got = dict(want, gate=jnp.full((2, 1), jnp.nan))
+    gaps = reference.change_gaps(got, want)
+    assert gaps["gate"] == np.inf and gaps["all"] == np.inf and gaps["layers"] == 0.0
+    half = dict(want, layers={"l0": {"wq": jnp.ones((2, 2))}})
+    assert reference.change_gaps(half, want) == {
+        "all": pytest.approx(np.sqrt(4.0 / (6 + 2 + 1 + 16))), "layers": pytest.approx(0.5),
+        "gate": 0.0}
+
+
+def test_the_reference_trains_by_the_gradient_of_its_whole_looped_loss(reference):
+    """The reference steps in blocks of one pass (``jax.vjp`` of
+    ``one_pass``, the passes' dictionaries added): with no momentum and no
+    decay the momentum buffer after one step is ``jax.grad`` of its
+    ``looped_losses``, every leaf; float32 sums in another order."""
+    fns = reference.lane_functions(SMALL, jnp.float32)
+    p, step = fns.init(jnp.float32(1.0)), fns.step
+    v = jax.tree.map(jnp.zeros_like, p)
+    train, _ = reference.dataset(SMALL)
+    want = jax.grad(lambda p: reference.looped_losses(p, train[2], SMALL)[0])(p)
+    new_p, got = step(p, v, 2, jnp.float32(0.5), jnp.float32(0.0), jnp.float32(0.0))
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(
+            g, w, atol=1e-5 * float(jnp.abs(w).max()) + 1e-12, err_msg=str(path))
+    np.testing.assert_allclose(new_p["head"], p["head"] - 0.5 * want["head"], atol=1e-6)
+
+
+# ------------------------------------------------------------------- exits
+@pytest.mark.parametrize("exits", [1, 2, 4])
+def test_the_exit_distribution_sums_to_one_and_its_last_term_is_the_remainder(
+        reference, exits):
+    gates = 4.0 * jax.random.normal(jax.random.key(exits), (exits, 50))
+    gates = gates.at[0, :3].set(jnp.asarray([40.0, -40.0, 0.0]))   # saturated either way
+    log_p, p = O.exit_distribution(gates)
+    np.testing.assert_allclose(p.sum(0), 1.0, atol=1e-6)
+    np.testing.assert_allclose(p, jnp.exp(log_p))
+    lam = jax.nn.sigmoid(gates)
+    # by the formula: p_t = lambda_t prod_{j<t} (1 - lambda_j); the last one
+    # is what is left, whatever its own gate says
+    stay = jnp.cumprod(1.0 - lam[:-1], axis=0)
+    np.testing.assert_allclose(p[-1], stay[-1] if exits > 1 else 1.0, atol=1e-6)
+    np.testing.assert_allclose(p[0], lam[0] if exits > 1 else 1.0, atol=1e-6)
+    np.testing.assert_allclose(p[-1], 1.0 - p[:-1].sum(0), atol=1e-6)
+    again = O.exit_distribution(gates.at[-1].add(7.0))[1]
+    np.testing.assert_allclose(again, p)
+    for ours, theirs in zip(log_p, reference.exit_log_probabilities(list(gates))):
+        np.testing.assert_allclose(ours, theirs, atol=1e-5)
+    # a saturated gate leaves a small number, not a zero: the entropy and
+    # its gradient stay numbers
+    assert np.isfinite(np.asarray(log_p)).all()
+    grad = jax.grad(lambda g: -(O.exit_distribution(g)[1] * O.exit_distribution(g)[0]).sum())(gates)
+    assert np.isfinite(np.asarray(grad)).all()
+
+
+def test_one_pass_and_no_entropy_term_is_the_plain_stack(builders, float32_operands):
+    """``total_ut_steps`` 1 and ``beta`` 0: one exit takes all the mass, so
+    the trained loss is the reported one and both are the loss of the plain
+    stack through the same layers (``lane.head_exit``: final norm, head,
+    cross-entropy), gradient and all."""
+    cfg = _cfg(builders, small(total_ut_steps=1, exit_entropy_beta=0.0))
+    params = O.init_ouro_params(jax.random.key(1), cfg, 1.0)
+    tokens = O.make_token_dataset(jax.random.key(0), cfg)[0][0]
+    (trained, (reported, counted)), grads = jax.value_and_grad(
+        lambda p: O.ouro_losses(p, tokens, cfg), has_aux=True)(params)
+    layers_only = O._visits(cfg)[:-1]         # without the final norm's visit
+    plain = lambda p: lane._loss(
+        p, tokens, layers_only, lane.head_exit(len(layers_only), cfg.rms_norm_eps))[0]
+    want, want_grads = jax.value_and_grad(plain)(params)
+    assert float(trained) == pytest.approx(float(want), rel=1e-6)
+    assert float(reported) == pytest.approx(float(want), rel=1e-6)
+    np.testing.assert_allclose(counted, [1.0, 0.0], atol=1e-6)   # all the mass, no entropy
+    for name in ("embed", "head", "norm_f", "layers"):
+        for g, w in zip(jax.tree.leaves(grads[name]), jax.tree.leaves(want_grads[name])):
+            np.testing.assert_allclose(g, w, atol=1e-6 * float(jnp.abs(w).max()) + 1e-12)
+    # the gate is not read where there is one exit
+    assert not float(jnp.abs(grads["gate"]).max())
+
+
+def test_the_reported_loss_reads_the_last_exit_alone(builders, float32_operands):
+    cfg = _cfg(builders)
+    params = O.init_ouro_params(jax.random.key(1), cfg, 1.0)
+    tokens = O.make_token_dataset(jax.random.key(0), cfg)[1][0]
+    trained, (reported, counted) = O.ouro_losses(params, tokens, cfg)
+    hs = O.ouro_forward(params, tokens, cfg)[2]
+    last = O._exit_cross_entropy(hs[-1], params["head"], tokens).mean()
+    assert float(reported) == pytest.approx(float(last), rel=1e-6)
+    # another gate moves the trained loss and the counters, not the report
+    other = dict(params, gate=3.0 * params["gate"] + 0.1, gate_bias=params["gate_bias"] + 2.0)
+    trained2, (reported2, counted2) = O.ouro_losses(other, tokens, cfg)
+    assert float(reported2) == float(reported)
+    assert abs(float(trained2) - float(trained)) > 1e-3
+    assert abs(float(counted2[0]) - float(counted[0])) > 1e-3
+    # what is counted: the last exit's mean mass, and the entropy's share of ln 3
+    gates = jnp.stack([h @ params["gate"][:, 0] + params["gate_bias"][0]
+                       for h in (hs[2], hs[4], hs[6])])
+    log_p, p = O.exit_distribution(gates)
+    np.testing.assert_allclose(
+        counted, [p[-1].mean(), -(p * log_p).sum(0).mean() / np.log(3.0)], rtol=1e-5)
+
+
+def test_the_lanes_facts_are_its_models(builders):
+    cfg = _cfg(builders)
+    facts = O.make_ouro_eval_fn(cfg).lane_facts
+    assert facts.counters == O.EXIT_COUNTERS + O.LOOP_COUNTERS
+    assert not [name for name in facts.counters if name.startswith("moe_")]
+    assert facts.traced_budget and facts.tokens_per_step == 32
+    # the published lane: 612,438,017 parameters at 12 bytes and its
+    # activations: one fits the chip's 16.9 GB, two do not
+    published = O.OuroConfig()
+    n = lane._count_params(lambda: O.init_ouro_params(jax.random.key(0), published, 1.0))
+    assert n == 612_438_017
+    assert 16.9e9 / 2 < O.ouro_lane_bytes(published) < 16.9e9
+    assert 12 * n < O.ouro_lane_bytes(published)
+    visits, exits = O._visits(published), O._exits(published)
+    assert [v.leaf for v in visits] == ["layers", "norm_f"] * 4
+    assert [v.times for v in visits] == [8, 1] * 4 and exits.after == (2, 4, 6, 8)
+
+
+# --------------------------------------- the other lanes, the same trainer
+def _plain_lane(name, builders):
+    if name == "mellum2":
+        cfg = builders["mellum2"](mellum2_small.SMALL)._replace(attn_query_block=16)
+        return (M.init_mellum2_params(jax.random.key(1), cfg, 1.0),
+                M.make_token_dataset(jax.random.key(0), cfg)[0][0],
+                M._layers(cfg), cfg.rms_norm_eps, lambda p, t: M.mellum2_loss(p, t, cfg))
+    cfg = builders["kimi-linear"](kimi_small.SMALL)
+    return (K.init_kimi_linear_params(jax.random.key(1), cfg, 1.0),
+            K.make_token_dataset(jax.random.key(0), cfg)[0][0],
+            K._layers(cfg), cfg.rms_norm_eps, lambda p, t: K.kimi_linear_loss(p, t, cfg))
+
+
+@pytest.mark.parametrize("name", ["kimi-linear", "mellum2"])
+def test_a_plain_stack_through_the_trainer_has_the_gradient_of_its_loss(
+        builders, float32_operands, name):
+    """The Kimi-Linear and Mellum2 lanes hand the generalised trainer L
+    visits and one exit: its loss, its counters and the gradient it steps by
+    are those of the lane's own loss under ``jax.grad``, every leaf."""
+    params, tokens, layers, eps, loss_fn = _plain_lane(name, builders)
+    visits = lane.once_through(layers, counted=len(lane.LANE_COUNTERS))
+    exits = lane.head_exit(len(layers), eps)
+    assert [v.leaf for v in visits] == ["l%d" % i for i in range(len(layers))]
+    assert exits.after == (len(layers),) and exits.leaves == ("norm_f", "head")
+    v, keep = _gradient_steps(params)
+    _, got, loss, (counters, none) = jax.jit(lambda p, v: lane._pass(
+        p, v, tokens, jnp.bool_(True), visits, exits, keep))(params, v)
+    (want_loss, want_counters), want = jax.jit(jax.value_and_grad(
+        lambda p: loss_fn(p, tokens), has_aux=True))(params)
+    assert none is None
+    assert float(loss) == pytest.approx(float(want_loss), rel=1e-6)
+    np.testing.assert_allclose(counters, want_counters)
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    # the same functions differentiated a visit at a time: float32 sums in
+    # another order
+    assert max(_worst(got, want).values()) < 2e-5

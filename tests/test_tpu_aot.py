@@ -129,6 +129,30 @@ def test_banded_attention_compiles_for_v5e_at_the_published_size(
     assert max(sizes) == largest
 
 
+def test_a_looped_layer_compiles_for_v5e_at_the_published_size(v5e_devices):
+    """The Ouro lane's layer (``workloads/ouro.py``: the lanes' one attention
+    at 16 key/value heads of ONE query head each, then the SwiGLU) at 2,048
+    tokens, forward pass only: the chip's compiler takes it, the scores stay
+    two-dimensional a head (rows = queries x 1), and no float32 array is
+    larger than the SwiGLU's gate and up side by side (2,048 x 11,264): the
+    widest block's scores of all 16 heads (16 x 512 x 2,048) lie under it,
+    and no head's square is ever whole (2,048 x 2,048 times 16 heads)."""
+    import re
+
+    from hpbandster_tpu.workloads import ouro as O
+
+    one = SingleDeviceSharding(v5e_devices[0])
+    cfg = O.OuroConfig()
+    leaves = {name: _sds(shape, jnp.float32, one)
+              for name, shape in O._layer_shapes(cfg).items()}
+    compiled = jax.jit(lambda h, p: O._layer(h, p, cfg)[0]).lower(
+        _sds((cfg.seq_len, cfg.hidden_size), jnp.float32, one), leaves).compile()
+    sizes = [int(np.prod([int(n) for n in dims.split(",")]))
+             for dims in re.findall(r"f32\[([\d,]+)\]", compiled.as_text())]
+    assert max(sizes) == cfg.seq_len * 2 * cfg.intermediate_size
+    assert 16 * cfg.attn_query_block * cfg.seq_len in sizes
+
+
 @pytest.mark.parametrize("score", ["softmax", "sigmoid"])
 def test_the_expert_layer_compiles_for_v5e_to_gathers_and_unfilled_buffers(
         v5e_devices, score):
