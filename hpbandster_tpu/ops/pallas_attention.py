@@ -1,0 +1,323 @@
+"""Pallas TPU kernels for the lanes' causal, optionally banded,
+grouped-query softmax attention (``workloads/lane.py``
+``banded_attention``): a tile's scores live in VMEM and nowhere else.
+
+One forward and one backward kernel, both over a grid of (key/value head,
+block of queries). A step holds its head's keys and values whole in VMEM
+(they are fetched once a head: the block index does not change from one
+block of queries to the next) and walks the tiles of keys that its
+queries may see, first to last, in loops inside the kernel: a tile
+wholly above the diagonal or below the band is never visited, and only
+the tiles that the diagonal or the band's edge crosses are masked (a
+loop of its own for them, so that no loop's body branches).
+
+* rows are (query head, query) pairs: the ``R`` query heads of a key/value
+  head share every tile of keys and every product (no head is repeated in
+  memory, and a product has ``R x block_q`` rows however few queries);
+* forward: the online softmax (running max, running sum, the output
+  rescaled as the max moves), float32; it keeps the output and one
+  log-sum-exp a row;
+* backward: ONE kernel, five products a tile: the scores again from
+  ``q``, ``k`` and the log-sum-exp, ``dv += p^T do``, ``dp = do v^T``,
+  ``dk += ds^T q``, ``dq += ds k``. ``dk`` and ``dv`` of the head stay in
+  VMEM across its blocks of queries (the output block does not move) and
+  each tile's share is added where it lies;
+* both products' operands arrive in the dtype the caller casts them to
+  (the lanes: bfloat16), every accumulation and everything between the
+  products is float32, ``1 / sqrt(d)`` multiplies the float32 scores:
+  the plain form's sums in another order.
+
+Layout: every array comes and goes as the projections leave it, heads
+side by side (``[T, G x R x d]``, ``[T, G x d]``: on the chip an array
+``[T, G, R, d]`` is tiled over its last two axes, so that even a reshape
+between the two is a pass over it, and one the compiler makes itself,
+under no scope); a step's block is its positions' ``R`` query heads,
+stood on top of one another inside the kernel by moving whole registers. A
+row's log-sum-exp is kept across the 128 lanes, as the running max and sum
+are (a column broadcasts to a tile of scores by reuse of registers).
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import NamedTuple, Optional
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+__all__ = ["Tiles", "fits", "fused_banded_attention", "tiles_visited"]
+
+_LANE = 128
+#: what a masked score is set to, ``banded_attention``'s own value: finite,
+#: so that a row whose first tile shows it nothing has a running max to
+#: leave (the diagonal's tile then wipes what it summed: ``exp(-1e30 - m)``)
+_MASKED = -1e30
+#: the most of the chip's 128 MiB of VMEM that a kernel asks for. It asks
+#: for what its shapes need (:func:`_vmem_bytes`) and no more: what a
+#: kernel reserves, the compiler cannot keep in VMEM for the operations
+#: around it
+_VMEM_LIMIT = 100 * 2 ** 20
+
+_NT = (((1,), (1,)), ((), ()))      # a @ b.T
+_TN = (((0,), (0,)), ((), ()))      # a.T @ b
+
+
+class Tiles(NamedTuple):
+    """Queries a block and keys a tile."""
+
+    block_q: int
+    block_k: int
+
+
+def _vmem_bytes(t: int, rows: int, d: int, tiles: Tiles, operand_bytes: int) -> int:
+    """What the backward kernel (the larger) holds in VMEM: a head's keys,
+    values and their float32 gradients and a block's rows (queries, output,
+    its gradient, the queries' gradient, the log-sum-exp), each twice over
+    for the pipeline, and some eight float32 arrays of a tile's scores.
+    The compiler's own count at the cells' shapes (chipless, PR 37): 32.7 MB
+    where this gives 46.7 (8,192 positions, 8 x 128 rows), 9.1 where this
+    gives 17.0 (2,048 positions, 512 rows)."""
+    resident = 2 * 2 * t * d * (operand_bytes + 4)
+    blocks = 2 * rows * (d * (operand_bytes + 3 * 4) + _LANE * 4)
+    return resident + blocks + 8 * rows * tiles.block_k * 4
+
+
+def fits(t: int, d: int, heads_per_kv: int, tiles: Tiles, operand_bytes: int = 2) -> bool:
+    """Whether the kernels take a sequence of ``t`` positions and heads of
+    ``d``, ``heads_per_kv`` query heads a key/value head: whole tiles of
+    whole lanes, and what a step holds within the kernels' share of VMEM."""
+    return (d % _LANE == 0 and tiles.block_k % _LANE == 0 and tiles.block_q % 16 == 0
+            and t % tiles.block_q == 0 and t % tiles.block_k == 0
+            and _vmem_bytes(t, heads_per_kv * tiles.block_q, d, tiles, operand_bytes)
+            <= _VMEM_LIMIT)
+
+
+def _loops(lo, tiles: Tiles, window: Optional[int]):
+    """``[(first, end, masked)]``: the loops that walk the tiles of keys
+    that the queries ``lo : lo + block_q`` may see, in order: those the
+    band's lower edge crosses, masked; those wholly inside; those the
+    diagonal crosses, masked (a narrow window's tile may be crossed by
+    both, and is walked once). ``lo`` a traced number or a Python one."""
+    bq, bk = tiles
+    up, down = (jnp.maximum, jnp.minimum) if isinstance(lo, jax.Array) else (max, min)
+    end = (lo + bq - 1) // bk + 1
+    diagonal = (lo + 1) // bk
+    if window is None:
+        return [(0, diagonal, False), (diagonal, end, True)]
+    first = up(lo - window + 1, 0) // bk
+    clear = up(lo + bq - window + bk - 1, 0) // bk
+    return [(first, down(clear, end), True), (clear, diagonal, False),
+            (up(diagonal, clear), end, True)]
+
+
+def tiles_visited(t: int, window: Optional[int], tiles: Tiles) -> int:
+    """Tiles of ``block_q x block_k`` scores that one (query head, pass)
+    computes: the kernels' own ranges, in Python."""
+    return sum(max(end - first, 0)
+               for lo in range(0, t, tiles.block_q)
+               for first, end, _ in _loops(lo, tiles, window))
+
+
+def _scores(q, k, lo, klo, tiles: Tiles, window: Optional[int], scale: float, masked: bool):
+    """A tile's scores f32[R x block_q, block_k], ``masked`` where the
+    diagonal or the band's edge crosses it."""
+    bq, bk = tiles
+    s = lax.dot_general(q, k, _NT, preferred_element_type=jnp.float32) * scale
+    if not masked:
+        return s
+    ahead = (lo + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+             - klo - lax.broadcasted_iota(jnp.int32, (bq, bk), 1))
+    seen = ahead >= 0
+    if window is not None:
+        seen = seen & (ahead < window)
+    # one block of queries' mask for every query head's rows; added, not
+    # selected: ``s - 1e30`` is ``-1e30`` to the last bit
+    bias = jnp.where(seen, 0.0, _MASKED).astype(jnp.float32)
+    return s + jnp.tile(bias, (s.shape[0] // bq, 1))
+
+
+def _across(column, width: int):
+    """A column kept across the 128 lanes, as wide as ``width``."""
+    return jnp.tile(column, (1, width // _LANE))
+
+
+def _walk(lo, tiles: Tiles, window: Optional[int], tile):
+    """``tile(klo, masked)`` for every tile of keys the queries ``lo : lo +
+    block_q`` may see, in order; ``klo`` its first key."""
+    for first, end, masked in _loops(lo, tiles, window):
+        lax.fori_loop(
+            first, end, lambda j, _, masked=masked: tile(
+                pl.multiple_of(j * tiles.block_k, tiles.block_k), masked), None)
+
+
+def _rows(ref, d: int):
+    """A block ``[block_q, R x d]`` (a position's query heads side by side,
+    as the projections leave them) as the products' rows ``[R x block_q,
+    d]``, head by head: whole registers moved, nothing shuffled."""
+    return jnp.concatenate(
+        [ref[:, h * d:(h + 1) * d] for h in range(ref.shape[1] // d)], axis=0)
+
+
+def _store_rows(ref, rows):
+    """:func:`_rows` undone, into ``ref``."""
+    bq, d = ref.shape[0], rows.shape[1]
+    for h in range(ref.shape[1] // d):
+        ref[:, h * d:(h + 1) * d] = rows[h * bq:(h + 1) * bq]
+
+
+def _forward_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
+                    tiles: Tiles, window: Optional[int], scale: float):
+    lo = pl.program_id(1) * tiles.block_q
+    d = acc_ref.shape[-1]
+    q = _rows(q_ref, d)
+    m_ref[...] = jnp.full_like(m_ref, _MASKED)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def tile(klo, masked):
+        keys = pl.ds(klo, tiles.block_k)
+        s = _scores(q, k_ref[keys, :], lo, klo, tiles, window, scale, masked)
+        m_prev = m_ref[...]
+        m_next = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - _across(m_next, tiles.block_k))
+        alpha = jnp.exp(m_prev - m_next)
+        l_ref[...] = alpha * l_ref[...] + p.sum(axis=-1, keepdims=True)
+        m_ref[...] = m_next
+        v = v_ref[keys, :]
+        acc_ref[...] = _across(alpha, d) * acc_ref[...] + jnp.dot(
+            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+
+    _walk(lo, tiles, window, tile)
+    l = l_ref[...]
+    _store_rows(o_ref, acc_ref[...] / _across(l, d))
+    lse_ref[...] = m_ref[...] + jnp.log(l)
+
+
+def _backward_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, do_ref,
+                     dq_ref, dk_ref, dv_ref, dq_acc, *,
+                     tiles: Tiles, window: Optional[int], scale: float):
+    lo = pl.program_id(1) * tiles.block_q
+    d = dq_acc.shape[-1]
+
+    @pl.when(pl.program_id(1) == 0)
+    def start():
+        dk_ref[...] = jnp.zeros_like(dk_ref)
+        dv_ref[...] = jnp.zeros_like(dv_ref)
+
+    q = _rows(q_ref, d)
+    do = _rows(do_ref, d)
+    # what the softmax's backward pass subtracts: sum_j p_ij dp_ij = do_i . o_i
+    delta = (do * _rows(o_ref, d)).sum(axis=-1, keepdims=True)
+    do = do.astype(q.dtype)
+    lse = lse_ref[...]
+    dq_acc[...] = jnp.zeros_like(dq_acc)
+
+    def tile(klo, masked):
+        keys = pl.ds(klo, tiles.block_k)
+        k, v = k_ref[keys, :], v_ref[keys, :]
+        p = jnp.exp(_scores(q, k, lo, klo, tiles, window, scale, masked)
+                    - _across(lse, tiles.block_k))
+        dv_ref[keys, :] += lax.dot_general(
+            p.astype(do.dtype), do, _TN, preferred_element_type=jnp.float32)
+        dp = lax.dot_general(do, v, _NT, preferred_element_type=jnp.float32)
+        # the scores' gradient but for ``1 / sqrt(d)``, which multiplies the
+        # two small products it enters and not the tile
+        ds = (p * (dp - delta)).astype(q.dtype)
+        dk_ref[keys, :] += scale * lax.dot_general(
+            ds, q, _TN, preferred_element_type=jnp.float32)
+        dq_acc[...] += jnp.dot(ds, k, preferred_element_type=jnp.float32)
+
+    _walk(lo, tiles, window, tile)
+    _store_rows(dq_ref, dq_acc[...] * scale)
+
+
+def _specs(t: int, r: int, d: int, tiles: Tiles):
+    """Block specifications over the grid (key/value head ``g``, block of
+    queries ``i``): a block of positions with its key/value head's ``R``
+    query heads side by side (of ``[T, G x R x d]``), a block's rows of
+    the lanes' width, a head's keys (values, their gradients) whole (of
+    ``[T, G x d]``)."""
+    return (pl.BlockSpec((tiles.block_q, r * d), lambda g, i: (i, g)),
+            pl.BlockSpec((None, None, r * tiles.block_q, _LANE), lambda g, i: (g, i, 0, 0)),
+            pl.BlockSpec((t, d), lambda g, i: (0, g)))
+
+
+def _params(q, k, heads, tiles: Tiles):
+    # blocks of queries in order: a head's keys stay, its ``dk`` and ``dv``
+    # are summed over them
+    _, r, d = heads
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "arbitrary"),
+        vmem_limit_bytes=min(_VMEM_LIMIT, _vmem_bytes(
+            k.shape[0], r * tiles.block_q, d, tiles, q.dtype.itemsize)))
+
+
+def _forward(q, k, v, heads, window, tiles: Tiles, interpret: bool):
+    """``q [T, G x R x d]``, ``k, v [T, G x d]``, ``heads = (G, R, d)`` ->
+    ``(out f32[T, G x R x d], lse f32[G, blocks, R x block_q, 128])``."""
+    g, r, d = heads
+    t, blocks, rows = q.shape[0], q.shape[0] // tiles.block_q, r * tiles.block_q
+    positions, rows_lane, whole = _specs(t, r, d, tiles)
+    return pl.pallas_call(
+        functools.partial(_forward_kernel, tiles=tiles, window=window, scale=d ** -0.5),
+        out_shape=(jax.ShapeDtypeStruct(q.shape, jnp.float32),
+                   jax.ShapeDtypeStruct((g, blocks, rows, _LANE), jnp.float32)),
+        grid=(g, blocks),
+        in_specs=[positions, whole, whole],
+        out_specs=(positions, rows_lane),
+        scratch_shapes=[pltpu.VMEM((rows, _LANE), jnp.float32),
+                        pltpu.VMEM((rows, _LANE), jnp.float32),
+                        pltpu.VMEM((rows, d), jnp.float32)],
+        compiler_params=_params(q, k, heads, tiles), interpret=interpret,
+        name="banded_attention_forward",
+    )(q, k, v)
+
+
+def _backward(q, k, v, out, lse, dout, heads, window, tiles: Tiles, interpret: bool):
+    """-> ``(dq f32[T, G x R x d], dk, dv f32[T, G x d])``."""
+    g, r, d = heads
+    t = q.shape[0]
+    positions, rows_lane, whole = _specs(t, r, d, tiles)
+    return pl.pallas_call(
+        functools.partial(_backward_kernel, tiles=tiles, window=window, scale=d ** -0.5),
+        out_shape=(jax.ShapeDtypeStruct(q.shape, jnp.float32),
+                   jax.ShapeDtypeStruct(k.shape, jnp.float32),
+                   jax.ShapeDtypeStruct(v.shape, jnp.float32)),
+        grid=(g, t // tiles.block_q),
+        in_specs=[positions, whole, whole, positions, rows_lane, positions],
+        out_specs=(positions, whole, whole),
+        scratch_shapes=[pltpu.VMEM((r * tiles.block_q, d), jnp.float32)],
+        compiler_params=_params(q, k, heads, tiles), interpret=interpret,
+        name="banded_attention_backward",
+    )(q, k, v, out, lse, dout)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def fused_banded_attention(q, k, v, heads, window: Optional[int], tiles: Tiles, operand,
+                           scope: str, interpret: bool = False):
+    """``banded_attention``'s mathematics by the kernels above, the heads
+    side by side: ``q`` f32[T, G x R x d] (query head ``g * R + r`` on
+    key/value head ``g``), ``k, v`` f32[T, G x d], ``heads = (G, R, d)`` ->
+    f32[T, G x R x d]; both products' operands are cast to ``operand``. The
+    device operations of both rules are named ``scope`` (a
+    ``jax.named_scope``; the backward rule is traced where its caller's
+    scope is no longer open)."""
+    return _attention_forward(q, k, v, heads, window, tiles, operand, scope, interpret)[0]
+
+
+def _attention_forward(q, k, v, heads, window, tiles, operand, scope, interpret):
+    with jax.named_scope(scope):
+        q, k, v = (x.astype(operand) for x in (q, k, v))
+        out, lse = _forward(q, k, v, heads, window, tiles, interpret)
+        return out, (q, k, v, out, lse)
+
+
+def _attention_backward(heads, window, tiles, operand, scope, interpret, kept, dout):
+    with jax.named_scope(scope):
+        return _backward(*kept, dout, heads, window, tiles, interpret)
+
+
+fused_banded_attention.defvjp(_attention_forward, _attention_backward)

@@ -27,7 +27,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from hpbandster_tpu.ops import pallas_attention
 from hpbandster_tpu.ops.fused import LaneFacts
+from hpbandster_tpu.ops.pallas_kde import pallas_available
 from hpbandster_tpu.space import ConfigurationSpace, UniformFloatHyperparameter
 
 __all__ = [
@@ -37,6 +39,8 @@ __all__ = [
     "LANE_COUNTERS",
     "MOE_COUNTERS",
     "Visit",
+    "attention_alive_bytes",
+    "attention_counters",
     "attention_key_blocks",
     "attention_mixer",
     "banded_attention",
@@ -145,6 +149,20 @@ def _rotate(x, cos, sin):
     return x * cos[lift] + turned * sin[lift]
 
 
+def _rotate_side_by_side(x, cos, sin):
+    """:func:`_rotate` for ``x`` f32[T, heads x d], the heads side by side
+    as a projection leaves them: the same products and sums an entry, with
+    no array of another shape between (a head's halves change places by
+    two turns of the whole row, each entry taking the one that stayed in
+    its head)."""
+    d = cos.shape[1]
+    heads = x.shape[1] // d
+    first_half = jnp.arange(x.shape[1]) % d < d // 2
+    turned = jnp.where(first_half, -jnp.roll(x, -(d // 2), axis=1),
+                       jnp.roll(x, d // 2, axis=1))
+    return x * jnp.tile(cos, (1, heads)) + turned * jnp.tile(sin, (1, heads))
+
+
 #: how many scores (a block's queries x their heads x its keys, of several
 #: key/value heads together where they fit) are alive at once. Measured on
 #: the chip at the Mellum2 lane's published size (PR 32; a layer's mixer,
@@ -155,6 +173,40 @@ def _rotate(x, cos, sin):
 #: matrices leaves the softmax's fast path)
 _SCORES_AT_ONCE = 2 ** 25
 
+#: the fused kernels' tiles: rows of a product (a block's queries x the
+#: query heads of a key/value head) and keys a tile; a block of queries is
+#: no wider than a tile of keys (the diagonal then crosses one tile a
+#: block). Read on the chip at both cells' sizes (PR 37; one layer's scores
+#: alone, forward / forward and backward, ms; the plain form 7.13 / 23.9
+#: for a window layer, 19.5 / 60.6 for the full one, 0.518 / 1.479 at the
+#: Ouro lane's 2,048 keys and 16 heads). 8 query heads x 128 queries
+#: against 512 keys: 2.38 / 6.19 and 4.89 / 13.85; x 256 queries: 2.39 /
+#: 6.14 and 4.99 / 13.80; x 512: 5.04 / 13.82 in the full layer; 128
+#: queries against 256 keys 2.28 / 6.09 in a window layer, but in the full
+#: one 256 x 256 read 10.6 / 25.3 where 256 x 512 read 7.80 / 19.9, and 128
+#: x 1,024 8.62 / 21.9 where 128 x 512 read 7.78 / 20.2 (those four with the
+#: masked tiles still under a ``cond``). One query head a key/value head at
+#: 2,048 keys: 512 queries against 512 keys 0.400 / 1.028, 256 queries
+#: 0.447 / 1.089, 1,024 queries 0.612 / 1.434 where 512 read 0.552 / 1.295
+#: (``cond``). One size serves every shape the kernels take
+_KERNEL_ROWS = 1024
+_KERNEL_KEYS = 512
+
+#: up to this many keys the plain form runs on the chip too: a block's
+#: scores then stay on its softmax's fast path (PR 34: 2,048 keys a block of
+#: 512 queries), and the lane as a whole read faster with it. At the Ouro
+#: lane's size (2,048 keys, 16 heads of one query head each, 32 visits a
+#: pass; PR 37, ``ouro-sgd.bohb-1x9``, two untraced runs and a traced one a
+#: side): 10.6706 s a sweep in plain JAX, 11.0078 s with the kernels
+#: (``lane.gqa`` 2.94 -> 3.33 s a sweep, every other part the same), though
+#: a layer's scores alone read 0.400 / 1.028 ms with the kernels against
+#: 0.518 / 1.479 ms (forward / forward and backward). One layer forward and
+#: backward at that size: the mixer alone 2.804 ms plain, 2.588 ms with the
+#: kernels; the mixer, its norms and the SwiGLU together 6.009 ms plain,
+#: 6.266 ms with them: the kernels' calls cost the products around them
+#: more than they save (why was not read)
+_PLAIN_KEYS = 2048
+
 
 def _attention_spans(t: int, window: Optional[int], block: int):
     """``[(lo, hi, klo)]``: queries ``lo:hi`` go against keys ``klo:hi``,
@@ -164,15 +216,64 @@ def _attention_spans(t: int, window: Optional[int], block: int):
     return [(lo, min(lo + block, t), first(lo)) for lo in range(0, t, block)]
 
 
-def attention_key_blocks(t: int, windows, block: int):
+def _kernel_tiles(t: int, d: int, heads_per_kv: int):
+    """The fused kernels' tiles for ``t`` positions and heads of ``d``, or
+    None where the plain form runs: the kernels (``ops/pallas_attention.py``:
+    a tile's scores never leave VMEM) where Mosaic compiles them, on a TPU
+    backend, the keys are more than the plain form is quick at
+    (:data:`_PLAIN_KEYS`) and the shapes fit the kernels' tiles; off the chip
+    the plain form (:func:`banded_attention`), which is also what the
+    kernels are tested against."""
+    if not pallas_available() or t <= _PLAIN_KEYS:
+        return None
+    keys = min(t, _KERNEL_KEYS)
+    tiles = pallas_attention.Tiles(min(keys, max(_KERNEL_ROWS // heads_per_kv, 16)), keys)
+    if not pallas_attention.fits(t, d, heads_per_kv, tiles, np.dtype(_OPERAND).itemsize):
+        return None
+    return tiles
+
+
+def attention_key_blocks(t: int, windows, block: int, tiles=None):
     """``(computed, square)``: blocks of ``block x block`` scores that
     :func:`banded_attention` computes over layers of the given ``windows``
-    (a number or ``None`` each), and those of their full squares."""
+    (a number or ``None`` each), and those of their full squares; with the
+    fused kernels' ``tiles`` (:func:`_kernel_tiles`), tiles of ``block_q x
+    block_k``."""
+    if tiles is not None:
+        return (sum(pallas_attention.tiles_visited(t, window, tiles) for window in windows),
+                (t // tiles.block_q) * (t // tiles.block_k) * len(windows))
     per_side = -(-t // block)
     computed = sum(-(-(hi - klo) // block)
                    for window in windows
                    for _, hi, klo in _attention_spans(t, window, block))
     return computed, per_side * per_side * len(windows)
+
+
+def attention_counters(t: int, d: int, heads_per_kv: int):
+    """The static fact of how a lane's attention is computed, beside its
+    counted ones (``make_lane_eval_fn(static_counters=...)``): the share of
+    its attention layers whose scores stay in VMEM (the fused kernels; the
+    layers of a lane are of one shape, so all of them or none: 1 on the chip
+    at the published sizes, 0 on a CPU)."""
+    return (("attn_scores_in_vmem", float(_kernel_tiles(t, d, heads_per_kv) is not None)),)
+
+
+def attention_alive_bytes(t: int, kv_heads: int, heads_per_kv: int, d: int,
+                          windows, block: int) -> int:
+    """Device bytes of attention's own that are alive at once in a layer's
+    backward pass, the largest over layers of the given ``windows``: the
+    plain form's three copies of the scores alive at once; the fused
+    kernels' residuals, the output and a log-sum-exp a row kept across the
+    128 lanes."""
+    if _kernel_tiles(t, d, heads_per_kv) is not None:
+        return 4 * t * kv_heads * heads_per_kv * (d + 128)
+
+    def scores(window):
+        widest = heads_per_kv * max(
+            (hi - lo) * (hi - klo) for lo, hi, klo in _attention_spans(t, window, block))
+        return 3 * 4 * widest * max(min(_SCORES_AT_ONCE // widest, kv_heads), 1)
+
+    return max(scores(window) for window in windows)
 
 
 def banded_attention(q, k, v, window: Optional[int], block: int,
@@ -181,7 +282,10 @@ def banded_attention(q, k, v, window: Optional[int], block: int,
     ``window`` is a number: position ``i`` sees ``j <= i`` and, with a
     window, ``i - j < window``. ``q`` f32[T, G, R, d] (query head ``g * R +
     r`` on key/value head ``g``), ``k, v`` f32[T, G, d]; returns f32[T, G,
-    R, d]. Scores are ``q . k / sqrt(d)``, the softmax float32.
+    R, d]. Scores are ``q . k / sqrt(d)``, the softmax float32. The plain
+    form (:func:`attention_mixer` takes the fused kernels where
+    :func:`_kernel_tiles` says so: the same mathematics, a tile's scores
+    never out of VMEM).
 
     Queries go in blocks of ``block``, each against the keys of
     :func:`_attention_spans` and no others; a key/value head is not
@@ -228,19 +332,36 @@ def banded_attention(q, k, v, window: Optional[int], block: int,
 
 
 def attention_mixer(x, p, *, kv_heads: int, heads_per_kv: int, head_dim: int,
-                    inv_freq, factor: float, window: Optional[int], block: int):
+                    inv_freq, factor: float, window: Optional[int], block: int,
+                    scope: str):
     """An attention layer's mixer, from the norm's output to ``W_o``: the
     projections ``wq``, ``wk``, ``wv`` as one product, queries and keys
-    turned by the rotary tables of ``inv_freq`` and ``factor``,
-    :func:`banded_attention`, ``wo``."""
+    turned by the rotary tables of ``inv_freq`` and ``factor``, causal
+    softmax attention (banded where ``window`` is a number), ``wo``.
+
+    The attention is :func:`banded_attention` in plain JAX or, where
+    :func:`_kernel_tiles` says so, the fused kernels of
+    ``ops/pallas_attention.py``, with their own backward pass: the heads
+    then stay side by side from the projections to ``wo`` (the kernels take
+    them so, the rotary tables are tiled across them), so that no array
+    changes layout on the way. ``scope`` is the caller's part (``lane.swa``,
+    ``lane.gqa``: the ``jax.named_scope`` it calls this under), which the
+    kernels' backward rule has to be told: it is traced where the caller's
+    scope is no longer open."""
     t = x.shape[0]
     g, r, d = kv_heads, heads_per_kv, head_dim
     q, k, v = _mm_beside(x, p["wq"], p["wk"], p["wv"])
     cos, sin = _rotary_tables(inv_freq, factor, t)
-    q = _rotate(q.reshape(t, g, r, d), cos, sin)
-    k = _rotate(k.reshape(t, g, d), cos, sin)
-    out = banded_attention(q, k, v.reshape(t, g, d), window, block)
-    return _mm(out.reshape(t, g * r * d), p["wo"])
+    tiles = _kernel_tiles(t, d, r)
+    if tiles is not None:
+        out = pallas_attention.fused_banded_attention(
+            _rotate_side_by_side(q, cos, sin), _rotate_side_by_side(k, cos, sin), v,
+            (g, r, d), window, tiles, _OPERAND, scope)
+    else:
+        out = banded_attention(
+            _rotate(q.reshape(t, g, r, d), cos, sin), _rotate(k.reshape(t, g, d), cos, sin),
+            v.reshape(t, g, d), window, block).reshape(t, g * r * d)
+    return _mm(out, p["wo"])
 
 
 # --------------------------------------------------------------- parameters

@@ -30,7 +30,11 @@ key/value heads at a time as keep the scores alive under
 (``jax.checkpoint``). A block of scores wholly outside the band or above the
 diagonal is never computed (:func:`attention_key_blocks` counts them), no
 ``S x S`` array exists in either kind, and a window layer's products are two
-blocks of keys wide whatever the length. Plain JAX, differentiated by JAX.
+blocks of keys wide whatever the length. Plain JAX, differentiated by JAX:
+the form off the chip. On a TPU ``lane.attention_mixer`` takes the fused
+kernels of ``ops/pallas_attention.py`` for both kinds (a tile's scores never
+leave VMEM, the band is the window and one block of queries wide, the
+backward pass is the kernels' own).
 
 Precision as the Kimi-Linear lane states it: float32 parameters, momentum
 and gradients; matrix-product operands bfloat16 with float32 accumulation;
@@ -190,7 +194,7 @@ def _attention(x, p, kind: str, cfg: Mellum2Config):
         heads_per_kv=cfg.num_heads // cfg.num_kv_heads, head_dim=cfg.head_dim,
         inv_freq=inv_freq, factor=factor,
         window=cfg.sliding_window if kind == "sliding" else None,
-        block=cfg.attn_query_block)
+        block=cfg.attn_query_block, scope=_MIXER_SCOPE[kind])
 
 
 #: the scope of a layer's mixer by its kind (``obs.timeline.LANE_SCOPES``)
@@ -233,25 +237,33 @@ def mellum2_forward(params: dict, tokens: jax.Array, cfg: Mellum2Config):
 
 
 # ------------------------------------------------------------- evaluation
+def _windows(cfg: Mellum2Config):
+    """Each layer's window, None a full-attention layer's."""
+    return [cfg.sliding_window if kind == "sliding" else None
+            for kind in cfg.layer_kinds]
+
+
 def mellum2_lane_bytes(cfg: Mellum2Config) -> int:
     """Device bytes one lane needs while it trains: float32 parameters,
     momentum and gradients (12 bytes a parameter) and the peak of its
     activations: the logits, their softmax and their gradient, a layer's
     input per layer, one layer's recomputed activations (about 24
     hidden-sized rows a token: projections, rotated heads, their
-    gradients) and three copies of the widest block of scores. At the
-    published widths it gives 12.5 GB where the chip's compiler counts
-    12.0 GB for the bracket and its allocator peaks at 8.5 GB: one lane
-    fits a 16.9 GB chip, two do not."""
+    gradients) and what attention keeps alive of its scores
+    (``lane.attention_alive_bytes``: three copies of the widest block in
+    plain JAX, the fused kernels' output and log-sum-exp on the chip). At
+    the published widths it gives 12.5 GB in plain JAX (11.9 GB with the
+    kernels) where the chip's compiler counts 12.0 GB for the bracket and
+    its allocator peaks at 8.5 GB: one lane fits a 16.9 GB chip, two do
+    not."""
     n_params = lane._count_params(
         lambda: init_mellum2_params(jax.random.key(0), cfg, 1.0))
     t = cfg.seq_len
-    widest = max(hi - klo for window in (cfg.sliding_window, None)
-                 for _, hi, klo in _attention_spans(t, window, cfg.attn_query_block))
-    heads_a_group = cfg.num_heads // cfg.num_kv_heads
-    activations = 4 * (
-        t * (3 * cfg.vocab_rows + (24 + len(cfg.layer_kinds)) * cfg.hidden_size)
-        + 3 * heads_a_group * min(cfg.attn_query_block, t) * widest)
+    activations = (
+        4 * t * (3 * cfg.vocab_rows + (24 + len(cfg.layer_kinds)) * cfg.hidden_size)
+        + lane.attention_alive_bytes(
+            t, cfg.num_kv_heads, cfg.num_heads // cfg.num_kv_heads, cfg.head_dim,
+            _windows(cfg), cfg.attn_query_block))
     return 12 * n_params + activations
 
 
@@ -261,12 +273,14 @@ def make_mellum2_eval_fn(cfg: Mellum2Config = Mellum2Config(), data_seed: int = 
     momentum-SGD steps of one ``seq_len``-token sequence);
     ``eval_fn.lane_facts`` states its footprint, its tokens a step and its
     counters: :data:`LANE_COUNTERS` from the device, then
-    :data:`ATTENTION_COUNTERS`, facts of the blocking, and
+    :data:`ATTENTION_COUNTERS`, facts of the blocking (the fused kernels'
+    tiles where they run), ``lane.attention_counters``, whether they do, and
     ``lane.MOE_COUNTERS``, how the expert layer moves its rows."""
     init_key = jax.random.key(data_seed + 1)
-    windows = [cfg.sliding_window if kind == "sliding" else None
-               for kind in cfg.layer_kinds]
-    blocks = attention_key_blocks(cfg.seq_len, windows, cfg.attn_query_block)
+    heads_per_kv = cfg.num_heads // cfg.num_kv_heads
+    blocks = attention_key_blocks(
+        cfg.seq_len, _windows(cfg), cfg.attn_query_block,
+        lane._kernel_tiles(cfg.seq_len, cfg.head_dim, heads_per_kv))
     layers = _layers(cfg)
     return lane.make_lane_eval_fn(
         init=lambda init_scale: init_mellum2_params(init_key, cfg, init_scale),
@@ -276,4 +290,5 @@ def make_mellum2_eval_fn(cfg: Mellum2Config = Mellum2Config(), data_seed: int = 
         lane_bytes=mellum2_lane_bytes(cfg),
         counted=lane.expert_counters(
             [True] * len(layers), cfg.seq_len * cfg.num_experts_per_token),
-        static_counters=tuple(zip(ATTENTION_COUNTERS, blocks)) + lane.MOE_COUNTERS)
+        static_counters=tuple(zip(ATTENTION_COUNTERS, blocks)) + lane.attention_counters(
+            cfg.seq_len, cfg.head_dim, heads_per_kv) + lane.MOE_COUNTERS)

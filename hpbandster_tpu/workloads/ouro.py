@@ -152,7 +152,7 @@ def _layer(h, p, cfg: OuroConfig):
             _rms(h, p["norm1"], eps), p, kv_heads=cfg.num_kv_heads,
             heads_per_kv=cfg.num_heads // cfg.num_kv_heads, head_dim=cfg.head_dim,
             inv_freq=rotary_inv_freq(cfg), factor=1.0, window=None,
-            block=cfg.attn_query_block)
+            block=cfg.attn_query_block, scope="lane.gqa")
         h = h + _rms(mixed, p["norm2"], eps)
     with jax.named_scope("lane.dense_ffn"):
         fed = _swiglu(_rms(h, p["norm3"], eps), p["w_gate"], p["w_up"], p["w_down"])
@@ -295,21 +295,20 @@ def ouro_lane_bytes(cfg: OuroConfig) -> int:
     all alive between a leaf's last visit and its first) and the peak of
     its activations: one exit's logits, their softmax and their gradient,
     the input of every visit, one layer's recomputed activations (about 24
-    hidden-sized and 9 feed-forward-sized rows a token) and three copies of
-    the scores alive at once. At the published widths it gives 10.2 GB
-    (the chip's allocator peaks at 8.7 GB: PR 34): one lane fits a 16.9 GB
-    chip, two do not."""
+    hidden-sized and 9 feed-forward-sized rows a token) and what attention
+    keeps alive of its scores (``lane.attention_alive_bytes``). At the
+    published widths it gives 10.2 GB (the chip's allocator peaks at 8.7
+    GB: PR 34): one lane fits a 16.9 GB chip, two do not."""
     n_params = lane._count_params(
         lambda: init_ouro_params(jax.random.key(0), cfg, 1.0))
     t = cfg.seq_len
     visits = (cfg.num_layers + 1) * cfg.total_ut_steps
-    widest = max((hi - lo) * (hi - klo) for lo, hi, klo in lane._attention_spans(
-        t, None, cfg.attn_query_block))
-    at_once = max(min(lane._SCORES_AT_ONCE // widest, cfg.num_kv_heads), 1)
-    activations = 4 * (
-        t * (3 * cfg.vocab_rows + (24 + visits) * cfg.hidden_size
-             + 9 * cfg.intermediate_size)
-        + 3 * at_once * widest * (cfg.num_heads // cfg.num_kv_heads))
+    activations = (
+        4 * t * (3 * cfg.vocab_rows + (24 + visits) * cfg.hidden_size
+                 + 9 * cfg.intermediate_size)
+        + lane.attention_alive_bytes(
+            t, cfg.num_kv_heads, cfg.num_heads // cfg.num_kv_heads, cfg.head_dim,
+            [None], cfg.attn_query_block))
     return 12 * n_params + activations
 
 
@@ -320,7 +319,8 @@ def make_ouro_eval_fn(cfg: OuroConfig = OuroConfig(), data_seed: int = 0):
     ``seq_len``-token sequence, every step all ``total_ut_steps`` passes and
     the loss over all the exits); ``eval_fn.lane_facts`` states its
     footprint, its tokens a step and its counters: :data:`EXIT_COUNTERS`
-    from the device, then :data:`LOOP_COUNTERS`, facts of the loop."""
+    from the device, then :data:`LOOP_COUNTERS`, facts of the loop, and
+    ``lane.attention_counters``, whether the scores stay in VMEM."""
     init_key = jax.random.key(data_seed + 1)
     visits, exits = _visits(cfg), _exits(cfg)
     loop = (cfg.total_ut_steps, cfg.num_layers * cfg.total_ut_steps, len(exits.after))
@@ -331,4 +331,5 @@ def make_ouro_eval_fn(cfg: OuroConfig = OuroConfig(), data_seed: int = 0):
         lane_bytes=ouro_lane_bytes(cfg),
         counted=lane.Counted(
             EXIT_COUNTERS, lambda _, at_exits, n_val: list(at_exits / n_val)),
-        static_counters=tuple(zip(LOOP_COUNTERS, loop)))
+        static_counters=tuple(zip(LOOP_COUNTERS, loop)) + lane.attention_counters(
+            cfg.seq_len, cfg.head_dim, cfg.num_heads // cfg.num_kv_heads))

@@ -219,11 +219,141 @@ def test_blocks_outside_the_band_are_never_computed():
                           moe_intermediate_size=16, attn_query_block=16)
     facts = M.make_mellum2_eval_fn(cfg).lane_facts
     assert facts.counters == (lane.LANE_COUNTERS + M.ATTENTION_COUNTERS
+                              + ("attn_scores_in_vmem",)
                               + tuple(name for name, _ in lane.MOE_COUNTERS))
     assert facts.traced_budget and facts.tokens_per_step == 64
     # queries in 4 blocks: a window of 8 reaches one block back, 1 + 3 x 2;
     # the full layer 1 + 2 + 3 + 4
     assert M.attention_key_blocks(64, [8, 8, 8, None], 16) == (3 * 7 + 10, 4 * 16)
+
+
+# ----------------------------------------------- the fused kernels (Pallas)
+def _kernel_qkv(length, g, r):
+    return _qkv(length, seed=length + r, g=g, r=r, d=128)
+
+
+@pytest.mark.parametrize("operand, limit, length, window, tiles", [
+    # float32 operands: the plain form's float32 sums in another order
+    (jnp.float32, 2e-5, 128, None, (128, 128)),    # a sequence of one tile
+    (jnp.float32, 2e-5, 384, None, (128, 128)),    # of several: 1 + 2 + 3 tiles of keys
+    (jnp.float32, 2e-5, 384, 128, (128, 128)),     # a window that is a multiple of the tile
+    (jnp.float32, 2e-5, 384, 100, (128, 128)),     # and one that is not
+    (jnp.float32, 2e-5, 256, 200, (64, 128)),      # blocks of queries narrower than a tile
+    (jnp.float32, 2e-5, 256, 1, (64, 128)),        # a position sees itself alone
+    # as the chip runs it: both products' operands rounded to bfloat16 (8
+    # bits of mantissa, 2^-9 = 2e-3 an operand; the kernel rounds the
+    # softmax's terms before their sum is divided out, the plain form after)
+    (jnp.bfloat16, 2e-2, 384, None, (128, 128)),
+    (jnp.bfloat16, 2e-2, 384, 100, (128, 128)),
+    (jnp.bfloat16, 2e-2, 512, 200, (64, 256)),
+])
+@pytest.mark.parametrize("g, r", [(2, 1), (1, 8)])
+def test_the_fused_kernels_are_the_plain_form(monkeypatch, operand, limit, length,
+                                              window, tiles, g, r):
+    """``ops.pallas_attention`` in the Pallas interpreter against
+    ``banded_attention``'s plain JAX, heads of 128: the values and the
+    gradients with respect to ``q``, ``k`` and ``v``, each within ``limit``
+    of the largest entry (of one where the plain form gives all zeros: the
+    queries' gradient when a position sees itself alone)."""
+    from hpbandster_tpu.ops import pallas_attention
+
+    monkeypatch.setattr(lane, "_OPERAND", operand)
+    q, k, v = _kernel_qkv(length, g, r)
+    tiles = pallas_attention.Tiles(*tiles)
+    assert pallas_attention.fits(length, 128, r, tiles)
+    t, d = length, 128
+    flat = lambda x: x.reshape(t, -1)     # the kernels take the heads side by side
+    fused = lambda q, k, v: pallas_attention.fused_banded_attention(
+        flat(q), flat(k), flat(v), (g, r, d), window, tiles, operand, "lane.swa", True
+    ).reshape(q.shape)
+    plain = lambda q, k, v: lane.banded_attention(q, k, v, window, 64)
+    weigh = jax.random.normal(jax.random.key(1), q.shape)
+    got, pull = jax.vjp(fused, q, k, v)
+    want, pull_plain = jax.vjp(plain, q, k, v)
+    for ours, theirs in zip((got,) + pull(weigh), (want,) + pull_plain(weigh)):
+        assert ours.shape == theirs.shape and ours.dtype == theirs.dtype
+        np.testing.assert_allclose(
+            ours, theirs, atol=limit * max(float(jnp.abs(theirs).max()), 1.0))
+
+
+def test_the_kernels_visit_the_band_and_one_tile(monkeypatch):
+    """Static facts of the kernels' own range: a window layer's computed
+    band is the window and one block of queries wide, the full layer's the
+    triangle and its diagonal's tiles."""
+    from hpbandster_tpu.ops.pallas_attention import Tiles, fits, tiles_visited
+
+    # 64 blocks of 128 queries: keys from ``lo - 1,023`` to ``lo + 127``, in
+    # tiles of 512 three (the first blocks fewer)
+    assert tiles_visited(8192, 1024, Tiles(128, 512)) == 1 + 1 + 1 + 1 + 2 * 4 + 3 * 56
+    assert tiles_visited(8192, None, Tiles(128, 512)) == 4 * sum(range(1, 17))
+    assert tiles_visited(2048, None, Tiles(512, 512)) == 1 + 2 + 3 + 4
+    assert tiles_visited(256, 1, Tiles(64, 128)) == 4
+    # whole tiles of whole lanes, and a head's keys and values within VMEM
+    assert fits(8192, 128, 8, Tiles(128, 512)) and fits(2048, 128, 1, Tiles(512, 512))
+    assert not fits(8192, 64, 8, Tiles(128, 512))
+    assert not fits(8200, 128, 8, Tiles(128, 512))
+    assert not fits(2 ** 16, 128, 8, Tiles(128, 512))
+
+
+@pytest.mark.parametrize("window", [None, 1, 100, 128, 200, 512, 1000])
+@pytest.mark.parametrize("block_q, block_k", [(64, 128), (128, 128), (256, 128), (128, 512)])
+def test_the_kernels_loops_cover_what_a_block_sees_once(window, block_q, block_k):
+    """The three loops of a block of queries (``_loops``), held
+    against the pairs themselves: every tile that holds a visible pair is
+    walked exactly once, none that holds none, and a tile walked without a
+    mask holds no hidden pair."""
+    from hpbandster_tpu.ops.pallas_attention import Tiles, _loops
+
+    t, tiles = 1024, Tiles(block_q, block_k)
+    at, key = np.arange(t)[:, None], np.arange(t)[None, :]
+    seen = key <= at
+    if window is not None:
+        seen &= at - key < window
+    for lo in range(0, t, block_q):
+        walked = [(j, masked) for first, end, masked in _loops(lo, tiles, window)
+                  for j in range(first, end)]
+        block = seen[lo:lo + block_q]
+        holds = [j for j in range(t // block_k) if block[:, j * block_k:(j + 1) * block_k].any()]
+        assert sorted(j for j, _ in walked) == holds
+        for j, masked in walked:
+            assert masked or block[:, j * block_k:(j + 1) * block_k].all()
+
+
+def test_off_the_chip_the_plain_form_runs_and_the_counter_says_so(monkeypatch):
+    """The rule (``lane._kernel_tiles``) reads the backend and the shapes,
+    nothing else: on the CPU the plain form whatever the shape, and
+    ``attn_scores_in_vmem`` is 0; told that Mosaic compiles here, the
+    kernels at the Mellum2 lane's published size, and the plain form where
+    the keys are few (the Ouro lane's 2,048) or a shape does not fit the
+    kernels' tiles."""
+    published = [(8192, 128, 8), (2048, 128, 1)]
+    for t, d, r in published:
+        assert lane._kernel_tiles(t, d, r) is None
+        assert lane.attention_counters(t, d, r) == (("attn_scores_in_vmem", 0.0),)
+    blocks = lane.attention_key_blocks(8192, [1024, 1024, 1024, None], 1024)
+    bytes_plain = lane.attention_alive_bytes(8192, 4, 8, 128, [1024, None], 1024)
+    assert bytes_plain == 3 * 4 * 8 * 1024 * 8192
+
+    monkeypatch.setattr(lane, "pallas_available", lambda: True)
+    # 8 heads x 128 queries against 512 keys; one head's 512 queries, no
+    # wider than a tile of keys
+    assert lane._kernel_tiles(8192, 128, 8) == (128, 512)
+    assert lane._kernel_tiles(4096, 128, 1) == (512, 512)
+    assert lane.attention_counters(8192, 128, 8) == (("attn_scores_in_vmem", 1.0),)
+    # few keys (a block's scores stay on the plain softmax's fast path), the
+    # tests' lanes (heads of 8 and 16), a length that is no whole tile, a
+    # sequence whose keys do not fit VMEM
+    for t, d, r in [(2048, 128, 1), (64, 8, 2), (8192, 64, 8), (8200, 128, 8),
+                    (2 ** 16, 128, 8)]:
+        assert lane._kernel_tiles(t, d, r) is None
+        assert lane.attention_counters(t, d, r) == (("attn_scores_in_vmem", 0.0),)
+    # the footprint and the counted tiles follow the path that runs: the
+    # kernels keep an output and a log-sum-exp a row, no block of scores
+    assert lane.attention_alive_bytes(8192, 4, 8, 128, [1024, None], 1024) == (
+        4 * 8192 * 32 * (128 + 128)) < bytes_plain
+    computed, square = lane.attention_key_blocks(
+        8192, [1024, 1024, 1024, None], 1024, lane._kernel_tiles(8192, 128, 8))
+    assert computed / square < blocks[0] / blocks[1]
 
 
 # ----------------------------------------------------------------- rotary
@@ -262,6 +392,58 @@ def test_rotation_keeps_norms_and_depends_on_distance_alone():
     np.testing.assert_allclose(jnp.linalg.norm(rows, axis=-1), jnp.linalg.norm(x), rtol=1e-5)
     # the same vector at positions i and j: the product depends on i - j
     np.testing.assert_allclose(rows[3] @ rows[10], rows[20] @ rows[27], rtol=1e-4)
+
+
+@pytest.mark.parametrize("kind", ["sliding", "full"])
+def test_heads_side_by_side_turn_as_heads_apart(kind):
+    """``lane._rotate_side_by_side`` on ``[T, heads x d]`` is ``_rotate`` on
+    ``[T, heads, d]`` to the last bit, and so is its gradient: the same
+    products and sums an entry."""
+    cfg = M.Mellum2Config(head_dim=16)
+    t, heads = 24, 6
+    cos, sin = M._rotary_tables(cfg, kind, t)
+    x = jax.random.normal(jax.random.key(2), (t, heads * 16))
+    apart = lambda x: M._rotate(x.reshape(t, heads, 16), cos, sin).reshape(t, -1)
+    beside = lambda x: lane._rotate_side_by_side(x, cos, sin)
+    np.testing.assert_array_equal(beside(x), apart(x))
+    cube = lambda turn: jax.grad(lambda x: (turn(x) ** 3).sum())(x)
+    np.testing.assert_array_equal(cube(beside), cube(apart))
+
+
+@pytest.mark.parametrize("window, r", [(None, 1), (100, 4)])
+def test_the_mixer_with_the_kernels_is_the_mixer_without(monkeypatch, window, r):
+    """``attention_mixer`` as the chip runs it (the rule told that Mosaic
+    compiles here, the kernels in the Pallas interpreter, heads side by side
+    from the projections to ``wo``) against itself in plain JAX: the output
+    and the gradients with respect to its input and its four matrices,
+    within bfloat16 operands' 2e-2 of the largest entry."""
+    from hpbandster_tpu.ops import pallas_attention
+
+    t, g, d, hidden = 256, 2, 128, 64
+    keys = jax.random.split(jax.random.key(4), 5)
+    x = jax.random.normal(keys[0], (t, hidden))
+    p = {name: jax.random.normal(key, shape) * shape[0] ** -0.5 for key, (name, shape) in zip(
+        keys[1:], {"wq": (hidden, g * r * d), "wk": (hidden, g * d), "wv": (hidden, g * d),
+                   "wo": (g * r * d, hidden)}.items())}
+    mixer = lambda x, p: lane.attention_mixer(
+        x, p, kv_heads=g, heads_per_kv=r, head_dim=d,
+        inv_freq=10000.0 ** (-np.arange(0, d, 2) / d), factor=1.0, window=window,
+        block=64, scope="lane.swa")
+    weigh = jax.random.normal(jax.random.key(5), (t, hidden))
+    want, pull = jax.vjp(mixer, x, p)
+    want = (want,) + tuple(jax.tree.leaves(pull(weigh)))
+
+    monkeypatch.setattr(lane, "pallas_available", lambda: True)
+    monkeypatch.setattr(lane, "_KERNEL_ROWS", 128)
+    monkeypatch.setattr(lane, "_KERNEL_KEYS", 128)
+    monkeypatch.setattr(lane, "_PLAIN_KEYS", 0)
+    in_interpreter = pallas_attention.fused_banded_attention
+    monkeypatch.setattr(
+        pallas_attention, "fused_banded_attention",
+        lambda *args: in_interpreter(*args, True))
+    got, pull = jax.vjp(mixer, x, p)
+    for ours, theirs in zip((got,) + tuple(jax.tree.leaves(pull(weigh))), want):
+        np.testing.assert_allclose(ours, theirs, atol=2e-2 * float(jnp.abs(theirs).max()))
 
 
 # ---------------------------------------------------------- expert layer

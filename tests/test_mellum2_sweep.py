@@ -73,10 +73,13 @@ def test_the_row_counts_the_lanes_and_the_blocks(swept):
     # static facts of the blocking: 64 tokens in blocks of 16, a window of 8
     assert row["attn_key_blocks_computed"] == 3 * 7 + 10
     assert row["attn_key_blocks_square"] == 4 * 16
+    # off the chip every layer's scores go through the plain form
+    assert row["attn_scores_in_vmem"] == 0
     # how the expert layer moves its rows: by gathers, in both passes
     assert row["moe_combine_by_gather"] == 1
     gauges = obs.get_metrics().snapshot()["gauges"]
     assert gauges["sweep.lane.moe_combine_by_gather"] == 1.0
+    assert gauges["sweep.lane.attn_scores_in_vmem"] == 0.0
     assert gauges["sweep.lane.lane_steps"] == 27
 
 

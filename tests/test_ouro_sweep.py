@@ -70,6 +70,8 @@ def test_the_row_counts_the_lanes_and_the_loop(swept):
     assert row["lane_tokens"] == 27 * 32 and row["lanes_at_once"] == 1
     # static facts of the loop: 2 layers run 3 times, an exit a pass
     assert (row["loop_passes"], row["layer_visits_per_pass"], row["exits_trained"]) == (3, 6, 3)
+    # off the chip every layer's scores go through the plain form
+    assert row["attn_scores_in_vmem"] == 0
     # from the device, over the held-out passes: the exit distribution's
     # last term and its entropy over ln 3
     assert 0.0 < row["exit_last_mass"] < 1.0
@@ -79,6 +81,7 @@ def test_the_row_counts_the_lanes_and_the_loop(swept):
     assert not [name for name in opt.eval_fn.lane_facts.counters if name.startswith("moe_")]
     gauges = obs.get_metrics().snapshot()["gauges"]
     assert gauges["sweep.lane.loop_passes"] == 3.0
+    assert gauges["sweep.lane.attn_scores_in_vmem"] == 0.0
     assert gauges["sweep.lane.exit_last_mass"] == pytest.approx(row["exit_last_mass"])
     assert gauges["sweep.lane.lane_steps"] == 27
 

@@ -102,55 +102,114 @@ def test_four_chip_mesh_sweep_compiles_with_pallas_scorer(v5e_devices):
     assert "all-reduce" in text or "all-gather" in text
 
 
-@pytest.mark.parametrize("kind, largest", [
-    # two key/value heads' scores of a block (2 x 8 x 1,024 x 2,048) lie
-    # under the projections' output, 8,192 x 5,120
-    ("sliding", 8192 * 5120),
-    # one key/value head's widest block: 8 x 1,024 x 8,192
-    ("full", 2 ** 26)])
-def test_banded_attention_compiles_for_v5e_at_the_published_size(
-        v5e_devices, kind, largest):
-    """The Mellum2 lane's mixer (``workloads/mellum2.py``) at 8,192 tokens,
-    forward pass only: the chip's compiler takes it, and its largest float32
-    array is a block's scores, not a square's (32 heads x 8,192^2 is 2^31
-    elements, one head's 2^26 in a window layer too)."""
+def _kernel_parts(text):
+    """``[(kernel, lane part)]`` of the compiled text's Mosaic kernels, the
+    part as the benchmark's reduction reads it (``device_phase_map`` by
+    ``LANE_SCOPES``), in the order of their names."""
     import re
 
+    from hpbandster_tpu.obs.profile import device_phase_map
+    from hpbandster_tpu.obs.timeline import LANE_SCOPES
+
+    parts = device_phase_map(text, LANE_SCOPES)
+    kernels = re.findall(
+        r"%(\S+) = [^\n]*custom_call_target=\"tpu_custom_call\"", text)
+    return sorted((name.split(".")[0], parts.get(name)) for name in kernels)
+
+
+def _f32_sizes(text):
+    import re
+
+    return [int(np.prod([int(n) for n in dims.split(",")]))
+            for dims in re.findall(r"f32\[([\d,]+)\]", text)]
+
+
+@pytest.fixture
+def mosaic_compiles_here(monkeypatch):
+    """The lanes' rule asks the backend, which is the CPU here: told that
+    Mosaic compiles, it hands the described chip what the real one gets."""
+    from hpbandster_tpu.workloads import lane
+
+    monkeypatch.setattr(lane, "pallas_available", lambda: True)
+
+
+@pytest.mark.parametrize("kind, scope", [("sliding", "lane.swa"), ("full", "lane.gqa")])
+def test_banded_attention_compiles_for_v5e_at_the_published_size(
+        v5e_devices, mosaic_compiles_here, kind, scope):
+    """The Mellum2 lane's mixer (``workloads/mellum2.py``) at 8,192 tokens,
+    forward and backward pass: the chip's compiler takes it, the scores are
+    a Mosaic kernel's (``ops/pallas_attention.py``) and no float32 array of
+    a block's scores exists (the largest is the projections' output, 8,192
+    x 5,120, for both kinds; the plain form's widest block was 8 x 1,024 x
+    8,192), and the forward and the backward kernel both carry the caller's
+    part in the compiled text, which is what charges their device time to
+    ``lane.swa`` / ``lane.gqa``."""
     from hpbandster_tpu.workloads import mellum2 as M
 
     one = SingleDeviceSharding(v5e_devices[0])
     cfg = M.Mellum2Config()
     leaves = {name: _sds(shape, jnp.float32, one)
               for name, shape in M._layer_shapes(cfg).items()}
-    compiled = jax.jit(lambda x, p: M._attention(x, p, kind, cfg)).lower(
-        _sds((cfg.seq_len, cfg.hidden_size), jnp.float32, one), leaves).compile()
-    sizes = [int(np.prod([int(n) for n in dims.split(",")]))
-             for dims in re.findall(r"f32\[([\d,]+)\]", compiled.as_text())]
-    assert max(sizes) == largest
+    x = _sds((cfg.seq_len, cfg.hidden_size), jnp.float32, one)
+
+    def both_passes(x, p, dy):
+        with jax.named_scope(scope):
+            y, pull = jax.vjp(lambda x, p: M._attention(x, p, kind, cfg), x, p)
+        return y, pull(dy)     # pulled back where the caller's scope is closed
+
+    text = jax.jit(both_passes).lower(x, leaves, x).compile().as_text()
+    assert _kernel_parts(text) == [
+        ("banded_attention_backward", scope), ("banded_attention_forward", scope)]
+    assert max(_f32_sizes(text)) == cfg.seq_len * 5120
 
 
-def test_a_looped_layer_compiles_for_v5e_at_the_published_size(v5e_devices):
+def test_a_looped_layer_compiles_for_v5e_at_the_published_size(
+        v5e_devices, mosaic_compiles_here):
     """The Ouro lane's layer (``workloads/ouro.py``: the lanes' one attention
     at 16 key/value heads of ONE query head each, then the SwiGLU) at 2,048
-    tokens, forward pass only: the chip's compiler takes it, the scores stay
-    two-dimensional a head (rows = queries x 1), and no float32 array is
-    larger than the SwiGLU's gate and up side by side (2,048 x 11,264): the
-    widest block's scores of all 16 heads (16 x 512 x 2,048) lie under it,
-    and no head's square is ever whole (2,048 x 2,048 times 16 heads)."""
-    import re
-
+    tokens: the chip's compiler takes it; at so few keys the rule keeps the
+    plain form on the chip too (``lane._PLAIN_KEYS``: no Mosaic kernel), the
+    scores stay two-dimensional a head (rows = queries x 1), and no float32
+    array is larger than the SwiGLU's gate and up side by side (2,048 x
+    11,264): the widest block's scores of all 16 heads (16 x 512 x 2,048)
+    lie under it, and no head's square is ever whole. Then the same layers
+    at twice the keys as the trainer runs them, a loop over the stacked
+    leaves forward and another backward: there the scores are the kernels',
+    and both carry ``lane.gqa`` inside the loops' bodies too."""
+    from hpbandster_tpu.workloads import lane
     from hpbandster_tpu.workloads import ouro as O
 
     one = SingleDeviceSharding(v5e_devices[0])
     cfg = O.OuroConfig()
-    leaves = {name: _sds(shape, jnp.float32, one)
-              for name, shape in O._layer_shapes(cfg).items()}
+    shapes = O._layer_shapes(cfg)
     compiled = jax.jit(lambda h, p: O._layer(h, p, cfg)[0]).lower(
-        _sds((cfg.seq_len, cfg.hidden_size), jnp.float32, one), leaves).compile()
-    sizes = [int(np.prod([int(n) for n in dims.split(",")]))
-             for dims in re.findall(r"f32\[([\d,]+)\]", compiled.as_text())]
+        _sds((cfg.seq_len, cfg.hidden_size), jnp.float32, one),
+        {name: _sds(shape, jnp.float32, one) for name, shape in shapes.items()}).compile()
+    sizes = _f32_sizes(compiled.as_text())
     assert max(sizes) == cfg.seq_len * 2 * cfg.intermediate_size
     assert 16 * cfg.attn_query_block * cfg.seq_len in sizes
+    assert _kernel_parts(compiled.as_text()) == []
+
+    longer = cfg._replace(seq_len=2 * cfg.seq_len)
+    visit = O._visits(longer)[0]
+    assert visit.times == cfg.num_layers
+
+    def a_pass_and_back(h, p, dh):
+        out, _, kept = lane._visit_forward(visit, h, p)
+        take_it = lambda pull, dh, written, _: pull(dh)
+        return out, lane._visit_backward(
+            visit, take_it, dh, kept, p, jax.tree.map(jnp.zeros_like, p),
+            scope="lane.accumulate")
+
+    h = _sds((longer.seq_len, cfg.hidden_size), jnp.float32, one)
+    stacked = {name: _sds((cfg.num_layers,) + shape, jnp.float32, one)
+               for name, shape in shapes.items()}
+    text = jax.jit(a_pass_and_back).lower(h, stacked, h).compile().as_text()
+    # the forward kernel twice: the pass, and the backward pass's own
+    # recomputation of a visit's inside
+    assert _kernel_parts(text) == [
+        ("banded_attention_backward", "lane.gqa"), ("banded_attention_forward", "lane.gqa"),
+        ("banded_attention_forward", "lane.gqa")]
 
 
 @pytest.mark.parametrize("score", ["softmax", "sigmoid"])
