@@ -38,7 +38,7 @@ import re
 import tempfile
 import threading
 import time
-from typing import Any, Dict, List, Optional, Sequence
+from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 from hpbandster_tpu.obs.metrics import get_metrics
 
@@ -46,8 +46,10 @@ __all__ = [
     "ProfileSession",
     "get_profile_session",
     "device_peaks",
+    "ProgramText",
     "device_phase_map",
     "hlo_module_name",
+    "parse_program_text",
     "roofline_report",
     "format_roofline",
     "transfer_summary",
@@ -409,6 +411,25 @@ _HLO_CALLEES = re.compile(
 )
 _HLO_BRANCHES = re.compile(r"branch_computations=\{([^}]*)\}")
 _SCOPE_NAME = re.compile(r"[a-z]+\.[a-z_]+")
+#: a transformation's wrapper around one name of an ``op_name``, innermost
+#: first: ``jvp(lane.gqa)``, ``vmap()``, then ``transpose(...)`` around it
+_WRAPPED = re.compile(r"[\w.\-]*\([^()]*\)")
+#: what ``jax.checkpoint`` calls the forward it computes again inside a
+#: backward pass (jax 0.9.0: ``.../checkpoint/rematted_computation/...``)
+_REMATTED = "rematted_computation"
+
+
+class ProgramText(NamedTuple):
+    """What :func:`device_phase_map` reads of one program's text, whatever
+    the family of names it is asked for (:func:`parse_program_text`)."""
+
+    #: ``jit_hpb_sweep`` of ``HloModule jit_hpb_sweep, ...``
+    module: str
+    #: the ``ENTRY`` computation's name
+    entry: str
+    #: ``{computation: [(instruction, its op_name or None, the
+    #: computations it calls)]}``, names without their ``%``
+    computations: Dict[str, List[Tuple[str, Optional[str], List[str]]]]
 
 
 def _hlo_text(compiled: Any) -> str:
@@ -425,12 +446,58 @@ def hlo_module_name(compiled: Any) -> str:
     return match.group(1)
 
 
+def parse_program_text(compiled: Any) -> ProgramText:
+    """One pass over a compiled program's optimized HLO text
+    (``compiled.as_text()``; the text itself is taken too): the
+    computations' call graph and every instruction's ``op_name``. Neither
+    depends on a family of scope names, so one parse serves them all
+    (``optimizers.sweep_phase_maps`` keeps it by executable). Seconds for a
+    large program."""
+    text = _hlo_text(compiled)
+    computations: Dict[str, List[Tuple[str, Optional[str], List[str]]]] = {}
+    op_names: Dict[str, str] = {}   # one string a distinct op_name
+    entry, current = None, None
+    for line in text.splitlines():
+        header = _HLO_COMPUTATION.match(line)
+        if header is not None:
+            current = computations.setdefault(header.group(2), [])
+            if header.group(1):
+                entry = header.group(2)
+            continue
+        instruction = _HLO_INSTRUCTION.match(line)
+        if instruction is None or current is None:
+            continue
+        op_name = _HLO_OP_NAME.search(line)
+        callees = _HLO_CALLEES.findall(line)
+        for group in _HLO_BRANCHES.findall(line):
+            callees += [c.strip().lstrip("%") for c in group.split(",")]
+        current.append((
+            instruction.group(1),
+            op_names.setdefault(op_name.group(1), op_name.group(1)) if op_name else None,
+            callees,
+        ))
+    if entry is None:
+        raise ValueError("the compiled text has no ENTRY computation")
+    return ProgramText(hlo_module_name(text), entry, computations)
+
+
+def _outside_wrappers(op_name: str) -> str:
+    """``op_name`` without what its transformations wrap:
+    ``pass.backward/transpose(pass.recompute)/jvp(lane.moe)/lane.moe/mul``
+    -> ``pass.backward///lane.moe/mul``."""
+    while True:
+        stripped = _WRAPPED.sub("", op_name)
+        if stripped == op_name:
+            return stripped
+        op_name = stripped
+
+
 def device_phase_map(
     compiled: Any, scopes: Optional[Sequence[str]] = None
 ) -> Dict[str, str]:
     """``{instruction name: phase}`` of one compiled program, read off its
-    optimized HLO text (``compiled.as_text()``; the text itself is taken
-    too).
+    optimized HLO text (``compiled.as_text()``; the text itself and a
+    :class:`ProgramText` parsed before are taken too).
 
     A profiler trace prints device operations by the compiler's
     instruction names (``multiply_subtract_fusion.546``), which change with
@@ -442,48 +509,59 @@ def device_phase_map(
     scopes are flat, so there is at most one, and the last would win).
     ``scopes`` names another closed list to read by
     (:data:`~hpbandster_tpu.obs.timeline.LANE_SCOPES`, the parts of a
-    lane); names of one list are not seen when reading by another. An
-    instruction without one inside a nested computation — a loop's body,
-    a fusion, a reducer — inherits its caller's. Instructions that stay
-    without a phase are left out. Names lose their ``%``.
+    lane; :data:`~hpbandster_tpu.obs.timeline.MOE_SCOPES`, the pieces of
+    its expert layer); names of one list are not seen when reading by
+    another. An instruction without one inside a nested computation — a
+    loop's body, a fusion, a reducer — inherits its caller's. Instructions
+    that stay without a phase are left out. Names lose their ``%``.
+
+    **The passes** (:data:`~hpbandster_tpu.obs.timeline.PASS_SCOPES`) are
+    read by a rule of their own: a name counts only where it stands outside
+    every wrapper of the ``op_name``; of those, the last wins. A
+    transformation writes the scope that was ambient where its primal was
+    traced back into the name, wrapped: the backward rule of a
+    ``custom_vjp`` traced under ``pass.recompute`` and pulled back under
+    ``pass.backward`` is ``pass.backward/transpose(pass.recompute)/
+    jvp(lane.moe)/lane.moe/dot_general``, a loop's backward pass
+    ``pass.backward/transpose(jvp(lane.gqa))/vmap(pass.recompute)/...``:
+    both are the backward pass's, where "the last name found" would say the
+    recomputation's. A recomputation inside a backward rule stands outside
+    (``pass.backward/.../lane.moe/pass.recompute/jvp()/...``) and wins, and
+    so does what ``jax.checkpoint`` computes again in a backward pass
+    (``.../checkpoint/rematted_computation/...``): ``pass.recompute``. A
+    part keeps its rule: ``transpose(jvp(lane.gqa))`` is ``lane.gqa``'s.
 
     Parsing a large program's text takes seconds: call this on demand,
     never on a sweep's path."""
-    if scopes is None:
-        from hpbandster_tpu.obs.timeline import DEVICE_SCOPES as scopes
+    from hpbandster_tpu.obs.timeline import DEVICE_SCOPES, PASS_SCOPES
 
-    computations: Dict[str, List[Any]] = {}
-    entry, current = None, None
-    for line in _hlo_text(compiled).splitlines():
-        header = _HLO_COMPUTATION.match(line)
-        if header is not None:
-            current = computations.setdefault(header.group(2), [])
-            if header.group(1):
-                entry = header.group(2)
-            continue
-        instruction = _HLO_INSTRUCTION.match(line)
-        if instruction is None or current is None:
-            continue
-        op_name = _HLO_OP_NAME.search(line)
-        found = [
-            s for s in _SCOPE_NAME.findall(op_name.group(1)) if s in scopes
-        ] if op_name else []
-        callees = _HLO_CALLEES.findall(line)
-        for group in _HLO_BRANCHES.findall(line):
-            callees += [c.strip().lstrip("%") for c in group.split(",")]
-        current.append(
-            (instruction.group(1), found[-1] if found else None, callees)
-        )
-    if entry is None:
-        raise ValueError("the compiled text has no ENTRY computation")
+    scopes = frozenset(DEVICE_SCOPES if scopes is None else scopes)
+    by_pass = scopes == frozenset(PASS_SCOPES)
+    program = (compiled if isinstance(compiled, ProgramText)
+               else parse_program_text(compiled))
+
+    found: Dict[Optional[str], Optional[str]] = {None: None}
+
+    def scope_of(op_name: str) -> Optional[str]:
+        if by_pass:
+            names = [
+                "pass.recompute" if s == _REMATTED else s
+                for s in _outside_wrappers(op_name).split("/")
+                if s in scopes or s == _REMATTED
+            ]
+        else:
+            names = [s for s in _SCOPE_NAME.findall(op_name) if s in scopes]
+        return names[-1] if names else None
 
     phases: Dict[str, str] = {}
-    inherited: Dict[str, Optional[str]] = {entry: None}
-    queue = [entry]
+    inherited: Dict[str, Optional[str]] = {program.entry: None}
+    queue = [program.entry]
     while queue:
         name = queue.pop()
-        for instruction, own, callees in computations.get(name, ()):
-            phase = own or inherited[name]
+        for instruction, op_name, callees in program.computations.get(name, ()):
+            if op_name not in found:
+                found[op_name] = scope_of(op_name)
+            phase = found[op_name] or inherited[name]
             if phase is not None:
                 phases[instruction] = phase
             for callee in callees:

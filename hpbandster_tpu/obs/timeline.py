@@ -129,7 +129,7 @@ DEVICE_SCOPES = (
 #: a second closed list, of the parts of one lane: ``jax.named_scope``
 #: names that a workload whose lane has layers of several kinds
 #: (``workloads/kimi_linear.py``, ``workloads/mellum2.py``, ``workloads/ouro.py``) sets *inside* ``hpb.train`` and
-#: ``hpb.validate``. The two families do not see each other:
+#: ``hpb.validate``. The families do not see each other:
 #: ``device_phase_map(compiled)`` reads the phases above,
 #: ``device_phase_map(compiled, LANE_SCOPES)`` these
 LANE_SCOPES = (
@@ -143,6 +143,40 @@ LANE_SCOPES = (
     "lane.update",     # the optimizer's step
     "lane.exit",       # a looped model's exits: gate, exit distribution, the losses' weighted sum, entropy
     "lane.accumulate", # adding a visit's gradient into the sum of a leaf that several visits share
+)
+
+#: a third closed list, of the pass an instruction of a lane belongs to,
+#: set by the one trainer (``workloads/lane.py``) and in no model's file:
+#: ``_forward`` (the trace that training and held-out passes share); the
+#: call ``jax.vjp(visit.through ...)`` of ``_visit_backward``, which
+#: computes a visit's inside again, and a backward rule's own recomputation
+#: (``_routed_backward``'s tiles); the pull-back's call, the exits'
+#: ``jax.grad`` and the embedding's gradient. The update and the sums keep
+#: ``lane.update`` / ``lane.accumulate`` and carry no pass. Read by a rule of
+#: its own (``obs.profile.device_phase_map``: only a name that stands outside
+#: every wrapper of the ``op_name`` counts), because a transformation
+#: writes the scope that was ambient where its primal was traced back into
+#: the name: ``pass.backward/transpose(pass.recompute)/...`` is the
+#: backward pass's
+PASS_SCOPES = (
+    "pass.forward",    # the forward trace of every pass, training or held out
+    "pass.recompute",  # a visit's (a tile's, a checkpointed block's) inside computed again for its gradient
+    "pass.backward",   # the pull-backs: the exits', each visit's, the embedding's
+)
+
+#: a fourth, of the pieces of the expert layer, inside ``lane.moe``
+#: (``workloads/lane.py`` ``moe_held_experts`` and both rules of
+#: ``_routed``, which name their own as they name ``lane.moe``). Named by
+#: what moves: a gather by ``order`` (token row -> sorted row) is the
+#: dispatch's in either pass, a gather by ``place`` with its sum over the
+#: top k the combine's
+MOE_SCOPES = (
+    "moe.router",      # logits, scores, top k, the chosen scores and weights
+    "moe.sort",        # slots, the counting sort, ``order``, the groups' ends, the loads' counters
+    "moe.dispatch",    # rows gathered by ``order``: the tiles' inputs, the output's gradient with its weights
+    "moe.experts",     # the grouped products, the experts' gradient sums, the closing group's zeros and the cast
+    "moe.combine",     # rows gathered by ``place`` and summed over the top k, both ways; the weights' gradient
+    "moe.shared",      # the shared expert's SwiGLU, where the layer has one
 )
 
 #: attribution priority when concurrent spans overlap (lower = wins):

@@ -305,7 +305,9 @@ class SweepDriver:
         artifacts never double-counts a compile. On a miss the two halves
         are spans of their own (``compile.trace_lower``: Python trace and
         lowering; ``compile.compile``: XLA, or the persistent cache's
-        load)."""
+        load), and their seconds are added to the gauges
+        ``sweep.build.trace_lower_s`` / ``sweep.build.compile_s``: sums over
+        the process's builds, which a hit does not touch."""
         key = self._key(plans, caps)
         compiled = _SWEEP_EXE_CACHE.get(key)
         hit, dt = compiled is not None, 0.0
@@ -321,10 +323,15 @@ class SweepDriver:
             t0 = time.perf_counter()
             with span("compile.trace_lower", COMPILE):
                 lowered = self.build(plans, caps).lower(*args)
+            t1 = time.perf_counter()
             with span("compile.compile", COMPILE):
                 compiled = lowered.compile()
-            dt = time.perf_counter() - t0
+            t2 = time.perf_counter()
+            dt = t2 - t0
             _SWEEP_EXE_CACHE[key] = compiled
+            gauge = obs.get_metrics().gauge
+            gauge("sweep.build.trace_lower_s").inc(t1 - t0)
+            gauge("sweep.build.compile_s").inc(t2 - t1)
         self.last_executable = compiled
         return compiled, dt, hit
 

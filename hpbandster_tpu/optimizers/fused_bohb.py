@@ -23,6 +23,7 @@ import logging
 import math
 import sys
 import time
+import weakref
 from typing import Any, Dict, List, Optional
 
 import numpy as np
@@ -50,6 +51,12 @@ __all__ = ["FusedBOHB", "FusedHyperBand", "FusedRandomSearch", "FusedH2BO",
            "sweep_phase_maps"]
 
 
+#: what ``sweep_phase_maps`` has read of an executable's text, kept while
+#: the executable lives: the text is fetched and parsed once a process,
+#: whatever the number of families asked for
+_PROGRAM_TEXTS: "weakref.WeakKeyDictionary" = weakref.WeakKeyDictionary()
+
+
 def sweep_phase_maps(scopes=None) -> Dict[str, Dict[str, str]]:
     """``{module name: {instruction name: phase}}`` over every sweep
     executable this process holds: the program's own map from what a
@@ -58,27 +65,32 @@ def sweep_phase_maps(scopes=None) -> Dict[str, Dict[str, str]]:
     to the phases of ``obs.timeline.DEVICE_SCOPES``
     (``obs.profile.device_phase_map``), or to those of another closed list
     of scope names given as ``scopes`` (``obs.timeline.LANE_SCOPES``: the
-    parts of a lane, inside the trainer). Two executables of one module name
+    parts of a lane, inside the trainer; ``PASS_SCOPES``: its forward,
+    recomputed and backward passes; ``MOE_SCOPES``: the pieces of its
+    expert layer). Two executables of one module name
     (a chunked run that crossed a capacity bucket) share an entry; an
     instruction name they give different phases is left out, and so is an
-    executable whose text names no scope at all. Parses each executable's
-    text: seconds for a large program, so call it after the sweeps, never
-    between them."""
-    from hpbandster_tpu.obs.profile import device_phase_map, hlo_module_name
+    executable whose text names no scope of the list at all. An
+    executable's text is fetched and parsed once (seconds for a large
+    program: the call graph and the ``op_name``s serve every list), so call
+    it after the sweeps, never between them."""
+    from hpbandster_tpu.obs.profile import device_phase_map, parse_program_text
 
     maps: Dict[str, Dict[str, str]] = {}
     clashed = set()
     for compiled in _SWEEP_EXE_CACHE.values():
-        text = compiled.as_text()
-        phases = device_phase_map(text, scopes)
+        program = _PROGRAM_TEXTS.get(compiled)
+        if program is None:
+            program = _PROGRAM_TEXTS[compiled] = parse_program_text(compiled)
+        phases = device_phase_map(program, scopes)
         if not phases:
-            # loaded from a persistent cache that a commit without scopes
-            # filled (the cache's key leaves metadata out): nothing to tell
+            # loaded from a persistent cache that a commit without these
+            # scopes filled (the cache's key leaves metadata out): nothing
+            # to tell
             continue
-        module = hlo_module_name(text)
         for name, phase in phases.items():
-            if maps.setdefault(module, {}).setdefault(name, phase) != phase:
-                clashed.add((module, name))
+            if maps.setdefault(program.module, {}).setdefault(name, phase) != phase:
+                clashed.add((program.module, name))
     for module, name in clashed:
         del maps[module][name]
     return maps
@@ -328,27 +340,26 @@ class FusedBOHB:
             # the first time this process meets the object: the check is a
             # whole Python trace of it, and its verdict can only be what it
             # was the construction before
-            with sweep_span("construct.eval_shape", ADMISSION,
-                            self._phase_carry):
-                d = int(self.codec.kind.shape[0])
-                if getattr(eval_fn, "lane_facts", None) is not None:
-                    # a maker that states its lane's facts (ops.fused.LaneFacts)
-                    # has stated a scalar loss with them: a lane that large
-                    # takes seconds of host time to trace, and a process that
-                    # paid them once would still pay them in its set-up
-                    traced = False
-                else:
-                    lowest = float(min_budget)
-                    traced = check_once(
-                        (stateful_eval if eval_fn is None else eval_fn,
-                         d, lowest),
-                        lambda: _check_objective(
-                            eval_fn, stateful_eval, d, lowest),
-                    )
-                #: 1 where this constructor traced its objective, 0 where
-                #: the memo (or ``lane_facts``) answered: the first
-                #: ``run_stats`` row takes it as its ``construct_traced``
-                self._construct_traced = int(traced)
+            d = int(self.codec.kind.shape[0])
+            if getattr(eval_fn, "lane_facts", None) is not None:
+                # a maker that states its lane's facts (ops.fused.LaneFacts)
+                # has stated a scalar loss with them: a lane that large
+                # takes seconds of host time to trace, and a process that
+                # paid them once would still pay them in its set-up
+                traced = False
+            else:
+                lowest = float(min_budget)
+                traced = check_once(
+                    (stateful_eval if eval_fn is None else eval_fn,
+                     d, lowest),
+                    lambda: _check_objective(
+                        eval_fn, stateful_eval, d, lowest),
+                )
+            #: 1 where this constructor traced its objective, 0 where
+            #: the memo (or ``lane_facts``) answered: the first
+            #: ``run_stats`` row takes it as its ``construct_traced``
+            #: (the trace's seconds are ``construct``'s)
+            self._construct_traced = int(traced)
             self.eval_fn = eval_fn
             self.stateful_eval = stateful_eval
             self.run_id = run_id

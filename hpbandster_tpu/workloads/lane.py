@@ -494,25 +494,33 @@ def _routed_forward(x, weight, e_in, e_down, order, place, ends, top_k, rows):
     has none."""
     with jax.named_scope("lane.moe"):
         d = x.shape[1]
-        xb = x.astype(_OPERAND)
-        n_held = ends[-1]
+        with jax.named_scope("moe.dispatch"):
+            xb = x.astype(_OPERAND)
+        with jax.named_scope("moe.experts"):
+            n_held = ends[-1]
 
         def tile(i, ys):
             # dispatch: a gather by ``order``
-            take = jax.lax.dynamic_slice(order, (i * rows,), (rows,))
-            return jax.lax.dynamic_update_slice(ys, _tile_experts(
-                xb[take // top_k], e_in, e_down, _tile_sizes(ends, i * rows, rows)),
-                (i * rows, 0))
+            with jax.named_scope("moe.dispatch"):
+                take = jax.lax.dynamic_slice(order, (i * rows,), (rows,))
+                xs = xb[take // top_k]
+            with jax.named_scope("moe.experts"):
+                return jax.lax.dynamic_update_slice(ys, _tile_experts(
+                    xs, e_in, e_down, _tile_sizes(ends, i * rows, rows)),
+                    (i * rows, 0))
 
         # what the tiles produce is the sorted rows, and only the tiles
         # that a held choice reaches are computed. The rows start as the
         # device finds them (no fill: a pass over 0.6 GB a layer at the
         # Mellum2 lane's size): a reached tile writes all of its rows, and
         # the combine reads no row of another
-        ys = jax.lax.fori_loop(
-            0, -(-n_held // rows), tile, jax.lax.empty((order.shape[0], d), jnp.float32))
+        # the loop is the experts' (what in its body has no name of its own)
+        with jax.named_scope("moe.experts"):
+            ys = jax.lax.fori_loop(
+                0, -(-n_held // rows), tile, jax.lax.empty((order.shape[0], d), jnp.float32))
         # combine: a gather by ``place``, summed over the top k
-        y = _sum_of_choices(ys, place, place < n_held, top_k, weight)
+        with jax.named_scope("moe.combine"):
+            y = _sum_of_choices(ys, place, place < n_held, top_k, weight)
     return y, (x, weight, e_in, e_down, order, place, ends)
 
 
@@ -524,33 +532,48 @@ def _routed_backward(top_k, rows, kept, dy):
     x, weight, e_in, e_down, order, place, ends = kept
     with jax.named_scope("lane.moe"):
         d = x.shape[1]
-        xb = x.astype(_OPERAND)
-        n_held = ends[-1]
+        with jax.named_scope("moe.dispatch"):
+            xb = x.astype(_OPERAND)
+        with jax.named_scope("moe.experts"):
+            n_held = ends[-1]
         weight_of = weight.reshape(-1)
 
         def tile(i, grads):
             g_in, g_down, dxs, dweights = grads
-            take = jax.lax.dynamic_slice(order, (i * rows,), (rows,))
-            token = take // top_k
-            sizes = _tile_sizes(ends, i * rows, rows)
-            ys, pull = jax.vjp(
-                lambda xs, e_in, e_down: _tile_experts(xs, e_in, e_down, sizes),
-                xb[token], e_in, e_down)
-            dy_rows = dy[token]
-            t_xs, t_in, t_down = pull(dy_rows * weight_of[take][:, None])
-            return (g_in + t_in, g_down + t_down,
-                    jax.lax.dynamic_update_slice(dxs, t_xs, (i * rows, 0)),
-                    jax.lax.dynamic_update_slice(
-                        dweights, (dy_rows * ys).sum(-1), (i * rows,)))
+            with jax.named_scope("moe.dispatch"):
+                take = jax.lax.dynamic_slice(order, (i * rows,), (rows,))
+                token = take // top_k
+            with jax.named_scope("moe.experts"):
+                sizes = _tile_sizes(ends, i * rows, rows)
+            with jax.named_scope("moe.dispatch"):
+                xs = xb[token]
+            # the rule's own recomputation, named as the trainer's is
+            with jax.named_scope("moe.experts"), jax.named_scope("pass.recompute"):
+                ys, pull = jax.vjp(
+                    lambda xs, e_in, e_down: _tile_experts(xs, e_in, e_down, sizes),
+                    xs, e_in, e_down)
+            # the combine's transpose: a gather by ``order``, as the dispatch is
+            with jax.named_scope("moe.dispatch"):
+                dy_rows = dy[token]
+                dys = dy_rows * weight_of[take][:, None]
+            with jax.named_scope("moe.experts"):
+                t_xs, t_in, t_down = pull(dys)
+                grads = (g_in + t_in, g_down + t_down,
+                         jax.lax.dynamic_update_slice(dxs, t_xs, (i * rows, 0)))
+            with jax.named_scope("moe.combine"):
+                return grads + (jax.lax.dynamic_update_slice(
+                    dweights, (dy_rows * ys).sum(-1), (i * rows,)),)
 
-        g_in, g_down, dxs, dweights = jax.lax.fori_loop(
-            0, -(-n_held // rows), tile,
-            (jnp.zeros_like(e_in), jnp.zeros_like(e_down),
-             jax.lax.empty((order.shape[0], d), xb.dtype),
-             jax.lax.empty(order.shape, jnp.float32)))
-        held = place < n_held
-        dx = _sum_of_choices(dxs, place, held, top_k)
-        dweight = jnp.where(held, dweights[place], 0.0).reshape(weight.shape)
+        with jax.named_scope("moe.experts"):
+            g_in, g_down, dxs, dweights = jax.lax.fori_loop(
+                0, -(-n_held // rows), tile,
+                (jnp.zeros_like(e_in), jnp.zeros_like(e_down),
+                 jax.lax.empty((order.shape[0], d), xb.dtype),
+                 jax.lax.empty(order.shape, jnp.float32)))
+        with jax.named_scope("moe.combine"):
+            held = place < n_held
+            dx = _sum_of_choices(dxs, place, held, top_k)
+            dweight = jnp.where(held, dweights[place], 0.0).reshape(weight.shape)
     return dx, dweight, g_in, g_down, None, None, None
 
 
@@ -576,33 +599,35 @@ def moe_held_experts(x, p, layer: ExpertLayer):
     gathers alone (:func:`_routed`)."""
     t = x.shape[0]
     top_k, held = layer.top_k, len(layer.held)
-    logits = jnp.matmul(x, p["router"], precision=_FLOAT32)
-    s = (jax.nn.sigmoid(logits) if layer.score == "sigmoid"
-         else jax.nn.softmax(logits, axis=-1))
-    _, chosen = jax.lax.top_k(s + p["router_bias"] if "router_bias" in p else s, top_k)
-    # the chosen scores by comparison and not by index: the transpose of
-    # ``take_along_axis`` is a scatter-add too
-    s_chosen = jnp.where(
-        chosen[:, :, None] == jnp.arange(layer.outputs), s[:, None, :], 0.0).sum(-1)
-    weight = s_chosen / s_chosen.sum(-1, keepdims=True)
-    if layer.scaling != 1.0:
-        weight = weight * layer.scaling
+    with jax.named_scope("moe.router"):
+        logits = jnp.matmul(x, p["router"], precision=_FLOAT32)
+        s = (jax.nn.sigmoid(logits) if layer.score == "sigmoid"
+             else jax.nn.softmax(logits, axis=-1))
+        _, chosen = jax.lax.top_k(s + p["router_bias"] if "router_bias" in p else s, top_k)
+        # the chosen scores by comparison and not by index: the transpose of
+        # ``take_along_axis`` is a scatter-add too
+        s_chosen = jnp.where(
+            chosen[:, :, None] == jnp.arange(layer.outputs), s[:, None, :], 0.0).sum(-1)
+        weight = s_chosen / s_chosen.sum(-1, keepdims=True)
+        if layer.scaling != 1.0:
+            weight = weight * layer.scaling
     slot_of = np.full((layer.outputs,), held, np.int32)          # held = "not here"
     slot_of[list(layer.held)] = np.arange(held)
-    slot = jnp.asarray(slot_of)[chosen].reshape(-1)              # [T * k]
-    # a counting sort, stable: a choice's place is its slot's start plus the
-    # earlier choices of its slot (the chip's compiler takes ten seconds
-    # over an ``argsort`` of this length, and there is one a layer and pass)
-    in_slot = (slot[:, None] == jnp.arange(held + 1)[None, :]).astype(jnp.int32)
-    all_loads = in_slot.sum(0)
-    place = (in_slot * (jnp.cumsum(in_slot, 0) - in_slot
-                        + (jnp.cumsum(all_loads) - all_loads)[None, :])).sum(1)
-    loads = all_loads[:held]
-    rows = min(t * top_k,
-               max(min(4 * t * top_k * held // layer.outputs, _TILE_ROWS), 8))
-    n_tiles = -(-t * top_k // rows)
-    order = jnp.zeros((n_tiles * rows,), jnp.int32).at[place].set(
-        jnp.arange(t * top_k, dtype=jnp.int32), unique_indices=True)
+    with jax.named_scope("moe.sort"):
+        slot = jnp.asarray(slot_of)[chosen].reshape(-1)              # [T * k]
+        # a counting sort, stable: a choice's place is its slot's start plus the
+        # earlier choices of its slot (the chip's compiler takes ten seconds
+        # over an ``argsort`` of this length, and there is one a layer and pass)
+        in_slot = (slot[:, None] == jnp.arange(held + 1)[None, :]).astype(jnp.int32)
+        all_loads = in_slot.sum(0)
+        place = (in_slot * (jnp.cumsum(in_slot, 0) - in_slot
+                            + (jnp.cumsum(all_loads) - all_loads)[None, :])).sum(1)
+        loads = all_loads[:held]
+        rows = min(t * top_k,
+                   max(min(4 * t * top_k * held // layer.outputs, _TILE_ROWS), 8))
+        n_tiles = -(-t * top_k // rows)
+        order = jnp.zeros((n_tiles * rows,), jnp.int32).at[place].set(
+            jnp.arange(t * top_k, dtype=jnp.int32), unique_indices=True)
 
     # every row of a tile belongs to a group: after the held experts comes
     # one whose weights are zero and takes the rows that are not for this
@@ -611,12 +636,18 @@ def moe_held_experts(x, p, layer: ExpertLayer):
     # output cannot reach.
     with_rest = lambda w: jnp.concatenate([w, jnp.zeros_like(w[:1])]).astype(_OPERAND)
     # gate and up side by side: one grouped product for the two
-    y = _routed(x, weight, with_rest(jnp.concatenate([p["e_gate"], p["e_up"]], -1)),
-                with_rest(p["e_down"]), order, place, jnp.cumsum(loads), top_k, rows)
+    with jax.named_scope("moe.experts"):
+        e_in = with_rest(jnp.concatenate([p["e_gate"], p["e_up"]], -1))
+        e_down = with_rest(p["e_down"])
+    with jax.named_scope("moe.sort"):
+        ends = jnp.cumsum(loads)
+    y = _routed(x, weight, e_in, e_down, order, place, ends, top_k, rows)
     if "shared_gate" in p:
-        y = y + _swiglu(x, p["shared_gate"], p["shared_up"], p["shared_down"])
-    load = loads.astype(jnp.float32)
-    counters = jnp.stack([load.sum(), load.max() / jnp.maximum(load.mean(), 1e-9)])
+        with jax.named_scope("moe.shared"):
+            y = y + _swiglu(x, p["shared_gate"], p["shared_up"], p["shared_down"])
+    with jax.named_scope("moe.sort"):
+        load = loads.astype(jnp.float32)
+        counters = jnp.stack([load.sum(), load.max() / jnp.maximum(load.mean(), 1e-9)])
     return y, counters
 
 
@@ -729,11 +760,19 @@ def _visit_backward(visit: Visit, one, dh, kept, p, written, read=None, *, scope
     out and putting it back is charged to ``scope``, the part that ``one``
     rewrites them for. ``-> (dh, written anew)``."""
     def pull(h, q):
-        return lambda dh: jax.vjp(lambda h, q: visit.through(h, q)[0], h, q)[1](dh)
+        def pulled(dh):
+            with jax.named_scope("pass.recompute"):
+                _, back = jax.vjp(lambda h, q: visit.through(h, q)[0], h, q)
+            with jax.named_scope("pass.backward"):
+                return back(dh)
+
+        return pulled
 
     if visit.times == 1:
         return one(pull(kept, p), dh, written, read)
-    take = _slices(visit, p)
+    # the slices' casts are made again for the inside that is computed again
+    with jax.named_scope("pass.recompute"):
+        take = _slices(visit, p)
 
     def from_the_last(k, carry):
         dh, written = carry
@@ -741,7 +780,9 @@ def _visit_backward(visit: Visit, one, dh, kept, p, written, read=None, *, scope
         at = lambda tree: jax.tree.map(lambda x: x[i], tree)
         with jax.named_scope(scope):
             written_i, read_i = at(written), at(read)
-        dh, anew = one(pull(kept[i], take(i)), dh, written_i, read_i)
+        with jax.named_scope("pass.recompute"):
+            kept_i, p_i = kept[i], take(i)
+        dh, anew = one(pull(kept_i, p_i), dh, written_i, read_i)
         with jax.named_scope(scope):
             return dh, jax.tree.map(
                 lambda x, slice_i: x.at[i].set(slice_i), written, anew)
@@ -813,15 +854,16 @@ def _forward(params: dict, tokens, visits, exits: Exits):
     a time, each visit's inside computed again (what ``jax.grad`` does with
     ``jax.checkpoint`` around every visit), so that a pass that needs no
     gradient (a held-out sequence) is the same trace as one that does."""
-    hs, counters, kept = [_embed(params, tokens)], [], []
-    for visit in visits:
-        h, c, inputs = _visit_forward(visit, hs[-1], params[visit.leaf])
-        hs.append(h)
-        counters.append(c)
-        kept.append(inputs)
-    loss, counted = exits.reported(
-        _exit_states(hs, exits), tuple(params[n] for n in exits.leaves), tokens)
-    return loss, (_stacked(visits, counters), counted), hs, kept
+    with jax.named_scope("pass.forward"):
+        hs, counters, kept = [_embed(params, tokens)], [], []
+        for visit in visits:
+            h, c, inputs = _visit_forward(visit, hs[-1], params[visit.leaf])
+            hs.append(h)
+            counters.append(c)
+            kept.append(inputs)
+        loss, counted = exits.reported(
+            _exit_states(hs, exits), tuple(params[n] for n in exits.leaves), tokens)
+        return loss, (_stacked(visits, counters), counted), hs, kept
 
 
 # ------------------------------------------------------------------- data
@@ -882,8 +924,9 @@ def _pass(p: dict, v: dict, seq, training, visits, exits: Exits, update):
 
     def exits_step(*state):
         pv = state[n_exits:]
-        d_states, grads = jax.grad(exits.trained, argnums=(0, 1))(
-            _exit_states(hs, exits), pv[::2], seq)
+        with jax.named_scope("pass.backward"):
+            d_states, grads = jax.grad(exits.trained, argnums=(0, 1))(
+                _exit_states(hs, exits), pv[::2], seq)
         stepped = [update(*pvg) for pvg in zip(pv[::2], pv[1::2], grads)]
         return tuple(d_states) + tuple(x for pair in stepped for x in pair)
 
@@ -956,7 +999,7 @@ def _pass(p: dict, v: dict, seq, training, visits, exits: Exits, update):
             dh = dh + stepped[exit_after[j]]
 
     def embed_step(pe, ve):
-        with jax.named_scope("lane.head"):
+        with jax.named_scope("pass.backward"), jax.named_scope("lane.head"):
             g = jnp.zeros_like(pe).at[seq[:-1]].add(dh)
         return update(pe, ve, g)
 

@@ -10,12 +10,14 @@ import jax.numpy as jnp
 import pytest
 
 from hpbandster_tpu import obs
-from hpbandster_tpu.obs.timeline import DEVICE_SCOPES, LANE_SCOPES
+from hpbandster_tpu.obs.timeline import (
+    DEVICE_SCOPES, LANE_SCOPES, MOE_SCOPES, PASS_SCOPES)
 from hpbandster_tpu.ops import fused
 from hpbandster_tpu.optimizers import FusedBOHB, sweep_phase_maps
 from hpbandster_tpu.optimizers.fused_bohb import _SWEEP_EXE_CACHE
 from hpbandster_tpu.workloads import kimi_linear as K
 
+import lane_names
 from kimi_small import SMALL, check_the_moe_backward_rule_is_named, load
 
 
@@ -36,7 +38,8 @@ def swept():
     try:
         opt = FusedBOHB(configspace=K.kimi_linear_space(seed=11), eval_fn=eval_fn,
                         run_id="kimi", min_budget=1, max_budget=9, eta=3, seed=11)
-        result = opt.run(n_iterations=1)
+        with lane_names.compiled_here():
+            result = opt.run(n_iterations=1)
         yield opt, result
     finally:
         patch.undo()
@@ -89,3 +92,24 @@ def test_the_lane_names_its_parts_inside_the_trainer(swept):
     inside = {phases.get(name) for name in parts}
     assert inside <= {"hpb.train", "hpb.validate"}
     check_the_moe_backward_rule_is_named(swept[0].last_executable.as_text(), parts)
+
+
+def test_the_trainer_names_its_passes(swept):
+    """Forward, recomputed and backward (``obs.timeline.PASS_SCOPES``), in
+    every part of the lane but the update."""
+    (parts,) = sweep_phase_maps(LANE_SCOPES).values()
+    (passes,) = sweep_phase_maps(PASS_SCOPES).values()
+    text = swept[0].last_executable.as_text()
+    assert passes == lane_names.check_the_trainer_names_its_passes(text, parts)
+
+
+def test_the_older_readers_read_what_they_read(swept):
+    lane_names.check_the_older_readers_read_what_they_read(
+        swept[0].last_executable.as_text())
+
+
+def test_the_expert_layer_names_its_pieces(swept):
+    (parts,) = sweep_phase_maps(LANE_SCOPES).values()
+    (pieces,) = sweep_phase_maps(MOE_SCOPES).values()
+    assert pieces == lane_names.check_the_expert_layer_names_its_pieces(
+        swept[0].last_executable.as_text(), parts, shared=True)

@@ -12,13 +12,15 @@ import jax.numpy as jnp
 import pytest
 
 from hpbandster_tpu import obs
-from hpbandster_tpu.obs.timeline import DEVICE_SCOPES, LANE_SCOPES
+from hpbandster_tpu.obs.timeline import (
+    DEVICE_SCOPES, LANE_SCOPES, MOE_SCOPES, PASS_SCOPES)
 from hpbandster_tpu.ops import fused
 from hpbandster_tpu.optimizers import FusedBOHB, sweep_phase_maps
 from hpbandster_tpu.optimizers.fused_bohb import _SWEEP_EXE_CACHE
 from hpbandster_tpu.workloads import lane
 from hpbandster_tpu.workloads import ouro as O
 
+import lane_names
 from ouro_small import SMALL, load
 
 
@@ -38,7 +40,8 @@ def swept():
     try:
         opt = FusedBOHB(configspace=O.ouro_space(seed=11), eval_fn=eval_fn,
                         run_id="ouro", min_budget=1, max_budget=9, eta=3, seed=11)
-        result = opt.run(n_iterations=1)
+        with lane_names.compiled_here():
+            result = opt.run(n_iterations=1)
         yield opt, result
     finally:
         patch.undo()
@@ -109,3 +112,22 @@ def test_the_lane_names_its_parts_inside_the_trainer(swept):
     assert set(backward) >= {"lane.gqa", "lane.dense_ffn", "lane.head", "lane.exit"}
     for part, names in backward.items():
         assert {parts.get(name, part) for name in names} == {part}, part
+
+
+def test_the_trainer_names_its_passes(swept):
+    """Forward, recomputed and backward (``obs.timeline.PASS_SCOPES``), in
+    every part of the lane but the update and the sums."""
+    (parts,) = sweep_phase_maps(LANE_SCOPES).values()
+    (passes,) = sweep_phase_maps(PASS_SCOPES).values()
+    text = swept[0].last_executable.as_text()
+    assert passes == lane_names.check_the_trainer_names_its_passes(text, parts)
+
+
+def test_the_older_readers_read_what_they_read(swept):
+    lane_names.check_the_older_readers_read_what_they_read(
+        swept[0].last_executable.as_text())
+
+
+def test_a_lane_without_experts_names_no_piece(swept):
+    # left out of the family's map, so its metrics read nothing
+    assert sweep_phase_maps(MOE_SCOPES) == {}

@@ -28,10 +28,11 @@ from hpbandster_tpu.workloads.ensemble import make_mlp_ensemble
 from hpbandster_tpu.workloads.mlp import MLPConfig, mlp_space
 from hpbandster_tpu.workloads.toys import branin_from_vector, branin_space
 
+import lane_names
+
 #: span -> the span that encloses it (None: a root of the thread)
 PARENT = {
     "construct": None,
-    "construct.eval_shape": "construct",
     "run": None,
     "sweep_planning": "run",
     "sweep_setup": "run",
@@ -70,14 +71,8 @@ def compiled_here():
     """Compile, never load: an executable out of the persistent cache
     carries the metadata of the code that compiled it first, which may be a
     commit that had no scope (the cache's key leaves metadata out)."""
-    from jax.experimental.compilation_cache import compilation_cache
-
-    cache_was_on = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    yield
-    jax.config.update("jax_enable_compilation_cache", cache_was_on)
-    compilation_cache.reset_cache()
+    with lane_names.compiled_here():
+        yield
 
 
 @pytest.fixture(scope="module")
@@ -207,8 +202,10 @@ def test_overlapped_replay_lands_on_the_row_of_its_chunk():
         first["phase_s"]["bracket_replay"], rel=0.05, abs=2e-4)
     assert "replay_overlap_s" not in first
     # construction and set-up ride the first row, the call's ends the last
-    assert {"construct", "construct.eval_shape", "sweep_planning",
-            "sweep_setup"} <= set(first["phase_s"])
+    assert {"construct", "sweep_planning", "sweep_setup"} <= set(first["phase_s"])
+    # whether the constructor traced the objective is a counter of the row,
+    # not a span of its own: the module's objective was admitted before
+    assert first["construct_traced"] in (0, 1) and last["construct_traced"] == 0
     assert not {"run", "result"} & set(first["phase_s"])
     assert {"run", "result"} <= set(last["phase_s"])
     assert "construct" not in last["phase_s"]
@@ -463,6 +460,33 @@ def test_every_entry_point_compiles_ahead_once(entry):
     assert compiles() == before + 1
 
 
+@pytest.mark.parametrize("entry", ["run", "incumbent", "sharded"])
+def test_a_build_adds_its_two_halves_to_the_gauges(entry):
+    """``sweep.build.trace_lower_s`` + ``sweep.build.compile_s`` grow by what
+    the row calls ``build_compile_s``, half by half the spans' seconds; a
+    hit touches neither."""
+    from hpbandster_tpu import obs
+    from hpbandster_tpu.parallel import config_mesh
+
+    def halves():
+        gauges = obs.get_metrics().snapshot()["gauges"]
+        return (gauges.get("sweep.build.trace_lower_s", 0.0),
+                gauges.get("sweep.build.compile_s", 0.0))
+
+    eval_fn, mesh = own_objective(), config_mesh(jax.devices()[:1])
+    before = halves()
+    rows, phase_s = entry_call(entry, eval_fn, mesh)
+    built = halves()
+    traced, compiled = built[0] - before[0], built[1] - before[1]
+    assert traced > 0 and compiled > 0
+    # the row rounds its seconds
+    assert traced + compiled == pytest.approx(rows[0]["build_compile_s"], abs=1e-3)
+    assert traced == pytest.approx(phase_s["compile.trace_lower"], rel=0.01, abs=1e-3)
+    assert compiled == pytest.approx(phase_s["compile.compile"], rel=0.01, abs=1e-3)
+    entry_call(entry, eval_fn, mesh)
+    assert halves() == built
+
+
 def test_entry_points_share_one_cache_and_never_an_entry():
     from hpbandster_tpu.optimizers.fused_bohb import _SWEEP_EXE_CACHE
     from hpbandster_tpu.parallel import config_mesh, multihost
@@ -545,9 +569,10 @@ def test_a_second_constructor_does_not_trace_the_objective(kind):
     first, second = construct(objective), construct(objective)
     assert calls == [1.0], "one trace, at the lowest budget, by the first"
     assert (first._construct_traced, second._construct_traced) == (1, 0)
-    # the span opens at every construction; on a hit it holds a lookup
+    # the counter is the fact; the check has no span of its own (a lookup
+    # on a hit), its seconds are the construction's
     for opt in (first, second):
-        assert {"construct", "construct.eval_shape"} == set(opt._phase_carry)
+        assert {"construct"} == set(opt._phase_carry)
 
 
 @pytest.mark.parametrize("kind", ["stateful", "stateless"])
