@@ -379,7 +379,8 @@ def _layer(h, p, kind, cfg: KimiLinearConfig):
     x = _rms(h, p["norm2"], cfg.rms_norm_eps)
     if ffn == "dense":
         with jax.named_scope("lane.dense_ffn"):
-            return h + _swiglu(x, p["w_gate"], p["w_up"], p["w_down"]), jnp.zeros((2,))
+            return (h + _swiglu(x, p["w_gate"], p["w_up"], p["w_down"]),
+                    jnp.zeros((len(LANE_COUNTERS),)))
     with jax.named_scope("lane.moe"):
         y, counters = moe_held_experts(x, p, cfg)
     return h + y, counters
@@ -437,7 +438,8 @@ def make_kimi_linear_eval_fn(cfg: KimiLinearConfig = KimiLinearConfig(),
     momentum-SGD steps of one ``seq_len``-token sequence);
     ``eval_fn.lane_facts`` states its footprint, its tokens a step and its
     counters: :data:`LANE_COUNTERS` from the device, then
-    ``lane.MOE_COUNTERS``, how the expert layer moves its rows."""
+    ``lane.expert_layer_counters``, how the expert layer moves its rows and
+    whether its products are the grouped kernels'."""
     init_key = jax.random.key(data_seed + 1)
     layers = _layers(cfg)
     return lane.make_lane_eval_fn(
@@ -449,4 +451,6 @@ def make_kimi_linear_eval_fn(cfg: KimiLinearConfig = KimiLinearConfig(),
         counted=lane.expert_counters(
             [ffn == "moe" for _, ffn in cfg.layer_kinds],
             cfg.seq_len * cfg.num_experts_per_token),
-        static_counters=lane.MOE_COUNTERS)
+        static_counters=lane.expert_layer_counters(
+            cfg.seq_len * cfg.num_experts_per_token, cfg.hidden_size,
+            cfg.moe_intermediate_size))

@@ -20,6 +20,7 @@ model's own file keeps its mixers, its configuration and its footprint.
 from __future__ import annotations
 
 import functools
+import math
 import zlib
 from typing import Any, NamedTuple, Optional, Tuple
 
@@ -27,7 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from hpbandster_tpu.ops import pallas_attention
+from hpbandster_tpu.ops import pallas_attention, pallas_grouped
 from hpbandster_tpu.ops.fused import LaneFacts
 from hpbandster_tpu.ops.pallas_kde import pallas_available
 from hpbandster_tpu.space import ConfigurationSpace, UniformFloatHyperparameter
@@ -46,6 +47,7 @@ __all__ = [
     "banded_attention",
     "decode_lane_hparams",
     "expert_counters",
+    "expert_layer_counters",
     "head_exit",
     "lane_space",
     "make_lane_eval_fn",
@@ -57,9 +59,11 @@ __all__ = [
 #: what a lane with expert layers counts on the device beside its loss
 #: (:func:`expert_counters`), over the expert layers of its held-out
 #: passes: the share of token-choices that fell on held experts (``held /
-#: outputs`` if routing is even) and the fullest held expert's load over
-#: the mean held load
-LANE_COUNTERS = ("moe_held_choice_share", "moe_load_max_over_mean")
+#: outputs`` if routing is even), the fullest held expert's load over the
+#: mean held load, and the rows that the grouped products computed over the
+#: held choices (a visited tile pays for all its rows: 1 is no padding)
+LANE_COUNTERS = (
+    "moe_held_choice_share", "moe_load_max_over_mean", "moe_rows_computed_over_held")
 
 
 def lane_space(seed=None) -> ConfigurationSpace:
@@ -424,9 +428,11 @@ class ExpertLayer(NamedTuple):
     scaling: float = 1.0
 
 
-#: a tile of the grouped product is four times the even load, and no more
-#: rows than this. Measured on the chip at 65,536 token-choices (PR 33,
-#: the rows moved by gathers; a layer's forward and backward pass, with
+#: the plain form's alone since PR 39 (the CPU path, and what the grouped
+#: kernels are tested against: :func:`_product_rows`): a tile of its
+#: grouped product is four times the even load, and no more rows than
+#: this. Measured on the chip at 65,536 token-choices (PR 33, the rows
+#: moved by gathers; a layer's forward and backward pass, with
 #: 16,087 / 17,450 choices held: just under and just over the even load of
 #: 16,384, which the lane's measured share of 25.1 % straddles): tiles of
 #: 4,096 rows 45 / 52 ms, 8,192: 41 / 52, 16,384: 39 / 58, 32,768: 58 / 58,
@@ -441,10 +447,48 @@ class ExpertLayer(NamedTuple):
 #: and 32,768 rows read best (67 ms against 70 at 8,192)
 _TILE_ROWS = 8192
 
+#: the grouped kernels' tile of sorted rows (``ops/pallas_grouped.py``): a
+#: tile that two experts share is visited once for each, so a tile's rows
+#: over an expert's load is the padding. Read on the chip (PR 39; one
+#: layer's forward and backward pass, router and sort included, ms): the
+#: Mellum2 lane's 65,536 choices, 16,271 held: the plain form 38.04, the
+#: kernels in tiles of 128 rows 16.34 (1.12 rows computed a held one), 256
+#: rows 16.44 (1.24), 512 rows 17.52; the kimi lane's 32,768 choices, 976
+#: held: 12.12 plain, 7.10 at 128 (1.97), 7.17 at 256 (2.89). Each of the
+#: seven kernels reads the same at 128 and at 256 rows (0.57-1.05 ms at the
+#: Mellum2 size: 63-65 % of the bfloat16 peak on the held rows)
+_KERNEL_TILE_ROWS = 128
+
 #: how the expert layer moves its rows, beside a lane's counted facts
 #: (``make_lane_eval_fn(static_counters=...)``): 1 where dispatch, combine
 #: and their transposes are gathers by the counting sort's two permutations
 MOE_COUNTERS = (("moe_combine_by_gather", 1),)
+
+
+def _product_rows(choices: int, d: int, f: int):
+    """The grouped kernels' tile of rows for ``choices`` sorted
+    token-choices through experts ``d -> 2 f -> d``, or None where the plain
+    form runs: the kernels (``ops/pallas_grouped.py``: the products over the
+    whole array of sorted rows, what lies between them never out of VMEM)
+    where Mosaic compiles them, on a TPU backend, and the shapes fit (widths
+    whole lanes, the rows whole tiles, an expert's weights and their
+    gradient's sum within the kernels' share of VMEM); off the chip the
+    plain form (the loop over tiles of ``jax.lax.ragged_dot``), which is
+    also what the kernels are tested against. Nothing here reads a model."""
+    if not pallas_available():
+        return None
+    rows = min(_KERNEL_TILE_ROWS, choices)
+    fits = pallas_grouped.fits(
+        choices, ((d, 2 * f), (f, d)), rows, np.dtype(_OPERAND).itemsize)
+    return rows if fits else None
+
+
+def expert_layer_counters(choices: int, d: int, f: int):
+    """The static facts of how a lane's expert layers are computed, beside
+    its counted ones: :data:`MOE_COUNTERS` and whether the grouped products
+    are the kernels' (1 on the chip at the published sizes, 0 on a CPU)."""
+    return MOE_COUNTERS + (
+        ("moe_products_in_vmem", float(_product_rows(choices, d, f) is not None)),)
 
 
 def _tile_sizes(ends, lo, rows: int):
@@ -476,22 +520,135 @@ def _sum_of_choices(sorted_rows, place, held, top_k: int, weight=None):
     return (picked if weight is None else picked * weight[:, :, None]).sum(1)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8))
-def _routed(x, weight, e_in, e_down, order, place, ends, top_k, rows):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9))
+def _routed(x, weight, e_in, e_down, order, place, ends, at, top_k, rows):
     """``y[t] = sum_j weight[t, j] * E(x[t])`` over the held choices of
     token ``t``: the rows move from token order to expert order and back by
     gathers alone, in both passes. ``order`` (sorted row -> choice) and
     ``place`` (choice -> sorted row) are the counting sort's permutation
     and its inverse, so each movement's transpose is a gather by the other
     one, and is written so here: what autodiff makes of a gather is a
-    scatter-add, which sorts its indices on the device."""
-    return _routed_forward(x, weight, e_in, e_down, order, place, ends, top_k, rows)[0]
+    scatter-add, which sorts its indices on the device. ``at`` is None and
+    the experts' products the plain form's (a loop over tiles of ``rows``
+    sorted rows, ``e_in`` and ``e_down`` closed by a group of zero weights),
+    or the grouped kernels' visits of tiles of ``rows`` rows
+    (``pallas_grouped.visits``) and the products the kernels'."""
+    return _routed_forward(x, weight, e_in, e_down, order, place, ends, at, top_k, rows)[0]
 
 
-def _routed_forward(x, weight, e_in, e_down, order, place, ends, top_k, rows):
+#: a number a row is kept across the chip's 128 lanes where a kernel reads
+#: or writes it (a column broadcasts to a tile by reuse of registers)
+_WEIGHT_LANES = 128
+
+
+def _gated(gate_up):
+    """Between the experts' two products: ``silu(gate) * up`` of a tile's
+    ``[gate | up]``, rounded as the down product reads it."""
+    f = gate_up.shape[1] // 2
+    return ((jax.nn.silu(gate_up[:, :f]) * gate_up[:, f:]).astype(_OPERAND),)
+
+
+def _gated_back(dh, gate_up, w):
+    """:func:`_gated` pulled back, a tile: ``dh`` f32[rows, f] is the rows'
+    cotangent through the down product, unweighted (``dy_rows @
+    e_down^T``); ``w`` the choices' weights, kept across 128 lanes. ->
+    ``(w * h`` (the down weights' gradient is its transpose against
+    ``dy_rows``), ``d[gate | up]`` of the weighted rows, ``dweight = (dh *
+    h).sum(-1)`` across 128 lanes: ``(dy_rows * ys).sum(-1)`` with the down
+    product's sum taken in the other order, so that product is not computed
+    again)``."""
+    f = dh.shape[1]
+    gate, up = gate_up[:, :f], gate_up[:, f:]
+    s = jax.nn.sigmoid(gate)
+    silu = gate * s
+    h = (silu * up).astype(_OPERAND).astype(jnp.float32)
+    w = jnp.tile(w, (1, f // w.shape[1]))
+    dweight = (dh * h).sum(axis=-1, keepdims=True)
+    dh = dh * w
+    d_gate_up = jnp.concatenate([dh * up * (s + silu * (1.0 - s)), dh * silu], axis=1)
+    return ((h * w).astype(_OPERAND), d_gate_up.astype(_OPERAND),
+            jnp.broadcast_to(dweight, (dh.shape[0], _WEIGHT_LANES)))
+
+
+def _rows_reached(source, index, n_held, rows: int):
+    """``source[index]`` for the sorted rows that a held choice reaches, in
+    chunks of whole tiles of ``rows`` (no more than ``_TILE_ROWS`` rows a
+    chunk); the rows past the last reached chunk are as the device hands
+    them over (no fill, and no gather of what no kernel visits: three
+    quarters of the choices in the Mellum2 lane)."""
+    m = index.shape[0]
+    chunk = math.gcd(m, max(_TILE_ROWS // rows, 1) * rows)
+
+    def gather(i, out):
+        take = jax.lax.dynamic_slice(index, (i * chunk,), (chunk,))
+        return jax.lax.dynamic_update_slice(out, source[take], (i * chunk, 0))
+
+    return jax.lax.fori_loop(
+        0, -(-n_held // chunk), gather, jax.lax.empty((m, source.shape[1]), source.dtype))
+
+
+def _kernels_forward(x, weight, e_in, e_down, order, place, ends, at, top_k, rows):
+    """:func:`_routed_forward` with the grouped kernels: the sorted rows
+    whole, two products, the SwiGLU between them inside the first."""
+    interpret = not pallas_available()
+    with jax.named_scope("lane.moe"):
+        with jax.named_scope("moe.dispatch"):
+            xs = _rows_reached(x.astype(_OPERAND), order // top_k, ends[-1], rows)
+        with jax.named_scope("moe.experts"):
+            h, = pallas_grouped.rows_by_group(
+                xs, e_in, at, rows, [(e_down.shape[1], _OPERAND)], epilogue=_gated,
+                interpret=interpret)
+            ys, = pallas_grouped.rows_by_group(
+                h, e_down, at, rows, [(x.shape[1], jnp.float32)], interpret=interpret)
+        # the tiles that no held choice reaches are as the device left them,
+        # and the combine reads no row of theirs
+        with jax.named_scope("moe.combine"):
+            y = _sum_of_choices(ys, place, place < ends[-1], top_k, weight)
+    return y, (x, weight, e_in, e_down, order, place, ends, at)
+
+
+def _kernels_backward(top_k, rows, kept, dy):
+    """:func:`_routed_backward` with the grouped kernels, product by
+    product on the held rows alone: gate and up again; ``dy_rows @
+    e_down^T`` with :func:`_gated_back` inside it; each expert's two
+    gradients as the transposed kernel, written once; ``d[gate | up] @
+    e_in^T``. Eight products' worth of a forward pass's three."""
+    x, weight, e_in, e_down, order, place, ends, at = kept
+    interpret = not pallas_available()
+    grouped = functools.partial(pallas_grouped.rows_by_group, interpret=interpret)
+    transposed = functools.partial(pallas_grouped.groups_by_rows, interpret=interpret)
+    with jax.named_scope("lane.moe"):
+        f = e_down.shape[1]
+        with jax.named_scope("moe.dispatch"):
+            token = order // top_k
+            xs = _rows_reached(x.astype(_OPERAND), token, ends[-1], rows)
+            # the combine's transpose: a gather by ``order``, as the dispatch is
+            dy_rows = _rows_reached(dy.astype(_OPERAND), token, ends[-1], rows)
+            w = jnp.broadcast_to(
+                weight.reshape(-1)[order][:, None], (order.shape[0], _WEIGHT_LANES))
+        with jax.named_scope("moe.experts"):
+            gate_up, = grouped(xs, e_in, at, rows, [(2 * f, jnp.float32)])
+            hw, d_gate_up, dweights = grouped(
+                dy_rows, e_down, at, rows,
+                [(f, _OPERAND), (2 * f, _OPERAND), (_WEIGHT_LANES, jnp.float32)],
+                transpose_rhs=True, epilogue=_gated_back, beside=(gate_up, w))
+            g_down = transposed(hw, dy_rows, at, rows, e_down.dtype)
+            g_in = transposed(xs, d_gate_up, at, rows, e_in.dtype)
+            dxs, = grouped(d_gate_up, e_in, at, rows, [(x.shape[1], _OPERAND)],
+                           transpose_rhs=True)
+        with jax.named_scope("moe.combine"):
+            held = place < ends[-1]
+            dx = _sum_of_choices(dxs, place, held, top_k)
+            dweight = jnp.where(held, dweights[place, 0], 0.0).reshape(weight.shape)
+    return dx, dweight, g_in, g_down, None, None, None, None
+
+
+def _routed_forward(x, weight, e_in, e_down, order, place, ends, at, top_k, rows):
     """``(y, what the backward rule keeps: the inputs)``. The rules name
     their own scope: the backward one is traced where the layer's caller
     has none."""
+    if at is not None:
+        return _kernels_forward(x, weight, e_in, e_down, order, place, ends, at, top_k, rows)
     with jax.named_scope("lane.moe"):
         d = x.shape[1]
         with jax.named_scope("moe.dispatch"):
@@ -521,7 +678,7 @@ def _routed_forward(x, weight, e_in, e_down, order, place, ends, top_k, rows):
         # combine: a gather by ``place``, summed over the top k
         with jax.named_scope("moe.combine"):
             y = _sum_of_choices(ys, place, place < n_held, top_k, weight)
-    return y, (x, weight, e_in, e_down, order, place, ends)
+    return y, (x, weight, e_in, e_down, order, place, ends, None)
 
 
 def _routed_backward(top_k, rows, kept, dy):
@@ -529,7 +686,9 @@ def _routed_backward(top_k, rows, kept, dy):
     (what ``jax.checkpoint`` around a tile did) and differentiated; the
     combine's transpose is a gather by ``order`` and the dispatch's a
     gather by ``place``."""
-    x, weight, e_in, e_down, order, place, ends = kept
+    if kept[-1] is not None:
+        return _kernels_backward(top_k, rows, kept, dy)
+    x, weight, e_in, e_down, order, place, ends, _ = kept
     with jax.named_scope("lane.moe"):
         d = x.shape[1]
         with jax.named_scope("moe.dispatch"):
@@ -574,7 +733,7 @@ def _routed_backward(top_k, rows, kept, dy):
             held = place < n_held
             dx = _sum_of_choices(dxs, place, held, top_k)
             dweight = jnp.where(held, dweights[place], 0.0).reshape(weight.shape)
-    return dx, dweight, g_in, g_down, None, None, None
+    return dx, dweight, g_in, g_down, None, None, None, None
 
 
 _routed.defvjp(_routed_forward, _routed_backward)
@@ -583,22 +742,26 @@ _routed.defvjp(_routed_forward, _routed_backward)
 def moe_held_experts(x, p, layer: ExpertLayer):
     """This chip's part of the expert layer: ``w_e * E_e(x)`` for each
     chosen expert it holds, and the shared expert once where the layer has
-    one. Returns ``(y f32[T, D], counters f32[2])``, the counters being
-    (token-choices on held experts, fullest held expert's load over the
-    mean held load).
+    one. Returns ``(y f32[T, D], counters f32[3])``.
 
     The router scores all ``outputs`` (``s``: a sigmoid each, or a softmax
     over them all), chooses the top k of ``s`` (``s + b`` with a bias) and
     weighs them ``s_e / sum(chosen s) * scaling``. Token-choices are sorted
-    by held expert (the others last) and the held ones go through
-    ``jax.lax.ragged_dot``, one group an expert, in tiles of four times the
-    even load (at most ``_TILE_ROWS`` rows), the rows that are not for this
-    chip in a last group of zero weights: only the tiles that a held choice
-    reaches are computed, so the work follows the load and no token is
-    dropped whatever the load. Rows move to expert order and back by
-    gathers alone (:func:`_routed`)."""
+    by held expert (the others last) and the held ones go through their
+    experts' products, one group an expert: only the tiles of sorted rows
+    that a held choice reaches are computed, so the work follows the load
+    and no token is dropped whatever the load. Where :func:`_product_rows`
+    says so the products are the grouped kernels' (``ops/pallas_grouped.py``,
+    over the whole array of sorted rows in tiles of ``_KERNEL_TILE_ROWS``,
+    forward and backward); else the plain form's, ``jax.lax.ragged_dot`` in
+    tiles of four times the even load (at most ``_TILE_ROWS`` rows), the
+    rows that are not for this chip in a last group of zero weights. Rows
+    move to expert order and back by gathers alone (:func:`_routed`). The
+    counters: (token-choices on held experts, fullest held expert's load
+    over the mean held load, rows of the tiles that were computed)."""
     t = x.shape[0]
     top_k, held = layer.top_k, len(layer.held)
+    kernel_rows = _product_rows(t * top_k, x.shape[1], p["e_down"].shape[1])
     with jax.named_scope("moe.router"):
         logits = jnp.matmul(x, p["router"], precision=_FLOAT32)
         s = (jax.nn.sigmoid(logits) if layer.score == "sigmoid"
@@ -623,8 +786,8 @@ def moe_held_experts(x, p, layer: ExpertLayer):
         place = (in_slot * (jnp.cumsum(in_slot, 0) - in_slot
                             + (jnp.cumsum(all_loads) - all_loads)[None, :])).sum(1)
         loads = all_loads[:held]
-        rows = min(t * top_k,
-                   max(min(4 * t * top_k * held // layer.outputs, _TILE_ROWS), 8))
+        rows = kernel_rows or min(
+            t * top_k, max(min(4 * t * top_k * held // layer.outputs, _TILE_ROWS), 8))
         n_tiles = -(-t * top_k // rows)
         order = jnp.zeros((n_tiles * rows,), jnp.int32).at[place].set(
             jnp.arange(t * top_k, dtype=jnp.int32), unique_indices=True)
@@ -633,21 +796,25 @@ def moe_held_experts(x, p, layer: ExpertLayer):
     # one whose weights are zero and takes the rows that are not for this
     # chip. On the chip ``ragged_dot`` leaves the rows that no group holds
     # as it finds them, in the backward pass too, where a mask on its
-    # output cannot reach.
-    with_rest = lambda w: jnp.concatenate([w, jnp.zeros_like(w[:1])]).astype(_OPERAND)
+    # output cannot reach. The kernels visit no row past the last held one.
+    with_rest = (lambda w: w) if kernel_rows else (
+        lambda w: jnp.concatenate([w, jnp.zeros_like(w[:1])]))
     # gate and up side by side: one grouped product for the two
     with jax.named_scope("moe.experts"):
-        e_in = with_rest(jnp.concatenate([p["e_gate"], p["e_up"]], -1))
-        e_down = with_rest(p["e_down"])
+        e_in = with_rest(jnp.concatenate([p["e_gate"], p["e_up"]], -1)).astype(_OPERAND)
+        e_down = with_rest(p["e_down"]).astype(_OPERAND)
     with jax.named_scope("moe.sort"):
         ends = jnp.cumsum(loads)
-    y = _routed(x, weight, e_in, e_down, order, place, ends, top_k, rows)
+        at = pallas_grouped.visits(ends, t * top_k, rows) if kernel_rows else None
+        computed = rows * (-(-ends[-1] // rows) if at is None else at.count)
+    y = _routed(x, weight, e_in, e_down, order, place, ends, at, top_k, rows)
     if "shared_gate" in p:
         with jax.named_scope("moe.shared"):
             y = y + _swiglu(x, p["shared_gate"], p["shared_up"], p["shared_down"])
     with jax.named_scope("moe.sort"):
         load = loads.astype(jnp.float32)
-        counters = jnp.stack([load.sum(), load.max() / jnp.maximum(load.mean(), 1e-9)])
+        counters = jnp.stack([load.sum(), load.max() / jnp.maximum(load.mean(), 1e-9),
+                              computed.astype(jnp.float32)])
     return y, counters
 
 
@@ -813,6 +980,7 @@ def expert_counters(moe_visits, choices_per_pass: int) -> Counted:
         return [
             moe[:, 0].sum() / max(moe.shape[0] * n_val * choices_per_pass, 1),
             moe[:, 1].mean() / n_val if moe.shape[0] else jnp.float32(0.0),
+            moe[:, 2].sum() / jnp.maximum(moe[:, 0].sum(), 1.0),
         ]
 
     return Counted(LANE_COUNTERS, reduce, tuple(bool(m) for m in moe_visits))

@@ -275,7 +275,8 @@ def make_mellum2_eval_fn(cfg: Mellum2Config = Mellum2Config(), data_seed: int = 
     counters: :data:`LANE_COUNTERS` from the device, then
     :data:`ATTENTION_COUNTERS`, facts of the blocking (the fused kernels'
     tiles where they run), ``lane.attention_counters``, whether they do, and
-    ``lane.MOE_COUNTERS``, how the expert layer moves its rows."""
+    ``lane.expert_layer_counters``, how the expert layer moves its rows and
+    whether its products are the grouped kernels'."""
     init_key = jax.random.key(data_seed + 1)
     heads_per_kv = cfg.num_heads // cfg.num_kv_heads
     blocks = attention_key_blocks(
@@ -291,4 +292,6 @@ def make_mellum2_eval_fn(cfg: Mellum2Config = Mellum2Config(), data_seed: int = 
         counted=lane.expert_counters(
             [True] * len(layers), cfg.seq_len * cfg.num_experts_per_token),
         static_counters=tuple(zip(ATTENTION_COUNTERS, blocks)) + lane.attention_counters(
-            cfg.seq_len, cfg.head_dim, heads_per_kv) + lane.MOE_COUNTERS)
+            cfg.seq_len, cfg.head_dim, heads_per_kv) + lane.expert_layer_counters(
+                cfg.seq_len * cfg.num_experts_per_token, cfg.hidden_size,
+                cfg.moe_intermediate_size))

@@ -356,6 +356,7 @@ def test_lane_counts_agree_with_the_lane(reference):
     assert n_params - sum(params[p] * layers[p] for p in params) == 11 * 2304
     facts = K.make_kimi_linear_eval_fn(
         K.KimiLinearConfig(seq_len=64, n_train=2, n_val=1)).lane_facts
-    assert facts.counters == K.LANE_COUNTERS + ("moe_combine_by_gather",)
+    assert facts.counters == K.LANE_COUNTERS + (
+        "moe_combine_by_gather", "moe_products_in_vmem")
     assert facts.tokens_per_step == 64
     assert 12 * n_params < K.kimi_linear_lane_bytes(K.KimiLinearConfig()) < 16.9e9

@@ -74,8 +74,13 @@ def test_the_row_counts_the_lanes(swept):
     assert 1.0 <= row["moe_load_max_over_mean"] < 4.0
     # how the expert layer moves its rows: by gathers, in both passes
     assert row["moe_combine_by_gather"] == 1
+    # and its products: off the chip the plain form's, whose reached tiles
+    # pay for all their rows (a tile is four times the even load)
+    assert row["moe_products_in_vmem"] == 0
+    assert 2.0 < row["moe_rows_computed_over_held"] < 8.0
     gauges = obs.get_metrics().snapshot()["gauges"]
     assert gauges["sweep.lane.moe_combine_by_gather"] == 1.0
+    assert gauges["sweep.lane.moe_products_in_vmem"] == 0.0
     assert gauges["sweep.lane.lane_steps"] == 27
 
 

@@ -220,7 +220,8 @@ def test_blocks_outside_the_band_are_never_computed():
     facts = M.make_mellum2_eval_fn(cfg).lane_facts
     assert facts.counters == (lane.LANE_COUNTERS + M.ATTENTION_COUNTERS
                               + ("attn_scores_in_vmem",)
-                              + tuple(name for name, _ in lane.MOE_COUNTERS))
+                              + tuple(name for name, _ in lane.MOE_COUNTERS)
+                              + ("moe_products_in_vmem",))
     assert facts.traced_budget and facts.tokens_per_step == 64
     # queries in 4 blocks: a window of 8 reaches one block back, 1 + 3 x 2;
     # the full layer 1 + 2 + 3 + 4
@@ -546,19 +547,21 @@ def test_the_tile_of_the_grouped_product_is_capped():
 
 
 # ------------------------------------------- rows moved by gathers alone
-def _sorted_by_hand(rows):
+def _sorted_by_hand(rows, slot=None):
     """Sixteen tokens' top 2 over four held experts and "not here" (slot
     4), sorted here by ``argsort``: expert 0 nobody chooses, expert 1
     every token chooses, a quarter of the choices are not held. The 24
     held choices reach three tiles of 8 rows and not the fourth, two of 12
-    and not the third (36 rows for 32 choices), and the one of 32."""
+    and not the third (36 rows for 32 choices), and the one of 32. Or the
+    choices' slots as given."""
     t, k, held = 16, 2, 4
-    slot = np.stack([np.ones(t, np.int32), np.asarray([2, 3, 4, 4] * 4, np.int32)],
-                    axis=1).reshape(-1)
+    if slot is None:
+        slot = np.stack([np.ones(t, np.int32), np.asarray([2, 3, 4, 4] * 4, np.int32)],
+                        axis=1).reshape(-1)
+        assert np.bincount(slot, minlength=held + 1)[:held].tolist() == [0, 16, 4, 4]
     order = np.argsort(slot, kind="stable").astype(np.int32)
     place = np.argsort(order, kind="stable").astype(np.int32)
     loads = np.bincount(slot, minlength=held + 1)[:held].astype(np.int32)
-    assert loads.tolist() == [0, 16, 4, 4]
     order = np.concatenate([order, np.zeros(-(-t * k // rows) * rows - t * k, np.int32)])
     return jnp.asarray(order), jnp.asarray(place), jnp.asarray(np.cumsum(loads))
 
@@ -591,7 +594,7 @@ def test_dispatch_and_combine_and_their_transposes_are_the_plain_indexing_forms(
             y = y.at[token].add(rows_out * weight.reshape(-1)[take][:, None])
         return y
 
-    ours = lambda *a: lane._routed(*a, order, place, ends, k, rows)
+    ours = lambda *a: lane._routed(*a, order, place, ends, None, k, rows)
     y, pull = jax.vjp(ours, *args)
     want, want_pull = jax.vjp(plain, *args)
     assert float(jnp.abs(want).max()) > 1.0
@@ -604,6 +607,113 @@ def test_dispatch_and_combine_and_their_transposes_are_the_plain_indexing_forms(
     # the expert nobody chooses and the closing group learn nothing
     g_in = pull(dy)[2]
     assert not bool(g_in[0].any()) and not bool(g_in[-1].any()) and bool(g_in[1].any())
+
+
+# ------------------------------ the experts' products as grouped kernels
+def _hand_sorted_layer(rows, slot=None, d=128, f=128):
+    """:func:`_sorted_by_hand`'s choices at widths of whole lanes: ``(the
+    layer's differentiable inputs, order, place, ends, a cotangent)``."""
+    t, k = 16, 2
+    order, place, ends = _sorted_by_hand(rows, slot)
+    keys = jax.random.split(jax.random.key(5), 5)
+    args = (jax.random.normal(keys[0], (t, d)),
+            jax.random.uniform(keys[1], (t, k)),
+            jax.random.normal(keys[2], (4, d, 2 * f)) * d ** -0.5,
+            jax.random.normal(keys[3], (4, f, d)) * f ** -0.5)
+    return args, order, place, ends, jax.random.normal(keys[4], (t, d))
+
+
+def _plain_and_kernels(rows, slot=None):
+    """``(the plain form, the grouped kernels in the Pallas interpreter)``
+    of ``lane._routed`` on the hand-sorted case, tiles of ``rows``."""
+    from hpbandster_tpu.ops import pallas_grouped
+
+    k = 2
+    with_rest = lambda w: jnp.concatenate([w, jnp.zeros_like(w[:1])])
+    order, place, ends = _sorted_by_hand(rows, slot)
+    at = pallas_grouped.visits(ends, order.shape[0], rows)
+    plain = lambda x, w, e_in, e_down: lane._routed(
+        x, w, with_rest(e_in), with_rest(e_down), order, place, ends, None, k, rows)
+    kernels = lambda x, w, e_in, e_down: lane._routed(
+        x, w, e_in, e_down, order, place, ends, at, k, rows)
+    return plain, kernels, at
+
+
+@pytest.mark.parametrize("rows", [8, 16, 32])
+def test_the_grouped_kernels_are_the_plain_form(float32_operands, rows):
+    """``ops/pallas_grouped.py`` under ``lane._routed``, in the Pallas
+    interpreter, against the plain form (the loop over tiles of
+    ``ragged_dot`` and the ``jax.vjp`` of a tile) on the hand-sorted case
+    (an expert nobody chooses, one every token chooses, a quarter of the
+    choices not held): ``y``, ``dx``, ``dweight`` (the down product's sum
+    taken in the other order) and both experts' gradients, to rounding. The
+    interpreter leaves what no visit writes as NaN, so the tiles that are
+    not visited provably reach nothing."""
+    args, _, _, _, dy = _hand_sorted_layer(rows)
+    plain, kernels, at = _plain_and_kernels(rows)
+    # the 24 held choices: an empty group's one visit and the tiles of the
+    # three others, none past the last held row
+    assert int(at.count) == {8: 1 + 2 + 1 + 1, 16: 4, 32: 4}[rows]
+    want, want_pull = jax.vjp(plain, *args)
+    y, pull = jax.vjp(kernels, *args)
+    assert float(jnp.abs(want).max()) > 1.0
+    np.testing.assert_allclose(y, want, atol=1e-5 * float(jnp.abs(want).max()))
+    for name, g, w in zip(("dx", "dweight", "g_in", "g_down"), pull(dy), want_pull(dy)):
+        assert g.shape == w.shape and g.dtype == w.dtype, name
+        np.testing.assert_allclose(
+            g, w, atol=2e-5 * float(jnp.abs(w).max()), err_msg=name)
+    # the expert nobody chooses learns nothing: written once, as zeros
+    g_in = pull(dy)[2]
+    assert not bool(g_in[0].any()) and bool(g_in[1].any())
+
+
+def test_what_no_held_choice_owns_reaches_nothing_through_the_kernels(float32_operands):
+    """A diverged lane's NaN in rows that no held choice owns reaches
+    neither ``y`` nor any gradient: four tokens of sixteen choose no held
+    expert and their inputs are NaN, so the sorted rows 24 to 31, the
+    second half of the last visited tile, hold NaN in the kernels'
+    operands. The first kernel stores by a select, the transposed one masks
+    both operands, and the combine and its transpose select too."""
+    rows = 16
+    slot = np.stack([np.asarray([1] * 12 + [4] * 4, np.int32),
+                     np.asarray([2, 3] * 6 + [4] * 4, np.int32)], axis=1).reshape(-1)
+    args, _, _, ends, dy = _hand_sorted_layer(rows, slot)
+    assert ends.tolist() == [0, 12, 18, 24]
+    _, kernels, at = _plain_and_kernels(rows, slot)
+    assert int(at.count) == 1 + 1 + 2 + 1    # the last one holds rows 16 to 31
+    nowhere = jnp.arange(16)[:, None] >= 12
+    poisoned = (jnp.where(nowhere, jnp.nan, args[0]),) + args[1:]
+    want, want_pull = jax.vjp(kernels, *args)
+    y, pull = jax.vjp(kernels, *poisoned)
+    np.testing.assert_array_equal(y, want)
+    assert not bool(y[12:].any()) and bool(y[:12].all())
+    for name, g, w in zip(("dx", "dweight", "g_in", "g_down"), pull(dy), want_pull(dy)):
+        assert bool(jnp.isfinite(g).all()), name
+        np.testing.assert_array_equal(g, w, err_msg=name)
+
+
+@pytest.mark.parametrize("mosaic, choices, d, f, rows", [
+    # on the CPU the plain form, whatever the shape
+    (False, 8192 * 8, 2304, 896, None), (False, 4096 * 8, 2304, 1024, None),
+    # told that Mosaic compiles here: both lanes' published shapes (1,024
+    # choices an expert if routing is even, and 128)
+    (True, 8192 * 8, 2304, 896, "tile"), (True, 4096 * 8, 2304, 1024, "tile"),
+    # fewer choices than a tile: one tile of them all
+    (True, 128, 256, 128, 128),
+    # widths that are no whole lanes (the tests' lanes), rows that are no
+    # whole tiles, an expert whose weights do not fit VMEM
+    (True, 64 * 4, 64, 16, None), (True, 8192 * 8 + 8, 2304, 896, None),
+    (True, 8192 * 8, 8192, 4096, None),
+])
+def test_the_rule_for_the_experts_products_reads_the_backend_and_the_shapes(
+        monkeypatch, mosaic, choices, d, f, rows):
+    """``lane._product_rows``: the grouped kernels' tile of rows, or None
+    where the plain form runs; ``moe_products_in_vmem`` says which."""
+    monkeypatch.setattr(lane, "pallas_available", lambda: mosaic)
+    rows = lane._KERNEL_TILE_ROWS if rows == "tile" else rows
+    assert lane._product_rows(choices, d, f) == rows
+    assert lane.expert_layer_counters(choices, d, f) == lane.MOE_COUNTERS + (
+        ("moe_products_in_vmem", float(rows is not None)),)
 
 
 @pytest.mark.parametrize("router", ["softmax", "sigmoid"])

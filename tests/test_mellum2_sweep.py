@@ -80,8 +80,15 @@ def test_the_row_counts_the_lanes_and_the_blocks(swept):
     assert row["attn_scores_in_vmem"] == 0
     # how the expert layer moves its rows: by gathers, in both passes
     assert row["moe_combine_by_gather"] == 1
+    # and its products: off the chip the plain form's, whose reached tiles
+    # pay for all their rows (one tile of 256 rows, four times the even
+    # load, for some 64 held choices)
+    assert row["moe_products_in_vmem"] == 0
+    assert 2.0 < row["moe_rows_computed_over_held"] < 8.0
     gauges = obs.get_metrics().snapshot()["gauges"]
     assert gauges["sweep.lane.moe_combine_by_gather"] == 1.0
+    assert gauges["sweep.lane.moe_products_in_vmem"] == 0.0
+    assert gauges["sweep.lane.moe_rows_computed_over_held"] == row["moe_rows_computed_over_held"]
     assert gauges["sweep.lane.attn_scores_in_vmem"] == 0.0
     assert gauges["sweep.lane.lane_steps"] == 27
 

@@ -246,3 +246,45 @@ def test_the_expert_layer_compiles_for_v5e_to_gathers_and_unfilled_buffers(
     unfilled = re.findall(
         r"= (\w+\[[\d,]*\])\S* custom-call\(\), custom_call_target=\"AllocateBuffer\"", text)
     assert {"f32[%s]" % rows, "bf16[%s]" % rows} <= set(unfilled)
+
+
+@pytest.mark.parametrize("lane_name, t, f, outputs, held, score", [
+    ("mellum2", 8192, 896, 64, 16, "softmax"), ("kimi-linear", 4096, 1024, 256, 8, "sigmoid")])
+def test_the_experts_products_compile_for_v5e_as_grouped_kernels(
+        v5e_devices, mosaic_compiles_here, lane_name, t, f, outputs, held, score):
+    """The expert layer at both lanes' published sizes (hidden 2,304, top 8),
+    forward and backward pass, where the rule takes the grouped kernels
+    (``ops/pallas_grouped.py``): the chip's compiler takes them (VMEM, the
+    grid whose length the device counts); two kernels forward and five in
+    the backward rule, every one charged to ``lane.moe`` and to
+    ``moe.experts`` by the names in the compiled text; no ``ragged-dot`` is
+    left, nothing scatters but the integer write of the sorted order, and
+    nothing sorts but the router's top k."""
+    import re
+
+    from hpbandster_tpu.obs.profile import device_phase_map
+    from hpbandster_tpu.obs.timeline import MOE_SCOPES
+    from hpbandster_tpu.workloads import lane
+    from kimi_small import lower_forward_and_backward
+
+    one = SingleDeviceSharding(v5e_devices[0])
+    d, k = 2304, 8
+    assert lane._product_rows(t * k, d, f) == lane._KERNEL_TILE_ROWS
+    facts = lane.ExpertLayer(
+        outputs=outputs, top_k=k, held=tuple(range(held)), score=score)
+    p = {"router": (d, outputs), "e_gate": (held, d, f), "e_up": (held, d, f),
+         "e_down": (held, f, d)}
+    p = {name: _sds(shape, jnp.float32, one) for name, shape in p.items()}
+    text = lower_forward_and_backward(
+        lambda x, p: lane.moe_held_experts(x, p, facts)[0],
+        _sds((t, d), jnp.float32, one), p).compile().as_text()
+    kernels = _kernel_parts(text)
+    assert kernels == [("grouped_groups_by_rows", "lane.moe")] * 2 + [
+        ("grouped_rows_by_group", "lane.moe")] * 5
+    pieces = device_phase_map(text, MOE_SCOPES)
+    named = re.findall(r"%(\S+) = [^\n]*custom_call_target=\"tpu_custom_call\"", text)
+    assert len(named) == 7 and {pieces.get(name) for name in named} == {"moe.experts"}
+    assert "ragged" not in text
+    moved = re.findall(r"= \(?(\w+)\[([\d,]*)\]\S* (scatter|gather)\(", text)
+    assert [m for m in moved if m[2] == "scatter"] == [("s32", str(t * k), "scatter")]
+    assert all("top_k" in line for line in text.splitlines() if " sort(" in line)
