@@ -83,3 +83,12 @@ from hpbandster_tpu.workloads.ouro import (  # noqa: F401
     ouro_lane_bytes,
     ouro_space,
 )
+from hpbandster_tpu.workloads.lfm2 import (  # noqa: F401
+    Lfm2Config,
+    init_lfm2_params,
+    lfm2_forward,
+    lfm2_lane_bytes,
+    lfm2_loss,
+    lfm2_space,
+    make_lfm2_eval_fn,
+)

@@ -64,6 +64,7 @@ from hpbandster_tpu.workloads import lane
 from hpbandster_tpu.workloads.lane import (  # noqa: F401 - the lane's public names
     LANE_COUNTERS,
     _FLOAT32,
+    _causal_conv,
     _einsum,
     _mm,
     _mm_beside,
@@ -192,14 +193,6 @@ def init_kimi_linear_params(key: jax.Array, cfg: KimiLinearConfig,
 # ----------------------------------------------------------------- layers
 def _l2norm(x):
     return x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + 1e-6)
-
-
-def _causal_conv(x, w):
-    """Depthwise causal convolution: ``y_t = sum_i w[i] x[t - K + 1 + i]``
-    with zeros before the sequence; ``x`` f32[T, C], ``w`` f32[K, C]."""
-    k, t = w.shape[0], x.shape[0]
-    padded = jnp.pad(x, ((k - 1, 0), (0, 0)))
-    return sum(w[i] * padded[i:i + t] for i in range(k))
 
 
 def _chunk_products(q, k, g, sub: int):

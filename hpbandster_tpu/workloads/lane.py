@@ -1,12 +1,12 @@
 """What the lanes of published blocks share (``kimi_linear.py``,
-``mellum2.py``, ``ouro.py``): a lane is one chip's share of a model of layers, trained
+``mellum2.py``, ``ouro.py``, ``lfm2.py``): a lane is one chip's share of a model of layers, trained
 from the configuration's key by momentum SGD, one sequence a step.
 
 Here live the search space and its decoding, the rule for a matrix
 product's operands, the norm and the SwiGLU, the draw of a leaf, the
 synthetic tokens, embedding and head, rotary positions and **the one causal
 softmax attention** (:func:`banded_attention`, under :func:`attention_mixer`),
-**the one expert layer** (:func:`moe_held_experts`: what differs between
+the gated short convolution (:func:`short_conv_mixer`), **the one expert layer** (:func:`moe_held_experts`: what differs between
 routers is stated as :class:`ExpertLayer`, a bias and a shared expert by
 their leaves) and **the one lane trainer** (:func:`make_lane_eval_fn`: a
 model hands it its init, its **visits** (which leaf each step of a pass
@@ -54,6 +54,7 @@ __all__ = [
     "make_token_dataset",
     "moe_held_experts",
     "once_through",
+    "short_conv_mixer",
 ]
 
 #: what a lane with expert layers counts on the device beside its loss
@@ -337,11 +338,14 @@ def banded_attention(q, k, v, window: Optional[int], block: int,
 
 def attention_mixer(x, p, *, kv_heads: int, heads_per_kv: int, head_dim: int,
                     inv_freq, factor: float, window: Optional[int], block: int,
-                    scope: str):
+                    scope: str, norm_eps: Optional[float] = None):
     """An attention layer's mixer, from the norm's output to ``W_o``: the
     projections ``wq``, ``wk``, ``wv`` as one product, queries and keys
     turned by the rotary tables of ``inv_freq`` and ``factor``, causal
-    softmax attention (banded where ``window`` is a number), ``wo``.
+    softmax attention (banded where ``window`` is a number), ``wo``. A layer
+    whose leaves hold ``q_norm`` and ``k_norm`` (f32[head_dim] each) puts
+    every head of its queries and of its keys through an RMSNorm of those
+    weights and ``norm_eps``, between the projection and the rotation.
 
     The attention is :func:`banded_attention` in plain JAX or, where
     :func:`_kernel_tiles` says so, the fused kernels of
@@ -355,6 +359,11 @@ def attention_mixer(x, p, *, kv_heads: int, heads_per_kv: int, head_dim: int,
     t = x.shape[0]
     g, r, d = kv_heads, heads_per_kv, head_dim
     q, k, v = _mm_beside(x, p["wq"], p["wk"], p["wv"])
+    q_norm, k_norm = p.get("q_norm"), p.get("k_norm")
+    if q_norm is not None:
+        # once, before the two paths part, so that both have it
+        per_head = lambda y, w: _rms(y.reshape(t, -1, d), w, norm_eps).reshape(y.shape)
+        q, k = per_head(q, q_norm), per_head(k, k_norm)
     cos, sin = _rotary_tables(inv_freq, factor, t)
     tiles = _kernel_tiles(t, d, r)
     if tiles is not None:
@@ -366,6 +375,28 @@ def attention_mixer(x, p, *, kv_heads: int, heads_per_kv: int, head_dim: int,
             _rotate(q.reshape(t, g, r, d), cos, sin), _rotate(k.reshape(t, g, d), cos, sin),
             v.reshape(t, g, d), window, block).reshape(t, g * r * d)
     return _mm(out, p["wo"])
+
+
+# ------------------------------------------------------- short convolution
+def _causal_conv(x, w):
+    """Depthwise causal convolution: ``y_t = sum_i w[i] x[t - K + 1 + i]``
+    with zeros before the sequence; ``x`` f32[T, C], ``w`` f32[K, C]."""
+    k, t = w.shape[0], x.shape[0]
+    padded = jnp.pad(x, ((k - 1, 0), (0, 0)))
+    return sum(w[i] * padded[i:i + t] for i in range(k))
+
+
+def short_conv_mixer(x, p, *, scope: str):
+    """A gated short convolution's mixer, from the norm's output to
+    ``W_out``, under the caller's part ``scope``: ``B, C, u`` the three
+    thirds of ``x W_in`` in that order, ``y = C * conv(B * u)`` with the
+    depthwise causal convolution of ``p["conv"]`` f32[K, D] (no activation,
+    no bias), ``y W_out``. The two products take the lanes' operands; the
+    gates and the convolution are float32. Plain JAX, differentiated by
+    JAX."""
+    with jax.named_scope(scope):
+        b, c, u = jnp.split(_mm(x, p["w_in"]), 3, axis=1)
+        return _mm(c * _causal_conv(b * u, p["conv"]), p["w_out"])
 
 
 # --------------------------------------------------------------- parameters
@@ -390,15 +421,20 @@ def _init_leaf(key, name: str, shape, init_scale):
     return (init_scale * fan_in ** -0.5) * draw
 
 
-def _init_params(key, cfg, layer_shapes, init_scale, init_leaf=_init_leaf) -> dict:
+def _init_params(key, cfg, layer_shapes, init_scale, init_leaf=_init_leaf,
+                 tied: bool = False) -> dict:
     """Embedding, final norm, head and ``l<i>`` for each of ``layer_shapes``
-    (a ``{leaf: shape}`` a layer)."""
-    shapes = {
-        "embed": (cfg.vocab_rows, cfg.hidden_size),
-        "norm_f": (cfg.hidden_size,),
-        "head": (cfg.hidden_size, cfg.vocab_rows),
-    }
-    params = {n: init_leaf(key, n, s, init_scale) for n, s in shapes.items()}
+    (a ``{leaf: shape}`` a layer). ``tied``: the head is the embedding,
+    transposed, and there is no leaf ``head``; the one matrix is then drawn
+    as the head it also is, ``init_scale / sqrt(hidden_size) * N(0, 1)`` (the
+    same draw, scaled by the head's fan-in: the first norm takes a lookup's
+    scale out again, the logits keep it)."""
+    d = cfg.hidden_size
+    shapes = {"embed": (cfg.vocab_rows, d), "norm_f": (d,)}
+    if not tied:
+        shapes["head"] = (d, cfg.vocab_rows)
+    scales = {"embed": init_scale * d ** -0.5} if tied else {}
+    params = {n: init_leaf(key, n, s, scales.get(n, init_scale)) for n, s in shapes.items()}
     for i, layer in enumerate(layer_shapes):
         params[f"l{i}"] = {
             n: init_leaf(key, f"l{i}/{n}", s, init_scale) for n, s in layer.items()}
@@ -426,6 +462,8 @@ class ExpertLayer(NamedTuple):
     score: str = "sigmoid"
     #: the chosen scores, renormalised to sum to one, times this
     scaling: float = 1.0
+    #: added to the sum of the chosen scores before they are divided by it
+    epsilon: float = 0.0
 
 
 #: the plain form's alone since PR 39 (the CPU path, and what the grouped
@@ -745,8 +783,9 @@ def moe_held_experts(x, p, layer: ExpertLayer):
     one. Returns ``(y f32[T, D], counters f32[3])``.
 
     The router scores all ``outputs`` (``s``: a sigmoid each, or a softmax
-    over them all), chooses the top k of ``s`` (``s + b`` with a bias) and
-    weighs them ``s_e / sum(chosen s) * scaling``. Token-choices are sorted
+    over them all), chooses the top k of ``s`` (``s + b`` with a bias: the
+    bias chooses and does not weigh) and weighs them ``s_e / (sum(chosen s)
+    + epsilon) * scaling``. Token-choices are sorted
     by held expert (the others last) and the held ones go through their
     experts' products, one group an expert: only the tiles of sorted rows
     that a held choice reaches are computed, so the work follows the load
@@ -771,7 +810,8 @@ def moe_held_experts(x, p, layer: ExpertLayer):
         # ``take_along_axis`` is a scatter-add too
         s_chosen = jnp.where(
             chosen[:, :, None] == jnp.arange(layer.outputs), s[:, None, :], 0.0).sum(-1)
-        weight = s_chosen / s_chosen.sum(-1, keepdims=True)
+        total = s_chosen.sum(-1, keepdims=True)
+        weight = s_chosen / (total + layer.epsilon if layer.epsilon else total)
         if layer.scaling != 1.0:
             weight = weight * layer.scaling
     slot_of = np.full((layer.outputs,), held, np.int32)          # held = "not here"
@@ -842,7 +882,10 @@ class Exits(NamedTuple):
     #: reads the last visit's
     after: Tuple[int, ...]
     #: the top-level leaves the exits read and no visit does: differentiated
-    #: once a step through all the exits together, and stepped then
+    #: once a step through all the exits together, and stepped then; but
+    #: ``embed`` among them (a head tied to the embedding: the exits read the
+    #: matrix that the lookup reads) is differentiated with them and stepped
+    #: once, with the lookup's gradient, by the sum of the two
     leaves: Tuple[str, ...]
     #: ``-> the loss a step descends``
     trained: Any
@@ -852,15 +895,19 @@ class Exits(NamedTuple):
     counted: int = 0
 
 
-def head_exit(n_visits: int, eps) -> Exits:
+def head_exit(n_visits: int, eps, tied: bool = False) -> Exits:
     """One exit after the last visit: final norm, head, mean next-token
-    cross-entropy, trained and reported alike."""
+    cross-entropy, trained and reported alike. ``tied``: the head is the
+    embedding, transposed (the exit's leaves name ``embed``)."""
     def loss(states, leaves, tokens):
         (h,), (norm_f, head) = states, leaves
+        if tied:
+            with jax.named_scope("lane.head"):
+                head = head.T
         return _head_loss(h, norm_f, head, tokens, eps)
 
-    return Exits(after=(n_visits,), leaves=("norm_f", "head"), trained=loss,
-                 reported=lambda *args: (loss(*args), None))
+    return Exits(after=(n_visits,), leaves=("norm_f", "embed" if tied else "head"),
+                 trained=loss, reported=lambda *args: (loss(*args), None))
 
 
 class Visit(NamedTuple):
@@ -1072,7 +1119,10 @@ def _pass(p: dict, v: dict, seq, training, visits, exits: Exits, update):
     every earlier visit still differentiates through the weights as they
     were. The exits' leaves are differentiated once, through all the exits
     together, and each exit's cotangent enters the chain where its state
-    was read."""
+    was read. A head tied to the embedding (``embed`` among the exits'
+    leaves) is one leaf read twice a pass: its gradient as the head is kept
+    to the end of the backward pass, the lookup's is added into it, and the
+    leaf is stepped once, in ``embed_step``, by the sum."""
     n_visits = len(visits)
     first_visit = {}
     for j, visit in enumerate(visits):
@@ -1090,30 +1140,48 @@ def _pass(p: dict, v: dict, seq, training, visits, exits: Exits, update):
 
     n_exits = len(exits.after)
 
+    def unwritten(tree):
+        return jax.tree.map(lambda x: jax.lax.empty(x.shape, x.dtype), tree)
+
+    # an exits' leaf that is stepped elsewhere (``embed``, the tied head: in
+    # ``embed_step``) is differentiated here and its gradient handed on;
+    # where a stepped leaf has its momentum it has a place for that gradient
+    stepped_here = [leaf != "embed" for leaf in exits.leaves]
+
     def exits_step(*state):
         pv = state[n_exits:]
         with jax.named_scope("pass.backward"):
             d_states, grads = jax.grad(exits.trained, argnums=(0, 1))(
                 _exit_states(hs, exits), pv[::2], seq)
-        stepped = [update(*pvg) for pvg in zip(pv[::2], pv[1::2], grads)]
+        stepped = [update(*pvg) if here else pvg[2:]
+                   for here, pvg in zip(stepped_here, zip(pv[::2], pv[1::2], grads))]
         return tuple(d_states) + tuple(x for pair in stepped for x in pair)
 
-    stepped = if_training(
-        exits_step, *[jnp.zeros_like(h) for h in _exit_states(hs, exits)],
-        *[x for leaf in exits.leaves for x in (p[leaf], v[leaf])])
+    def exits_same(*state):
+        # a held-out pass reads no gradient: the place of one as the device finds it
+        kept = [pair if here else (unwritten(pair[0]),) for here, pair in zip(
+            stepped_here, zip(state[n_exits::2], state[n_exits + 1::2]))]
+        return state[:n_exits] + tuple(x for pair in kept for x in pair)
+
+    stepped = list(jax.lax.cond(
+        training, exits_step, exits_same,
+        *[jnp.zeros_like(h) for h in _exit_states(hs, exits)],
+        *[x for leaf in exits.leaves for x in (p[leaf], v[leaf])]))
     # exit ``e`` read the state after ``exits.after[e]`` visits
     exit_after = {n: e for e, n in enumerate(exits.after)}
-    for i, leaf in enumerate(exits.leaves):
-        p[leaf], v[leaf] = stepped[n_exits + 2 * i], stepped[n_exits + 2 * i + 1]
-    dh = stepped[exit_after[n_visits]]
+    d_exits, stepped = stepped[:n_exits], stepped[n_exits:]
+    as_head = ()    # a tied head's gradient, for ``embed_step``
+    for here, leaf in zip(stepped_here, exits.leaves):
+        if here:
+            p[leaf], v[leaf] = stepped.pop(0), stepped.pop(0)
+        else:
+            as_head = (stepped.pop(0),)
+    dh = d_exits[exit_after[n_visits]]
     # the sum of a shared leaf's gradients over its visits so far: the
     # leaf's last visit, the backward pass's first, writes it whole (a fill
     # of zeros to add to is a pass over the leaf that lands in no part)
     sums = {}
     last_visit = {visit.leaf: j for j, visit in enumerate(visits)}
-
-    def unwritten(tree):
-        return jax.tree.map(lambda x: jax.lax.empty(x.shape, x.dtype), tree)
 
     def add(total, g):
         with jax.named_scope("lane.accumulate"):
@@ -1164,14 +1232,19 @@ def _pass(p: dict, v: dict, seq, training, visits, exits: Exits, update):
             dh, p[leaf], v[leaf] = if_training(visit_step, dh, p[leaf], v[leaf])
         if j in exit_after:
             # an earlier exit read the state this visit took in
-            dh = dh + stepped[exit_after[j]]
+            dh = dh + d_exits[exit_after[j]]
 
-    def embed_step(pe, ve):
+    tied = not all(stepped_here)
+
+    def embed_step(pe, ve, *g_head):
+        # a tied head's gradient is what the lookup's is added into
         with jax.named_scope("pass.backward"), jax.named_scope("lane.head"):
-            g = jnp.zeros_like(pe).at[seq[:-1]].add(dh)
+            (start,) = g_head if tied else (jnp.zeros_like(pe),)
+            g = start.at[seq[:-1]].add(dh)
         return update(pe, ve, g)
 
-    p["embed"], v["embed"] = if_training(embed_step, p["embed"], v["embed"])
+    p["embed"], v["embed"] = if_training(
+        embed_step, p["embed"], v["embed"], *as_head, out=(0, 1) if tied else None)
     return p, v, loss, counters
 
 

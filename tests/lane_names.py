@@ -93,9 +93,13 @@ def check_the_trainer_names_its_passes(text, parts):
     passless = {"lane.update", "lane.accumulate"}
     without = [n for n, part in parts.items() if part not in passless and n not in passes]
     assert not without, without[:5]
-    fused = {n for name, instructions in program.computations.items()
-             if name.startswith("fused_computation") or ".clone" in name
-             for n, _, _ in instructions}
+    inside = [name for name in program.computations
+              if name.startswith("fused_computation") or ".clone" in name]
+    fused = set()
+    while inside:       # and what a fused instruction calls (a reduction's region)
+        for n, _, callees in program.computations.get(inside.pop(), ()):
+            fused.add(n)
+            inside += [c for c in callees if c in program.computations]
     stepped = [n for n, part in parts.items()
                if part in passless and n in passes and n not in fused]
     assert not stepped, stepped[:5]

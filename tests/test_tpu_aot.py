@@ -212,6 +212,53 @@ def test_a_looped_layer_compiles_for_v5e_at_the_published_size(
         ("banded_attention_forward", "lane.gqa")]
 
 
+def test_the_convolution_and_narrow_head_layers_compile_for_v5e_at_the_published_size(
+        v5e_devices, mosaic_compiles_here):
+    """The LFM2 lane's two mixers (``workloads/lfm2.py``) at 8,192 tokens,
+    forward and backward pass. The gated short convolution is plain JAX under
+    ``lane.conv`` in both passes, and no float32 array is larger than ``W_in``'s
+    output (8,192 x 6,144). Attention of 64-wide heads stays with the plain
+    form though Mosaic compiles here (the kernels want heads of whole lanes):
+    no kernel, a block's scores two-dimensional, one key/value head's four
+    query heads at a time (4 x 1,024 x 8,192), the per-head norm's
+    reciprocal root traced with it."""
+    from hpbandster_tpu.workloads import lane
+    from hpbandster_tpu.workloads import lfm2 as L
+
+    one = SingleDeviceSharding(v5e_devices[0])
+    cfg = L.Lfm2Config()
+    x = _sds((cfg.seq_len, cfg.hidden_size), jnp.float32, one)
+
+    def both_passes(mixer):
+        def run(x, p, dy):
+            y, pull = jax.vjp(mixer, x, p)
+            return y, pull(dy)
+        return run
+
+    leaves = lambda kind: {name: _sds(shape, jnp.float32, one)
+                           for name, shape in L._layer_shapes(cfg, *kind).items()}
+    text = jax.jit(both_passes(
+        lambda x, p: lane.short_conv_mixer(x, p, scope="lane.conv"))).lower(
+            x, leaves(("conv", "dense")), x).compile().as_text()
+    assert max(_f32_sizes(text)) == cfg.seq_len * 3 * cfg.hidden_size
+    assert "lane.conv" in text and "transpose(jvp(lane.conv))" in text
+    assert _kernel_parts(text) == []
+
+    def attention(x, p):
+        with jax.named_scope("lane.gqa"):
+            return lane.attention_mixer(
+                x, p, kv_heads=cfg.num_kv_heads, heads_per_kv=4, head_dim=cfg.head_dim,
+                inv_freq=L.rotary_inv_freq(cfg), factor=1.0, window=None,
+                block=cfg.attn_query_block, scope="lane.gqa", norm_eps=cfg.norm_eps)
+
+    text = jax.jit(both_passes(attention)).lower(
+        x, leaves(("attention", "moe")), x).compile().as_text()
+    assert _kernel_parts(text) == []
+    assert 4 * cfg.attn_query_block * cfg.seq_len in _f32_sizes(text)
+    assert max(_f32_sizes(text)) <= 4 * cfg.attn_query_block * cfg.seq_len
+    assert "rsqrt" in text
+
+
 @pytest.mark.parametrize("score", ["softmax", "sigmoid"])
 def test_the_expert_layer_compiles_for_v5e_to_gathers_and_unfilled_buffers(
         v5e_devices, score):
