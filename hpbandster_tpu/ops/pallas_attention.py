@@ -11,6 +11,23 @@ wholly above the diagonal or below the band is never visited, and only
 the tiles that the diagonal or the band's edge crosses are masked (a
 loop of its own for them, so that no loop's body branches).
 
+Widths taken (:func:`fits`): heads of a multiple of 128 lanes, a key/value
+head a step; and heads of 64 where the key/value heads pair up (an even
+number of them), a PAIR a step, side by side in one tile of 128 lanes:
+the step's keys and values are the pair's 128 columns of ``[T, G x 64]``
+(a whole tile of lanes, which one head's 64 are not), and a query head's
+rows hold its 64 lanes in the half where its key/value head lies and
+zeros in the other (:func:`_rows`). ``q k^T`` over the 128 lanes is then
+the head's own scores (the zeros add exactly 0 to a float32 sum), ``p v``
+gives 128 lanes of which the head's half is kept at the store, and in the
+backward kernel ``dv += p^T do``, ``dp = do v^T``, ``dk += ds^T q`` and
+``sum(do o)`` are right as they stand because a row's other half is zero;
+``dq = ds k`` is cut to its half at the store. The kernels' bodies are
+the same for both widths; the products are those of a 128-wide head at
+the same number of query heads, which a 64-deep contraction costs a 128 x
+128 MXU anyway. Nothing else (32, 96, an odd number of heads of 64) is
+taken: the lanes run the plain form there.
+
 * rows are (query head, query) pairs: the ``R`` query heads of a key/value
   head share every tile of keys and every product (no head is repeated in
   memory, and a product has ``R x block_q`` rows however few queries);
@@ -31,8 +48,9 @@ Layout: every array comes and goes as the projections leave it, heads
 side by side (``[T, G x R x d]``, ``[T, G x d]``: on the chip an array
 ``[T, G, R, d]`` is tiled over its last two axes, so that even a reshape
 between the two is a pass over it, and one the compiler makes itself,
-under no scope); a step's block is its positions' ``R`` query heads,
-stood on top of one another inside the kernel by moving whole registers. A
+under no scope); a step's block is its positions' ``R`` query heads (a
+pair's ``2 x R``), stood on top of one another inside the kernel by moving
+whole registers. A
 row's log-sum-exp is kept across the 128 lanes, as the running max and sum
 are (a column broadcasts to a tile of scores by reuse of registers).
 """
@@ -48,7 +66,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["Tiles", "fits", "fused_banded_attention", "tiles_visited"]
+__all__ = ["Tiles", "fits", "fused_banded_attention", "heads_a_step", "tiles_visited"]
 
 _LANE = 128
 #: what a masked score is set to, ``banded_attention``'s own value: finite,
@@ -72,27 +90,49 @@ class Tiles(NamedTuple):
     block_k: int
 
 
-def _vmem_bytes(t: int, rows: int, d: int, tiles: Tiles, operand_bytes: int) -> int:
-    """What the backward kernel (the larger) holds in VMEM: a head's keys,
-    values and their float32 gradients and a block's rows (queries, output,
-    its gradient, the queries' gradient, the log-sum-exp), each twice over
-    for the pipeline, and some eight float32 arrays of a tile's scores.
-    The compiler's own count at the cells' shapes (chipless, PR 37): 32.7 MB
-    where this gives 46.7 (8,192 positions, 8 x 128 rows), 9.1 where this
-    gives 17.0 (2,048 positions, 512 rows)."""
-    resident = 2 * 2 * t * d * (operand_bytes + 4)
-    blocks = 2 * rows * (d * (operand_bytes + 3 * 4) + _LANE * 4)
+def _vmem_bytes(t: int, rows: int, width: int, tiles: Tiles, operand_bytes: int) -> int:
+    """What the backward kernel (the larger) holds in VMEM: a step's keys
+    (a head's, or a pair's: ``width`` lanes), values and their float32
+    gradients and a block's ``rows`` (queries, output, its gradient, the
+    queries' gradient, the log-sum-exp), each twice over for the pipeline,
+    and some eight float32 arrays of a tile's scores. The compiler's own
+    count at the cells' shapes (chipless, PR 37): 32.7 MB where this gives
+    46.7 (8,192 positions, 8 x 128 rows), 9.1 where this gives 17.0 (2,048
+    positions, 512 rows)."""
+    resident = 2 * 2 * t * width * (operand_bytes + 4)
+    blocks = 2 * rows * (width * (operand_bytes + 3 * 4) + _LANE * 4)
     return resident + blocks + 8 * rows * tiles.block_k * 4
 
 
-def fits(t: int, d: int, heads_per_kv: int, tiles: Tiles, operand_bytes: int = 2) -> bool:
-    """Whether the kernels take a sequence of ``t`` positions and heads of
-    ``d``, ``heads_per_kv`` query heads a key/value head: whole tiles of
-    whole lanes, and what a step holds within the kernels' share of VMEM."""
-    return (d % _LANE == 0 and tiles.block_k % _LANE == 0 and tiles.block_q % 16 == 0
+def _side_by_side(d: int) -> int:
+    """Key/value heads of ``d`` that a grid step takes side by side in one
+    tile of lanes: one head of whole tiles, a pair of 64; 0 for a width the
+    kernels do not take."""
+    return 1 if d % _LANE == 0 else 2 if 2 * d == _LANE else 0
+
+
+def heads_a_step(d: int, heads_per_kv: int) -> int:
+    """Query heads whose rows a grid step holds: a key/value head's, a
+    pair's where heads of 64 pair up (what a block of queries is sized
+    from; a width that is not taken counts as a head a step)."""
+    return max(_side_by_side(d), 1) * heads_per_kv
+
+
+def fits(t: int, d: int, heads_per_kv: int, kv_heads: int, tiles: Tiles,
+         operand_bytes: int = 2) -> bool:
+    """Whether the kernels take a sequence of ``t`` positions and
+    ``kv_heads`` key/value heads of ``d``, ``heads_per_kv`` query heads
+    each: heads of whole tiles of lanes (``d`` a multiple of 128) or of 64
+    that pair up (an even ``kv_heads``: two stand side by side in one tile),
+    whole tiles of keys and of queries, and what a step holds (a pair's
+    rows and columns where heads pair up) within the kernels' share of
+    VMEM."""
+    beside = _side_by_side(d)
+    return (beside > 0 and kv_heads % beside == 0
+            and tiles.block_k % _LANE == 0 and tiles.block_q % 16 == 0
             and t % tiles.block_q == 0 and t % tiles.block_k == 0
-            and _vmem_bytes(t, heads_per_kv * tiles.block_q, d, tiles, operand_bytes)
-            <= _VMEM_LIMIT)
+            and _vmem_bytes(t, heads_a_step(d, heads_per_kv) * tiles.block_q, beside * d,
+                            tiles, operand_bytes) <= _VMEM_LIMIT)
 
 
 def _loops(lo, tiles: Tiles, window: Optional[int]):
@@ -154,24 +194,54 @@ def _walk(lo, tiles: Tiles, window: Optional[int], tile):
 
 
 def _rows(ref, d: int):
-    """A block ``[block_q, R x d]`` (a position's query heads side by side,
-    as the projections leave them) as the products' rows ``[R x block_q,
-    d]``, head by head: whole registers moved, nothing shuffled."""
-    return jnp.concatenate(
-        [ref[:, h * d:(h + 1) * d] for h in range(ref.shape[1] // d)], axis=0)
+    """A block ``[block_q, heads x d]`` (a position's query heads side by
+    side, as the projections leave them) as the products' rows ``[heads x
+    block_q, width]``, head by head: whole registers moved, nothing
+    shuffled. Heads of whole tiles as they are (``width = d``). Of a pair
+    of key/value heads of 64 (``width`` 128; the block holds the first
+    one's query heads, then the second's, two to a 128-lane slice) a query
+    head's 64 lanes go to the half where its key/value head lies in the
+    pair's tile, masked in place or turned by 64 lanes first, and the other
+    half is zeros (the module's account says why that is enough)."""
+    if d % _LANE == 0:
+        return jnp.concatenate(
+            [ref[:, h * d:(h + 1) * d] for h in range(ref.shape[1] // d)], axis=0)
+    heads = ref.shape[1] // d
+    low = lax.broadcasted_iota(jnp.int32, (ref.shape[0], _LANE), 1) < d
+    rows = []
+    for h in range(heads):
+        half = h // (heads // 2)        # where its key/value head lies in the pair's tile
+        both = ref[:, h // 2 * _LANE:(h // 2 + 1) * _LANE]
+        mine = both.astype(jnp.float32)       # the chip turns registers of 32 bits only
+        if h % 2 != half:
+            mine = pltpu.roll(mine, d, axis=1)
+        rows.append(jnp.where(low == (half == 0), mine, 0.0).astype(both.dtype))
+    return jnp.concatenate(rows, axis=0)
 
 
-def _store_rows(ref, rows):
-    """:func:`_rows` undone, into ``ref``."""
-    bq, d = ref.shape[0], rows.shape[1]
-    for h in range(ref.shape[1] // d):
-        ref[:, h * d:(h + 1) * d] = rows[h * bq:(h + 1) * bq]
+def _store_rows(ref, rows, d: int):
+    """:func:`_rows` undone, into ``ref``: of a pair's rows the half where
+    the head's key/value head lies is kept, and stood where the head lies
+    in the block."""
+    bq = ref.shape[0]
+    heads = ref.shape[1] // d
+    head = lambda h: rows[h * bq:(h + 1) * bq]
+    if d % _LANE == 0:
+        for h in range(heads):
+            ref[:, h * d:(h + 1) * d] = head(h)
+        return
+    low = lax.broadcasted_iota(jnp.int32, (bq, _LANE), 1) < d
+    # head ``h``'s own half is ``h // (heads // 2)``; in the block it lies in half ``h % 2``
+    placed = lambda h: (head(h) if h % 2 == h // (heads // 2)
+                        else pltpu.roll(head(h), d, axis=1))
+    for h in range(0, heads, 2):
+        ref[:, h // 2 * _LANE:(h // 2 + 1) * _LANE] = jnp.where(low, placed(h), placed(h + 1))
 
 
 def _forward_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
-                    tiles: Tiles, window: Optional[int], scale: float):
+                    d: int, tiles: Tiles, window: Optional[int]):
     lo = pl.program_id(1) * tiles.block_q
-    d = acc_ref.shape[-1]
+    width, scale = acc_ref.shape[-1], d ** -0.5
     q = _rows(q_ref, d)
     m_ref[...] = jnp.full_like(m_ref, _MASKED)
     l_ref[...] = jnp.zeros_like(l_ref)
@@ -187,20 +257,20 @@ def _forward_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, 
         l_ref[...] = alpha * l_ref[...] + p.sum(axis=-1, keepdims=True)
         m_ref[...] = m_next
         v = v_ref[keys, :]
-        acc_ref[...] = _across(alpha, d) * acc_ref[...] + jnp.dot(
+        acc_ref[...] = _across(alpha, width) * acc_ref[...] + jnp.dot(
             p.astype(v.dtype), v, preferred_element_type=jnp.float32)
 
     _walk(lo, tiles, window, tile)
     l = l_ref[...]
-    _store_rows(o_ref, acc_ref[...] / _across(l, d))
+    _store_rows(o_ref, acc_ref[...] / _across(l, width), d)
     lse_ref[...] = m_ref[...] + jnp.log(l)
 
 
 def _backward_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, do_ref,
                      dq_ref, dk_ref, dv_ref, dq_acc, *,
-                     tiles: Tiles, window: Optional[int], scale: float):
+                     d: int, tiles: Tiles, window: Optional[int]):
     lo = pl.program_id(1) * tiles.block_q
-    d = dq_acc.shape[-1]
+    scale = d ** -0.5
 
     @pl.when(pl.program_id(1) == 0)
     def start():
@@ -231,46 +301,58 @@ def _backward_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, do_ref,
         dq_acc[...] += jnp.dot(ds, k, preferred_element_type=jnp.float32)
 
     _walk(lo, tiles, window, tile)
-    _store_rows(dq_ref, dq_acc[...] * scale)
+    _store_rows(dq_ref, dq_acc[...] * scale, d)
 
 
-def _specs(t: int, r: int, d: int, tiles: Tiles):
-    """Block specifications over the grid (key/value head ``g``, block of
-    queries ``i``): a block of positions with its key/value head's ``R``
-    query heads side by side (of ``[T, G x R x d]``), a block's rows of
-    the lanes' width, a head's keys (values, their gradients) whole (of
-    ``[T, G x d]``)."""
-    return (pl.BlockSpec((tiles.block_q, r * d), lambda g, i: (i, g)),
+def _step(heads):
+    """``(steps, query heads, width)`` of ``heads = (G, R, d)``: the grid's
+    steps over the key/value heads, the query heads a step holds and the
+    lanes its keys take: a head of whole tiles a step, or a pair of 64."""
+    g, r, d = heads
+    beside = _side_by_side(d)
+    return g // beside, heads_a_step(d, r), beside * d
+
+
+def _specs(t: int, heads, tiles: Tiles):
+    """Block specifications over the grid (step ``g``: a key/value head or
+    a pair, block of queries ``i``): a block of positions with the step's
+    query heads side by side (of ``[T, G x R x d]``), a block's rows of the
+    lanes' width, the step's keys (values, their gradients) whole (of ``[T,
+    G x d]``: a pair's columns are one tile of lanes, which a single head
+    of 64 is not)."""
+    _, r, width = _step(heads)
+    return (pl.BlockSpec((tiles.block_q, r * heads[2]), lambda g, i: (i, g)),
             pl.BlockSpec((None, None, r * tiles.block_q, _LANE), lambda g, i: (g, i, 0, 0)),
-            pl.BlockSpec((t, d), lambda g, i: (0, g)))
+            pl.BlockSpec((t, width), lambda g, i: (0, g)))
 
 
 def _params(q, k, heads, tiles: Tiles):
-    # blocks of queries in order: a head's keys stay, its ``dk`` and ``dv``
-    # are summed over them
-    _, r, d = heads
+    # blocks of queries in order: a step's keys stay, their ``dk`` and
+    # ``dv`` are summed over them
+    _, r, width = _step(heads)
     return pltpu.CompilerParams(
         dimension_semantics=("parallel", "arbitrary"),
         vmem_limit_bytes=min(_VMEM_LIMIT, _vmem_bytes(
-            k.shape[0], r * tiles.block_q, d, tiles, q.dtype.itemsize)))
+            k.shape[0], r * tiles.block_q, width, tiles, q.dtype.itemsize)))
 
 
 def _forward(q, k, v, heads, window, tiles: Tiles, interpret: bool):
     """``q [T, G x R x d]``, ``k, v [T, G x d]``, ``heads = (G, R, d)`` ->
-    ``(out f32[T, G x R x d], lse f32[G, blocks, R x block_q, 128])``."""
-    g, r, d = heads
+    ``(out f32[T, G x R x d], lse f32[steps, blocks, rows, 128])``, ``rows``
+    a step's query heads x ``block_q``."""
+    steps, r, width = _step(heads)
     t, blocks, rows = q.shape[0], q.shape[0] // tiles.block_q, r * tiles.block_q
-    positions, rows_lane, whole = _specs(t, r, d, tiles)
+    positions, rows_lane, whole = _specs(t, heads, tiles)
     return pl.pallas_call(
-        functools.partial(_forward_kernel, tiles=tiles, window=window, scale=d ** -0.5),
+        functools.partial(_forward_kernel, d=heads[2], tiles=tiles, window=window),
         out_shape=(jax.ShapeDtypeStruct(q.shape, jnp.float32),
-                   jax.ShapeDtypeStruct((g, blocks, rows, _LANE), jnp.float32)),
-        grid=(g, blocks),
+                   jax.ShapeDtypeStruct((steps, blocks, rows, _LANE), jnp.float32)),
+        grid=(steps, blocks),
         in_specs=[positions, whole, whole],
         out_specs=(positions, rows_lane),
         scratch_shapes=[pltpu.VMEM((rows, _LANE), jnp.float32),
                         pltpu.VMEM((rows, _LANE), jnp.float32),
-                        pltpu.VMEM((rows, d), jnp.float32)],
+                        pltpu.VMEM((rows, width), jnp.float32)],
         compiler_params=_params(q, k, heads, tiles), interpret=interpret,
         name="banded_attention_forward",
     )(q, k, v)
@@ -278,18 +360,18 @@ def _forward(q, k, v, heads, window, tiles: Tiles, interpret: bool):
 
 def _backward(q, k, v, out, lse, dout, heads, window, tiles: Tiles, interpret: bool):
     """-> ``(dq f32[T, G x R x d], dk, dv f32[T, G x d])``."""
-    g, r, d = heads
+    steps, r, width = _step(heads)
     t = q.shape[0]
-    positions, rows_lane, whole = _specs(t, r, d, tiles)
+    positions, rows_lane, whole = _specs(t, heads, tiles)
     return pl.pallas_call(
-        functools.partial(_backward_kernel, tiles=tiles, window=window, scale=d ** -0.5),
+        functools.partial(_backward_kernel, d=heads[2], tiles=tiles, window=window),
         out_shape=(jax.ShapeDtypeStruct(q.shape, jnp.float32),
                    jax.ShapeDtypeStruct(k.shape, jnp.float32),
                    jax.ShapeDtypeStruct(v.shape, jnp.float32)),
-        grid=(g, t // tiles.block_q),
+        grid=(steps, t // tiles.block_q),
         in_specs=[positions, whole, whole, positions, rows_lane, positions],
         out_specs=(positions, whole, whole),
-        scratch_shapes=[pltpu.VMEM((r * tiles.block_q, d), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((r * tiles.block_q, width), jnp.float32)],
         compiler_params=_params(q, k, heads, tiles), interpret=interpret,
         name="banded_attention_backward",
     )(q, k, v, out, lse, dout)

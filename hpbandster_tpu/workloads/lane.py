@@ -221,19 +221,23 @@ def _attention_spans(t: int, window: Optional[int], block: int):
     return [(lo, min(lo + block, t), first(lo)) for lo in range(0, t, block)]
 
 
-def _kernel_tiles(t: int, d: int, heads_per_kv: int):
-    """The fused kernels' tiles for ``t`` positions and heads of ``d``, or
-    None where the plain form runs: the kernels (``ops/pallas_attention.py``:
-    a tile's scores never leave VMEM) where Mosaic compiles them, on a TPU
-    backend, the keys are more than the plain form is quick at
-    (:data:`_PLAIN_KEYS`) and the shapes fit the kernels' tiles; off the chip
+def _kernel_tiles(t: int, d: int, heads_per_kv: int, kv_heads: int):
+    """The fused kernels' tiles for ``t`` positions and ``kv_heads``
+    key/value heads of ``d``, or None where the plain form runs: the kernels
+    (``ops/pallas_attention.py``: a tile's scores never leave VMEM) where
+    Mosaic compiles them, on a TPU backend, the keys are more than the plain
+    form is quick at (:data:`_PLAIN_KEYS`) and the shapes fit the kernels'
+    tiles (heads of whole tiles of lanes, or of 64 in pairs); off the chip
     the plain form (:func:`banded_attention`), which is also what the
-    kernels are tested against."""
+    kernels are tested against. A block of queries is sized from the rows a
+    step really holds: a pair's query heads where heads of 64 pair up."""
     if not pallas_available() or t <= _PLAIN_KEYS:
         return None
     keys = min(t, _KERNEL_KEYS)
-    tiles = pallas_attention.Tiles(min(keys, max(_KERNEL_ROWS // heads_per_kv, 16)), keys)
-    if not pallas_attention.fits(t, d, heads_per_kv, tiles, np.dtype(_OPERAND).itemsize):
+    heads = pallas_attention.heads_a_step(d, heads_per_kv)
+    tiles = pallas_attention.Tiles(min(keys, max(_KERNEL_ROWS // heads, 16)), keys)
+    if not pallas_attention.fits(
+            t, d, heads_per_kv, kv_heads, tiles, np.dtype(_OPERAND).itemsize):
         return None
     return tiles
 
@@ -254,13 +258,14 @@ def attention_key_blocks(t: int, windows, block: int, tiles=None):
     return computed, per_side * per_side * len(windows)
 
 
-def attention_counters(t: int, d: int, heads_per_kv: int):
+def attention_counters(t: int, d: int, heads_per_kv: int, kv_heads: int):
     """The static fact of how a lane's attention is computed, beside its
     counted ones (``make_lane_eval_fn(static_counters=...)``): the share of
     its attention layers whose scores stay in VMEM (the fused kernels; the
     layers of a lane are of one shape, so all of them or none: 1 on the chip
     at the published sizes, 0 on a CPU)."""
-    return (("attn_scores_in_vmem", float(_kernel_tiles(t, d, heads_per_kv) is not None)),)
+    return (("attn_scores_in_vmem",
+             float(_kernel_tiles(t, d, heads_per_kv, kv_heads) is not None)),)
 
 
 def attention_alive_bytes(t: int, kv_heads: int, heads_per_kv: int, d: int,
@@ -270,7 +275,7 @@ def attention_alive_bytes(t: int, kv_heads: int, heads_per_kv: int, d: int,
     plain form's three copies of the scores alive at once; the fused
     kernels' residuals, the output and a log-sum-exp a row kept across the
     128 lanes."""
-    if _kernel_tiles(t, d, heads_per_kv) is not None:
+    if _kernel_tiles(t, d, heads_per_kv, kv_heads) is not None:
         return 4 * t * kv_heads * heads_per_kv * (d + 128)
 
     def scores(window):
@@ -365,7 +370,7 @@ def attention_mixer(x, p, *, kv_heads: int, heads_per_kv: int, head_dim: int,
         per_head = lambda y, w: _rms(y.reshape(t, -1, d), w, norm_eps).reshape(y.shape)
         q, k = per_head(q, q_norm), per_head(k, k_norm)
     cos, sin = _rotary_tables(inv_freq, factor, t)
-    tiles = _kernel_tiles(t, d, r)
+    tiles = _kernel_tiles(t, d, r, g)
     if tiles is not None:
         out = pallas_attention.fused_banded_attention(
             _rotate_side_by_side(q, cos, sin), _rotate_side_by_side(k, cos, sin), v,

@@ -218,8 +218,9 @@ def lfm2_lane_bytes(cfg: Lfm2Config) -> int:
     hidden-sized rows a token: the three thirds of ``W_in``'s output, the
     gates, their gradients) and what attention keeps alive of its scores
     (``lane.attention_alive_bytes``). At the published widths it gives 10.2
-    GB where the chip's allocator peaks at 6.4 GB (PR 40): one lane fits a
-    16.9 GB chip, two do not."""
+    GB in plain JAX (10.0 GB with the kernels) where the chip's allocator
+    peaks at 6.5 GB (PR 41; 6.4 GB on the plain form, PR 40): one lane fits
+    a 16.9 GB chip, two do not."""
     n_params = lane._count_params(
         lambda: init_lfm2_params(jax.random.key(0), cfg, 1.0))
     t = cfg.seq_len
@@ -246,7 +247,7 @@ def make_lfm2_eval_fn(cfg: Lfm2Config = Lfm2Config(), data_seed: int = 0):
     choices = cfg.seq_len * cfg.num_experts_per_token
     blocks = lane.attention_key_blocks(
         cfg.seq_len, [None] * _attention_layers(cfg), cfg.attn_query_block,
-        lane._kernel_tiles(cfg.seq_len, cfg.head_dim, heads_per_kv))
+        lane._kernel_tiles(cfg.seq_len, cfg.head_dim, heads_per_kv, cfg.num_kv_heads))
     layout = (sum(mixer == "conv" for mixer, _ in cfg.layer_kinds), 1)
     return lane.make_lane_eval_fn(
         init=lambda init_scale: init_lfm2_params(init_key, cfg, init_scale),
@@ -256,6 +257,7 @@ def make_lfm2_eval_fn(cfg: Lfm2Config = Lfm2Config(), data_seed: int = 0):
         counted=lane.expert_counters(
             [True] * sum(ffn == "moe" for _, ffn in cfg.layer_kinds), choices),
         static_counters=tuple(zip(ATTENTION_COUNTERS, blocks))
-        + lane.attention_counters(cfg.seq_len, cfg.head_dim, heads_per_kv)
+        + lane.attention_counters(
+            cfg.seq_len, cfg.head_dim, heads_per_kv, cfg.num_kv_heads)
         + lane.expert_layer_counters(choices, cfg.hidden_size, cfg.moe_intermediate_size)
         + tuple(zip(LAYOUT_COUNTERS, layout)))

@@ -281,7 +281,7 @@ def make_mellum2_eval_fn(cfg: Mellum2Config = Mellum2Config(), data_seed: int = 
     heads_per_kv = cfg.num_heads // cfg.num_kv_heads
     blocks = attention_key_blocks(
         cfg.seq_len, _windows(cfg), cfg.attn_query_block,
-        lane._kernel_tiles(cfg.seq_len, cfg.head_dim, heads_per_kv))
+        lane._kernel_tiles(cfg.seq_len, cfg.head_dim, heads_per_kv, cfg.num_kv_heads))
     layers = _layers(cfg)
     return lane.make_lane_eval_fn(
         init=lambda init_scale: init_mellum2_params(init_key, cfg, init_scale),
@@ -292,6 +292,7 @@ def make_mellum2_eval_fn(cfg: Mellum2Config = Mellum2Config(), data_seed: int = 
         counted=lane.expert_counters(
             [True] * len(layers), cfg.seq_len * cfg.num_experts_per_token),
         static_counters=tuple(zip(ATTENTION_COUNTERS, blocks)) + lane.attention_counters(
-            cfg.seq_len, cfg.head_dim, heads_per_kv) + lane.expert_layer_counters(
+            cfg.seq_len, cfg.head_dim, heads_per_kv, cfg.num_kv_heads
+        ) + lane.expert_layer_counters(
                 cfg.seq_len * cfg.num_experts_per_token, cfg.hidden_size,
                 cfg.moe_intermediate_size))

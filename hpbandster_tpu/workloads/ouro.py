@@ -332,4 +332,4 @@ def make_ouro_eval_fn(cfg: OuroConfig = OuroConfig(), data_seed: int = 0):
         counted=lane.Counted(
             EXIT_COUNTERS, lambda _, at_exits, n_val: list(at_exits / n_val)),
         static_counters=tuple(zip(LOOP_COUNTERS, loop)) + lane.attention_counters(
-            cfg.seq_len, cfg.head_dim, cfg.num_heads // cfg.num_kv_heads))
+            cfg.seq_len, cfg.head_dim, cfg.num_heads // cfg.num_kv_heads, cfg.num_kv_heads))

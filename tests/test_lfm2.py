@@ -391,13 +391,28 @@ def test_the_per_head_norm_is_applied_before_the_rotation(
     assert float(jnp.abs(swapped - mixer(p)).max()) > 1e-3
 
 
-def test_heads_of_64_take_the_plain_form_whatever_the_backend(monkeypatch):
-    """The fused kernels want heads of whole lanes (128): this lane's heads
-    of 64 stay with the plain form on the chip too, and the counter says so."""
+def test_heads_of_64_take_the_kernels_in_pairs_where_mosaic_compiles(monkeypatch):
+    """Off the chip this lane's heads of 64 take the plain form; told that
+    Mosaic compiles, the fused kernels take them two key/value heads side
+    by side in one tile of 128 lanes (``ops/pallas_attention.py``), a block
+    of queries sized from the pair's 2 x 4 query heads, and the counter
+    says so. The expert layer's products stay with the plain form on the
+    chip too."""
+    cfg = L.Lfm2Config()
+    shape = (cfg.seq_len, cfg.head_dim, cfg.num_heads // cfg.num_kv_heads, cfg.num_kv_heads)
+    assert shape == (8192, 64, 4, 8)
+    assert lane._kernel_tiles(*shape) is None
+    assert dict(lane.attention_counters(*shape)) == {"attn_scores_in_vmem": 0.0}
+    plain_bytes = lane.attention_alive_bytes(8192, 8, 4, 64, [None], cfg.attn_query_block)
     monkeypatch.setattr(lane, "pallas_available", lambda: True)
-    assert lane._kernel_tiles(8192, 128, 8) is not None
-    assert lane._kernel_tiles(8192, 64, 4) is None
-    assert dict(lane.attention_counters(8192, 64, 4)) == {"attn_scores_in_vmem": 0.0}
+    assert lane._kernel_tiles(8192, 128, 8, 4) is not None
+    assert lane._kernel_tiles(*shape) == (128, 512)
+    assert dict(lane.attention_counters(*shape)) == {"attn_scores_in_vmem": 1.0}
+    # an odd head has no pair; the output and a log-sum-exp a row in place
+    # of three copies of a block's scores
+    assert lane._kernel_tiles(8192, 64, 4, 7) is None
+    assert lane.attention_alive_bytes(
+        8192, 8, 4, 64, [None], cfg.attn_query_block) == 4 * 8192 * 32 * (64 + 128) < plain_bytes
     # experts of 1,792 under a hidden size of 2,048: gate and up side by side
     # are more than the grouped kernels hold of an expert in VMEM
     assert lane._product_rows(8192 * 4, 2048, 1792) is None

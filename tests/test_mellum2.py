@@ -229,8 +229,8 @@ def test_blocks_outside_the_band_are_never_computed():
 
 
 # ----------------------------------------------- the fused kernels (Pallas)
-def _kernel_qkv(length, g, r):
-    return _qkv(length, seed=length + r, g=g, r=r, d=128)
+def _kernel_qkv(length, g, r, d=128):
+    return _qkv(length, seed=length + r, g=g, r=r, d=d)
 
 
 @pytest.mark.parametrize("operand, limit, length, window, tiles", [
@@ -248,21 +248,29 @@ def _kernel_qkv(length, g, r):
     (jnp.bfloat16, 2e-2, 384, 100, (128, 128)),
     (jnp.bfloat16, 2e-2, 512, 200, (64, 256)),
 ])
-@pytest.mark.parametrize("g, r", [(2, 1), (1, 8)])
+@pytest.mark.parametrize("g, r, d", [
+    (2, 1, 128), (1, 8, 128),
+    # heads of 64, two key/value heads side by side in a tile of lanes: one
+    # pair and its eight query heads (a 128-lane slice of the block holds
+    # two query heads of ONE key/value head), two pairs of one query head
+    # each (a slice holds a query head of each), four pairs
+    (2, 4, 64), (4, 1, 64), (8, 1, 64),
+])
 def test_the_fused_kernels_are_the_plain_form(monkeypatch, operand, limit, length,
-                                              window, tiles, g, r):
+                                              window, tiles, g, r, d):
     """``ops.pallas_attention`` in the Pallas interpreter against
-    ``banded_attention``'s plain JAX, heads of 128: the values and the
-    gradients with respect to ``q``, ``k`` and ``v``, each within ``limit``
-    of the largest entry (of one where the plain form gives all zeros: the
-    queries' gradient when a position sees itself alone)."""
+    ``banded_attention``'s plain JAX, heads of 128 and pairs of heads of 64:
+    the values and the gradients with respect to ``q``, ``k`` and ``v``,
+    each within ``limit`` of the largest entry (of one where the plain form
+    gives all zeros: the queries' gradient when a position sees itself
+    alone)."""
     from hpbandster_tpu.ops import pallas_attention
 
     monkeypatch.setattr(lane, "_OPERAND", operand)
-    q, k, v = _kernel_qkv(length, g, r)
+    q, k, v = _kernel_qkv(length, g, r, d)
     tiles = pallas_attention.Tiles(*tiles)
-    assert pallas_attention.fits(length, 128, r, tiles)
-    t, d = length, 128
+    assert pallas_attention.fits(length, d, r, g, tiles)
+    t = length
     flat = lambda x: x.reshape(t, -1)     # the kernels take the heads side by side
     fused = lambda q, k, v: pallas_attention.fused_banded_attention(
         flat(q), flat(k), flat(v), (g, r, d), window, tiles, operand, "lane.swa", True
@@ -275,6 +283,64 @@ def test_the_fused_kernels_are_the_plain_form(monkeypatch, operand, limit, lengt
         assert ours.shape == theirs.shape and ours.dtype == theirs.dtype
         np.testing.assert_allclose(
             ours, theirs, atol=limit * max(float(jnp.abs(theirs).max()), 1.0))
+
+
+@pytest.mark.parametrize("operand, limit", [(jnp.float32, 2e-5), (jnp.bfloat16, 2e-2)])
+@pytest.mark.parametrize("window", [None, 100])
+def test_a_pair_of_heads_leaks_nothing_between_its_halves(monkeypatch, operand, limit, window):
+    """Two key/value heads of 64 in one tile of lanes, the second's keys,
+    values and queries a thousand times the first's: every head's output
+    and gradients are the plain form's within ``limit`` of THAT HEAD's
+    largest entry (a thousandth of the second head in the first's half
+    would be as large as the first itself), and what is pulled back through
+    the first head's queries alone reaches nothing of the second head, to
+    the last bit."""
+    from hpbandster_tpu.ops import pallas_attention
+
+    monkeypatch.setattr(lane, "_OPERAND", operand)
+    t, g, r, d = 256, 2, 2, 64
+    tiles = pallas_attention.Tiles(64, 128)
+    loud = jnp.asarray([1.0, 1000.0])
+    q, k, v = _kernel_qkv(t, g, r, d)
+    # the scores stay the same size (the queries' gain in the keys' place
+    # would saturate the softmax): the values and the weights carry it
+    v = v * loud[None, :, None]
+    flat = lambda x: x.reshape(t, -1)
+    fused = lambda q, k, v: pallas_attention.fused_banded_attention(
+        flat(q), flat(k), flat(v), (g, r, d), window, tiles, operand, "lane.gqa", True
+    ).reshape(q.shape)
+    plain = lambda q, k, v: lane.banded_attention(q, k, v, window, 64)
+    weigh = jax.random.normal(jax.random.key(2), q.shape) * loud[None, :, None, None]
+    got, pull = jax.vjp(fused, q, k, v)
+    want, pull_plain = jax.vjp(plain, q, k, v)
+    for ours, theirs in zip((got,) + pull(weigh), (want,) + pull_plain(weigh)):
+        for head in range(g):
+            np.testing.assert_allclose(
+                ours[:, head], theirs[:, head],
+                atol=limit * max(float(jnp.abs(theirs[:, head]).max()), 1.0))
+    first_alone = weigh.at[:, 1].set(0.0)
+    dq, dk, dv = pull(first_alone)
+    for of_the_second in (dq[:, 1], dk[:, 1], dv[:, 1]):
+        np.testing.assert_array_equal(of_the_second, 0.0)
+    assert float(jnp.abs(dk[:, 0]).max()) > 0 and float(jnp.abs(dv[:, 0]).max()) > 0
+
+
+@pytest.mark.parametrize("t, d, r, g, taken", [
+    (8192, 128, 8, 4, True), (2048, 128, 1, 16, True), (8192, 128, 8, 3, True),
+    # heads of 64 in pairs: the LFM2 lane's (4 pairs of 4 query heads each)
+    (8192, 64, 4, 8, True), (8192, 64, 1, 2, True),
+    # no pair for the last head; no width between: 32 lanes, 96, 192
+    (8192, 64, 4, 7, False), (8192, 64, 4, 1, False),
+    (8192, 32, 4, 8, False), (8192, 96, 4, 8, False), (8192, 192, 4, 8, False),
+    # a length that is no whole tile, keys that do not fit VMEM
+    (8200, 64, 4, 8, False), (2 ** 16, 64, 4, 8, False),
+])
+def test_the_kernels_take_whole_tiles_of_lanes_or_pairs_of_64(t, d, r, g, taken):
+    """``fits``: heads of a multiple of 128 lanes, or of 64 where the
+    key/value heads pair up; nothing else."""
+    from hpbandster_tpu.ops.pallas_attention import Tiles, fits
+
+    assert fits(t, d, r, g, Tiles(128, 512)) == taken
 
 
 def test_the_kernels_visit_the_band_and_one_tile(monkeypatch):
@@ -290,10 +356,10 @@ def test_the_kernels_visit_the_band_and_one_tile(monkeypatch):
     assert tiles_visited(2048, None, Tiles(512, 512)) == 1 + 2 + 3 + 4
     assert tiles_visited(256, 1, Tiles(64, 128)) == 4
     # whole tiles of whole lanes, and a head's keys and values within VMEM
-    assert fits(8192, 128, 8, Tiles(128, 512)) and fits(2048, 128, 1, Tiles(512, 512))
-    assert not fits(8192, 64, 8, Tiles(128, 512))
-    assert not fits(8200, 128, 8, Tiles(128, 512))
-    assert not fits(2 ** 16, 128, 8, Tiles(128, 512))
+    assert fits(8192, 128, 8, 4, Tiles(128, 512)) and fits(2048, 128, 1, 16, Tiles(512, 512))
+    assert not fits(8192, 64, 8, 3, Tiles(128, 512))
+    assert not fits(8200, 128, 8, 4, Tiles(128, 512))
+    assert not fits(2 ** 16, 128, 8, 4, Tiles(128, 512))
 
 
 @pytest.mark.parametrize("window", [None, 1, 100, 128, 200, 512, 1000])
@@ -327,10 +393,10 @@ def test_off_the_chip_the_plain_form_runs_and_the_counter_says_so(monkeypatch):
     kernels at the Mellum2 lane's published size, and the plain form where
     the keys are few (the Ouro lane's 2,048) or a shape does not fit the
     kernels' tiles."""
-    published = [(8192, 128, 8), (2048, 128, 1)]
-    for t, d, r in published:
-        assert lane._kernel_tiles(t, d, r) is None
-        assert lane.attention_counters(t, d, r) == (("attn_scores_in_vmem", 0.0),)
+    published = [(8192, 128, 8, 4), (2048, 128, 1, 16), (8192, 64, 4, 8)]
+    for t, d, r, g in published:
+        assert lane._kernel_tiles(t, d, r, g) is None
+        assert lane.attention_counters(t, d, r, g) == (("attn_scores_in_vmem", 0.0),)
     blocks = lane.attention_key_blocks(8192, [1024, 1024, 1024, None], 1024)
     bytes_plain = lane.attention_alive_bytes(8192, 4, 8, 128, [1024, None], 1024)
     assert bytes_plain == 3 * 4 * 8 * 1024 * 8192
@@ -338,22 +404,26 @@ def test_off_the_chip_the_plain_form_runs_and_the_counter_says_so(monkeypatch):
     monkeypatch.setattr(lane, "pallas_available", lambda: True)
     # 8 heads x 128 queries against 512 keys; one head's 512 queries, no
     # wider than a tile of keys
-    assert lane._kernel_tiles(8192, 128, 8) == (128, 512)
-    assert lane._kernel_tiles(4096, 128, 1) == (512, 512)
-    assert lane.attention_counters(8192, 128, 8) == (("attn_scores_in_vmem", 1.0),)
+    assert lane._kernel_tiles(8192, 128, 8, 4) == (128, 512)
+    assert lane._kernel_tiles(4096, 128, 1, 16) == (512, 512)
+    assert lane.attention_counters(8192, 128, 8, 4) == (("attn_scores_in_vmem", 1.0),)
+    # a pair of heads of 64 a step: the block of queries is sized from the
+    # pair's 2 x 4 query heads, the rows a step really holds
+    assert lane._kernel_tiles(8192, 64, 4, 8) == (128, 512)
+    assert lane._kernel_tiles(8192, 64, 1, 2) == (512, 512)
     # few keys (a block's scores stay on the plain softmax's fast path), the
-    # tests' lanes (heads of 8 and 16), a length that is no whole tile, a
-    # sequence whose keys do not fit VMEM
-    for t, d, r in [(2048, 128, 1), (64, 8, 2), (8192, 64, 8), (8200, 128, 8),
-                    (2 ** 16, 128, 8)]:
-        assert lane._kernel_tiles(t, d, r) is None
-        assert lane.attention_counters(t, d, r) == (("attn_scores_in_vmem", 0.0),)
+    # tests' lanes (heads of 8 and 16), heads of 64 of which one has no pair,
+    # a length that is no whole tile, a sequence whose keys do not fit VMEM
+    for t, d, r, g in [(2048, 128, 1, 16), (64, 8, 2, 2), (8192, 64, 8, 3), (8200, 128, 8, 4),
+                       (2 ** 16, 128, 8, 4)]:
+        assert lane._kernel_tiles(t, d, r, g) is None
+        assert lane.attention_counters(t, d, r, g) == (("attn_scores_in_vmem", 0.0),)
     # the footprint and the counted tiles follow the path that runs: the
     # kernels keep an output and a log-sum-exp a row, no block of scores
     assert lane.attention_alive_bytes(8192, 4, 8, 128, [1024, None], 1024) == (
         4 * 8192 * 32 * (128 + 128)) < bytes_plain
     computed, square = lane.attention_key_blocks(
-        8192, [1024, 1024, 1024, None], 1024, lane._kernel_tiles(8192, 128, 8))
+        8192, [1024, 1024, 1024, None], 1024, lane._kernel_tiles(8192, 128, 8, 4))
     assert computed / square < blocks[0] / blocks[1]
 
 
@@ -411,25 +481,34 @@ def test_heads_side_by_side_turn_as_heads_apart(kind):
     np.testing.assert_array_equal(cube(beside), cube(apart))
 
 
-@pytest.mark.parametrize("window, r", [(None, 1), (100, 4)])
-def test_the_mixer_with_the_kernels_is_the_mixer_without(monkeypatch, window, r):
+@pytest.mark.parametrize("window, r, d, normed", [
+    (None, 1, 128, False), (100, 4, 128, False),
+    # heads of 64 in pairs, each head of the queries and of the keys through
+    # its norm first (the LFM2 lane's layer); and heads of 128 under the norm
+    (None, 4, 64, True), (100, 1, 64, True), (100, 4, 64, False), (None, 1, 128, True),
+])
+def test_the_mixer_with_the_kernels_is_the_mixer_without(monkeypatch, window, r, d, normed):
     """``attention_mixer`` as the chip runs it (the rule told that Mosaic
     compiles here, the kernels in the Pallas interpreter, heads side by side
     from the projections to ``wo``) against itself in plain JAX: the output
-    and the gradients with respect to its input and its four matrices,
-    within bfloat16 operands' 2e-2 of the largest entry."""
+    and the gradients with respect to its input, its four matrices and,
+    where the layer has them, the per-head norms' weights, within bfloat16
+    operands' 2e-2 of the largest entry."""
     from hpbandster_tpu.ops import pallas_attention
 
-    t, g, d, hidden = 256, 2, 128, 64
-    keys = jax.random.split(jax.random.key(4), 5)
+    t, g, hidden = 256, 2, 64
+    keys = jax.random.split(jax.random.key(4), 7)
     x = jax.random.normal(keys[0], (t, hidden))
     p = {name: jax.random.normal(key, shape) * shape[0] ** -0.5 for key, (name, shape) in zip(
         keys[1:], {"wq": (hidden, g * r * d), "wk": (hidden, g * d), "wv": (hidden, g * d),
                    "wo": (g * r * d, hidden)}.items())}
+    if normed:
+        p["q_norm"] = 1.0 + 0.3 * jax.random.normal(keys[5], (d,))
+        p["k_norm"] = 1.0 + 0.3 * jax.random.normal(keys[6], (d,))
     mixer = lambda x, p: lane.attention_mixer(
         x, p, kv_heads=g, heads_per_kv=r, head_dim=d,
         inv_freq=10000.0 ** (-np.arange(0, d, 2) / d), factor=1.0, window=window,
-        block=64, scope="lane.swa")
+        block=64, scope="lane.swa", norm_eps=1e-5)
     weigh = jax.random.normal(jax.random.key(5), (t, hidden))
     want, pull = jax.vjp(mixer, x, p)
     want = (want,) + tuple(jax.tree.leaves(pull(weigh)))
@@ -439,10 +518,13 @@ def test_the_mixer_with_the_kernels_is_the_mixer_without(monkeypatch, window, r)
     monkeypatch.setattr(lane, "_KERNEL_KEYS", 128)
     monkeypatch.setattr(lane, "_PLAIN_KEYS", 0)
     in_interpreter = pallas_attention.fused_banded_attention
+    calls = []
     monkeypatch.setattr(
         pallas_attention, "fused_banded_attention",
-        lambda *args: in_interpreter(*args, True))
+        lambda *args: calls.append(args[3:6]) or in_interpreter(*args, True))
     got, pull = jax.vjp(mixer, x, p)
+    # a step's rows are 128 whatever the width: a pair's 2 x r heads of 64
+    assert calls == [((g, r, d), window, (max(128 // (r * (128 // d)), 16), 128))]
     for ours, theirs in zip((got,) + tuple(jax.tree.leaves(pull(weigh))), want):
         np.testing.assert_allclose(ours, theirs, atol=2e-2 * float(jnp.abs(theirs).max()))
 

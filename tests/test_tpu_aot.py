@@ -217,11 +217,15 @@ def test_the_convolution_and_narrow_head_layers_compile_for_v5e_at_the_published
     """The LFM2 lane's two mixers (``workloads/lfm2.py``) at 8,192 tokens,
     forward and backward pass. The gated short convolution is plain JAX under
     ``lane.conv`` in both passes, and no float32 array is larger than ``W_in``'s
-    output (8,192 x 6,144). Attention of 64-wide heads stays with the plain
-    form though Mosaic compiles here (the kernels want heads of whole lanes):
-    no kernel, a block's scores two-dimensional, one key/value head's four
-    query heads at a time (4 x 1,024 x 8,192), the per-head norm's
-    reciprocal root traced with it."""
+    output (8,192 x 6,144). Attention of 64-wide heads is the Mosaic
+    kernels' (``ops/pallas_attention.py``: two key/value heads side by side
+    in one tile of lanes): both carry ``lane.gqa`` in the compiled text, no
+    float32 array of a block's scores exists (the plain form's was one
+    key/value head's four query heads at a time, 4 x 1,024 rows of 8,192
+    keys; nothing is as wide as the keys now), and the per-head norm's
+    reciprocal root is traced before them."""
+    import re
+
     from hpbandster_tpu.workloads import lane
     from hpbandster_tpu.workloads import lfm2 as L
 
@@ -253,9 +257,12 @@ def test_the_convolution_and_narrow_head_layers_compile_for_v5e_at_the_published
 
     text = jax.jit(both_passes(attention)).lower(
         x, leaves(("attention", "moe")), x).compile().as_text()
-    assert _kernel_parts(text) == []
-    assert 4 * cfg.attn_query_block * cfg.seq_len in _f32_sizes(text)
-    assert max(_f32_sizes(text)) <= 4 * cfg.attn_query_block * cfg.seq_len
+    assert _kernel_parts(text) == [
+        ("banded_attention_backward", "lane.gqa"), ("banded_attention_forward", "lane.gqa")]
+    # no array is as wide as the keys; the largest is the log-sum-exp, a
+    # number a (query head, query) kept across the 128 lanes
+    assert not re.search(r"f32\[[\d,]*\b%d\]" % cfg.seq_len, text)
+    assert max(_f32_sizes(text)) == cfg.num_heads * cfg.seq_len * 128
     assert "rsqrt" in text
 
 
