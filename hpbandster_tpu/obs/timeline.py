@@ -137,6 +137,7 @@ LANE_SCOPES = (
     "lane.mla",        # latent attention, its projections
     "lane.swa",        # sliding-window attention: projections, rotary, the band
     "lane.gqa",        # full causal grouped-query attention, the same
+    "lane.bda",        # attention under the block-diffusion rule of sight (a clean and a masked copy), the same
     "lane.conv",       # a gated short convolution: its two products, the gates, the taps
     "lane.moe",        # router, held experts, a shared expert where there is one
     "lane.dense_ffn",  # a dense feed-forward layer

@@ -92,3 +92,13 @@ from hpbandster_tpu.workloads.lfm2 import (  # noqa: F401
     lfm2_space,
     make_lfm2_eval_fn,
 )
+from hpbandster_tpu.workloads.sdar import (  # noqa: F401
+    SdarConfig,
+    init_sdar_params,
+    make_diffusion_dataset,
+    make_sdar_eval_fn,
+    sdar_forward,
+    sdar_lane_bytes,
+    sdar_loss,
+    sdar_space,
+)

@@ -1,11 +1,13 @@
 """What the lanes of published blocks share (``kimi_linear.py``,
-``mellum2.py``, ``ouro.py``, ``lfm2.py``): a lane is one chip's share of a model of layers, trained
+``mellum2.py``, ``ouro.py``, ``lfm2.py``, ``sdar.py``): a lane is one chip's share of a model of layers, trained
 from the configuration's key by momentum SGD, one sequence a step.
 
 Here live the search space and its decoding, the rule for a matrix
 product's operands, the norm and the SwiGLU, the draw of a leaf, the
-synthetic tokens, embedding and head, rotary positions and **the one causal
-softmax attention** (:func:`banded_attention`, under :func:`attention_mixer`),
+synthetic tokens, embedding and head, rotary positions and **the one
+softmax attention** (:func:`banded_attention`, under :func:`attention_mixer`;
+what a row sees is its rule of sight: :class:`Causal`, with or without a
+window, or :class:`BlockDiffusion`),
 the gated short convolution (:func:`short_conv_mixer`), **the one expert layer** (:func:`moe_held_experts`: what differs between
 routers is stated as :class:`ExpertLayer`, a bias and a shared expert by
 their leaves) and **the one lane trainer** (:func:`make_lane_eval_fn`: a
@@ -13,7 +15,8 @@ model hands it its init, its **visits** (which leaf each step of a pass
 takes through which function: a plain stack visits every layer once, a
 looped model the same layers several times over) and its **exits**
 (:class:`Exits`: where a pass's state is read, the loss that is trained and
-the loss that is reported), and what it counts (:class:`Counted`)). A
+the loss that is reported, and where a sequence is a record and not a row of
+ids, which ids a pass starts from), and what it counts (:class:`Counted`)). A
 model's own file keeps its mixers, its configuration and its footprint.
 """
 
@@ -34,6 +37,8 @@ from hpbandster_tpu.ops.pallas_kde import pallas_available
 from hpbandster_tpu.space import ConfigurationSpace, UniformFloatHyperparameter
 
 __all__ = [
+    "BlockDiffusion",
+    "Causal",
     "Counted",
     "Exits",
     "ExpertLayer",
@@ -136,11 +141,16 @@ def _swiglu(x, w_gate, w_up, w_down):
 
 
 # ---------------------------------------------------- positions, attention
-def _rotary_tables(inv_freq, factor, t: int):
+def _rotary_tables(inv_freq, factor, positions):
     """``(cos, sin)`` f32[T, head_dim] from a head's ``inv_freq``
     f64[head_dim / 2]: channel ``i`` turns with ``i + d / 2`` (the
-    rotate-half form), angles in float32, both tables times ``factor``."""
-    angle = (jnp.arange(t, dtype=jnp.float32)[:, None]
+    rotate-half form), angles in float32, both tables times ``factor``.
+    ``positions``: a count ``T`` (row ``i`` stands at position ``i``) or
+    the rows' own positions f32[T] (:meth:`BlockDiffusion.positions`: two
+    rows a position)."""
+    if isinstance(positions, int):
+        positions = jnp.arange(positions, dtype=jnp.float32)
+    angle = (positions[:, None]
              * jnp.asarray(inv_freq, jnp.float32)[None, :])
     angle = jnp.concatenate([angle, angle], axis=-1)
     return jnp.cos(angle) * factor, jnp.sin(angle) * factor
@@ -213,24 +223,103 @@ _KERNEL_KEYS = 512
 _PLAIN_KEYS = 2048
 
 
-def _attention_spans(t: int, window: Optional[int], block: int):
-    """``[(lo, hi, klo)]``: queries ``lo:hi`` go against keys ``klo:hi``,
-    ``klo`` the start of the block of keys that holds the first position
-    query ``lo`` may see."""
-    first = lambda lo: 0 if window is None else max(0, lo - window + 1) // block * block
-    return [(lo, min(lo + block, t), first(lo)) for lo in range(0, t, block)]
+class Causal(NamedTuple):
+    """The rule of sight of a causal model: a row is a position, and
+    position ``i`` sees ``j <= i`` and, with a window, ``i - j < window``.
+    Where a rule is asked for, a bare number or ``None`` is this rule with
+    that window."""
+
+    window: Optional[int] = None
+
+    def positions(self, rows: int):
+        return rows
+
+    def seen(self, at, key, rows: int):
+        seen = key <= at
+        if self.window is not None:
+            seen = seen & (at - key < self.window)
+        return seen
+
+    def spans(self, rows: int, block: int):
+        first = lambda lo: (0 if self.window is None
+                            else max(0, lo - self.window + 1) // block * block)
+        return [(lo, min(lo + block, rows), ((first(lo), min(lo + block, rows)),))
+                for lo in range(0, rows, block)]
 
 
-def _kernel_tiles(t: int, d: int, heads_per_kv: int, kv_heads: int):
+class BlockDiffusion(NamedTuple):
+    """The rule of sight of training by masked diffusion over blocks: a
+    sequence of ``S`` positions in blocks of ``block_length`` goes through
+    the layers as ``2 S`` rows, the clean copy (rows ``0 .. S - 1``) and
+    then the masked one (rows ``S .. 2 S - 1``), row ``i`` and row ``S + i``
+    both at position ``i``. With ``B(i) = i div block_length``:
+
+    * clean query ``i`` sees clean key ``j`` iff ``B(j) <= B(i)`` (its whole
+      own block, both ways inside it, and every earlier block) and no masked
+      row;
+    * masked query ``i`` sees clean key ``j`` iff ``B(j) < B(i)``, and masked
+      key ``j`` iff ``B(j) = B(i)``.
+
+    Not causal: about a quarter of the ``(2 S)^2`` square is seen (``S^2 +
+    4 S`` pairs a head at blocks of 4, of ``4 S^2``)."""
+
+    block_length: int
+
+    def positions(self, rows: int):
+        return (jnp.arange(rows) % (rows // 2)).astype(jnp.float32)
+
+    def seen(self, at, key, rows: int):
+        half, length = rows // 2, self.block_length
+        masked_query, masked_key = at >= half, key >= half
+        own, its = (at % half) // length, (key % half) // length
+        return jnp.where(masked_query,
+                         jnp.where(masked_key, its == own, its < own),
+                         ~masked_key & (its <= own))
+
+    def spans(self, rows: int, block: int):
+        half = rows // 2
+        if half % block or block % self.block_length:
+            raise ValueError("a block of queries is whole diffusion blocks, a copy whole blocks")
+        clean = [(lo, lo + block, ((0, lo + block),)) for lo in range(0, half, block)]
+
+        def masked(lo, hi):
+            # the clean keys up to its own block's (of the diagonal block it
+            # sees the diffusion blocks before each query's: none where the
+            # block is one diffusion block), then itself
+            clean_to = hi if block > self.block_length else lo
+            own = (half + lo, half + hi)
+            return own + ((((0, clean_to), own) if clean_to else (own,)),)
+
+        return clean + [masked(lo, hi) for lo, hi, _ in clean]
+
+
+def _rule(sight):
+    """A rule of sight as its class: a bare window (or ``None``) is causal."""
+    return sight if isinstance(sight, (Causal, BlockDiffusion)) else Causal(sight)
+
+
+def _attention_spans(t: int, sight, block: int):
+    """``[(lo, hi, runs)]``: the queries of rows ``lo:hi`` go against the
+    keys of ``runs``, ``((klo, khi), ...)``, in that order and no others.
+    Causal: one run ``klo:hi``, ``klo`` the start of the block of keys that
+    holds the first position query ``lo`` may see; a masked block of
+    :class:`BlockDiffusion` two, clean keys and its own."""
+    return _rule(sight).spans(t, block)
+
+
+def _kernel_tiles(t: int, d: int, heads_per_kv: int, kv_heads: int, sight=None):
     """The fused kernels' tiles for ``t`` positions and ``kv_heads``
     key/value heads of ``d``, or None where the plain form runs: the kernels
     (``ops/pallas_attention.py``: a tile's scores never leave VMEM) where
-    Mosaic compiles them, on a TPU backend, the keys are more than the plain
+    Mosaic compiles them, on a TPU backend, the rule of sight is the one
+    they hold (:class:`Causal`), the keys are more than the plain
     form is quick at (:data:`_PLAIN_KEYS`) and the shapes fit the kernels'
     tiles (heads of whole tiles of lanes, or of 64 in pairs); off the chip
     the plain form (:func:`banded_attention`), which is also what the
     kernels are tested against. A block of queries is sized from the rows a
     step really holds: a pair's query heads where heads of 64 pair up."""
+    if not isinstance(_rule(sight), Causal):
+        return None
     if not pallas_available() or t <= _PLAIN_KEYS:
         return None
     keys = min(t, _KERNEL_KEYS)
@@ -242,55 +331,66 @@ def _kernel_tiles(t: int, d: int, heads_per_kv: int, kv_heads: int):
     return tiles
 
 
-def attention_key_blocks(t: int, windows, block: int, tiles=None):
+def attention_key_blocks(t: int, sights, block: int, tiles=None):
     """``(computed, square)``: blocks of ``block x block`` scores that
-    :func:`banded_attention` computes over layers of the given ``windows``
-    (a number or ``None`` each), and those of their full squares; with the
+    :func:`banded_attention` computes over layers of the given rules of
+    sight (a window or ``None`` each: causal; or a :class:`BlockDiffusion`,
+    ``t`` its ``2 S`` rows), and those of their full squares; with the
     fused kernels' ``tiles`` (:func:`_kernel_tiles`), tiles of ``block_q x
     block_k``."""
     if tiles is not None:
-        return (sum(pallas_attention.tiles_visited(t, window, tiles) for window in windows),
-                (t // tiles.block_q) * (t // tiles.block_k) * len(windows))
+        return (sum(pallas_attention.tiles_visited(t, _rule(sight).window, tiles)
+                    for sight in sights),
+                (t // tiles.block_q) * (t // tiles.block_k) * len(sights))
     per_side = -(-t // block)
-    computed = sum(-(-(hi - klo) // block)
-                   for window in windows
-                   for _, hi, klo in _attention_spans(t, window, block))
-    return computed, per_side * per_side * len(windows)
+    computed = sum(-(-(khi - klo) // block)
+                   for sight in sights
+                   for _, _, runs in _attention_spans(t, sight, block)
+                   for klo, khi in runs)
+    return computed, per_side * per_side * len(sights)
 
 
-def attention_counters(t: int, d: int, heads_per_kv: int, kv_heads: int):
+def attention_counters(t: int, d: int, heads_per_kv: int, kv_heads: int, sight=None):
     """The static fact of how a lane's attention is computed, beside its
     counted ones (``make_lane_eval_fn(static_counters=...)``): the share of
     its attention layers whose scores stay in VMEM (the fused kernels; the
-    layers of a lane are of one shape, so all of them or none: 1 on the chip
-    at the published sizes, 0 on a CPU)."""
+    layers of a lane are of one shape and one kind of rule, so all of them
+    or none: 1 on the chip at the published sizes of the causal lanes, 0 on
+    a CPU and under :class:`BlockDiffusion`)."""
     return (("attn_scores_in_vmem",
-             float(_kernel_tiles(t, d, heads_per_kv, kv_heads) is not None)),)
+             float(_kernel_tiles(t, d, heads_per_kv, kv_heads, sight) is not None)),)
+
+
+def _widest_scores(t: int, sight, block: int) -> int:
+    """The most (query, key) pairs of one block of one head's scores."""
+    return max((hi - lo) * sum(khi - klo for klo, khi in runs)
+               for lo, hi, runs in _attention_spans(t, sight, block))
 
 
 def attention_alive_bytes(t: int, kv_heads: int, heads_per_kv: int, d: int,
-                          windows, block: int) -> int:
+                          sights, block: int) -> int:
     """Device bytes of attention's own that are alive at once in a layer's
-    backward pass, the largest over layers of the given ``windows``: the
+    backward pass, the largest over layers of the given rules of sight: the
     plain form's three copies of the scores alive at once; the fused
     kernels' residuals, the output and a log-sum-exp a row kept across the
     128 lanes."""
-    if _kernel_tiles(t, d, heads_per_kv, kv_heads) is not None:
-        return 4 * t * kv_heads * heads_per_kv * (d + 128)
-
-    def scores(window):
-        widest = heads_per_kv * max(
-            (hi - lo) * (hi - klo) for lo, hi, klo in _attention_spans(t, window, block))
+    def alive(sight):
+        if _kernel_tiles(t, d, heads_per_kv, kv_heads, sight) is not None:
+            return 4 * t * kv_heads * heads_per_kv * (d + 128)
+        widest = heads_per_kv * _widest_scores(t, sight, block)
         return 3 * 4 * widest * max(min(_SCORES_AT_ONCE // widest, kv_heads), 1)
 
-    return max(scores(window) for window in windows)
+    return max(alive(sight) for sight in sights)
 
 
-def banded_attention(q, k, v, window: Optional[int], block: int,
+def banded_attention(q, k, v, sight, block: int,
                      scores_at_once: int = _SCORES_AT_ONCE):
-    """Causal softmax attention with grouped queries, banded where
-    ``window`` is a number: position ``i`` sees ``j <= i`` and, with a
-    window, ``i - j < window``. ``q`` f32[T, G, R, d] (query head ``g * R +
+    """Softmax attention with grouped queries under the rule of sight
+    ``sight``. A number or ``None`` (:class:`Causal`): position ``i`` sees
+    ``j <= i`` and, with a window, ``i - j < window``. A
+    :class:`BlockDiffusion`: the ``T = 2 S`` rows are a clean and a masked
+    copy of ``S`` positions, and a row sees what that rule says, one softmax
+    a query over all of it. ``q`` f32[T, G, R, d] (query head ``g * R +
     r`` on key/value head ``g``), ``k, v`` f32[T, G, d]; returns f32[T, G,
     R, d]. Scores are ``q . k / sqrt(d)``, the softmax float32. The plain
     form (:func:`attention_mixer` takes the fused kernels where
@@ -306,22 +406,20 @@ def banded_attention(q, k, v, window: Optional[int], block: int,
     groups."""
     t, d = q.shape[0], q.shape[-1]
     scale = d ** -0.5
-    spans = _attention_spans(t, window, block)
+    rule = _rule(sight)
+    spans = rule.spans(t, block)
 
-    def one_block(qb, kb, vb, lo, klo):
+    def one_block(qb, kb, vb, lo, runs):
         # rows are (query, head) pairs: the R query heads of a key/value
         # head share one product, and everything between the two products
         # is two-dimensional (a [block, R, keys] array of scores costs the
         # chip eight times the time: its softmax leaves the fast path)
-        nq, r, nk = qb.shape[0], qb.shape[1], kb.shape[0]
+        nq, r = qb.shape[0], qb.shape[1]
         s = _mm(qb.reshape(nq * r, d), kb.T) * scale
         at = lo + jnp.arange(nq * r)[:, None] // r
-        key = klo + jnp.arange(nk)[None, :]
-        seen = key <= at
-        if window is not None:
-            seen = seen & (at - key < window)
-        att = jax.nn.softmax(jnp.where(seen, s, -1e30), axis=-1)
-        return _mm(att, vb).reshape(nq, r, d)
+        key = _beside([klo + jnp.arange(khi - klo)[None, :] for klo, khi in runs], axis=1)
+        att = jax.nn.softmax(jnp.where(rule.seen(at, key, t), s, -1e30), axis=-1)
+        return _mm(att, vb).reshape(qb.shape)
 
     # a Python loop over the blocks, each traced where it starts: a
     # ``lax.scan`` over a window layer's seven blocks of one shape built
@@ -331,23 +429,32 @@ def banded_attention(q, k, v, window: Optional[int], block: int,
     def one_group(qkv):
         qg, kg, vg = qkv
         return jnp.concatenate([
-            one_block(qg[lo:hi], kg[klo:hi], vg[klo:hi], lo, klo)
-            for lo, hi, klo in spans], axis=0)
+            one_block(qg[lo:hi], _beside([kg[klo:khi] for klo, khi in runs]),
+                      _beside([vg[klo:khi] for klo, khi in runs]), lo, runs)
+            for lo, hi, runs in spans], axis=0)
 
     groups = (q.swapaxes(0, 1), k.swapaxes(0, 1), v.swapaxes(0, 1))
-    widest = max((hi - lo) * (hi - klo) for lo, hi, klo in spans) * q.shape[2]
+    widest = _widest_scores(t, sight, block) * q.shape[2]
     at_once = scores_at_once // widest
     out = jax.lax.map(one_group, groups, batch_size=at_once if at_once > 1 else None)
     return out.swapaxes(0, 1)
 
 
+def _beside(runs, axis: int = 0):
+    """The runs of keys one after the other (one run: as it is)."""
+    return runs[0] if len(runs) == 1 else jnp.concatenate(runs, axis=axis)
+
+
 def attention_mixer(x, p, *, kv_heads: int, heads_per_kv: int, head_dim: int,
-                    inv_freq, factor: float, window: Optional[int], block: int,
+                    inv_freq, factor: float, sight, block: int,
                     scope: str, norm_eps: Optional[float] = None):
     """An attention layer's mixer, from the norm's output to ``W_o``: the
     projections ``wq``, ``wk``, ``wv`` as one product, queries and keys
-    turned by the rotary tables of ``inv_freq`` and ``factor``, causal
-    softmax attention (banded where ``window`` is a number), ``wo``. A layer
+    turned by the rotary tables of ``inv_freq`` and ``factor`` at the rows'
+    positions, softmax attention under the rule of sight ``sight`` (a
+    window or ``None``: causal, banded where it is a number; a
+    :class:`BlockDiffusion`: ``x`` is the clean and the masked copy, two
+    rows a position), ``wo``. A layer
     whose leaves hold ``q_norm`` and ``k_norm`` (f32[head_dim] each) puts
     every head of its queries and of its keys through an RMSNorm of those
     weights and ``norm_eps``, between the projection and the rotation.
@@ -358,9 +465,9 @@ def attention_mixer(x, p, *, kv_heads: int, heads_per_kv: int, head_dim: int,
     then stay side by side from the projections to ``wo`` (the kernels take
     them so, the rotary tables are tiled across them), so that no array
     changes layout on the way. ``scope`` is the caller's part (``lane.swa``,
-    ``lane.gqa``: the ``jax.named_scope`` it calls this under), which the
-    kernels' backward rule has to be told: it is traced where the caller's
-    scope is no longer open."""
+    ``lane.gqa``, ``lane.bda``: the ``jax.named_scope`` it calls this
+    under), which the kernels' backward rule has to be told: it is traced
+    where the caller's scope is no longer open."""
     t = x.shape[0]
     g, r, d = kv_heads, heads_per_kv, head_dim
     q, k, v = _mm_beside(x, p["wq"], p["wk"], p["wv"])
@@ -369,16 +476,17 @@ def attention_mixer(x, p, *, kv_heads: int, heads_per_kv: int, head_dim: int,
         # once, before the two paths part, so that both have it
         per_head = lambda y, w: _rms(y.reshape(t, -1, d), w, norm_eps).reshape(y.shape)
         q, k = per_head(q, q_norm), per_head(k, k_norm)
-    cos, sin = _rotary_tables(inv_freq, factor, t)
-    tiles = _kernel_tiles(t, d, r, g)
+    rule = _rule(sight)
+    cos, sin = _rotary_tables(inv_freq, factor, rule.positions(t))
+    tiles = _kernel_tiles(t, d, r, g, rule)
     if tiles is not None:
         out = pallas_attention.fused_banded_attention(
             _rotate_side_by_side(q, cos, sin), _rotate_side_by_side(k, cos, sin), v,
-            (g, r, d), window, tiles, _OPERAND, scope)
+            (g, r, d), rule.window, tiles, _OPERAND, scope)
     else:
         out = banded_attention(
             _rotate(q.reshape(t, g, r, d), cos, sin), _rotate(k.reshape(t, g, d), cos, sin),
-            v.reshape(t, g, d), window, block).reshape(t, g * r * d)
+            v.reshape(t, g, d), sight, block).reshape(t, g * r * d)
     return _mm(out, p["wo"])
 
 
@@ -864,9 +972,15 @@ def moe_held_experts(x, p, layer: ExpertLayer):
 
 
 # ------------------------------------------------------- embedding and head
-def _embed(params, tokens):
+def _entry(seq, exits):
+    """The ids whose embeddings are a pass's first state: a row of ids less
+    its last, or what the model's ``exits.entry`` reads off a record."""
+    return seq[:-1] if exits.entry is None else exits.entry(seq)
+
+
+def _embed(params, seq, exits):
     with jax.named_scope("lane.head"):
-        return params["embed"][tokens[:-1]]
+        return params["embed"][_entry(seq, exits)]
 
 
 def _head_loss(h, norm_f, head, tokens, eps):
@@ -881,7 +995,8 @@ class Exits(NamedTuple):
     """Where a model's passes end, and what is read there. A state is
     ``h`` f32[T, D] after so many visits; ``trained`` and ``reported`` take
     ``(states, leaves, tokens)``: the states of ``after`` in order, the
-    parameters of ``leaves`` in order, ``tokens`` i32[T + 1]."""
+    parameters of ``leaves`` in order, ``tokens`` the pass's sequence: i32[T
+    + 1], or the record that ``entry`` reads too."""
 
     #: exit ``e`` reads the state after ``after[e]`` visits; the last one
     #: reads the last visit's
@@ -898,6 +1013,11 @@ class Exits(NamedTuple):
     reported: Any
     #: how many numbers ``reported`` counts beside its loss
     counted: int = 0
+    #: where a sequence is a record and not a row of ids (a tree of arrays,
+    #: one slice of the data's): ``entry(seq) -> i32[T]``, the ids whose
+    #: embeddings are a pass's first state (a model trained by diffusion:
+    #: the clean copy, then the masked one); None: ``seq[:-1]``
+    entry: Any = None
 
 
 def head_exit(n_visits: int, eps, tied: bool = False) -> Exits:
@@ -1050,13 +1170,13 @@ def _exit_states(hs, exits: Exits):
 
 
 def _loss(params: dict, tokens, visits, exits: Exits):
-    """``tokens`` i32[T + 1] -> ``(the trained loss, ((the reported loss,
+    """``tokens`` i32[T + 1] (or a record, :class:`Exits`) -> ``(the trained loss, ((the reported loss,
     the exits' counters), the visits' counters, :func:`_stacked`))``
     through ``visits`` (a :class:`Visit` each); for ``jax.grad``: each
     visit's inside is recomputed in the backward pass, and a leaf that
     several visits take gets the sum of their gradients from the
     differentiation itself."""
-    hs, counters = [_embed(params, tokens)], []
+    hs, counters = [_embed(params, tokens, exits)], []
     for visit in visits:
         h, c, _ = _visit_forward(
             visit, hs[-1], params[visit.leaf], jax.checkpoint(visit.through))
@@ -1075,7 +1195,7 @@ def _forward(params: dict, tokens, visits, exits: Exits):
     ``jax.checkpoint`` around every visit), so that a pass that needs no
     gradient (a held-out sequence) is the same trace as one that does."""
     with jax.named_scope("pass.forward"):
-        hs, counters, kept = [_embed(params, tokens)], [], []
+        hs, counters, kept = [_embed(params, tokens, exits)], [], []
         for visit in visits:
             h, c, inputs = _visit_forward(visit, hs[-1], params[visit.leaf])
             hs.append(h)
@@ -1245,7 +1365,7 @@ def _pass(p: dict, v: dict, seq, training, visits, exits: Exits, update):
         # a tied head's gradient is what the lookup's is added into
         with jax.named_scope("pass.backward"), jax.named_scope("lane.head"):
             (start,) = g_head if tied else (jnp.zeros_like(pe),)
-            g = start.at[seq[:-1]].add(dh)
+            g = start.at[_entry(seq, exits)].add(dh)
         return update(pe, ve, g)
 
     p["embed"], v["embed"] = if_training(
@@ -1254,7 +1374,8 @@ def _pass(p: dict, v: dict, seq, training, visits, exits: Exits, update):
 
 
 def make_lane_eval_fn(*, init, visits, exits: Exits, data, lane_bytes: int,
-                      counted: Counted, static_counters=()):
+                      counted: Counted, static_counters=(),
+                      tokens_per_step: Optional[int] = None):
     """``eval_fn(config_vec, budget) -> held-out loss`` of a lane, handed to
     ``FusedBOHB(eval_fn=...)`` as ``make_transformer_eval_fn``'s is. Budget
     is momentum-SGD steps of one sequence; step ``t`` trains on sequence
@@ -1277,15 +1398,20 @@ def make_lane_eval_fn(*, init, visits, exits: Exits, data, lane_bytes: int,
     * ``exits`` (:class:`Exits`): where a pass's state is read, the loss
       that is trained and the loss that is reported (a plain stack:
       :func:`head_exit`);
-    * ``data = (train, val)`` of :func:`make_token_dataset`; ``lane_bytes``
-      the device bytes a lane needs while it trains;
+    * ``data = (train, val)`` of :func:`make_token_dataset`, or two trees
+      of arrays with the sequences along their first axes (a sequence is
+      then a record, one slice of every array: ``exits.entry`` and the
+      exits' losses read it) and ``tokens_per_step`` the data tokens of one;
+      ``lane_bytes`` the device bytes a lane needs while it trains;
     * ``counted`` (:class:`Counted`): what the lane counts on the device
       over its held-out passes (a lane with experts:
       :func:`expert_counters`);
     * ``static_counters``: ``((name, value), ...)`` facts of how the lane
       is computed that ride beside the counted ones."""
     train, val = data
-    n_train, n_val = train.shape[0], val.shape[0]
+    n_train, n_val = (jax.tree.leaves(tree)[0].shape[0] for tree in (train, val))
+    if tokens_per_step is None:
+        tokens_per_step = train.shape[1] - 1
     if exits.after[-1] != len(visits) or list(exits.after) != sorted(set(exits.after)):
         raise ValueError("the last exit reads the last visit's state; exits in order")
 
@@ -1313,8 +1439,10 @@ def make_lane_eval_fn(*, init, visits, exits: Exits, data, lane_bytes: int,
         def one_pass(t, carry):
             p, v, held_loss, held_counters = carry
             training = t < steps
-            seq = jnp.where(training, train[t % n_train],
-                            val[jnp.clip(t - steps, 0, n_val - 1)])
+            seq = jax.tree.map(
+                lambda train, val: jnp.where(
+                    training, train[t % n_train], val[jnp.clip(t - steps, 0, n_val - 1)]),
+                train, val)
             p, v, loss, counters = _pass(p, v, seq, training, visits, exits, update)
             held = jnp.where(training, 0.0, 1.0)
             return p, v, held_loss + held * loss, jax.tree.map(
@@ -1350,7 +1478,7 @@ def make_lane_eval_fn(*, init, visits, exits: Exits, data, lane_bytes: int,
         return with_counters(vec, budget)[0]
 
     eval_fn.lane_facts = LaneFacts(
-        bytes=lane_bytes, tokens_per_step=train.shape[1] - 1,
+        bytes=lane_bytes, tokens_per_step=tokens_per_step,
         counters=tuple(counted.names) + tuple(name for name, _ in static_counters),
         with_counters=with_counters, traced_budget=True)
     eval_fn.change = change

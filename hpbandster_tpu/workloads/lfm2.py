@@ -165,7 +165,7 @@ def _layer(h, p, kind, cfg: Lfm2Config):
             h = h + lane.attention_mixer(
                 x, p, kv_heads=cfg.num_kv_heads,
                 heads_per_kv=cfg.num_heads // cfg.num_kv_heads, head_dim=cfg.head_dim,
-                inv_freq=rotary_inv_freq(cfg), factor=1.0, window=None,
+                inv_freq=rotary_inv_freq(cfg), factor=1.0, sight=None,
                 block=cfg.attn_query_block, scope="lane.gqa", norm_eps=cfg.norm_eps)
     x = _rms(h, p["norm2"], cfg.norm_eps)
     if ffn == "dense":

@@ -193,7 +193,7 @@ def _attention(x, p, kind: str, cfg: Mellum2Config):
         x, p, kv_heads=cfg.num_kv_heads,
         heads_per_kv=cfg.num_heads // cfg.num_kv_heads, head_dim=cfg.head_dim,
         inv_freq=inv_freq, factor=factor,
-        window=cfg.sliding_window if kind == "sliding" else None,
+        sight=cfg.sliding_window if kind == "sliding" else None,
         block=cfg.attn_query_block, scope=_MIXER_SCOPE[kind])
 
 

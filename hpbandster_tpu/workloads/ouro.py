@@ -151,7 +151,7 @@ def _layer(h, p, cfg: OuroConfig):
         mixed = lane.attention_mixer(
             _rms(h, p["norm1"], eps), p, kv_heads=cfg.num_kv_heads,
             heads_per_kv=cfg.num_heads // cfg.num_kv_heads, head_dim=cfg.head_dim,
-            inv_freq=rotary_inv_freq(cfg), factor=1.0, window=None,
+            inv_freq=rotary_inv_freq(cfg), factor=1.0, sight=None,
             block=cfg.attn_query_block, scope="lane.gqa")
         h = h + _rms(mixed, p["norm2"], eps)
     with jax.named_scope("lane.dense_ffn"):

@@ -359,7 +359,7 @@ def test_the_per_head_norm_is_applied_before_the_rotation(
     x = jax.random.normal(jax.random.key(6), (32, 64))
     mixer = lambda leaves: lane.attention_mixer(
         x, leaves, kv_heads=2, heads_per_kv=2, head_dim=16, inv_freq=L.rotary_inv_freq(cfg),
-        factor=1.0, window=None, block=16, scope="lane.gqa", norm_eps=cfg.norm_eps)
+        factor=1.0, sight=None, block=16, scope="lane.gqa", norm_eps=cfg.norm_eps)
     np.testing.assert_allclose(mixer(p), reference.attention(x, p, SMALL), atol=2e-5)
     # by hand: normalise every head of the projections, then rotate, then attend
     bare = {n: w for n, w in p.items() if n not in ("q_norm", "k_norm")}

@@ -212,8 +212,8 @@ def test_blocks_outside_the_band_are_never_computed():
     assert M.attention_key_blocks(8192, [1024], 1024) == (15, 64)
     assert M.attention_key_blocks(8192, [None], 1024) == (36, 64)
     # whatever the length, a window layer's products are two blocks wide
-    assert max(hi - klo for _, hi, klo in M._attention_spans(32768, 1024, 1024)) == 2048
-    assert max(hi - klo for _, hi, klo in M._attention_spans(8192, None, 1024)) == 8192
+    assert max(khi - klo for _, _, ((klo, khi),) in M._attention_spans(32768, 1024, 1024)) == 2048
+    assert max(khi - klo for _, _, ((klo, khi),) in M._attention_spans(8192, None, 1024)) == 8192
     cfg = M.Mellum2Config(seq_len=64, n_train=2, n_val=1, vocab_rows=96, hidden_size=32,
                           num_heads=4, num_kv_heads=2, head_dim=8, sliding_window=8,
                           moe_intermediate_size=16, attn_query_block=16)
@@ -507,7 +507,7 @@ def test_the_mixer_with_the_kernels_is_the_mixer_without(monkeypatch, window, r,
         p["k_norm"] = 1.0 + 0.3 * jax.random.normal(keys[6], (d,))
     mixer = lambda x, p: lane.attention_mixer(
         x, p, kv_heads=g, heads_per_kv=r, head_dim=d,
-        inv_freq=10000.0 ** (-np.arange(0, d, 2) / d), factor=1.0, window=window,
+        inv_freq=10000.0 ** (-np.arange(0, d, 2) / d), factor=1.0, sight=window,
         block=64, scope="lane.swa", norm_eps=1e-5)
     weigh = jax.random.normal(jax.random.key(5), (t, hidden))
     want, pull = jax.vjp(mixer, x, p)

@@ -1,0 +1,149 @@
+"""A bracket of the small SDAR lane (``sdar_small.py``: two layers of
+attention under the block-diffusion rule of sight and softmax-routed experts,
+as one loop over their stacked leaves; a clean and a masked copy of every
+sequence, a weighted loss on the masked rows) through ``FusedBOHB``, its lanes
+taken in turn, every reported loss held to the benchmark's plain reference
+and every promotion to ``benchmark/reference/halving.py``. In a file of its
+own: the sweep's compilation is the suite's cost here, and the workers share
+out files."""
+
+import collections
+import sys
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from hpbandster_tpu import obs
+from hpbandster_tpu.obs.profile import device_phase_map
+from hpbandster_tpu.obs.timeline import (
+    DEVICE_SCOPES, LANE_SCOPES, MOE_SCOPES, PASS_SCOPES)
+from hpbandster_tpu.ops import fused
+from hpbandster_tpu.optimizers import FusedBOHB, sweep_phase_maps
+from hpbandster_tpu.optimizers.fused_bohb import _SWEEP_EXE_CACHE
+from hpbandster_tpu.workloads import lane
+from hpbandster_tpu.workloads import sdar as D
+
+import lane_names
+from sdar_small import SMALL, check_the_moe_backward_rule_is_named, load
+
+
+@pytest.fixture(scope="module")
+def swept():
+    """One bracket of 9, 3, 1 lanes at 1, 3, 9 steps, float32 operands so
+    that the reference can hold every loss tightly, one lane at a time."""
+    sys.modules.setdefault("program", load("program.py"))
+    cfg = load("configs", "sdar-sgd.py").lane_config(SMALL)._replace(attn_query_block=16)
+    patch = pytest.MonkeyPatch()
+    patch.setattr(lane, "_OPERAND", jnp.float32)
+    eval_fn = D.make_sdar_eval_fn(cfg, data_seed=SMALL["data_seed"])
+    patch.setattr(fused, "_device_memory_bytes", lambda: eval_fn.lane_facts.bytes + 1)
+    # the phase maps below are over every sweep executable the process
+    # holds: this worker's earlier files have left theirs
+    _SWEEP_EXE_CACHE.clear()
+    try:
+        opt = FusedBOHB(configspace=D.sdar_space(seed=11), eval_fn=eval_fn,
+                        run_id="sdar", min_budget=1, max_budget=9, eta=3, seed=11)
+        with lane_names.compiled_here():
+            result = opt.run(n_iterations=1)
+        yield opt, result
+    finally:
+        patch.undo()
+
+
+def test_every_reported_loss_is_the_references(swept):
+    _, result = swept
+    reference = load("reference", "sdar-sgd.py")
+    by_lane = collections.defaultdict(dict)
+    for run in result.get_all_runs():
+        by_lane[run.config_id][int(run.budget)] = run.loss
+    id2config = result.get_id2config_mapping()
+    assert sorted(len(v) for v in by_lane.values()) == [1] * 6 + [2, 2, 3]
+    for config_id, reported in by_lane.items():
+        hp = id2config[config_id]["config"]
+        marks = sorted(reported)
+        want = reference.reference_losses(
+            SMALL, [hp[n] for n in reference.HPARAMS], marks)
+        for mark, w in zip(marks, want):
+            # float32 both sides, sums in another order; a lane whose
+            # learning rate is near 1 amplifies that over nine steps
+            assert reference.gap(reported[mark], w) < 2e-3, (hp, mark, reported[mark], w)
+
+
+def test_the_promotions_are_the_halving_references(swept):
+    opt, result = swept
+    program = sys.modules["program"]
+    halving = load("reference", "halving.py")
+    traffic = {"entry": "fused_bohb", "run": {"n_iterations": 1}}
+    plans = halving.schedule(SMALL, traffic, 1)
+    assert plans == [([9, 3, 1], [1.0, 3.0, 9.0])]
+    record = program._runs_record(result, opt.total_evaluated)
+    assert all(value <= limit for _, value, limit in halving.bookkeeping([record], plans))
+    assert halving.promotion_violations(record, plans) == 0
+
+
+def test_the_row_counts_the_lanes_the_rows_and_the_masks(swept):
+    opt, _ = swept
+    row = opt.run_stats[-1]
+    assert row["evaluations"] == 13 and row["lane_steps"] == 27
+    # the data tokens of a step: 32; the rows are twice that and say so
+    assert row["lane_tokens"] == 27 * 32 and row["lanes_at_once"] == 1
+    assert row["diffusion_rows_per_token"] == 2
+    # the held-out sequence's own draw of masks, the same on every lane
+    val = D.make_diffusion_dataset(
+        jax.random.key(SMALL["data_seed"]),
+        load("configs", "sdar-sgd.py").lane_config(SMALL))[1]
+    assert row["diffusion_masked_share"] == pytest.approx(float(val["mask"].mean()))
+    # 4 of 8 experts held, top 2: half of the choices if routing is even,
+    # over the two layers' 64 rows each
+    assert 0.2 < row["moe_held_choice_share"] < 0.8
+    assert 1.0 <= row["moe_load_max_over_mean"] < 4.0
+    # two layers, 64 rows in blocks of 16: clean 1 + 2, masked 2 + 3, of 16
+    assert (row["attn_key_blocks_computed"], row["attn_key_blocks_square"]) == (16, 32)
+    # the block-diffusion rule takes the plain form wherever it runs
+    assert row["attn_scores_in_vmem"] == 0 and row["moe_products_in_vmem"] == 0
+    assert row["moe_combine_by_gather"] == 1
+    assert 1.0 <= row["moe_rows_computed_over_held"] < 8.0
+    gauges = obs.get_metrics().snapshot()["gauges"]
+    assert gauges["sweep.lane.diffusion_rows_per_token"] == 2.0
+    assert gauges["sweep.lane.diffusion_masked_share"] == pytest.approx(
+        row["diffusion_masked_share"])
+    assert gauges["sweep.lane.moe_held_choice_share"] == pytest.approx(
+        row["moe_held_choice_share"])
+    assert gauges["sweep.lane.attn_scores_in_vmem"] == 0.0
+    assert gauges["sweep.lane.lane_steps"] == 27
+
+
+def test_the_lane_names_its_parts_inside_the_trainer(swept):
+    (phases,) = sweep_phase_maps().values()
+    (parts,) = sweep_phase_maps(LANE_SCOPES).values()
+    assert set(parts.values()) == {"lane.bda", "lane.moe", "lane.head", "lane.update"}
+    assert {"hpb.train", "hpb.promote"} <= set(phases.values()) <= set(DEVICE_SCOPES)
+    inside = {phases.get(name) for name in parts}
+    assert inside <= {"hpb.train", "hpb.validate"}
+    text = swept[0].last_executable.as_text()
+    check_the_moe_backward_rule_is_named(text, parts)
+    # attention under its own scope, forward, again and backward
+    passes = device_phase_map(text, PASS_SCOPES)
+    assert {passes.get(n) for n, part in parts.items() if part == "lane.bda"} >= set(PASS_SCOPES)
+
+
+def test_the_trainer_names_its_passes(swept):
+    """Forward, recomputed and backward (``obs.timeline.PASS_SCOPES``), in
+    every part of the lane but the update."""
+    (parts,) = sweep_phase_maps(LANE_SCOPES).values()
+    (passes,) = sweep_phase_maps(PASS_SCOPES).values()
+    text = swept[0].last_executable.as_text()
+    assert passes == lane_names.check_the_trainer_names_its_passes(text, parts)
+
+
+def test_the_older_readers_read_what_they_read(swept):
+    lane_names.check_the_older_readers_read_what_they_read(
+        swept[0].last_executable.as_text())
+
+
+def test_the_expert_layer_names_its_pieces(swept):
+    (parts,) = sweep_phase_maps(LANE_SCOPES).values()
+    (pieces,) = sweep_phase_maps(MOE_SCOPES).values()
+    assert pieces == lane_names.check_the_expert_layer_names_its_pieces(
+        swept[0].last_executable.as_text(), parts, shared=False)

@@ -252,7 +252,7 @@ def test_the_convolution_and_narrow_head_layers_compile_for_v5e_at_the_published
         with jax.named_scope("lane.gqa"):
             return lane.attention_mixer(
                 x, p, kv_heads=cfg.num_kv_heads, heads_per_kv=4, head_dim=cfg.head_dim,
-                inv_freq=L.rotary_inv_freq(cfg), factor=1.0, window=None,
+                inv_freq=L.rotary_inv_freq(cfg), factor=1.0, sight=None,
                 block=cfg.attn_query_block, scope="lane.gqa", norm_eps=cfg.norm_eps)
 
     text = jax.jit(both_passes(attention)).lower(
@@ -342,3 +342,38 @@ def test_the_experts_products_compile_for_v5e_as_grouped_kernels(
     moved = re.findall(r"= \(?(\w+)\[([\d,]*)\]\S* (scatter|gather)\(", text)
     assert [m for m in moved if m[2] == "scatter"] == [("s32", str(t * k), "scatter")]
     assert all("top_k" in line for line in text.splitlines() if " sort(" in line)
+
+
+def test_attention_under_the_block_diffusion_rule_compiles_for_v5e_in_plain_jax(
+        v5e_devices, mosaic_compiles_here):
+    """The SDAR lane's attention scores (``workloads/sdar.py``: 2 x 4,096
+    rows, the clean and the masked copy, under ``lane.BlockDiffusion(4)``) at
+    the published size, forward pass. Where Mosaic compiles the causal lanes
+    take the fused kernels at this shape; this rule of sight takes none
+    (``lane._kernel_tiles`` answers by the rule), and no array is as wide as
+    the rows: the largest float32 array is a masked block's scores, one
+    key/value head's eight query heads of 512 queries against the clean keys
+    and the block's own (4,096 + 512)."""
+    import re
+
+    from hpbandster_tpu.workloads import lane
+    from hpbandster_tpu.workloads import sdar as D
+
+    one = SingleDeviceSharding(v5e_devices[0])
+    cfg = D.SdarConfig()
+    rows, g, r, d = 2 * cfg.seq_len, cfg.num_kv_heads, cfg.num_heads // cfg.num_kv_heads, cfg.head_dim
+    sight = lane.BlockDiffusion(cfg.block_length)
+    assert lane._kernel_tiles(rows, d, r, g) is not None
+    assert lane._kernel_tiles(rows, d, r, g, sight) is None
+
+    def scores(q, k, v):
+        with jax.named_scope("lane.bda"):
+            return lane.banded_attention(q, k, v, sight, cfg.attn_query_block)
+
+    text = jax.jit(scores).lower(
+        _sds((rows, g, r, d), jnp.float32, one), _sds((rows, g, d), jnp.float32, one),
+        _sds((rows, g, d), jnp.float32, one)).compile().as_text()
+    assert _kernel_parts(text) == [] and "lane.bda" in text
+    assert not re.search(r"f32\[[\d,]*\b%d\]" % rows, text)
+    widest = r * cfg.attn_query_block * (cfg.seq_len + cfg.attn_query_block)
+    assert max(size for size in _f32_sizes(text) if size != rows * g * r * d) == widest
