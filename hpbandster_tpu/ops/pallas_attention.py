@@ -1,15 +1,35 @@
-"""Pallas TPU kernels for the lanes' causal, optionally banded,
-grouped-query softmax attention (``workloads/lane.py``
-``banded_attention``): a tile's scores live in VMEM and nowhere else.
+"""Pallas TPU kernels for the lanes' grouped-query softmax attention under
+a rule of sight (``workloads/lane.py`` ``banded_attention``: causal,
+optionally banded, or the block-diffusion rule over a clean and a masked
+copy of the rows): a tile's scores live in VMEM and nowhere else.
 
 One forward and one backward kernel, both over a grid of (key/value head,
 block of queries). A step holds its head's keys and values whole in VMEM
 (they are fetched once a head: the block index does not change from one
 block of queries to the next) and walks the tiles of keys that its
-queries may see, first to last, in loops inside the kernel: a tile
-wholly above the diagonal or below the band is never visited, and only
-the tiles that the diagonal or the band's edge crosses are masked (a
-loop of its own for them, so that no loop's body branches).
+queries may see, in loops inside the kernel: a tile in which the rule
+shows the block nothing is never visited, and only the tiles that hold
+hidden pairs beside seen ones are masked (loops of their own for them, so
+that no loop's body branches).
+
+What a block of queries walks, and what a masked tile hides, is the rule's
+to say and the kernels' static argument: ``rule.tile_loops(lo, tiles,
+rows)`` gives ``[(first, end, width, seen)]`` for the queries ``lo : lo +
+block_q`` (``lo`` a traced number in the kernels, a Python one in
+:func:`tiles_visited`), each a loop over the tiles ``first .. end - 1`` of
+``width`` keys (tile ``j`` starts at key ``j x width``; a loop may take
+tiles narrower than ``block_k``, whole tiles of lanes), ``seen`` None where
+every pair of such a tile is seen, else ``seen(klo) -> bool[block_q,
+width]`` for the tile that starts at key ``klo``. The loops need not be
+contiguous nor in the keys' order (the online softmax takes any), but
+between them they hold every seen pair exactly once; a row whose first
+walked tile shows it nothing is sound (see ``_MASKED``), one that sees
+nothing at all is not. The rules are ``lane.Causal`` (the triangle's or the
+band's tiles, the diagonal's and the band's edge masked) and
+``lane.BlockDiffusion`` (a clean block of queries the triangle of its
+half; a masked one the clean tiles before it, the clean tile that holds its
+own positions masked, then its own keys of the masked copy); nothing here
+knows either by name.
 
 Widths taken (:func:`fits`): heads of a multiple of 128 lanes, a key/value
 head a step; and heads of 64 where the key/value heads pair up (an even
@@ -58,7 +78,7 @@ are (a column broadcasts to a tile of scores by reuse of registers).
 from __future__ import annotations
 
 import functools
-from typing import NamedTuple, Optional
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -71,7 +91,10 @@ __all__ = ["Tiles", "fits", "fused_banded_attention", "heads_a_step", "tiles_vis
 _LANE = 128
 #: what a masked score is set to, ``banded_attention``'s own value: finite,
 #: so that a row whose first tile shows it nothing has a running max to
-#: leave (the diagonal's tile then wipes what it summed: ``exp(-1e30 - m)``)
+#: leave (a band's first tile; under the block-diffusion rule the clean tile
+#: of a masked row of the first diffusion block): the first tile that shows
+#: it something wipes what it summed (``exp(-1e30 - m)`` is exactly 0), and
+#: in the backward kernel ``exp(s - lse)`` of a masked score is exactly 0
 _MASKED = -1e30
 #: the most of the chip's 128 MiB of VMEM that a kernel asks for. It asks
 #: for what its shapes need (:func:`_vmem_bytes`) and no more: what a
@@ -135,48 +158,27 @@ def fits(t: int, d: int, heads_per_kv: int, kv_heads: int, tiles: Tiles,
                             tiles, operand_bytes) <= _VMEM_LIMIT)
 
 
-def _loops(lo, tiles: Tiles, window: Optional[int]):
-    """``[(first, end, masked)]``: the loops that walk the tiles of keys
-    that the queries ``lo : lo + block_q`` may see, in order: those the
-    band's lower edge crosses, masked; those wholly inside; those the
-    diagonal crosses, masked (a narrow window's tile may be crossed by
-    both, and is walked once). ``lo`` a traced number or a Python one."""
-    bq, bk = tiles
-    up, down = (jnp.maximum, jnp.minimum) if isinstance(lo, jax.Array) else (max, min)
-    end = (lo + bq - 1) // bk + 1
-    diagonal = (lo + 1) // bk
-    if window is None:
-        return [(0, diagonal, False), (diagonal, end, True)]
-    first = up(lo - window + 1, 0) // bk
-    clear = up(lo + bq - window + bk - 1, 0) // bk
-    return [(first, down(clear, end), True), (clear, diagonal, False),
-            (up(diagonal, clear), end, True)]
-
-
-def tiles_visited(t: int, window: Optional[int], tiles: Tiles) -> int:
+def tiles_visited(t: int, rule, tiles: Tiles):
     """Tiles of ``block_q x block_k`` scores that one (query head, pass)
-    computes: the kernels' own ranges, in Python."""
-    return sum(max(end - first, 0)
+    computes under ``rule`` over ``t`` rows: the kernels' own ranges
+    (``rule.tile_loops``), in Python; a loop of narrower tiles counts by
+    their width."""
+    return sum(max(end - first, 0) * width
                for lo in range(0, t, tiles.block_q)
-               for first, end, _ in _loops(lo, tiles, window))
+               for first, end, width, _ in rule.tile_loops(lo, tiles, t)) / tiles.block_k
 
 
-def _scores(q, k, lo, klo, tiles: Tiles, window: Optional[int], scale: float, masked: bool):
-    """A tile's scores f32[R x block_q, block_k], ``masked`` where the
-    diagonal or the band's edge crosses it."""
-    bq, bk = tiles
+def _scores(q, k, klo, scale: float, seen):
+    """A tile's scores f32[R x block_q, width], the keys from ``klo``;
+    under ``seen`` (the rule's, ``klo -> bool[block_q, width]``, of a tile
+    that holds pairs the rule hides) the hidden ones are ``_MASKED``."""
     s = lax.dot_general(q, k, _NT, preferred_element_type=jnp.float32) * scale
-    if not masked:
+    if seen is None:
         return s
-    ahead = (lo + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-             - klo - lax.broadcasted_iota(jnp.int32, (bq, bk), 1))
-    seen = ahead >= 0
-    if window is not None:
-        seen = seen & (ahead < window)
     # one block of queries' mask for every query head's rows; added, not
     # selected: ``s - 1e30`` is ``-1e30`` to the last bit
-    bias = jnp.where(seen, 0.0, _MASKED).astype(jnp.float32)
-    return s + jnp.tile(bias, (s.shape[0] // bq, 1))
+    bias = jnp.where(seen(klo), 0.0, _MASKED).astype(jnp.float32)
+    return s + jnp.tile(bias, (s.shape[0] // bias.shape[0], 1))
 
 
 def _across(column, width: int):
@@ -184,13 +186,17 @@ def _across(column, width: int):
     return jnp.tile(column, (1, width // _LANE))
 
 
-def _walk(lo, tiles: Tiles, window: Optional[int], tile):
-    """``tile(klo, masked)`` for every tile of keys the queries ``lo : lo +
-    block_q`` may see, in order; ``klo`` its first key."""
-    for first, end, masked in _loops(lo, tiles, window):
-        lax.fori_loop(
-            first, end, lambda j, _, masked=masked: tile(
-                pl.multiple_of(j * tiles.block_k, tiles.block_k), masked), None)
+def _walk(lo, tiles: Tiles, rule, rows: int, tile):
+    """``tile(keys, klo, seen)`` for every tile of keys that the queries
+    ``lo : lo + block_q`` of ``rows`` may see under ``rule``, loop by loop
+    in the rule's order; ``keys`` the tile's slice, ``klo`` its first key,
+    ``seen`` its loop's mask (None: a tile wholly seen)."""
+    for first, end, width, seen in rule.tile_loops(lo, tiles, rows):
+        def one(j, _, width=width, seen=seen):
+            klo = pl.multiple_of(j * width, width)
+            tile(pl.ds(klo, width), klo, seen)
+
+        lax.fori_loop(first, end, one, None)
 
 
 def _rows(ref, d: int):
@@ -239,7 +245,7 @@ def _store_rows(ref, rows, d: int):
 
 
 def _forward_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, *,
-                    d: int, tiles: Tiles, window: Optional[int]):
+                    d: int, tiles: Tiles, rule):
     lo = pl.program_id(1) * tiles.block_q
     width, scale = acc_ref.shape[-1], d ** -0.5
     q = _rows(q_ref, d)
@@ -247,12 +253,11 @@ def _forward_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, 
     l_ref[...] = jnp.zeros_like(l_ref)
     acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    def tile(klo, masked):
-        keys = pl.ds(klo, tiles.block_k)
-        s = _scores(q, k_ref[keys, :], lo, klo, tiles, window, scale, masked)
+    def tile(keys, klo, seen):
+        s = _scores(q, k_ref[keys, :], klo, scale, seen)
         m_prev = m_ref[...]
         m_next = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-        p = jnp.exp(s - _across(m_next, tiles.block_k))
+        p = jnp.exp(s - _across(m_next, s.shape[1]))
         alpha = jnp.exp(m_prev - m_next)
         l_ref[...] = alpha * l_ref[...] + p.sum(axis=-1, keepdims=True)
         m_ref[...] = m_next
@@ -260,7 +265,7 @@ def _forward_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, 
         acc_ref[...] = _across(alpha, width) * acc_ref[...] + jnp.dot(
             p.astype(v.dtype), v, preferred_element_type=jnp.float32)
 
-    _walk(lo, tiles, window, tile)
+    _walk(lo, tiles, rule, k_ref.shape[0], tile)
     l = l_ref[...]
     _store_rows(o_ref, acc_ref[...] / _across(l, width), d)
     lse_ref[...] = m_ref[...] + jnp.log(l)
@@ -268,7 +273,7 @@ def _forward_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref, 
 
 def _backward_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, do_ref,
                      dq_ref, dk_ref, dv_ref, dq_acc, *,
-                     d: int, tiles: Tiles, window: Optional[int]):
+                     d: int, tiles: Tiles, rule):
     lo = pl.program_id(1) * tiles.block_q
     scale = d ** -0.5
 
@@ -285,11 +290,10 @@ def _backward_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, do_ref,
     lse = lse_ref[...]
     dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    def tile(klo, masked):
-        keys = pl.ds(klo, tiles.block_k)
+    def tile(keys, klo, seen):
         k, v = k_ref[keys, :], v_ref[keys, :]
-        p = jnp.exp(_scores(q, k, lo, klo, tiles, window, scale, masked)
-                    - _across(lse, tiles.block_k))
+        s = _scores(q, k, klo, scale, seen)
+        p = jnp.exp(s - _across(lse, s.shape[1]))
         dv_ref[keys, :] += lax.dot_general(
             p.astype(do.dtype), do, _TN, preferred_element_type=jnp.float32)
         dp = lax.dot_general(do, v, _NT, preferred_element_type=jnp.float32)
@@ -300,7 +304,7 @@ def _backward_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, do_ref,
             ds, q, _TN, preferred_element_type=jnp.float32)
         dq_acc[...] += jnp.dot(ds, k, preferred_element_type=jnp.float32)
 
-    _walk(lo, tiles, window, tile)
+    _walk(lo, tiles, rule, k_ref.shape[0], tile)
     _store_rows(dq_ref, dq_acc[...] * scale, d)
 
 
@@ -336,7 +340,7 @@ def _params(q, k, heads, tiles: Tiles):
             k.shape[0], r * tiles.block_q, width, tiles, q.dtype.itemsize)))
 
 
-def _forward(q, k, v, heads, window, tiles: Tiles, interpret: bool):
+def _forward(q, k, v, heads, rule, tiles: Tiles, interpret: bool):
     """``q [T, G x R x d]``, ``k, v [T, G x d]``, ``heads = (G, R, d)`` ->
     ``(out f32[T, G x R x d], lse f32[steps, blocks, rows, 128])``, ``rows``
     a step's query heads x ``block_q``."""
@@ -344,7 +348,7 @@ def _forward(q, k, v, heads, window, tiles: Tiles, interpret: bool):
     t, blocks, rows = q.shape[0], q.shape[0] // tiles.block_q, r * tiles.block_q
     positions, rows_lane, whole = _specs(t, heads, tiles)
     return pl.pallas_call(
-        functools.partial(_forward_kernel, d=heads[2], tiles=tiles, window=window),
+        functools.partial(_forward_kernel, d=heads[2], tiles=tiles, rule=rule),
         out_shape=(jax.ShapeDtypeStruct(q.shape, jnp.float32),
                    jax.ShapeDtypeStruct((steps, blocks, rows, _LANE), jnp.float32)),
         grid=(steps, blocks),
@@ -358,13 +362,13 @@ def _forward(q, k, v, heads, window, tiles: Tiles, interpret: bool):
     )(q, k, v)
 
 
-def _backward(q, k, v, out, lse, dout, heads, window, tiles: Tiles, interpret: bool):
+def _backward(q, k, v, out, lse, dout, heads, rule, tiles: Tiles, interpret: bool):
     """-> ``(dq f32[T, G x R x d], dk, dv f32[T, G x d])``."""
     steps, r, width = _step(heads)
     t = q.shape[0]
     positions, rows_lane, whole = _specs(t, heads, tiles)
     return pl.pallas_call(
-        functools.partial(_backward_kernel, d=heads[2], tiles=tiles, window=window),
+        functools.partial(_backward_kernel, d=heads[2], tiles=tiles, rule=rule),
         out_shape=(jax.ShapeDtypeStruct(q.shape, jnp.float32),
                    jax.ShapeDtypeStruct(k.shape, jnp.float32),
                    jax.ShapeDtypeStruct(v.shape, jnp.float32)),
@@ -378,28 +382,30 @@ def _backward(q, k, v, out, lse, dout, heads, window, tiles: Tiles, interpret: b
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
-def fused_banded_attention(q, k, v, heads, window: Optional[int], tiles: Tiles, operand,
+def fused_banded_attention(q, k, v, heads, rule, tiles: Tiles, operand,
                            scope: str, interpret: bool = False):
     """``banded_attention``'s mathematics by the kernels above, the heads
     side by side: ``q`` f32[T, G x R x d] (query head ``g * R + r`` on
     key/value head ``g``), ``k, v`` f32[T, G x d], ``heads = (G, R, d)`` ->
-    f32[T, G x R x d]; both products' operands are cast to ``operand``. The
+    f32[T, G x R x d], under ``rule`` (a rule of sight, hashable: what the
+    module's account asks of it); both products' operands are cast to
+    ``operand``. The
     device operations of both rules are named ``scope`` (a
     ``jax.named_scope``; the backward rule is traced where its caller's
     scope is no longer open)."""
-    return _attention_forward(q, k, v, heads, window, tiles, operand, scope, interpret)[0]
+    return _attention_forward(q, k, v, heads, rule, tiles, operand, scope, interpret)[0]
 
 
-def _attention_forward(q, k, v, heads, window, tiles, operand, scope, interpret):
+def _attention_forward(q, k, v, heads, rule, tiles, operand, scope, interpret):
     with jax.named_scope(scope):
         q, k, v = (x.astype(operand) for x in (q, k, v))
-        out, lse = _forward(q, k, v, heads, window, tiles, interpret)
+        out, lse = _forward(q, k, v, heads, rule, tiles, interpret)
         return out, (q, k, v, out, lse)
 
 
-def _attention_backward(heads, window, tiles, operand, scope, interpret, kept, dout):
+def _attention_backward(heads, rule, tiles, operand, scope, interpret, kept, dout):
     with jax.named_scope(scope):
-        return _backward(*kept, dout, heads, window, tiles, interpret)
+        return _backward(*kept, dout, heads, rule, tiles, interpret)
 
 
 fused_banded_attention.defvjp(_attention_forward, _attention_backward)

@@ -227,7 +227,15 @@ class Causal(NamedTuple):
     """The rule of sight of a causal model: a row is a position, and
     position ``i`` sees ``j <= i`` and, with a window, ``i - j < window``.
     Where a rule is asked for, a bare number or ``None`` is this rule with
-    that window."""
+    that window.
+
+    A rule of sight says, of ``rows`` rows: their ``positions`` (for the
+    rotary tables), the pairs ``seen(at, key, rows)`` on arrays of row
+    numbers, the plain form's ``spans(rows, block)`` (the runs of keys a
+    block of queries goes against) and, for the fused kernels,
+    ``whole_tiles(rows, tiles)`` (whether its rows fall into whole tiles)
+    and ``tile_loops(lo, tiles, rows)`` (the tiles a block of queries walks
+    and their masks: ``ops/pallas_attention.py`` says what they hold)."""
 
     window: Optional[int] = None
 
@@ -245,6 +253,33 @@ class Causal(NamedTuple):
                             else max(0, lo - self.window + 1) // block * block)
         return [(lo, min(lo + block, rows), ((first(lo), min(lo + block, rows)),))
                 for lo in range(0, rows, block)]
+
+    def whole_tiles(self, rows: int, tiles) -> bool:
+        return True
+
+    def tile_loops(self, lo, tiles, rows: int):
+        """The band's loops: the tiles its lower edge crosses, masked; those
+        wholly inside; those the diagonal crosses, masked (a narrow window's
+        tile may be crossed by both, and is walked once)."""
+        bq, bk = tiles
+        up, down = (jnp.maximum, jnp.minimum) if isinstance(lo, jax.Array) else (max, min)
+        end = (lo + bq - 1) // bk + 1
+        diagonal = (lo + 1) // bk
+
+        def band(klo):
+            ahead = (lo + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+                     - klo - jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1))
+            seen = ahead >= 0
+            if self.window is not None:
+                seen = seen & (ahead < self.window)
+            return seen
+
+        if self.window is None:
+            return [(0, diagonal, bk, None), (diagonal, end, bk, band)]
+        first = up(lo - self.window + 1, 0) // bk
+        clear = up(lo + bq - self.window + bk - 1, 0) // bk
+        return [(first, down(clear, end), bk, band), (clear, diagonal, bk, None),
+                (up(diagonal, clear), end, bk, band)]
 
 
 class BlockDiffusion(NamedTuple):
@@ -292,6 +327,45 @@ class BlockDiffusion(NamedTuple):
 
         return clean + [masked(lo, hi) for lo, hi, _ in clean]
 
+    def whole_tiles(self, rows: int, tiles) -> bool:
+        bq, bk = tiles
+        half = rows // 2
+        return (rows % 2 == 0 and half % bq == 0 and half % bk == 0
+                and bq % self.block_length == 0)
+
+    def tile_loops(self, lo, tiles, rows: int):
+        """A clean block of queries walks the causal triangle's tiles (its
+        diagonal's masked: a query sees its whole diffusion block); a masked
+        one the clean tiles wholly before it, the clean tile(s) that hold
+        its own positions, masked (a query sees the diffusion blocks before
+        its own), then its own keys in the masked copy, masked, as a tile no
+        wider than the block where that is whole tiles of lanes."""
+        bq, bk = tiles
+        half, length = rows // 2, self.block_length
+        masked = (lo >= half) * 1            # 1: a block of the masked copy
+        at = lo - masked * half              # the block's first position
+        reach = (1 - masked) * length        # of its own diffusion block's clean keys
+        # the block's own keys as a tile of its own width (whole tiles of 128
+        # lanes), not of ``bk``: the SDAR layer's scores alone read 3.13 / 8.75
+        # ms so (forward / forward and backward) and 3.27 / 9.09 ms with the
+        # own keys a whole tile of 512 (PR 43, on the chip)
+        own = bq if bq < bk and bq % 128 == 0 else bk
+        start = (lambda x: x & -length) if length & (length - 1) == 0 else (
+            lambda x: x - jax.lax.rem(x, length))     # of a position's diffusion block
+        iota = lambda width, axis: jax.lax.broadcasted_iota(jnp.int32, (bq, width), axis)
+
+        def before(klo):
+            return klo + iota(bk, 1) < start(at + iota(bk, 0)) + reach
+
+        def itself(klo):
+            return start(klo - half + iota(own, 1)) == start(at + iota(own, 0))
+
+        clear = (at + reach) // bk           # the tiles before it that every query sees whole
+        first = (half + at) // own
+        return [(0, clear, bk, None),
+                (clear, (at + bq - 1 + bk - masked * length) // bk, bk, before),
+                (first, first + masked * ((half + at + bq - 1) // own + 1 - first), own, itself)]
+
 
 def _rule(sight):
     """A rule of sight as its class: a bare window (or ``None``) is causal."""
@@ -308,25 +382,27 @@ def _attention_spans(t: int, sight, block: int):
 
 
 def _kernel_tiles(t: int, d: int, heads_per_kv: int, kv_heads: int, sight=None):
-    """The fused kernels' tiles for ``t`` positions and ``kv_heads``
-    key/value heads of ``d``, or None where the plain form runs: the kernels
-    (``ops/pallas_attention.py``: a tile's scores never leave VMEM) where
-    Mosaic compiles them, on a TPU backend, the rule of sight is the one
-    they hold (:class:`Causal`), the keys are more than the plain
-    form is quick at (:data:`_PLAIN_KEYS`) and the shapes fit the kernels'
-    tiles (heads of whole tiles of lanes, or of 64 in pairs); off the chip
-    the plain form (:func:`banded_attention`), which is also what the
-    kernels are tested against. A block of queries is sized from the rows a
-    step really holds: a pair's query heads where heads of 64 pair up."""
-    if not isinstance(_rule(sight), Causal):
-        return None
+    """The fused kernels' tiles for ``t`` rows and ``kv_heads`` key/value
+    heads of ``d`` under the rule of sight ``sight``, or None where the
+    plain form runs: the kernels (``ops/pallas_attention.py``: a tile's
+    scores never leave VMEM) where Mosaic compiles them, on a TPU backend,
+    the keys are more than the plain form is quick at (:data:`_PLAIN_KEYS`),
+    the shapes fit the kernels' tiles (heads of whole tiles of lanes, or of
+    64 in pairs) and the rule's rows are whole tiles (``whole_tiles``: any
+    under :class:`Causal`; under :class:`BlockDiffusion` each copy whole
+    tiles of keys and of queries, a block of queries whole diffusion
+    blocks); off the chip the plain form (:func:`banded_attention`), which
+    is also what the kernels are tested against. The backend and the shapes
+    decide, under either rule alike. A block of queries is sized from the
+    rows a step really holds: a pair's query heads where heads of 64 pair
+    up."""
     if not pallas_available() or t <= _PLAIN_KEYS:
         return None
     keys = min(t, _KERNEL_KEYS)
     heads = pallas_attention.heads_a_step(d, heads_per_kv)
     tiles = pallas_attention.Tiles(min(keys, max(_KERNEL_ROWS // heads, 16)), keys)
-    if not pallas_attention.fits(
-            t, d, heads_per_kv, kv_heads, tiles, np.dtype(_OPERAND).itemsize):
+    if not (_rule(sight).whole_tiles(t, tiles) and pallas_attention.fits(
+            t, d, heads_per_kv, kv_heads, tiles, np.dtype(_OPERAND).itemsize)):
         return None
     return tiles
 
@@ -337,9 +413,10 @@ def attention_key_blocks(t: int, sights, block: int, tiles=None):
     sight (a window or ``None`` each: causal; or a :class:`BlockDiffusion`,
     ``t`` its ``2 S`` rows), and those of their full squares; with the
     fused kernels' ``tiles`` (:func:`_kernel_tiles`), tiles of ``block_q x
-    block_k``."""
+    block_k`` that the kernels walk under each rule (a narrower tile counts
+    by its width: a masked block's own keys under :class:`BlockDiffusion`)."""
     if tiles is not None:
-        return (sum(pallas_attention.tiles_visited(t, _rule(sight).window, tiles)
+        return (sum(pallas_attention.tiles_visited(t, _rule(sight), tiles)
                     for sight in sights),
                 (t // tiles.block_q) * (t // tiles.block_k) * len(sights))
     per_side = -(-t // block)
@@ -355,8 +432,10 @@ def attention_counters(t: int, d: int, heads_per_kv: int, kv_heads: int, sight=N
     counted ones (``make_lane_eval_fn(static_counters=...)``): the share of
     its attention layers whose scores stay in VMEM (the fused kernels; the
     layers of a lane are of one shape and one kind of rule, so all of them
-    or none: 1 on the chip at the published sizes of the causal lanes, 0 on
-    a CPU and under :class:`BlockDiffusion`)."""
+    or none: 1 on the chip at the published sizes of the Mellum2, LFM2 and
+    SDAR lanes, under :class:`Causal` and :class:`BlockDiffusion` alike; 0
+    on a CPU, at the Ouro lane's 2,048 keys and where a shape does not fit
+    the kernels' tiles)."""
     return (("attn_scores_in_vmem",
              float(_kernel_tiles(t, d, heads_per_kv, kv_heads, sight) is not None)),)
 
@@ -482,7 +561,7 @@ def attention_mixer(x, p, *, kv_heads: int, heads_per_kv: int, head_dim: int,
     if tiles is not None:
         out = pallas_attention.fused_banded_attention(
             _rotate_side_by_side(q, cos, sin), _rotate_side_by_side(k, cos, sin), v,
-            (g, r, d), rule.window, tiles, _OPERAND, scope)
+            (g, r, d), rule, tiles, _OPERAND, scope)
     else:
         out = banded_attention(
             _rotate(q.reshape(t, g, r, d), cos, sin), _rotate(k.reshape(t, g, d), cos, sin),

@@ -233,7 +233,18 @@ def _kernel_qkv(length, g, r, d=128):
     return _qkv(length, seed=length + r, g=g, r=r, d=d)
 
 
-@pytest.mark.parametrize("operand, limit, length, window, tiles", [
+_DIFFUSION = lane.BlockDiffusion(4)
+
+
+_KERNEL_HEADS = [
+    (2, 1, 128), (1, 8, 128),
+    # heads of 64, two key/value heads side by side in a tile of lanes: one
+    # pair and its eight query heads (a 128-lane slice of the block holds
+    # two query heads of ONE key/value head), two pairs of one query head
+    # each (a slice holds a query head of each), four pairs
+    (2, 4, 64), (4, 1, 64), (8, 1, 64),
+]
+_CAUSAL_CASES = [
     # float32 operands: the plain form's float32 sums in another order
     (jnp.float32, 2e-5, 128, None, (128, 128)),    # a sequence of one tile
     (jnp.float32, 2e-5, 384, None, (128, 128)),    # of several: 1 + 2 + 3 tiles of keys
@@ -247,35 +258,48 @@ def _kernel_qkv(length, g, r, d=128):
     (jnp.bfloat16, 2e-2, 384, None, (128, 128)),
     (jnp.bfloat16, 2e-2, 384, 100, (128, 128)),
     (jnp.bfloat16, 2e-2, 512, 200, (64, 256)),
-])
-@pytest.mark.parametrize("g, r, d", [
-    (2, 1, 128), (1, 8, 128),
-    # heads of 64, two key/value heads side by side in a tile of lanes: one
-    # pair and its eight query heads (a 128-lane slice of the block holds
-    # two query heads of ONE key/value head), two pairs of one query head
-    # each (a slice holds a query head of each), four pairs
-    (2, 4, 64), (4, 1, 64), (8, 1, 64),
-])
+]
+_DIFFUSION_CASES = [
+    # the block-diffusion rule of sight over 2 x 256 rows in diffusion
+    # blocks of 4: a block of queries narrower than a tile of keys, as wide,
+    # wider; and one of whole tiles of lanes under a wider tile of keys,
+    # whose masked blocks walk their own keys as a tile of their own width
+    (jnp.float32, 2e-5, 512, _DIFFUSION, (64, 128)),
+    (jnp.float32, 2e-5, 512, _DIFFUSION, (128, 128)),
+    (jnp.float32, 2e-5, 512, _DIFFUSION, (256, 128)),
+    (jnp.float32, 2e-5, 512, _DIFFUSION, (128, 256)),
+    (jnp.bfloat16, 2e-2, 512, _DIFFUSION, (64, 128)),
+    (jnp.bfloat16, 2e-2, 512, _DIFFUSION, (128, 256)),
+]
+
+
+@pytest.mark.parametrize(
+    "operand, limit, length, sight, tiles, g, r, d",
+    [case + heads for case in _CAUSAL_CASES for heads in _KERNEL_HEADS]
+    # under the rule: heads of 128, one and eight to a key/value head, and a
+    # pair of heads of 64 with its eight query heads
+    + [case + heads for case in _DIFFUSION_CASES for heads in _KERNEL_HEADS[:3]])
 def test_the_fused_kernels_are_the_plain_form(monkeypatch, operand, limit, length,
-                                              window, tiles, g, r, d):
+                                              sight, tiles, g, r, d):
     """``ops.pallas_attention`` in the Pallas interpreter against
-    ``banded_attention``'s plain JAX, heads of 128 and pairs of heads of 64:
-    the values and the gradients with respect to ``q``, ``k`` and ``v``,
-    each within ``limit`` of the largest entry (of one where the plain form
-    gives all zeros: the queries' gradient when a position sees itself
-    alone)."""
+    ``banded_attention``'s plain JAX under the same rule of sight (a window
+    or ``None``: causal; the block-diffusion rule), heads of 128 and pairs
+    of heads of 64: the values and the gradients with respect to ``q``,
+    ``k`` and ``v``, each within ``limit`` of the largest entry (of one
+    where the plain form gives all zeros: the queries' gradient when a
+    position sees itself alone)."""
     from hpbandster_tpu.ops import pallas_attention
 
     monkeypatch.setattr(lane, "_OPERAND", operand)
     q, k, v = _kernel_qkv(length, g, r, d)
-    tiles = pallas_attention.Tiles(*tiles)
-    assert pallas_attention.fits(length, d, r, g, tiles)
+    tiles, rule = pallas_attention.Tiles(*tiles), lane._rule(sight)
+    assert pallas_attention.fits(length, d, r, g, tiles) and rule.whole_tiles(length, tiles)
     t = length
     flat = lambda x: x.reshape(t, -1)     # the kernels take the heads side by side
     fused = lambda q, k, v: pallas_attention.fused_banded_attention(
-        flat(q), flat(k), flat(v), (g, r, d), window, tiles, operand, "lane.swa", True
+        flat(q), flat(k), flat(v), (g, r, d), rule, tiles, operand, "lane.swa", True
     ).reshape(q.shape)
-    plain = lambda q, k, v: lane.banded_attention(q, k, v, window, 64)
+    plain = lambda q, k, v: lane.banded_attention(q, k, v, sight, 64)
     weigh = jax.random.normal(jax.random.key(1), q.shape)
     got, pull = jax.vjp(fused, q, k, v)
     want, pull_plain = jax.vjp(plain, q, k, v)
@@ -307,8 +331,8 @@ def test_a_pair_of_heads_leaks_nothing_between_its_halves(monkeypatch, operand, 
     v = v * loud[None, :, None]
     flat = lambda x: x.reshape(t, -1)
     fused = lambda q, k, v: pallas_attention.fused_banded_attention(
-        flat(q), flat(k), flat(v), (g, r, d), window, tiles, operand, "lane.gqa", True
-    ).reshape(q.shape)
+        flat(q), flat(k), flat(v), (g, r, d), lane.Causal(window), tiles, operand, "lane.gqa",
+        True).reshape(q.shape)
     plain = lambda q, k, v: lane.banded_attention(q, k, v, window, 64)
     weigh = jax.random.normal(jax.random.key(2), q.shape) * loud[None, :, None, None]
     got, pull = jax.vjp(fused, q, k, v)
@@ -351,10 +375,18 @@ def test_the_kernels_visit_the_band_and_one_tile(monkeypatch):
 
     # 64 blocks of 128 queries: keys from ``lo - 1,023`` to ``lo + 127``, in
     # tiles of 512 three (the first blocks fewer)
-    assert tiles_visited(8192, 1024, Tiles(128, 512)) == 1 + 1 + 1 + 1 + 2 * 4 + 3 * 56
-    assert tiles_visited(8192, None, Tiles(128, 512)) == 4 * sum(range(1, 17))
-    assert tiles_visited(2048, None, Tiles(512, 512)) == 1 + 2 + 3 + 4
-    assert tiles_visited(256, 1, Tiles(64, 128)) == 4
+    assert tiles_visited(8192, lane.Causal(1024), Tiles(128, 512)) == 1 + 1 + 1 + 1 + 2 * 4 + 3 * 56
+    assert tiles_visited(8192, lane.Causal(), Tiles(128, 512)) == 4 * sum(range(1, 17))
+    assert tiles_visited(2048, lane.Causal(), Tiles(512, 512)) == 1 + 2 + 3 + 4
+    assert tiles_visited(256, lane.Causal(1), Tiles(64, 128)) == 4
+    # the block-diffusion rule over 2 x 4,096 rows: a clean block of queries
+    # walks the causal triangle of its half (144 tiles), a masked one the
+    # same clean tiles (the last of them masked) and its own 128 keys of the
+    # masked copy, a quarter of a tile: 31.25 % of the square's 1,024 tiles
+    # where the own keys are walked as a whole tile, 28.9 % as they are
+    assert tiles_visited(8192, _DIFFUSION, Tiles(128, 512)) == 144 + 144 + 32 / 4 == 296
+    # a block as wide as a tile of keys walks its own keys as that tile
+    assert tiles_visited(8192, _DIFFUSION, Tiles(512, 512)) == 36 + 36 + 8
     # whole tiles of whole lanes, and a head's keys and values within VMEM
     assert fits(8192, 128, 8, 4, Tiles(128, 512)) and fits(2048, 128, 1, 16, Tiles(512, 512))
     assert not fits(8192, 64, 8, 3, Tiles(128, 512))
@@ -362,28 +394,36 @@ def test_the_kernels_visit_the_band_and_one_tile(monkeypatch):
     assert not fits(2 ** 16, 128, 8, 4, Tiles(128, 512))
 
 
-@pytest.mark.parametrize("window", [None, 1, 100, 128, 200, 512, 1000])
+@pytest.mark.parametrize("sight", [None, 1, 100, 128, 200, 512, 1000,
+                                   _DIFFUSION, lane.BlockDiffusion(32), lane.BlockDiffusion(64)])
 @pytest.mark.parametrize("block_q, block_k", [(64, 128), (128, 128), (256, 128), (128, 512)])
-def test_the_kernels_loops_cover_what_a_block_sees_once(window, block_q, block_k):
-    """The three loops of a block of queries (``_loops``), held
-    against the pairs themselves: every tile that holds a visible pair is
-    walked exactly once, none that holds none, and a tile walked without a
-    mask holds no hidden pair."""
-    from hpbandster_tpu.ops.pallas_attention import Tiles, _loops
+def test_the_kernels_loops_cover_what_a_block_sees_once(sight, block_q, block_k):
+    """The loops of a block of queries (``tile_loops`` of the rule of
+    sight: what the kernels walk and ``tiles_visited`` counts), held against
+    the pairs themselves (the rule's ``seen``): every key that a query of
+    the block sees lies in exactly one walked tile, no walked tile holds
+    none, a tile walked without a mask holds no hidden pair, and a masked
+    tile's mask is the rule's own."""
+    from hpbandster_tpu.ops.pallas_attention import Tiles, tiles_visited
 
-    t, tiles = 1024, Tiles(block_q, block_k)
-    at, key = np.arange(t)[:, None], np.arange(t)[None, :]
-    seen = key <= at
-    if window is not None:
-        seen &= at - key < window
+    t, tiles, rule = 1024, Tiles(block_q, block_k), lane._rule(sight)
+    assert rule.whole_tiles(t, tiles)
+    seen = np.asarray(rule.seen(jnp.arange(t)[:, None], jnp.arange(t)[None, :], t))
+    walked_keys = 0
     for lo in range(0, t, block_q):
-        walked = [(j, masked) for first, end, masked in _loops(lo, tiles, window)
-                  for j in range(first, end)]
-        block = seen[lo:lo + block_q]
-        holds = [j for j in range(t // block_k) if block[:, j * block_k:(j + 1) * block_k].any()]
-        assert sorted(j for j, _ in walked) == holds
-        for j, masked in walked:
-            assert masked or block[:, j * block_k:(j + 1) * block_k].all()
+        block, covered = seen[lo:lo + block_q], np.zeros(t, int)
+        for first, end, width, mask in rule.tile_loops(lo, tiles, t):
+            for klo in range(first * width, end * width, width):
+                covered[klo:klo + width] += 1
+                tile = block[:, klo:klo + width]
+                assert tile.any()
+                if mask is None:
+                    assert tile.all()
+                else:
+                    np.testing.assert_array_equal(mask(klo), tile)
+        assert covered.max() == 1 and (covered[block.any(axis=0)] == 1).all()
+        walked_keys += covered.sum()
+    assert tiles_visited(t, rule, tiles) == walked_keys / block_k
 
 
 def test_off_the_chip_the_plain_form_runs_and_the_counter_says_so(monkeypatch):
@@ -486,6 +526,10 @@ def test_heads_side_by_side_turn_as_heads_apart(kind):
     # heads of 64 in pairs, each head of the queries and of the keys through
     # its norm first (the LFM2 lane's layer); and heads of 128 under the norm
     (None, 4, 64, True), (100, 1, 64, True), (100, 4, 64, False), (None, 1, 128, True),
+    # the block-diffusion rule of sight (the rows a clean and a masked copy,
+    # the rotary tables at the rows' repeated positions): the SDAR lane's
+    # layer, heads of 128 under the norm; and pairs of heads of 64
+    (_DIFFUSION, 4, 128, True), (_DIFFUSION, 1, 128, False), (_DIFFUSION, 4, 64, True),
 ])
 def test_the_mixer_with_the_kernels_is_the_mixer_without(monkeypatch, window, r, d, normed):
     """``attention_mixer`` as the chip runs it (the rule told that Mosaic
@@ -524,7 +568,7 @@ def test_the_mixer_with_the_kernels_is_the_mixer_without(monkeypatch, window, r,
         lambda *args: calls.append(args[3:6]) or in_interpreter(*args, True))
     got, pull = jax.vjp(mixer, x, p)
     # a step's rows are 128 whatever the width: a pair's 2 x r heads of 64
-    assert calls == [((g, r, d), window, (max(128 // (r * (128 // d)), 16), 128))]
+    assert calls == [((g, r, d), lane._rule(window), (max(128 // (r * (128 // d)), 16), 128))]
     for ours, theirs in zip((got,) + tuple(jax.tree.leaves(pull(weigh))), want):
         np.testing.assert_allclose(ours, theirs, atol=2e-2 * float(jnp.abs(theirs).max()))
 

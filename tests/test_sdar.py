@@ -195,7 +195,7 @@ def test_attention_under_the_rule_is_the_dense_masked_softmax(float32_operands, 
         np.testing.assert_allclose(a, b, atol=2e-5)
 
 
-def test_positions_repeat_and_the_kernels_are_not_taken(monkeypatch):
+def test_positions_repeat_and_the_kernels_take_the_rule(monkeypatch):
     sight = lane.BlockDiffusion(4)
     np.testing.assert_array_equal(sight.positions(8), [0, 1, 2, 3, 0, 1, 2, 3])
     inv_freq = 10000.0 ** (-np.arange(0, 8, 2) / 8)
@@ -204,16 +204,72 @@ def test_positions_repeat_and_the_kernels_are_not_taken(monkeypatch):
     for twice, once in ((cos, plain[0]), (sin, plain[1])):
         np.testing.assert_array_equal(twice[:4], once)
         np.testing.assert_array_equal(twice[4:], once)
-    # the rule decides, whatever the backend and the shapes: the fused
-    # kernels hold the causal rule alone
+    # off the chip the plain form, whatever the rule
+    assert lane._kernel_tiles(8192, 128, 8, 4, sight) is None
+    assert lane.attention_counters(8192, 128, 8, 4, sight) == (("attn_scores_in_vmem", 0.0),)
+    # where Mosaic compiles, the backend and the shapes decide under either
+    # rule: the fused kernels walk the tiles the rule gives them
     monkeypatch.setattr(lane, "pallas_available", lambda: True)
     assert lane._kernel_tiles(8192, 128, 8, 4) == (128, 512)
     assert lane._kernel_tiles(8192, 128, 8, 4, lane.Causal(1024)) == (128, 512)
-    assert lane._kernel_tiles(8192, 128, 8, 4, sight) is None
-    assert lane.attention_counters(8192, 128, 8, 4, sight) == (("attn_scores_in_vmem", 0.0),)
+    assert lane._kernel_tiles(8192, 128, 8, 4, sight) == (128, 512)
+    assert lane._kernel_tiles(8192, 64, 4, 8, sight) == (128, 512)
+    assert lane.attention_counters(8192, 128, 8, 4, sight) == (("attn_scores_in_vmem", 1.0),)
+    # a copy that is no whole tiles of keys (17 x 256 rows), diffusion blocks
+    # that a block of 128 queries would cut, few keys: the plain form, where
+    # the causal rule takes the same number of rows
+    assert lane._kernel_tiles(8704, 128, 8, 4) == (128, 512)
+    assert lane._kernel_tiles(8704, 128, 8, 4, sight) is None
+    assert lane._kernel_tiles(8192, 128, 8, 4, lane.BlockDiffusion(24)) is None
+    assert lane._kernel_tiles(2048, 128, 8, 4, sight) is None
+    # the counted tiles and the footprint follow the path that runs: 296 of
+    # 1,024 tiles of 128 x 512 a layer (a masked block's own keys are a tile
+    # of 128), an output and a log-sum-exp a row and no block of scores
+    assert lane.attention_key_blocks(
+        8192, [sight] * 4, 512, lane._kernel_tiles(8192, 128, 8, 4, sight)) == (4 * 296, 4 * 1024)
+    assert lane.attention_alive_bytes(8192, 4, 8, 128, [sight], 512) == 4 * 8192 * 32 * (128 + 128)
     # a bare window is the causal rule
     assert lane._rule(None) == lane.Causal() and lane._rule(8) == lane.Causal(8)
     assert lane._rule(sight) is sight
+
+
+@pytest.mark.parametrize("operand", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("tiles, g, r, d", [((64, 128), 2, 2, 128), ((128, 256), 1, 4, 128),
+                                            ((64, 128), 2, 2, 64)])
+def test_the_kernels_show_a_masked_row_nothing_the_rule_hides(operand, tiles, g, r, d):
+    """The fused kernels under the rule, in the Pallas interpreter: the keys
+    and values that the rule hides from the masked rows of a diffusion block
+    (its own block's clean copy, every later clean block, every other
+    block's masked rows) changed outright move none of those rows' outputs
+    and none of their queries' gradients, to the last bit (a hidden pair
+    weighs exactly 0 in both kernels), though rows that do see them move;
+    and a masked row of the first block, which sees no clean key at all (its
+    first walked tile shows it nothing), comes out finite in both."""
+    from hpbandster_tpu.ops import pallas_attention
+
+    t, length = 512, 4
+    half, rule, tiles = t // 2, lane.BlockDiffusion(length), pallas_attention.Tiles(*tiles)
+    assert pallas_attention.fits(t, d, r, g, tiles) and rule.whole_tiles(t, tiles)
+    keys = jax.random.split(jax.random.key(7), 6)
+    q, weigh = (jax.random.normal(key, (t, g * r * d)) for key in keys[:2])
+    k, v, k_other, v_other = (jax.random.normal(key, (t, g * d)) for key in keys[2:])
+
+    def out_and_dq(k, v):
+        out, pull = jax.vjp(lambda q: pallas_attention.fused_banded_attention(
+            q, k, v, (g, r, d), rule, tiles, operand, "lane.bda", True), q)
+        return out, pull(weigh)[0]
+
+    want = out_and_dq(k, v)
+    assert all(bool(jnp.isfinite(x).all()) for x in want)
+    assert float(jnp.abs(want[0][half:half + length]).max()) > 0
+    for block in (0, 17, half // length - 1):
+        rows = slice(half + block * length, half + (block + 1) * length)
+        at = jnp.arange(t)
+        hidden = jnp.where(at < half, at >= block * length, (at < rows.start) | (at >= rows.stop))
+        got = out_and_dq(jnp.where(hidden[:, None], k_other, k), jnp.where(hidden[:, None], v_other, v))
+        for ours, theirs in zip(got, want):
+            np.testing.assert_array_equal(ours[rows], theirs[rows])
+            assert float(jnp.abs(ours - theirs).max()) > 0     # the probe reaches other rows
 
 
 # ----------------------------------------------------------- seed, loss, gradient

@@ -344,16 +344,22 @@ def test_the_experts_products_compile_for_v5e_as_grouped_kernels(
     assert all("top_k" in line for line in text.splitlines() if " sort(" in line)
 
 
-def test_attention_under_the_block_diffusion_rule_compiles_for_v5e_in_plain_jax(
+def test_attention_under_the_block_diffusion_rule_compiles_for_v5e_with_the_kernels(
         v5e_devices, mosaic_compiles_here):
-    """The SDAR lane's attention scores (``workloads/sdar.py``: 2 x 4,096
+    """The SDAR lane's attention layer (``workloads/sdar.py``: 2 x 4,096
     rows, the clean and the masked copy, under ``lane.BlockDiffusion(4)``) at
-    the published size, forward pass. Where Mosaic compiles the causal lanes
-    take the fused kernels at this shape; this rule of sight takes none
-    (``lane._kernel_tiles`` answers by the rule), and no array is as wide as
-    the rows: the largest float32 array is a masked block's scores, one
-    key/value head's eight query heads of 512 queries against the clean keys
-    and the block's own (4,096 + 512)."""
+    the published size, forward and backward pass. Where Mosaic compiles the
+    rule of sight takes the fused kernels at this shape as the causal rule
+    does (``lane._kernel_tiles`` answers by the backend and the shapes): both
+    carry ``lane.bda`` in the compiled text, no float32 array of a block's
+    scores exists (the plain form's widest was one key/value head's eight
+    query heads of 512 queries against 4,096 + 512 keys; nothing is as wide
+    as the rows now): the largest is the projections' output (8,192 x
+    5,120), and after it the log-sum-exp, a number a (query head, row) kept
+    across the 128 lanes. Then the plain form under the rule
+    alone, which the CPU and the reference's tests still run: the chip's
+    compiler takes it, with no kernel, and its largest float32 array is that
+    masked block's scores."""
     import re
 
     from hpbandster_tpu.workloads import lane
@@ -363,8 +369,30 @@ def test_attention_under_the_block_diffusion_rule_compiles_for_v5e_in_plain_jax(
     cfg = D.SdarConfig()
     rows, g, r, d = 2 * cfg.seq_len, cfg.num_kv_heads, cfg.num_heads // cfg.num_kv_heads, cfg.head_dim
     sight = lane.BlockDiffusion(cfg.block_length)
-    assert lane._kernel_tiles(rows, d, r, g) is not None
-    assert lane._kernel_tiles(rows, d, r, g, sight) is None
+    assert lane._kernel_tiles(rows, d, r, g, sight) == lane._kernel_tiles(rows, d, r, g) == (128, 512)
+
+    def attention(x, p):
+        with jax.named_scope("lane.bda"):
+            return lane.attention_mixer(
+                x, p, kv_heads=g, heads_per_kv=r, head_dim=d, inv_freq=D.rotary_inv_freq(cfg),
+                factor=1.0, sight=sight, block=cfg.attn_query_block, scope="lane.bda",
+                norm_eps=cfg.rms_norm_eps)
+
+    def both_passes(x, p, dy):
+        y, pull = jax.vjp(attention, x, p)
+        return y, pull(dy)     # pulled back where the caller's scope is closed
+
+    x = _sds((rows, cfg.hidden_size), jnp.float32, one)
+    leaves = {name: _sds(shape, jnp.float32, one)
+              for name, shape in D._layer_shapes(cfg).items()
+              if name in ("wq", "wk", "wv", "wo", "q_norm", "k_norm")}
+    text = jax.jit(both_passes).lower(x, leaves, x).compile().as_text()
+    assert _kernel_parts(text) == [
+        ("banded_attention_backward", "lane.bda"), ("banded_attention_forward", "lane.bda")]
+    # (the one array as long as the rows is their positions, a vector)
+    assert not re.search(r"f32\[[\d,]+,%d\]" % rows, text)
+    sizes = _f32_sizes(text)
+    assert max(sizes) == rows * (g * r * d + 2 * g * d) and cfg.num_heads * rows * 128 in sizes
 
     def scores(q, k, v):
         with jax.named_scope("lane.bda"):
