@@ -10,6 +10,7 @@ lane as the chip does (bfloat16 operands), the tolerance is bfloat16's.
 
 import json
 import os
+import re
 import sys
 
 import jax
@@ -155,6 +156,210 @@ def test_chunked_kda_in_float32_and_its_gradient(reference, float32_operands):
     for g, w in zip(got, want):
         # float32 sums in another order
         np.testing.assert_allclose(g, w, atol=2e-4 * float(jnp.abs(w).max()))
+
+
+def _plain_chunk_products(q, k, g, sub):
+    """``K._chunk_products`` as it stood, with nothing held back from JAX's
+    gradient: where a block's decay is split takes its two cotangents."""
+    c, d = q.shape[-2:]
+    r = c // sub
+    blocks = lambda x: x.reshape(x.shape[:-2] + (r, sub, d))
+    qb, kb, gb = blocks(q), blocks(k), blocks(g)
+    tri = jnp.tril(jnp.ones((sub, sub), bool))[:, :, None]
+    decay = jnp.exp(jnp.where(
+        tri, gb[..., :, None, :] - gb[..., None, :, :], -jnp.inf))
+    kd = kb[..., None, :, :] * decay
+    a_diag = jnp.sum(kb[..., :, None, :] * kd, -1)
+    p_diag = jnp.sum(qb[..., :, None, :] * kd, -1)
+    if r == 1:
+        return a_diag[..., 0, :, :], p_diag[..., 0, :, :]
+    g_star = jnp.concatenate(
+        [jnp.zeros_like(gb[..., :1, -1, :]), gb[..., :-1, -1, :]], -2)
+    left = jnp.exp(gb - g_star[..., :, None, :])
+    below = jnp.tril(jnp.ones((r, r), bool), -1)[:, :, None, None]
+    right = kb[..., None, :, :, :] * jnp.exp(jnp.where(
+        below, g_star[..., :, None, None, :] - gb[..., None, :, :, :], -jnp.inf))
+    off = jnp.einsum(
+        "...bic,...bdjc->...bidj", jnp.concatenate([kb * left, qb * left], -2), right,
+        precision=K._FLOAT32)
+    eye = jnp.eye(r, dtype=jnp.float32)[:, None, :, None]
+    whole = lambda diag, off: (
+        diag[..., :, :, None, :] * eye + off).reshape(q.shape[:-2] + (c, c))
+    return (whole(a_diag, off[..., :sub, :, :]), whole(p_diag, off[..., sub:, :, :]))
+
+
+def _plain_kda_chunked(q, k, v, log_a, beta, chunk, sub=None):
+    """``K.kda_chunked`` as it stood before its backward rule, for JAX to
+    differentiate: the oracle of the rule's tests, which no workload calls.
+    The scan and the solve are linearised and transposed by their own
+    rules, the blocks' products sit under ``jax.checkpoint``."""
+    t, h, dk = q.shape
+    dv = v.shape[-1]
+    sub = sub or max(chunk // 4, 1)
+    pad = -t % chunk
+    if pad:
+        q, k, v, log_a = (jnp.pad(x, ((0, pad), (0, 0), (0, 0)))
+                          for x in (q, k, v, log_a))
+        beta = jnp.pad(beta, ((0, pad), (0, 0)))
+    n = (t + pad) // chunk
+    split = lambda x: x.reshape((n, chunk) + x.shape[1:]).swapaxes(1, 2)
+    q, k, v, log_a, beta = (split(x) for x in (q, k, v, log_a, beta))
+    g = jnp.cumsum(log_a, axis=2)
+    a, p = jax.checkpoint(_plain_chunk_products, static_argnums=(3,))(q, k, g, sub)
+    strictly = jnp.tril(jnp.ones((chunk, chunk), bool), -1)
+    system = (jnp.eye(chunk, dtype=jnp.float32)
+              + beta[..., None] * jnp.where(strictly, a, 0.0))
+    from_start = jnp.exp(g)
+    rhs = beta[..., None] * jnp.concatenate([v, k * from_start], -1)
+    solved = jax.scipy.linalg.solve_triangular(system, rhs, lower=True)
+    w_v, w_k = solved[..., :dv], solved[..., dv:]
+    q_start = q * from_start
+    k_end = k * jnp.exp(g[:, :, -1:, :] - g)
+    keep = from_start[:, :, -1, :, None]
+
+    def one_chunk(state, xs):
+        w_v, rows, p, k_end, keep = xs
+        from_state = K._einsum("hic,hcv->hiv", rows, state)
+        u = w_v - from_state[:, :chunk]
+        out = from_state[:, chunk:] + K._einsum("hij,hjv->hiv", p, u)
+        return keep * state + K._einsum("hic,hiv->hcv", k_end, u), out
+
+    _, out = jax.lax.scan(
+        one_chunk, jnp.zeros((h, dk, dv), jnp.float32),
+        (w_v, jnp.concatenate([w_k, q_start], 2), p, k_end, keep))
+    return out.swapaxes(1, 2).reshape((t + pad, h, dv))[:t]
+
+
+def _kda_inputs(length, h=3, dk=8, chunk=16):
+    """Decays from none (``log a = 0``: a channel in four) to so strong
+    that a chunk's sum passes -100, ``beta`` at exactly 0 and 1 too."""
+    keys = jax.random.split(jax.random.key(100 + length), 7)
+    q, k = (K._l2norm(jax.random.normal(kk, (length, h, dk))) for kk in keys[:2])
+    v = jax.random.normal(keys[2], (length, h, dk))
+    log_a = -jnp.exp(jax.random.uniform(keys[3], (length, h, dk), minval=-9.0, maxval=4.0))
+    log_a = jnp.where(jax.random.uniform(keys[4], (length, h, dk)) < 0.25, 0.0, log_a)
+    beta = jax.nn.sigmoid(jax.random.normal(keys[5], (length, h)))
+    ends = jax.random.uniform(keys[6], (length, h))
+    beta = jnp.where(ends < 0.1, 0.0, jnp.where(ends > 0.9, 1.0, beta))
+    if length >= chunk:
+        assert float(log_a[:chunk].sum(0).min()) < -100
+    assert bool((log_a == 0).any() and (beta == 0).any() and (beta == 1).any())
+    return q, k, v, log_a, beta
+
+
+@pytest.mark.parametrize("operand, limit", [
+    # float32 operands: the same products in the same order, sums in another
+    (jnp.float32, 2e-4),
+    # as the chip runs it: the rule rounds a cotangent to bfloat16 where
+    # JAX's transposes round another one; the forward test's tolerance
+    (jnp.bfloat16, 3e-2),
+])
+@pytest.mark.parametrize("oracle", ["plain_body", "recurrence"])
+@pytest.mark.parametrize("length", [70, 64, 9, 37])
+def test_the_rules_five_gradients(reference, monkeypatch, length, oracle, operand, limit):
+    """``q, k, v, log_a, beta`` through the backward rule against what JAX
+    makes of the plain body (the same operands) and against the
+    token-by-token recurrence (float32), over lengths padded and whole, of
+    one chunk and of several; each within ``limit`` of the gradient's
+    largest entry. The values are the plain body's bit for bit."""
+    monkeypatch.setattr(K.lane, "_OPERAND", operand)
+    chunk = 16
+    x = _kda_inputs(length, chunk=chunk)
+    weights = jax.random.normal(jax.random.key(length), x[2].shape)
+    ours = lambda *x: (K.kda_chunked(*x, chunk) * weights).sum()
+    if oracle == "plain_body":
+        assert bool((K.kda_chunked(*x, chunk) == _plain_kda_chunked(*x, chunk)).all())
+        theirs = lambda *x: (_plain_kda_chunked(*x, chunk) * weights).sum()
+    else:
+        theirs = lambda q, k, v, g, b: (
+            reference.delta_rule(q, k, v, jnp.exp(g), b) * weights).sum()
+    got = jax.jit(jax.grad(ours, argnums=(0, 1, 2, 3, 4)))(*x)
+    want = jax.jit(jax.grad(theirs, argnums=(0, 1, 2, 3, 4)))(*x)
+    for name, g, w in zip(("q", "k", "v", "log_a", "beta"), got, want):
+        assert bool(jnp.isfinite(g).all()), name
+        np.testing.assert_allclose(
+            g, w, atol=limit * float(jnp.abs(w).max()), err_msg=name)
+
+
+def _scans(jaxpr):
+    """Every ``scan`` of a jaxpr, however deep."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _scans(sub)
+
+
+def _mechanism_args(t=256, h=2, d=16):
+    shape = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32)
+    return (shape(t, h, d),) * 4 + (shape(t, h),)
+
+
+def _through(kda):
+    """``kda`` in the cell's chunks of 64 and blocks of 16."""
+    return lambda *x: kda(*x, 64, 16)
+
+
+def test_the_gradient_of_kda_is_the_rules_and_no_transposed_scan():
+    """What ``jax.grad`` makes of ``kda_chunked``: its value holds the
+    rule's call; its gradient two scans that were written (the rule's
+    forward, and one from the last chunk to the first), where the plain
+    body's second scan is the one JAX transposed (it carries linear
+    arguments)."""
+    args = _mechanism_args()
+    value = jax.make_jaxpr(_through(K.kda_chunked))(*args)
+    assert "custom_vjp_call" in {e.primitive.name for e in value.jaxpr.eqns}
+    grad = lambda kda: jax.make_jaxpr(jax.grad(
+        lambda *x: (_through(kda)(*x) ** 2).sum(), argnums=(0, 1, 2, 3, 4)))(*args)
+    ours, plain = (list(_scans(grad(kda).jaxpr)) for kda in (K.kda_chunked, _plain_kda_chunked))
+    assert [any(e.params["linear"]) for e in plain] == [False, True]
+    assert [any(e.params["linear"]) for e in ours] == [False, False]
+    assert [e.params["reverse"] for e in ours] == [False, True]
+
+
+def test_the_rule_keeps_less_than_half_of_what_jax_kept():
+    """What the forward hands the backward (``jax.eval_shape`` of
+    ``jax.vjp``'s pull-back, every array counted): the inputs, the solved
+    rows, the chunks' starting states and ``u``, under half the bytes that
+    ``jax.vjp`` of the plain body keeps (24 arrays: 1.05 MB here, 1.14 GB
+    a layer at the cell's shape, where the rule's eight are 0.60 GB)."""
+    def kept(kda):
+        _, pull = jax.eval_shape(lambda *x: jax.vjp(_through(kda), *x), *_mechanism_args())
+        return [int(np.prod(x.shape)) * x.dtype.itemsize for x in jax.tree.leaves(pull)]
+
+    ours, plain = kept(K.kda_chunked), kept(_plain_kda_chunked)
+    assert len(plain) == 24 and len(ours) == 8
+    assert sum(ours) < 0.5 * sum(plain)
+
+
+def test_a_step_computes_no_more_exp_arrays_than_the_plain_body():
+    """The plain body's gradient computes 8 ``exp`` arrays (the forward's 5
+    and the 3 of the blocks' products again, behind ``jax.checkpoint``'s
+    barrier). The rule's backward writes the chunk-local part again from
+    the inputs with no barrier, so the compiler is free to keep it from the
+    rule's forward: the compiled program computes the forward's 5."""
+    def programs(kda):
+        lowered = jax.jit(jax.grad(
+            lambda *x: (_through(kda)(*x) ** 2).sum(), argnums=(0, 1, 2, 3, 4))
+        ).lower(*_mechanism_args())
+        return (len(re.findall(r"stablehlo\.exponential\b", lowered.as_text())),
+                len(re.findall(r" exponential\(", lowered.compile().as_text())))
+
+    forward = len(re.findall(r"stablehlo\.exponential\b", jax.jit(
+        _through(K.kda_chunked)).lower(*_mechanism_args()).as_text()))
+    assert forward == 5
+    assert programs(_plain_kda_chunked)[0] == 8
+    written, computed = programs(K.kda_chunked)
+    assert written == 2 * forward and computed == forward <= 8
+
+
+@pytest.mark.parametrize("length", [70, 64, 9])
+def test_the_rules_forward_is_the_value(length):
+    """Under ``jax.vjp`` the forward hands over what it kept and the value
+    bit for bit, padded or whole."""
+    x = _kda_inputs(length)
+    out, _ = jax.vjp(lambda *x: K.kda_chunked(*x, 16), *x)
+    assert bool((out == K.kda_chunked(*x, 16)).all())
 
 
 def test_shares_of_the_expert_layer_add_up_to_the_uncut_layer(
@@ -357,6 +562,6 @@ def test_lane_counts_agree_with_the_lane(reference):
     facts = K.make_kimi_linear_eval_fn(
         K.KimiLinearConfig(seq_len=64, n_train=2, n_val=1)).lane_facts
     assert facts.counters == K.LANE_COUNTERS + (
-        "moe_combine_by_gather", "moe_products_in_vmem")
+        "moe_combine_by_gather", "moe_products_in_vmem", "kda_backward_by_rule")
     assert facts.tokens_per_step == 64
     assert 12 * n_params < K.kimi_linear_lane_bytes(K.KimiLinearConfig()) < 16.9e9

@@ -174,8 +174,21 @@ def small_lane(model):
             attn_query_block=16), data_seed=0)
 
 
+@pytest.fixture
+def traces_not_left_behind():
+    """What a test traces leaves this worker with it: ``jax.checkpoint``
+    keeps a module-level function's trace by its identity and shapes
+    (``ouro._exit_cross_entropy``), so a later file that builds the same
+    small lane with float32 operands, or reads its scopes, would be handed
+    this one's (``test_ouro.py`` and ``test_ouro_sweep.py`` failed after
+    this file in one worker)."""
+    yield
+    jax.clear_caches()
+
+
 @pytest.mark.parametrize("model", ["kimi", "mellum2", "ouro"])
-def test_a_lane_lowers_to_what_no_scope_at_all_lowers_to(monkeypatch, model):
+def test_a_lane_lowers_to_what_no_scope_at_all_lowers_to(
+        monkeypatch, traces_not_left_behind, model):
     """The lowered program (its text prints no locations) is byte for byte
     the one without a single ``jax.named_scope``: the passes, the pieces
     and the parts are metadata, and what the compiler and the compile
