@@ -8,7 +8,7 @@ full training run, and top-k promotions all execute on the accelerator.
 
 The attention/MLP matmuls run in bfloat16 with float32 accumulation — the
 MXU's native regime — so on real TPU hardware this rung reports meaningful
-MFU (bench.py's `transformer` tier measures it against peak bf16).
+MFU.
 
 Reference analog: the reference's model-family examples are the MNIST
 MLP/Keras/PyTorch workers (SURVEY.md §2 "examples"); this rung extends the

@@ -7,8 +7,8 @@ boundaries: sharding only exists because the arrays are big enough to
 spread over a mesh. Exactly there, buffer donation is the difference
 between XLA updating state in place (the fused sweep's warm-buffer
 thread, ops/sweep.py) and a dead copy round-tripping the host link — the
-compile/transfer tax the runtime telemetry (PR 5) measures and the budget
-gate (bench.py ``TIER_BUDGETS``) enforces.
+compile/transfer tax the runtime telemetry (PR 5) measures and the
+ceilings of ``tests/test_program_counts.py`` enforce.
 
 Donation is not always RIGHT, though: a buffer whose outputs cannot alias
 it (shape/dtype mismatch) gains nothing, and donating a caller-reused

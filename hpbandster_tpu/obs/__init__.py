@@ -55,8 +55,8 @@ emit into (see docs/observability.md):
   live run (``watch --snapshot host:port`` polls a health RPC instead).
 
 Everything here is stdlib-only and costs ~nothing when no sink is
-attached (the bench's ``obs_overhead`` tier measures exactly that), so
-the instrumentation stays on permanently — attach sinks to look.
+attached, so the instrumentation stays on permanently — attach sinks to
+look.
 
 Quick start::
 
@@ -282,7 +282,7 @@ __all__ = [
 
 def set_enabled(flag: bool) -> None:
     """Process-wide kill switch: ``False`` turns every emit / counter /
-    span into a single-boolean-check no-op (the bench's A/B lever)."""
+    span into a single-boolean-check no-op."""
     _events._set_enabled(flag)
     _metrics._set_enabled(flag)
 

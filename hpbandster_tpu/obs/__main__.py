@@ -24,18 +24,15 @@
   journal's end-to-end wall-clock to named phases (admission wait,
   compile, transfer, rung compute, promotion, KDE refit, RPC): a
   per-phase table plus a machine-readable verdict (attributed share vs
-  threshold) — the same verdict ``bench.py``'s ``timeline_overhead``
-  tier records next to the budget verdicts. Exit 0 even when the
-  verdict fails (it reports, the bench gate enforces).
+  threshold). Exit 0 even when the verdict fails (it reports).
 * ``slo <journal> [<journal> ...] [--json]`` — deterministic offline
   re-evaluation of the SLO pack (``obs/slo.py`` + ``obs/alerts.py``)
   over a journaled run: per-SLO burn rate / budget-remaining / state
   table, the alert-transition replay-parity check (journaled
   ``slo_alert`` records, envelope stripped, must match the offline
   recomputation byte-identically), and a machine-readable verdict
-  ``{firing, budget_remaining, ok}`` — the same verdict ``bench.py``'s
-  ``slo_overhead`` tier records. Exit 0 even when the verdict fails
-  (it reports, the bench gate enforces).
+  ``{firing, budget_remaining, ok}``. Exit 0 even when the verdict
+  fails (it reports).
 * ``alerts <journal> [<journal> ...] [--json]`` — the alert lifecycle
   ledger: every ``slo_alert`` transition (pending -> firing ->
   resolved) with its burn rates and budget, from the journal's own

@@ -27,8 +27,7 @@ the join the report CLI (``obs/report.py``) builds its model-vs-random
 win rate and promotion-regret tables from.
 
 Emission goes through the event bus, so the no-sink cost is the usual
-~zero (the ``audit_emit_ns`` micro in the bench's ``obs_overhead`` tier
-measures it), and the ``obs-reserved-fields`` graftlint rule applies
+~zero, and the ``obs-reserved-fields`` graftlint rule applies
 unchanged: audit call sites never stamp ``trace_id``/``host`` by hand.
 """
 

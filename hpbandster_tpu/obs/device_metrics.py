@@ -20,8 +20,9 @@ through ``run_bracket`` and the resident ``lax.scan`` carry:
 Every leaf is sized by the *schedule* (brackets x rungs x bins), never
 by the config count, so the whole telemetry bill rides the sweep's
 existing final d2h and the resident tier's flat-host-link assertion is
-preserved by construction (``bench.py`` ``resident_100k`` measures it
-with telemetry ON).
+preserved by construction (``tests/test_program_counts.py``
+``test_resident_telemetry_rides_the_flat_link`` counts it with telemetry
+ON).
 
 Host-side, :func:`decode_device_metrics` folds the fetched pytree into
 one deterministic JSON-safe record; :func:`publish_device_metrics`

@@ -17,8 +17,7 @@ the functions that need it, same rule as the rest of ``obs``):
   the per-function recompile counter. The measured seconds are the
   first-call wall time (trace + compile + first execution — compile
   dominates for anything XLA spends real time on); steady-state calls
-  pay one signature hash + set lookup, measured by the bench's
-  ``runtime_overhead`` tier against the <2% obs bar.
+  pay one signature hash + set lookup.
 * :class:`DeviceSampler` — a periodic daemon thread publishing
   per-device gauges: ``memory_stats()`` bytes in use / limit where the
   backend reports them (TPU/GPU; CPU reports nothing), plus live-buffer
@@ -522,8 +521,9 @@ def publish_sweep_transfers(
       scale with config count (one vector + one scalar per sweep);
     * ``sweep.host_syncs`` — transferred-BUFFER count (both directions,
       the unit every ``note_transfer`` site counts in: a fetch of one
-      4-leaf payload counts 4): the sweep's host-surface bill, which the
-      resident-loop bench tier pins constant in config count.
+      4-leaf payload counts 4): the sweep's host-surface bill, which
+      ``tests/test_program_counts.py`` pins constant in config count
+      (``test_resident_link_is_flat_in_the_configuration_count``).
 
     Counts only the repo's own :func:`note_transfer` choke points — the
     set whose round-trips dominate on high-latency links.

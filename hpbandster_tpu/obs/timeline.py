@@ -24,8 +24,7 @@ them into one causally-ordered timeline:
   time segments and charges each to the highest-priority active phase,
   so phase seconds always sum to <= the end-to-end span (a property test
   pins this for arbitrary journals). ``obs critical-path`` renders the
-  per-phase table; the machine-readable verdict lands in bench.py's
-  artifact next to the budget verdicts.
+  per-phase table and a machine-readable verdict.
 
 Clock discipline (the cross-host alignment fix): merged records are
 ordered on each host's monotonic clock re-anchored by the host's MEDIAN
@@ -233,8 +232,7 @@ def phase_span(name: str, phase: str, **fields: Any):
     code (``obs-emit-in-jit``). Returns the span context manager
     directly rather than wrapping it in a second generator frame: the
     validation happens once at call time, so the inactive ``with`` costs
-    ONE context frame, not two (bench_timeline_overhead measures this
-    path)."""
+    ONE context frame, not two."""
     _check_phase(phase)
     return E.span(name, phase=phase, **fields)
 
@@ -319,8 +317,7 @@ class TimelineRecorder:
     def __call__(self, ev: E.Event) -> None:
         # hot path: ONE list append. Flattening into journal-shaped dicts
         # is deferred to :attr:`records` — the recorded process pays
-        # O(100ns) per event, not the µs-scale dict build (the
-        # timeline_overhead bench bar rides on this)
+        # O(100ns) per event, not the µs-scale dict build
         self._events.append(ev)
 
     @property
@@ -725,7 +722,7 @@ def critical_path(
     end-to-end span exactly: they can never double-count overlapping
     concurrent work, and their sum is <= the end-to-end span by
     construction. The ``verdict`` sub-dict is the machine-readable
-    acceptance record bench.py persists next to the budget verdicts."""
+    acceptance record ``obs critical-path --json`` prints."""
     ordered, offsets = align_clocks(list(records))
     intervals = [
         iv for iv in _intervals(ordered, offsets) if iv["phase"] is not None

@@ -35,7 +35,7 @@ Programs are AOT-compiled through ``tracked_jit``'s ``lower().compile()``
 proxy (:func:`precompile_buckets`), optionally on a background thread so
 the compile overlaps stage-0 sampling, and every compile lands in the
 process-wide ledger — the budget tests in ``tests/test_buckets.py`` and
-the bench budget gate read it back.
+the ceilings of ``tests/test_program_counts.py`` read it back.
 """
 
 from __future__ import annotations
@@ -566,8 +566,8 @@ class _BucketRunner:
     The executable is built exactly once (lazily on first dispatch, or
     ahead of time via :meth:`ensure_compiled` / :func:`precompile_buckets`)
     through the tracked ``lower().compile()`` proxy, so the compile ledger
-    sees exactly one compile per bucket — the number the budget tests and
-    the bench gate assert on. Dispatches always run the AOT executable;
+    sees exactly one compile per bucket — the number the budget tests
+    assert on. Dispatches always run the AOT executable;
     the jit wrapper itself is never called (that would compile a second,
     untracked-by-AOT cache entry).
     """

@@ -366,20 +366,6 @@ def impute_conditional_masked(
     return jnp.where(isnan, fill, data)
 
 
-def _pallas_fit_requested() -> Optional[bool]:
-    """Tri-state ``HPB_PALLAS_KDE_FIT`` flag: ``"1"`` forces the Pallas
-    bandwidth-fit kernel (interpreted off-TPU), ``"0"`` forces the XLA
-    path, unset defers to the caller's ``use_pallas_fit`` argument
-    (default: XLA — the Pallas fit is opt-in until a TPU window
-    re-baselines it; see docs/perf_notes.md "Resident outer loop")."""
-    import os
-
-    env = os.environ.get("HPB_PALLAS_KDE_FIT", "")
-    if env in ("0", "1"):
-        return env == "1"
-    return None
-
-
 def fit_kde_pair_masked(
     vecs: jax.Array,
     losses: jax.Array,
@@ -389,7 +375,6 @@ def fit_kde_pair_masked(
     cards: jax.Array,
     min_bandwidth: float,
     impute_key=None,
-    use_pallas_fit: Optional[bool] = None,
 ) -> Tuple[KDE, KDE]:
     """Traced-count good/bad KDE fit over a full-capacity buffer.
 
@@ -402,35 +387,10 @@ def fit_kde_pair_masked(
     observation COUNTS stay out of the compiled program. This is the one
     definition behind both the dynamic-count fused sweep
     (``ops/sweep.py``) and the in-trace refit+propose op below.
-
-    ``use_pallas_fit=True`` (or ``HPB_PALLAS_KDE_FIT=1``, which
-    overrides) computes the bandwidth reduction through
-    ``ops.pallas_kde.pallas_normal_reference_bandwidths`` — one
-    VMEM-streaming moment pass instead of two [C, d] HBM intermediates,
-    the lever if the fit is the wall at 1M observations (measured by the
-    bench ``resident_100k`` tier's ``kde_fit`` probe). A distinct
-    numeric consumer (one-pass variance), so it is opt-in behind the
-    flag; the split/sort half is unchanged either way.
     """
-    env = _pallas_fit_requested()
-    pallas_fit = bool(use_pallas_fit) if env is None else env
-
     def mk(data: jax.Array, mask: jax.Array) -> KDE:
         mask = mask.astype(jnp.float32)
-        if pallas_fit:
-            from hpbandster_tpu.ops.pallas_kde import (
-                pallas_available,
-                pallas_normal_reference_bandwidths,
-            )
-
-            bw = pallas_normal_reference_bandwidths(
-                data, mask, cards, min_bandwidth,
-                interpret=not pallas_available(),
-            )
-        else:
-            bw = normal_reference_bandwidths(
-                data, mask, cards, min_bandwidth
-            )
+        bw = normal_reference_bandwidths(data, mask, cards, min_bandwidth)
         return KDE(data, mask, bw)
 
     with jax.named_scope("hpb.kde_fit"):

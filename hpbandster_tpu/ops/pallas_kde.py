@@ -42,7 +42,6 @@ __all__ = [
     "pallas_propose_batch",
     "pallas_propose_batch_seeded",
     "pallas_refit_propose_batch_seeded",
-    "pallas_normal_reference_bandwidths",
     "pallas_available",
 ]
 
@@ -333,91 +332,3 @@ def pallas_refit_propose_batch_seeded(
         jax.random.key(seed), good, bad, vartypes, cards, n, num_samples,
         bandwidth_factor, min_bandwidth, interpret,
     )
-
-
-# --------------------------------------------------------- bandwidth fit
-#: row tile for the masked-moment reduction — bigger than the scorer's
-#: candidate tile because the moment kernel is pure streaming reduction
-#: (no [TS, N] intermediate), so VMEM pressure is one [TILE_R, LANE] block
-_TILE_R = 512
-
-
-def _moments_kernel(data_ref, mask_ref, out_ref):
-    """Accumulate per-dim masked sum / sum-of-squares / count across row
-    tiles. TPU grid execution is sequential, so every program may
-    accumulate into the SAME output block (initialized by program 0) —
-    the canonical Pallas reduction layout."""
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _init():  # noqa: ANN202 — pallas when-block
-        out_ref[:] = jnp.zeros_like(out_ref)
-
-    d = data_ref[:]          # [TILE_R, LANE]
-    m = mask_ref[:]          # [TILE_R, LANE] (mask broadcast to lanes)
-    dm = d * m
-    out_ref[0:1, :] += jnp.sum(dm, axis=0, keepdims=True)
-    out_ref[1:2, :] += jnp.sum(dm * d, axis=0, keepdims=True)
-    out_ref[2:3, :] += jnp.sum(m, axis=0, keepdims=True)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _masked_moments_padded(data, mask2, interpret: bool):
-    """``data`` f32[C_pad, LANE], ``mask2`` f32[C_pad, LANE] ->
-    f32[8, LANE] whose rows 0/1/2 are per-dim masked sum / sumsq /
-    count (rows 3+ are sublane padding)."""
-    return pl.pallas_call(
-        _moments_kernel,
-        out_shape=jax.ShapeDtypeStruct((_SUBLANE, _LANE), jnp.float32),
-        grid=(data.shape[0] // _TILE_R,),
-        in_specs=[
-            pl.BlockSpec((_TILE_R, _LANE), lambda i: (i, 0)),
-            pl.BlockSpec((_TILE_R, _LANE), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((_SUBLANE, _LANE), lambda i: (0, 0)),
-        interpret=interpret,
-    )(data, mask2)
-
-
-def pallas_normal_reference_bandwidths(
-    data: jax.Array,
-    mask: jax.Array,
-    cards: jax.Array,
-    min_bandwidth: float = 1e-3,
-    interpret: bool = False,
-) -> jax.Array:
-    """Pallas twin of ``ops.kde.normal_reference_bandwidths`` — the
-    truncnorm-KDE FIT's reduction half as one VMEM-resident streaming
-    pass over the observation buffer.
-
-    At 1M observations the XLA fit materializes two [C, d] intermediates
-    (masked data and its square) through HBM; this kernel computes the
-    per-dim masked moments in one pass and finishes the ~d-element
-    bandwidth arithmetic in plain jnp. Variance comes from the one-pass
-    identity ``E[x^2] - E[x]^2`` (clamped at 0) instead of the XLA
-    path's two-pass form, so the fitted bandwidths are a distinct — not
-    bit-identical — consumer; gate it with ``HPB_PALLAS_KDE_FIT`` (see
-    ``ops.kde.fit_kde_pair_masked``) and re-baseline budgets when
-    flipping the flag. Trace-safe (jnp padding only), so it can live
-    inside the fused/resident sweep program.
-    """
-    data = jnp.asarray(data, jnp.float32)
-    mask = jnp.asarray(mask, jnp.float32)
-    c, d = data.shape
-    c_pad = _round_up(c, _TILE_R)
-    pad = ((0, c_pad - c), (0, _LANE - d))
-    dpad = jnp.pad(data, pad)
-    mpad = jnp.pad(jnp.broadcast_to(mask[:, None], (c, d)), pad)
-    from hpbandster_tpu.ops.kde import _discrete_bw_cap
-
-    mom = _masked_moments_padded(dpad, mpad, interpret=interpret)
-    s1, s2, cnt = mom[0, :d], mom[1, :d], mom[2, :d]
-    n = jnp.maximum(cnt, 1.0)
-    mean = s1 / n
-    var = jnp.maximum(s2 / n - jnp.square(mean), 0.0)
-    sigma = jnp.sqrt(var)
-    bw = 1.06 * sigma * n ** (-1.0 / (4.0 + d))
-    # the Aitchison–Aitken cap has ONE definition (ops/kde.py) — the
-    # Pallas twin must clamp exactly like the XLA path it is benchmarked
-    # against
-    return jnp.clip(bw, min_bandwidth, _discrete_bw_cap(jnp.asarray(cards)))

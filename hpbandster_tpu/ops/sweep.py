@@ -640,7 +640,9 @@ class DeviceMetrics(NamedTuple):
     by the config count, so carrying it through ``run_bracket`` and the
     resident ``lax.scan`` adds a constant to the final d2h payload
     whatever the sweep size — the resident flat-host-link contract
-    (``bench.py`` ``resident_100k`` asserts it with telemetry ON). Rows
+    (``tests/test_program_counts.py``
+    ``test_resident_telemetry_rides_the_flat_link`` asserts it with
+    telemetry ON). Rows
     beyond a bracket's actual rung count stay at their init value; the
     host decoder (``obs.device_metrics.decode_device_metrics``) walks
     the plan shapes and never reads them. Bin layout is owned by
@@ -961,7 +963,7 @@ def make_fused_sweep_fn(
     ``program_name`` overrides the base name the compiled program is
     tracked under (``obs.runtime`` ledger; default ``"fused_sweep"``) —
     the resident/spmd suffixes still apply. Distinct workloads get
-    distinct ledger rows, which is what lets a bench tier find ITS
+    distinct ledger rows, which is what lets a caller find ITS
     program's cost analysis in ``obs.profile.roofline_report``.
     """
     from hpbandster_tpu.parallel.mesh import is_multiprocess_mesh, shard_count

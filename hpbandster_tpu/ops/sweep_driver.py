@@ -38,8 +38,8 @@ __all__: List[str] = []
 #: batch caches: one compile per (objective, schedule, space, knobs, mesh)).
 #: Values are AOT-compiled executables — cache hits skip retracing AND
 #: recompiling on repeated runs of the same schedule, whichever entry point
-#: asks (bench repeats of one sharded sweep must not recompile: the
-#: compile-count acceptance is per PROCESS, not per call).
+#: asks (a repeated sharded sweep must not recompile: the compile counts
+#: of ``tests/test_program_counts.py`` are per PROCESS, not per call).
 _SWEEP_EXE_CACHE: LRUCache = LRUCache(maxsize=16)
 
 #: objectives that have passed ``FusedBOHB.__init__``'s admission check,
@@ -96,8 +96,7 @@ def stream_warm_buffers(warm_v, warm_l, caps, d, mesh, axis,
     a driver materializes O(total configs) host memory in a single
     piece. Here the callback only ever holds ONE shard's slice
     (capacity / shard count rows), so peak host RSS is bounded by a
-    slice regardless of sweep size (the bench ``fused_100k`` /
-    ``fused_1M`` RSS probe). Shardings match the sweep's in-trace
+    slice regardless of sweep size. Shardings match the sweep's in-trace
     state pins (``ops/sweep.py`` ``pin_state_shards``): the AOT
     executable sees identical input shardings whether the state
     arrives streamed (chunk 0 / after a capacity doubling) or as the
@@ -260,18 +259,11 @@ class SweepDriver:
 
     def _key(self, plans, caps):
         if self.dynamic:
-            from hpbandster_tpu.ops.kde import _pallas_fit_requested
-
             # the whole point of the dynamic tier: observation counts are
             # traced inputs, so they must NOT key the executable — only the
-            # buffer capacities (shapes) do. The resolved
-            # HPB_PALLAS_KDE_FIT flag keys too: it is read at trace time
-            # inside fit_kde_pair_masked, so flipping it mid-process must
-            # MISS the cache, not silently serve an executable compiled
-            # under the other fit path.
+            # buffer capacities (shapes) do
             obs_term = ("dynamic", tuple(sorted(caps.items())),
-                        self.resident, self.thread_state,
-                        _pallas_fit_requested())
+                        self.resident, self.thread_state)
         else:
             obs_term = tuple(sorted((b, len(l)) for b, l in self.warm_l.items()))
         return (
@@ -381,9 +373,9 @@ class SweepDriver:
             args = (seed,)
         elif self._streams(caps):
             # sharded mesh: warm buffers stream up PER SHARD SLICE — the
-            # full-capacity array (1M+ rows at the fused_1M scale) never
+            # full-capacity array (1M+ rows at the 2^20 scale) never
             # materializes on host in one piece (ISSUE 10: bounded peak
-            # host RSS, probed by the bench tier)
+            # host RSS)
             buffers, streamed_bytes = stream_warm_buffers(
                 self.warm_v, self.warm_l, caps, d, self.mesh, self.axis,
                 replicate_indivisible=self.cold == "stream_each",

@@ -22,7 +22,7 @@ that claim is *exercised* instead of assumed:
 * :class:`ChaosMonkey` — the fleet-level driver: a seeded background
   thread that kills a fraction of the interposed workers at each tick
   and revives them after a configurable outage, producing the sustained
-  churn the ``chaos`` bench tier measures throughput retention under.
+  churn ``tests/test_chaos.py`` holds the trajectory equal under.
 
 Every injected fault is observable: a ``chaos_fault`` event on the bus
 (``obs.CHAOS_FAULT``) and ``chaos.faults`` / ``chaos.faults_<kind>``
@@ -332,7 +332,7 @@ class ChaosMonkey:
     """Seeded background churn over a set of :class:`ChaosProxy` targets.
 
     Each ``interval_s`` tick, every *alive* target is killed with
-    probability ``kill_fraction`` (seeded RNG — a 10%-churn bench run is
+    probability ``kill_fraction`` (seeded RNG — a 10%-churn run is
     replayable); killed targets revive after ``outage_s``. ``max_dead``
     caps simultaneous corpses so the pool never reaches zero workers
     (a fleet with every slice preempted is an outage, not churn).
@@ -382,7 +382,7 @@ class ChaosMonkey:
     def _revive(self, name: str, proxy: ChaosProxy) -> bool:
         """Guarded revive: a failed rebind (the freed ephemeral port was
         claimed during the outage) must neither kill the churn thread —
-        silently turning a "10% churn" bench into a mostly-clean run —
+        silently turning a "10% churn" run into a mostly-clean one —
         nor propagate out of stop() past the caller's remaining cleanup.
         The target just stays dead, loudly."""
         try:

@@ -217,7 +217,8 @@ def run_sharded_fused_sweep(
     per-rung loss histograms and crash/promotion counts accumulate on
     device and ride the incumbent's d2h — an O(schedule) constant, so
     the flat-host-link bill stays flat in config count WITH telemetry
-    enabled (the ``resident_100k`` bench tier measures exactly that).
+    enabled (``tests/test_program_counts.py``
+    ``test_resident_telemetry_rides_the_flat_link`` counts exactly that).
     The decoded record is published as gauges, journaled as
     ``device_telemetry``, and returned under ``"device_telemetry"``.
 

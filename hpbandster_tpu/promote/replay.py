@@ -26,8 +26,8 @@ the recorded rule terminated has no next-budget result — regret is
 measured within the evaluated set, and ``evaluated_promoted`` says how
 much hindsight each number rests on.
 
-Also here: the straggler-timing helpers the ``async_straggler`` bench
-tier and the liveness tests share — :func:`promotion_waits` (how long
+Also here: the straggler-timing helpers of the liveness tests
+(``tests/test_promote.py``) — :func:`promotion_waits` (how long
 each promoted config sat between its rung result and its promotion; the
 sync barrier's stall made measurable) and :func:`worker_utilization`
 (busy fraction per worker from the journal's run spans).
@@ -489,8 +489,8 @@ def promotion_waits(records: List[Dict[str, Any]]) -> Dict[str, Any]:
 
 def worker_utilization(records: List[Dict[str, Any]]) -> Dict[str, Any]:
     """Per-worker busy fraction over the journal's wall window — the
-    utilization number the ``async_straggler`` bench tier pairs sync vs
-    ASHA on. Derived from ``summarize_records``' worker-utilization
+    utilization number ``tests/test_promote.py`` pairs sync vs ASHA on.
+    Derived from ``summarize_records``' worker-utilization
     aggregation (ONE implementation of the busy-seconds/window
     arithmetic; this is a reshaping, not a re-computation), folded into
     a single fleet-wide busy fraction."""

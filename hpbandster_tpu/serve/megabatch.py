@@ -26,8 +26,8 @@ interact under ``vmap``, and the test suite pins exact equality.
 
 Runners are process-cached and AOT-compiled through the tracked
 ``lower().compile()`` proxy exactly like the solo bucket runners, so the
-compile ledger, the bench budget gate, and the roofline report see the
-megabatch programs as first-class citizens.
+compile ledger, the ceilings of ``tests/test_program_counts.py`` and the
+roofline report see the megabatch programs as first-class citizens.
 """
 
 from __future__ import annotations

@@ -4,7 +4,7 @@ The fused tiers' first-run cost is dominated by XLA compiles; jax can
 persist compiled executables to disk so the SECOND process on a machine
 pays none of it. Every startup path that is about to build device
 programs (``FusedBOHB``, ``BatchedExecutor``, ``Worker``,
-``TPUBatchedWorker``, ``ServePool``, ``bench.py``, the test suite) calls
+``TPUBatchedWorker``, ``ServePool``, the test suite) calls
 the one function here.
 
 Where the cache lives:

@@ -53,8 +53,7 @@ __all__ = [
 #: 2.0 puts the Bayes ceiling well under 100%). Train labels carry 5% noise
 #: so memorizing the train set costs validation accuracy (the same trap
 #: ``workloads/teacher.py`` documents for the MLP rung). A small BOHB
-#: sweep's incumbent must clear this bar (``tests/test_cnn_workloads.py``),
-#: and the bench reports it (``bench.py``).
+#: sweep's incumbent must clear this bar (``tests/test_cnn_workloads.py``).
 CNN_TARGET_VAL_ACCURACY = 0.70
 
 

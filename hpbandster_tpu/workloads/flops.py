@@ -1,9 +1,9 @@
 """Analytic FLOPs models for the built-in training workloads (VERDICT r2 #1).
 
-Grounds the perf story in hardware terms: the bench multiplies these
-per-step costs by the number of SGD steps a sweep executed and divides by
-wall-clock to report achieved FLOP/s and **MFU** (fraction of the chip's
-peak bf16 throughput), instead of only workload-specific configs/s.
+Grounds the perf story in hardware terms: these per-step costs times the
+number of SGD steps a sweep executed, over its wall-clock, are achieved
+FLOP/s and **MFU** (fraction of the chip's peak bf16 throughput), instead
+of only workload-specific configs/s.
 
 Accounting convention (the standard MFU bookkeeping used for large-model
 utilization reports): count matmul/convolution FLOPs only (2 FLOPs per
@@ -44,8 +44,8 @@ __all__ = [
 
 #: per-chip peak dense bf16 FLOP/s by ``device.device_kind`` prefix.
 #: v5e ("TPU v5 lite"): 394 TOPS int8 / 197 TFLOP/s bf16; v4: 275; v5p: 459;
-#: v6e ("TPU v6 lite", Trillium): 918. Unknown kinds return None — the
-#: bench then reports achieved FLOP/s without an MFU percentage, and
+#: v6e ("TPU v6 lite", Trillium): 918. Unknown kinds return None — a
+#: caller can then report achieved FLOP/s without an MFU percentage, and
 #: ``chip_smoke.py`` fails on a chip that has no row here.
 _PEAK_BF16 = {
     "TPU v6 lite": 918e12,

@@ -22,7 +22,7 @@ reaches ≈ 0.92 validation accuracy while bad draws stall below 0.4, and
 the train/val gap is real (an over-fit student hits ≥ 0.99 train with
 ≈ 0.85 val) — wide dynamic range for the optimizer to climb and a true
 generalization axis. ``TARGET_VAL_ACCURACY = 0.90`` encodes the documented
-target that convergence tests and the bench report against.
+target that convergence tests report against.
 """
 
 from __future__ import annotations

@@ -59,8 +59,7 @@ __all__ = [
 #: copy circuit (81 steps is deliberately tight for this config: the
 #: budget axis stays informative instead of saturating, the same design
 #: choice as the CNN rung's noise ceiling). Target = just under the
-#: measured best-of-12 (the CNN convention), ~11x chance; bench.py's
-#: `transformer` tier records the incumbent against it.
+#: measured best-of-12 (the CNN convention), ~11x chance.
 TRANSFORMER_TARGET_VAL_ACCURACY = 0.35
 
 

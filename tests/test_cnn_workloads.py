@@ -153,11 +153,11 @@ class TestCNNGeneralization:
     def test_bohb_incumbent_converges_on_generalization_axis(self):
         # sweep-level convergence assertion, CPU-sized: a pinned-seed
         # 2-bracket BOHB on a 16x16 config (measured: incumbent val acc
-        # 0.648 vs best-of-12-random 0.766 and ~0.10 chance). The full
-        # documented CNN_TARGET_VAL_ACCURACY assertion runs in bench.py on
-        # the TPU-sized default config, where a 65-eval sweep measured
-        # 0.746 >= 0.70 — this workload is needle-like (most draws stall
-        # at chance), which is exactly the landscape HPO exists for.
+        # 0.648 vs best-of-12-random 0.766 and ~0.10 chance). The
+        # documented CNN_TARGET_VAL_ACCURACY is the TPU-sized default
+        # config's, where a 65-eval sweep measured 0.746 >= 0.70 — this
+        # workload is needle-like (most draws stall at chance), which is
+        # exactly the landscape HPO exists for.
         from hpbandster_tpu.optimizers import BOHB
         from hpbandster_tpu.parallel import BatchedExecutor, VmapBackend
 

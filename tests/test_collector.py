@@ -734,9 +734,8 @@ class TestMasterCollectorEndToEnd:
     def test_poll_round_duty_cycle_under_two_percent(self):
         """Acceptance: collector overhead < 2% of a warm sweep. At the
         default 2 s interval the steady-state overhead reduces to the
-        poll-round duty cycle (round cost / interval) — the same number
-        bench.py's collector_overhead tier reports against the bar —
-        measured here over 3 real health-endpoint sockets."""
+        poll-round duty cycle (round cost / interval), measured here
+        over 3 real health-endpoint sockets."""
         servers = [_start_health_server() for _ in range(3)]
         c = FleetCollector(
             endpoints=[s.uri for s in servers], interval_s=2.0,

@@ -378,7 +378,7 @@ class TestStragglerAuditLoop:
     def test_ledger_scoped_by_run_and_tenant(self):
         # config-id triples restart at (0,0,0) every sweep: a marker
         # noted in one run (or tenant) must not drain into another's
-        # promotion decision — the bench's sequential sync/asha pairing
+        # promotion decision — sequential sync/asha pairs of one process
         # and concurrent serve tenants both depend on it
         with obs.use_run("run-a"):
             obs.note_straggler((0, 0, 3))

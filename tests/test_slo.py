@@ -5,7 +5,8 @@ alerts`` CLIs.
 The replay-parity class is the load-bearing one: a live-managed
 journaled run, re-scanned offline, must reproduce every published gauge
 value and every alert transition byte-identically (the contract
-``obs slo --journal`` and bench.py's ``slo_overhead`` verdict enforce).
+``obs slo --journal`` enforces, and
+``tests/test_program_counts.py`` holds over a real pool's wave).
 """
 
 import io

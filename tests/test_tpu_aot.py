@@ -74,18 +74,6 @@ def test_scorer_compiles_for_v5e_at_sweep_capacities(v5e_devices, n_obs):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("rows", [512, 4096, 8192, 16384, 131072])
-def test_moments_kernel_compiles_for_v5e(v5e_devices, rows):
-    one = SingleDeviceSharding(v5e_devices[0])
-    block = _sds((rows, 128), jnp.float32, one)
-    compiled = jax.jit(
-        lambda data, mask: pallas_kde._masked_moments_padded(
-            data, mask, interpret=False
-        )
-    ).lower(block, block).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-
-
 def test_four_chip_mesh_sweep_compiles_with_pallas_scorer(v5e_devices):
     """The README's "shard over all chips" program: a Mosaic call inside
     the mesh-sharded sweep must sit under a shard_map or the SPMD

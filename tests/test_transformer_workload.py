@@ -227,8 +227,8 @@ class TestLearnsCopy:
         assert np.isfinite(best_acc)
         # the learnable-lr band is narrow (the calibration probe shows most
         # draws stall at chance), so a 2-bracket sweep certifies WIRING +
-        # beats-chance, not the documented target — that assertion runs in
-        # bench.py on the full config (measured here: 0.292 with seed 2)
+        # beats-chance, not the documented target, which is the full
+        # config's (measured here: 0.292 with seed 2)
         assert best_acc > 0.2, (
             f"incumbent copied-half val acc {best_acc:.3f}: the sweep "
             f"failed to climb decisively above chance (~0.0625)"
