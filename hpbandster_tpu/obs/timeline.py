@@ -127,12 +127,13 @@ DEVICE_SCOPES = (
 
 #: a second closed list, of the parts of one lane: ``jax.named_scope``
 #: names that a workload whose lane has layers of several kinds
-#: (``workloads/kimi_linear.py``, ``workloads/mellum2.py``, ``workloads/ouro.py``) sets *inside* ``hpb.train`` and
+#: (``workloads/kimi_linear.py``, ``workloads/mellum2.py``, ``workloads/ouro.py``, ``workloads/olmo_hybrid.py``) sets *inside* ``hpb.train`` and
 #: ``hpb.validate``. The families do not see each other:
 #: ``device_phase_map(compiled)`` reads the phases above,
 #: ``device_phase_map(compiled, LANE_SCOPES)`` these
 LANE_SCOPES = (
     "lane.kda",        # gated delta-rule linear attention, its projections
+    "lane.gdn",        # the same with one gate a head (Gated DeltaNet): projections, taps, gates, the scan and its backward rule
     "lane.mla",        # latent attention, its projections
     "lane.swa",        # sliding-window attention: projections, rotary, the band
     "lane.gqa",        # full causal grouped-query attention, the same

@@ -102,3 +102,13 @@ from hpbandster_tpu.workloads.sdar import (  # noqa: F401
     sdar_loss,
     sdar_space,
 )
+from hpbandster_tpu.workloads.delta_rule import delta_rule_chunked  # noqa: F401
+from hpbandster_tpu.workloads.olmo_hybrid import (  # noqa: F401
+    OlmoHybridConfig,
+    init_olmo_hybrid_params,
+    make_olmo_hybrid_eval_fn,
+    olmo_hybrid_forward,
+    olmo_hybrid_lane_bytes,
+    olmo_hybrid_loss,
+    olmo_hybrid_space,
+)

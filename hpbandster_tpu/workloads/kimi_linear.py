@@ -43,11 +43,13 @@ Departures from the published description, all ``assumed`` in
   is not in ``config.json``, and SGD leaves it at zero because top-k
   passes it no gradient;
 * KDA is computed chunkwise (chunks of 64, the WY form) with every decay
-  applied as ``exp`` of a difference that is never positive; its gradient
-  is a backward rule of its own (:func:`_chunks_backward`, a
-  ``jax.custom_vjp``: the scan from the last chunk to the first written
-  out, the solve's transpose one solve, JAX's own pull-back only for the
-  part that needs no state); no fused kernel;
+  applied as ``exp`` of a difference that is never positive
+  (``workloads/delta_rule.py``, the linear-attention lanes' one scan, in
+  its form of a gate a channel); its gradient is a backward rule of its own
+  (``delta_rule._chunks_backward``, a ``jax.custom_vjp``: the scan from the
+  last chunk to the first written out, the solve's transpose one solve,
+  JAX's own pull-back only for the part that needs no state); no fused
+  kernel;
 * the router's 2304 x 256 product keeps float32 operands (three
   bfloat16 passes): its top-8 is a discrete choice that bfloat16 operands
   would flip;
@@ -57,14 +59,13 @@ Departures from the published description, all ``assumed`` in
 
 from __future__ import annotations
 
-import functools
 from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from hpbandster_tpu.workloads import lane
+from hpbandster_tpu.workloads.delta_rule import _init_leaf, _l2norm, delta_rule_chunked
 from hpbandster_tpu.workloads.lane import (  # noqa: F401 - the lane's public names
     LANE_COUNTERS,
     _FLOAT32,
@@ -173,20 +174,6 @@ def _layer_shapes(cfg: KimiLinearConfig, mixer: str, ffn: str) -> dict:
     return shapes
 
 
-def _init_leaf(key, name: str, shape, init_scale):
-    """The lane's draw of a leaf (``lane._init_leaf``), and KDA's two
-    leaves that are not drawn: ``A_log`` the log of 1..16 over the heads,
-    ``dt_bias`` the inverse softplus of 0.001..0.1 over the channels."""
-    leaf = name.rsplit("/", 1)[-1]
-    if leaf == "A_log":
-        return jnp.log(jnp.linspace(1.0, 16.0, shape[0], dtype=jnp.float32))
-    if leaf == "dt_bias":
-        dt = jnp.exp(jnp.linspace(
-            np.log(0.001), np.log(0.1), shape[0], dtype=jnp.float32))
-        return dt + jnp.log(-jnp.expm1(-dt))  # softplus^-1
-    return lane._init_leaf(key, name, shape, init_scale)
-
-
 def init_kimi_linear_params(key: jax.Array, cfg: KimiLinearConfig,
                             init_scale) -> dict:
     return lane._init_params(
@@ -195,200 +182,16 @@ def init_kimi_linear_params(key: jax.Array, cfg: KimiLinearConfig,
 
 
 # ----------------------------------------------------------------- layers
-def _l2norm(x):
-    return x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + 1e-6)
-
-
-def _chunk_products(q, k, g, sub: int):
-    """``(A, P)`` f32[..., C, C] of a chunk: ``A_ij = sum_c k_ic k_jc
-    exp(g_ic - g_jc)`` and ``P`` with ``q`` on the left, for ``j <= i``
-    (zero above the diagonal); ``q, k, g`` f32[..., C, d], ``g`` the running
-    sum of ``log a`` (never rising). In blocks of ``sub`` positions: a block
-    on the diagonal sums its ``sub x sub x d`` decays one by one; a block
-    under it splits the decay at ``g`` of the last position before the row
-    block, ``exp(g_i - g*) exp(g* - g_j)``, both exponents never positive,
-    and becomes a matrix product."""
-    c, d = q.shape[-2:]
-    r = c // sub
-    blocks = lambda x: x.reshape(x.shape[:-2] + (r, sub, d))
-    qb, kb, gb = blocks(q), blocks(k), blocks(g)
-    # on the diagonal: [..., r, i, j, d], reduced over d at once
-    tri = jnp.tril(jnp.ones((sub, sub), bool))[:, :, None]
-    decay = jnp.exp(jnp.where(
-        tri, gb[..., :, None, :] - gb[..., None, :, :], -jnp.inf))
-    kd = kb[..., None, :, :] * decay
-    a_diag = jnp.sum(kb[..., :, None, :] * kd, -1)          # [..., r, i, j]
-    p_diag = jnp.sum(qb[..., :, None, :] * kd, -1)
-    if r == 1:
-        return a_diag[..., 0, :, :], p_diag[..., 0, :, :]
-    # under it: g* of row block b is g at the end of block b - 1. Where the
-    # decay is split is no one's gradient: exp(g_i - g*) exp(g* - g_j) does
-    # not move with g*, and the two sums that would say so are not computed
-    g_star = jax.lax.stop_gradient(jnp.concatenate(
-        [jnp.zeros_like(gb[..., :1, -1, :]), gb[..., :-1, -1, :]], -2))  # [..., r, d]
-    left = jnp.exp(gb - g_star[..., :, None, :])                        # [..., r, i, d]
-    below = jnp.tril(jnp.ones((r, r), bool), -1)[:, :, None, None]
-    right = kb[..., None, :, :, :] * jnp.exp(jnp.where(                 # [..., b, b', j, d]
-        below, g_star[..., :, None, None, :] - gb[..., None, :, :, :], -jnp.inf))
-    # the rows of k and of q in one product: [..., b, 2 sub, b', j]
-    off = jnp.einsum(
-        "...bic,...bdjc->...bidj", jnp.concatenate([kb * left, qb * left], -2), right,
-        precision=_FLOAT32)
-    eye = jnp.eye(r, dtype=jnp.float32)[:, None, :, None]               # [b, 1, b', 1]
-    whole = lambda diag, off: (
-        diag[..., :, :, None, :] * eye + off).reshape(q.shape[:-2] + (c, c))
-    return (whole(a_diag, off[..., :sub, :, :]), whole(p_diag, off[..., sub:, :, :]))
-
-
-def _chunk_local(q, k, v, log_a, beta, sub: int):
-    """What of a chunk does not need the state, for all chunks at once
-    (``[n, H, C, ...]`` in): ``(system, rhs, P, q exp G, k exp(G_C - G),
-    exp G_C)``, the triangular system ``I + diag(beta) tril(A, -1)`` and its
-    right-hand side ``diag(beta) [V, K exp G]`` among them."""
-    chunk = q.shape[2]
-    g = jnp.cumsum(log_a, axis=2)
-    a, p = _chunk_products(q, k, g, sub)
-    strictly = jnp.tril(jnp.ones((chunk, chunk), bool), -1)
-    system = (jnp.eye(chunk, dtype=jnp.float32)
-              + beta[..., None] * jnp.where(strictly, a, 0.0))
-    from_start = jnp.exp(g)
-    rhs = beta[..., None] * jnp.concatenate([v, k * from_start], -1)
-    q_start = q * from_start
-    k_end = k * jnp.exp(g[:, :, -1:, :] - g)
-    keep = from_start[:, :, -1, :, None]                 # [n, H, dk, 1]
-    return system, rhs, p, q_start, k_end, keep
-
-
-def _chunks_forward(q, k, v, log_a, beta, sub: int, kept: bool):
-    """The chunks' outputs f32[n, H, C, d_v] and, where ``kept``, what the
-    backward rule reads beside the inputs: the solved rows, the state every
-    chunk starts with and its corrected values ``u``."""
-    _, h, chunk, dk = q.shape
-    dv = v.shape[-1]
-    system, rhs, p, q_start, k_end, keep = _chunk_local(q, k, v, log_a, beta, sub)
-    solved = jax.scipy.linalg.solve_triangular(system, rhs, lower=True)
-    w_v, w_k = solved[..., :dv], solved[..., dv:]
-
-    def one_chunk(state, xs):
-        w_v, rows, p, k_end, keep = xs           # rows: w_k above q_start
-        from_state = _einsum("hic,hcv->hiv", rows, state)
-        u = w_v - from_state[:, :chunk]
-        out = from_state[:, chunk:] + _einsum("hij,hjv->hiv", p, u)
-        after = keep * state + _einsum("hic,hiv->hcv", k_end, u)
-        return after, ((out, state, u) if kept else out)
-
-    _, out = jax.lax.scan(
-        one_chunk, jnp.zeros((h, dk, dv), jnp.float32),
-        (w_v, jnp.concatenate([w_k, q_start], 2), p, k_end, keep))
-    if not kept:
-        return out
-    out, starts, u = out
-    return out, (q, k, v, log_a, beta, solved, starts, u)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
-def _kda_chunks(q, k, v, log_a, beta, sub: int):
-    """:func:`kda_chunked` on whole chunks, ``[n, H, C, ...]`` in and out,
-    under a backward rule of its own (:func:`_chunks_backward`)."""
-    return _chunks_forward(q, k, v, log_a, beta, sub, kept=False)
-
-
-def _chunks_backward(sub: int, kept, dout):
-    """The pull-back of :func:`_kda_chunks`, from the last chunk to the
-    first. With ``S0`` the state a chunk starts with and ``dS`` the
-    cotangent of the state it ends with (the forward's ``out = q_start S0 +
-    P u``, ``u = w_v - w_k S0``, ``S_C = keep * S0 + k_end^T u``)::
-
-        du  = P^T dout + k_end dS            dS0 = [w_k; q_start]^T [-du; dout]
-        dP  = dout u^T                             + keep * dS
-        dw_v = du          dw_k = -du S0^T         dq_start = dout S0^T
-        dk_end = u dS^T    dkeep = sum_v(S0 * dS)
-
-    Only ``du`` and ``dS0`` need the chunk after: they are the scan, two
-    products a chunk; the other five are products over all chunks at once,
-    before it (``P^T dout``) and after. Then the solve's transpose, one
-    solve with the transposed system (``d rhs = system^-T d solved``,
-    ``d system = -d rhs solved^T``: what of it lies on or above the diagonal
-    meets a constant), and JAX's own pull-back of :func:`_chunk_local`,
-    whose inside is computed again here from the inputs. The rule names its
-    own scopes: it is traced where the layer's caller has none."""
-    q, k, v, log_a, beta, solved, starts, u = kept
-    chunk, dv = q.shape[2], v.shape[-1]
-    with jax.named_scope("lane.kda"):
-        with jax.named_scope("pass.recompute"):
-            (system, _, p, q_start, k_end, keep), local_back = jax.vjp(
-                functools.partial(_chunk_local, sub=sub), q, k, v, log_a, beta)
-        rows = jnp.concatenate([solved[..., dv:], q_start], 2)
-        du_own = _einsum("nhij,nhiv->nhjv", p, dout)
-
-        def one_chunk(d_after, xs):
-            du_own, dout, rows, k_end, keep = xs
-            du = du_own + _einsum("hic,hcv->hiv", k_end, d_after)
-            d_start = keep * d_after + _einsum(
-                "hic,hiv->hcv", rows, jnp.concatenate([-du, dout], 1))
-            return d_start, (du, d_after)
-
-        _, (du, d_after) = jax.lax.scan(
-            one_chunk, jnp.zeros_like(starts[0]),
-            (du_own, dout, rows, k_end, keep), reverse=True)
-        dp = _einsum("nhiv,nhjv->nhij", dout, u)
-        d_rows = _einsum("nhiv,nhcv->nhic", jnp.concatenate([-du, dout], 2), starts)
-        dk_end = _einsum("nhiv,nhcv->nhic", u, d_after)
-        dkeep = jnp.sum(starts * d_after, -1, keepdims=True)
-        d_rhs = jax.scipy.linalg.solve_triangular(
-            system, jnp.concatenate([du, d_rows[:, :, :chunk]], -1), lower=True, trans=1)
-        d_system = -jnp.einsum("nhiv,nhjv->nhij", d_rhs, solved, precision=_FLOAT32)
-        return local_back((d_system, d_rhs, dp, d_rows[:, :, chunk:], dk_end, dkeep))
-
-
-_kda_chunks.defvjp(functools.partial(_chunks_forward, kept=True), _chunks_backward)
-
-
 def kda_chunked(q, k, v, log_a, beta, chunk: int, sub: int = None):
-    """The gated delta rule, chunk by chunk.
-
-    Per head, with ``S`` f32[d_k, d_v] zero at the start::
-
-        S_t = (I - beta_t k_t k_t^T) diag(a_t) S_{t-1} + beta_t k_t v_t^T
-        o_t = S_t^T q_t
-
-    ``q, k`` f32[T, H, d_k], ``v`` f32[T, H, d_v], ``log_a`` f32[T, H, d_k]
-    (``log a_t <= 0``), ``beta`` f32[T, H]; returns f32[T, H, d_v]. Inside
-    a chunk, with ``G_i`` the running sum of ``log_a`` and ``u_i`` the
-    delta rule's corrected values, ``(I + diag(beta) tril(A, -1)) U =
-    diag(beta) (V - (K exp G) S_0)`` where ``A_ij = sum_c k_ic k_jc
-    exp(G_ic - G_jc)``: one triangular solve gives ``U`` from the state the
-    chunk starts with (the WY form), then ``o_i = (q_i exp G_i) S_0 +
-    sum_{j<=i} P_ij u_j`` with ``P`` as ``A`` with ``q`` on the left, and
-    ``S_C = diag(exp G_C) S_0 + (K exp(G_C - G))^T U``. Every exponent is
-    a difference that is never positive, so no decay however strong
-    overflows. What does not need the state (``A``, ``P``, the solve
-    against ``[V, K exp G]``) is computed for all chunks at once
-    (:func:`_chunk_local`, the products in blocks of ``sub``, a quarter of
-    the chunk unless given); only the state's own recurrence, three small
-    products a chunk, is a scan. A length that is no multiple of ``chunk``
-    is padded with steps that leave the state alone (``a = 1, beta = 0``).
-
-    Its gradient is a rule of its own (:func:`_chunks_backward`: the scan
-    from the last chunk to the first written out, the solve's transpose one
-    solve), not what JAX makes of the scan and the solve."""
-    t, h, _ = q.shape
-    sub = sub or max(chunk // 4, 1)
-    pad = -t % chunk
-    if pad:
-        q, k, v, log_a = (jnp.pad(x, ((0, pad), (0, 0), (0, 0)))
-                          for x in (q, k, v, log_a))
-        beta = jnp.pad(beta, ((0, pad), (0, 0)))
-    n = (t + pad) // chunk
-    # chunk-major, head before position: [n, H, C, d]
-    split = lambda x: x.reshape((n, chunk) + x.shape[1:]).swapaxes(1, 2)
-    out = _kda_chunks(*(split(x) for x in (q, k, v, log_a, beta)), sub)
-    return out.swapaxes(1, 2).reshape((t + pad, h, v.shape[-1]))[:t]
+    """KDA's scan: the gated delta rule with a gate a channel (``log_a``
+    f32[T, H, d_k]), chunk by chunk under its own backward rule
+    (``delta_rule.delta_rule_chunked``, which says how)."""
+    return delta_rule_chunked(q, k, v, log_a, beta, chunk, sub, scope="lane.kda")
 
 
 #: how the lane differentiates KDA, beside its counted facts
 #: (``make_lane_eval_fn(static_counters=...)``): 1 where the gradient is the
-#: rule of :func:`_chunks_backward`
+#: rule of ``delta_rule._chunks_backward``
 KDA_COUNTERS = (("kda_backward_by_rule", 1),)
 
 
@@ -506,8 +309,11 @@ def kimi_linear_lane_bytes(cfg: KimiLinearConfig) -> int:
     activations: the logits and their gradient, one layer's recomputed
     activations (about 40 hidden-sized rows a token, the attention scores
     of ``mla_heads_at_once`` heads) and a layer's input per layer. At the
-    published widths it gives 11.5 GB where the chip's compiler counts
-    12.1 GB for the bracket: one lane fits a 16.9 GB chip, two do not."""
+    published widths it gives 11.5 GB where the chip's allocator peaks at
+    8.88 GB (``device.peak_hbm_bytes`` 8,883,300,000 since PR 44: the
+    trainer steps a layer's leaves where the backward pass leaves them, so
+    one layer's gradient is alive at a time): one lane fits a 16.9 GB chip,
+    two do not."""
     n_params = lane._count_params(
         lambda: init_kimi_linear_params(jax.random.key(0), cfg, 1.0))
     t = cfg.seq_len

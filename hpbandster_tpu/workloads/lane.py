@@ -1,5 +1,6 @@
 """What the lanes of published blocks share (``kimi_linear.py``,
-``mellum2.py``, ``ouro.py``, ``lfm2.py``, ``sdar.py``): a lane is one chip's share of a model of layers, trained
+``mellum2.py``, ``ouro.py``, ``lfm2.py``, ``sdar.py``, ``olmo_hybrid.py``): a lane
+is one chip's share of a model of layers, trained
 from the configuration's key by momentum SGD, one sequence a step.
 
 Here live the search space and its decoding, the rule for a matrix
@@ -530,13 +531,16 @@ def attention_mixer(x, p, *, kv_heads: int, heads_per_kv: int, head_dim: int,
     """An attention layer's mixer, from the norm's output to ``W_o``: the
     projections ``wq``, ``wk``, ``wv`` as one product, queries and keys
     turned by the rotary tables of ``inv_freq`` and ``factor`` at the rows'
-    positions, softmax attention under the rule of sight ``sight`` (a
-    window or ``None``: causal, banded where it is a number; a
-    :class:`BlockDiffusion`: ``x`` is the clean and the masked copy, two
-    rows a position), ``wo``. A layer
-    whose leaves hold ``q_norm`` and ``k_norm`` (f32[head_dim] each) puts
-    every head of its queries and of its keys through an RMSNorm of those
-    weights and ``norm_eps``, between the projection and the rotation.
+    positions (``inv_freq`` None: a layer without positions, nothing is
+    turned and no tables are built), softmax attention under the rule of
+    sight ``sight`` (a window or ``None``: causal, banded where it is a
+    number; a :class:`BlockDiffusion`: ``x`` is the clean and the masked
+    copy, two rows a position), ``wo``. A layer whose leaves hold ``q_norm``
+    and ``k_norm`` puts its queries and its keys through an RMSNorm of those
+    weights and ``norm_eps``, between the projection and the rotation; the
+    leaf's shape says over what: f32[head_dim], every head through its own
+    norm with the one weight; f32[heads x head_dim], the projection's whole
+    width through one norm, before the heads are split.
 
     The attention is :func:`banded_attention` in plain JAX or, where
     :func:`_kernel_tiles` says so, the fused kernels of
@@ -553,18 +557,23 @@ def attention_mixer(x, p, *, kv_heads: int, heads_per_kv: int, head_dim: int,
     q_norm, k_norm = p.get("q_norm"), p.get("k_norm")
     if q_norm is not None:
         # once, before the two paths part, so that both have it
-        per_head = lambda y, w: _rms(y.reshape(t, -1, d), w, norm_eps).reshape(y.shape)
-        q, k = per_head(q, q_norm), per_head(k, k_norm)
+        normed = lambda y, w: _rms(y.reshape(t, -1, w.shape[0]), w, norm_eps).reshape(y.shape)
+        q, k = normed(q, q_norm), normed(k, k_norm)
     rule = _rule(sight)
-    cos, sin = _rotary_tables(inv_freq, factor, rule.positions(t))
+    if inv_freq is None:
+        turn = turn_side_by_side = lambda y: y
+    else:
+        cos, sin = _rotary_tables(inv_freq, factor, rule.positions(t))
+        turn = functools.partial(_rotate, cos=cos, sin=sin)
+        turn_side_by_side = functools.partial(_rotate_side_by_side, cos=cos, sin=sin)
     tiles = _kernel_tiles(t, d, r, g, rule)
     if tiles is not None:
         out = pallas_attention.fused_banded_attention(
-            _rotate_side_by_side(q, cos, sin), _rotate_side_by_side(k, cos, sin), v,
+            turn_side_by_side(q), turn_side_by_side(k), v,
             (g, r, d), rule, tiles, _OPERAND, scope)
     else:
         out = banded_attention(
-            _rotate(q.reshape(t, g, r, d), cos, sin), _rotate(k.reshape(t, g, d), cos, sin),
+            turn(q.reshape(t, g, r, d)), turn(k.reshape(t, g, d)),
             v.reshape(t, g, d), sight, block).reshape(t, g * r * d)
     return _mm(out, p["wo"])
 

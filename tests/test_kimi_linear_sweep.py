@@ -87,10 +87,11 @@ def test_the_row_counts_the_lanes(swept):
 def test_the_lane_names_its_parts_inside_the_trainer(swept):
     (phases,) = sweep_phase_maps().values()
     (parts,) = sweep_phase_maps(LANE_SCOPES).values()
-    # every part of the list but the mixers of the Mellum2 and LFM2 lanes and
-    # what only a looped lane has (exits, a shared leaf's sum)
+    # every part of the list but the mixers of the other lanes and what only
+    # a looped lane has (exits, a shared leaf's sum)
     assert set(parts.values()) == set(LANE_SCOPES) - {
-        "lane.swa", "lane.gqa", "lane.bda", "lane.conv", "lane.exit", "lane.accumulate"}
+        "lane.gdn", "lane.swa", "lane.gqa", "lane.bda", "lane.conv", "lane.exit",
+        "lane.accumulate"}
     assert {"hpb.train", "hpb.promote"} <= set(phases.values()) <= set(DEVICE_SCOPES)
     # a lane's part lies inside the evaluation: no instruction has a part
     # and a phase other than the trainer's two

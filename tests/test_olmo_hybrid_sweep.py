@@ -1,0 +1,137 @@
+"""A bracket of the small Olmo-Hybrid lane (``olmo_hybrid_small.py``: three
+Gated-DeltaNet layers, gated once a head, and one full-attention layer without
+positions, the norm after the sub-layer) through ``FusedBOHB``, its lanes
+taken in turn, every reported loss held to the benchmark's plain reference.
+In a file of its own: the sweep's compilation is the suite's cost here, and
+the workers share out files."""
+
+import collections
+import re
+import sys
+
+import jax.numpy as jnp
+import pytest
+
+from hpbandster_tpu import obs
+from hpbandster_tpu.obs.timeline import (
+    DEVICE_SCOPES, LANE_SCOPES, MOE_SCOPES, PASS_SCOPES)
+from hpbandster_tpu.ops import fused
+from hpbandster_tpu.optimizers import FusedBOHB, sweep_phase_maps
+from hpbandster_tpu.optimizers.fused_bohb import _SWEEP_EXE_CACHE
+from hpbandster_tpu.workloads import lane
+from hpbandster_tpu.workloads import olmo_hybrid as OH
+
+import lane_names
+from olmo_hybrid_small import SMALL, load
+
+
+@pytest.fixture(scope="module")
+def swept():
+    """One bracket of 9, 3, 1 lanes at 1, 3, 9 steps, float32 operands so
+    that the reference can hold every loss tightly, one lane at a time."""
+    sys.modules.setdefault("program", load("program.py"))
+    cfg = load("configs", "olmo-hybrid-sgd.py").lane_config(SMALL)._replace(
+        attn_query_block=16, gdn_chunk=16)
+    patch = pytest.MonkeyPatch()
+    patch.setattr(lane, "_OPERAND", jnp.float32)
+    eval_fn = OH.make_olmo_hybrid_eval_fn(cfg, data_seed=SMALL["data_seed"])
+    patch.setattr(fused, "_device_memory_bytes", lambda: eval_fn.lane_facts.bytes + 1)
+    # the phase maps below are over every sweep executable the process
+    # holds: this worker's earlier files have left theirs
+    _SWEEP_EXE_CACHE.clear()
+    try:
+        opt = FusedBOHB(configspace=OH.olmo_hybrid_space(seed=11), eval_fn=eval_fn,
+                        run_id="olmo", min_budget=1, max_budget=9, eta=3, seed=11)
+        with lane_names.compiled_here():
+            result = opt.run(n_iterations=1)
+        yield opt, result
+    finally:
+        patch.undo()
+
+
+def test_every_reported_loss_is_the_references(swept):
+    _, result = swept
+    reference = load("reference", "olmo-hybrid-sgd.py")
+    by_lane = collections.defaultdict(dict)
+    for run in result.get_all_runs():
+        by_lane[run.config_id][int(run.budget)] = run.loss
+    id2config = result.get_id2config_mapping()
+    assert sorted(len(v) for v in by_lane.values()) == [1] * 6 + [2, 2, 3]
+    for config_id, reported in by_lane.items():
+        hp = id2config[config_id]["config"]
+        marks = sorted(reported)
+        want = reference.reference_losses(
+            SMALL, [hp[n] for n in reference.HPARAMS], marks)
+        for mark, w in zip(marks, want):
+            # float32 both sides, sums in another order: under 3e-6 but for
+            # the promoted lane of learning rate 0.14, which amplifies that
+            # to 7.6e-3 after three steps (a mixer reads the stream as it
+            # is, with no norm before it: ``test_olmo_hybrid.py``)
+            assert reference.gap(reported[mark], w) < 2e-2, (hp, mark, reported[mark], w)
+
+
+def test_the_row_counts_the_lanes_and_how_the_scan_ran(swept):
+    opt, _ = swept
+    row = opt.run_stats[-1]
+    assert row["evaluations"] == 13 and row["lane_steps"] == 27
+    assert row["lane_tokens"] == 27 * 32 and row["lanes_at_once"] == 1
+    # static facts: the scan ran its form of a gate a head, under its own
+    # backward rule; off the chip the full layer's scores take the plain form
+    assert (row["gdn_gate_per_head"], row["gdn_backward_by_rule"]) == (1, 1)
+    assert row["attn_scores_in_vmem"] == 0
+    # the counters are the model's: no experts, no loop, and not KDA's
+    assert not [name for name in row if name.startswith(("moe_", "kda_", "loop_"))]
+    assert opt.eval_fn.lane_facts.counters == (
+        "gdn_gate_per_head", "gdn_backward_by_rule", "attn_scores_in_vmem")
+    gauges = obs.get_metrics().snapshot()["gauges"]
+    assert gauges["sweep.lane.gdn_gate_per_head"] == 1.0
+    assert gauges["sweep.lane.gdn_backward_by_rule"] == 1.0
+    assert gauges["sweep.lane.lane_steps"] == 27
+
+
+def test_the_lane_names_its_parts_inside_the_trainer(swept):
+    (phases,) = sweep_phase_maps().values()
+    (parts,) = sweep_phase_maps(LANE_SCOPES).values()
+    assert set(parts.values()) == {
+        "lane.gdn", "lane.gqa", "lane.dense_ffn", "lane.head", "lane.update"}
+    assert {"hpb.train", "hpb.promote"} <= set(phases.values()) <= set(DEVICE_SCOPES)
+    inside = {phases.get(name) for name in parts}
+    assert inside <= {"hpb.train", "hpb.validate"}
+    # the backward pass is charged where the forward pass is. The scan's
+    # backward rule is written by hand (``delta_rule._chunks_backward``) and
+    # traced where the layer's scope is no longer open: it names ``lane.gdn``
+    # itself, its scan from the last chunk to the first and its solve with it
+    text = swept[0].last_executable.as_text()
+    backward, in_rule = collections.defaultdict(list), []
+    for line in text.splitlines():
+        name = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = ", line)
+        made_of = re.search(r"transpose\(jvp\((lane\.\w+)\)\)", line)
+        if name and made_of:
+            backward[made_of.group(1)].append(name.group(1))
+        if name and re.search(r'op_name="[^"]*pass\.backward/[^"]*/lane\.gdn/', line):
+            in_rule.append(name.group(1))
+    assert set(backward) >= {"lane.gdn", "lane.gqa", "lane.dense_ffn", "lane.head"}
+    for part, names in backward.items():
+        assert {parts.get(name, part) for name in names} == {part}, part
+    assert len(in_rule) > 20 and {parts.get(name) for name in in_rule} == {"lane.gdn"}
+    solves = [n for n in parts if "triangular" in n or "solve" in n]
+    assert all(parts[n] == "lane.gdn" for n in solves)
+
+
+def test_the_trainer_names_its_passes(swept):
+    """Forward, recomputed and backward (``obs.timeline.PASS_SCOPES``), in
+    every part of the lane but the update."""
+    (parts,) = sweep_phase_maps(LANE_SCOPES).values()
+    (passes,) = sweep_phase_maps(PASS_SCOPES).values()
+    text = swept[0].last_executable.as_text()
+    assert passes == lane_names.check_the_trainer_names_its_passes(text, parts)
+
+
+def test_the_older_readers_read_what_they_read(swept):
+    lane_names.check_the_older_readers_read_what_they_read(
+        swept[0].last_executable.as_text())
+
+
+def test_a_lane_without_experts_names_no_piece(swept):
+    # left out of the family's map, so its metrics read nothing
+    assert sweep_phase_maps(MOE_SCOPES) == {}

@@ -393,3 +393,54 @@ def test_attention_under_the_block_diffusion_rule_compiles_for_v5e_with_the_kern
     assert not re.search(r"f32\[[\d,]*\b%d\]" % rows, text)
     widest = r * cfg.attn_query_block * (cfg.seq_len + cfg.attn_query_block)
     assert max(size for size in _f32_sizes(text) if size != rows * g * r * d) == widest
+
+
+def test_a_gated_deltanet_layer_compiles_for_v5e_at_the_published_size(
+        v5e_devices, mosaic_compiles_here):
+    """The Olmo-Hybrid lane's linear layer (``workloads/olmo_hybrid.py``: the
+    Gated-DeltaNet mixer, 30 heads of ``d_k`` 96 beside ``d_v`` 192, gated
+    once a head, and the SwiGLU, each under the norm that follows it) at
+    2,048 tokens, forward and backward pass: the chip's compiler takes it;
+    the scan's form of a gate a head is plain JAX under ``lane.gdn`` in both
+    passes, the backward rule (``delta_rule._chunks_backward``) naming the
+    part itself; a chunk's decays are ``[chunks, heads, 64, 64]`` arrays and
+    no array carries the per-channel form's blocks (no ``16 x 16 x 96``); no
+    float32 array is larger than the gradient of the SwiGLU's gate and up
+    side by side (3,840 x 22,016). The
+    full layer at as many keys: no positions, so no cosine, and at 2,048
+    keys the plain form on the chip too; at 8,192 keys the kernels take 30
+    heads of 128."""
+    import re
+    import time
+
+    from hpbandster_tpu.workloads import lane
+    from hpbandster_tpu.workloads import olmo_hybrid as OH
+
+    one = SingleDeviceSharding(v5e_devices[0])
+    cfg = OH.OlmoHybridConfig()
+    x = _sds((cfg.seq_len, cfg.hidden_size), jnp.float32, one)
+    leaves = lambda mixer: {name: _sds(shape, jnp.float32, one)
+                            for name, shape in OH._layer_shapes(cfg, mixer).items()}
+
+    def both_passes(mixer):
+        def run(x, p, dy):
+            y, pull = jax.vjp(lambda x, p: OH._layer(x, p, mixer, cfg)[0], x, p)
+            return y, pull(dy)
+        return run
+
+    t0 = time.perf_counter()
+    text = jax.jit(both_passes("gdn")).lower(x, leaves("gdn"), x).compile().as_text()
+    print("a linear layer's forward and backward pass compiled for v5e in %.1f s"
+          % (time.perf_counter() - t0))
+    assert _kernel_parts(text) == []
+    assert "lane.gdn" in text and "transpose(jvp(lane.gdn))" in text
+    # the rule's own scan and solve, named by the rule
+    assert re.search(r'op_name="[^"]*jvp\(lane\.gdn\)\)?/lane\.gdn/while', text)
+    chunks, heads = cfg.seq_len // cfg.gdn_chunk, cfg.linear_num_heads
+    assert re.search(r"f32\[%d,%d,64,64\]" % (chunks, heads), text)
+    assert not re.search(r"f32\[[\d,]*16,16,96\]", text)
+    assert max(_f32_sizes(text)) == cfg.hidden_size * 2 * cfg.intermediate_size
+
+    text = jax.jit(both_passes("gqa")).lower(x, leaves("gqa"), x).compile().as_text()
+    assert _kernel_parts(text) == [] and "cosine" not in text and "lane.gqa" in text
+    assert lane._kernel_tiles(8192, cfg.head_dim, 1, cfg.num_kv_heads) is not None
