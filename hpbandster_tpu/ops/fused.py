@@ -16,6 +16,7 @@ mesh-padding rows), index-stably — matching ``sh_promotion_mask``.
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
@@ -25,7 +26,7 @@ import numpy as np
 from hpbandster_tpu.obs.runtime import note_transfer, tracked_jit
 
 __all__ = ["LaneFacts", "StatefulEval", "eval_lanes", "fused_sh_bracket",
-           "lanes_at_once", "make_fused_bracket_fn", "shard_rows",
+           "init_draws", "lanes_at_once", "make_fused_bracket_fn", "shard_rows",
            "stage_telemetry"]
 
 #: crashed (NaN) losses map here for ranking: behind any real loss, ahead of
@@ -161,6 +162,13 @@ class LaneFacts(NamedTuple):
     #: loop over it): lanes in turn can then share one trace of the lane
     #: over all the rungs of a bracket (:func:`_sh_bracket_in_turn`)
     traced_budget: bool = False
+    #: ``shared() -> tree``: what of a lane no configuration changes and
+    #: every evaluation would make again (the unit draw of its initial
+    #: weights). ``eval_fn`` and ``with_counters`` take the tree as a third
+    #: argument; a loop that takes the evaluations in turn (a bracket's, or
+    #: a rung's) makes it once, before the loop, where it fits the device
+    #: beside the lanes (:func:`_holds_shared`)
+    shared: Any = None
 
 
 def _device_memory_bytes():
@@ -182,6 +190,65 @@ def lanes_at_once(eval_fn, n_lanes: int) -> int:
     return max(int(memory // facts.bytes), 1)
 
 
+@functools.lru_cache(maxsize=64)
+def _shared_bytes(shared) -> int:
+    """The bytes of ``shared()``, from its shapes alone: read once a lane,
+    not once a chunk's accounting."""
+    return sum(leaf.size * leaf.dtype.itemsize
+               for leaf in jax.tree.leaves(jax.eval_shape(shared)))
+
+
+def _holds_shared(facts, at_once: int) -> bool:
+    """Whether a loop that takes a lane's evaluations in turn, ``at_once`` of
+    them side by side, makes what they share (``facts.shared``) once and
+    holds it: the lane states it, and its bytes fit the device beside the
+    lanes'. A backend that does not say its memory (the CPU) holds it."""
+    if facts is None or facts.shared is None:
+        return False
+    memory = _device_memory_bytes()
+    return memory is None or at_once * facts.bytes + _shared_bytes(facts.shared) <= memory
+
+
+def _evaluation(eval_fn, facts, counted: bool, at_once: int):
+    """``one(vec, budget)`` of a loop that takes a lane's evaluations in
+    turn: its loss, or ``(loss, counters)`` where ``counted``. Called before
+    the loop: what the evaluations share and the device can hold
+    (:func:`_holds_shared`) is made here, once, and every evaluation is
+    handed it; else every evaluation makes it, as before there was any."""
+    one = facts.with_counters if counted else eval_fn
+    if not _holds_shared(facts, at_once):
+        return one
+    shared = facts.shared()
+    return lambda vec, budget: one(vec, budget, shared)
+
+
+def _in_turn(eval_fn, n_rows: int, depth: int, mesh=None) -> bool:
+    """Whether a bracket of ``depth`` rungs, the widest of ``n_rows`` lanes,
+    is one loop over its evaluations (:func:`_sh_bracket_in_turn`)."""
+    facts = getattr(eval_fn, "lane_facts", None)
+    return (facts is not None and facts.traced_budget and mesh is None
+            and depth > 1 and lanes_at_once(eval_fn, n_rows) == 1)
+
+
+def init_draws(eval_fn, num_configs: Sequence[int]) -> int:
+    """How often the program of one bracket of ``num_configs`` lanes a rung
+    makes what a lane's evaluations share (the draw of its initial weights),
+    by the two rules the program itself was built by (:func:`_in_turn`,
+    :func:`_holds_shared`): a loop makes it once a turn, and once in all
+    where it was handed it or takes one turn. The loops: the bracket's,
+    where its lanes run in turn in one; else a rung's, whose turn is as
+    many lanes as fit side by side (a rung that fits is one turn, a
+    ``vmap``: one trace of the evaluation). A mesh changes nothing here:
+    lanes that do not fit side by side are not sharded yet."""
+    facts = eval_fn.lane_facts
+    if _in_turn(eval_fn, int(num_configs[0]), len(num_configs)):
+        loops = [(int(sum(num_configs)), 1)]
+    else:
+        at_once = [lanes_at_once(eval_fn, int(n)) for n in num_configs]
+        loops = [(-(-int(n) // a), a) for n, a in zip(num_configs, at_once)]
+    return sum(1 if turns == 1 or _holds_shared(facts, a) else turns for turns, a in loops)
+
+
 def eval_lanes(eval_fn, vecs: jax.Array, budget: float, mesh=None,
                counters: Optional[list] = None) -> jax.Array:
     """A rung's losses ``f32[n]`` from its vectors ``f32[n, d]``: the one
@@ -192,13 +259,19 @@ def eval_lanes(eval_fn, vecs: jax.Array, budget: float, mesh=None,
     rung of one lane, the lane is traced unbatched. With ``counters`` a
     list and an ``eval_fn`` that counts on the device
     (``lane_facts.counters``), the rung's ``f32[n, len(counters)]`` is
-    appended to it."""
+    appended to it. Where the lanes are taken in turn, what they share
+    (``lane_facts.shared``) is made once, before the loop
+    (:func:`_evaluation`)."""
     facts = getattr(eval_fn, "lane_facts", None)
     counted = counters is not None and facts is not None and bool(facts.counters)
     one = ((lambda v: facts.with_counters(v, budget)) if counted
            else (lambda v: eval_fn(v, budget)))
     n = vecs.shape[0]
     at_once = lanes_at_once(eval_fn, n)
+    if at_once < n and mesh is None:
+        # lanes in turn: the loop would make what they share once a turn
+        evaluation = _evaluation(eval_fn, facts, counted, at_once)
+        one = lambda v: evaluation(v, budget)
     if facts is None or 1 < n <= at_once:
         out = jax.vmap(one)(vecs)
     elif mesh is not None and at_once < n:
@@ -225,7 +298,10 @@ def _sh_bracket_in_turn(eval_fn, vectors, num_configs, budgets, rank_key,
     published block takes a minute to compile). Slot ``i`` evaluates row
     ``i`` of a queue of vectors at its rung's budget; after a rung's last
     slot the promotion of :func:`fused_sh_bracket`, the same arithmetic on
-    the same arrays, fills the next rung's rows of the queue."""
+    the same arrays, fills the next rung's rows of the queue. What the
+    evaluations share (``lane_facts.shared``) is made once, before the loop
+    (:func:`_evaluation`): the loop's body reads it as an operand that no
+    slot changes."""
     n0, n_rows = int(num_configs[0]), vectors.shape[0]
     widths = [n_rows] + [int(k) for k in num_configs[1:]]
     starts = np.concatenate([[0], np.cumsum(widths)])
@@ -237,6 +313,8 @@ def _sh_bracket_in_turn(eval_fn, vectors, num_configs, budgets, rank_key,
     # after slot i: nothing (0), or the promotion that follows rung s (s + 1)
     then = np.zeros(total, np.int32)
     then[starts[1:-1] - 1] = np.arange(1, depth)
+    with jax.named_scope("hpb.train"):
+        evaluation = _evaluation(eval_fn, facts, counted, 1)
 
     def promote(s, carry):
         """What follows rung ``s``: rank its survivors by their history
@@ -267,11 +345,10 @@ def _sh_bracket_in_turn(eval_fn, vectors, num_configs, budgets, rank_key,
         queue, losses, counts, idx, tops = carry
         budget = jnp.asarray(budgets, jnp.float32)[jnp.asarray(stage_of)[i]]
         with jax.named_scope("hpb.train"):
+            loss = evaluation(queue[i], budget)
             if counted:
-                loss, count = facts.with_counters(queue[i], budget)
+                loss, count = loss
                 counts = counts.at[i].set(count.astype(jnp.float32))
-            else:
-                loss = eval_fn(queue[i], budget)
         losses = losses.at[i].set(loss.astype(jnp.float32))
         carry = (queue, losses, counts, idx, tops)
         return jax.lax.switch(
@@ -393,9 +470,7 @@ def fused_sh_bracket(
         return scores
 
     vectors = shard_rows(vectors, mesh, axis)
-    facts = getattr(eval_fn, "lane_facts", None)
-    if (facts is not None and facts.traced_budget and mesh is None
-            and len(num_configs) > 1 and lanes_at_once(eval_fn, n_rows) == 1):
+    if _in_turn(eval_fn, n_rows, len(num_configs), mesh):
         return _sh_bracket_in_turn(eval_fn, vectors, num_configs, budgets,
                                    rank_key, scores_for, lane_counters)
     state = None

@@ -100,13 +100,15 @@ def _lane_accounting(eval_fn, plans, outputs) -> Dict[str, Any]:
     """What a chunk's ``run_stats`` row says of the lanes of an ``eval_fn``
     whose maker stated its facts (``ops.fused.LaneFacts``): the steps and
     tokens its evaluations trained (a stateless evaluation trains its whole
-    budget), how many lanes the widest rung evaluated side by side, and the
+    budget), how many lanes the widest rung evaluated side by side, how
+    often the program drew a lane's initial weights (once a loop that was
+    handed the draw, else once a turn of it: ``ops.fused.init_draws``), and the
     mean over the evaluations of each device counter. Published as gauges
     ``sweep.lane.<name>`` too. Empty for every other evaluation."""
     facts = getattr(eval_fn, "lane_facts", None)
     if facts is None:
         return {}
-    from hpbandster_tpu.ops.fused import lanes_at_once
+    from hpbandster_tpu.ops.fused import init_draws, lanes_at_once
 
     steps = sum(n * int(round(b)) for plan in plans
                 for n, b in zip(plan.num_configs, plan.budgets))
@@ -115,6 +117,7 @@ def _lane_accounting(eval_fn, plans, outputs) -> Dict[str, Any]:
         "lane_tokens": steps * facts.tokens_per_step,
         "lanes_at_once": lanes_at_once(
             eval_fn, max(n for plan in plans for n in plan.num_configs)),
+        "init_draws": sum(init_draws(eval_fn, plan.num_configs) for plan in plans),
     }
     if facts.counters:
         counted = np.concatenate([out.lane_counters for out in outputs])

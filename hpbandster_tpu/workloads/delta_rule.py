@@ -39,7 +39,8 @@ def _init_leaf(key, name: str, shape, init_scale):
     """The lane's draw of a leaf (``lane._init_leaf``), and the gate's two
     leaves that are not drawn: ``A_log`` the log of 1..16 over the heads,
     ``dt_bias`` the inverse softplus of 0.001..0.1 (geometric) over its
-    entries (KDA's channels, Gated DeltaNet's heads)."""
+    entries (KDA's channels, Gated DeltaNet's heads). ``key`` is passed on
+    as it came: a PRNG key, or ``lane.Init``'s ``draw(name, shape)``."""
     leaf = name.rsplit("/", 1)[-1]
     if leaf == "A_log":
         return jnp.log(jnp.linspace(1.0, 16.0, shape[0], dtype=jnp.float32))

@@ -338,7 +338,7 @@ def make_kimi_linear_eval_fn(cfg: KimiLinearConfig = KimiLinearConfig(),
     init_key = jax.random.key(data_seed + 1)
     layers = _layers(cfg)
     return lane.make_lane_eval_fn(
-        init=lambda init_scale: init_kimi_linear_params(init_key, cfg, init_scale),
+        init=lane.Init(init_kimi_linear_params, init_key, cfg),
         visits=lane.once_through(layers, counted=len(LANE_COUNTERS)),
         exits=lane.head_exit(len(layers), cfg.rms_norm_eps),
         data=make_token_dataset(jax.random.key(data_seed), cfg),

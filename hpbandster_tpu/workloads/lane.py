@@ -43,6 +43,7 @@ __all__ = [
     "Counted",
     "Exits",
     "ExpertLayer",
+    "Init",
     "LANE_COUNTERS",
     "MOE_COUNTERS",
     "Visit",
@@ -601,23 +602,68 @@ def short_conv_mixer(x, p, *, scope: str):
 
 
 # --------------------------------------------------------------- parameters
+def _unit_draw(key, name: str, shape):
+    """A leaf's unit normals from the key and its own name, so that the draw
+    does not depend on which other leaves exist."""
+    # drawn as a matrix and folded: the same numbers in the same order (the
+    # chip's compiler takes fourteen seconds over a three-dimensional draw)
+    return jax.random.normal(
+        jax.random.fold_in(key, zlib.crc32(name.encode()) & 0x7FFFFFFF),
+        (int(np.prod(shape[:-1])), shape[-1]), jnp.float32).reshape(shape)
+
+
+class Init:
+    """A lane's initial weights in the two halves a bracket takes apart, of
+    ``params(key, *args, init_scale)``, a model's ``init_<model>_params``,
+    which draws through :func:`_init_leaf` alone: ``shared()``, the unit
+    draws by the leaves' names, the same numbers whatever the configuration;
+    ``scale(shared, init_scale)``, the parameters from them, with the leaves
+    that are not drawn; ``init(init_scale)``, both in one program, as it was
+    before there were halves. A leaf is the same expression of the same
+    draw either way, and the same number to its last bit or the one before:
+    where the draw and its scaling are one fusion the compiler multiplies
+    the normal's own last factor, the square root of two, into the scale
+    first, and where the draw is an operand it cannot. Both halves are
+    ``params`` itself, handed where its key goes a ``draw(name, shape)``
+    that keeps what it draws, or one that hands out what was kept: so
+    ``params`` hands its key to :func:`_init_leaf` as it got it, and
+    neither splits it nor folds anything into it."""
+
+    def __init__(self, params, key, *args):
+        self._params, self._key, self._args = params, key, args
+
+    def __call__(self, init_scale):
+        return self._params(self._key, *self._args, init_scale)
+
+    def shared(self) -> dict:
+        held = {}
+
+        def draw(name, shape):
+            held[name] = _unit_draw(self._key, name, shape)
+            return held[name]
+
+        self._params(draw, *self._args, 1.0)    # the scaling is dead code in a trace
+        return held
+
+    def scale(self, shared: dict, init_scale):
+        return self._params(lambda name, shape: shared[name], *self._args, init_scale)
+
+
 def _init_leaf(key, name: str, shape, init_scale):
-    """One leaf from the key and its own name, so that the draw does not
-    depend on which other leaves exist. Matrices (and depthwise
-    convolutions) are ``init_scale / sqrt(fan_in) * N(0, 1)``, the
-    embedding ``init_scale * N(0, 1)`` (a lookup's fan-in is one: a
-    smaller embedding only has the first norm multiply its gradient up),
-    norm weights one, a bias (``*_bias``) zero."""
+    """One leaf from the key and its own name (:func:`_unit_draw`). ``key``
+    is a PRNG key or, from :class:`Init`, a ``draw(name, shape)`` that
+    answers with the leaf's unit normals: a model's ``init_<model>_params``
+    passes it on untouched. Matrices (and depthwise convolutions) are
+    ``init_scale / sqrt(fan_in) * N(0, 1)``, the embedding ``init_scale *
+    N(0, 1)`` (a lookup's fan-in is one: a smaller embedding only has the
+    first norm multiply its gradient up), norm weights one, a bias
+    (``*_bias``) zero."""
     leaf = name.rsplit("/", 1)[-1]
     if leaf.startswith("norm") or leaf.endswith("_norm"):
         return jnp.ones(shape, jnp.float32)
     if leaf.endswith("_bias"):
         return jnp.zeros(shape, jnp.float32)
-    # drawn as a matrix and folded: the same numbers in the same order (the
-    # chip's compiler takes fourteen seconds over a three-dimensional draw)
-    draw = jax.random.normal(
-        jax.random.fold_in(key, zlib.crc32(name.encode()) & 0x7FFFFFFF),
-        (int(np.prod(shape[:-1])), shape[-1]), jnp.float32).reshape(shape)
+    draw = key(name, shape) if callable(key) else _unit_draw(key, name, shape)
     fan_in = 1 if leaf == "embed" else shape[-2]
     return (init_scale * fan_in ** -0.5) * draw
 
@@ -629,7 +675,8 @@ def _init_params(key, cfg, layer_shapes, init_scale, init_leaf=_init_leaf,
     transposed, and there is no leaf ``head``; the one matrix is then drawn
     as the head it also is, ``init_scale / sqrt(hidden_size) * N(0, 1)`` (the
     same draw, scaled by the head's fan-in: the first norm takes a lookup's
-    scale out again, the logits keep it)."""
+    scale out again, the logits keep it). ``key`` goes to ``init_leaf`` as
+    it came: it may be :class:`Init`'s ``draw(name, shape)`` and no key."""
     d = cfg.hidden_size
     shapes = {"embed": (cfg.vocab_rows, d), "norm_f": (d,)}
     if not tied:
@@ -1476,8 +1523,10 @@ def make_lane_eval_fn(*, init, visits, exits: Exits, data, lane_bytes: int,
 
     The model is what it hands over:
 
-    * ``init(init_scale) -> params``: ``embed``, what the visits and the
-      exits name, from the configuration's key;
+    * ``init`` (:class:`Init`): ``init(init_scale) -> params``, ``embed`` and
+      what the visits and the exits name, from the configuration's key, and
+      its two halves, the draw that no hyperparameter changes
+      (``lane_facts.shared``) and its scaling;
     * ``visits``: a :class:`Visit` each, a pass in order. A plain stack
       visits every layer once (:func:`once_through`); a leaf may be visited
       several times a pass (layers run several times with one set of
@@ -1507,12 +1556,14 @@ def make_lane_eval_fn(*, init, visits, exits: Exits, data, lane_bytes: int,
     if len(set(counting)) > 1:
         raise ValueError("the visits that count anything count as many numbers each")
 
-    def passes(vec: jax.Array, budget, held_out: int):
+    def passes(vec: jax.Array, budget, held_out: int, shared=None):
         """``budget`` training passes, then ``held_out`` held-out ones: ``->
         (the parameters at initialisation, after the passes, the held-out
-        losses' sum, what the held-out passes counted)``."""
+        losses' sum, what the held-out passes counted)``. ``shared``: the
+        unit draws (``init.shared()``) made by the caller, once for all its
+        evaluations; without them the evaluation draws for itself."""
         lr, momentum, wd, init_scale = decode_lane_hparams(vec)
-        params = init(init_scale)
+        params = init(init_scale) if shared is None else init.scale(shared, init_scale)
         steps = jnp.asarray(budget, jnp.float32).round().astype(jnp.int32)
 
         def update(p, v, g):
@@ -1543,8 +1594,8 @@ def make_lane_eval_fn(*, init, visits, exits: Exits, data, lane_bytes: int,
                  jnp.zeros((exits.counted,), jnp.float32) if exits.counted else None)))
         return params, after, loss, counters
 
-    def with_counters(vec: jax.Array, budget):
-        _, _, loss, (visit_counters, exit_counters) = passes(vec, budget, n_val)
+    def with_counters(vec: jax.Array, budget, shared=None):
+        _, _, loss, (visit_counters, exit_counters) = passes(vec, budget, n_val, shared)
         loss = loss / n_val
         if counted.visits is not None:
             visit_counters = visit_counters[np.asarray(counted.visits, bool)]
@@ -1562,12 +1613,12 @@ def make_lane_eval_fn(*, init, visits, exits: Exits, data, lane_bytes: int,
         params, after, _, _ = passes(vec, budget, 0)
         return jax.tree.map(jnp.subtract, after, params)
 
-    def eval_fn(vec: jax.Array, budget) -> jax.Array:
-        return with_counters(vec, budget)[0]
+    def eval_fn(vec: jax.Array, budget, shared=None) -> jax.Array:
+        return with_counters(vec, budget, shared)[0]
 
     eval_fn.lane_facts = LaneFacts(
         bytes=lane_bytes, tokens_per_step=tokens_per_step,
         counters=tuple(counted.names) + tuple(name for name, _ in static_counters),
-        with_counters=with_counters, traced_budget=True)
+        with_counters=with_counters, traced_budget=True, shared=init.shared)
     eval_fn.change = change
     return eval_fn

@@ -250,7 +250,7 @@ def make_lfm2_eval_fn(cfg: Lfm2Config = Lfm2Config(), data_seed: int = 0):
         lane._kernel_tiles(cfg.seq_len, cfg.head_dim, heads_per_kv, cfg.num_kv_heads))
     layout = (sum(mixer == "conv" for mixer, _ in cfg.layer_kinds), 1)
     return lane.make_lane_eval_fn(
-        init=lambda init_scale: init_lfm2_params(init_key, cfg, init_scale),
+        init=lane.Init(init_lfm2_params, init_key, cfg),
         visits=_visits(cfg), exits=_exits(cfg),
         data=make_token_dataset(jax.random.key(data_seed), cfg),
         lane_bytes=lfm2_lane_bytes(cfg),

@@ -284,7 +284,7 @@ def make_mellum2_eval_fn(cfg: Mellum2Config = Mellum2Config(), data_seed: int = 
         lane._kernel_tiles(cfg.seq_len, cfg.head_dim, heads_per_kv, cfg.num_kv_heads))
     layers = _layers(cfg)
     return lane.make_lane_eval_fn(
-        init=lambda init_scale: init_mellum2_params(init_key, cfg, init_scale),
+        init=lane.Init(init_mellum2_params, init_key, cfg),
         visits=lane.once_through(layers, counted=len(LANE_COUNTERS)),
         exits=lane.head_exit(len(layers), cfg.rms_norm_eps),
         data=make_token_dataset(jax.random.key(data_seed), cfg),

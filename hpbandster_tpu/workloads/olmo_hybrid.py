@@ -231,7 +231,7 @@ def make_olmo_hybrid_eval_fn(cfg: OlmoHybridConfig = OlmoHybridConfig(),
     init_key = jax.random.key(data_seed + 1)
     visits, exits = _model(cfg)
     return lane.make_lane_eval_fn(
-        init=lambda init_scale: init_olmo_hybrid_params(init_key, cfg, init_scale),
+        init=lane.Init(init_olmo_hybrid_params, init_key, cfg),
         visits=visits, exits=exits,
         data=make_token_dataset(jax.random.key(data_seed), cfg),
         lane_bytes=olmo_hybrid_lane_bytes(cfg),

@@ -325,7 +325,7 @@ def make_ouro_eval_fn(cfg: OuroConfig = OuroConfig(), data_seed: int = 0):
     visits, exits = _visits(cfg), _exits(cfg)
     loop = (cfg.total_ut_steps, cfg.num_layers * cfg.total_ut_steps, len(exits.after))
     return lane.make_lane_eval_fn(
-        init=lambda init_scale: init_ouro_params(init_key, cfg, init_scale),
+        init=lane.Init(init_ouro_params, init_key, cfg),
         visits=visits, exits=exits,
         data=make_token_dataset(jax.random.key(data_seed), cfg),
         lane_bytes=ouro_lane_bytes(cfg),

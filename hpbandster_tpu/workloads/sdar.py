@@ -316,7 +316,7 @@ def make_sdar_eval_fn(cfg: SdarConfig = SdarConfig(), data_seed: int = 0):
         return [held, load / cfg.num_layers, computed, exits[0] / (n_val * cfg.seq_len)]
 
     return lane.make_lane_eval_fn(
-        init=lambda init_scale: init_sdar_params(init_key, cfg, init_scale),
+        init=lane.Init(init_sdar_params, init_key, cfg),
         visits=_visits(cfg), exits=_exits(cfg),
         data=make_diffusion_dataset(jax.random.key(data_seed), cfg),
         lane_bytes=sdar_lane_bytes(cfg), tokens_per_step=cfg.seq_len,

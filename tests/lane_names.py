@@ -2,7 +2,8 @@
 program: the passes (``obs.timeline.PASS_SCOPES``), the expert layer's
 pieces (``MOE_SCOPES``), and ``device_phase_map`` as it stood before either
 (PR 37's, kept here as the reference that the part and phase readers are
-held to, entry for entry)."""
+held to, entry for entry); and where a jaxpr draws its random bits, inside
+a loop or outside every one."""
 
 import contextlib
 import re
@@ -36,6 +37,22 @@ def compiled_here():
     finally:
         jax.config.update("jax_enable_compilation_cache", cache_was_on)
         compilation_cache.reset_cache()
+
+
+def random_bits(jaxpr, in_loop=False, found=None):
+    """``[outside every loop, inside one]``: the equations of a jaxpr that
+    draw random bits, wherever they are nested (a ``fori_loop`` is a
+    ``scan`` where its trip count is concrete, else a ``while``)."""
+    found = [0, 0] if found is None else found
+    for eqn in jaxpr.eqns:
+        found[in_loop] += eqn.primitive.name == "random_bits"
+        inside = in_loop or eqn.primitive.name in ("while", "scan")
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    random_bits(sub, inside, found)
+    return found
 
 
 def reference_phase_map(text, scopes=DEVICE_SCOPES):

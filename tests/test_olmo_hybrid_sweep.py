@@ -89,6 +89,14 @@ def test_the_row_counts_the_lanes_and_how_the_scan_ran(swept):
     assert gauges["sweep.lane.lane_steps"] == 27
 
 
+def test_a_device_the_draw_does_not_fit_draws_every_evaluation(swept):
+    """The fixture's device holds one lane and a byte: the unit draw of the
+    initial weights does not fit beside it, and the row says who drew."""
+    opt, _ = swept
+    assert opt.run_stats[-1]["init_draws"] == 13
+    assert obs.get_metrics().snapshot()["gauges"]["sweep.lane.init_draws"] == 13
+
+
 def test_the_lane_names_its_parts_inside_the_trainer(swept):
     (phases,) = sweep_phase_maps().values()
     (parts,) = sweep_phase_maps(LANE_SCOPES).values()

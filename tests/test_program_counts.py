@@ -691,6 +691,76 @@ def test_slo_replay_of_a_served_wave_is_identical(slo):
     assert slo["offline"] == slo["live"]
 
 
+# ------------------------------------- the draws of a lane's initial weights
+@pytest.fixture(scope="module")
+def lane_brackets():
+    """The small LFM2 lane's bracket of 9, 3, 1 lanes through ``FusedBOHB``,
+    its 13 evaluations one loop, on a device that holds the unit draw of the
+    initial weights beside a lane and on one that does not: ``{how: (where
+    the bracket's jaxpr draws its random bits, the chunk's row, every run's
+    loss)}``. A new evaluation object a sweep: the executable's key holds
+    its identity, not the device's bytes."""
+    import sys
+
+    from hpbandster_tpu.ops import fused as fused_ops
+    from hpbandster_tpu.workloads import lfm2
+
+    from lane_names import random_bits
+    from lfm2_small import SMALL, load
+
+    sys.modules.setdefault("program", load("program.py"))
+    cfg = load("configs", "lfm2-sgd.py").lane_config(SMALL)._replace(attn_query_block=16)
+    patch, out = pytest.MonkeyPatch(), {}
+    try:
+        for how, lanes, spare in (("held", 2, -1), ("drawn", 1, 1)):
+            eval_fn = lfm2.make_lfm2_eval_fn(cfg, data_seed=SMALL["data_seed"])
+            # a byte short of two lanes: one lane and its draw; a byte over one
+            patch.setattr(fused_ops, "_device_memory_bytes",
+                          lambda room=lanes * eval_fn.lane_facts.bytes + spare: room)
+            places = random_bits(jax.make_jaxpr(lambda v: fused_ops.fused_sh_bracket(
+                eval_fn, v, (9, 3, 1), (1.0, 3.0, 9.0)))(jax.numpy.zeros((9, 4))).jaxpr)
+            opt = FusedBOHB(configspace=lfm2.lfm2_space(seed=11), eval_fn=eval_fn,
+                            run_id="draw", min_budget=1, max_budget=9, eta=3, seed=11)
+            result = opt.run(n_iterations=1)
+            out[how] = (places, opt.run_stats[-1], sorted(
+                (r.config_id, r.budget, r.loss) for r in result.get_all_runs()))
+            out[how + ".gauge"] = obs.get_metrics().snapshot()["gauges"]["sweep.lane.init_draws"]
+    finally:
+        patch.undo()
+    return out
+
+
+def test_a_bracket_in_turn_draws_outside_its_loop_where_the_draw_fits(lane_brackets):
+    """The program's own count: the random bits of the lanes' initial
+    weights are drawn before the loop over the bracket's 13 evaluations and
+    nowhere inside it; on a device that cannot hold the draw beside a lane
+    they are drawn inside, an evaluation each."""
+    (outside, inside), row, _ = lane_brackets["held"]
+    assert outside > 0 and inside == 0
+    assert (row["evaluations"], row["lanes_at_once"], row["init_draws"]) == (13, 1, 1)
+    (outside, inside), row, _ = lane_brackets["drawn"]
+    assert outside == 0 and inside > 0
+    assert (row["evaluations"], row["lanes_at_once"], row["init_draws"]) == (13, 1, 13)
+    assert (lane_brackets["held.gauge"], lane_brackets["drawn.gauge"]) == (1, 13)
+
+
+def test_a_draw_handed_over_changes_a_loss_in_its_last_bits(lane_brackets):
+    """The same sweep either way: the first rung's configurations and
+    budgets, and its losses to what a step makes of the last two bits of
+    the initial weights (an evaluation that draws and scales in one fusion
+    has the compiler fold the draw's last factor into the scale)."""
+    import statistics
+
+    held, drawn = lane_brackets["held"][2], lane_brackets["drawn"][2]
+    assert len(held) == 13 == len(drawn)
+    assert sorted(budget for _, budget, _ in held) == sorted(budget for _, budget, _ in drawn)
+    first = [[run for run in runs if run[1] == 1] for runs in (held, drawn)]
+    assert [run[0] for run in first[0]] == [run[0] for run in first[1]] and len(first[0]) == 9
+    assert statistics.median(
+        abs(got - want) / abs(want)
+        for (_, _, got), (_, _, want) in zip(*first)) < 1e-4
+
+
 # ------------------------------------------------------ the executable key
 def test_sweep_key_reads_no_environment(monkeypatch):
     """What selects a program is an argument of the driver, so the key of
