@@ -19,6 +19,18 @@ the recurrence of the state over chunks and the backward rule
 (:func:`_chunks_backward`, whose ``jax.vjp`` of the chunk-local part picks
 the form up with it) are shared. ``d_v`` is read from ``v``: ``d_k`` and
 ``d_v`` need not be equal.
+
+**The solve is products** (:func:`_inverse_and_solved`): a chunk's system is
+``I + N`` with ``N`` strictly lower, and its inverse the doubled block
+inverse of ``ops/pallas_triangular.py`` (``[[X, 0], [-Z Y X, Z]]`` from blocks
+of one row up: forward substitution's arithmetic in another order, whatever
+the chunk's length), in one kernel that holds a tile of systems in VMEM where
+Mosaic compiles it and the shapes fit, as plain batched products elsewhere.
+The forward keeps the inverse beside the solved rows, and the backward's
+transposed solve is one product with it. Every product that makes or
+applies the inverse, in the kernel, in the plain form and in the backward,
+has float32 operands and sums (:data:`_EXACT`): the solved rows and their
+pull-back stand where ``solve_triangular``'s stood, at float32's rounding.
 """
 
 from __future__ import annotations
@@ -29,10 +41,16 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from hpbandster_tpu.ops import pallas_triangular
 from hpbandster_tpu.workloads import lane
 from hpbandster_tpu.workloads.lane import _FLOAT32, _einsum
 
-__all__ = ["delta_rule_chunked"]
+__all__ = ["delta_rule_chunked", "solve_counters"]
+
+#: the products that make or apply a system's inverse: six bfloat16 passes a
+#: float32 product on the chip (``lane._FLOAT32``'s three leave the solved rows
+#: and ``d rhs`` 60 to 90 times further from float64 than substitution does)
+_EXACT = jax.lax.Precision.HIGHEST
 
 
 def _init_leaf(key, name: str, shape, init_scale):
@@ -86,14 +104,20 @@ def _chunk_products(q, k, g, sub: int):
     below = jnp.tril(jnp.ones((r, r), bool), -1)[:, :, None, None]
     right = kb[..., None, :, :, :] * jnp.exp(jnp.where(                 # [..., b, b', j, d]
         below, g_star[..., :, None, None, :] - gb[..., None, :, :, :], -jnp.inf))
-    # the rows of k and of q in one product: [..., b, 2 sub, b', j]
+    # the rows of k and of q in one product against all the chunk's columns
+    # at once, [..., b, 2 sub, C]: a row of 64 x 64 blocks is then a row of
+    # the chunk's matrix as it lies in memory, and (b, i) -> C below moves
+    # nothing (blocks of [b, i, b', j] the compiler holds with the chunks in
+    # the lanes and copies twice to hand the solve a matrix: 0.8 ms a layer)
+    right = right.reshape(right.shape[:-3] + (c, d))
     off = jnp.einsum(
-        "...bic,...bdjc->...bidj", jnp.concatenate([kb * left, qb * left], -2), right,
+        "...bic,...bjc->...bij", jnp.concatenate([kb * left, qb * left], -2), right,
         precision=_FLOAT32)
     eye = jnp.eye(r, dtype=jnp.float32)[:, None, :, None]               # [b, 1, b', 1]
     whole = lambda diag, off: (
-        diag[..., :, :, None, :] * eye + off).reshape(q.shape[:-2] + (c, c))
-    return (whole(a_diag, off[..., :sub, :, :]), whole(p_diag, off[..., sub:, :, :]))
+        (diag[..., :, :, None, :] * eye).reshape(diag.shape[:-1] + (c,)) + off
+    ).reshape(q.shape[:-2] + (c, c))
+    return whole(a_diag, off[..., :sub, :]), whole(p_diag, off[..., sub:, :])
 
 
 def _head_products(q, k, g):
@@ -135,14 +159,60 @@ def _chunk_local(q, k, v, log_a, beta, sub: int):
     return system, rhs, p, q_start, k_end, keep
 
 
+def _solve_in_vmem(systems: int, chunk: int, width: int) -> bool:
+    """Whether ``systems`` systems of ``chunk`` rows against right-hand sides
+    ``width`` wide go through the kernel (``ops/pallas_triangular.py``: a
+    tile of systems in VMEM, the inverse formed there): where Mosaic
+    compiles it, on a TPU backend, and the shapes fit its tiles; elsewhere
+    the same inverse as plain batched products, which is also what the kernel
+    is tested against. The backend and the shapes decide: nothing here reads
+    a model."""
+    return lane.pallas_available() and pallas_triangular.fits(systems, chunk, width)
+
+
+def solve_counters(t: int, h: int, dk: int, dv: int, chunk: int):
+    """The static fact of how a lane of ``t`` positions and ``h`` heads solves
+    its chunks' systems, beside its counted ones: 1 where the kernel does (on
+    the chip at the published sizes), 0 where the plain products do."""
+    return (("delta_solve_in_vmem",
+             float(_solve_in_vmem(-(-t // chunk) * h, chunk, dk + dv))),)
+
+
+def _inverse_and_solved(system, rhs):
+    """``(system^-1, system^-1 rhs)`` of the chunks' unit lower triangular
+    systems f32[n, H, C, C] against ``rhs`` f32[n, H, C, R], by products:
+    the doubled block inverse (``pallas_triangular.blocked_inverse``), exact
+    as forward substitution is, in the kernel or plain
+    (:func:`_solve_in_vmem`); the inverse is applied at :data:`_EXACT` either
+    way."""
+    n, h, chunk, width = rhs.shape
+    if _solve_in_vmem(n * h, chunk, width):
+        inverse, solved = pallas_triangular.inverse_and_solved(
+            system.reshape(n * h, chunk, chunk), rhs.reshape(n * h, chunk, width))
+        return inverse.reshape(system.shape), solved.reshape(rhs.shape)
+    inverse = pallas_triangular.blocked_inverse(system, chunk)
+    return inverse, jnp.matmul(inverse, rhs, precision=_EXACT)
+
+
+def _solve_pulled_back(inverse, solved, d_solved):
+    """``(d system, d rhs)`` of ``solved = system^-1 rhs``: the transposed
+    solve is one product with the inverse the forward kept, ``d rhs =
+    system^-T d solved`` at :data:`_EXACT` as the forward's, and ``d system =
+    -d rhs solved^T`` (what of it lies on or above the diagonal meets a
+    constant)."""
+    d_rhs = jnp.einsum("nhji,nhjr->nhir", inverse, d_solved, precision=_EXACT)
+    return -jnp.einsum("nhiv,nhjv->nhij", d_rhs, solved, precision=_FLOAT32), d_rhs
+
+
 def _chunks_forward(q, k, v, log_a, beta, sub: int, scope: str, kept: bool):
     """The chunks' outputs f32[n, H, C, d_v] and, where ``kept``, what the
-    backward rule reads beside the inputs: the solved rows, the state every
-    chunk starts with and its corrected values ``u``."""
+    backward rule reads beside the inputs: the systems' inverses and the
+    solved rows (:func:`_inverse_and_solved`), the state every chunk starts
+    with and its corrected values ``u``."""
     _, h, chunk, dk = q.shape
     dv = v.shape[-1]
     system, rhs, p, q_start, k_end, keep = _chunk_local(q, k, v, log_a, beta, sub)
-    solved = jax.scipy.linalg.solve_triangular(system, rhs, lower=True)
+    inverse, solved = _inverse_and_solved(system, rhs)
     w_v, w_k = solved[..., :dv], solved[..., dv:]
 
     def one_chunk(state, xs):
@@ -159,7 +229,7 @@ def _chunks_forward(q, k, v, log_a, beta, sub: int, scope: str, kept: bool):
     if not kept:
         return out
     out, starts, u = out
-    return out, (q, k, v, log_a, beta, solved, starts, u)
+    return out, (q, k, v, log_a, beta, inverse, solved, starts, u)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
@@ -183,19 +253,18 @@ def _chunks_backward(sub: int, scope: str, kept, dout):
     Only ``du`` and ``dS0`` need the chunk after: they are the scan, two
     products a chunk; the other five are products over all chunks at once,
     before it (``P^T dout``) and after. Then the solve's transpose, one
-    solve with the transposed system (``d rhs = system^-T d solved``,
-    ``d system = -d rhs solved^T``: what of it lies on or above the diagonal
-    meets a constant), and JAX's own pull-back of :func:`_chunk_local`,
+    product with the inverse that the forward kept
+    (:func:`_solve_pulled_back`), and JAX's own pull-back of :func:`_chunk_local`,
     whose inside is computed again here from the inputs, in the form that
     ``log_a``'s shape says (with a gate a head ``dkeep`` is summed over the
     state's rows too). The rule names its own scopes (``scope``: the
     caller's part, ``lane.kda`` or ``lane.gdn``): it is traced where the
     layer's caller has none."""
-    q, k, v, log_a, beta, solved, starts, u = kept
+    q, k, v, log_a, beta, inverse, solved, starts, u = kept
     chunk, dv = q.shape[2], v.shape[-1]
     with jax.named_scope(scope):
         with jax.named_scope("pass.recompute"):
-            (system, _, p, q_start, k_end, keep), local_back = jax.vjp(
+            (_, _, p, q_start, k_end, keep), local_back = jax.vjp(
                 functools.partial(_chunk_local, sub=sub), q, k, v, log_a, beta)
         rows = jnp.concatenate([solved[..., dv:], q_start], 2)
         du_own = _einsum("nhij,nhiv->nhjv", p, dout)
@@ -216,9 +285,8 @@ def _chunks_backward(sub: int, scope: str, kept, dout):
         dkeep = jnp.sum(starts * d_after, -1, keepdims=True)
         if keep.shape[-2] == 1:
             dkeep = jnp.sum(dkeep, -2, keepdims=True)
-        d_rhs = jax.scipy.linalg.solve_triangular(
-            system, jnp.concatenate([du, d_rows[:, :, :chunk]], -1), lower=True, trans=1)
-        d_system = -jnp.einsum("nhiv,nhjv->nhij", d_rhs, solved, precision=_FLOAT32)
+        d_system, d_rhs = _solve_pulled_back(
+            inverse, solved, jnp.concatenate([du, d_rows[:, :, :chunk]], -1))
         return local_back((d_system, d_rhs, dp, d_rows[:, :, chunk:], dk_end, dkeep))
 
 
@@ -235,7 +303,8 @@ def delta_rule_chunked(q, k, v, log_a, beta, chunk: int, sub: int = None, *,
     the running sum of ``log_a`` and ``u_i`` the delta rule's corrected
     values, ``(I + diag(beta) tril(A, -1)) U = diag(beta) (V - (K exp G)
     S_0)`` where ``A_ij = sum_c k_ic k_jc exp(G_ic - G_jc)``: one triangular
-    solve gives ``U`` from the state the chunk starts with (the WY form),
+    system, inverted by products (:func:`_inverse_and_solved`: exact, as
+    substitution is), gives ``U`` from the state the chunk starts with (the WY form),
     then ``o_i = (q_i exp G_i) S_0 + sum_{j<=i} P_ij u_j`` with ``P`` as
     ``A`` with ``q`` on the left, and ``S_C = diag(exp G_C) S_0 + (K exp(G_C
     - G))^T U``. Every exponent is a difference that is never positive, so
@@ -250,7 +319,8 @@ def delta_rule_chunked(q, k, v, log_a, beta, chunk: int, sub: int = None, *,
 
     Its gradient is a rule of its own (:func:`_chunks_backward`: the scan
     from the last chunk to the first written out, the solve's transpose one
-    solve), not what JAX makes of the scan and the solve; ``scope`` is the
+    product with the kept inverse), not what JAX makes of the scan and the
+    solve; ``scope`` is the
     caller's part (``lane.kda``, ``lane.gdn``: the ``jax.named_scope`` it
     calls this under), which the rule names again."""
     t, h, _ = q.shape

@@ -47,9 +47,12 @@ Departures from the published description, all ``assumed`` in
   (``workloads/delta_rule.py``, the linear-attention lanes' one scan, in
   its form of a gate a channel); its gradient is a backward rule of its own
   (``delta_rule._chunks_backward``, a ``jax.custom_vjp``: the scan from the
-  last chunk to the first written out, the solve's transpose one solve,
-  JAX's own pull-back only for the part that needs no state); no fused
-  kernel;
+  last chunk to the first written out, JAX's own pull-back only for the
+  part that needs no state); a chunk's triangular system is inverted by
+  products, exactly, in one kernel that holds a tile of systems in VMEM
+  (``ops/pallas_triangular.py``; plain products off the chip), and the
+  solve's transpose is a product with the kept inverse; the rest of the scan
+  is plain JAX, no fused kernel;
 * the router's 2304 x 256 product keeps float32 operands (three
   bfloat16 passes): its top-8 is a discrete choice that bfloat16 operands
   would flip;
@@ -65,7 +68,8 @@ import jax
 import jax.numpy as jnp
 
 from hpbandster_tpu.workloads import lane
-from hpbandster_tpu.workloads.delta_rule import _init_leaf, _l2norm, delta_rule_chunked
+from hpbandster_tpu.workloads.delta_rule import (
+    _init_leaf, _l2norm, delta_rule_chunked, solve_counters)
 from hpbandster_tpu.workloads.lane import (  # noqa: F401 - the lane's public names
     LANE_COUNTERS,
     _FLOAT32,
@@ -334,7 +338,9 @@ def make_kimi_linear_eval_fn(cfg: KimiLinearConfig = KimiLinearConfig(),
     counters: :data:`LANE_COUNTERS` from the device, then
     ``lane.expert_layer_counters``, how the expert layer moves its rows and
     whether its products are the grouped kernels', then
-    :data:`KDA_COUNTERS`, how KDA is differentiated."""
+    :data:`KDA_COUNTERS`, how KDA is differentiated, and
+    ``delta_rule.solve_counters``, whether its chunks' systems are solved in
+    VMEM."""
     init_key = jax.random.key(data_seed + 1)
     layers = _layers(cfg)
     return lane.make_lane_eval_fn(
@@ -348,4 +354,6 @@ def make_kimi_linear_eval_fn(cfg: KimiLinearConfig = KimiLinearConfig(),
             cfg.seq_len * cfg.num_experts_per_token),
         static_counters=lane.expert_layer_counters(
             cfg.seq_len * cfg.num_experts_per_token, cfg.hidden_size,
-            cfg.moe_intermediate_size) + KDA_COUNTERS)
+            cfg.moe_intermediate_size) + KDA_COUNTERS + solve_counters(
+                cfg.seq_len, cfg.num_heads, cfg.kda_head_dim, cfg.kda_head_dim,
+                cfg.kda_chunk))

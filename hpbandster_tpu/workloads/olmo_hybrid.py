@@ -46,7 +46,8 @@ import jax
 import jax.numpy as jnp
 
 from hpbandster_tpu.workloads import lane
-from hpbandster_tpu.workloads.delta_rule import _init_leaf, _l2norm, delta_rule_chunked
+from hpbandster_tpu.workloads.delta_rule import (
+    _init_leaf, _l2norm, delta_rule_chunked, solve_counters)
 from hpbandster_tpu.workloads.lane import (  # noqa: F401 - the lane's public names
     _causal_conv,
     _mm,
@@ -226,8 +227,10 @@ def make_olmo_hybrid_eval_fn(cfg: OlmoHybridConfig = OlmoHybridConfig(),
     momentum-SGD steps of one ``seq_len``-token sequence);
     ``eval_fn.lane_facts`` states its footprint, its tokens a step and its
     counters, static facts all: :data:`GDN_COUNTERS`, how the linear layers'
-    scan is computed and differentiated, and ``lane.attention_counters``,
-    whether the full layer's scores stay in VMEM."""
+    scan is computed and differentiated, ``delta_rule.solve_counters``,
+    whether its chunks' systems are solved in VMEM, and
+    ``lane.attention_counters``, whether the full layer's scores stay in
+    VMEM."""
     init_key = jax.random.key(data_seed + 1)
     visits, exits = _model(cfg)
     return lane.make_lane_eval_fn(
@@ -236,5 +239,7 @@ def make_olmo_hybrid_eval_fn(cfg: OlmoHybridConfig = OlmoHybridConfig(),
         data=make_token_dataset(jax.random.key(data_seed), cfg),
         lane_bytes=olmo_hybrid_lane_bytes(cfg),
         counted=lane.Counted((), lambda *_: []),
-        static_counters=GDN_COUNTERS + lane.attention_counters(
+        static_counters=GDN_COUNTERS + solve_counters(
+            cfg.seq_len, cfg.linear_num_heads, cfg.linear_key_head_dim,
+            cfg.linear_value_head_dim, cfg.gdn_chunk) + lane.attention_counters(
             cfg.seq_len, cfg.head_dim, cfg.num_heads // cfg.num_kv_heads, cfg.num_kv_heads))

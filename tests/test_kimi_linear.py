@@ -261,14 +261,19 @@ def test_the_rules_five_gradients(reference, monkeypatch, length, oracle, operan
     makes of the plain body (the same operands) and against the
     token-by-token recurrence (float32), over lengths padded and whole, of
     one chunk and of several; each within ``limit`` of the gradient's
-    largest entry. The values are the plain body's bit for bit."""
+    largest entry. The values are the plain body's within float32 rounding
+    (four units in the last place of the largest: the plain body solves a
+    chunk's system by ``solve_triangular``'s substitution, the rule inverts
+    it by products, ``delta_rule._inverse_and_solved``)."""
     monkeypatch.setattr(K.lane, "_OPERAND", operand)
     chunk = 16
     x = _kda_inputs(length, chunk=chunk)
     weights = jax.random.normal(jax.random.key(length), x[2].shape)
     ours = lambda *x: (K.kda_chunked(*x, chunk) * weights).sum()
     if oracle == "plain_body":
-        assert bool((K.kda_chunked(*x, chunk) == _plain_kda_chunked(*x, chunk)).all())
+        plain = _plain_kda_chunked(*x, chunk)
+        np.testing.assert_allclose(
+            K.kda_chunked(*x, chunk), plain, atol=2.0 ** -21 * float(jnp.abs(plain).max()))
         theirs = lambda *x: (_plain_kda_chunked(*x, chunk) * weights).sum()
     else:
         theirs = lambda q, k, v, g, b: (
@@ -319,16 +324,18 @@ def test_the_gradient_of_kda_is_the_rules_and_no_transposed_scan():
 
 def test_the_rule_keeps_less_than_half_of_what_jax_kept():
     """What the forward hands the backward (``jax.eval_shape`` of
-    ``jax.vjp``'s pull-back, every array counted): the inputs, the solved
-    rows, the chunks' starting states and ``u``, under half the bytes that
-    ``jax.vjp`` of the plain body keeps (24 arrays: 1.05 MB here, 1.14 GB
-    a layer at the cell's shape, where the rule's eight are 0.60 GB)."""
+    ``jax.vjp``'s pull-back, every array counted): the inputs, the systems'
+    inverses (since PR 49: 16 KB a chunk and head, 34 MB a layer at the
+    cell's shape), the solved rows, the chunks' starting states and ``u``,
+    under half the bytes that ``jax.vjp`` of the plain body keeps (24 arrays:
+    1.05 MB here, 1.14 GB a layer at the cell's shape, where the rule's nine
+    are 0.63 GB)."""
     def kept(kda):
         _, pull = jax.eval_shape(lambda *x: jax.vjp(_through(kda), *x), *_mechanism_args())
         return [int(np.prod(x.shape)) * x.dtype.itemsize for x in jax.tree.leaves(pull)]
 
     ours, plain = kept(K.kda_chunked), kept(_plain_kda_chunked)
-    assert len(plain) == 24 and len(ours) == 8
+    assert len(plain) == 24 and len(ours) == 9
     assert sum(ours) < 0.5 * sum(plain)
 
 
@@ -562,6 +569,7 @@ def test_lane_counts_agree_with_the_lane(reference):
     facts = K.make_kimi_linear_eval_fn(
         K.KimiLinearConfig(seq_len=64, n_train=2, n_val=1)).lane_facts
     assert facts.counters == K.LANE_COUNTERS + (
-        "moe_combine_by_gather", "moe_products_in_vmem", "kda_backward_by_rule")
+        "moe_combine_by_gather", "moe_products_in_vmem", "kda_backward_by_rule",
+        "delta_solve_in_vmem")
     assert facts.tokens_per_step == 64
     assert 12 * n_params < K.kimi_linear_lane_bytes(K.KimiLinearConfig()) < 16.9e9

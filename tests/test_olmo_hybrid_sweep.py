@@ -82,7 +82,10 @@ def test_the_row_counts_the_lanes_and_how_the_scan_ran(swept):
     # the counters are the model's: no experts, no loop, and not KDA's
     assert not [name for name in row if name.startswith(("moe_", "kda_", "loop_"))]
     assert opt.eval_fn.lane_facts.counters == (
-        "gdn_gate_per_head", "gdn_backward_by_rule", "attn_scores_in_vmem")
+        "gdn_gate_per_head", "gdn_backward_by_rule", "delta_solve_in_vmem",
+        "attn_scores_in_vmem")
+    # off the chip the chunks' systems are inverted by plain products
+    assert row["delta_solve_in_vmem"] == 0
     gauges = obs.get_metrics().snapshot()["gauges"]
     assert gauges["sweep.lane.gdn_gate_per_head"] == 1.0
     assert gauges["sweep.lane.gdn_backward_by_rule"] == 1.0
@@ -124,6 +127,21 @@ def test_the_lane_names_its_parts_inside_the_trainer(swept):
     assert len(in_rule) > 20 and {parts.get(name) for name in in_rule} == {"lane.gdn"}
     solves = [n for n in parts if "triangular" in n or "solve" in n]
     assert all(parts[n] == "lane.gdn" for n in solves)
+    # since PR 49 no solve is left: a chunk's system is inverted by products
+    # (``ops/pallas_triangular.py``; off the chip plain ones, on it one kernel,
+    # which ``tests/test_tpu_aot.py`` finds under ``lane.gdn`` as well), and
+    # their operations are the part's too, in the forward pass and in the
+    # step's (what the backward rule reads is kept by the rule's forward)
+    assert not re.search(r'op_name="[^"]*triangular_solve|custom_call_target="[^"]*(?:trsm|[Tt]riang)', text)
+    (passes,) = sweep_phase_maps(PASS_SCOPES).values()
+    inverted = [re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = ", line).group(1)
+                for line in text.splitlines()
+                if " dot(" in line and "operand_precision={highest,highest}" in line]
+    # chunks of 16: six products a layer and pass make the inverse, one
+    # applies it and one the backward's transpose, and nothing else in the
+    # lane multiplies at ``Precision.HIGHEST``
+    assert len(inverted) >= 18 and {parts.get(name) for name in inverted} == {"lane.gdn"}
+    assert {passes.get(name) for name in inverted} >= {"pass.forward", "pass.recompute"}
 
 
 def test_the_trainer_names_its_passes(swept):

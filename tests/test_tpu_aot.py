@@ -395,6 +395,36 @@ def test_attention_under_the_block_diffusion_rule_compiles_for_v5e_with_the_kern
     assert max(size for size in _f32_sizes(text) if size != rows * g * r * d) == widest
 
 
+@pytest.mark.parametrize("cell, chunks, heads, width", [
+    ("kimi-linear-sgd", 64, 32, 128 + 128), ("olmo-hybrid-sgd", 32, 30, 96 + 192)])
+def test_the_delta_rules_systems_compile_for_v5e_as_one_kernel(
+        v5e_devices, mosaic_compiles_here, cell, chunks, heads, width):
+    """Both cells' chunk systems (64 x 64, one a chunk and head, against
+    ``[V, K exp G]``) through ``delta_rule._inverse_and_solved``: Mosaic takes
+    the kernel at these shapes (two systems side by side in a tile of lanes,
+    a right-hand side of 288 lanes too), the program holds it once under the
+    caller's part and no triangular solve, and the kernel asks for no more
+    VMEM than its shapes say."""
+    import re
+
+    from hpbandster_tpu.ops import pallas_triangular
+    from hpbandster_tpu.workloads import delta_rule
+
+    one = SingleDeviceSharding(v5e_devices[0])
+    assert pallas_triangular.fits(chunks * heads, 64, width)
+    assert pallas_triangular._vmem_bytes(64, width) < 16 * 2 ** 20
+
+    def solve(system, rhs):
+        with jax.named_scope("lane.gdn"):
+            return delta_rule._inverse_and_solved(system, rhs)
+
+    text = jax.jit(solve).lower(
+        _sds((chunks, heads, 64, 64), jnp.float32, one),
+        _sds((chunks, heads, 64, width), jnp.float32, one)).compile().as_text()
+    assert _kernel_parts(text) == [("delta_inverse_and_solved", "lane.gdn")]
+    assert not re.search(r'op_name="[^"]*triangular_solve|custom_call_target="[^"]*(?:trsm|[Tt]riang)', text)
+
+
 def test_a_gated_deltanet_layer_compiles_for_v5e_at_the_published_size(
         v5e_devices, mosaic_compiles_here):
     """The Olmo-Hybrid lane's linear layer (``workloads/olmo_hybrid.py``: the
@@ -402,8 +432,10 @@ def test_a_gated_deltanet_layer_compiles_for_v5e_at_the_published_size(
     once a head, and the SwiGLU, each under the norm that follows it) at
     2,048 tokens, forward and backward pass: the chip's compiler takes it;
     the scan's form of a gate a head is plain JAX under ``lane.gdn`` in both
-    passes, the backward rule (``delta_rule._chunks_backward``) naming the
-    part itself; a chunk's decays are ``[chunks, heads, 64, 64]`` arrays and
+    passes but for the chunks' systems, which one kernel inverts and solves
+    in VMEM (``ops/pallas_triangular.py``, booked under ``lane.gdn`` too; no
+    triangular solve is left), the backward rule
+    (``delta_rule._chunks_backward``) naming the part itself; a chunk's decays are ``[chunks, heads, 64, 64]`` arrays and
     no array carries the per-channel form's blocks (no ``16 x 16 x 96``); no
     float32 array is larger than the gradient of the SwiGLU's gate and up
     side by side (3,840 x 22,016). The
@@ -432,7 +464,8 @@ def test_a_gated_deltanet_layer_compiles_for_v5e_at_the_published_size(
     text = jax.jit(both_passes("gdn")).lower(x, leaves("gdn"), x).compile().as_text()
     print("a linear layer's forward and backward pass compiled for v5e in %.1f s"
           % (time.perf_counter() - t0))
-    assert _kernel_parts(text) == []
+    assert _kernel_parts(text) == [("delta_inverse_and_solved", "lane.gdn")]
+    assert not re.search(r'op_name="[^"]*triangular_solve|custom_call_target="[^"]*(?:trsm|[Tt]riang)', text)
     assert "lane.gdn" in text and "transpose(jvp(lane.gdn))" in text
     # the rule's own scan and solve, named by the rule
     assert re.search(r'op_name="[^"]*jvp\(lane\.gdn\)\)?/lane\.gdn/while', text)
