@@ -112,3 +112,12 @@ from hpbandster_tpu.workloads.olmo_hybrid import (  # noqa: F401
     olmo_hybrid_loss,
     olmo_hybrid_space,
 )
+from hpbandster_tpu.workloads.laguna import (  # noqa: F401
+    LagunaConfig,
+    init_laguna_params,
+    laguna_forward,
+    laguna_lane_bytes,
+    laguna_loss,
+    laguna_space,
+    make_laguna_eval_fn,
+)

@@ -1,6 +1,6 @@
 """What the lanes of published blocks share (``kimi_linear.py``,
-``mellum2.py``, ``ouro.py``, ``lfm2.py``, ``sdar.py``, ``olmo_hybrid.py``): a lane
-is one chip's share of a model of layers, trained
+``mellum2.py``, ``ouro.py``, ``lfm2.py``, ``sdar.py``, ``olmo_hybrid.py``,
+``laguna.py``): a lane is one chip's share of a model of layers, trained
 from the configuration's key by momentum SGD, one sequence a step.
 
 Here live the search space and its decoding, the rule for a matrix
@@ -8,7 +8,10 @@ product's operands, the norm and the SwiGLU, the draw of a leaf, the
 synthetic tokens, embedding and head, rotary positions and **the one
 softmax attention** (:func:`banded_attention`, under :func:`attention_mixer`;
 what a row sees is its rule of sight: :class:`Causal`, with or without a
-window, or :class:`BlockDiffusion`),
+window, or :class:`BlockDiffusion`; a layer's own count of query heads, a
+gate a head and the part of a head that is turned are the layer's leaves'
+and arguments' to say, so a lane's attention layers need not be of one
+shape),
 the gated short convolution (:func:`short_conv_mixer`), **the one expert layer** (:func:`moe_held_experts`: what differs between
 routers is stated as :class:`ExpertLayer`, a bias and a shared expert by
 their leaves) and **the one lane trainer** (:func:`make_lane_eval_fn`: a
@@ -143,10 +146,54 @@ def _swiglu(x, w_gate, w_up, w_down):
 
 
 # ---------------------------------------------------- positions, attention
-def _rotary_tables(inv_freq, factor, positions):
+class Yarn(NamedTuple):
+    """YaRN's stretch of a head's frequencies, as a ``config.json``'s
+    ``rope_parameters`` has it."""
+
+    factor: float
+    original_max_position: int
+    beta_fast: float
+    beta_slow: float
+    attention_factor: float
+
+
+def yarn_correction_range(width: int, theta: float, yarn: Yarn):
+    """``(low, high)`` of YaRN's ramp over ``width`` channels: the channel
+    at which a turn count ``r`` over the original context is reached is
+    ``width ln(L / (2 pi r)) / (2 ln theta)``; ``beta_fast``'s floor and
+    ``beta_slow``'s ceiling."""
+    def channel(turns):
+        return (width * math.log(yarn.original_max_position / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(channel(yarn.beta_fast)), 0)
+    high = min(math.ceil(channel(yarn.beta_slow)), width - 1)
+    return low, (high if high != low else high + 0.001)
+
+
+def rotary_inv_freq(width: int, theta: float, yarn: Optional[Yarn] = None):
+    """``(inv_freq f64[width / 2], factor)`` of the ``width`` channels of a
+    head that a layer turns. Plain RoPE: ``theta^(-2i / width)`` and 1.
+    YaRN: channels that turn more than ``beta_fast`` times over the original
+    context keep their frequency, those that turn less than ``beta_slow``
+    times have it divided by ``factor``, a linear ramp between
+    (:func:`yarn_correction_range`); cos and sin both carry the attention
+    factor."""
+    plain = theta ** (-np.arange(0, width, 2, dtype=np.float64) / width)
+    if yarn is None:
+        return plain, 1.0
+    low, high = yarn_correction_range(width, theta, yarn)
+    ramp = np.clip((np.arange(width // 2, dtype=np.float64) - low) / (high - low), 0.0, 1.0)
+    return (1.0 - ramp) * plain + ramp * plain / yarn.factor, yarn.attention_factor
+
+
+def _rotary_tables(inv_freq, factor, positions, head_dim: Optional[int] = None):
     """``(cos, sin)`` f32[T, head_dim] from a head's ``inv_freq``
-    f64[head_dim / 2]: channel ``i`` turns with ``i + d / 2`` (the
+    f64[rotary / 2]: channel ``i`` turns with ``i + rotary / 2`` (the
     rotate-half form), angles in float32, both tables times ``factor``.
+    ``rotary`` is the whole head unless ``head_dim`` says the head is wider:
+    the channels from ``rotary`` on are not turned, and their columns are 1
+    and 0, without ``factor`` (:func:`_rotate` is told ``rotary`` too).
     ``positions``: a count ``T`` (row ``i`` stands at position ``i``) or
     the rows' own positions f32[T] (:meth:`BlockDiffusion.positions`: two
     rows a position)."""
@@ -155,28 +202,40 @@ def _rotary_tables(inv_freq, factor, positions):
     angle = (positions[:, None]
              * jnp.asarray(inv_freq, jnp.float32)[None, :])
     angle = jnp.concatenate([angle, angle], axis=-1)
-    return jnp.cos(angle) * factor, jnp.sin(angle) * factor
+    cos, sin = jnp.cos(angle) * factor, jnp.sin(angle) * factor
+    if head_dim is None or head_dim == angle.shape[1]:
+        return cos, sin
+    rest = (angle.shape[0], head_dim - angle.shape[1])
+    return (jnp.concatenate([cos, jnp.ones(rest, jnp.float32)], axis=1),
+            jnp.concatenate([sin, jnp.zeros(rest, jnp.float32)], axis=1))
 
 
-def _rotate(x, cos, sin):
-    """``x`` f32[T, ..., d] turned by its position's angles."""
-    half = x.shape[-1] // 2
-    turned = jnp.concatenate([-x[..., half:], x[..., :half]], axis=-1)
+def _rotate(x, cos, sin, rotary: Optional[int] = None):
+    """``x`` f32[T, ..., d] turned by its position's angles: all of a head,
+    or its first ``rotary`` channels (channel ``i`` with ``i + rotary / 2``;
+    the tables hold 1 and 0 for the rest, which stay as they are)."""
+    d = x.shape[-1]
+    half = (rotary or d) // 2
+    turned = jnp.concatenate(
+        [-x[..., half:2 * half], x[..., :half]]
+        + ([jnp.zeros_like(x[..., 2 * half:])] if 2 * half < d else []), axis=-1)
     lift = (slice(None),) + (None,) * (x.ndim - 2)
     return x * cos[lift] + turned * sin[lift]
 
 
-def _rotate_side_by_side(x, cos, sin):
+def _rotate_side_by_side(x, cos, sin, rotary: Optional[int] = None):
     """:func:`_rotate` for ``x`` f32[T, heads x d], the heads side by side
     as a projection leaves them: the same products and sums an entry, with
-    no array of another shape between (a head's halves change places by
-    two turns of the whole row, each entry taking the one that stayed in
-    its head)."""
+    no array of another shape between (the halves of a head's ``rotary``
+    channels change places by two turns of the whole row, each entry taking
+    the one that stayed in its head; what a channel that is not turned
+    takes meets a sine of 0)."""
     d = cos.shape[1]
     heads = x.shape[1] // d
-    first_half = jnp.arange(x.shape[1]) % d < d // 2
-    turned = jnp.where(first_half, -jnp.roll(x, -(d // 2), axis=1),
-                       jnp.roll(x, d // 2, axis=1))
+    half = (rotary or d) // 2
+    first_half = jnp.arange(x.shape[1]) % d < half
+    turned = jnp.where(first_half, -jnp.roll(x, -half, axis=1),
+                       jnp.roll(x, half, axis=1))
     return x * jnp.tile(cos, (1, heads)) + turned * jnp.tile(sin, (1, heads))
 
 
@@ -205,7 +264,21 @@ _SCORES_AT_ONCE = 2 ** 25
 #: masked tiles still under a ``cond``). One query head a key/value head at
 #: 2,048 keys: 512 queries against 512 keys 0.400 / 1.028, 256 queries
 #: 0.447 / 1.089, 1,024 queries 0.612 / 1.434 where 512 read 0.552 / 1.295
-#: (``cond``). One size serves every shape the kernels take
+#: (``cond``). One size serves every shape the kernels take. **A group of 6
+#: query heads (PR 50, the Laguna lane's full layers: 48 heads on 8, 8,192
+#: keys; one layer's scores alone, forward / forward and backward, ms, on the
+#: chip)**: 128 queries x 6 heads = 768 rows against 512 keys 6.68 / 19.16
+#: (the rule's choice: the most queries, a power of two, within the rows);
+#: 64 queries 6.71 / 22.04; 256 queries (1,536 rows) 6.73 / 18.87; 128
+#: against 256 keys 7.92 / 23.26, against 1,024 keys 7.35 / 20.19; the plain
+#: form 49.8 / 63.4 (six heads' scores of a block are a batch off the
+#: softmax's fast path). The same layer at 8 heads a group (64 heads) 8.85 /
+#: 25.21, so a group of 6 costs its 3 / 4 of the rows and no more. That
+#: lane's window layers (64 heads, a window of 512): 128 x 512 2.98 / 7.41,
+#: 256 x 512 2.92 / 7.26, 64 x 512 2.99 / 7.88, **128 x 256 2.64 / 6.78** (a
+#: band half as wide as the Mellum2 lane's would rather walk narrower tiles:
+#: not taken, one size serves), 128 x 1,024 3.93 / 10.08, the plain form
+#: 14.3 / 33.2
 _KERNEL_ROWS = 1024
 _KERNEL_KEYS = 512
 
@@ -396,50 +469,76 @@ def _kernel_tiles(t: int, d: int, heads_per_kv: int, kv_heads: int, sight=None):
     blocks); off the chip the plain form (:func:`banded_attention`), which
     is also what the kernels are tested against. The backend and the shapes
     decide, under either rule alike. A block of queries is sized from the
-    rows a step really holds: a pair's query heads where heads of 64 pair
-    up."""
+    rows a step really holds (a pair's query heads where heads of 64 pair
+    up): the most queries, a power of two, whose rows are within
+    :data:`_KERNEL_ROWS` (a group of 8 query heads 128 queries and 1,024
+    rows; of 6, 128 and 768; of 3, 256 and 768)."""
     if not pallas_available() or t <= _PLAIN_KEYS:
         return None
     keys = min(t, _KERNEL_KEYS)
     heads = pallas_attention.heads_a_step(d, heads_per_kv)
-    tiles = pallas_attention.Tiles(min(keys, max(_KERNEL_ROWS // heads, 16)), keys)
+    queries = 2 ** int(math.log2(max(_KERNEL_ROWS // heads, 16)))
+    tiles = pallas_attention.Tiles(min(keys, queries), keys)
     if not (_rule(sight).whole_tiles(t, tiles) and pallas_attention.fits(
             t, d, heads_per_kv, kv_heads, tiles, np.dtype(_OPERAND).itemsize)):
         return None
     return tiles
 
 
-def attention_key_blocks(t: int, sights, block: int, tiles=None):
+def _a_layer(value, layers: int):
+    """``value`` for each of ``layers`` layers: a list is one a layer
+    already, anything else (a rule of sight and the kernels' tiles are
+    tuples) is every layer's."""
+    if isinstance(value, list):
+        if len(value) != layers:
+            raise ValueError("one entry a layer: %d for %d layers" % (len(value), layers))
+        return value
+    return [value] * layers
+
+
+def attention_key_blocks(t: int, sights, block: int, tiles=None, heads=None):
     """``(computed, square)``: blocks of ``block x block`` scores that
     :func:`banded_attention` computes over layers of the given rules of
     sight (a window or ``None`` each: causal; or a :class:`BlockDiffusion`,
     ``t`` its ``2 S`` rows), and those of their full squares; with the
-    fused kernels' ``tiles`` (:func:`_kernel_tiles`), tiles of ``block_q x
+    fused kernels' ``tiles`` (:func:`_kernel_tiles`; a list: each layer's
+    own, None where a layer takes the plain form), tiles of ``block_q x
     block_k`` that the kernels walk under each rule (a narrower tile counts
-    by its width: a masked block's own keys under :class:`BlockDiffusion`)."""
-    if tiles is not None:
-        return (sum(pallas_attention.tiles_visited(t, _rule(sight), tiles)
-                    for sight in sights),
-                (t // tiles.block_q) * (t // tiles.block_k) * len(sights))
-    per_side = -(-t // block)
-    computed = sum(-(-(khi - klo) // block)
-                   for sight in sights
-                   for _, _, runs in _attention_spans(t, sight, block)
-                   for klo, khi in runs)
-    return computed, per_side * per_side * len(sights)
+    by its width: a masked block's own keys under :class:`BlockDiffusion`).
+    A count is one query head's; ``heads`` (a number a layer) counts each
+    layer as many times: a lane whose layers differ in their heads."""
+    sights = list(sights)
+    weights = _a_layer(1 if heads is None else list(heads), len(sights))
+    computed = square = 0
+    for sight, tile, weight in zip(sights, _a_layer(tiles, len(sights)), weights):
+        if tile is not None:
+            computed += weight * pallas_attention.tiles_visited(t, _rule(sight), tile)
+            square += weight * (t // tile.block_q) * (t // tile.block_k)
+        else:
+            per_side = -(-t // block)
+            computed += weight * sum(
+                -(-(khi - klo) // block)
+                for _, _, runs in _attention_spans(t, sight, block) for klo, khi in runs)
+            square += weight * per_side * per_side
+    return computed, square
 
 
-def attention_counters(t: int, d: int, heads_per_kv: int, kv_heads: int, sight=None):
+def attention_counters(t: int, d: int, heads_per_kv, kv_heads: int, sight=None):
     """The static fact of how a lane's attention is computed, beside its
     counted ones (``make_lane_eval_fn(static_counters=...)``): the share of
-    its attention layers whose scores stay in VMEM (the fused kernels; the
-    layers of a lane are of one shape and one kind of rule, so all of them
-    or none: 1 on the chip at the published sizes of the Mellum2, LFM2 and
-    SDAR lanes, under :class:`Causal` and :class:`BlockDiffusion` alike; 0
-    on a CPU, at the Ouro lane's 2,048 keys and where a shape does not fit
-    the kernels' tiles)."""
-    return (("attn_scores_in_vmem",
-             float(_kernel_tiles(t, d, heads_per_kv, kv_heads, sight) is not None)),)
+    its attention layers whose scores stay in VMEM (the fused kernels).
+    ``heads_per_kv`` and ``sight`` are every layer's, or a list each of one
+    entry a layer where the layers differ in their heads or their rule. A
+    lane whose layers are of one shape reads 1 or 0 (1 on the chip at the
+    published sizes of the Mellum2, LFM2 and SDAR lanes, under
+    :class:`Causal` and :class:`BlockDiffusion` alike; 0 on a CPU, at the
+    Ouro lane's 2,048 keys and where a shape does not fit the kernels'
+    tiles); the Laguna lane's five layers, 48 and 64 heads, each answer for
+    themselves."""
+    layers = max([len(x) for x in (heads_per_kv, sight) if isinstance(x, list)] or [1])
+    in_vmem = [_kernel_tiles(t, d, r, kv_heads, rule) is not None
+               for r, rule in zip(_a_layer(heads_per_kv, layers), _a_layer(sight, layers))]
+    return (("attn_scores_in_vmem", sum(in_vmem) / len(in_vmem)),)
 
 
 def _widest_scores(t: int, sight, block: int) -> int:
@@ -448,20 +547,23 @@ def _widest_scores(t: int, sight, block: int) -> int:
                for lo, hi, runs in _attention_spans(t, sight, block))
 
 
-def attention_alive_bytes(t: int, kv_heads: int, heads_per_kv: int, d: int,
+def attention_alive_bytes(t: int, kv_heads: int, heads_per_kv, d: int,
                           sights, block: int) -> int:
     """Device bytes of attention's own that are alive at once in a layer's
-    backward pass, the largest over layers of the given rules of sight: the
-    plain form's three copies of the scores alive at once; the fused
-    kernels' residuals, the output and a log-sum-exp a row kept across the
-    128 lanes."""
-    def alive(sight):
-        if _kernel_tiles(t, d, heads_per_kv, kv_heads, sight) is not None:
-            return 4 * t * kv_heads * heads_per_kv * (d + 128)
-        widest = heads_per_kv * _widest_scores(t, sight, block)
+    backward pass, the largest over layers of the given rules of sight
+    (``heads_per_kv`` every layer's, or a list of one a layer): the plain
+    form's three copies of the scores alive at once; the fused kernels'
+    residuals, the output and a log-sum-exp a row kept across the 128
+    lanes."""
+    def alive(sight, r):
+        if _kernel_tiles(t, d, r, kv_heads, sight) is not None:
+            return 4 * t * kv_heads * r * (d + 128)
+        widest = r * _widest_scores(t, sight, block)
         return 3 * 4 * widest * max(min(_SCORES_AT_ONCE // widest, kv_heads), 1)
 
-    return max(alive(sight) for sight in sights)
+    sights = list(sights)
+    return max(alive(sight, r)
+               for sight, r in zip(sights, _a_layer(heads_per_kv, len(sights))))
 
 
 def banded_attention(q, k, v, sight, block: int,
@@ -521,6 +623,19 @@ def banded_attention(q, k, v, sight, block: int,
     return out.swapaxes(0, 1)
 
 
+def _across_a_head(gate, d: int):
+    """``gate`` [T, heads] (an operand: :func:`attention_mixer` has rounded
+    it) with each head's number across its ``d`` lanes, f32[T, heads x d],
+    the heads side by side: a product with the heads' 0 / 1 matrix, exact,
+    so that its transpose, the sum of a head's lanes, is a product too and
+    no array ``[T, heads, d]`` stands between. On the
+    chip the repeat and the sum as such read 8.8 ms a window layer and step
+    for the sum alone (PR 50: a twelfth of the sweep over the five layers),
+    and the repeat is a broadcast to three axes and a change of layout."""
+    a_head = jnp.repeat(jnp.eye(gate.shape[1], dtype=_OPERAND), d, axis=1)
+    return _mm(gate, a_head)
+
+
 def _beside(runs, axis: int = 0):
     """The runs of keys one after the other (one run: as it is)."""
     return runs[0] if len(runs) == 1 else jnp.concatenate(runs, axis=axis)
@@ -541,7 +656,19 @@ def attention_mixer(x, p, *, kv_heads: int, heads_per_kv: int, head_dim: int,
     weights and ``norm_eps``, between the projection and the rotation; the
     leaf's shape says over what: f32[head_dim], every head through its own
     norm with the one weight; f32[heads x head_dim], the projection's whole
-    width through one norm, before the heads are split.
+    width through one norm, before the heads are split. A layer whose leaves
+    hold ``w_head_gate`` f32[D, heads] gates its heads: ``sigmoid(x
+    w_head_gate)``, one number a head and position (one more block of columns
+    of the projections' product), multiplies the head's attention output
+    before ``wo`` (not ``w_gate``: that is a dense SwiGLU's leaf, and a layer
+    hands its leaves here whole). The gate's sigmoid is float32 and its
+    value is rounded to the products' operand type once, before the two
+    paths below part, as ``wo``'s operand is: on the kernels' path it goes
+    across a head's lanes by a product (:func:`_across_a_head`), and both
+    paths multiply by the same numbers. An ``inv_freq`` shorter than half a head
+    turns the head's first ``2 len(inv_freq)`` channels alone (channel ``i``
+    with ``i + len(inv_freq)``); the rest pass unturned and without
+    ``factor``.
 
     The attention is :func:`banded_attention` in plain JAX or, where
     :func:`_kernel_tiles` says so, the fused kernels of
@@ -554,7 +681,12 @@ def attention_mixer(x, p, *, kv_heads: int, heads_per_kv: int, head_dim: int,
     where the caller's scope is no longer open."""
     t = x.shape[0]
     g, r, d = kv_heads, heads_per_kv, head_dim
-    q, k, v = _mm_beside(x, p["wq"], p["wk"], p["wv"])
+    gate = p.get("w_head_gate")
+    if gate is None:
+        q, k, v = _mm_beside(x, p["wq"], p["wk"], p["wv"])
+    else:
+        q, k, v, gate = _mm_beside(x, p["wq"], p["wk"], p["wv"], gate)
+        gate = jax.nn.sigmoid(gate).astype(_OPERAND)
     q_norm, k_norm = p.get("q_norm"), p.get("k_norm")
     if q_norm is not None:
         # once, before the two paths part, so that both have it
@@ -564,18 +696,24 @@ def attention_mixer(x, p, *, kv_heads: int, heads_per_kv: int, head_dim: int,
     if inv_freq is None:
         turn = turn_side_by_side = lambda y: y
     else:
-        cos, sin = _rotary_tables(inv_freq, factor, rule.positions(t))
-        turn = functools.partial(_rotate, cos=cos, sin=sin)
-        turn_side_by_side = functools.partial(_rotate_side_by_side, cos=cos, sin=sin)
+        cos, sin = _rotary_tables(inv_freq, factor, rule.positions(t), d)
+        tables = dict(cos=cos, sin=sin, rotary=2 * len(inv_freq))
+        turn = functools.partial(_rotate, **tables)
+        turn_side_by_side = functools.partial(_rotate_side_by_side, **tables)
     tiles = _kernel_tiles(t, d, r, g, rule)
     if tiles is not None:
         out = pallas_attention.fused_banded_attention(
             turn_side_by_side(q), turn_side_by_side(k), v,
             (g, r, d), rule, tiles, _OPERAND, scope)
+        if gate is not None:
+            out = out * _across_a_head(gate, d)
     else:
         out = banded_attention(
             turn(q.reshape(t, g, r, d)), turn(k.reshape(t, g, d)),
-            v.reshape(t, g, d), sight, block).reshape(t, g * r * d)
+            v.reshape(t, g, d), sight, block)
+        if gate is not None:
+            out = out * gate.reshape(t, g, r, 1)
+        out = out.reshape(t, g * r * d)
     return _mm(out, p["wo"])
 
 

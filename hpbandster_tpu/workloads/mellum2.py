@@ -48,12 +48,10 @@ it, no auxiliary loss, no multi-token-prediction head.
 
 from __future__ import annotations
 
-import math
 from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from hpbandster_tpu.workloads import lane
 from hpbandster_tpu.workloads.lane import (  # noqa: F401 - the lane's public names
@@ -149,34 +147,23 @@ def init_mellum2_params(key: jax.Array, cfg: Mellum2Config, init_scale) -> dict:
 
 
 # -------------------------------------------------------------- positions
+def _yarn(cfg: Mellum2Config) -> lane.Yarn:
+    return lane.Yarn(cfg.yarn_factor, cfg.yarn_original_max_position, cfg.yarn_beta_fast,
+                     cfg.yarn_beta_slow, cfg.yarn_attention_factor)
+
+
 def rotary_inv_freq(cfg: Mellum2Config, kind: str):
-    """``(inv_freq f64[head_dim / 2], factor)`` of a layer of ``kind``.
-    A window layer: ``theta^(-2i / d)`` and 1. A full layer (YaRN):
-    channels that turn more than ``beta_fast`` times over the original
-    context keep their frequency, those that turn less than ``beta_slow``
-    times have it divided by ``factor``, a linear ramp between (``low`` and
-    ``high``, the channels where the turns cross the two betas, floor and
-    ceiling); cos and sin both carry the attention factor."""
-    d = cfg.head_dim
-    plain = cfg.rope_theta ** (-np.arange(0, d, 2, dtype=np.float64) / d)
-    if kind == "sliding":
-        return plain, 1.0
-    low, high = yarn_correction_range(cfg)
-    ramp = np.clip((np.arange(d // 2, dtype=np.float64) - low) / (high - low), 0.0, 1.0)
-    return (1.0 - ramp) * plain + ramp * plain / cfg.yarn_factor, cfg.yarn_attention_factor
+    """``(inv_freq f64[head_dim / 2], factor)`` of a layer of ``kind``
+    (``lane.rotary_inv_freq`` over the whole head): a window layer plain
+    RoPE, ``theta^(-2i / d)`` and 1; a full layer YaRN's ramp, cos and sin
+    both carrying the attention factor."""
+    return lane.rotary_inv_freq(
+        cfg.head_dim, cfg.rope_theta, None if kind == "sliding" else _yarn(cfg))
 
 
 def yarn_correction_range(cfg: Mellum2Config):
-    """``(low, high)``: the channel at which a turn count ``r`` over the
-    original context is reached is ``d ln(L / (2 pi r)) / (2 ln theta)``."""
-    def channel(turns):
-        return (cfg.head_dim * math.log(
-            cfg.yarn_original_max_position / (turns * 2 * math.pi))
-            / (2 * math.log(cfg.rope_theta)))
-
-    low = max(math.floor(channel(cfg.yarn_beta_fast)), 0)
-    high = min(math.ceil(channel(cfg.yarn_beta_slow)), cfg.head_dim - 1)
-    return low, (high if high != low else high + 0.001)
+    """``(low, high)`` of the full layers' ramp (``lane.yarn_correction_range``)."""
+    return lane.yarn_correction_range(cfg.head_dim, cfg.rope_theta, _yarn(cfg))
 
 
 def _rotary_tables(cfg: Mellum2Config, kind: str, t: int):

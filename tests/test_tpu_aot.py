@@ -151,6 +151,49 @@ def test_banded_attention_compiles_for_v5e_at_the_published_size(
     assert max(_f32_sizes(text)) == cfg.seq_len * 5120
 
 
+@pytest.mark.parametrize("kind, scope, heads", [
+    ("full", "lane.gqa", 48), ("sliding", "lane.swa", 64)])
+def test_a_group_of_six_query_heads_compiles_for_v5e_with_the_kernels(
+        v5e_devices, mosaic_compiles_here, kind, scope, heads):
+    """The Laguna-XS.2 lane's mixers (``workloads/laguna.py``) at 8,192 keys,
+    forward and backward pass: a full layer's 48 query heads, 6 a key/value
+    head (768 rows a step: no power of two) over heads of which half is
+    turned, and a window layer's 64 under the 512 window, both gated a head.
+    Mosaic takes both, the kernels carry the caller's part, and no float32
+    array of a block's scores exists (the largest is the projections' output
+    with the gate's columns). And ``lane._kernel_tiles`` returns for every
+    shape a cell ran before exactly the tiles it returned then."""
+    from hpbandster_tpu.workloads import laguna as L
+    from hpbandster_tpu.workloads import lane
+
+    one = SingleDeviceSharding(v5e_devices[0])
+    cfg = L.LagunaConfig()
+    r, g, d, t = heads // cfg.num_kv_heads, cfg.num_kv_heads, cfg.head_dim, cfg.seq_len
+    assert dict(cfg.heads_by_kind)[kind] == heads
+    assert lane._kernel_tiles(t, d, r, g, L._sight(cfg, kind)) == (128, 512)
+    # the accepted cells' shapes (Mellum2 / SDAR, LFM2's pairs of 64, the
+    # SDAR rule, one head a key/value head) keep their tiles; few keys the
+    # plain form
+    assert [lane._kernel_tiles(*shape) for shape in (
+        (8192, 128, 8, 4), (8192, 128, 8, 4, 1024), (8192, 64, 4, 8),
+        (8192, 128, 8, 4, lane.BlockDiffusion(4)), (4096, 128, 1, 16),
+        (2048, 128, 1, 16), (2048, 128, 1, 30))] == [(128, 512)] * 4 + [(512, 512), None, None]
+    shapes = L._layer_shapes(cfg, kind, "sparse")
+    leaves = {name: _sds(shapes[name], jnp.float32, one)
+              for name in ("wq", "wk", "wv", "w_head_gate", "wo")}
+    x = _sds((t, cfg.hidden_size), jnp.float32, one)
+
+    def both_passes(x, p, dy):
+        with jax.named_scope(scope):
+            y, pull = jax.vjp(lambda x, p: L._attention(x, p, kind, cfg), x, p)
+        return y, pull(dy)     # pulled back where the caller's scope is closed
+
+    text = jax.jit(both_passes).lower(x, leaves, x).compile().as_text()
+    assert _kernel_parts(text) == [
+        ("banded_attention_backward", scope), ("banded_attention_forward", scope)]
+    assert max(_f32_sizes(text)) == t * (heads * d + 2 * g * d + heads)
+
+
 def test_a_looped_layer_compiles_for_v5e_at_the_published_size(
         v5e_devices, mosaic_compiles_here):
     """The Ouro lane's layer (``workloads/ouro.py``: the lanes' one attention
