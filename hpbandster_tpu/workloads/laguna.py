@@ -178,8 +178,12 @@ def rotary_inv_freq(cfg: LagunaConfig, kind: str):
     yarn = None if kind == "sliding" else lane.Yarn(
         cfg.yarn_factor, cfg.yarn_original_max_position, cfg.yarn_beta_fast,
         cfg.yarn_beta_slow, cfg.yarn_attention_factor)
-    return lane.rotary_inv_freq(
-        int(cfg.head_dim * dict(cfg.rotary_by_kind)[kind]), dict(cfg.theta_by_kind)[kind], yarn)
+    return lane.rotary_inv_freq(_rotary(cfg, kind), dict(cfg.theta_by_kind)[kind], yarn)
+
+
+def _rotary(cfg: LagunaConfig, kind: str) -> int:
+    """The channels of a head that a layer of ``kind`` turns."""
+    return int(cfg.head_dim * dict(cfg.rotary_by_kind)[kind])
 
 
 # ------------------------------------------------------------------ layers
@@ -280,8 +284,8 @@ def make_laguna_eval_fn(cfg: LagunaConfig = LagunaConfig(), data_seed: int = 0):
     counters: :data:`LANE_COUNTERS` from the device (over the four expert
     layers), then :data:`ATTENTION_COUNTERS`, the blocking summed over the
     layers, each counted once a query head of its own (48 or 64: the fused
-    kernels' tiles where they run), ``lane.attention_counters``, the share
-    of the five layers whose scores stay in VMEM, and
+    kernels' tiles where they run), ``lane.attention_counters``, the shares
+    of the five layers whose scores stay in VMEM and whose turn is a kernel, and
     ``lane.expert_layer_counters``."""
     init_key = jax.random.key(data_seed + 1)
     heads, sights = _attention_shapes(cfg)
@@ -299,6 +303,7 @@ def make_laguna_eval_fn(cfg: LagunaConfig = LagunaConfig(), data_seed: int = 0):
         lane_bytes=laguna_lane_bytes(cfg),
         counted=lane.expert_counters([mlp == "sparse" for mlp in cfg.mlp_kinds], choices),
         static_counters=tuple(zip(ATTENTION_COUNTERS, blocks)) + lane.attention_counters(
-            cfg.seq_len, cfg.head_dim, heads, cfg.num_kv_heads, sights
+            cfg.seq_len, cfg.head_dim, heads, cfg.num_kv_heads, sights,
+            [_rotary(cfg, kind) for kind in cfg.layer_kinds]
         ) + lane.expert_layer_counters(
             choices, cfg.hidden_size, cfg.moe_intermediate_size))

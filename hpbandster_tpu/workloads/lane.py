@@ -35,7 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from hpbandster_tpu.ops import pallas_attention, pallas_grouped
+from hpbandster_tpu.ops import pallas_attention, pallas_grouped, pallas_rotary
 from hpbandster_tpu.ops.fused import LaneFacts
 from hpbandster_tpu.ops.pallas_kde import pallas_available
 from hpbandster_tpu.space import ConfigurationSpace, UniformFloatHyperparameter
@@ -223,16 +223,34 @@ def _rotate(x, cos, sin, rotary: Optional[int] = None):
     return x * cos[lift] + turned * sin[lift]
 
 
-def _rotate_side_by_side(x, cos, sin, rotary: Optional[int] = None):
+def _turn_in_vmem(t: int, width: int, d: int, rotary: Optional[int] = None) -> bool:
+    """Whether the turn of ``[t, width]``, heads of ``d`` side by side, is
+    ``ops/pallas_rotary.py``'s kernel: where Mosaic compiles it, on a TPU
+    backend, and the shapes are whole blocks of it (heads of whole tiles of
+    lanes, or of 64 in pairs). The backend and the shapes decide, as they do
+    for the scores (:func:`_kernel_tiles`)."""
+    return pallas_available() and pallas_rotary.fits(t, width, d, (rotary or d) // 2)
+
+
+def _rotate_side_by_side(x, cos, sin, rotary: Optional[int] = None, *, scope: str):
     """:func:`_rotate` for ``x`` f32[T, heads x d], the heads side by side
     as a projection leaves them: the same products and sums an entry, with
-    no array of another shape between (the halves of a head's ``rotary``
-    channels change places by two turns of the whole row, each entry taking
-    the one that stayed in its head; what a channel that is not turned
-    takes meets a sine of 0)."""
+    no array of another shape between. Where :func:`_turn_in_vmem` says so,
+    one kernel (``ops/pallas_rotary.py``): a head's halves change places in
+    VMEM, the tables meet each head there as ``[rows, d]``, and the result
+    is rounded there, once, to the products' operand type, which is what the
+    fused attention kernels would do to it first thing; its device
+    operations, the backward rule's too, are named ``scope`` (the caller's
+    part). Elsewhere the plain form, float32, which the kernel is tested
+    against: the halves of a head's ``rotary`` channels change places by two
+    turns of the whole row, each entry taking the one that stayed in its
+    head (what a channel that is not turned takes meets a sine of 0), and
+    the tables are tiled across the heads."""
     d = cos.shape[1]
-    heads = x.shape[1] // d
     half = (rotary or d) // 2
+    if _turn_in_vmem(x.shape[0], x.shape[1], d, rotary):
+        return pallas_rotary.rotate_side_by_side(x, cos, sin, half, _OPERAND, scope)
+    heads = x.shape[1] // d
     first_half = jnp.arange(x.shape[1]) % d < half
     turned = jnp.where(first_half, -jnp.roll(x, -half, axis=1),
                        jnp.roll(x, half, axis=1))
@@ -523,22 +541,33 @@ def attention_key_blocks(t: int, sights, block: int, tiles=None, heads=None):
     return computed, square
 
 
-def attention_counters(t: int, d: int, heads_per_kv, kv_heads: int, sight=None):
-    """The static fact of how a lane's attention is computed, beside its
-    counted ones (``make_lane_eval_fn(static_counters=...)``): the share of
-    its attention layers whose scores stay in VMEM (the fused kernels).
-    ``heads_per_kv`` and ``sight`` are every layer's, or a list each of one
-    entry a layer where the layers differ in their heads or their rule. A
-    lane whose layers are of one shape reads 1 or 0 (1 on the chip at the
-    published sizes of the Mellum2, LFM2 and SDAR lanes, under
-    :class:`Causal` and :class:`BlockDiffusion` alike; 0 on a CPU, at the
-    Ouro lane's 2,048 keys and where a shape does not fit the kernels'
-    tiles); the Laguna lane's five layers, 48 and 64 heads, each answer for
-    themselves."""
-    layers = max([len(x) for x in (heads_per_kv, sight) if isinstance(x, list)] or [1])
-    in_vmem = [_kernel_tiles(t, d, r, kv_heads, rule) is not None
-               for r, rule in zip(_a_layer(heads_per_kv, layers), _a_layer(sight, layers))]
-    return (("attn_scores_in_vmem", sum(in_vmem) / len(in_vmem)),)
+def attention_counters(t: int, d: int, heads_per_kv, kv_heads: int, sight=None,
+                       rotary=None):
+    """The static facts of how a lane's attention is computed, beside its
+    counted ones (``make_lane_eval_fn(static_counters=...)``):
+    ``attn_scores_in_vmem``, the share of its attention layers whose scores
+    stay in VMEM (the fused kernels), and ``attn_rotation_in_vmem``, the
+    share of its attention layers that turn their heads whose turn, of the
+    queries and of the keys, is ``ops/pallas_rotary.py``'s kernel
+    (:func:`_rotate_side_by_side` on the fused kernels' path; 0 for a lane
+    that turns nothing). ``heads_per_kv``, ``sight`` and ``rotary`` (the
+    channels of a head that a layer turns: None the whole head, 0 a layer
+    without positions) are every layer's, or a list each of one entry a
+    layer where the layers differ. A lane whose layers are of one shape
+    reads 1 or 0 (1 and 1 on the chip at the published sizes of the Mellum2,
+    LFM2 and SDAR lanes, under :class:`Causal` and :class:`BlockDiffusion`
+    alike; 0 and 0 on a CPU, at the Ouro lane's 2,048 keys and where a shape
+    does not fit the kernels' tiles); the Laguna lane's five layers, 48 and
+    64 heads, each answer for themselves."""
+    layers = max([len(x) for x in (heads_per_kv, sight, rotary) if isinstance(x, list)] or [1])
+    shapes = list(zip(_a_layer(heads_per_kv, layers), _a_layer(sight, layers),
+                      _a_layer(rotary, layers)))
+    in_vmem = [_kernel_tiles(t, d, r, kv_heads, rule) is not None for r, rule, _ in shapes]
+    turned = [scores and all(_turn_in_vmem(t, heads * d, d, turns)
+                             for heads in (kv_heads * r, kv_heads))
+              for scores, (r, _, turns) in zip(in_vmem, shapes) if turns != 0]
+    return (("attn_scores_in_vmem", sum(in_vmem) / len(in_vmem)),
+            ("attn_rotation_in_vmem", sum(turned) / max(len(turned), 1)))
 
 
 def _widest_scores(t: int, sight, block: int) -> int:
@@ -674,19 +703,28 @@ def attention_mixer(x, p, *, kv_heads: int, heads_per_kv: int, head_dim: int,
     :func:`_kernel_tiles` says so, the fused kernels of
     ``ops/pallas_attention.py``, with their own backward pass: the heads
     then stay side by side from the projections to ``wo`` (the kernels take
-    them so, the rotary tables are tiled across them), so that no array
-    changes layout on the way. ``scope`` is the caller's part (``lane.swa``,
-    ``lane.gqa``, ``lane.bda``: the ``jax.named_scope`` it calls this
-    under), which the kernels' backward rule has to be told: it is traced
-    where the caller's scope is no longer open."""
+    them so, and :func:`_rotate_side_by_side` turns them so: one kernel
+    where its shapes fit, which hands the attention kernels their operands
+    already rounded), so that no array changes layout on the way. ``scope``
+    is the caller's part (``lane.swa``, ``lane.gqa``, ``lane.bda``: the
+    ``jax.named_scope`` it calls this under), which the kernels' backward
+    rules have to be told: they are traced where the caller's scope is no
+    longer open."""
     t = x.shape[0]
     g, r, d = kv_heads, heads_per_kv, head_dim
     gate = p.get("w_head_gate")
     if gate is None:
         q, k, v = _mm_beside(x, p["wq"], p["wk"], p["wv"])
     else:
-        q, k, v, gate = _mm_beside(x, p["wq"], p["wk"], p["wv"], gate)
-        gate = jax.nn.sigmoid(gate).astype(_OPERAND)
+        # the gate's 48 or 64 columns would leave the product no whole tiles
+        # of lanes wide, and the chip's compiler then holds its result with
+        # the rows in the lanes: every part of it that a kernel takes is
+        # copied out row-major first (1 ms a window layer's queries, under no
+        # scope). Columns of zeros make it whole
+        heads = gate.shape[1]
+        q, k, v, gate = _mm_beside(
+            x, p["wq"], p["wk"], p["wv"], jnp.pad(gate, ((0, 0), (0, -heads % 128))))
+        gate = jax.nn.sigmoid(gate[:, :heads]).astype(_OPERAND)
     q_norm, k_norm = p.get("q_norm"), p.get("k_norm")
     if q_norm is not None:
         # once, before the two paths part, so that both have it
@@ -699,7 +737,7 @@ def attention_mixer(x, p, *, kv_heads: int, heads_per_kv: int, head_dim: int,
         cos, sin = _rotary_tables(inv_freq, factor, rule.positions(t), d)
         tables = dict(cos=cos, sin=sin, rotary=2 * len(inv_freq))
         turn = functools.partial(_rotate, **tables)
-        turn_side_by_side = functools.partial(_rotate_side_by_side, **tables)
+        turn_side_by_side = functools.partial(_rotate_side_by_side, **tables, scope=scope)
     tiles = _kernel_tiles(t, d, r, g, rule)
     if tiles is not None:
         out = pallas_attention.fused_banded_attention(

@@ -230,7 +230,7 @@ def make_olmo_hybrid_eval_fn(cfg: OlmoHybridConfig = OlmoHybridConfig(),
     scan is computed and differentiated, ``delta_rule.solve_counters``,
     whether its chunks' systems are solved in VMEM, and
     ``lane.attention_counters``, whether the full layer's scores stay in
-    VMEM."""
+    VMEM (it turns nothing: ``attn_rotation_in_vmem`` reads 0)."""
     init_key = jax.random.key(data_seed + 1)
     visits, exits = _model(cfg)
     return lane.make_lane_eval_fn(
@@ -242,4 +242,5 @@ def make_olmo_hybrid_eval_fn(cfg: OlmoHybridConfig = OlmoHybridConfig(),
         static_counters=GDN_COUNTERS + solve_counters(
             cfg.seq_len, cfg.linear_num_heads, cfg.linear_key_head_dim,
             cfg.linear_value_head_dim, cfg.gdn_chunk) + lane.attention_counters(
-            cfg.seq_len, cfg.head_dim, cfg.num_heads // cfg.num_kv_heads, cfg.num_kv_heads))
+            cfg.seq_len, cfg.head_dim, cfg.num_heads // cfg.num_kv_heads, cfg.num_kv_heads,
+            rotary=0))

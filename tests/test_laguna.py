@@ -273,7 +273,7 @@ def test_heads_side_by_side_turn_as_heads_apart(lane_config, kind):
     rotary = None if 2 * len(inv_freq) == 16 else 2 * len(inv_freq)
     x = jax.random.normal(jax.random.key(2), (t, heads * 16))
     apart = lambda x: lane._rotate(x.reshape(t, heads, 16), cos, sin, rotary).reshape(t, -1)
-    beside = lambda x: lane._rotate_side_by_side(x, cos, sin, rotary)
+    beside = lambda x: lane._rotate_side_by_side(x, cos, sin, rotary, scope="lane.gqa")
     np.testing.assert_array_equal(beside(x), apart(x))
     cube = lambda turn: jax.grad(lambda x: (turn(x) ** 3).sum())(x)
     np.testing.assert_array_equal(cube(beside), cube(apart))
@@ -366,7 +366,7 @@ def test_the_mixer_with_the_kernels_is_the_mixer_without(
     with respect to its input, its four matrices and the gate, within
     bfloat16 operands' 2e-2 of the largest entry, and with float32 operands,
     where nothing is rounded and the two paths are the same sums, 2e-5."""
-    from hpbandster_tpu.ops import pallas_attention
+    from hpbandster_tpu.ops import pallas_attention, pallas_rotary
 
     monkeypatch.setattr(lane, "_OPERAND", operand)
     t, g, d, hidden = 256, 2, 128, 64
@@ -389,9 +389,16 @@ def test_the_mixer_with_the_kernels_is_the_mixer_without(
     monkeypatch.setattr(
         pallas_attention, "fused_banded_attention",
         lambda *args: calls.append(args[3:6]) or in_interpreter(*args, True))
+    # the queries' and the keys' turn is the rotation's kernel on this path
+    turn_in_interpreter = pallas_rotary.rotate_side_by_side
+    turns = []
+    monkeypatch.setattr(
+        pallas_rotary, "rotate_side_by_side",
+        lambda *args: turns.append(args[3:]) or turn_in_interpreter(*args, True))
     got, pull = jax.vjp(mixer, x, p)
     # a block of queries is a power of two: 16 for 6 or 8 heads, 32 for 3
     assert calls == [((g, r, d), lane._rule(window), (32 if r == 3 else 16, 128))]
+    assert turns == [(rotary // 2, operand, "lane.gqa")] * 2
     for ours, theirs in zip((got,) + tuple(jax.tree.leaves(pull(weigh))), want):
         np.testing.assert_allclose(ours, theirs, atol=limit * float(jnp.abs(theirs).max()))
 
@@ -437,7 +444,7 @@ def test_the_tiles_of_a_group_of_six_and_the_lanes_share_of_layers(monkeypatch):
     heads, sights = L._attention_shapes(cfg)
     assert heads == [6, 8, 8, 8, 6] and sights == [None, 512, 512, 512, None]
     assert lane.attention_counters(8192, 128, heads, 8, sights) == (
-        ("attn_scores_in_vmem", 0.0),)
+        ("attn_scores_in_vmem", 0.0), ("attn_rotation_in_vmem", 0.0))
     plain_bytes = lane.attention_alive_bytes(8192, 8, heads, 128, sights, 1024)
     assert plain_bytes == 3 * 4 * 6 * 1024 * 8192   # a full layer's widest block, six heads
     monkeypatch.setattr(lane, "pallas_available", lambda: True)
@@ -455,10 +462,11 @@ def test_the_tiles_of_a_group_of_six_and_the_lanes_share_of_layers(monkeypatch):
     assert lane._kernel_tiles(2048, 128, 1, 30) is None and lane._kernel_tiles(
         2048, 128, 1, 16) is None
     assert lane.attention_counters(8192, 128, heads, 8, sights) == (
-        ("attn_scores_in_vmem", 1.0),)
+        ("attn_scores_in_vmem", 1.0), ("attn_rotation_in_vmem", 1.0))
     # a lane of which some layers fit and some do not reads their share
     assert lane.attention_counters(8192, 128, [6, 8, 8, 8, 6], 8, [
-        None, 512, lane.BlockDiffusion(24), 512, None]) == (("attn_scores_in_vmem", 0.8),)
+        None, 512, lane.BlockDiffusion(24), 512, None]) == (
+        ("attn_scores_in_vmem", 0.8), ("attn_rotation_in_vmem", 0.8))
     with pytest.raises(ValueError):
         lane.attention_counters(8192, 128, [6, 8], 8, [None, 512, 512])
     assert lane.attention_alive_bytes(8192, 8, heads, 128, sights, 1024) == (

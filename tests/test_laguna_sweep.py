@@ -104,10 +104,12 @@ def test_the_row_counts_the_lanes_the_blocks_and_the_layers_in_vmem(swept):
     assert row["attn_scores_in_vmem"] == 0
     assert row["moe_combine_by_gather"] == 1 and row["moe_products_in_vmem"] == 0
     assert opt.eval_fn.lane_facts.counters == (
-        lane.LANE_COUNTERS + L.ATTENTION_COUNTERS + ("attn_scores_in_vmem",)
+        lane.LANE_COUNTERS + L.ATTENTION_COUNTERS
+        + ("attn_scores_in_vmem", "attn_rotation_in_vmem")
         + tuple(name for name, _ in lane.MOE_COUNTERS) + ("moe_products_in_vmem",))
     gauges = obs.get_metrics().snapshot()["gauges"]
     assert gauges["sweep.lane.attn_scores_in_vmem"] == 0.0
+    assert gauges["sweep.lane.attn_rotation_in_vmem"] == row["attn_rotation_in_vmem"] == 0.0
     assert gauges["sweep.lane.moe_held_choice_share"] == row["moe_held_choice_share"]
     assert gauges["sweep.lane.lane_steps"] == 27
 

@@ -402,12 +402,14 @@ def test_heads_of_64_take_the_kernels_in_pairs_where_mosaic_compiles(monkeypatch
     shape = (cfg.seq_len, cfg.head_dim, cfg.num_heads // cfg.num_kv_heads, cfg.num_kv_heads)
     assert shape == (8192, 64, 4, 8)
     assert lane._kernel_tiles(*shape) is None
-    assert dict(lane.attention_counters(*shape)) == {"attn_scores_in_vmem": 0.0}
+    assert dict(lane.attention_counters(*shape)) == {
+        "attn_scores_in_vmem": 0.0, "attn_rotation_in_vmem": 0.0}
     plain_bytes = lane.attention_alive_bytes(8192, 8, 4, 64, [None], cfg.attn_query_block)
     monkeypatch.setattr(lane, "pallas_available", lambda: True)
     assert lane._kernel_tiles(8192, 128, 8, 4) is not None
     assert lane._kernel_tiles(*shape) == (128, 512)
-    assert dict(lane.attention_counters(*shape)) == {"attn_scores_in_vmem": 1.0}
+    assert dict(lane.attention_counters(*shape)) == {
+        "attn_scores_in_vmem": 1.0, "attn_rotation_in_vmem": 1.0}
     # an odd head has no pair; the output and a log-sum-exp a row in place
     # of three copies of a block's scores
     assert lane._kernel_tiles(8192, 64, 4, 7) is None
@@ -531,7 +533,8 @@ def test_the_lanes_facts_are_its_models(lane_config):
     cfg = _cfg(lane_config)
     facts = L.make_lfm2_eval_fn(cfg, data_seed=0).lane_facts
     assert facts.counters == lane.LANE_COUNTERS + L.ATTENTION_COUNTERS + (
-        "attn_scores_in_vmem", "moe_combine_by_gather", "moe_products_in_vmem"
+        "attn_scores_in_vmem", "attn_rotation_in_vmem", "moe_combine_by_gather",
+        "moe_products_in_vmem"
     ) + L.LAYOUT_COUNTERS
     assert facts.tokens_per_step == 32 and facts.traced_budget
     full = L.Lfm2Config()

@@ -219,7 +219,7 @@ def test_blocks_outside_the_band_are_never_computed():
                           moe_intermediate_size=16, attn_query_block=16)
     facts = M.make_mellum2_eval_fn(cfg).lane_facts
     assert facts.counters == (lane.LANE_COUNTERS + M.ATTENTION_COUNTERS
-                              + ("attn_scores_in_vmem",)
+                              + ("attn_scores_in_vmem", "attn_rotation_in_vmem")
                               + tuple(name for name, _ in lane.MOE_COUNTERS)
                               + ("moe_products_in_vmem",))
     assert facts.traced_budget and facts.tokens_per_step == 64
@@ -436,7 +436,8 @@ def test_off_the_chip_the_plain_form_runs_and_the_counter_says_so(monkeypatch):
     published = [(8192, 128, 8, 4), (2048, 128, 1, 16), (8192, 64, 4, 8)]
     for t, d, r, g in published:
         assert lane._kernel_tiles(t, d, r, g) is None
-        assert lane.attention_counters(t, d, r, g) == (("attn_scores_in_vmem", 0.0),)
+        assert lane.attention_counters(t, d, r, g) == (
+        ("attn_scores_in_vmem", 0.0), ("attn_rotation_in_vmem", 0.0))
     blocks = lane.attention_key_blocks(8192, [1024, 1024, 1024, None], 1024)
     bytes_plain = lane.attention_alive_bytes(8192, 4, 8, 128, [1024, None], 1024)
     assert bytes_plain == 3 * 4 * 8 * 1024 * 8192
@@ -446,7 +447,8 @@ def test_off_the_chip_the_plain_form_runs_and_the_counter_says_so(monkeypatch):
     # wider than a tile of keys
     assert lane._kernel_tiles(8192, 128, 8, 4) == (128, 512)
     assert lane._kernel_tiles(4096, 128, 1, 16) == (512, 512)
-    assert lane.attention_counters(8192, 128, 8, 4) == (("attn_scores_in_vmem", 1.0),)
+    assert lane.attention_counters(8192, 128, 8, 4) == (
+        ("attn_scores_in_vmem", 1.0), ("attn_rotation_in_vmem", 1.0))
     # a pair of heads of 64 a step: the block of queries is sized from the
     # pair's 2 x 4 query heads, the rows a step really holds
     assert lane._kernel_tiles(8192, 64, 4, 8) == (128, 512)
@@ -457,7 +459,8 @@ def test_off_the_chip_the_plain_form_runs_and_the_counter_says_so(monkeypatch):
     for t, d, r, g in [(2048, 128, 1, 16), (64, 8, 2, 2), (8192, 64, 8, 3), (8200, 128, 8, 4),
                        (2 ** 16, 128, 8, 4)]:
         assert lane._kernel_tiles(t, d, r, g) is None
-        assert lane.attention_counters(t, d, r, g) == (("attn_scores_in_vmem", 0.0),)
+        assert lane.attention_counters(t, d, r, g) == (
+        ("attn_scores_in_vmem", 0.0), ("attn_rotation_in_vmem", 0.0))
     # the footprint and the counted tiles follow the path that runs: the
     # kernels keep an output and a log-sum-exp a row, no block of scores
     assert lane.attention_alive_bytes(8192, 4, 8, 128, [1024, None], 1024) == (
@@ -515,7 +518,7 @@ def test_heads_side_by_side_turn_as_heads_apart(kind):
     cos, sin = M._rotary_tables(cfg, kind, t)
     x = jax.random.normal(jax.random.key(2), (t, heads * 16))
     apart = lambda x: M._rotate(x.reshape(t, heads, 16), cos, sin).reshape(t, -1)
-    beside = lambda x: lane._rotate_side_by_side(x, cos, sin)
+    beside = lambda x: lane._rotate_side_by_side(x, cos, sin, scope="lane.swa")
     np.testing.assert_array_equal(beside(x), apart(x))
     cube = lambda turn: jax.grad(lambda x: (turn(x) ** 3).sum())(x)
     np.testing.assert_array_equal(cube(beside), cube(apart))
@@ -538,7 +541,7 @@ def test_the_mixer_with_the_kernels_is_the_mixer_without(monkeypatch, window, r,
     and the gradients with respect to its input, its four matrices and,
     where the layer has them, the per-head norms' weights, within bfloat16
     operands' 2e-2 of the largest entry."""
-    from hpbandster_tpu.ops import pallas_attention
+    from hpbandster_tpu.ops import pallas_attention, pallas_rotary
 
     t, g, hidden = 256, 2, 64
     keys = jax.random.split(jax.random.key(4), 7)
@@ -566,9 +569,16 @@ def test_the_mixer_with_the_kernels_is_the_mixer_without(monkeypatch, window, r,
     monkeypatch.setattr(
         pallas_attention, "fused_banded_attention",
         lambda *args: calls.append(args[3:6]) or in_interpreter(*args, True))
+    # the queries' and the keys' turn is the rotation's kernel on this path
+    turn_in_interpreter = pallas_rotary.rotate_side_by_side
+    turns = []
+    monkeypatch.setattr(
+        pallas_rotary, "rotate_side_by_side",
+        lambda *args: turns.append(args[3:]) or turn_in_interpreter(*args, True))
     got, pull = jax.vjp(mixer, x, p)
     # a step's rows are 128 whatever the width: a pair's 2 x r heads of 64
     assert calls == [((g, r, d), lane._rule(window), (max(128 // (r * (128 // d)), 16), 128))]
+    assert turns == [(d // 2, lane._OPERAND, "lane.swa")] * 2
     for ours, theirs in zip((got,) + tuple(jax.tree.leaves(pull(weigh))), want):
         np.testing.assert_allclose(ours, theirs, atol=2e-2 * float(jnp.abs(theirs).max()))
 

@@ -386,7 +386,7 @@ def test_the_lanes_facts_are_its_models(lane_config):
     facts = OH.make_olmo_hybrid_eval_fn(_cfg(lane_config)).lane_facts
     assert facts.counters == (
         "gdn_gate_per_head", "gdn_backward_by_rule", "delta_solve_in_vmem",
-        "attn_scores_in_vmem")
+        "attn_scores_in_vmem", "attn_rotation_in_vmem")
     assert facts.traced_budget and facts.tokens_per_step == 32
     # the published lane: 928,862,196 parameters, 8 bytes each and the
     # largest layer's gradient: one fits the chip's 16.9 GB, two do not

@@ -83,7 +83,7 @@ def test_the_row_counts_the_lanes_and_how_the_scan_ran(swept):
     assert not [name for name in row if name.startswith(("moe_", "kda_", "loop_"))]
     assert opt.eval_fn.lane_facts.counters == (
         "gdn_gate_per_head", "gdn_backward_by_rule", "delta_solve_in_vmem",
-        "attn_scores_in_vmem")
+        "attn_scores_in_vmem", "attn_rotation_in_vmem")
     # off the chip the chunks' systems are inverted by plain products
     assert row["delta_solve_in_vmem"] == 0
     gauges = obs.get_metrics().snapshot()["gauges"]

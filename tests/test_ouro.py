@@ -419,7 +419,8 @@ def test_the_reported_loss_reads_the_last_exit_alone(builders, float32_operands)
 def test_the_lanes_facts_are_its_models(builders):
     cfg = _cfg(builders)
     facts = O.make_ouro_eval_fn(cfg).lane_facts
-    assert facts.counters == O.EXIT_COUNTERS + O.LOOP_COUNTERS + ("attn_scores_in_vmem",)
+    assert facts.counters == O.EXIT_COUNTERS + O.LOOP_COUNTERS + (
+        "attn_scores_in_vmem", "attn_rotation_in_vmem")
     assert not [name for name in facts.counters if name.startswith("moe_")]
     assert facts.traced_budget and facts.tokens_per_step == 32
     # the published lane: 612,438,017 parameters at 12 bytes and its

@@ -206,7 +206,8 @@ def test_positions_repeat_and_the_kernels_take_the_rule(monkeypatch):
         np.testing.assert_array_equal(twice[4:], once)
     # off the chip the plain form, whatever the rule
     assert lane._kernel_tiles(8192, 128, 8, 4, sight) is None
-    assert lane.attention_counters(8192, 128, 8, 4, sight) == (("attn_scores_in_vmem", 0.0),)
+    assert lane.attention_counters(8192, 128, 8, 4, sight) == (
+        ("attn_scores_in_vmem", 0.0), ("attn_rotation_in_vmem", 0.0))
     # where Mosaic compiles, the backend and the shapes decide under either
     # rule: the fused kernels walk the tiles the rule gives them
     monkeypatch.setattr(lane, "pallas_available", lambda: True)
@@ -214,7 +215,8 @@ def test_positions_repeat_and_the_kernels_take_the_rule(monkeypatch):
     assert lane._kernel_tiles(8192, 128, 8, 4, lane.Causal(1024)) == (128, 512)
     assert lane._kernel_tiles(8192, 128, 8, 4, sight) == (128, 512)
     assert lane._kernel_tiles(8192, 64, 4, 8, sight) == (128, 512)
-    assert lane.attention_counters(8192, 128, 8, 4, sight) == (("attn_scores_in_vmem", 1.0),)
+    assert lane.attention_counters(8192, 128, 8, 4, sight) == (
+        ("attn_scores_in_vmem", 1.0), ("attn_rotation_in_vmem", 1.0))
     # a copy that is no whole tiles of keys (17 x 256 rows), diffusion blocks
     # that a block of 128 queries would cut, few keys: the plain form, where
     # the causal rule takes the same number of rows
@@ -632,8 +634,9 @@ def test_the_lanes_facts_are_its_models(lane_config):
     cfg = _cfg(lane_config)
     facts = D.make_sdar_eval_fn(cfg, data_seed=0).lane_facts
     assert facts.counters == lane.LANE_COUNTERS + ("diffusion_masked_share",) + (
-        D.ATTENTION_COUNTERS) + ("attn_scores_in_vmem", "moe_combine_by_gather",
-                                 "moe_products_in_vmem", "diffusion_rows_per_token")
+        D.ATTENTION_COUNTERS) + ("attn_scores_in_vmem", "attn_rotation_in_vmem",
+                                 "moe_combine_by_gather", "moe_products_in_vmem",
+                                 "diffusion_rows_per_token")
     # the data tokens of a step; the rows are twice that
     assert facts.tokens_per_step == S and facts.traced_budget
     full = D.SdarConfig()

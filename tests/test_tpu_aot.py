@@ -112,6 +112,15 @@ def _f32_sizes(text):
             for dims in re.findall(r"f32\[([\d,]+)\]", text)]
 
 
+def _kernels_with_the_turns(scope, forward_passes=1):
+    """A mixer's Mosaic kernels as :func:`_kernel_parts` lists them where its
+    rotation is a kernel too (``ops/pallas_rotary.py``): the queries' and the
+    keys' turn a forward pass, and their transposes backward."""
+    return ([("banded_attention_backward", scope)]
+            + [("banded_attention_forward", scope)] * forward_passes
+            + [("rotary_turn", scope)] * 2 * (forward_passes + 1))
+
+
 @pytest.fixture
 def mosaic_compiles_here(monkeypatch):
     """The lanes' rule asks the backend, which is the CPU here: told that
@@ -146,8 +155,7 @@ def test_banded_attention_compiles_for_v5e_at_the_published_size(
         return y, pull(dy)     # pulled back where the caller's scope is closed
 
     text = jax.jit(both_passes).lower(x, leaves, x).compile().as_text()
-    assert _kernel_parts(text) == [
-        ("banded_attention_backward", scope), ("banded_attention_forward", scope)]
+    assert _kernel_parts(text) == _kernels_with_the_turns(scope)
     assert max(_f32_sizes(text)) == cfg.seq_len * 5120
 
 
@@ -161,7 +169,8 @@ def test_a_group_of_six_query_heads_compiles_for_v5e_with_the_kernels(
     turned, and a window layer's 64 under the 512 window, both gated a head.
     Mosaic takes both, the kernels carry the caller's part, and no float32
     array of a block's scores exists (the largest is the projections' output
-    with the gate's columns). And ``lane._kernel_tiles`` returns for every
+    with the gate's columns, made a whole tile of lanes). And
+    ``lane._kernel_tiles`` returns for every
     shape a cell ran before exactly the tiles it returned then."""
     from hpbandster_tpu.workloads import laguna as L
     from hpbandster_tpu.workloads import lane
@@ -189,9 +198,51 @@ def test_a_group_of_six_query_heads_compiles_for_v5e_with_the_kernels(
         return y, pull(dy)     # pulled back where the caller's scope is closed
 
     text = jax.jit(both_passes).lower(x, leaves, x).compile().as_text()
-    assert _kernel_parts(text) == [
-        ("banded_attention_backward", scope), ("banded_attention_forward", scope)]
-    assert max(_f32_sizes(text)) == t * (heads * d + 2 * g * d + heads)
+    assert _kernel_parts(text) == _kernels_with_the_turns(scope)
+    # (the gate's columns padded to a whole tile of lanes)
+    assert max(_f32_sizes(text)) == t * (heads * d + 2 * g * d + 128)
+
+
+@pytest.mark.parametrize("kind, scope, heads, rotary", [
+    ("sliding", "lane.swa", 64, 128), ("full", "lane.gqa", 48, 64)])
+def test_the_rotation_of_heads_side_by_side_copies_no_row_on_v5e(
+        v5e_devices, mosaic_compiles_here, kind, scope, heads, rotary):
+    """The same two mixers, forward and backward, read for what the rotation
+    leaves in the compiled text: it is the kernel (``ops/pallas_rotary.py``),
+    four calls under the caller's part, the forward ones handing the attention
+    kernels bfloat16; no table is tiled across the heads; and the compiler
+    copies out no float32 array of 8,192 rows at all: not the turns' slices
+    (``[8192, 8128]`` of a window layer's queries, ``[8192, 6112]`` of a full
+    layer's, which the plain form's two turns of the whole row were), and not
+    the queries themselves (``[8192, 8192]`` / ``[8192, 6144]``: the
+    projections' product is whole tiles of lanes wide, so the compiler holds
+    it row-major, as the kernel takes it)."""
+    import re
+
+    from hpbandster_tpu.workloads import laguna as L
+    from hpbandster_tpu.workloads import lane
+
+    one = SingleDeviceSharding(v5e_devices[0])
+    cfg = L.LagunaConfig()
+    t, d, g = cfg.seq_len, cfg.head_dim, cfg.num_kv_heads
+    assert L._rotary(cfg, kind) == rotary
+    assert lane._turn_in_vmem(t, heads * d, d, rotary) and lane._turn_in_vmem(t, g * d, d, rotary)
+    shapes = L._layer_shapes(cfg, kind, "sparse")
+    leaves = {name: _sds(shapes[name], jnp.float32, one)
+              for name in ("wq", "wk", "wv", "w_head_gate", "wo")}
+    x = _sds((t, cfg.hidden_size), jnp.float32, one)
+
+    def both_passes(x, p, dy):
+        with jax.named_scope(scope):
+            y, pull = jax.vjp(lambda x, p: L._attention(x, p, kind, cfg), x, p)
+        return y, pull(dy)
+
+    text = jax.jit(both_passes).lower(x, leaves, x).compile().as_text()
+    turns = re.findall(r"%rotary_turn\S* = (\w+)\[(\d+),(\d+)\]", text)
+    assert sorted(turns) == sorted(
+        (dtype, str(t), str(width)) for dtype in ("bf16", "f32") for width in (heads * d, g * d))
+    assert "/tile\"" not in text
+    assert not re.findall(r"= f32\[%d,\d+\]\S* copy\(" % t, text)
 
 
 def test_a_looped_layer_compiles_for_v5e_at_the_published_size(
@@ -238,9 +289,7 @@ def test_a_looped_layer_compiles_for_v5e_at_the_published_size(
     text = jax.jit(a_pass_and_back).lower(h, stacked, h).compile().as_text()
     # the forward kernel twice: the pass, and the backward pass's own
     # recomputation of a visit's inside
-    assert _kernel_parts(text) == [
-        ("banded_attention_backward", "lane.gqa"), ("banded_attention_forward", "lane.gqa"),
-        ("banded_attention_forward", "lane.gqa")]
+    assert _kernel_parts(text) == _kernels_with_the_turns("lane.gqa", forward_passes=2)
 
 
 def test_the_convolution_and_narrow_head_layers_compile_for_v5e_at_the_published_size(
@@ -288,8 +337,9 @@ def test_the_convolution_and_narrow_head_layers_compile_for_v5e_at_the_published
 
     text = jax.jit(both_passes(attention)).lower(
         x, leaves(("attention", "moe")), x).compile().as_text()
-    assert _kernel_parts(text) == [
-        ("banded_attention_backward", "lane.gqa"), ("banded_attention_forward", "lane.gqa")]
+    # (the turn of heads of 64 is the rotation's kernel too, two heads a tile of lanes)
+    assert _kernel_parts(text) == _kernels_with_the_turns("lane.gqa")
+    assert "/tile\"" not in text
     # no array is as wide as the keys; the largest is the log-sum-exp, a
     # number a (query head, query) kept across the 128 lanes
     assert not re.search(r"f32\[[\d,]*\b%d\]" % cfg.seq_len, text)
@@ -418,8 +468,7 @@ def test_attention_under_the_block_diffusion_rule_compiles_for_v5e_with_the_kern
               for name, shape in D._layer_shapes(cfg).items()
               if name in ("wq", "wk", "wv", "wo", "q_norm", "k_norm")}
     text = jax.jit(both_passes).lower(x, leaves, x).compile().as_text()
-    assert _kernel_parts(text) == [
-        ("banded_attention_backward", "lane.bda"), ("banded_attention_forward", "lane.bda")]
+    assert _kernel_parts(text) == _kernels_with_the_turns("lane.bda")
     # (the one array as long as the rows is their positions, a vector)
     assert not re.search(r"f32\[[\d,]+,%d\]" % rows, text)
     sizes = _f32_sizes(text)
