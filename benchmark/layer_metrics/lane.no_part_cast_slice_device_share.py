@@ -1,0 +1,11 @@
+"""Lane: percent of the device's busy seconds in no part of the lane, in an
+instruction the compiler made itself (no ``op_name``) of kind
+``cast_slice``: a cast or a slice of a stacked leaf lifted out of a loop, a
+pad, a concatenation. One of the five shares that add up to
+``lane.no_part_device_share`` (``lane_kinds.py``)."""
+
+import lane_kinds
+
+
+def read(ctx):
+    return lane_kinds.no_part_share(ctx, "cast_slice")

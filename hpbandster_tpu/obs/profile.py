@@ -47,6 +47,8 @@ __all__ = [
     "get_profile_session",
     "device_peaks",
     "ProgramText",
+    "adopted_phase_map",
+    "device_kind_map",
     "device_phase_map",
     "hlo_module_name",
     "parse_program_text",
@@ -403,7 +405,26 @@ def _fmtnum(v: Any) -> str:
 # ---------------------------------------------------- device phase map
 _HLO_MODULE = re.compile(r"^HloModule ([^\s,]+)")
 _HLO_COMPUTATION = re.compile(r"^(ENTRY )?%?([\w.\-]+) \(.*\{$")
-_HLO_INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%?([\w.\-]+) = ")
+_HLO_INSTRUCTION = re.compile(r"^\s+(ROOT )?%?([\w.\-]+) = ")
+#: the opcode stands after the shape, which may be a tuple's with spaces
+#: inside it: the first lower-case word that a ``(`` follows directly (in a
+#: shape a ``(`` follows ``T``, ``S`` or another bracket, never a space
+#: and a lower-case word)
+_HLO_OPCODE = re.compile(r" ([a-z][a-z0-9\-]*)\(")
+#: where the operands of an instruction end: the ``)`` that an attribute
+#: (``, calls=``) or the line's end follows; a ``)`` inside an operand's
+#: printed shape is followed by neither
+_HLO_OPERANDS_END = re.compile(r"\)(?:, [a-z_\-]+=|$)")
+_HLO_REFERENCE = re.compile(r"%([\w.\-]+)")
+#: the one fact more that three opcodes carry: a parameter's number, the
+#: element a ``get-tuple-element`` takes, a custom call's target
+_HLO_DETAIL = {
+    "parameter": (re.compile(r"parameter\((\d+)\)"), int),
+    "get-tuple-element": (re.compile(r", index=(\d+)"), int),
+    "custom-call": (re.compile(r'custom_call_target="([^"]*)"'), str),
+}
+#: a loop's state is a tuple of hundreds of shapes: its beginning is kept
+_SHAPE_KEPT = 64
 _HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
 _HLO_CALLEES = re.compile(
     r"(?:calls|to_apply|body|condition|true_computation|false_computation)"
@@ -430,6 +451,17 @@ class ProgramText(NamedTuple):
     #: ``{computation: [(instruction, its op_name or None, the
     #: computations it calls)]}``, names without their ``%``
     computations: Dict[str, List[Tuple[str, Optional[str], List[str]]]]
+    #: ``{instruction: (opcode, the instructions it reads in the order of
+    #: its operands, detail, shape)}``: what :func:`device_kind_map` and
+    #: :func:`adopted_phase_map` read. ``detail`` is a ``parameter``'s
+    #: number, the element a ``get-tuple-element`` takes, a
+    #: ``custom-call``'s target, else ``None``; ``shape`` the first
+    #: ``_SHAPE_KEPT`` characters of the result's shape and layout, for a
+    #: table a person reads. An operand is found by its ``%``, as
+    #: ``compiled.as_text()`` prints it
+    instructions: Dict[str, Tuple[str, Tuple[str, ...], Any, str]]
+    #: ``{computation: its ROOT instruction}``
+    roots: Dict[str, str]
 
 
 def _hlo_text(compiled: Any) -> str:
@@ -449,36 +481,66 @@ def hlo_module_name(compiled: Any) -> str:
 def parse_program_text(compiled: Any) -> ProgramText:
     """One pass over a compiled program's optimized HLO text
     (``compiled.as_text()``; the text itself is taken too): the
-    computations' call graph and every instruction's ``op_name``. Neither
-    depends on a family of scope names, so one parse serves them all
-    (``optimizers.sweep_phase_maps`` keeps it by executable). Seconds for a
-    large program."""
+    computations' call graph, every instruction's ``op_name``, its opcode
+    and the instructions it reads. None depends on a family of scope names,
+    so one parse serves them all (``optimizers.sweep_phase_maps`` keeps it
+    by executable). Seconds for a large program."""
     text = _hlo_text(compiled)
     computations: Dict[str, List[Tuple[str, Optional[str], List[str]]]] = {}
-    op_names: Dict[str, str] = {}   # one string a distinct op_name
-    entry, current = None, None
+    instructions: Dict[str, Tuple[str, Tuple[str, ...], Any, str]] = {}
+    roots: Dict[str, str] = {}
+    shared: Dict[str, str] = {}     # one string a distinct name, op_name, opcode
+    entry, current, computation = None, None, None
     for line in text.splitlines():
         header = _HLO_COMPUTATION.match(line)
         if header is not None:
-            current = computations.setdefault(header.group(2), [])
+            computation = header.group(2)
+            current = computations.setdefault(computation, [])
             if header.group(1):
-                entry = header.group(2)
+                entry = computation
             continue
         instruction = _HLO_INSTRUCTION.match(line)
         if instruction is None or current is None:
             continue
+        name = instruction.group(2)
+        name = shared.setdefault(name, name)
+        if instruction.group(1):
+            roots[computation] = name
         op_name = _HLO_OP_NAME.search(line)
         callees = _HLO_CALLEES.findall(line)
         for group in _HLO_BRANCHES.findall(line):
             callees += [c.strip().lstrip("%") for c in group.split(",")]
         current.append((
-            instruction.group(1),
-            op_names.setdefault(op_name.group(1), op_name.group(1)) if op_name else None,
+            name,
+            shared.setdefault(op_name.group(1), op_name.group(1)) if op_name else None,
             callees,
         ))
+        opcode = _HLO_OPCODE.search(line, instruction.end() - 1)
+        if opcode is None:      # no line of the compiler's: nothing is known of it
+            instructions[name] = ("", (), None, "")
+            continue
+        end = _HLO_OPERANDS_END.search(line, opcode.end())
+        detail = None
+        if opcode.group(1) in _HLO_DETAIL:
+            pattern, convert = _HLO_DETAIL[opcode.group(1)]
+            stated = pattern.search(line, opcode.start())
+            detail = convert(stated.group(1)) if stated else None
+        instructions[name] = (
+            shared.setdefault(opcode.group(1), opcode.group(1)),
+            tuple(shared.setdefault(r, r) for r in _HLO_REFERENCE.findall(
+                line, opcode.end(), end.start() if end else len(line))),
+            detail,
+            line[instruction.end():min(opcode.start(), instruction.end() + _SHAPE_KEPT)],
+        )
     if entry is None:
         raise ValueError("the compiled text has no ENTRY computation")
-    return ProgramText(hlo_module_name(text), entry, computations)
+    return ProgramText(hlo_module_name(text), entry, computations, instructions, roots)
+
+
+def _program(compiled: Any) -> ProgramText:
+    """A compiled program's parse: made here, or the one handed in."""
+    return (compiled if isinstance(compiled, ProgramText)
+            else parse_program_text(compiled))
 
 
 def _outside_wrappers(op_name: str) -> str:
@@ -537,8 +599,7 @@ def device_phase_map(
 
     scopes = frozenset(DEVICE_SCOPES if scopes is None else scopes)
     by_pass = scopes == frozenset(PASS_SCOPES)
-    program = (compiled if isinstance(compiled, ProgramText)
-               else parse_program_text(compiled))
+    program = _program(compiled)
 
     found: Dict[Optional[str], Optional[str]] = {None: None}
 
@@ -569,3 +630,253 @@ def device_phase_map(
                     inherited[callee] = phase
                     queue.append(callee)
     return phases
+
+
+# ------------------------------------------- kinds, and adoption by reader
+#: what a fused computation holds beside its operations: a fusion's kind
+#: leaves these out
+_NO_OPERATION = frozenset((
+    "parameter", "constant", "bitcast", "tuple", "get-tuple-element"))
+_COPY_OPCODES = frozenset(("copy", "copy-start", "copy-done", "transpose"))
+_CAST_SLICE_OPCODES = frozenset((
+    "convert", "slice", "dynamic-slice", "slice-start", "slice-done",
+    "dynamic-update-slice", "concatenate", "pad"))
+_FILL_OPCODES = frozenset(("broadcast", "iota"))
+#: a custom call into a Mosaic kernel: the Pallas kernels, and what the
+#: chip's compiler makes of ``jax.lax.ragged_dot`` (``ragged-dot-none.3``
+#: and its ``ragged-dot-metadata``); the compiler's bookkeeping calls
+#: (``AllocateBuffer``, ``ConcatBitcast``) take no time and are no kernels
+_KERNEL_TARGETS = frozenset(("tpu_custom_call",))
+#: judged by the opcodes of the computation it wraps: a fusion, and the
+#: beginning of an operation the chip runs beside others (``slice-start.3 =
+#: async-start(...), calls=%async_computation.3``, a ``slice`` inside)
+_WRAPPERS = frozenset(("fusion", "async-start"))
+#: ... whose later steps (``slice-done.3 = async-done(%slice-start.3)``)
+#: are of the kind of what they finish
+_FINISHERS = frozenset(("async-update", "async-done"))
+#: the compiler's own joining of buffers that lie side by side: a change of
+#: layout that takes no time, booked with the copies so that the walk
+#: below passes through it (ISSUE 52 books every call that is no kernel's
+#: as ``compute``)
+_LAYOUT_TARGETS = frozenset(("ConcatBitcast",))
+#: how many instructions the walk of :func:`adopted_phase_map` may pass
+#: before it gives up: a cast lifted out of two loops is eight away from
+#: the product that reads it (tuple, while, parameter, element, twice)
+_ADOPTION_DEPTH = 8
+
+
+def device_kind_map(compiled: Any) -> Dict[str, str]:
+    """``{instruction name: kind}`` of one compiled program (its text, or a
+    :class:`ProgramText`), for every instruction one of
+    :data:`~hpbandster_tpu.obs.timeline.OP_KINDS`, decided by opcode alone:
+
+    * ``kernel``: a ``custom-call`` whose target is ``tpu_custom_call`` (a
+      Pallas kernel, and what the chip's compiler makes of ``ragged_dot``);
+    * ``copy``: ``copy``, ``copy-start`` / ``-done``, ``transpose``, the
+      compiler's ``ConcatBitcast``, and a fusion of nothing else;
+    * ``cast_slice``: ``convert``, ``slice``, ``dynamic-slice``,
+      ``slice-start`` / ``-done``, ``dynamic-update-slice``,
+      ``concatenate``, ``pad``, and a fusion of these and of copies alone;
+    * ``fill``: a ``broadcast`` or ``iota``, or a fusion, none of whose
+      operands is anything but a constant;
+    * ``compute``: everything else.
+
+    A fusion is judged by the opcodes of its fused computation, leaving out
+    ``parameter``, ``constant``, ``bitcast``, ``tuple`` and
+    ``get-tuple-element``; so is an ``async-start`` by the computation it
+    wraps (on the chip a slice into the fast memory is ``slice-start.3 =
+    async-start(...), calls=%async_computation.3``), and the ``async-done``
+    that finishes it is of its kind."""
+    program = _program(compiled)
+    instructions = program.instructions
+    both = _COPY_OPCODES | _CAST_SLICE_OPCODES
+
+    def constants_alone(operands: Tuple[str, ...]) -> bool:
+        return all(instructions.get(o, ("",))[0] == "constant" for o in operands)
+
+    kinds: Dict[str, str] = {}
+    for rows in program.computations.values():
+        for name, _, callees in rows:
+            opcode, operands, detail, _ = instructions[name]
+            if opcode == "custom-call":
+                kind = ("kernel" if detail in _KERNEL_TARGETS
+                        else "copy" if detail in _LAYOUT_TARGETS else "compute")
+            elif opcode in _WRAPPERS:
+                inside = {
+                    instructions[i][0]
+                    for callee in callees
+                    for i, _, _ in program.computations.get(callee, ())
+                } - _NO_OPERATION
+                kind = ("fill" if constants_alone(operands)
+                        else "copy" if inside <= _COPY_OPCODES
+                        else "cast_slice" if inside <= both else "compute")
+            elif opcode in _FINISHERS:
+                # begun by its operand, further up the same computation
+                kind = kinds.get(operands[0] if operands else "", "compute")
+            elif opcode in _COPY_OPCODES:
+                kind = "copy"
+            elif opcode in _CAST_SLICE_OPCODES:
+                kind = "cast_slice"
+            elif opcode in _FILL_OPCODES and constants_alone(operands):
+                kind = "fill"
+            else:
+                kind = "compute"
+            kinds[name] = kind
+    return kinds
+
+
+def adopted_phase_map(
+    compiled: Any, scopes: Optional[Sequence[str]] = None
+) -> Dict[str, str]:
+    """``{instruction name: phase}`` for instructions that
+    :func:`device_phase_map` leaves out of the same list of ``scopes``: an
+    instruction with no phase by name takes the phase of what reads it.
+
+    One rule. Walk from the instruction to its readers. A reader that has a
+    phase by name ends its branch with that phase. One that has none is
+    passed through if it only moves what it reads (a ``bitcast``, ``tuple``
+    or ``get-tuple-element``, or an instruction of kind ``copy`` or
+    ``cast_slice``: :func:`device_kind_map`), and crossed by operand
+    position if it is a ``while``, ``call`` or ``conditional``: operand
+    ``i`` of a call is the callee's ``parameter(i)``, operand ``j + 1`` of a
+    conditional its branch ``j``'s parameter, a loop's state its body's and
+    its condition's, and element ``k`` of the tuple stays element ``k``,
+    also from the body's ROOT to the next turn's parameter. Any other
+    reader ends its branch with nothing, and no branch is longer than
+    ``_ADOPTION_DEPTH`` instructions. If every phase found is the same,
+    that is the instruction's; if none was found, the same walk is made
+    the other way, over what the instruction reads; if two were found, or
+    nothing, the instruction stays out: an orphan. No vote, no weighting,
+    and a name is never overridden: what :func:`device_phase_map` returns
+    is not touched.
+
+    Built on demand from the parse that serves every list; never on a
+    sweep's path."""
+    program = _program(compiled)
+    return _adopted(program, device_phase_map(program, scopes), device_kind_map(program))
+
+
+def _adopted(
+    program: ProgramText, phases: Dict[str, str], kinds: Dict[str, str]
+) -> Dict[str, str]:
+    """:func:`adopted_phase_map` over maps made before."""
+    instructions = program.instructions
+
+    readers: Dict[str, List[Tuple[str, int]]] = {}
+    parameters: Dict[Tuple[str, int], str] = {}
+    home: Dict[str, str] = {}                   # a parameter's computation
+    callees_of: Dict[str, List[str]] = {}       # of a while, call, conditional
+    callers: Dict[str, List[str]] = {}          # the same, from the callee
+    for computation, rows in program.computations.items():
+        for name, _, callees in rows:
+            opcode, operands, detail, _ = instructions[name]
+            for position, operand in enumerate(operands):
+                readers.setdefault(operand, []).append((name, position))
+            if opcode == "parameter":
+                parameters[computation, detail] = name
+                home[name] = computation
+            elif opcode in ("while", "call", "conditional"):
+                callees_of[name] = callees
+                for callee in callees:
+                    callers.setdefault(callee, []).append(name)
+    #: a loop body's ROOT tuple -> the body's parameter, the next turn's state
+    next_turn = {
+        program.roots[body]: parameters[body, 0]
+        for loop, callees in callees_of.items() if instructions[loop][0] == "while"
+        for body in callees if (body, 0) in parameters
+        and instructions.get(program.roots.get(body), ("",))[0] == "tuple"}
+
+    def passed(name: str) -> bool:
+        return (instructions[name][0] == "bitcast"
+                or kinds[name] in ("copy", "cast_slice"))
+
+    def entered(reader: str, position: int) -> List[str]:
+        """The parameters that stand for operand ``position`` of ``reader``
+        in the computations it calls."""
+        opcode, callees = instructions[reader][0], callees_of[reader]
+        if opcode == "while":
+            inside = [(callee, 0) for callee in callees]
+        elif opcode == "call":
+            inside = [(callee, position) for callee in callees[:1]]
+        else:
+            inside = [(callees[position - 1], 0)] if 0 < position <= len(callees) else []
+        return [parameters[key] for key in inside if key in parameters]
+
+    def read_by(name: str, element: Optional[int], depth: int, found: set) -> None:
+        """The phases of the nearest named readers of ``name``, of its
+        element ``element`` where it is a tuple that the walk built."""
+        if depth == 0:
+            return
+        for reader, position in readers.get(name, ()):
+            opcode, _, detail, _ = instructions[reader]
+            if opcode == "get-tuple-element" and element not in (None, detail):
+                continue    # another element of the tuple
+            if reader in phases:
+                found.add(phases[reader])
+            elif reader in callees_of:
+                for parameter in entered(reader, position):
+                    read_by(parameter, element, depth - 1, found)
+            elif opcode == "get-tuple-element":
+                read_by(reader, None, depth - 1, found)
+            elif element is not None:
+                continue    # a tuple inside a tuple is followed no further
+            elif reader in next_turn:
+                read_by(next_turn[reader], position, depth - 1, found)
+            elif opcode == "tuple":
+                read_by(reader, position, depth - 1, found)
+            elif passed(reader):
+                read_by(reader, None, depth - 1, found)
+
+    def handed(parameter: str) -> List[str]:
+        """What the one caller of a parameter's computation hands it."""
+        computation = home[parameter]
+        entering = callers.get(computation, ())
+        if len(entering) != 1:
+            return []
+        opcode, operands = instructions[entering[0]][:2]
+        position = (instructions[parameter][2] if opcode == "call"
+                    else 1 + callees_of[entering[0]].index(computation)
+                    if opcode == "conditional" else 0)
+        return list(operands[position:position + 1])
+
+    def read_from(name: str, element: Optional[int], depth: int, found: set,
+                  first: bool = False) -> None:
+        """The same walk the other way: the phases of the nearest named
+        instructions that ``name`` (its element ``element``) is made of."""
+        if depth == 0:
+            return
+        if not first and name in phases:
+            found.add(phases[name])
+            return
+        opcode, operands, detail, _ = instructions.get(name, ("", (), None, ""))
+        if opcode == "get-tuple-element":
+            sources = [(o, detail) for o in operands] if element is None else []
+        elif opcode == "tuple":
+            sources = ([(o, None) for o in operands[element:element + 1]]
+                       if element is not None else
+                       [(o, None) for o in operands] if first else [])
+        elif opcode == "parameter":
+            sources = [(o, element) for o in handed(name)]
+        elif opcode == "while":
+            # a loop's result: what its last turn left, and what it began with
+            sources = [(o, element) for o in operands] + [
+                (program.roots[body], element) for body in callees_of.get(name, ())
+                if body in program.roots]
+        elif element is None and (first or passed(name)):
+            sources = [(o, None) for o in operands]
+        else:
+            sources = []
+        for source, which in sources:
+            read_from(source, which, depth - 1, found)
+
+    adopted: Dict[str, str] = {}
+    for name in instructions:
+        if name in phases:
+            continue
+        found: set = set()
+        read_by(name, None, _ADOPTION_DEPTH, found)
+        if not found:
+            read_from(name, None, _ADOPTION_DEPTH, found, first=True)
+        if len(found) == 1:
+            adopted[name] = found.pop()
+    return adopted

@@ -68,6 +68,7 @@ __all__ = [
     "SPAN_PREFIX",
     "DEVICE_SCOPES",
     "LANE_SCOPES",
+    "OP_KINDS",
     "phase_span",
     "sweep_span",
     "mark",
@@ -179,6 +180,20 @@ MOE_SCOPES = (
     "moe.experts",     # the grouped products, the experts' gradient sums, the closing group's zeros and the cast
     "moe.combine",     # rows gathered by ``place`` and summed over the top k, both ways; the weights' gradient
     "moe.shared",      # the shared expert's SwiGLU, where the layer has one
+)
+
+#: the closed list of kinds of operation an instruction of a compiled
+#: program is, decided by its opcode alone (``obs.profile.device_kind_map``;
+#: a ``fusion`` and an ``async-start`` by the opcodes of the computation
+#: they wrap, an ``async-done`` as what it finishes): every instruction
+#: has exactly one. What a part's seconds are made of, and what the seconds
+#: in no part are, is read by these names
+OP_KINDS = (
+    "kernel",      # a custom call into a Mosaic kernel (``tpu_custom_call``: the Pallas kernels, and what the chip's compiler makes of ``ragged_dot``)
+    "copy",        # ``copy``, ``copy-start`` / ``-done``, ``transpose``, and a fusion of nothing else: a change of layout, a move into the fast memory
+    "cast_slice",  # ``convert``, ``slice``, ``dynamic-slice``, ``slice-start`` / ``-done``, ``dynamic-update-slice``, ``concatenate``, ``pad``, and a fusion of these and of copies alone
+    "fill",        # a ``broadcast`` or ``iota``, or a fusion, that reads nothing but constants
+    "compute",     # everything else
 )
 
 #: attribution priority when concurrent spans overlap (lower = wins):

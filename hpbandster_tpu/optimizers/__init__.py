@@ -9,5 +9,6 @@ from hpbandster_tpu.optimizers.fused_bohb import (  # noqa: F401
     FusedH2BO,
     FusedHyperBand,
     FusedRandomSearch,
+    sweep_instruction_facts,
     sweep_phase_maps,
 )

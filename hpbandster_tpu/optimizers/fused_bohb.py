@@ -48,13 +48,24 @@ from hpbandster_tpu.ops.sweep_driver import (
 from hpbandster_tpu.space import ConfigurationSpace
 
 __all__ = ["FusedBOHB", "FusedHyperBand", "FusedRandomSearch", "FusedH2BO",
-           "sweep_phase_maps"]
+           "sweep_instruction_facts", "sweep_phase_maps"]
 
 
-#: what ``sweep_phase_maps`` has read of an executable's text, kept while
-#: the executable lives: the text is fetched and parsed once a process,
-#: whatever the number of families asked for
+#: what ``sweep_phase_maps`` and ``sweep_instruction_facts`` have read of an
+#: executable's text, kept while the executable lives: the text is fetched
+#: and parsed once a process, whatever the number of families asked for
 _PROGRAM_TEXTS: "weakref.WeakKeyDictionary" = weakref.WeakKeyDictionary()
+
+
+def _program_texts():
+    """The parsed text of every sweep executable this process holds."""
+    from hpbandster_tpu.obs.profile import parse_program_text
+
+    for compiled in _SWEEP_EXE_CACHE.values():
+        program = _PROGRAM_TEXTS.get(compiled)
+        if program is None:
+            program = _PROGRAM_TEXTS[compiled] = parse_program_text(compiled)
+        yield program
 
 
 def sweep_phase_maps(scopes=None) -> Dict[str, Dict[str, str]]:
@@ -74,14 +85,11 @@ def sweep_phase_maps(scopes=None) -> Dict[str, Dict[str, str]]:
     executable's text is fetched and parsed once (seconds for a large
     program: the call graph and the ``op_name``s serve every list), so call
     it after the sweeps, never between them."""
-    from hpbandster_tpu.obs.profile import device_phase_map, parse_program_text
+    from hpbandster_tpu.obs.profile import device_phase_map
 
     maps: Dict[str, Dict[str, str]] = {}
     clashed = set()
-    for compiled in _SWEEP_EXE_CACHE.values():
-        program = _PROGRAM_TEXTS.get(compiled)
-        if program is None:
-            program = _PROGRAM_TEXTS[compiled] = parse_program_text(compiled)
+    for program in _program_texts():
         phases = device_phase_map(program, scopes)
         if not phases:
             # loaded from a persistent cache that a commit without these
@@ -94,6 +102,46 @@ def sweep_phase_maps(scopes=None) -> Dict[str, Dict[str, str]]:
     for module, name in clashed:
         del maps[module][name]
     return maps
+
+
+def sweep_instruction_facts(scopes=None) -> Dict[str, Dict[str, Dict[str, Any]]]:
+    """``{module name: {instruction name: {"opcode", "kind", "named",
+    "adopted", "shape", "op_name"}}}`` over the same executables, for every
+    instruction of each: its opcode, its kind (one of
+    ``obs.timeline.OP_KINDS``: ``obs.profile.device_kind_map``), whether its
+    own line carries an ``op_name``, and the name of ``scopes`` it takes
+    from what reads it where it has none by name
+    (``obs.profile.adopted_phase_map``; ``None`` for an instruction that has
+    one by name, and for an orphan); for a table a person reads, the
+    beginning of its shape and its ``op_name``. What
+    ``sweep_phase_maps(scopes)`` says of a name is not repeated here: the
+    two are read side by side. Its rules hold: an instruction that two
+    executables of one module name give different facts is left out, and
+    so is an executable whose text names no scope of the list. Nothing that
+    ``sweep_phase_maps`` has parsed is parsed again; the walk over readers
+    is made anew each call (half a second for the largest lane's program):
+    call it once, after the sweeps."""
+    from hpbandster_tpu.obs.profile import _adopted, device_kind_map, device_phase_map
+
+    facts: Dict[str, Dict[str, Dict[str, Any]]] = {}
+    clashed = set()
+    for program in _program_texts():
+        phases = device_phase_map(program, scopes)
+        if not phases:
+            continue
+        kinds = device_kind_map(program)
+        adopted = _adopted(program, phases, kinds)
+        instructions = program.instructions
+        for rows in program.computations.values():
+            for name, op_name, _ in rows:
+                fact = {"opcode": instructions[name][0], "kind": kinds[name],
+                        "named": op_name is not None, "adopted": adopted.get(name),
+                        "shape": instructions[name][3], "op_name": op_name}
+                if facts.setdefault(program.module, {}).setdefault(name, fact) != fact:
+                    clashed.add((program.module, name))
+    for module, name in clashed:
+        del facts[module][name]
+    return facts
 
 
 def _lane_accounting(eval_fn, plans, outputs) -> Dict[str, Any]:

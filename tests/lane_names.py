@@ -90,6 +90,47 @@ def reference_phase_map(text, scopes=DEVICE_SCOPES):
     return phases
 
 
+def parsed_before_the_operands(text):
+    """``(module, entry, computations)`` as PR 51's ``parse_program_text``
+    returned them, line for line its loop: what ``device_phase_map`` reads of
+    a text, before the parse kept opcodes and operands beside it (PR 52)."""
+    computations, names, entry, current = {}, {}, None, None
+    for line in text.splitlines():
+        header = _COMPUTATION.match(line)
+        if header is not None:
+            current = computations.setdefault(header.group(2), [])
+            if header.group(1):
+                entry = header.group(2)
+            continue
+        instruction = _INSTRUCTION.match(line)
+        if instruction is None or current is None:
+            continue
+        op_name = _OP_NAME.search(line)
+        callees = _CALLEES.findall(line)
+        for group in _BRANCHES.findall(line):
+            callees += [c.strip().lstrip("%") for c in group.split(",")]
+        current.append((
+            instruction.group(1),
+            names.setdefault(op_name.group(1), op_name.group(1)) if op_name else None,
+            callees))
+    return re.match(r"^HloModule ([^\s,]+)", text).group(1), entry, computations
+
+
+def check_the_maps_are_what_they_were(text):
+    """``device_phase_map`` under all four lists returns what PR 51's
+    returned: the fields it reads of the parse are PR 51's, entry for
+    entry, and without the new ones it returns the same."""
+    program = parse_program_text(text)
+    assert tuple(program[:3]) == parsed_before_the_operands(text)
+    bare = program._replace(instructions={}, roots={})
+    for scopes in (DEVICE_SCOPES, LANE_SCOPES, PASS_SCOPES, MOE_SCOPES):
+        assert device_phase_map(program, scopes) == device_phase_map(bare, scopes)
+        assert device_phase_map(text, scopes) == device_phase_map(bare, scopes)
+    for scopes in (DEVICE_SCOPES, LANE_SCOPES, MOE_SCOPES):
+        assert device_phase_map(program, scopes) == reference_phase_map(text, scopes)
+    return program
+
+
 def check_the_older_readers_read_what_they_read(text):
     """The parts' and the phases' maps, from one parse, are PR 37's."""
     program = parse_program_text(text)

@@ -147,6 +147,14 @@ def test_sweep_phase_maps_fetches_a_text_once_whatever_the_families(monkeypatch)
         # the two would clash otherwise, and the metric read a wrong number
         assert sweep_phase_maps(PASS_SCOPES) == {"jit_f": {"op.1": "pass.forward"}}
         assert sweep_phase_maps(MOE_SCOPES) == {"jit_f": {"op.1": "moe.router"}}
+        # ... and the facts of every instruction are read off the same parse
+        # (the older executable names a part too: the two agree on ``x``
+        # and differ in ``op.1``'s name, which is left out)
+        facts = fused_bohb.sweep_instruction_facts(LANE_SCOPES)
+        assert facts == {"jit_f": {"x": {
+            "opcode": "parameter", "kind": "compute", "named": False, "adopted": "lane.moe",
+            "shape": "f32[4]{0}", "op_name": None}}}
+        assert fused_bohb.sweep_instruction_facts(MOE_SCOPES)["jit_f"]["op.1"]["opcode"] == "negate"
     assert (named.fetched, older.fetched) == (1, 1)
     # kept while the executable lives, and no longer
     assert len(fused_bohb._PROGRAM_TEXTS) >= 2

@@ -569,3 +569,66 @@ def test_a_gated_deltanet_layer_compiles_for_v5e_at_the_published_size(
     text = jax.jit(both_passes("gqa")).lower(x, leaves("gqa"), x).compile().as_text()
     assert _kernel_parts(text) == [] and "cosine" not in text and "lane.gqa" in text
     assert lane._kernel_tiles(8192, cfg.head_dim, 1, cfg.num_kv_heads) is not None
+
+
+def test_the_tpu_compilers_text_gives_every_instruction_a_kind_and_a_lifted_cast_its_part(
+        v5e_devices):
+    """The facts ``obs.profile`` keeps of an instruction (ISSUE 52), on the
+    TPU compiler's real text: two parts scanned over their stacked leaves,
+    under ``jax.grad`` and a momentum step. The compiler lifts the casts of
+    the whole stacks out of the loop and leaves them without a name: each is
+    adopted by the part whose product reads it inside."""
+    import lane_names
+    from hpbandster_tpu.obs.profile import (
+        adopted_phase_map, device_kind_map, device_phase_map)
+    from hpbandster_tpu.obs.timeline import LANE_SCOPES, OP_KINDS
+
+    one = SingleDeviceSharding(v5e_devices[0])
+    layers, width, rows = 4, 512, 1024
+
+    def layer(x, w):
+        with jax.named_scope("lane.gqa"):
+            x = x + jnp.tanh(jnp.dot(x.astype(jnp.bfloat16), w[0].astype(jnp.bfloat16),
+                                     preferred_element_type=jnp.float32))
+        with jax.named_scope("lane.dense_ffn"):
+            x = x + jnp.dot(jax.nn.silu(x).astype(jnp.bfloat16), w[1].astype(jnp.bfloat16),
+                            preferred_element_type=jnp.float32)
+        return x, None
+
+    def loss(ws, x):
+        x, _ = jax.lax.scan(layer, x, ws)
+        with jax.named_scope("lane.head"):
+            return jnp.mean(x * x)
+
+    def step(ws, m, x):
+        g = jax.grad(loss)(ws, x)
+        with jax.named_scope("lane.update"):
+            m = jax.tree.map(lambda m, g: 0.9 * m + g, m, g)
+            return jax.tree.map(lambda w, m: w - 0.1 * m, ws, m), m
+
+    def train(ws, x):
+        m = jax.tree.map(jnp.zeros_like, ws)
+        return jax.lax.fori_loop(0, 3, lambda i, c: step(*c, x), (ws, m))[0]
+
+    stack = _sds((layers, width, width), jnp.float32, one)
+    text = jax.jit(train).lower((stack, stack), _sds((rows, width), jnp.float32, one)
+                                ).compile().as_text()
+    program = lane_names.check_the_maps_are_what_they_were(text)
+    assert len(program.instructions) == sum(map(len, program.computations.values())) > 300
+    kinds = device_kind_map(program)
+    assert kinds.keys() == program.instructions.keys() and set(kinds.values()) <= set(OP_KINDS)
+    assert {"copy", "cast_slice", "fill", "compute"} <= set(kinds.values())
+    parts = device_phase_map(program, LANE_SCOPES)
+    adopted = adopted_phase_map(program, LANE_SCOPES)
+    assert adopted and not set(adopted) & set(parts)
+    named = {n for rows_ in program.computations.values() for n, op_name, _ in rows_ if op_name}
+    lifted = [n for n, (opcode, _, _, shape) in program.instructions.items()
+              if opcode == "convert" and n not in named
+              and shape.startswith("bf16[%d,%d,%d]" % (layers, width, width))]
+    assert len(lifted) == 2 and not set(lifted) & set(parts)
+    assert {adopted.get(n) for n in lifted} == {"lane.gqa", "lane.dense_ffn"}
+    # the zeros of the momentum are filled for the update alone, or for a
+    # part's gradient sum: never left to no one
+    fills = [n for n, kind in kinds.items() if kind == "fill" and n not in parts
+             and program.instructions[n][3].startswith("f32[%d," % layers)]
+    assert fills and all(n in adopted for n in fills)
